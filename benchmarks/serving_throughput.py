@@ -12,9 +12,8 @@ Two scenarios, both on the real chip (prints one JSON line per mode):
    scenario; round 4 samples inside the dispatch, so the chunk path must
    hold its advantage under load.
 
-Through a remote/tunneled runtime the chunk mode's round-trip
-amortisation is the whole story; on a local TPU VM both modes rise but
-the ordering stands.
+The chunk mode amortises the per-dispatch round-trip over chunk_steps
+tokens; how much that buys depends on the machine's dispatch overhead.
 
 Run: ``python benchmarks/serving_throughput.py``.
 """

@@ -19,7 +19,6 @@ import argparse
 import json
 import shutil
 import subprocess
-import tempfile
 
 _CHILD = r"""
 import json, os, time
@@ -30,7 +29,7 @@ from tpu_engine.mesh_runtime import MeshConfig
 from tpu_engine.sharding import ShardingStage, TPUTrainConfig
 from tpu_engine.train import build_train_program
 
-enable_compilation_cache(os.environ["WARM_RESTART_CACHE"])
+enable_compilation_cache()  # JAX_COMPILATION_CACHE_DIR, set by the parent
 cfg = TPUTrainConfig(
     model_name=os.environ.get("WARM_RESTART_MODEL", "llama-1b"),
     sharding_stage=ShardingStage.FULL_PARTITIONING,
@@ -59,7 +58,7 @@ print(json.dumps({
 def run_child(cache_dir: str, model: str, batch: int, seq: int) -> dict:
     env = dict(os.environ)
     env.update(
-        WARM_RESTART_CACHE=cache_dir,
+        JAX_COMPILATION_CACHE_DIR=cache_dir,
         WARM_RESTART_MODEL=model,
         WARM_RESTART_BATCH=str(batch),
         WARM_RESTART_SEQ=str(seq),
@@ -81,7 +80,18 @@ def main() -> int:
     ap.add_argument("--keep-cache", action="store_true")
     args = ap.parse_args()
 
-    cache = tempfile.mkdtemp(prefix="warm-restart-cache-")
+    # A fixed sub-directory of wherever the cache is placed (the path is
+    # part of what a cache entry is found by), emptied first so the first
+    # child is cold. The parent resolves it by hand: importing tpu_engine
+    # would initialise JAX here and take the chip from the children.
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cache = os.path.join(
+        os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        or os.path.join(repo, ".jax_cache"),
+        "warm_restart",
+    )
+    shutil.rmtree(cache, ignore_errors=True)
+    os.makedirs(cache)
     try:
         cold = run_child(cache, args.model, args.batch, args.seq)
         print(json.dumps({"phase": "cold", **cold}))

@@ -1,8 +1,10 @@
 """Persistent XLA compilation cache (SURVEY.md §7 hard part c — warm-start
-compiles bound resume MTTR): structured enable results, resolution order,
-CPU-backend exclusion, the cache-unused latch, and explicit re-points."""
+compiles bound resume MTTR): structured enable results, the one placement
+rule (``JAX_COMPILATION_CACHE_DIR`` else a fixed in-checkout directory),
+CPU-backend exclusion and the cache-unused latch."""
 
 import os
+import tempfile
 
 import jax
 import jax.numpy as jnp
@@ -13,21 +15,22 @@ from tpu_engine.compile_cache import CacheEnableResult
 
 
 @pytest.fixture(autouse=True)
-def _fresh_index():
+def _fresh_index(monkeypatch):
     """Each test gets a pristine process-wide compile index — the enable
-    path attaches the index sidecar to the cache dir as a side effect."""
+    path attaches the index sidecar to the cache dir as a side effect —
+    and a not-yet-enabled cache."""
     compile_index.reset_index()
+    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
     yield
     compile_index.reset_index()
 
 
 def test_enable_populates_cache(tmp_path, monkeypatch):
     d = str(tmp_path / "xla-cache")
-    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
     # force=True: the CPU test backend is normally excluded (see below).
-    res = compile_cache.enable_compilation_cache(d, force=True)
-    assert res == d  # CacheEnableResult compares equal to its dir string
-    assert res.enabled and res.changed and not res.repointed
+    res = compile_cache.enable_compilation_cache(force=True)
+    assert res.dir == d and res.enabled and res.changed
     assert res.skipped_reason is None
     # Lower the threshold so this test's trivial compile qualifies.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
@@ -35,8 +38,8 @@ def test_enable_populates_cache(tmp_path, monkeypatch):
     f(jnp.ones((64, 64))).block_until_ready()
     assert os.listdir(d), "no cache entries written"
     # Idempotent re-enable keeps the directory and reports changed=False.
-    again = compile_cache.enable_compilation_cache(d, force=True)
-    assert again == d and again.enabled and not again.changed
+    again = compile_cache.enable_compilation_cache(force=True)
+    assert again.dir == d and again.enabled and not again.changed
     assert compile_cache.cache_dir_in_use() == d
     # Enabling attached the fleet index's sidecar next to the executables.
     assert compile_index.get_index().stats()["sidecar_path"] == os.path.join(
@@ -44,45 +47,53 @@ def test_enable_populates_cache(tmp_path, monkeypatch):
     )
 
 
-def test_env_var_resolution(tmp_path, monkeypatch):
+def test_env_places_the_cache_and_nothing_overrides_it(tmp_path, monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` set ⇒ that directory, and no argument
+    or config field exists that could point the cache anywhere else."""
+    import inspect
+
+    from tpu_engine.sharding import TPUTrainConfig
+
     d = str(tmp_path / "from-env")
-    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
-    assert compile_cache.enable_compilation_cache(None, force=True) == d
+    assert compile_cache.resolve_cache_dir() == d
+    assert compile_cache.enable_compilation_cache(force=True).dir == d
     assert os.path.isdir(d)
+    assert jax.config.jax_compilation_cache_dir == d
+    assert list(
+        inspect.signature(compile_cache.enable_compilation_cache).parameters
+    ) == ["force"]
+    assert not [f for f in TPUTrainConfig.model_fields if "cache" in f]
 
 
-def test_resolution_order_explicit_beats_env_beats_default(tmp_path, monkeypatch):
-    """Explicit argument > JAX_COMPILATION_CACHE_DIR > the local default."""
-    explicit = str(tmp_path / "explicit")
-    env = str(tmp_path / "env")
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
-    # Explicit argument wins over the env var.
-    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
-    assert compile_cache.enable_compilation_cache(explicit, force=True) == explicit
-    # Env var wins over the default.
-    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
-    assert compile_cache.enable_compilation_cache(None, force=True) == env
-    # Neither → the local default (no mkdir assertion: HOME is real).
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
-    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
-    assert (
-        compile_cache.enable_compilation_cache(None, force=True)
-        == compile_cache.DEFAULT_CACHE_DIR
-    )
+def test_default_is_a_fixed_path_inside_the_checkout(monkeypatch):
+    """Env unset ⇒ ``<repo>/.jax_cache``: the same path in every process
+    (the path is part of the cache key's locality — a directory that moves
+    never hits), never under ~ or the temp dir, no pid, no timestamp."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    d = compile_cache.resolve_cache_dir()
+    assert d == os.path.join(repo, ".jax_cache") == compile_cache.DEFAULT_CACHE_DIR
+    assert os.path.dirname(d) == repo  # inside the checkout, not under ~
+    assert not d.startswith(tempfile.gettempdir() + os.sep)
+    assert str(os.getpid()) not in d
+    assert not any(ch.isdigit() for ch in os.path.relpath(d, repo))
+    # The git-ignore rule that keeps it out of commits exists.
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
 
 
 def test_cpu_backend_is_excluded_by_default(tmp_path, monkeypatch):
     """XLA:CPU AOT reloads don't round-trip machine features (observed
     interpreter SIGILLs in the CPU test mesh) — the cache only enables on
-    accelerator backends unless forced. The skip is a structured result
-    now, falsy and naming its reason."""
+    accelerator backends unless forced. The skip is a structured result,
+    falsy and naming its reason."""
     d = str(tmp_path / "cpu-skip")
-    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
-    res = compile_cache.enable_compilation_cache(d)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+    res = compile_cache.enable_compilation_cache()
     assert isinstance(res, CacheEnableResult)
     assert not res  # nothing enabled → falsy
-    assert res == None  # noqa: E711 — dir comparison, the legacy contract
+    assert res.dir is None
     assert res.skipped_reason == "cpu-backend"
     assert not os.path.exists(d)
     assert compile_cache.cache_dir_in_use() is None
@@ -92,43 +103,23 @@ def test_cpu_skip_preserves_prior_enable(tmp_path, monkeypatch):
     """A later un-forced call on CPU must not disturb an earlier forced
     enable: the result still reports the active dir and stays truthy."""
     d = str(tmp_path / "forced")
-    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
-    assert compile_cache.enable_compilation_cache(d, force=True) == d
-    res = compile_cache.enable_compilation_cache(str(tmp_path / "other"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+    assert compile_cache.enable_compilation_cache(force=True).dir == d
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "other"))
+    res = compile_cache.enable_compilation_cache()
     assert res.skipped_reason == "cpu-backend"
-    assert res and res == d  # prior enable intact
+    assert res and res.dir == d  # prior enable intact
     assert compile_cache.cache_dir_in_use() == d
-
-
-def test_explicit_repoint_resets_and_flags(tmp_path, monkeypatch, caplog):
-    """Enabling with a *different* explicit dir is a deliberate re-point:
-    new executables land in the new dir, the transition is logged, and the
-    result carries repointed=True. Old entries are not migrated."""
-    a = str(tmp_path / "cache-a")
-    b = str(tmp_path / "cache-b")
-    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
-    assert compile_cache.enable_compilation_cache(a, force=True) == a
-    with caplog.at_level("WARNING", logger=compile_cache.log.name):
-        res = compile_cache.enable_compilation_cache(b, force=True)
-    assert res == b and res.changed and res.repointed
-    assert compile_cache.cache_dir_in_use() == b
-    assert any("re-pointed" in r.message for r in caplog.records)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.jit(lambda x: jnp.sinh(x @ x).sum())(
-        jnp.ones((48, 48))
-    ).block_until_ready()
-    assert os.listdir(b), "post-re-point compile did not land in the new dir"
 
 
 def test_supervisor_enables_without_crashing(tmp_path, monkeypatch):
     """The supervised job's enable call is a safe no-op on the CPU backend
-    (and points the cache at the configured dir on TPU)."""
+    (and points the cache at the resolved dir on TPU)."""
     from tpu_engine.mesh_runtime import MeshConfig
     from tpu_engine.sharding import Precision, ShardingStage, TPUTrainConfig
     from tpu_engine.supervisor import TrainingJob
 
-    d = str(tmp_path / "job-cache")
-    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "job-cache"))
     cfg = TPUTrainConfig(
         model_name="gpt-tiny",
         sharding_stage=ShardingStage.DISABLED,
@@ -137,14 +128,12 @@ def test_supervisor_enables_without_crashing(tmp_path, monkeypatch):
         seq_len=16,
         precision=Precision.FP32,
         activation_checkpointing=False,
-        compilation_cache_dir=d,
     )
     job = TrainingJob("cache-test", cfg, max_steps=1)
     job.start()
     job.join(timeout=300)
     assert job.status.value == "completed", job.error
-    # CPU backend: skipped by design; the config threading is covered by
-    # the force-path tests above.
+    # CPU backend: skipped by design.
     assert compile_cache.cache_dir_in_use() is None
 
 
@@ -161,8 +150,8 @@ def test_enable_after_prior_compile_still_caches(tmp_path, monkeypatch):
     jax.jit(lambda x: x * 2)(jnp.ones(4)).block_until_ready()
 
     d = str(tmp_path / "late-enable")
-    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
-    assert compile_cache.enable_compilation_cache(d, force=True) == d
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+    assert compile_cache.enable_compilation_cache(force=True).dir == d
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.jit(lambda x: jnp.cos(x @ x).sum())(
         jnp.ones((32, 32))

@@ -104,3 +104,115 @@ def test_fleet_snapshot_attributes_running_job_to_its_mesh_chips():
     assert not any(
         r.job_id == res.job_id for d in fleet.devices for r in d.jobs
     )
+
+
+# ---------------------------------------------------------------------------
+# Own load is not a fault and not a stranger's (PR 21: first live HBM
+# telemetry — a full-width job read as CRITICAL on its own chip).
+# ---------------------------------------------------------------------------
+
+
+class _FullChip:
+    """A runtime device whose memory_stats reads 96% full."""
+
+    platform = "tpu"
+    device_kind = "TPU v5 lite"
+    process_index = 0
+    coords = (0, 0, 0)
+    core_on_chip = 0
+
+    def __init__(self, id_=0, used_frac=0.96):
+        self.id = id_
+        self._used = used_frac
+
+    def memory_stats(self):
+        limit = 16 * 2**30
+        return {"bytes_limit": limit, "bytes_in_use": int(limit * self._used)}
+
+
+@pytest.fixture
+def _busy_duty():
+    """The derived source reports the chips 99% busy; no SDK, no CLI."""
+    src = telemetry.DerivedDutySource()
+    telemetry.set_sources([src])
+    yield src
+    telemetry.set_sources(None)
+
+
+def test_own_footprint_and_duty_do_not_read_as_fault_or_unavailable(_busy_duty):
+    _busy_duty.observe(0.99, 1.0, device_ids=[0])
+    mgr = TPUManager(devices=[_FullChip(0)])
+    # Nobody here placed this load: the reference thresholds hold.
+    (dev,) = mgr.get_fleet_status().devices
+    assert dev.hbm_utilization_pct >= 95 and dev.duty_cycle_pct == 99.0
+    assert dev.health_status.value == "critical" and not dev.is_available
+    # The same chip, carrying this control plane's job: full and busy by
+    # design — healthy, schedulable, nothing for a supervisor to heal.
+    telemetry.register_job_devices("own-job", [0], 0, lambda: "running")
+    (dev,) = mgr.get_fleet_status().devices
+    assert dev.carries_own_load and [j.job_id for j in dev.jobs] == ["own-job"]
+    assert dev.health_status.value == "healthy" and dev.alerts == []
+    assert dev.is_available
+    # A real fault still shows through own load.
+    from tpu_engine import faults
+
+    faults.activate(faults.FaultPlan(specs=[faults.FaultSpec(
+        kind=faults.FaultKind.CHIP_UNHEALTHY, at_step=0, device_index=0,
+    )]))
+    try:
+        (dev,) = mgr.get_fleet_status().devices
+        assert dev.health_status.value == "critical" and not dev.is_available
+    finally:
+        faults.clear_active()
+
+
+def test_injected_snapshots_keep_the_load_thresholds():
+    """Injected snapshots carry no job claims: 80% HBM / 90% duty still
+    make a chip unschedulable, 95% HBM still reads CRITICAL."""
+    telemetry.register_job_devices("own-job", [0], 0, lambda: "running")
+    fleet = TPUManager().get_fleet_status(metrics=[
+        {"index": 0, "hbm_total_gb": 16.0, "hbm_used_gb": 15.5},
+        {"index": 1, "hbm_total_gb": 16.0, "hbm_used_gb": 4.0,
+         "duty_cycle_pct": 92.0},
+    ])
+    assert fleet.devices[0].health_status.value == "critical"
+    assert [d.is_available for d in fleet.devices] == [False, False]
+
+
+def test_job_end_drops_its_duty_reading(_busy_duty):
+    """A chip must not read busy for max_age_s after its job left: the
+    next admission (requeue, resume, the job after) would sit queued."""
+    telemetry.register_job_devices("done-job", [0], 0, lambda: "running")
+    telemetry.observe_step(0.99, 1.0, device_ids=[0])
+    _busy_duty.observe(0.99, 1.0, device_ids=[0])
+    mgr = TPUManager(devices=[_FullChip(0, used_frac=0.1)])
+    telemetry.unregister_job_devices("done-job")
+    # The process-wide source forgot the scope ...
+    assert "0" not in telemetry.derived_duty().staleness()["scope_ages_s"]
+    # ... so with that source registered the chip reads idle again.
+    telemetry.set_sources([telemetry.derived_duty()])
+    (dev,) = mgr.get_fleet_status().devices
+    assert dev.duty_cycle_pct is None and dev.is_available
+
+
+def test_delete_job_releases_device_state():
+    """delete_job gives the chips back even though the scheduler's
+    submission record still references the job object."""
+    launcher = TPULauncher()
+    cfg = TPUTrainConfig(
+        model_name="gpt-tiny", mesh=MeshConfig(data=2, fsdp=4),
+        micro_batch_size=1, seq_len=32, precision=Precision.FP32,
+        total_steps=100, warmup_steps=2, activation_checkpointing=False,
+    )
+    res = launcher.launch(cfg, max_steps=2, block=True)
+    job = launcher.get_job(res.job_id)
+    assert job.status == JobStatus.COMPLETED and job._state is not None
+    sub = launcher.scheduler.get(res.submission_id)
+    assert launcher.delete_job(res.job_id)
+    assert sub.job is job  # history keeps the husk ...
+    assert job._state is None and job.program is None  # ... not the HBM
+    assert job.describe()["status"] == "completed"
+    assert job.describe()["attention_impl"] == "xla"
+    with pytest.raises(RuntimeError, match="no initialized state"):
+        job.generate_sample([[1, 2, 3]])
+    assert launcher.scheduler.stats()["reserved_hbm_gib"] == 0.0

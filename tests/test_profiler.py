@@ -63,6 +63,25 @@ def test_mfu_accounting():
     assert v == pytest.approx(88_650e9 / PEAK_FLOPS_BF16["v5e"], rel=1e-6)
 
 
+def test_unknown_tpu_kind_is_an_error_not_a_default():
+    """A utilization against a guessed peak is not a measurement: a TPU
+    whose device_kind is missing from the table raises; off-TPU there is
+    simply no peak."""
+    from tpu_engine.profiler import peak_flops_per_chip
+
+    class NewChip:
+        platform = "tpu"
+        device_kind = "TPU v9 mystery"
+
+    class Cpu:
+        platform = "cpu"
+        device_kind = "cpu"
+
+    with pytest.raises(ValueError, match="TPU v9 mystery"):
+        peak_flops_per_chip(NewChip())
+    assert peak_flops_per_chip(Cpu()) is None
+
+
 def test_pipeline_tick_account():
     # Off the pipelined path there is nothing to account.
     assert pipeline_tick_account("gpipe", 1, 8) is None

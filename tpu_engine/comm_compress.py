@@ -51,7 +51,6 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from tpu_engine.mesh_runtime import BATCH_AXES
@@ -502,12 +501,12 @@ def build(
         if secondary_weights
         else P()  # placeholder leaf for the empty {} pytree
     )
-    sm_grad = shard_map(
+    sm_grad = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         in_specs=(pspecs, hpz_in_spec, P(BATCH_AXES), P(), P()),
         out_specs=(P(), pspecs),
-        check_rep=False,
+        check_vma=False,
     )
 
     def accumulate(params, hpz, batch, key):
@@ -564,13 +563,13 @@ def build(
             )
             return {"codes": codes, "scales": scales}
 
-        sm_refresh = shard_map(
+        sm_refresh = jax.shard_map(
             refresh_body,
-            mesh,
+            mesh=mesh,
             in_specs=(pspecs,),
             out_specs={"codes": spec_trees["codes"],
                        "scales": spec_trees["scales"]},
-            check_rep=False,
+            check_vma=False,
         )
 
         def refresh(params):

@@ -13,6 +13,12 @@ JAX consults the cache per compilation, not at backend init. The fleet-level
 warm/cold bookkeeping over this cache lives in
 ``tpu_engine/compile_index.py`` — enabling here attaches that index's JSON
 sidecar to the cache dir.
+
+The directory is placed from OUTSIDE the program, by one rule
+(:func:`resolve_cache_dir`): ``JAX_COMPILATION_CACHE_DIR`` when set, else a
+fixed directory inside the checkout. No argument, config field or code path
+points the cache anywhere else — the path is part of how a deployment keeps
+its cache across restarts, and a directory that moves never hits.
 """
 
 from __future__ import annotations
@@ -24,65 +30,46 @@ from typing import Optional
 
 log = logging.getLogger(__name__)
 
+# Fixed, inside the checkout (git-ignored): never under ~, never built from
+# a temporary name, a pid or the time.
 DEFAULT_CACHE_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "tpu_engine", "xla-cache"
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
 )
 
 _enabled_dir: Optional[str] = None
 
 
-@dataclass(frozen=True, eq=False)
+def resolve_cache_dir() -> str:
+    """The one cache directory: ``JAX_COMPILATION_CACHE_DIR`` if set (e.g.
+    by infra/tpu-jobset.yaml onto a persistent volume), else the fixed
+    in-checkout default."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
+
+
+@dataclass(frozen=True)
 class CacheEnableResult:
     """Structured outcome of :func:`enable_compilation_cache`.
 
     ``dir`` is the directory the cache is active with after this call (None
-    when nothing is enabled); ``changed`` means this call touched JAX config
-    (first enable, or a re-point); ``repointed`` flags the explicit
-    already-enabled → different-explicit-dir transition; ``skipped_reason``
-    names why the call was a no-op (currently only ``"cpu-backend"``).
-
-    Compares equal to the directory string (and to None when nothing is
-    enabled) so existing ``enable_compilation_cache(d) == d`` call sites
-    keep working; truthiness is "the cache is enabled".
+    when nothing is enabled); ``changed`` means this call touched JAX
+    config; ``skipped_reason`` names why the call was a no-op (currently
+    only ``"cpu-backend"``). Truthiness is "the cache is enabled".
     """
 
     dir: Optional[str]
     enabled: bool
     changed: bool = False
-    repointed: bool = False
     skipped_reason: Optional[str] = None
 
     def __bool__(self) -> bool:
         return self.enabled
 
-    def __eq__(self, other: object) -> bool:
-        if isinstance(other, CacheEnableResult):
-            return (self.dir, self.enabled, self.changed, self.repointed,
-                    self.skipped_reason) == (
-                        other.dir, other.enabled, other.changed,
-                        other.repointed, other.skipped_reason)
-        if other is None or isinstance(other, str):
-            return self.dir == other
-        return NotImplemented
 
-
-def enable_compilation_cache(
-    cache_dir: Optional[str] = None, force: bool = False
-) -> CacheEnableResult:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (idempotent).
-
-    Resolution order: explicit argument > ``JAX_COMPILATION_CACHE_DIR`` env
-    (set by infra/tpu-jobset.yaml onto a persistent volume) > the local
-    default. Returns a :class:`CacheEnableResult`. The thresholds are
-    lowered so the train step (which takes seconds to minutes to compile)
-    always qualifies, while trivial sub-second compiles stay out of the
-    cache.
-
-    Calling again with a *different* explicit directory is an explicit
-    **re-point**: the cache singleton is reset (so executables land in the
-    new directory, not the first one), the transition is logged, and the
-    result carries ``repointed=True``. Entries already written to the old
-    directory are not migrated.
+def enable_compilation_cache(force: bool = False) -> CacheEnableResult:
+    """Point JAX's persistent compilation cache at :func:`resolve_cache_dir`
+    (idempotent). The thresholds are lowered so the train step (which takes
+    seconds to minutes to compile) always qualifies, while trivial
+    sub-second compiles stay out of the cache.
 
     NOT enabled on the CPU backend unless ``force``: XLA:CPU AOT reloads
     are compiled with machine-feature sets that do not round-trip
@@ -91,13 +78,9 @@ def enable_compilation_cache(
     warm TPU restarts — does not apply there anyway.
     """
     global _enabled_dir
-    d = (
-        cache_dir
-        or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-        or DEFAULT_CACHE_DIR
-    )
+    d = resolve_cache_dir()
     if _enabled_dir == d:
-        return CacheEnableResult(dir=d, enabled=True, changed=False)
+        return CacheEnableResult(dir=d, enabled=True)
     import jax
 
     if not force and jax.default_backend() == "cpu":
@@ -108,15 +91,8 @@ def enable_compilation_cache(
             skipped_reason="cpu-backend",
         )
 
-    repointed = _enabled_dir is not None
-    if repointed:
-        log.warning(
-            "persistent XLA compilation cache re-pointed: %s -> %s "
-            "(existing entries are not migrated)",
-            _enabled_dir, d,
-        )
     os.makedirs(d, exist_ok=True)
-    prev = getattr(jax.config, "jax_compilation_cache_dir", None)
+    prev = jax.config.jax_compilation_cache_dir
     jax.config.update("jax_compilation_cache_dir", d)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
@@ -126,12 +102,9 @@ def enable_compilation_cache(
         # ``is_cache_used`` memoizes a cache-OFF verdict at the process's
         # FIRST compile — so enabling after any earlier jit (telemetry
         # probe, eval_shape warm-up) would silently cache nothing.
-        try:
-            from jax._src import compilation_cache as _cc
+        from jax._src import compilation_cache as _cc
 
-            _cc.reset_cache()
-        except Exception:
-            log.warning("could not reset jax compilation cache singleton")
+        _cc.reset_cache()
     _enabled_dir = d
     log.info("persistent XLA compilation cache: %s", d)
     # The fleet compile index persists its layout-keyed sidecar next to the
@@ -142,7 +115,7 @@ def enable_compilation_cache(
         get_index().attach_dir(d)
     except Exception:  # the index must never break cache enablement
         log.debug("compile index sidecar attach failed", exc_info=True)
-    return CacheEnableResult(dir=d, enabled=True, changed=True, repointed=repointed)
+    return CacheEnableResult(dir=d, enabled=True, changed=True)
 
 
 def cache_dir_in_use() -> Optional[str]:

@@ -210,6 +210,9 @@ class TPULauncher:
                 "master_params": config.param_dtype.value,
                 "loss_scaling": "none (bf16 — not needed)",
             },
+            # Comm-tuning compiler flags (tpu_engine/comm.py): whether they
+            # are IN FORCE in this process, not what the config asks for.
+            "comm_flags": comm.comm_flags_status(config),
             # ZeRO++-style collective compression (tpu_engine/comm_compress.py):
             # which mechanisms are on and the analytic wire-volume factors.
             "comm_compression": comm.compression_plan(config),
@@ -425,7 +428,8 @@ class TPULauncher:
         return True
 
     def delete_job(self, job_id: str) -> bool:
-        """Drop a *terminal* job from the registry (bounds registry growth;
+        """Drop a *terminal* job from the registry and release what it holds
+        on the devices (bounds registry growth and gives the HBM back;
         checkpoints on disk are untouched). Raises ValueError for a job
         that is still pending/compiling/running — stop it first."""
         with self._lock:
@@ -437,6 +441,10 @@ class TPULauncher:
                     f"job '{job_id}' is {job.status.value}; stop it before deleting"
                 )
             del self._jobs[job_id]
+        # The scheduler's submission record still references the job (its
+        # describe() history) — dropping the registry entry alone would
+        # leave params and optimizer state resident.
+        job.release_device_state()
         return True
 
 
@@ -468,15 +476,14 @@ def main(argv: Optional[list[str]] = None) -> int:
                         "--set mesh.fsdp=8 (repeatable)")
     args = parser.parse_args(argv)
 
-    launcher = TPULauncher()
     if args.list_presets:
-        for name, cfg in launcher.presets().items():
+        for name, cfg in TPULauncher.presets().items():
             print(f"{name}: {cfg.model_name} stage={int(cfg.sharding_stage)} "
                   f"eff_batch={cfg.effective_batch_size}")
         return 0
 
     if args.preset:
-        all_presets = launcher.presets()
+        all_presets = TPULauncher.presets()
         if args.preset not in all_presets:
             parser.error(f"unknown preset '{args.preset}'; known: {sorted(all_presets)}")
         cfg_dict = all_presets[args.preset].model_dump()
@@ -501,11 +508,12 @@ def main(argv: Optional[list[str]] = None) -> int:
             target[leaf] = value
     config = TPUTrainConfig(**cfg_dict)
 
-    # Comm-tuning XLA flags must land before the backend initialises
+    # Start-up order matters, and nothing before this point may touch a jax
+    # device (constructing TPULauncher does — its planner reads the chip's
+    # peak — so it comes last).
+    # Comm-tuning compiler flags must land before the backend initialises
     # (tpu_engine/comm.py — the reference's overlap_comm/bucket analogue).
-    from tpu_engine.comm import apply_comm_flags
-
-    apply_comm_flags(config)
+    comm.apply_comm_flags(config)
 
     # Multi-host rendezvous FIRST: jax.distributed.initialize() refuses to
     # run once any jax call has initialised the XLA backend — and the
@@ -518,8 +526,9 @@ def main(argv: Optional[list[str]] = None) -> int:
     # elastic relaunch) warm-start their compiles (tpu_engine/compile_cache).
     from tpu_engine.compile_cache import enable_compilation_cache
 
-    enable_compilation_cache(config.compilation_cache_dir)
+    enable_compilation_cache()
 
+    launcher = TPULauncher()
     result = launcher.launch(
         config,
         dry_run=args.dry_run,

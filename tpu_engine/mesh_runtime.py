@@ -30,6 +30,7 @@ the bandwidth-hungry ``model`` and ``sequence`` collectives ride ICI while
 
 from __future__ import annotations
 
+import logging
 import os
 from typing import Any, Optional, Sequence
 
@@ -39,26 +40,13 @@ from jax.experimental import mesh_utils
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from pydantic import BaseModel, Field, model_validator
 
+log = logging.getLogger(__name__)
+
 MESH_AXES = ("data", "fsdp", "pipe", "sequence", "model")
 
 # Axes over which the batch dimension is sharded (everything that is not
 # tensor- or sequence-parallel).
 BATCH_AXES = ("data", "fsdp")
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: top-level export with
-    ``check_vma`` (new) vs ``jax.experimental.shard_map`` with
-    ``check_rep`` (old). Replication checking is off either way — the
-    kernel call sites here all return fully sharded outputs, which the
-    checker cannot verify through a Pallas call."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
 
 
 class MeshConfig(BaseModel):
@@ -212,8 +200,7 @@ def initialize_distributed(
     coordinator from the environment, so all arguments are optional. Returns
     True if distributed mode was initialised, False for single-process runs.
     """
-    already = getattr(jax.distributed, "is_initialized", None)
-    if callable(already) and already():
+    if jax.distributed.is_initialized():
         return True
     env_says_multiprocess = any(
         os.environ.get(k)
@@ -236,18 +223,19 @@ def initialize_distributed(
 def _device_array(shape: tuple[int, ...], devs: Sequence[jax.Device]) -> np.ndarray:
     """ICI-aware device layout, with fallbacks for shapes the default
     assignment can't map (e.g. a (2, 8) logical mesh on a 4x4 torus —
-    raises NotImplementedError unless physical axes may be split)."""
-    try:
-        return mesh_utils.create_device_mesh(shape, devices=list(devs))
-    except NotImplementedError:
+    raises NotImplementedError unless physical axes may be split). Every
+    fallback is logged with its cause: an enumeration-order mesh runs, but
+    its collectives may not ride neighbouring ICI links."""
+    for options in ({}, {"allow_split_physical_axes": True}):
         try:
-            return mesh_utils.create_device_mesh(
-                shape, devices=list(devs), allow_split_physical_axes=True
+            return mesh_utils.create_device_mesh(shape, devices=list(devs), **options)
+        except (NotImplementedError, ValueError, AssertionError) as e:
+            log.warning(
+                "mesh %s: no ICI-aware assignment with %s (%s: %s)",
+                shape, options or "default options", type(e).__name__, e,
             )
-        except Exception:
-            return np.asarray(devs).reshape(shape)
-    except (ValueError, AssertionError):
-        return np.asarray(devs).reshape(shape)
+    log.warning("mesh %s: devices laid out in enumeration order", shape)
+    return np.asarray(devs).reshape(shape)
 
 
 def build_mesh(

@@ -17,8 +17,8 @@ Design choices for the MXU/HBM (see SURVEY.md §7 and the task's TPU notes):
 - activation checkpointing is ``jax.checkpoint`` around the scanned block,
   policy-selectable (reference activation-checkpointing config:
   ``deepspeed_launcher.py:215-223``);
-- attention dispatches to the Pallas flash-attention kernel on TPU when
-  enabled (``tpu_engine/ops``), with a pure-XLA fallback that XLA fuses well.
+- attention is the Pallas flash-attention kernel (``tpu_engine/ops``) or
+  plain XLA attention, chosen once by the caller (``build_train_program``).
 """
 
 from __future__ import annotations
@@ -59,7 +59,7 @@ class ModelConfig:
     max_seq_len: int = 2048
     rope_theta: float = 10_000.0
     norm_eps: float = 1e-5
-    # Attention implementation: "xla" (fallback) or "flash" (Pallas kernel).
+    # Attention implementation: "xla" or "flash" (Pallas kernel).
     attention_impl: str = "xla"
     # Sliding-window (Mistral-style) attention: each query sees only the
     # trailing `sliding_window` keys. 0 = full causal. The flash kernel
@@ -432,8 +432,9 @@ def _attention(q, k, v, impl: str, mesh=None, window: int = 0):
       ``sequence`` axis (``tpu_engine/parallel/ring_attention.py``);
     - ``"ulysses"`` — sequence-parallel all-to-all attention (head↔sequence
       shard swap, ``tpu_engine/parallel/ulysses_attention.py``);
-    - ``"flash"`` — Pallas TPU flash kernel (``tpu_engine/ops``);
-    - ``"xla"``  — plain XLA attention (fallback / reference semantics).
+    - ``"flash"`` — Pallas TPU flash kernel (``tpu_engine/ops``); a shape
+      it cannot run raises, it never degrades to XLA;
+    - ``"xla"``  — plain XLA attention (reference semantics).
 
     ``window > 0`` = sliding-window attention (flash/xla paths only; the
     sequence-parallel strategies are full-context by construction).
@@ -456,52 +457,61 @@ def _attention(q, k, v, impl: str, mesh=None, window: int = 0):
         return ulysses_mha(q, k, v, mesh=mesh, causal=True)
     from tpu_engine.ops import flash_attention  # lazy: avoids import cycles
 
-    if impl == "flash" and mesh is not None and mesh.size > 1:
-        # Mosaic (Pallas) calls cannot be partitioned by GSPMD — on a
-        # multi-device mesh the kernel must run under shard_map with the
-        # activation layout pinned: batch over (data, fsdp), heads over
-        # "model", sequence local (a >1 "sequence" axis never reaches the
-        # flash path — build_train_program routes it to ring/ulysses).
-        from functools import partial
-
-        from jax.sharding import PartitionSpec as P
-
-        from tpu_engine.mesh_runtime import shard_map_compat
-
-        model_size = mesh.shape.get("model", 1)
-        H, KV = q.shape[2], k.shape[2]
-        if H % model_size == 0 and KV % model_size == 0:
-            spec = P(("data", "fsdp"), None, "model", None)
-            sh = jax.sharding.NamedSharding(mesh, spec)
-            # Pin the boundary on BOTH sides of the manual region. shard_map
-            # reshards implicitly, but the explicit constraints also pin the
-            # *cotangents* in the backward pass (with_sharding_constraint is
-            # its own transpose) — without them, GSPMD sharding propagation
-            # around the manual region is ambiguous and the partitioner's
-            # dot-strategy estimator probes layouts it can only reach by
-            # involuntary full rematerialization (MULTICHIP_r02 tail).
-            q, k, v = (jax.lax.with_sharding_constraint(t, sh) for t in (q, k, v))
-            # Decide interpret mode from the MESH's devices, not the default
-            # backend: an AOT compile for a described TPU topology may run
-            # under a CPU-forced process (tests), and the CPU dry-run mesh
-            # must exercise the kernel's real custom_vjp wrapping (interpret
-            # mode) rather than silently testing the XLA fallback — that
-            # would be a *different* backward graph than the one that ships.
-            interpret = mesh.devices.flat[0].platform != "tpu"
-            fn = shard_map_compat(
-                partial(flash_attention.mha, causal=True, window=window,
-                        interpret=interpret),
-                mesh=mesh,
-                in_specs=(spec, spec, spec),
-                out_specs=spec,
-            )
-            return jax.lax.with_sharding_constraint(fn(q, k, v), sh)
-        # GQA ratio would change per-shard (wrong kv mapping) — XLA path.
+    if impl != "flash":
         return flash_attention.mha(q, k, v, causal=True, force_xla=True,
                                    window=window)
 
-    return flash_attention.mha(q, k, v, causal=True,
-                               force_xla=(impl != "flash"), window=window)
+    # Interpret mode is decided by the devices the kernel will run on — the
+    # mesh's, or the default backend's when no mesh was threaded — never
+    # by a fallback: an AOT compile for a described TPU topology may run
+    # under a CPU-forced process, and the CPU dry-run mesh must exercise
+    # the kernel's real custom_vjp wrapping (a *different* backward graph
+    # than XLA attention's).
+    probe = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+    interpret = probe.platform != "tpu"
+    if mesh is None or mesh.size == 1:
+        return flash_attention.mha(q, k, v, causal=True, window=window,
+                                   interpret=interpret)
+
+    # Mosaic (Pallas) calls cannot be partitioned by GSPMD — on a
+    # multi-device mesh the kernel must run under shard_map with the
+    # activation layout pinned: batch over (data, fsdp), heads over
+    # "model", sequence local (a >1 "sequence" axis never reaches the
+    # flash path — build_train_program routes it to ring/ulysses).
+    from jax.sharding import PartitionSpec as P
+
+    model_size = mesh.shape.get("model", 1)
+    H, KV = q.shape[2], k.shape[2]
+    if H % model_size or KV % model_size:
+        # Sharding heads unevenly would change the per-shard GQA ratio
+        # (wrong q→kv mapping). build_train_program resolves "auto" away
+        # from flash for such shapes; reaching here is an explicit request.
+        raise ValueError(
+            f"attention_impl='flash' needs q heads ({H}) and kv heads ({KV}) "
+            f"divisible by the 'model' mesh axis ({model_size}); use "
+            "attention_impl='xla' or a model axis that divides both"
+        )
+    spec = P(("data", "fsdp"), None, "model", None)
+    sh = jax.sharding.NamedSharding(mesh, spec)
+    # Pin the boundary on BOTH sides of the manual region. shard_map
+    # reshards implicitly, but the explicit constraints also pin the
+    # *cotangents* in the backward pass (with_sharding_constraint is its
+    # own transpose) — without them, GSPMD sharding propagation around the
+    # manual region is ambiguous and the partitioner's dot-strategy
+    # estimator probes layouts it can only reach by involuntary full
+    # rematerialization (MULTICHIP_r02 tail).
+    q, k, v = (jax.lax.with_sharding_constraint(t, sh) for t in (q, k, v))
+    # check_vma off: the checker cannot verify a fully sharded output
+    # through a Pallas call.
+    fn = jax.shard_map(
+        partial(flash_attention.mha, causal=True, window=window,
+                interpret=interpret),
+        mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=spec,
+        check_vma=False,
+    )
+    return jax.lax.with_sharding_constraint(fn(q, k, v), sh)
 
 
 def _moe_mlp_ragged(h, layer_params, cfg: ModelConfig):

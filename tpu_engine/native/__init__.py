@@ -4,15 +4,20 @@ The reference has zero first-party native code (SURVEY.md §2.2); this is the
 TPU build's native surface — a mmap'd tokenized-dataset reader with threaded
 gather and double-buffered prefetch, plus a /proc host-telemetry probe.
 
-``ensure_built()`` compiles the shared library with g++ on first use (cached
-by source mtime; the Dockerfile pre-builds it at image build). Every entry
-point has a pure-NumPy fallback, so the engine runs — slower — where no
-toolchain exists; ``tpu_engine.data`` picks the fastest available.
+``ensure_built()`` compiles the shared library with g++ on first use. The
+built file is named by a hash of ``tpunative.cpp``, so only a binary of the
+tracked source is ever loaded: a stale ``_build/`` copied along with the
+tree has another name and is never picked up. Every entry point has a
+pure-NumPy stand-in, so the engine runs — slower — where no toolchain
+exists; ``tpu_engine.data`` picks the fastest available and :func:`status`
+says which, and why.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -20,14 +25,21 @@ from typing import Optional
 
 import numpy as np
 
+log = logging.getLogger(__name__)
+
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "tpunative.cpp")
 _BUILD_DIR = os.path.join(_DIR, "_build")
-_LIB = os.path.join(_BUILD_DIR, "libtpunative.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed: Optional[str] = None
+
+
+def _lib_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"libtpunative-{digest}.so")
 
 
 class _TnHostStats(ctypes.Structure):
@@ -44,29 +56,50 @@ def ensure_built(force: bool = False) -> Optional[str]:
     """Compile the native library if needed; returns its path or None."""
     global _build_failed
     with _lock:
-        if not force and os.path.exists(_LIB) and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC):
-            return _LIB
+        lib = _lib_path()
+        if not force and os.path.exists(lib):
+            return lib
         if _build_failed is not None and not force:
             return None
         os.makedirs(_BUILD_DIR, exist_ok=True)
+        # Build under a per-process name, then rename: a concurrent builder
+        # (another worker on this host) never sees a half-written library.
+        tmp = f"{lib}.{os.getpid()}.tmp"
         cmd = [
             "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-            _SRC, "-o", _LIB,
+            _SRC, "-o", tmp,
         ]
         try:
             proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
         except (OSError, subprocess.TimeoutExpired) as e:
             _build_failed = str(e)
+        else:
+            _build_failed = proc.stderr[-2000:] if proc.returncode != 0 else None
+        if _build_failed is not None:
+            log.warning(
+                "native library build failed — NumPy readers in use: %s",
+                _build_failed,
+            )
             return None
-        if proc.returncode != 0:
-            _build_failed = proc.stderr[-2000:]
-            return None
-        _build_failed = None
-        return _LIB
+        os.replace(tmp, lib)
+        return lib
 
 
 def build_error() -> Optional[str]:
     return _build_failed
+
+
+def status() -> str:
+    """Which reader is in use and why, without triggering a build:
+    ``native (<file>)``, ``numpy (native build failed: ...)`` or
+    ``not built yet (compiles on first dataset use)``."""
+    if _lib is not None:
+        return f"native ({os.path.basename(_lib._name)})"
+    if _build_failed is not None:
+        return f"numpy (native build failed: {_build_failed.strip()[-200:]})"
+    if os.path.exists(_lib_path()):
+        return f"native ({os.path.basename(_lib_path())}, not loaded yet)"
+    return "not built yet (compiles on first dataset use)"
 
 
 def load() -> Optional[ctypes.CDLL]:

@@ -7,7 +7,7 @@ tile (never the whole sequence), and online-softmax state (m/l/acc) lives in
 VMEM scratch that persists across the K iterations. Peak VMEM is
 O(BLOCK_Q · D + BLOCK_K · D + BLOCK_Q · BLOCK_K) regardless of sequence
 length — the S×S score matrix is never materialised, and neither is a full
-[S, D] K/V copy (the ``_xla_mha`` fallback materialises S×S).
+[S, D] K/V copy (``_xla_mha`` materialises S×S).
 
 Backward: custom_vjp over two Pallas kernels. The forward saves the
 log-sum-exp rows; the backward reconstructs attention probabilities
@@ -30,13 +30,10 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Renamed TPUCompilerParams -> CompilerParams across jax releases.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 _NEG_INF = -1e30
 
 
-class FlashUnsupported(Exception):
+class FlashUnsupported(ValueError):
     """Raised (at trace time) when a shape/config can't use the flash kernel."""
 
 
@@ -52,12 +49,31 @@ _BWD_BLOCK_CAP = 1024
 
 
 def _pick_block(s: int) -> int:
-    for b in (1024, 512, 256, 128, 64):
+    """Forward tile for sequence length ``s``; 0 = no tiling exists.
+
+    A tile is a multiple of 128 or the whole sequence: the lse rows leave
+    the kernel as ``(1, 1, block)`` blocks, and Mosaic requires a block's
+    last dimension to be 128-divisible or equal to the array's (a 64-wide
+    tile of a 128-token sequence is refused on the chip — PR 21)."""
+    for b in (1024, 512, 256, 128):
         if s % b == 0 and s // b >= 2:
             return b
-    if s % 64 == 0:
-        return min(s, 1024)
-    return 0  # caller falls back to XLA attention
+    if s in (64, 128):
+        return s
+    return 0
+
+
+def tiling_obstacle(seq_len: int) -> str | None:
+    """Why the kernel cannot tile ``seq_len`` (None = it can). The one
+    predicate shared by :func:`flash_mha` and the train program's
+    attention resolution, so "flash" is only ever reported for a shape the
+    kernel accepts."""
+    if _pick_block(seq_len) == 0:
+        return (
+            f"no flash tiling for seq_len={seq_len} (needs 64 or a multiple "
+            "of 128)"
+        )
+    return None
 
 
 # ---------------------------------------------------------------------------
@@ -254,7 +270,7 @@ def _flash_fwd(q, k, v, block: int, interpret: bool, window: int,
             pltpu.VMEM((block, D), jnp.float32),    # output accumulator
             pltpu.VMEM((block, D), q.dtype),        # scale·log2e-folded Q
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -433,7 +449,7 @@ def _flash_bwd(block: int, interpret: bool, window: int, res, do,
         out_specs=qkv_spec,
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bb, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -475,7 +491,7 @@ def _flash_bwd(block: int, interpret: bool, window: int, res, do,
             pltpu.VMEM((bb, D), jnp.float32),
             pltpu.VMEM((bb, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -534,7 +550,7 @@ def _flash_fwd_lse_bwd(block, interpret, causal, res, cts):
 flash_fwd_lse.defvjp(_flash_fwd_lse_fwd, _flash_fwd_lse_bwd)
 
 
-def flash_mha(q, k, v, causal: bool = True, interpret: bool | None = None,
+def flash_mha(q, k, v, causal: bool = True, interpret: bool = False,
               window: int = 0):
     """Flash attention on [B, S, H, D]; returns [B, S, H, D].
 
@@ -542,26 +558,24 @@ def flash_mha(q, k, v, causal: bool = True, interpret: bool | None = None,
     (sliding-window attention, Mistral-style): block pairs wholly outside
     the window are skipped — compute and DMA — so cost is O(S·W), not O(S²).
 
+    ``interpret=True`` is Pallas interpret mode, for CPU meshes only; the
+    default compiles the Mosaic kernel and fails on a non-TPU backend.
+
     Raises :class:`FlashUnsupported` (at trace time) when the shape doesn't
-    tile or attention is non-causal; the dispatcher in
-    ``flash_attention.mha`` then falls back to the XLA path.
+    tile or attention is non-causal.
     """
     B, S, H, D = q.shape
     KV = k.shape[2]
+    obstacle = tiling_obstacle(S)
+    if obstacle is not None:
+        raise FlashUnsupported(obstacle)
+    if not causal:
+        raise FlashUnsupported("the flash kernel entry is causal-only")
     block = _pick_block(S)
-    if not causal or block == 0 or S < 64:
-        raise FlashUnsupported(f"no flash tiling for seq_len={S}, causal={causal}")
     if window < 0:
         raise ValueError(f"window must be >= 0, got {window}")
     if window >= S:
         window = 0  # a window covering the whole sequence is plain causal
-    if interpret is None:
-        # Off-TPU the kernel would only run in interpret mode — orders of
-        # magnitude slower than XLA attention. Don't do that silently; let
-        # the dispatcher fall back to XLA. Tests opt in with interpret=True.
-        if jax.devices()[0].platform != "tpu":
-            raise FlashUnsupported("no TPU present (pass interpret=True to force)")
-        interpret = False
     if KV != H:
         k = jnp.repeat(k, H // KV, axis=2)
         v = jnp.repeat(v, H // KV, axis=2)

@@ -1,7 +1,7 @@
-"""Flash attention for TPU (Pallas), with an XLA fallback.
+"""Attention entry point: the Pallas TPU flash kernel or plain XLA attention.
 
-Phase-7 home of the Pallas kernel; the public entry point :func:`mha` is
-stable from day one so the model can dispatch to it unconditionally.
+The caller chooses; nothing here substitutes one for the other. A shape
+the kernel cannot tile raises :class:`FlashUnsupported`.
 
 Layout convention: q [B, S, H, D], k/v [B, S, KV, D] (GQA when KV < H),
 causal masking only (decoder-only LM).
@@ -32,19 +32,16 @@ def _xla_mha(q, k, v, causal: bool = True, window: int = 0):
 
 
 def mha(q, k, v, causal: bool = True, force_xla: bool = False, window: int = 0,
-        interpret: bool | None = None):
-    """Multi-head attention dispatch.
+        interpret: bool = False):
+    """Multi-head attention: the Pallas flash kernel, or XLA attention when
+    ``force_xla``.
 
     ``window > 0`` is sliding-window (Mistral-style) attention: each query
-    sees only the trailing ``window`` keys. ``force_xla=True`` (or an
-    untileable shape) → the XLA implementation; otherwise the first-party
-    Pallas flash kernel.
+    sees only the trailing ``window`` keys.
 
-    ``interpret=True`` forces the Pallas kernel in interpret mode off-TPU
-    (slow — the multi-device shard_map path uses it so the CPU dry-run
-    exercises the kernel's real custom_vjp wrapping rather than silently
-    testing the XLA fallback); ``None`` lets ``flash_mha`` fall back to XLA
-    when no TPU is attached.
+    ``interpret=True`` runs the kernel in Pallas interpret mode — for CPU
+    meshes only, where it exercises the kernel's real custom_vjp wrapping.
+    Callers derive it from the devices their arrays live on.
     """
     if window < 0:
         raise ValueError(f"window must be >= 0, got {window}")
@@ -52,10 +49,6 @@ def mha(q, k, v, causal: bool = True, force_xla: bool = False, window: int = 0,
         raise ValueError("sliding-window attention requires causal=True")
     if force_xla:
         return _xla_mha(q, k, v, causal=causal, window=window)
-    from tpu_engine.ops._flash_pallas import FlashUnsupported, flash_mha
+    from tpu_engine.ops._flash_pallas import flash_mha
 
-    try:
-        return flash_mha(q, k, v, causal=causal, window=window,
-                         interpret=interpret)
-    except FlashUnsupported:
-        return _xla_mha(q, k, v, causal=causal, window=window)
+    return flash_mha(q, k, v, causal=causal, window=window, interpret=interpret)

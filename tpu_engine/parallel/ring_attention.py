@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpu_engine.mesh_runtime import BATCH_AXES, shard_map_compat
+from tpu_engine.mesh_runtime import BATCH_AXES
 from tpu_engine.ops._flash_pallas import _pick_block, flash_fwd_lse
 
 _NEG_INF = -1e30
@@ -222,11 +222,14 @@ def ring_mha(
     # same custom_vjp wrapping as the TPU build (cf. ulysses/flash paths).
     interpret = mesh.devices.flat[0].platform != "tpu"
     spec = P(BATCH_AXES, axis_name, "model", None)
-    f = shard_map_compat(
+    # check_vma off: the checker cannot verify a fully sharded output
+    # through a Pallas call.
+    f = jax.shard_map(
         partial(_ring_attention_local, axis_name=axis_name, causal=causal,
                 interpret=interpret),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
+        check_vma=False,
     )
     return f(q, k, v)

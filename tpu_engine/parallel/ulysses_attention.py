@@ -33,8 +33,9 @@ import jax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpu_engine.mesh_runtime import BATCH_AXES, shard_map_compat
+from tpu_engine.mesh_runtime import BATCH_AXES
 from tpu_engine.ops import flash_attention
+from tpu_engine.ops._flash_pallas import tiling_obstacle
 
 
 def _ulysses_local(
@@ -67,7 +68,13 @@ def _ulysses_local(
     k = a2a(k, split_axis=2, concat_axis=1)
     v = a2a(v, split_axis=2, concat_axis=1)
 
-    out = flash_attention.mha(q, k, v, causal=causal, interpret=interpret)
+    # Full-sequence attention over this device's head group: the flash
+    # kernel where the (static) sequence length tiles, XLA attention for
+    # the rest — chosen from the shape, like ring attention's per-hop body.
+    out = flash_attention.mha(
+        q, k, v, causal=causal, interpret=interpret,
+        force_xla=not causal or tiling_obstacle(q.shape[1]) is not None,
+    )
 
     # Swap back: head-sharded → sequence-sharded.
     return a2a(out, split_axis=1, concat_axis=2)
@@ -90,10 +97,12 @@ def ulysses_mha(
     """
     # Off-TPU (CPU dry-run/test meshes) the kernel runs in interpret mode so
     # the same custom_vjp wrapping that ships on TPU is what gets exercised
-    # — not the XLA fallback's different backward graph.
+    # — not XLA attention's different backward graph.
     on_tpu = mesh.devices.flat[0].platform == "tpu"
     spec = P(BATCH_AXES, axis_name, "model", None)
-    f = shard_map_compat(
+    # check_vma off: the checker cannot verify a fully sharded output
+    # through a Pallas call.
+    f = jax.shard_map(
         partial(
             _ulysses_local,
             axis_name=axis_name,
@@ -103,5 +112,6 @@ def ulysses_mha(
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
+        check_vma=False,
     )
     return f(q, k, v)

@@ -41,14 +41,22 @@ PEAK_FLOPS_BF16 = {
 
 
 def peak_flops_per_chip(device: Optional[jax.Device] = None) -> Optional[float]:
-    """Peak bf16 FLOP/s for ``device`` (default: first visible), or None if
-    the chip generation isn't recognised (e.g. CPU test meshes)."""
+    """Peak bf16 FLOP/s for ``device`` (default: first visible). None off
+    TPU (CPU test meshes have no peak); a TPU whose ``device_kind`` is not
+    in the table is an error, not a default — a utilization against a
+    guessed peak is not a measurement."""
     if device is None:
         device = jax.devices()[0]
     kind = getattr(device, "device_kind", "").lower()
     for key, flops in PEAK_FLOPS_BF16.items():
         if key in kind:
             return flops
+    if getattr(device, "platform", None) == "tpu":
+        raise ValueError(
+            f"no peak FLOP/s entry for TPU device_kind "
+            f"{getattr(device, 'device_kind', None)!r}; known: "
+            f"{sorted(PEAK_FLOPS_BF16)} (add it to PEAK_FLOPS_BF16 with its source)"
+        )
     return None
 
 

@@ -1276,6 +1276,13 @@ class FleetScheduler:
                 self._set_state(sub, SubmissionState.QUEUED)
                 sub.preemptions += 1
                 sub.job = None
+                # The dead attempt's train state is on disk now; registries
+                # that still list the attempt (TPULauncher._jobs, until the
+                # next attempt replaces it) must not keep it resident, or
+                # the chips it filled never read free enough to re-admit.
+                release = getattr(job, "release_device_state", None)
+                if release is not None:
+                    release()
                 self.requeues_total += 1
                 if str(getattr(job, "preemption_reason", "") or "").startswith("self-heal"):
                     self.self_heal_requeues_total += 1

@@ -184,6 +184,11 @@ class TrainingJob:
         self.rollback_count = 0
         self.resumed_from_step: Optional[int] = None
         self.resumed_via_reshard: Optional[dict] = None
+        #: What the built program holds: the resolved attention kernel and
+        #: the comm-flag delivery found at build time
+        #: (tpu_engine.comm.comm_flags_status). None before the build.
+        self.attention_impl: Optional[str] = None
+        self.comm_flags: Optional[dict[str, Any]] = None
         self._topology_written = False
         self.preemption_reason: Optional[str] = None
         self.started_at: Optional[float] = None
@@ -255,6 +260,29 @@ class TrainingJob:
     def is_alive(self) -> bool:
         return self._thread is not None and self._thread.is_alive()
 
+    def release_device_state(self) -> None:
+        """Give the chips back: drop the train state, the compiled program
+        (and the constants it captured) and the merged-LoRA cache. For an
+        attempt that is over — a terminal job being deleted, or a preempted
+        attempt whose successor resumes from the checkpoint. ``describe()``
+        keeps working; sampling and export raise "no initialized state".
+        Until this runs a finished job's params and optimizer state stay
+        resident (they serve ``generate``/``export``), and on a full-width
+        job that alone keeps the next one from being admitted."""
+        # A terminal status is set just before the thread's own clean-up
+        # (closing datasets, waiting out an async save) — let that finish.
+        self.join(timeout=30.0)
+        if self.is_alive:
+            raise RuntimeError(
+                f"job '{self.job_id}' is still running; stop it first"
+            )
+        with self._state_lock:
+            self._state = None
+            self._merged_cache = None
+            self.program = None
+        self.data_fn = None
+        self._eval_data_fn = None
+
     # -- preemption ----------------------------------------------------------
 
     def _on_preemption(self, reason: str) -> None:
@@ -295,8 +323,9 @@ class TrainingJob:
 
     def _unhealthy_mesh_devices(self) -> list[int]:
         """Fleet device indices that are CRITICAL *and* inside this job's
-        mesh. Keyed on health, not ``is_available`` — this job's own HBM
-        footprint and duty cycle must never read as a failure."""
+        mesh. This job's own HBM footprint and duty cycle never read as a
+        failure: the fleet view does not classify load on chips that carry
+        this control plane's job claims (``TPUDevice.carries_own_load``)."""
         prog = self.program
         if prog is None:
             return []
@@ -499,10 +528,12 @@ class TrainingJob:
         cfg = self._elastic_config()
         # Comm-tuning flags: in the worker CLI these were applied before the
         # backend initialised; in a long-lived server this warns that the
-        # per-job knobs cannot take effect (never a silent no-op).
-        from tpu_engine.comm import apply_comm_flags
+        # per-job knobs cannot take effect, and describe() reports them as
+        # not in force (never a silent no-op).
+        from tpu_engine.comm import apply_comm_flags, comm_flags_status
 
         apply_comm_flags(cfg)
+        self.comm_flags = comm_flags_status(cfg)
         if cfg.lora_rank and cfg.lora_base_hf_checkpoint:
             from transformers import AutoModelForCausalLM
 
@@ -618,7 +649,7 @@ class TrainingJob:
                 parent=attempt_span,
             ) as compile_span:
                 t_compile0 = time.time()
-                enable_compilation_cache(self.config.compilation_cache_dir)
+                enable_compilation_cache()
                 if self.program is None:
                     self.program = self._build_program()
                 compile_s = max(time.time() - t_compile0, 0.0)
@@ -630,6 +661,7 @@ class TrainingJob:
                     cache_hit=cache_hit, compile_s=round(compile_s, 6),
                 )
             prog = self.program
+            self.attention_impl = prog.model_config.attention_impl
 
             # Per-chip attribution: claim this job's chips in the fleet view
             # (reference per-GPU process table, ``gpu_manager.py:174-184``)
@@ -695,6 +727,7 @@ class TrainingJob:
 
             # Input pipeline: explicit data_fn > config dataset file > synthetic.
             if self.data_fn is None and self.config.dataset_path:
+                from tpu_engine import native
                 from tpu_engine.data import TokenFileDataset, make_data_fn
 
                 self._dataset = TokenFileDataset(
@@ -704,9 +737,9 @@ class TrainingJob:
                 )
                 self.data_fn = make_data_fn(prog, self._dataset, seed=self.config.seed)
                 log.info(
-                    "job %s: dataset %s (%d sequences, native=%s)",
+                    "job %s: dataset %s (%d sequences, reader: %s)",
                     self.job_id, self.config.dataset_path,
-                    self._dataset.num_sequences, self._dataset.native,
+                    self._dataset.num_sequences, native.status(),
                 )
 
             # Held-out eval source: dedicated file > held-out synthetic seeds.
@@ -1504,6 +1537,9 @@ class TrainingJob:
             "error": self.error,
             "model_name": self.config.model_name,
             "sharding_stage": int(self.config.sharding_stage),
+            # What the compiled program holds (None until it is built).
+            "attention_impl": self.attention_impl,
+            "comm_flags": self.comm_flags,
             "max_steps": self.max_steps,
             "current_step": self.current_step,
             "rollback_count": self.rollback_count,

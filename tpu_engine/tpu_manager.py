@@ -137,15 +137,27 @@ class TPUDevice(BaseModel):
         return max(self.hbm_total_gb - self.hbm_used_gb, 0.0)
 
     @property
-    def is_available(self) -> bool:
-        """Schedulable: <80% HBM used, duty cycle <90% (if known), not critical.
+    def carries_own_load(self) -> bool:
+        """This control plane's supervised jobs hold the chip (live
+        snapshots only). Their HBM footprint and duty cycle are what a chip
+        looks like BECAUSE a job runs on it — load, not a fault and not a
+        stranger's: the scheduler's reservation ledger and headroom gate
+        account for it."""
+        return bool(self.jobs)
 
-        Same semantics as reference ``GPUDevice.is_available``
+    @property
+    def is_available(self) -> bool:
+        """Schedulable: not critical, and — unless the load is the control
+        plane's own — <80% HBM used and duty cycle <90% (if known).
+
+        Same thresholds as reference ``GPUDevice.is_available``
         (``gpu_manager.py:57-62`` — the code, not its stale docstring; see
-        SURVEY.md §5 quirks).
+        SURVEY.md §5 quirks) for foreign load and injected snapshots.
         """
         if self.health_status == TPUHealthStatus.CRITICAL:
             return False
+        if self.carries_own_load:
+            return True
         if self.hbm_utilization_pct >= 80.0:
             return False
         if self.duty_cycle_pct is not None and self.duty_cycle_pct >= 90.0:
@@ -240,7 +252,6 @@ class TPUManager:
             hbm_used_gb=round(hbm_used, 3),
             hbm_utilization_pct=round(util, 2),
         )
-        self._assess_health(dev)
         return dev
 
     def parse_metrics(self, metrics: Sequence[dict[str, Any]]) -> list[TPUDevice]:
@@ -342,7 +353,12 @@ class TPUManager:
                 alerts.append(f"WARNING: temperature {dev.temperature_c:.0f}C >= {self.TEMP_WARNING_C:.0f}C")
                 status = TPUHealthStatus.WARNING
 
-        if dev.hbm_total_gb > 0:
+        # HBM and duty thresholds judge load nobody here placed (foreign
+        # processes, injected snapshots). A chip running this control
+        # plane's own jobs is full and busy by design — classifying that
+        # as WARNING/CRITICAL made a full-width job evict itself.
+        own_load = dev.carries_own_load
+        if dev.hbm_total_gb > 0 and not own_load:
             if dev.hbm_utilization_pct >= self.HBM_CRITICAL_PCT:
                 alerts.append(f"CRITICAL: HBM {dev.hbm_utilization_pct:.1f}% >= {self.HBM_CRITICAL_PCT:.0f}%")
                 status = TPUHealthStatus.CRITICAL
@@ -351,7 +367,11 @@ class TPUManager:
                 if status != TPUHealthStatus.CRITICAL:
                     status = TPUHealthStatus.WARNING
 
-        if dev.duty_cycle_pct is not None and dev.duty_cycle_pct >= self.DUTY_WARNING_PCT:
+        if (
+            not own_load
+            and dev.duty_cycle_pct is not None
+            and dev.duty_cycle_pct >= self.DUTY_WARNING_PCT
+        ):
             alerts.append(f"WARNING: duty cycle {dev.duty_cycle_pct:.1f}% >= {self.DUTY_WARNING_PCT:.0f}%")
             if status == TPUHealthStatus.HEALTHY:
                 status = TPUHealthStatus.WARNING
@@ -450,13 +470,25 @@ class TPUManager:
                 return TPUFleetStatus(
                     fleet_alerts=[f"TPU runtime unavailable: {type(e).__name__}: {e}"]
                 )
-            # Live path: lay the telemetry-source overlay (libtpu SDK
-            # monitoring, engine-derived duty cycle — tpu_engine.telemetry)
-            # over the runtime's memory_stats view, then re-classify health
-            # with the merged fields. This is what makes duty/throttle
-            # alerts fire in production, not just on injected snapshots.
+            # Live path. Per-chip job attribution first: lay the
+            # supervised-job claims (tpu_engine.telemetry
+            # .register_job_devices) over the device table, matched by
+            # runtime device id — the TPU answer to the reference's per-GPU
+            # process table (``gpu_manager.py:174-184``), and what tells
+            # health classification whose load a chip carries.
             from tpu_engine import telemetry
 
+            attribution = telemetry.job_attribution()
+            if attribution:
+                for dev, d in zip(devices, runtime_devs):
+                    refs = attribution.get(int(getattr(d, "id", dev.index)))
+                    if refs:
+                        dev.jobs = [TPUJobRef(**r) for r in refs]
+
+            # Then the telemetry-source overlay (libtpu SDK monitoring,
+            # engine-derived duty cycle — tpu_engine.telemetry) over the
+            # runtime's memory_stats view. This is what makes duty/throttle
+            # alerts fire in production, not just on injected snapshots.
             overlay = telemetry.sample_overlay(len(devices))
             if overlay is not None:
                 telemetry_sources = overlay.sources
@@ -489,18 +521,10 @@ class TPUManager:
                         dev.processes = [
                             _process_ref(int(extra["holder_pid"]))
                         ]
-                    self._assess_health(dev)
 
-            # Per-chip job attribution: lay the supervised-job claims
-            # (tpu_engine.telemetry.register_job_devices) over the device
-            # table, matched by runtime device id — the TPU answer to the
-            # reference's per-GPU process table (``gpu_manager.py:174-184``).
-            attribution = telemetry.job_attribution()
-            if attribution:
-                for dev, d in zip(devices, runtime_devs):
-                    refs = attribution.get(int(getattr(d, "id", dev.index)))
-                    if refs:
-                        dev.jobs = [TPUJobRef(**r) for r in refs]
+            # Classify once, with the merged fields and the attribution.
+            for dev in devices:
+                self._assess_health(dev)
 
         # Fault-injection overlay (tpu_engine.faults): applied to EVERY
         # snapshot path — injected, mock, and live — so the chaos harness

@@ -343,6 +343,24 @@ class TrainProgram:
         return jax.device_put(host, self.batch_sharding)
 
 
+def _flash_obstacle(
+    seq_len: int, model_cfg: tfm.ModelConfig, model_axis: int
+) -> Optional[str]:
+    """Why the Pallas flash kernel cannot run this job (None = it can)."""
+    from tpu_engine.ops._flash_pallas import tiling_obstacle
+
+    obstacle = tiling_obstacle(seq_len)
+    if obstacle is not None:
+        return obstacle
+    if model_cfg.n_heads % model_axis or model_cfg.n_kv_heads % model_axis:
+        return (
+            f"{model_cfg.n_heads} q heads / {model_cfg.n_kv_heads} kv heads do "
+            f"not divide the 'model' mesh axis ({model_axis}) — sharding them "
+            "unevenly would change the per-shard GQA ratio"
+        )
+    return None
+
+
 def build_train_program(
     cfg: TPUTrainConfig,
     model_cfg: Optional[tfm.ModelConfig] = None,
@@ -361,18 +379,29 @@ def build_train_program(
     if runtime is None:
         runtime = MeshRuntime(cfg.mesh)
     mesh = runtime.mesh
-    # Attention implementation resolution:
+    # Attention implementation, resolved ONCE, here, from facts known now
+    # (platform of the mesh's devices, seq_len, heads vs the model axis).
+    # What the plan and describe() report is what the compiled step holds:
     # - a >1 'sequence' axis forces sequence-parallel attention (GSPMD alone
     #   would all-gather the sequence dim): ring by default, or the
     #   all-to-all Ulysses formulation when requested explicitly;
-    # - "auto" → the Pallas flash kernel on TPU, XLA elsewhere;
-    # - explicit "xla" / "flash" / "ring" / "ulysses" is honoured.
+    # - "auto" → the Pallas flash kernel on a TPU mesh for shapes it can
+    #   run, XLA attention otherwise;
+    # - explicit "xla" / "flash" / "ring" / "ulysses" is honoured, and an
+    #   explicit "flash" the kernel cannot run is an error, never XLA.
+    on_tpu = mesh.devices.flat[0].platform == "tpu"
     if runtime.axis_sizes["sequence"] > 1:
         impl = "ulysses" if cfg.attention_impl == "ulysses" else "ring"
-    elif cfg.attention_impl == "auto":
-        impl = "flash" if mesh.devices.flat[0].platform == "tpu" else "xla"
     else:
-        impl = cfg.attention_impl
+        obstacle = _flash_obstacle(
+            cfg.seq_len, model_cfg, runtime.axis_sizes["model"]
+        )
+        if cfg.attention_impl == "auto":
+            impl = "flash" if on_tpu and obstacle is None else "xla"
+        else:
+            impl = cfg.attention_impl
+        if impl == "flash" and obstacle is not None:
+            raise ValueError(f"attention_impl='flash' cannot run: {obstacle}")
     # Flash under pipeline parallelism: the stage vmap runs with
     # spmd_axis_name="pipe" (tpu_engine/parallel/pipeline.py), whose
     # shard_map batching rule threads the pipe axis into the kernel's
@@ -442,14 +471,11 @@ def build_train_program(
             "moe_impl='dense' on meshes with a model axis"
         )
     # Mesh is threaded into the forward pass for sequence-parallel attention
-    # (shard_map over the 'sequence' axis) and for the flash kernel on
-    # multi-device meshes (Mosaic calls cannot be GSPMD-partitioned — the
-    # kernel runs under shard_map, see transformer._attention).
-    attn_mesh = (
-        mesh
-        if impl in ("ring", "ulysses") or (impl == "flash" and mesh.size > 1)
-        else None
-    )
+    # (shard_map over the 'sequence' axis) and for the flash kernel: its
+    # devices decide compiled vs interpret mode, and on multi-device meshes
+    # the kernel runs under shard_map (Mosaic calls cannot be
+    # GSPMD-partitioned — see transformer._attention).
+    attn_mesh = mesh if impl in ("ring", "ulysses", "flash") else None
     seq_size = runtime.axis_sizes["sequence"]
     if impl == "ulysses":
         local_heads = model_cfg.n_heads // runtime.axis_sizes["model"]
@@ -671,10 +697,15 @@ def build_train_program(
         else:
             base_params = jax.device_put(base_params, full_param_sh)
 
-    # Optimizer-state offload: pinned host memory when the backend supports it
-    # (reference CPU offload, ``deepspeed_launcher.py:197-203``).
+    # Optimizer-state offload: pinned host memory (reference CPU offload,
+    # ``deepspeed_launcher.py:197-203``).
     opt_memory_kind = None
-    if cfg.optimizer_offload == OffloadDevice.HOST and host_memory_kind_available(mesh):
+    if cfg.optimizer_offload == OffloadDevice.HOST:
+        if not host_memory_kind_available(mesh):
+            raise ValueError(
+                "optimizer_offload=host requires a backend with pinned_host "
+                "memory support (TPU, or the JAX CPU backend)"
+            )
         opt_memory_kind = "pinned_host"
     opt_leaf_sh = named_shardings(mesh, o_pspecs, memory_kind=opt_memory_kind)
     grad_sh = named_shardings(mesh, g_pspecs)
@@ -1146,7 +1177,6 @@ def build_train_program(
     # re-placed on host with a device_put *outside* jit. Semantically
     # identical; the CPU path exists so the 8-virtual-device test mesh can
     # exercise offloaded configs at all.
-    on_tpu = mesh.devices.flat[0].platform == "tpu"
     if has_host_kinds and not on_tpu:
         _jit_step = jax.jit(
             train_step,
