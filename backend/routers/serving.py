@@ -395,7 +395,9 @@ async def result(request: web.Request) -> web.Response:
 
 async def stats(request: web.Request) -> web.Response:
     srv = _require_server()
-    return json_response(await asyncio.to_thread(srv.stats))
+    doc = await asyncio.to_thread(srv.stats)
+    doc["profile"] = await asyncio.to_thread(srv.profile)  # the engine loop's phase clock
+    return json_response(doc)
 
 
 @pathparams({"request_id": "integer"})

@@ -367,7 +367,8 @@ def test_profile_job_routes(client):
     assert d["status"] == "completed"
     prof = client.get(f"/api/v1/profile/jobs/{job_id}").json()["profile"]
     assert prof["steps_seen"] == 3
-    assert set(prof["phases"]) == {"data", "dispatch", "device", "other"}
+    assert set(prof["phases"]) == {"data", "dispatch", "device", "health", "anomaly",
+                                   "monitor", "checkpoint", "other"}
     assert d["profile"]["steps_seen"] == 3  # also embedded in job describe()
 
 
@@ -803,6 +804,7 @@ def test_serving_lifecycle_over_http(client):
         assert len(body["tokens"]) == 4
         st = client.get("/api/v1/serving/stats").json()
         assert st["tokens_generated"] >= 4
+        assert st["profile"]["phases"]["emit"]["mean_ms"] > 0  # the engine loop's phase clock
         assert client.get("/api/v1/serving/result/9999").status_code == 404
     finally:
         assert client.post("/api/v1/serving/stop").json()["stopped"]

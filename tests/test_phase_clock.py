@@ -1,0 +1,161 @@
+"""The phase clock where it runs: a real ``TrainingJob``'s loop, a real
+``ContinuousBatcher``'s ``step``, and a fleet request's lifecycle spans.
+(The clock itself is in ``test_profiler.py``.)"""
+
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpu_engine import tracing
+from tpu_engine.models import transformer as tfm
+from tpu_engine.serving import BATCHER_PHASES, ContinuousBatcher
+from tpu_engine.serving_fleet import REQUEST_STAGES, build_replica_engine
+from tpu_engine.supervisor import SUPERVISOR_PHASES, JobStatus, TrainingJob
+
+from tests.test_serving_fleet import (  # noqa: F401 — sched_factory is a fixture
+    make_fleet,
+    mock_fleet_fn,
+    sched_factory,
+    small_spec,
+    wait_until,
+)
+from tests.test_tracing import tiny_config
+
+
+def test_training_job_profile_covers_the_whole_iteration(tmp_path):
+    """Every supervisor phase is reported, and ``total`` is the time between
+    two ``data_fn`` calls — not the part of it up to the metric read."""
+    calls = []
+    cfg = tiny_config(tmp_path / "ckpt", total_steps=8, checkpoint_interval_steps=4)
+
+    def data_fn(step):
+        calls.append(time.perf_counter())
+        time.sleep(0.01)  # a data phase that can be told from nothing
+        return job.program.synthetic_batch(step)
+
+    job = TrainingJob("phase-job", cfg, data_fn=data_fn)
+    job.start()
+    job.join(timeout=300)
+    assert job.status == JobStatus.COMPLETED, job.error
+    prof = job.describe()["profile"]
+    assert prof["steps_seen"] == 8
+    assert set(prof["phases"]) == set(SUPERVISOR_PHASES) | {"other"}
+    assert prof["phases"]["data"]["p50_ms"] >= 10
+    assert prof["phases"]["device"]["mean_ms"] > 0
+    # A save ran at steps 4 and 8: the checkpoint phase saw it.
+    assert prof["phases"]["checkpoint"]["mean_ms"] > 0
+    # Between the first and the last data_fn call lie seven whole iterations;
+    # the eighth ends where the loop leaves its body.
+    intervals = np.diff(calls)
+    assert len(intervals) == 7
+    totals = list(job.profiler._totals)
+    assert len(totals) == 8
+    assert totals[:7] == pytest.approx(list(intervals), abs=2e-3)
+    assert prof["total"]["mean_ms"] == pytest.approx(np.mean(totals) * 1e3)
+    # The phases account for the total: the remainder is small and has a name.
+    fractions = sum(p["fraction"] for p in prof["phases"].values())
+    assert fractions == pytest.approx(1.0, abs=0.02)
+    assert prof["phases"]["other"]["fraction"] < 0.2
+    # What the operator reads is a whole-iteration time too.
+    assert job.last_step_time_s >= 0.01
+    # The attempt span's step_s (goodput's cap on productive time) counts
+    # every iteration once: the wall time from the first data_fn call to the
+    # last, plus the last iteration.
+    (attempt,) = [sp for sp in tracing.get_recorder().spans(trace_id=job.trace_id, limit=0)
+                  if sp["kind"] == "attempt"]
+    assert attempt["attrs"]["step_s"] == pytest.approx(sum(totals), abs=1e-5)
+    assert attempt["attrs"]["step_s"] == pytest.approx(
+        calls[-1] - calls[0] + totals[-1], abs=5e-3)
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    cfg = tfm.MODEL_CONFIGS["gpt-tiny"]
+    return cfg, tfm.init_params(jax.random.PRNGKey(3), cfg, dtype=jnp.float32)
+
+
+def test_batcher_stats_show_phases_counters_and_ordered_stamps(tiny_model):
+    cfg, params = tiny_model
+    srv = ContinuousBatcher(params, cfg, max_slots=2, max_len=96, chunk_steps=4,
+                            compute_dtype=jnp.float32, prefill_pad_to=16,
+                            prefill_chunk=16)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(1, cfg.vocab_size, n).tolist() for n in (7, 40, 3)]
+    rids = [srv.submit(p, max_new_tokens=n) for p, n in zip(prompts, (6, 9, 5))]
+    for _ in range(60):
+        if all(srv.result(r)["status"] == "done" for r in rids):
+            break
+        srv.step()
+    st = srv.stats()
+    assert "profile" not in st  # the router's per-request read stays counters
+    prof = srv.profile()
+    assert set(prof["phases"]) == set(BATCHER_PHASES) | {"other"}
+    for phase in ("admit", "prefill", "first_token", "stage", "device", "emit"):
+        assert prof["phases"][phase]["mean_ms"] > 0, phase
+    assert prof["phases"]["idle"]["mean_ms"] == 0  # step() was driven by hand
+    # Every first token comes from the prefill logits, the rest from decode
+    # dispatches that compute chunk_steps tokens a slot and throw the
+    # overshoot away.
+    emitted = st["decode_tokens_emitted_total"]
+    assert emitted == st["tokens_generated"] - len(rids) == 5 + 8 + 4
+    assert emitted < st["decode_tokens_computed_total"]
+    assert st["decode_tokens_computed_total"] % srv.chunk_steps == 0
+    for rid in rids:
+        out = srv.result(rid)
+        stamps = [out[k] for k in ("submitted_at", "admitted_at", "prefill_started_at",
+                                   "first_token_at", "finished_at")]
+        assert stamps == sorted(stamps), out
+    # The third request waited for a slot: its queue time is real.
+    third = srv.result(rids[2])
+    assert third["admitted_at"] > srv.result(rids[0])["first_token_at"]
+
+
+def test_serve_forever_puts_its_sleep_in_the_idle_phase(tiny_model):
+    cfg, params = tiny_model
+    srv = ContinuousBatcher(params, cfg, max_slots=1, max_len=32,
+                            compute_dtype=jnp.float32, prefill_pad_to=16)
+    stop = threading.Event()
+    t = threading.Thread(target=srv.serve_forever, args=(stop,), kwargs={"idle_sleep": 0.005})
+    t.start()
+    try:
+        assert wait_until(lambda: srv.profile()["steps_seen"] >= 5, timeout=30)
+    finally:
+        stop.set()
+        t.join(timeout=30)
+    idle = srv.profile()["phases"]["idle"]
+    assert idle["p50_ms"] >= 5 and idle["fraction"] > 0.5
+
+
+def test_fleet_request_trace_holds_its_four_stages(sched_factory):
+    """Closing a request's span records engine_queue, prefill_wait, prefill
+    and decode under it, contiguous, from the engine's own stamps."""
+    s = sched_factory(max_concurrent_jobs=2, fleet_fn=mock_fleet_fn)
+    spec = small_spec(max_slots=2, max_len=64, prefill_chunk=16)
+    fleet = make_fleet(s, spec=spec, engine_factory=build_replica_engine)
+    fleet.scale_to(1)
+    assert wait_until(lambda: len(fleet.running_replicas()) == 1, timeout=120)
+    fid = fleet.submit_request(list(range(1, 21)), max_new_tokens=6)
+    assert wait_until(lambda: fleet.result(fid)["status"] == "done", timeout=120)
+    out = fleet.result(fid)
+    rec = tracing.get_recorder()
+    spans = rec.spans(trace_id=out["trace_id"], limit=0)
+    (root,) = [sp for sp in spans if sp["parent_id"] is None]
+    names = [n for n, _, _ in REQUEST_STAGES]
+    stages = {sp["name"]: sp for sp in spans
+              if sp["parent_id"] == root["span_id"] and sp["name"] in names}
+    assert set(stages) == set(names)
+    order = [stages[n] for n in names]
+    assert order[0]["t0"] >= root["t0"] and order[-1]["t1"] <= root["t1"]
+    for before, after in zip(order, order[1:]):
+        assert before["t1"] == after["t0"]  # contiguous: one stamp ends one, begins the next
+    for sp in order:
+        assert sp["duration_s"] >= 0
+        assert sp["attrs"]["prompt_tokens"] == 20 and sp["attrs"]["tokens"] == 6
+        assert sp["attrs"]["replica"] == out["replica"]
+        assert sp["attrs"]["engine_rid"] is not None
+    assert order[0]["t0"] == out["submitted_at"] and order[2]["t1"] == out["first_token_at"]
+    fleet.stop()

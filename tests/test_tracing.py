@@ -371,20 +371,3 @@ def test_chaos_benchmark_writes_perfetto_trace(tmp_path, monkeypatch, capsys):
     assert any(e["ph"] == "s" for e in doc["traceEvents"])
     summary = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert summary["ok"]
-
-
-def test_trace_breakdown_capture_writes_perfetto_trace(tmp_path):
-    from benchmarks.trace_breakdown import capture
-
-    rec = FlightRecorder()
-    wall, xplane = capture(
-        logdir=str(tmp_path / "xplane"), steps=1, model="gpt-tiny",
-        micro=1, seq=64, mesh_axes={"data": 8}, recorder=rec,
-    )
-    assert wall > 0
-    out = str(tmp_path / "tb_trace.json")
-    with open(out, "w") as f:
-        json.dump(rec.export_chrome_trace(), f)
-    doc = _assert_perfetto_loadable(out)
-    names = {e.get("name") for e in doc["traceEvents"]}
-    assert {"compile", "warmup", "profile_capture"} <= names

@@ -138,11 +138,12 @@ def _moe_mlp_decode(h, layer_params, cfg: ModelConfig):
     [D, F] matmuls and keeps every shape static.
     """
     E, K = cfg.n_experts, cfg.top_k
-    router_logits = jnp.einsum(
-        "btd,de->bte", h, layer_params["router"]["kernel"],
-        preferred_element_type=jnp.float32,
-    )
-    probs = jax.nn.softmax(router_logits, axis=-1)  # [B, T, E] fp32
+    with jax.named_scope("moe_router"):
+        router_logits = jnp.einsum(
+            "btd,de->bte", h, layer_params["router"]["kernel"],
+            preferred_element_type=jnp.float32,
+        )
+        probs = jax.nn.softmax(router_logits, axis=-1)  # [B, T, E] fp32
 
     def kern(name):
         # Expert kernels may be int8 QuantWeights (weight-only quantized
@@ -156,21 +157,24 @@ def _moe_mlp_decode(h, layer_params, cfg: ModelConfig):
             return dequantize_weight(w, h.dtype)
         return w
 
-    gate = jnp.einsum("btd,edf->btef", h, kern("gate"))
-    up = jnp.einsum("btd,edf->btef", h, kern("up"))
-    expert_out = jnp.einsum(
-        "btef,efd->bted", jax.nn.silu(gate) * up, kern("down")
-    )  # [B, T, E, D]
+    with jax.named_scope("moe_experts"):
+        gate = jnp.einsum("btd,edf->btef", h, kern("gate"))
+        up = jnp.einsum("btd,edf->btef", h, kern("up"))
+        expert_out = jnp.einsum(
+            "btef,efd->bted", jax.nn.silu(gate) * up, kern("down")
+        )  # [B, T, E, D]
 
-    # Top-k gates, renormalised to sum to 1 (matches training's combine).
-    top_vals, top_idx = lax.top_k(probs, K)  # [B, T, K]
-    top_vals = top_vals / jnp.maximum(jnp.sum(top_vals, -1, keepdims=True), 1e-9)
-    weights = jnp.zeros_like(probs).at[
-        jnp.arange(probs.shape[0])[:, None, None],
-        jnp.arange(probs.shape[1])[None, :, None],
-        top_idx,
-    ].set(top_vals)  # [B, T, E]
-    return jnp.einsum("bte,bted->btd", weights.astype(h.dtype), expert_out)
+    with jax.named_scope("moe_router"):
+        # Top-k gates, renormalised to sum to 1 (matches training's combine).
+        top_vals, top_idx = lax.top_k(probs, K)  # [B, T, K]
+        top_vals = top_vals / jnp.maximum(jnp.sum(top_vals, -1, keepdims=True), 1e-9)
+        weights = jnp.zeros_like(probs).at[
+            jnp.arange(probs.shape[0])[:, None, None],
+            jnp.arange(probs.shape[1])[None, :, None],
+            top_idx,
+        ].set(top_vals)  # [B, T, E]
+    with jax.named_scope("moe_experts"):
+        return jnp.einsum("bte,bted->btd", weights.astype(h.dtype), expert_out)
 
 
 def _quantize_rows(rows: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -206,58 +210,68 @@ def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
         return _proj(h, layer_params[name]["kernel"],
                      bias=layer_params[name]["bias"] if gpt2 else None)
 
-    h = _norm(x, layer_params["attn_norm"], cfg)
-    q = proj(h, "q").reshape(B, T, H, HD)
-    k = proj(h, "k").reshape(B, T, KV, HD)
-    v = proj(h, "v").reshape(B, T, KV, HD)
-    if cfg.arch == "qwen":  # per-head qk-norm, before RoPE (as in training)
-        q = _rms_norm(q, layer_params["q_norm"]["scale"], cfg.norm_eps)
-        k = _rms_norm(k, layer_params["k_norm"]["scale"], cfg.norm_eps)
-    if not gpt2:  # gpt2 adds learned positions at embed time instead
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+    # named_scope is metadata only: the names a profile groups device ops by
+    # (attn > kv_write / decode_attn, then mlp or moe_router / moe_experts).
+    with jax.named_scope("attn"):
+        h = _norm(x, layer_params["attn_norm"], cfg)
+        q = proj(h, "q").reshape(B, T, H, HD)
+        k = proj(h, "k").reshape(B, T, KV, HD)
+        v = proj(h, "v").reshape(B, T, KV, HD)
+        if cfg.arch == "qwen":  # per-head qk-norm, before RoPE (as in training)
+            q = _rms_norm(q, layer_params["q_norm"]["scale"], cfg.norm_eps)
+            k = _rms_norm(k, layer_params["k_norm"]["scale"], cfg.norm_eps)
+        if not gpt2:  # gpt2 adds learned positions at embed time instead
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
 
-    if k_scale_c is not None:
-        k_codes, k_s = _quantize_rows(k)
-        v_codes, v_s = _quantize_rows(v)
-        k_cache = write(k_cache, k_codes)
-        v_cache = write(v_cache, v_codes)
-        k_scale_c = write(k_scale_c, k_s)
-        v_scale_c = write(v_scale_c, v_s)
-        kc = k_cache.astype(x.dtype) * k_scale_c.astype(x.dtype)
-        vc = v_cache.astype(x.dtype) * v_scale_c.astype(x.dtype)
-    else:
-        k_cache = write(k_cache, k)
-        v_cache = write(v_cache, v)
-        kc, vc = k_cache, v_cache
-    if KV != H:  # GQA
-        kc = jnp.repeat(kc, H // KV, axis=2)
-        vc = jnp.repeat(vc, H // KV, axis=2)
+        with jax.named_scope("kv_write"):
+            if k_scale_c is not None:
+                k_codes, k_s = _quantize_rows(k)
+                v_codes, v_s = _quantize_rows(v)
+                k_cache = write(k_cache, k_codes)
+                v_cache = write(v_cache, v_codes)
+                k_scale_c = write(k_scale_c, k_s)
+                v_scale_c = write(v_scale_c, v_s)
+            else:
+                k_cache = write(k_cache, k)
+                v_cache = write(v_cache, v)
 
-    scale = 1.0 / (HD ** 0.5)
-    scores = jnp.einsum(
-        "bthd,bmhd->bhtm", q, kc, preferred_element_type=jnp.float32
-    ) * scale
-    # Slot m is visible to query t iff it holds a real position (≥ 0) that
-    # is ≤ the query's global position (causal). Sliding-window models
-    # additionally hide keys older than the window, matching the
-    # training-time mask; ring-buffer slots overwritten by in-chunk later
-    # positions are masked for earlier queries by the same comparison.
-    key_pos = slot_pos if slot_pos.ndim == 2 else slot_pos[None, :]  # [B|1, M]
-    kp = key_pos[:, None, :]                                         # [B|1, 1, M]
-    mask = (kp >= 0) & (kp <= positions[:, :, None])
-    if cfg.sliding_window:
-        mask &= kp > positions[:, :, None] - cfg.sliding_window
-    scores = jnp.where(mask[:, None, :, :], scores, _NEG_INF)
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(x.dtype)
-    attn = jnp.einsum("bhtm,bmhd->bthd", probs, vc).reshape(B, T, H * HD)
-    x = x + proj(attn, "o")
+        with jax.named_scope("decode_attn"):
+            if k_scale_c is not None:
+                kc = k_cache.astype(x.dtype) * k_scale_c.astype(x.dtype)
+                vc = v_cache.astype(x.dtype) * v_scale_c.astype(x.dtype)
+            else:
+                kc, vc = k_cache, v_cache
+            if KV != H:  # GQA
+                kc = jnp.repeat(kc, H // KV, axis=2)
+                vc = jnp.repeat(vc, H // KV, axis=2)
+
+            scale = 1.0 / (HD ** 0.5)
+            scores = jnp.einsum(
+                "bthd,bmhd->bhtm", q, kc, preferred_element_type=jnp.float32
+            ) * scale
+            # Slot m is visible to query t iff it holds a real position (≥ 0)
+            # that is ≤ the query's global position (causal). Sliding-window
+            # models additionally hide keys older than the window, matching
+            # the training-time mask; ring-buffer slots overwritten by
+            # in-chunk later positions are masked for earlier queries by the
+            # same comparison.
+            key_pos = slot_pos if slot_pos.ndim == 2 else slot_pos[None, :]  # [B|1, M]
+            kp = key_pos[:, None, :]                                         # [B|1, 1, M]
+            mask = (kp >= 0) & (kp <= positions[:, :, None])
+            if cfg.sliding_window:
+                mask &= kp > positions[:, :, None] - cfg.sliding_window
+            scores = jnp.where(mask[:, None, :, :], scores, _NEG_INF)
+            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(x.dtype)
+            attn = jnp.einsum("bhtm,bmhd->bthd", probs, vc).reshape(B, T, H * HD)
+        x = x + proj(attn, "o")
 
     h = _norm(x, layer_params["mlp_norm"], cfg)
     if cfg.is_moe:
         x = x + _moe_mlp_decode(h, layer_params, cfg)
     else:
-        x = x + _dense_mlp(h, layer_params, cfg=cfg)
+        with jax.named_scope("mlp"):
+            x = x + _dense_mlp(h, layer_params, cfg=cfg)
     return x, k_cache, v_cache, k_scale_c, v_scale_c
 
 
@@ -479,11 +493,12 @@ def _generate_jit(
     B, P = prompt.shape
 
     def sample(logits, key):
-        if greedy:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return _filtered_sample(
-            logits, key, temperature, top_k, top_p if use_top_p else None
-        )
+        with jax.named_scope("sample"):
+            if greedy:
+                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return _filtered_sample(
+                logits, key, temperature, top_k, top_p if use_top_p else None
+            )
 
     keys = jax.random.split(rng, max_new_tokens)  # one fresh key per draw
     cache = init_cache(cfg, B, P + max_new_tokens, dtype=compute_dtype,

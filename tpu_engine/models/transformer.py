@@ -537,20 +537,21 @@ def _moe_mlp_ragged(h, layer_params, cfg: ModelConfig):
     BS = B * S
     x = h.reshape(BS, D)
 
-    router_logits = jnp.einsum(
-        "td,de->te", x, layer_params["router"]["kernel"],
-        preferred_element_type=jnp.float32,
-    )
-    probs = jax.nn.softmax(router_logits, axis=-1)       # [BS, E] fp32
-    gate_vals, expert_idx = lax.top_k(probs, K)          # [BS, K]
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+    with jax.named_scope("moe_router"):
+        router_logits = jnp.einsum(
+            "td,de->te", x, layer_params["router"]["kernel"],
+            preferred_element_type=jnp.float32,
+        )
+        probs = jax.nn.softmax(router_logits, axis=-1)       # [BS, E] fp32
+        gate_vals, expert_idx = lax.top_k(probs, K)          # [BS, K]
+        gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
 
-    flat_expert = expert_idx.reshape(-1)                 # [BS*K]
-    order = jnp.argsort(flat_expert)                     # stable
-    tok = jnp.arange(BS * K, dtype=jnp.int32) // K       # slot → token
-    tok_sorted = tok[order]
-    xs = jnp.take(x, tok_sorted, axis=0)                 # [BS*K, D] gather
-    group_sizes = jnp.bincount(flat_expert, length=E).astype(jnp.int32)
+        flat_expert = expert_idx.reshape(-1)                 # [BS*K]
+        order = jnp.argsort(flat_expert)                     # stable
+        tok = jnp.arange(BS * K, dtype=jnp.int32) // K       # slot → token
+        tok_sorted = tok[order]
+        xs = jnp.take(x, tok_sorted, axis=0)                 # [BS*K, D] gather
+        group_sizes = jnp.bincount(flat_expert, length=E).astype(jnp.int32)
 
     def kern(name):
         w = layer_params[name]["kernel"]
@@ -558,17 +559,18 @@ def _moe_mlp_ragged(h, layer_params, cfg: ModelConfig):
             return dequantize_weight(w, h.dtype)
         return w
 
-    g = lax.ragged_dot(xs, kern("gate"), group_sizes,
-                       preferred_element_type=h.dtype)
-    u = lax.ragged_dot(xs, kern("up"), group_sizes,
-                       preferred_element_type=h.dtype)
-    y = lax.ragged_dot(jax.nn.silu(g) * u, kern("down"), group_sizes,
-                       preferred_element_type=h.dtype)   # [BS*K, D]
-    w_sorted = gate_vals.reshape(-1)[order].astype(h.dtype)
-    out = jax.ops.segment_sum(
-        y * w_sorted[:, None], tok_sorted, num_segments=BS
-    )
-    out = out.reshape(B, S, D)
+    with jax.named_scope("moe_experts"):
+        g = lax.ragged_dot(xs, kern("gate"), group_sizes,
+                           preferred_element_type=h.dtype)
+        u = lax.ragged_dot(xs, kern("up"), group_sizes,
+                           preferred_element_type=h.dtype)
+        y = lax.ragged_dot(jax.nn.silu(g) * u, kern("down"), group_sizes,
+                           preferred_element_type=h.dtype)   # [BS*K, D]
+        w_sorted = gate_vals.reshape(-1)[order].astype(h.dtype)
+        out = jax.ops.segment_sum(
+            y * w_sorted[:, None], tok_sorted, num_segments=BS
+        )
+        out = out.reshape(B, S, D)
 
     # Same load-balancing aux loss as the dense path (Switch eq. 4).
     first_choice = jax.nn.one_hot(expert_idx[:, 0], E, dtype=jnp.float32)
@@ -592,35 +594,36 @@ def _moe_mlp(h, layer_params, cfg: ModelConfig):
     E, K = cfg.n_experts, cfg.top_k
     C = cfg.expert_capacity(S)
 
-    router_logits = jnp.einsum(
-        "bsd,de->bse", h, layer_params["router"]["kernel"],
-        preferred_element_type=jnp.float32,
-    )
-    probs = jax.nn.softmax(router_logits, axis=-1)  # [B, S, E] fp32
+    with jax.named_scope("moe_router"):
+        router_logits = jnp.einsum(
+            "bsd,de->bse", h, layer_params["router"]["kernel"],
+            preferred_element_type=jnp.float32,
+        )
+        probs = jax.nn.softmax(router_logits, axis=-1)  # [B, S, E] fp32
 
-    # Greedy top-k assignment with per-expert capacity, one k at a time so
-    # first choices claim capacity before second choices.
-    remaining = probs
-    count_so_far = jnp.zeros((B, E), jnp.float32)  # tokens already accepted
-    combine = jnp.zeros((B, S, E, C), h.dtype)
-    for _ in range(K):
-        idx = jnp.argmax(remaining, axis=-1)                      # [B, S]
-        mask = jax.nn.one_hot(idx, E, dtype=jnp.float32)          # [B, S, E]
-        gate_val = jnp.sum(probs * mask, axis=-1)                 # [B, S]
-        # Position each token takes inside its expert's capacity buffer.
-        pos = jnp.cumsum(mask, axis=1) - 1 + count_so_far[:, None, :]
-        pos_tok = jnp.sum(pos * mask, axis=-1)                    # [B, S]
-        keep = (pos_tok < C) & (gate_val > 0)
-        count_so_far = count_so_far + jnp.sum(mask, axis=1)
-        onehot_pos = jax.nn.one_hot(pos_tok.astype(jnp.int32), C, dtype=jnp.float32)  # [B, S, C]
-        contrib = (gate_val * keep)[:, :, None, None] * mask[:, :, :, None] * onehot_pos[:, :, None, :]
-        combine = combine + contrib.astype(h.dtype)
-        remaining = remaining * (1.0 - mask)  # exclude chosen expert for next k
+        # Greedy top-k assignment with per-expert capacity, one k at a time so
+        # first choices claim capacity before second choices.
+        remaining = probs
+        count_so_far = jnp.zeros((B, E), jnp.float32)  # tokens already accepted
+        combine = jnp.zeros((B, S, E, C), h.dtype)
+        for _ in range(K):
+            idx = jnp.argmax(remaining, axis=-1)                      # [B, S]
+            mask = jax.nn.one_hot(idx, E, dtype=jnp.float32)          # [B, S, E]
+            gate_val = jnp.sum(probs * mask, axis=-1)                 # [B, S]
+            # Position each token takes inside its expert's capacity buffer.
+            pos = jnp.cumsum(mask, axis=1) - 1 + count_so_far[:, None, :]
+            pos_tok = jnp.sum(pos * mask, axis=-1)                    # [B, S]
+            keep = (pos_tok < C) & (gate_val > 0)
+            count_so_far = count_so_far + jnp.sum(mask, axis=1)
+            onehot_pos = jax.nn.one_hot(pos_tok.astype(jnp.int32), C, dtype=jnp.float32)  # [B, S, C]
+            contrib = (gate_val * keep)[:, :, None, None] * mask[:, :, :, None] * onehot_pos[:, :, None, :]
+            combine = combine + contrib.astype(h.dtype)
+            remaining = remaining * (1.0 - mask)  # exclude chosen expert for next k
 
-    # Renormalise the kept top-k gates to sum to 1 per token.
-    denom = jnp.sum(combine, axis=(2, 3), keepdims=True)
-    combine = combine / jnp.maximum(denom, 1e-9).astype(h.dtype)
-    dispatch = (combine > 0).astype(h.dtype)                      # [B, S, E, C]
+        # Renormalise the kept top-k gates to sum to 1 per token.
+        denom = jnp.sum(combine, axis=(2, 3), keepdims=True)
+        combine = combine / jnp.maximum(denom, 1e-9).astype(h.dtype)
+        dispatch = (combine > 0).astype(h.dtype)                      # [B, S, E, C]
 
     def kern(name):
         # Expert kernels may be int8 QuantWeights (quantized eval /
@@ -635,12 +638,13 @@ def _moe_mlp(h, layer_params, cfg: ModelConfig):
     # router (fp32 softmax input) and the [B,S,E,C] dispatch/combine
     # einsums (0/1 masks and gates — not matmul-heavy per element, and
     # quantization-sensitive) stay full precision.
-    dot = _train_dot(cfg, "moe") or jnp.einsum
-    expert_in = jnp.einsum("bsec,bsd->ebcd", dispatch, h)         # [E, B, C, D]
-    gate = dot("ebcd,edf->ebcf", expert_in, kern("gate"))
-    up = dot("ebcd,edf->ebcf", expert_in, kern("up"))
-    expert_out = dot("ebcf,efd->ebcd", jax.nn.silu(gate) * up, kern("down"))
-    out = jnp.einsum("bsec,ebcd->bsd", combine, expert_out)
+    with jax.named_scope("moe_experts"):
+        dot = _train_dot(cfg, "moe") or jnp.einsum
+        expert_in = jnp.einsum("bsec,bsd->ebcd", dispatch, h)         # [E, B, C, D]
+        gate = dot("ebcd,edf->ebcf", expert_in, kern("gate"))
+        up = dot("ebcd,edf->ebcf", expert_in, kern("up"))
+        expert_out = dot("ebcf,efd->ebcd", jax.nn.silu(gate) * up, kern("down"))
+        out = jnp.einsum("bsec,ebcd->bsd", combine, expert_out)
 
     # Load-balancing auxiliary loss (Switch Transformer eq. 4): fraction of
     # tokens dispatched to each expert × mean router prob, scaled by E.
@@ -740,25 +744,26 @@ def _block(
     gpt2 = cfg.arch == "gpt2"
     bias = (lambda name: layer_params[name]["bias"]) if gpt2 else (lambda name: None)
     dot = _train_dot(cfg, "attn")
-    h = _norm(x, layer_params["attn_norm"], cfg)
-    q = _proj(h, layer_params["q"]["kernel"], lora.get("q"), lora_scale,
-              bias("q"), dot=dot).reshape(B, S, H, HD)
-    k = _proj(h, layer_params["k"]["kernel"], lora.get("k"), lora_scale,
-              bias("k"), dot=dot).reshape(B, S, KV, HD)
-    v = _proj(h, layer_params["v"]["kernel"], lora.get("v"), lora_scale,
-              bias("v"), dot=dot).reshape(B, S, KV, HD)
-    if cfg.arch == "qwen":  # per-head qk-norm, before RoPE
-        q = _rms_norm(q, layer_params["q_norm"]["scale"], cfg.norm_eps)
-        k = _rms_norm(k, layer_params["k_norm"]["scale"], cfg.norm_eps)
-    if not gpt2:  # gpt2 uses learned absolute positions, added at embed time
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-    q, k, v = tag(q, "q"), tag(k, "k"), tag(v, "v")
-    attn = _attention(q, k, v, cfg.attention_impl, mesh=mesh,
-                      window=cfg.sliding_window)
-    attn = tag(attn.reshape(B, S, H * HD), "attn_out")
-    x = x + _proj(attn, layer_params["o"]["kernel"], lora.get("o"), lora_scale,
-                  bias("o"), dot=dot)
+    with jax.named_scope("attn"):
+        h = _norm(x, layer_params["attn_norm"], cfg)
+        q = _proj(h, layer_params["q"]["kernel"], lora.get("q"), lora_scale,
+                  bias("q"), dot=dot).reshape(B, S, H, HD)
+        k = _proj(h, layer_params["k"]["kernel"], lora.get("k"), lora_scale,
+                  bias("k"), dot=dot).reshape(B, S, KV, HD)
+        v = _proj(h, layer_params["v"]["kernel"], lora.get("v"), lora_scale,
+                  bias("v"), dot=dot).reshape(B, S, KV, HD)
+        if cfg.arch == "qwen":  # per-head qk-norm, before RoPE
+            q = _rms_norm(q, layer_params["q_norm"]["scale"], cfg.norm_eps)
+            k = _rms_norm(k, layer_params["k_norm"]["scale"], cfg.norm_eps)
+        if not gpt2:  # gpt2 uses learned absolute positions, added at embed time
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
+        q, k, v = tag(q, "q"), tag(k, "k"), tag(v, "v")
+        attn = _attention(q, k, v, cfg.attention_impl, mesh=mesh,
+                          window=cfg.sliding_window)
+        attn = tag(attn.reshape(B, S, H * HD), "attn_out")
+        x = x + _proj(attn, layer_params["o"]["kernel"], lora.get("o"), lora_scale,
+                      bias("o"), dot=dot)
 
     h = _norm(x, layer_params["mlp_norm"], cfg)
     if cfg.is_moe:
@@ -777,7 +782,9 @@ def _block(
         mlp_out, aux = moe(h, layer_params, cfg)
         x = x + mlp_out
         return x, aux
-    return x + _dense_mlp(h, layer_params, lora, lora_scale, cfg=cfg), jnp.zeros((), jnp.float32)
+    with jax.named_scope("mlp"):
+        x = x + _dense_mlp(h, layer_params, lora, lora_scale, cfg=cfg)
+    return x, jnp.zeros((), jnp.float32)
 
 
 _REMAT_POLICIES = {
@@ -885,34 +892,36 @@ def embed_tokens(params: dict[str, Any], tokens: jax.Array, compute_dtype=jnp.bf
     scale the looked-up embeddings by sqrt(d_model). ``cfg`` is REQUIRED:
     arch-dependent math behind an optional parameter turns a forgotten
     argument into a silently different model."""
-    embed = params["embed"]["embedding"].astype(compute_dtype)
-    x = jnp.take(embed, tokens, axis=0)
-    if cfg.arch == "gemma":
-        x = x * jnp.asarray(cfg.d_model ** 0.5, compute_dtype)
-    if "pos_embed" in params:
-        if positions is None:
-            positions = jnp.arange(tokens.shape[-1], dtype=jnp.int32)
-        wpe = params["pos_embed"]["embedding"].astype(compute_dtype)
-        x = x + jnp.take(wpe, positions, axis=0)
+    with jax.named_scope("embed"):
+        embed = params["embed"]["embedding"].astype(compute_dtype)
+        x = jnp.take(embed, tokens, axis=0)
+        if cfg.arch == "gemma":
+            x = x * jnp.asarray(cfg.d_model ** 0.5, compute_dtype)
+        if "pos_embed" in params:
+            if positions is None:
+                positions = jnp.arange(tokens.shape[-1], dtype=jnp.int32)
+            wpe = params["pos_embed"]["embedding"].astype(compute_dtype)
+            x = x + jnp.take(wpe, positions, axis=0)
     return x
 
 
 def unembed(params: dict[str, Any], x: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Final norm + LM head: activations [..., S, D] → logits [..., S, V]
     fp32. GPT-2-family models tie the head to the token embedding."""
-    x = _norm(x, jax.tree.map(lambda a: a.astype(x.dtype), params["final_norm"]), cfg)
-    head = (params["embed"]["embedding"].T if cfg.arch in ("gpt2", "gemma")
-            else params["lm_head"]["kernel"])
-    if isinstance(head, QuantWeight):
-        logits = jnp.einsum(
-            "...sd,dv->...sv", x, head.q.astype(x.dtype),
+    with jax.named_scope("head"):
+        x = _norm(x, jax.tree.map(lambda a: a.astype(x.dtype), params["final_norm"]), cfg)
+        head = (params["embed"]["embedding"].T if cfg.arch in ("gpt2", "gemma")
+                else params["lm_head"]["kernel"])
+        if isinstance(head, QuantWeight):
+            logits = jnp.einsum(
+                "...sd,dv->...sv", x, head.q.astype(x.dtype),
+                preferred_element_type=jnp.float32,
+            )
+            return logits * head.scale.astype(jnp.float32)
+        return jnp.einsum(
+            "...sd,dv->...sv", x, head.astype(x.dtype),
             preferred_element_type=jnp.float32,
         )
-        return logits * head.scale.astype(jnp.float32)
-    return jnp.einsum(
-        "...sd,dv->...sv", x, head.astype(x.dtype),
-        preferred_element_type=jnp.float32,
-    )
 
 
 def cast_layer_stack(params: dict[str, Any], compute_dtype=jnp.bfloat16) -> dict[str, Any]:
@@ -920,13 +929,14 @@ def cast_layer_stack(params: dict[str, Any], compute_dtype=jnp.bfloat16) -> dict
     :class:`QuantWeight` kernels pass through untouched — their int8
     codes cast at the matmul and their fp32 scales must NOT round to
     bf16 (that would double the quantization error for free)."""
-    return jax.tree.map(
-        lambda a: a if isinstance(a, QuantWeight)
-        else a.astype(compute_dtype) if jnp.issubdtype(a.dtype, jnp.floating)
-        else a,
-        params["layers"],
-        is_leaf=lambda a: isinstance(a, QuantWeight),
-    )
+    with jax.named_scope("cast_weights"):
+        return jax.tree.map(
+            lambda a: a if isinstance(a, QuantWeight)
+            else a.astype(compute_dtype) if jnp.issubdtype(a.dtype, jnp.floating)
+            else a,
+            params["layers"],
+            is_leaf=lambda a: isinstance(a, QuantWeight),
+        )
 
 
 def forward_hidden_and_aux(

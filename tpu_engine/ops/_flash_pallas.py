@@ -248,6 +248,7 @@ def _flash_fwd(q, k, v, block: int, interpret: bool, window: int,
                      window=window, causal=causal)
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",  # the kernel's name in a profile
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block, D), lambda bh, i, j: (bh, i, 0)),
@@ -436,6 +437,7 @@ def _flash_bwd(block: int, interpret: bool, window: int, res, do,
     dq = pl.pallas_call(
         partial(_bwd_dq_kernel, block_q=bb, block_k=bb, scale=scale,
                 window=window, causal=causal),
+        name="flash_bwd_dq",
         # (bh, q-block, k-block innermost) — inner dim shortened by a window
         grid=(BH, n_blk, _n_kv_blocks(n_blk, bb, window) if causal else n_blk),
         in_specs=[
@@ -472,6 +474,7 @@ def _flash_bwd(block: int, interpret: bool, window: int, res, do,
     dk, dv = pl.pallas_call(
         partial(_bwd_dkv_kernel, block_q=bb, block_k=bb, scale=scale,
                 window=window, n_blk=n_blk, causal=causal),
+        name="flash_bwd_dkv",
         # (bh, k-block, q-block innermost) — inner dim shortened by a window
         grid=(BH, n_blk, _n_q_blocks(n_blk, bb, window) if causal else n_blk),
         in_specs=[

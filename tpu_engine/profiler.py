@@ -7,9 +7,10 @@ The reference delegates all profiling to DeepSpeed config flags
 (``ai_engine/loss_monitor.py:50``). Here the engine owns the numbers
 (SURVEY.md §5 tracing plan):
 
-- :class:`StepProfiler` — the in-loop wall-clock breakdown: data-wait,
-  device-step, host-sync and monitor overhead per step, with rolling
-  mean/p50/p95 summaries (bounded window — no unbounded growth);
+- :class:`StepProfiler` — the phase clock inside the supervisor loop and
+  ``ContinuousBatcher.step``: every phase of an iteration by name, on the
+  host clock (rolling mean/p50/p95, bounded window) and as
+  ``tpu_engine.<loop>.<phase>`` annotations on the profiler's clock;
 - :func:`mfu` / :func:`peak_flops_per_chip` — tokens/sec/chip → model-FLOPs
   utilisation against the chip's bf16 peak (the BASELINE.json north-star
   metric);
@@ -20,6 +21,7 @@ The reference delegates all profiling to DeepSpeed config flags
 
 from __future__ import annotations
 
+import contextlib
 import statistics
 import threading
 import time
@@ -105,18 +107,29 @@ def pipeline_tick_account(
 
 
 class StepProfiler:
-    """Rolling wall-clock breakdown of the train loop's phases.
+    """The phase clock of one host loop: a rolling wall-clock breakdown of
+    its iterations, on the host clock and, while a ``jax.profiler`` session
+    runs, on the profiler's clock beside the device ops.
 
-    Phases (per step): ``data`` (batch fetch / host pipeline), ``dispatch``
-    (trace-cache hit + async enqueue of the jit step), ``device`` (device
-    execution + metric transfer — JAX dispatch is async, so the wall-clock
-    cost of the step lands in the blocking device→host read), ``other``
-    (monitor, checkpoint bookkeeping). All in seconds.
+    A step runs **from one** :meth:`begin_step` **to the next**, so
+    ``summary()["total"]`` (and the rates derived from it) covers the whole
+    iteration, not just the part up to the blocking device read. Inside it
+    ``with prof.phase(name, **ids)`` adds the interval's host seconds to
+    ``name`` and holds a ``jax.profiler.TraceAnnotation(
+    "tpu_engine.<loop>.<name>", **ids)`` for exactly that interval. With no
+    profiler session the annotation is a flag test; together with two
+    ``perf_counter`` reads a phase that is the whole cost of the clock.
+    Phases do not nest. ``other`` is the un-attributed remainder of the
+    iteration and is reported like any phase: its annotation spans the whole
+    iteration, so in a trace the phases lie inside it and whatever they leave
+    uncovered reads ``tpu_engine.<loop>.other``. All in seconds.
+
+    ``loop`` and ``phases`` are the owning loop's: it names itself and the
+    phases of its body (``other`` is appended here).
     """
 
-    PHASES = ("data", "dispatch", "device", "other")
-
-    def __init__(self, window: int = 100, tokens_per_step: Optional[int] = None,
+    def __init__(self, *, loop: str, phases: tuple[str, ...], window: int = 100,
+                 tokens_per_step: Optional[int] = None,
                  flops_per_token: Optional[float] = None, n_devices: int = 1,
                  pipeline_account: Optional[dict[str, Any]] = None):
         self.window = window
@@ -130,52 +143,80 @@ class StepProfiler:
         # under-reports pipelined MFU: the bubble is a schedule property,
         # not a kernel-efficiency loss.
         self.pipeline_account = pipeline_account
-        self._phases: dict[str, deque[float]] = {p: deque(maxlen=window) for p in self.PHASES}
+        self.loop = loop
+        self.phases = tuple(phases) + ("other",)
+        self._span_names = {p: f"tpu_engine.{loop}.{p}" for p in self.phases}
+        self._iteration: Optional[Any] = None  # the open iteration's ``other`` annotation
+        self._phases: dict[str, deque[float]] = {p: deque(maxlen=window) for p in self.phases}
         self._totals: deque[float] = deque(maxlen=window)
         self._steps_seen = 0
         self._lock = threading.Lock()
-        self._t_phase: Optional[float] = None
         self._t_step_start: Optional[float] = None
         self._current: dict[str, float] = {}
+        self._last: dict[str, float] = {}
+        self._last_total: Optional[float] = None
 
     # -- recording ----------------------------------------------------------
 
-    def begin_step(self) -> None:
+    def begin_step(self) -> Optional[float]:
+        """Open an iteration, closing the one before it; returns the closed
+        iteration's total seconds (None when none was open)."""
         now = time.perf_counter()
+        closed = self._close(now)
         self._t_step_start = now
-        self._t_phase = now
         self._current = {}
+        self._iteration = jax.profiler.TraceAnnotation(self._span_names["other"])
+        self._iteration.__enter__()
+        return closed
 
-    def mark(self, phase: str) -> None:
-        """Close the currently-running phase as ``phase``."""
-        now = time.perf_counter()
-        if self._t_phase is not None:
-            self._current[phase] = self._current.get(phase, 0.0) + (now - self._t_phase)
-        self._t_phase = now
+    def end_step(self) -> Optional[float]:
+        """Close the open iteration without opening another (the loop has
+        left its body for the last time). Returns its total seconds."""
+        return self._close(time.perf_counter())
 
-    def end_step(self) -> float:
-        """Close the step; un-attributed time lands in ``other``. Returns
-        total step wall-clock seconds."""
-        now = time.perf_counter()
-        total = (now - self._t_step_start) if self._t_step_start is not None else 0.0
-        attributed = sum(self._current.values())
-        self._current["other"] = self._current.get("other", 0.0) + max(total - attributed, 0.0)
+    def _close(self, now: float) -> Optional[float]:
+        if self._t_step_start is None:
+            return None
+        self._iteration.__exit__(None, None, None)
+        total = now - self._t_step_start
+        cur = self._current
+        cur["other"] = max(total - sum(cur.values()), 0.0)
         with self._lock:
-            for p in self.PHASES:
-                self._phases[p].append(self._current.get(p, 0.0))
+            for p in self.phases:
+                self._phases[p].append(cur.get(p, 0.0))
             self._totals.append(total)
             self._steps_seen += 1
-        self._t_phase = None
+        self._last, self._last_total = cur, total
         self._t_step_start = None
         return total
 
-    def last_step_phases(self) -> dict[str, float]:
-        """Phase seconds of the most recently ended step (empty before any).
-        Feeds the derived duty-cycle telemetry source."""
-        return dict(self._current)
+    @contextlib.contextmanager
+    def phase(self, name: str, **ids: Any):
+        """Attribute the enclosed interval to ``name``; ``ids`` (step,
+        request id, slot …) travel on the profiler annotation only."""
+        with jax.profiler.TraceAnnotation(self._span_names[name], **ids):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                cur = self._current
+                cur[name] = cur.get(name, 0.0) + (time.perf_counter() - t0)
+
+    def open_step(self) -> tuple[dict[str, float], float]:
+        """(phase seconds so far, seconds since it began) of the open
+        iteration."""
+        if self._t_step_start is None:
+            return {}, 0.0
+        return dict(self._current), time.perf_counter() - self._t_step_start
+
+    def last_step(self) -> Optional[tuple[dict[str, float], float]]:
+        """(phase seconds, whole-iteration seconds) of the most recently
+        closed iteration; None before any has closed."""
+        if self._last_total is None:
+            return None
+        return dict(self._last), self._last_total
 
     # -- views --------------------------------------------------------------
-
     @staticmethod
     def _stats(xs: list[float]) -> dict[str, float]:
         if not xs:

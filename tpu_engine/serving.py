@@ -73,6 +73,16 @@ from tpu_engine.models.transformer import (
     embed_tokens,
     unembed,
 )
+from tpu_engine.profiler import StepProfiler
+
+
+def _program(name: str, fn, **static):
+    """``fn`` with ``static`` bound, under the name its jitted program
+    carries in a profile (``jit_<name>``; a bare ``partial`` would be
+    ``jit__unknown``)."""
+    bound = partial(fn, **static)
+    bound.__name__ = name
+    return bound
 
 
 @jax.tree_util.register_dataclass
@@ -225,14 +235,14 @@ def _pick_tokens(
     (request id, draw count) — the stream for a request is deterministic
     for a given server ``seed`` and independent of which other requests
     share the batch or when they were admitted."""
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
     def draw(rid, cnt, lg, t):
         key = jax.random.fold_in(jax.random.fold_in(base_key, rid), cnt)
         return jax.random.categorical(key, lg / jnp.maximum(t, 1e-6))
 
-    sampled = jax.vmap(draw)(req_ids, counts, logits, temps).astype(jnp.int32)
-    return jnp.where(temps > 0.0, sampled, greedy)
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        sampled = jax.vmap(draw)(req_ids, counts, logits, temps).astype(jnp.int32)
+        return jnp.where(temps > 0.0, sampled, greedy)
 
 
 def decode_chunk(
@@ -566,7 +576,12 @@ class Request:
     error: Optional[str] = None
     tokens: list[int] = field(default_factory=list)
     slot: Optional[int] = None
+    # Lifecycle stamps, all on time.time(): submitted → admitted (slot
+    # taken) → prefill_started (first chunk begins) → first token → finished.
+    # A wire-prefilled request (submit_prefilled) never prefills here.
     submitted_at: float = field(default_factory=time.time)
+    admitted_at: Optional[float] = None
+    prefill_started_at: Optional[float] = None
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
     # Disaggregated serving: a finished ``hold_kv`` request keeps its slot
@@ -606,6 +621,21 @@ class _PrefillState:
     @property
     def padded(self) -> int:
         return self.toks.shape[1]
+
+
+# Phases of ``ContinuousBatcher.step`` on its phase clock (StepProfiler):
+# ``profile()["phases"]`` and the ``tpu_engine.batcher.<phase>`` trace
+# annotations carry these names.
+BATCHER_PHASES = (
+    "handoff",      # disaggregated-serving extraction orders
+    "admit",        # locked admission pass + ingestion-cache allocation
+    "prefill",      # one _advance_prefill chunk
+    "first_token",  # sampling from the prefill logits + its emission
+    "stage",        # host arrays, transfers and the decode/speculative dispatch
+    "device",       # the blocking token read
+    "emit",         # the locked emission loop
+    "idle",         # serve_forever's sleep when nothing is queued
+)
 
 
 class ContinuousBatcher:
@@ -730,22 +760,25 @@ class ContinuousBatcher:
                 prefill_chunk=self.prefill_chunk,
             )
             self._spec = jax.jit(
-                partial(speculative_round, cfg=cfg, draft_cfg=draft_cfg,
-                        gamma=self.spec_gamma, compute_dtype=compute_dtype),
+                _program("speculative_round", speculative_round, cfg=cfg,
+                         draft_cfg=draft_cfg, gamma=self.spec_gamma,
+                         compute_dtype=compute_dtype),
                 donate_argnums=(3, 4),  # both pools alias across rounds
             )
             # The draft's prompt ingestion needs no logits — skip the
             # T×D×V unembed per chunk (it would rival the whole 2-layer
             # draft forward it accompanies).
             self._draft_prefill_fn = jax.jit(
-                partial(_draft_prefill_ingest, cfg=draft_cfg,
-                        compute_dtype=compute_dtype),
+                _program("draft_prefill_chunk", _draft_prefill_ingest,
+                         cfg=draft_cfg, compute_dtype=compute_dtype),
                 donate_argnums=(2,),
             )
             self._draft_insert = jax.jit(
-                _insert_prefill, donate_argnums=(0,), static_argnums=(4,),
+                _program("insert_prefill", _insert_prefill),
+                donate_argnums=(0,), static_argnums=(4,),
             )
-            self._draft_reset = jax.jit(_reset_slot, donate_argnums=(0,))
+            self._draft_reset = jax.jit(_program("reset_slot", _reset_slot),
+                                        donate_argnums=(0,))
 
         # -- prompt-prefix KV cache (shared system prompts) -----------------
         self._prefix_cache: Optional[_PrefixCache] = None
@@ -769,9 +802,11 @@ class ContinuousBatcher:
             # stored-entry lane counts are prefill_chunk multiples and the
             # traced use_len carries the token-granular hit length, so
             # compiled variants stay few.
-            self._slice_prefix = jax.jit(_slice_prefix, static_argnums=(1,))
+            self._slice_prefix = jax.jit(
+                _program("slice_prefix", _slice_prefix), static_argnums=(1,))
             self._paste_prefix = jax.jit(
-                _paste_prefix, donate_argnums=(0,), static_argnums=(3,),
+                _program("paste_prefix", _paste_prefix),
+                donate_argnums=(0,), static_argnums=(3,),
                 out_shardings=None if mesh is None else KVCache(
                     k=self._kv_sh, v=self._kv_sh, pos=self._rep,
                     length=self._rep, ring=False,
@@ -781,24 +816,26 @@ class ContinuousBatcher:
             )
 
         self._decode = jax.jit(
-            partial(decode_chunk, cfg=cfg, n_steps=self.chunk_steps,
-                    compute_dtype=compute_dtype),
+            _program("decode_chunk", decode_chunk, cfg=cfg,
+                     n_steps=self.chunk_steps, compute_dtype=compute_dtype),
             donate_argnums=(2,),  # the pool: alias, never copy (2x HBM)
             out_shardings=None if mesh is None else (self._rep, self._cache_sh),
         )
         self._prefill_fn = jax.jit(
-            partial(_prefill_forward, cfg=cfg, compute_dtype=compute_dtype),
+            _program("prefill_chunk", _prefill_forward, cfg=cfg,
+                     compute_dtype=compute_dtype),
             donate_argnums=(2,),
         )
         # NOTE: c1 (arg 1) is dead after the insert but NOT donated — its
         # [L, 1, M, ...] buffers can never alias the [L, slots, S, ...]
         # pool, so donation would only emit "unusable donation" warnings.
         self._insert = jax.jit(
-            _insert_prefill, donate_argnums=(0,), static_argnums=(4,),
+            _program("insert_prefill", _insert_prefill),
+            donate_argnums=(0,), static_argnums=(4,),
             out_shardings=None if mesh is None else self._cache_sh,
         )
         self._reset = jax.jit(
-            _reset_slot, donate_argnums=(0,),
+            _program("reset_slot", _reset_slot), donate_argnums=(0,),
             out_shardings=None if mesh is None else self._cache_sh,
         )
 
@@ -830,6 +867,11 @@ class ContinuousBatcher:
         self._lock = threading.Lock()
         self._done = threading.Condition(self._lock)
         self._tokens_out = 0
+        # The engine loop's phase clock (see StepProfiler) and the decode
+        # layer's attempted and useful token counts; engine-thread writes only.
+        self._profiler = StepProfiler(loop="batcher", phases=BATCHER_PHASES)
+        self._decode_tokens_computed = 0
+        self._decode_tokens_emitted = 0
         self._spec_rounds = 0
         self._spec_accepted = 0
         self._started = time.time()
@@ -1110,6 +1152,11 @@ class ContinuousBatcher:
             # Absolute stamp too: fleet-level TTFT measures from FLEET
             # submission (queue + route + prefill), not engine admission.
             out["first_token_at"] = req.first_token_at
+        for stamp in ("submitted_at", "admitted_at", "prefill_started_at",
+                      "finished_at"):
+            at = getattr(req, stamp)
+            if at is not None:
+                out[stamp] = at
         if req.error:
             out["error"] = req.error
         return out
@@ -1186,6 +1233,13 @@ class ContinuousBatcher:
                 "queued_handoffs": len(self._prefilled_queue),
                 "handoffs_out": self.handoffs_out,
                 "handoffs_in": self.handoffs_in,
+                # Monotonic; computed/emitted is the decode layer's
+                # attempted-to-useful ratio: a dispatch computes chunk_steps
+                # tokens for every active slot, and what overshoots a
+                # finished request (or a rejected speculative proposal) is
+                # thrown away.
+                "decode_tokens_computed_total": self._decode_tokens_computed,
+                "decode_tokens_emitted_total": self._decode_tokens_emitted,
             }
             if self._prefix_cache is not None:
                 out["prefix_cache"] = self._prefix_cache.stats()
@@ -1203,7 +1257,13 @@ class ContinuousBatcher:
                     self._spec_accepted / (self._spec_rounds *
                                            (self.spec_gamma + 1)), 3
                 )
-            return out
+        return out
+
+    def profile(self) -> dict[str, Any]:
+        """The engine loop's phase clock, summarised (``StepProfiler.
+        summary``): the operator's view beside :meth:`stats`, which the
+        fleet's router reads per request and which therefore stays counters."""
+        return self._profiler.summary()
 
     # -- engine side ---------------------------------------------------------
 
@@ -1326,44 +1386,57 @@ class ContinuousBatcher:
         ``submit``/``result``/``stats`` from serving threads never wait on
         device work. The engine thread is the sole mutator of the KV pool
         and slot arrays, so they need no lock at all."""
+        prof = self._profiler
+        prof.begin_step()  # closes the previous iteration, idle sleep included
         # ---- handoff orders first: extraction frees held slots, so the
         # admission pass below can reuse them in the SAME step ----
-        with self._lock:
-            orders, self._handoff_requests = self._handoff_requests, []
-        for rid, quantize in orders:
-            self._service_handoff(rid, quantize)
+        with prof.phase("handoff"):
+            with self._lock:
+                orders, self._handoff_requests = self._handoff_requests, []
+            for rid, quantize in orders:
+                self._service_handoff(rid, quantize)
 
         # ---- admission (bookkeeping under the lock): wire-prefilled
         # requests win free slots (their prompt K/V is already paid for —
         # they only need a lane to decode in), then queued prompts ----
-        admitted_handoffs: list[tuple[int, Request, Any]] = []
-        admitted: list[tuple[int, Request]] = []
-        with self._lock:
-            for slot in range(self.max_slots):
-                if self._slots[slot] is not None:
-                    continue
-                if self._prefilled_queue:
-                    req, handoff = self._prefilled_queue.pop(0)
-                    req.status, req.slot = "running", slot
-                    self._slots[slot] = req
-                    admitted_handoffs.append((slot, req, handoff))
-                elif self._queue:
-                    req = self._queue.pop(0)
-                    req.status, req.slot = "running", slot
-                    self._slots[slot] = req
-                    admitted.append((slot, req))
-        for slot, req, handoff in admitted_handoffs:  # device insert, no lock
-            self._insert_handoff(handoff, slot)
-        for slot, req in admitted:  # host-side alloc only — cheap
-            self._prefilling[slot] = self._begin_prefill(req, slot)
+        with prof.phase("admit"):
+            admitted_handoffs: list[tuple[int, Request, Any]] = []
+            admitted: list[tuple[int, Request]] = []
+            with self._lock:
+                now = time.time()
+                for slot in range(self.max_slots):
+                    if self._slots[slot] is not None:
+                        continue
+                    if self._prefilled_queue:
+                        req, handoff = self._prefilled_queue.pop(0)
+                        req.status, req.slot = "running", slot
+                        req.admitted_at = now
+                        self._slots[slot] = req
+                        admitted_handoffs.append((slot, req, handoff))
+                    elif self._queue:
+                        req = self._queue.pop(0)
+                        req.status, req.slot = "running", slot
+                        req.admitted_at = now
+                        self._slots[slot] = req
+                        admitted.append((slot, req))
+            for slot, req, handoff in admitted_handoffs:  # device insert, no lock
+                self._insert_handoff(handoff, slot)
+            for slot, req in admitted:  # host-side alloc only — cheap
+                self._prefilling[slot] = self._begin_prefill(req, slot)
 
         # ---- ONE prefill chunk per step (bounded decode stall) ----
         if self._prefilling:
             slot, st = next(iter(self._prefilling.items()))
             if st.req.status != "running":
                 self._prefilling.pop(slot)  # cancelled/failed meanwhile
-            elif self._advance_prefill(st):
-                self._prefilling.pop(slot)
+            else:
+                with prof.phase("prefill", rid=st.req.id, slot=slot,
+                                chunk=st.consumed // self.prefill_chunk):
+                    if st.req.prefill_started_at is None:
+                        st.req.prefill_started_at = time.time()
+                    ingested = self._advance_prefill(st)
+                if ingested:
+                    self._prefilling.pop(slot)
 
         # ---- first token for freshly-prefilled slots comes from the
         # prefill logits; everyone else decodes a chunk. (A slot with
@@ -1371,92 +1444,90 @@ class ContinuousBatcher:
         # is captured in the final chunk, which also completes the
         # ingestion in the same _advance_prefill call.) ----
         produced = 0
-        fresh = self._pending_first_logits
-        self._pending_first_logits = {}
-        # Sampling a first token can dispatch to the device (categorical
-        # draw) — do it OUTSIDE the lock, like every other long operation;
-        # only this engine thread mutates _slots, so the reads are safe.
-        first_toks = {
-            slot: self._first_token(logits, self._slots[slot])
-            for slot, logits in fresh.items()
-            if self._slots[slot] is not None
-        }
-        with self._lock:
-            for slot, tok in first_toks.items():
-                req = self._slots[slot]
-                if req is None:
-                    continue
-                self._emit(req, slot, tok)
-                produced += 1
-            self._note_tokens(produced)
-            # Status filter matters for held slots: a finished hold_kv
-            # request still occupies its slot (pinning the K/V for the
-            # handoff plane) but must NOT keep decoding — advancing its
-            # length would scribble garbage past the extraction frontier.
-            active_reqs = [
-                (i, r) for i, r in enumerate(self._slots)
-                if r is not None and r.status == "running"
-                and i not in self._prefilling
-            ]
+        with prof.phase("first_token"):
+            fresh = self._pending_first_logits
+            self._pending_first_logits = {}
+            # Sampling a first token can dispatch to the device (categorical
+            # draw) — do it OUTSIDE the lock, like every other long operation;
+            # only this engine thread mutates _slots, so the reads are safe.
+            first_toks = {
+                slot: self._first_token(logits, self._slots[slot])
+                for slot, logits in fresh.items()
+                if self._slots[slot] is not None
+            }
+            with self._lock:
+                for slot, tok in first_toks.items():
+                    req = self._slots[slot]
+                    if req is None:
+                        continue
+                    self._emit(req, slot, tok)
+                    produced += 1
+                self._note_tokens(produced)
+                # Status filter matters for held slots: a finished hold_kv
+                # request still occupies its slot (pinning the K/V for the
+                # handoff plane) but must NOT keep decoding — advancing its
+                # length would scribble garbage past the extraction frontier.
+                active_reqs = [
+                    (i, r) for i, r in enumerate(self._slots)
+                    if r is not None and r.status == "running"
+                    and i not in self._prefilling
+                ]
         if not active_reqs:
             return produced
-
-        active = np.zeros((self.max_slots,), bool)
-        for i, _ in active_reqs:
-            active[i] = True
 
         # Speculative path: draft proposes gamma tokens per slot, target
         # verifies every slot's chain in one T=gamma+1 forward; each round
         # emits 1..gamma+1 tokens per slot for two model dispatches.
         # (Greedy-only by the submit guard — no sampling state needed.)
-        if self._draft_params is not None:
-            tgt, n_acc, self._cache, self._draft_cache = self._spec(
-                self.params, self._draft_params,
-                jnp.asarray(self._last_tokens), self._cache,
-                self._draft_cache, jnp.asarray(active),
-            )
-            tgt_host = np.asarray(tgt)          # [B, gamma+1]
-            n_acc_host = np.asarray(n_acc)      # [B]
-            with self._lock:
-                emitted = 0
-                for slot, req in active_reqs:
-                    if self._slots[slot] is not req:
-                        continue
-                    self._spec_rounds += 1
-                    self._spec_accepted += int(n_acc_host[slot])
-                    for t in tgt_host[slot][: n_acc_host[slot]]:
-                        self._emit(req, slot, int(t))
-                        emitted += 1
-                        if req.status != "running":
-                            break  # slot reset; surplus accepted tokens dropped
-                self._note_tokens(emitted)
-            return produced + emitted
-
-        temps = np.zeros((self.max_slots,), np.float32)
-        req_ids = np.zeros((self.max_slots,), np.int32)
-        counts = np.zeros((self.max_slots,), np.int32)
-        for i, r in active_reqs:
-            temps[i] = r.temperature
-            req_ids[i] = r.id
-            counts[i] = len(r.tokens)
-
-        toks_bn, self._cache = self._decode(
-            self.params, jnp.asarray(self._last_tokens), self._cache,
-            jnp.asarray(active), jnp.asarray(temps), jnp.asarray(req_ids),
-            jnp.asarray(counts), self._base_key,
-        )
-        toks_host = np.asarray(toks_bn)  # [B, n] — one transfer
-        with self._lock:
+        speculative = self._draft_params is not None
+        with prof.phase("stage"):
+            active = np.zeros((self.max_slots,), bool)
+            for i, _ in active_reqs:
+                active[i] = True
+            if speculative:
+                tgt, n_acc, self._cache, self._draft_cache = self._spec(
+                    self.params, self._draft_params,
+                    jnp.asarray(self._last_tokens), self._cache,
+                    self._draft_cache, jnp.asarray(active),
+                )
+            else:
+                temps = np.zeros((self.max_slots,), np.float32)
+                req_ids = np.zeros((self.max_slots,), np.int32)
+                counts = np.zeros((self.max_slots,), np.int32)
+                for i, r in active_reqs:
+                    temps[i] = r.temperature
+                    req_ids[i] = r.id
+                    counts[i] = len(r.tokens)
+                toks_bn, self._cache = self._decode(
+                    self.params, jnp.asarray(self._last_tokens), self._cache,
+                    jnp.asarray(active), jnp.asarray(temps), jnp.asarray(req_ids),
+                    jnp.asarray(counts), self._base_key,
+                )
+        with prof.phase("device"):
+            if speculative:
+                toks_host = np.asarray(tgt)         # [B, gamma+1]
+                n_take = np.asarray(n_acc)          # [B] accepted per slot
+            else:
+                toks_host = np.asarray(toks_bn)     # [B, n] — one transfer
+                n_take = None
+        self._decode_tokens_computed += len(active_reqs) * toks_host.shape[1]
+        with prof.phase("emit"), self._lock:
             emitted = 0
             for slot, req in active_reqs:
                 if self._slots[slot] is not req:
                     continue  # request state changed while we computed
-                for t in toks_host[slot]:
+                row = toks_host[slot]
+                if speculative:
+                    self._spec_rounds += 1
+                    self._spec_accepted += int(n_take[slot])
+                    row = row[: n_take[slot]]
+                for t in row:
                     self._emit(req, slot, int(t))
                     emitted += 1
                     if req.status != "running":
                         break  # overshoot discarded; slot already reset
             self._note_tokens(emitted)
+        self._decode_tokens_emitted += emitted
         return produced + emitted
 
     def _service_handoff(self, rid: int, quantize: Optional[bool]) -> None:
@@ -1596,7 +1667,8 @@ class ContinuousBatcher:
                 if produced == 0 and not self._prefilling and not self._queue \
                         and not self._handoff_requests \
                         and not self._prefilled_queue:
-                    time.sleep(idle_sleep)
+                    with self._profiler.phase("idle"):
+                        time.sleep(idle_sleep)
         finally:
             if self.last_error is None:
                 self._drain("server stopped")

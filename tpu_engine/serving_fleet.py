@@ -66,6 +66,16 @@ from tpu_engine.supervisor import JobStatus
 
 log = logging.getLogger(__name__)
 
+# A request's stages inside the engine, each from one ``Request`` stamp to the
+# next (``ContinuousBatcher._result_locked`` reports them): the child spans
+# recorded under the fleet's request span when it closes.
+REQUEST_STAGES = (
+    ("engine_queue", "submitted_at", "admitted_at"),
+    ("prefill_wait", "admitted_at", "prefill_started_at"),
+    ("prefill", "prefill_started_at", "first_token_at"),
+    ("decode", "first_token_at", "finished_at"),
+)
+
 
 class ServingReplicaSpec(BaseModel):
     """Shape of one decode replica — every replica of a fleet is identical
@@ -1165,6 +1175,26 @@ class ServingFleet:
             "slots": slots,
         }
 
+    @staticmethod
+    def _record_lifecycle(span: Any, req: dict, out: dict, tokens: int) -> None:
+        """The engine's stamps of a finished request as child spans of its
+        request span, with explicit times: where its latency went, by
+        stage. Recorded here, once, off the engine thread — never per token
+        or per step. A stage whose stamps the engine did not report (a
+        wire-prefilled request never prefills; a stub engine has none) is
+        left out."""
+        attrs = {
+            "engine_rid": req["engine_rid"], "replica": req["replica"],
+            "prompt_tokens": out.get("prompt_len"), "tokens": tokens,
+        }
+        for name, begin, end in REQUEST_STAGES:
+            t0, t1 = out.get(begin), out.get(end)
+            if t0 is not None and t1 is not None:
+                tracing.get_recorder().record_span(
+                    name, kind="serving", trace_id=span.trace_id, parent=span,
+                    t0=t0, t1=t1, attrs=attrs,
+                )
+
     def result(self, fid: str) -> dict[str, Any]:
         """Fleet-side view of one request; re-dispatches it when its
         replica was preempted mid-flight (stateless replicas make retry the
@@ -1226,6 +1256,7 @@ class ServingFleet:
                         out["fleet_ttft_ms"] = round(ttft, 2)
                 span = req.get("_span")
                 if span is not None and span.t1 is None:
+                    self._record_lifecycle(span, req, out, n_new)
                     span.end(
                         status=out.get("status"),
                         tokens=n_new,
@@ -1343,6 +1374,9 @@ class ServingFleet:
                 if entry["engine_ready"]:
                     try:
                         entry["engine"] = job.engine.stats()
+                        profile = getattr(job.engine, "profile", None)
+                        if profile is not None:  # the engine loop's phase clock
+                            entry["engine"]["profile"] = profile()
                     except Exception:  # noqa: BLE001 — engine mid-teardown
                         entry["engine_ready"] = False
                 replicas[sid] = entry

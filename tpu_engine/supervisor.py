@@ -49,6 +49,19 @@ from tpu_engine.train import TrainProgram, build_train_program
 
 log = logging.getLogger(__name__)
 
+# Phases of the training loop's body, on its phase clock (StepProfiler):
+# ``describe()["profile"]["phases"]`` and the ``tpu_engine.supervisor.<phase>``
+# trace annotations carry these names.
+SUPERVISOR_PHASES = (
+    "data",        # data_fn / synthetic batch
+    "dispatch",    # trace-cache hit + async enqueue of the jitted step
+    "device",      # the blocking jax.device_get(metrics): device execution lands here
+    "health",      # fault seams + _unhealthy_mesh_devices
+    "anomaly",     # step-time detector, attribution, hetero tracker and consult
+    "monitor",     # monitor.ingest, _log_metrics, alert handling
+    "checkpoint",  # eval, periodic save, _advance_stable
+)
+
 
 def _perplexity(loss: float) -> float:
     """exp(loss), clamped so a divergence spike can't overflow to inf."""
@@ -788,7 +801,8 @@ class TrainingJob:
                 tokens_per_batch *= d
             from tpu_engine.models import transformer as tfm
 
-            self.profiler = StepProfiler(
+            prof = self.profiler = StepProfiler(
+                loop="supervisor", phases=SUPERVISOR_PHASES,
                 tokens_per_step=tokens_per_batch,
                 flops_per_token=tfm.train_flops_per_token(prog.model_config, self.config.seq_len),
                 n_devices=prog.runtime.n_devices,
@@ -826,252 +840,270 @@ class TrainingJob:
 
                 hetero_mod.set_active(self._hetero)
             step = start_step
+            closed_step = None  # the step number the last closed iteration reached
             while step < self.max_steps and not self._stop.is_set():
-                self.profiler.begin_step()
-                batch = (
-                    self.data_fn(step) if self.data_fn is not None else prog.synthetic_batch(step)
-                )
-                self.profiler.mark("data")
-                with self._state_lock:
+                # Every iteration counts once, when it closes (the last one
+                # at the loop's exit).
+                attempt_step_s += prof.begin_step() or 0.0
+                it = step  # this iteration's id on every phase annotation
+                with prof.phase("data", step=it):
+                    batch = (
+                        self.data_fn(step) if self.data_fn is not None
+                        else prog.synthetic_batch(step)
+                    )
+                with prof.phase("dispatch", step=it), self._state_lock:
                     self._state, metrics = prog.step(self._state, batch)
-                self.profiler.mark("dispatch")
-                host = {k: float(v) for k, v in jax.device_get(metrics).items()}
-                self.profiler.mark("device")
-                dt = self.profiler.end_step()
-                attempt_step_s += dt
+                with prof.phase("device", step=it):
+                    host = {k: float(v) for k, v in jax.device_get(metrics).items()}
+                # Step time is the WHOLE iteration, begin to begin — what an
+                # operator's tokens/s must be over — so it is the previous
+                # iteration's; an attempt's first iteration has only its own
+                # time up to here.
+                last = prof.last_step()
+                phases_s, dt = last or prof.open_step()
                 self.last_step_time_s = dt
                 self.tokens_per_sec = tokens_per_batch / dt if dt > 0 else None
                 # Feed the fleet's derived duty-cycle source: device-phase
                 # time (the blocking device→host read absorbs the step's
                 # device execution) over step wall time.
                 telemetry.observe_step(
-                    self.profiler.last_step_phases().get("device", 0.0), dt,
-                    device_ids=local_device_ids,
+                    phases_s.get("device", 0.0), dt, device_ids=local_device_ids,
                 )
                 step = int(host["step"])
                 self.current_step = step
+                # The step whose time ``dt`` is: the anomaly record names it.
+                dt_step = closed_step if last is not None else step
+                closed_step = step
 
                 # Fault-injection seams + self-healing health check.
-                inj = self._injector()
-                if inj is not None:
-                    inj.observe_step(step)
-                    slow_spec = inj.take_host_slow(step)
-                    slow = float(slow_spec.slow_s) if slow_spec is not None else 0.0
-                    if slow > 0:
-                        # Host-slow is a *reported* stall (step time +
-                        # throughput degrade) — never an actual sleep, so
-                        # chaos runs stay deterministic and fast.
-                        self.last_step_time_s = dt + slow
-                        self.tokens_per_sec = tokens_per_batch / self.last_step_time_s
-                        rec.event(
-                            "host-slow",
-                            kind="fault",
-                            trace_id=self.trace_id,
-                            parent=attempt_span,
-                            attrs={"step": step, "penalty_s": slow},
-                        )
-                        if self._hetero is not None:
-                            # Attribute the stall to the host the spec
-                            # names (fleet device index → owning process).
-                            n_proc = self._hetero.tracker.n_processes
-                            dev_per_proc = max(
-                                prog.runtime.n_devices // n_proc, 1
+                with prof.phase("health", step=it):
+                    inj = self._injector()
+                    if inj is not None:
+                        inj.observe_step(step)
+                        slow_spec = inj.take_host_slow(step)
+                        slow = float(slow_spec.slow_s) if slow_spec is not None else 0.0
+                        if slow > 0:
+                            # Host-slow is a *reported* stall (step time +
+                            # throughput degrade) — never an actual sleep, so
+                            # chaos runs stay deterministic and fast.
+                            self.last_step_time_s = dt + slow
+                            dt_step = step  # the stall is reported at this step
+                            self.tokens_per_sec = tokens_per_batch / self.last_step_time_s
+                            rec.event(
+                                "host-slow",
+                                kind="fault",
+                                trace_id=self.trace_id,
+                                parent=attempt_span,
+                                attrs={"step": step, "penalty_s": slow},
                             )
-                            proc = (
-                                slow_spec.device_index // dev_per_proc
-                                if slow_spec.device_index is not None
-                                else None
-                            )
-                            self._last_slow_proc = proc
-                            self._hetero.tracker.note_host_slow(proc, slow, dt)
-                    if inj.preempt_due(step):
-                        # Synchronous injection (not via the watcher thread):
-                        # the step that triggers is the step that saves.
-                        self._on_preemption("fault-injected:preemption-signal")
-                if (
-                    self.self_heal
-                    and self.preemption_reason is None
-                    and step % self.health_check_interval_steps == 0
-                ):
-                    bad = self._unhealthy_mesh_devices()
-                    if bad:
-                        self._begin_self_heal(step, bad)
+                            if self._hetero is not None:
+                                # Attribute the stall to the host the spec
+                                # names (fleet device index → owning process).
+                                n_proc = self._hetero.tracker.n_processes
+                                dev_per_proc = max(
+                                    prog.runtime.n_devices // n_proc, 1
+                                )
+                                proc = (
+                                    slow_spec.device_index // dev_per_proc
+                                    if slow_spec.device_index is not None
+                                    else None
+                                )
+                                self._last_slow_proc = proc
+                                self._hetero.tracker.note_host_slow(proc, slow, dt)
+                        if inj.preempt_due(step):
+                            # Synchronous injection (not via the watcher thread):
+                            # the step that triggers is the step that saves.
+                            self._on_preemption("fault-injected:preemption-signal")
+                    if (
+                        self.self_heal
+                        and self.preemption_reason is None
+                        and step % self.health_check_interval_steps == 0
+                    ):
+                        bad = self._unhealthy_mesh_devices()
+                        if bad:
+                            self._begin_self_heal(step, bad)
 
                 # Step-time anomaly attribution: flag against the sliding
                 # baseline, then attribute to whatever span/event overlaps
-                # this step's wall window (the previous step's end →  now
+                # the observed iteration's wall window through now (so it
                 # covers inter-step work like a checkpoint save). The
                 # host-slow event above lands BEFORE this check, so an
                 # injected stall is both the anomaly and its cause.
-                if self._anomaly is not None:
-                    now_ts = time.time()
-                    observed = (
-                        self.last_step_time_s
-                        if self.last_step_time_s is not None
-                        else dt
-                    )
-                    anom = self._anomaly.observe(step, observed)
-                    if anom is not None:
-                        w0 = (
-                            self._prev_step_end_ts
-                            if self._prev_step_end_ts is not None
-                            else now_ts - observed
+                with prof.phase("anomaly", step=it):
+                    if self._anomaly is not None:
+                        now_ts = time.time()
+                        observed = (
+                            self.last_step_time_s
+                            if self.last_step_time_s is not None
+                            else dt
                         )
-                        cause = rec.attribute(self.trace_id, w0, now_ts)
-                        anom["cause"] = cause
-                        self.anomalies_total += 1
-                        self.last_anomaly = dict(anom)
-                        if self._hetero is not None:
-                            # Sustained host-slow attribution seeds the
-                            # throughput tracker even when no injector
-                            # reported a penalty (real-fleet path).
-                            self._hetero.tracker.note_attribution(
-                                cause, anom, self._last_slow_proc
+                        anom = self._anomaly.observe(dt_step, observed)
+                        if anom is not None:
+                            # From the begin of the iteration observed
+                            # (the one before this, once one has closed) or
+                            # the last check, whichever is earlier.
+                            w0 = now_ts - observed - prof.open_step()[1]
+                            if self._prev_step_end_ts is not None:
+                                w0 = min(w0, self._prev_step_end_ts)
+                            cause = rec.attribute(self.trace_id, w0, now_ts)
+                            anom["cause"] = cause
+                            self.anomalies_total += 1
+                            self.last_anomaly = dict(anom)
+                            if self._hetero is not None:
+                                # Sustained host-slow attribution seeds the
+                                # throughput tracker even when no injector
+                                # reported a penalty (real-fleet path).
+                                self._hetero.tracker.note_attribution(
+                                    cause, anom, self._last_slow_proc
+                                )
+                            rec.record_anomaly(
+                                cause,
+                                trace_id=self.trace_id,
+                                attrs={
+                                    "job_id": self.job_id,
+                                    "step": anom["step"],
+                                    "duration_s": anom["duration_s"],
+                                    "baseline_s": anom["baseline_s"],
+                                    "sustained": anom["sustained"],
+                                },
                             )
-                        rec.record_anomaly(
-                            cause,
-                            trace_id=self.trace_id,
-                            attrs={
-                                "job_id": self.job_id,
-                                "step": anom["step"],
-                                "duration_s": anom["duration_s"],
-                                "baseline_s": anom["baseline_s"],
-                                "sustained": anom["sustained"],
-                            },
-                        )
-                        if (
-                            anom["sustained"]
-                            and self._anomaly_trace_session is not None
-                            and not self._auto_trace_started
-                        ):
-                            # Opt-in: one bounded XPlane capture per job on
-                            # sustained regression (never a retry storm).
-                            self._auto_trace_started = True
-                            try:
-                                log_dir = self._anomaly_trace_dir or (
-                                    tempfile.mkdtemp(
-                                        prefix=f"anomtrace_{self.job_id}_"
-                                    )
-                                )
-                                self._anomaly_trace_session.start(
-                                    log_dir, duration_s=30.0
-                                )
-                                rec.event(
-                                    "auto_trace_started",
-                                    kind="supervisor",
-                                    trace_id=self.trace_id,
-                                    attrs={"log_dir": log_dir, "step": step},
-                                )
-                            except Exception as e:
-                                rec.event(
-                                    "auto_trace_unavailable",
-                                    kind="supervisor",
-                                    trace_id=self.trace_id,
-                                    attrs={"error": str(e)},
-                                )
-                    self._prev_step_end_ts = now_ts
-
-                # Heterogeneity plane: every step feeds the throughput EMA
-                # (decay-to-1 heals transient stalls); every
-                # hetero_check_interval_steps the rebalancer is consulted.
-                # A live (non-dry-run) plan moves the data split through
-                # data_fn.reassign — the declared global batch is preserved
-                # exactly (validated again at the data layer).
-                if self._hetero is not None:
-                    self._hetero.tracker.observe_step(
-                        self.last_step_time_s if self.last_step_time_s else dt
-                    )
-                    consult = step % self.hetero_check_interval_steps == 0
-                    if not consult and jax.process_count() <= 1:
-                        # Out-of-band consult requested by the scheduler's
-                        # rebalance-over-shrink path. Honored between
-                        # modulo boundaries only single-process —
-                        # multi-process ranks must all consult at the same
-                        # step, so there the request simply rides the next
-                        # periodic consult.
-                        consult = self._hetero.consult_pending()
-                    if consult:
-                        h_plan = self._hetero.maybe_rebalance(step)
-                        if h_plan is not None and not h_plan.dry_run:
-                            reassign_fn = getattr(self.data_fn, "reassign", None)
-                            if reassign_fn is None:
-                                # No seam to move rows through (synthetic
-                                # batches): roll the plan back so the
-                                # gauges never report a split that is not
-                                # actually feeding the mesh.
-                                self._hetero.revert(h_plan)
-                            else:
+                            if (
+                                anom["sustained"]
+                                and self._anomaly_trace_session is not None
+                                and not self._auto_trace_started
+                            ):
+                                # Opt-in: one bounded XPlane capture per job on
+                                # sustained regression (never a retry storm).
+                                self._auto_trace_started = True
                                 try:
-                                    reassign_fn(h_plan.assignment)
-                                    self.hetero_rebalances_total += 1
-                                    rec.event(
-                                        "hetero_reassign",
-                                        kind="hetero",
-                                        trace_id=self.trace_id,
-                                        parent=attempt_span,
-                                        attrs={
-                                            "step": step,
-                                            "assignment": list(h_plan.assignment),
-                                        },
+                                    log_dir = self._anomaly_trace_dir or (
+                                        tempfile.mkdtemp(
+                                            prefix=f"anomtrace_{self.job_id}_"
+                                        )
                                     )
-                                except ValueError as e:
+                                    self._anomaly_trace_session.start(
+                                        log_dir, duration_s=30.0
+                                    )
+                                    rec.event(
+                                        "auto_trace_started",
+                                        kind="supervisor",
+                                        trace_id=self.trace_id,
+                                        attrs={"log_dir": log_dir, "step": step},
+                                    )
+                                except Exception as e:
+                                    rec.event(
+                                        "auto_trace_unavailable",
+                                        kind="supervisor",
+                                        trace_id=self.trace_id,
+                                        attrs={"error": str(e)},
+                                    )
+                        self._prev_step_end_ts = now_ts
+
+                    # Heterogeneity plane: every step feeds the throughput EMA
+                    # (decay-to-1 heals transient stalls); every
+                    # hetero_check_interval_steps the rebalancer is consulted.
+                    # A live (non-dry-run) plan moves the data split through
+                    # data_fn.reassign — the declared global batch is preserved
+                    # exactly (validated again at the data layer).
+                    if self._hetero is not None:
+                        self._hetero.tracker.observe_step(
+                            self.last_step_time_s if self.last_step_time_s else dt
+                        )
+                        consult = step % self.hetero_check_interval_steps == 0
+                        if not consult and jax.process_count() <= 1:
+                            # Out-of-band consult requested by the scheduler's
+                            # rebalance-over-shrink path. Honored between
+                            # modulo boundaries only single-process —
+                            # multi-process ranks must all consult at the same
+                            # step, so there the request simply rides the next
+                            # periodic consult.
+                            consult = self._hetero.consult_pending()
+                        if consult:
+                            h_plan = self._hetero.maybe_rebalance(step)
+                            if h_plan is not None and not h_plan.dry_run:
+                                reassign_fn = getattr(self.data_fn, "reassign", None)
+                                if reassign_fn is None:
+                                    # No seam to move rows through (synthetic
+                                    # batches): roll the plan back so the
+                                    # gauges never report a split that is not
+                                    # actually feeding the mesh.
                                     self._hetero.revert(h_plan)
-                                    rec.event(
-                                        "hetero_reassign_rejected",
-                                        kind="hetero",
-                                        trace_id=self.trace_id,
-                                        attrs={"step": step, "error": str(e)},
-                                    )
+                                else:
+                                    try:
+                                        reassign_fn(h_plan.assignment)
+                                        self.hetero_rebalances_total += 1
+                                        rec.event(
+                                            "hetero_reassign",
+                                            kind="hetero",
+                                            trace_id=self.trace_id,
+                                            parent=attempt_span,
+                                            attrs={
+                                                "step": step,
+                                                "assignment": list(h_plan.assignment),
+                                            },
+                                        )
+                                    except ValueError as e:
+                                        self._hetero.revert(h_plan)
+                                        rec.event(
+                                            "hetero_reassign_rejected",
+                                            kind="hetero",
+                                            trace_id=self.trace_id,
+                                            attrs={"step": step, "error": str(e)},
+                                        )
 
-                alerts = self.monitor.ingest(
-                    TrainingMetrics(
-                        step=step,
-                        loss=host["loss"],
-                        learning_rate=host["learning_rate"],
-                        gradient_norm=host["grad_norm"],
-                        throughput_tokens_per_sec=self.tokens_per_sec,
+                with prof.phase("monitor", step=it):
+                    alerts = self.monitor.ingest(
+                        TrainingMetrics(
+                            step=step,
+                            loss=host["loss"],
+                            learning_rate=host["learning_rate"],
+                            gradient_norm=host["grad_norm"],
+                            throughput_tokens_per_sec=self.tokens_per_sec,
+                        )
                     )
-                )
 
-                if step % self.config.log_every_steps == 0:
-                    self._log_metrics(
-                        kind="train", step=step, loss=host["loss"],
-                        learning_rate=host["learning_rate"],
-                        grad_norm=host["grad_norm"],
-                        tokens_per_sec=self.tokens_per_sec,
-                    )
+                    if step % self.config.log_every_steps == 0:
+                        self._log_metrics(
+                            kind="train", step=step, loss=host["loss"],
+                            learning_rate=host["learning_rate"],
+                            grad_norm=host["grad_norm"],
+                            tokens_per_sec=self.tokens_per_sec,
+                        )
 
-                critical = [a for a in alerts if a.severity == AlertSeverity.CRITICAL]
-                if critical:
-                    self._last_critical_step = step
-                    if self.auto_rollback and self.ckpt is not None:
-                        rolled = self._rollback(before_step=step)
-                        if rolled is not None:
-                            step = rolled
-                            continue
-                        if any(a.alert_type == "divergence" for a in critical):
-                            raise RuntimeError(
-                                f"diverged at step {step} with no stable checkpoint to roll back to"
-                            )
-                    elif any(a.alert_type == "divergence" for a in critical):
-                        raise RuntimeError(f"training diverged at step {step}")
+                    critical = [a for a in alerts if a.severity == AlertSeverity.CRITICAL]
+                    if critical:
+                        self._last_critical_step = step
+                        if self.auto_rollback and self.ckpt is not None:
+                            rolled = self._rollback(before_step=step)
+                            if rolled is not None:
+                                step = rolled
+                                continue
+                            if any(a.alert_type == "divergence" for a in critical):
+                                raise RuntimeError(
+                                    f"diverged at step {step} with no stable checkpoint to roll back to"
+                                )
+                        elif any(a.alert_type == "divergence" for a in critical):
+                            raise RuntimeError(f"training diverged at step {step}")
 
-                # Held-out evaluation.
-                if (
-                    self.config.eval_interval_steps
-                    and step % self.config.eval_interval_steps == 0
-                ):
-                    self._run_eval(step)
+                with prof.phase("checkpoint", step=it):
+                    # Held-out evaluation.
+                    if (
+                        self.config.eval_interval_steps
+                        and step % self.config.eval_interval_steps == 0
+                    ):
+                        self._run_eval(step)
 
-                # Periodic checkpoint + stable-pointer advancement.
-                if self.ckpt is not None:
-                    if step % self.config.checkpoint_interval_steps == 0:
-                        with self._state_lock:  # disk-overlap: saved params
-                            self._flush_state()  # must include every update
-                        self.ckpt.save(step, self._state, metrics={"loss": host["loss"]})
-                        self._note_saved_topology()
-                        self._pending_stable.append(step)
-                    self._advance_stable(step)
+                    # Periodic checkpoint + stable-pointer advancement.
+                    if self.ckpt is not None:
+                        if step % self.config.checkpoint_interval_steps == 0:
+                            with self._state_lock:  # disk-overlap: saved params
+                                self._flush_state()  # must include every update
+                            self.ckpt.save(step, self._state, metrics={"loss": host["loss"]})
+                            self._note_saved_topology()
+                            self._pending_stable.append(step)
+                        self._advance_stable(step)
+            # The last iteration has no next begin to close it.
+            attempt_step_s += prof.end_step() or 0.0
 
             # Final save + status.
             if self.ckpt is not None and self._state is not None:
@@ -1105,6 +1137,8 @@ class TrainingJob:
             self.status = JobStatus.FAILED
         finally:
             self.finished_at = time.time()
+            if self.profiler is not None:  # an iteration a failure left open
+                attempt_step_s += self.profiler.end_step() or 0.0
             if attempt_span.t1 is None:
                 attempt_span.end(
                     status=self.status.value,

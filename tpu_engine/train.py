@@ -813,23 +813,24 @@ def build_train_program(
             layer_stream=layer_stream,
             layer_constraint=layer_constraint,
         )
-        # include_aux gates the training-only regularisers (MoE aux, z-loss)
-        # so eval_step reports pure cross-entropy.
-        z_coef = cfg.z_loss_coef if include_aux else 0.0
-        if cfg.loss_chunk_size:
-            ll_sum, z_sum, n_valid = _chunked_ce_sums(
-                params, hidden, loss_tokens, model_cfg, cfg.loss_chunk_size
-            )
-        else:
-            ll_sum, z_sum, n_valid = _ce_sums(
-                tfm.unembed(params, hidden, model_cfg), loss_tokens
-            )
-        d = jnp.maximum(n_valid, 1.0) if denom is None else denom
-        loss = -ll_sum / d
-        if z_coef:
-            loss = loss + z_coef * z_sum / d
-        if model_cfg.is_moe and include_aux:
-            loss = loss + aux_weight * model_cfg.router_aux_coef * aux
+        with jax.named_scope("loss"):
+            # include_aux gates the training-only regularisers (MoE aux, z-loss)
+            # so eval_step reports pure cross-entropy.
+            z_coef = cfg.z_loss_coef if include_aux else 0.0
+            if cfg.loss_chunk_size:
+                ll_sum, z_sum, n_valid = _chunked_ce_sums(
+                    params, hidden, loss_tokens, model_cfg, cfg.loss_chunk_size
+                )
+            else:
+                ll_sum, z_sum, n_valid = _ce_sums(
+                    tfm.unembed(params, hidden, model_cfg), loss_tokens
+                )
+            d = jnp.maximum(n_valid, 1.0) if denom is None else denom
+            loss = -ll_sum / d
+            if z_coef:
+                loss = loss + z_coef * z_sum / d
+            if model_cfg.is_moe and include_aux:
+                loss = loss + aux_weight * model_cfg.router_aux_coef * aux
         return loss
 
     if use_lora:
@@ -1115,41 +1116,44 @@ def build_train_program(
         params = state["params"]
         params_g = _cast_for_grad(params)
 
-        if pipe_size > 1:
-            loss, grads = pipe_grad_fn(params_g, batch)
-            grads = _reduce_grads(grads)
-        elif compression is not None:
-            # Step-deterministic key for qgZ's stochastic rounding (and
-            # restart-reproducible: derived from seed + step, not a
-            # threaded RNG state).
-            qkey = jax.random.fold_in(
-                jax.random.PRNGKey(cfg.seed), state["step"]
-            )
-            loss, grads = compression.accumulate(
-                params_g, state.get("hpz"), batch, qkey
-            )
-        else:
-            loss, grads = accumulate_grads(
-                grad_fn, _reduce_grads, params_g, params, batch, grad_sh
-            )
-        grad_norm = optax.global_norm(grads)
+        # named_scope: metadata for profiles only, nothing computed changes.
+        with jax.named_scope("forward_backward"):
+            if pipe_size > 1:
+                loss, grads = pipe_grad_fn(params_g, batch)
+                grads = _reduce_grads(grads)
+            elif compression is not None:
+                # Step-deterministic key for qgZ's stochastic rounding (and
+                # restart-reproducible: derived from seed + step, not a
+                # threaded RNG state).
+                qkey = jax.random.fold_in(
+                    jax.random.PRNGKey(cfg.seed), state["step"]
+                )
+                loss, grads = compression.accumulate(
+                    params_g, state.get("hpz"), batch, qkey
+                )
+            else:
+                loss, grads = accumulate_grads(
+                    grad_fn, _reduce_grads, params_g, params, batch, grad_sh
+                )
+        with jax.named_scope("optimizer"):
+            grad_norm = optax.global_norm(grads)
 
-        # Offloaded subtrees stream through device memory for the update
-        # math (the per-device transient is the 1/N shard — reference
-        # "streamed to device inside the update", ``deepspeed_launcher.py:
-        # 197-203``) and are placed back in pinned host memory explicitly,
-        # so the step's out-shardings see already-host-resident values.
-        opt_in = state["opt_state"]
-        if opt_memory_kind is not None:
-            opt_in = jax.tree.map(jax.device_put, opt_in, _device_kinds(opt_sh_tree))
-        params_upd = params
-        if offload_params:
-            params_upd = jax.tree.map(jax.device_put, params, _device_kinds(param_sh))
+            # Offloaded subtrees stream through device memory for the update
+            # math (the per-device transient is the 1/N shard — reference
+            # "streamed to device inside the update", ``deepspeed_launcher.py:
+            # 197-203``) and are placed back in pinned host memory explicitly,
+            # so the step's out-shardings see already-host-resident values.
+            opt_in = state["opt_state"]
+            if opt_memory_kind is not None:
+                opt_in = jax.tree.map(jax.device_put, opt_in, _device_kinds(opt_sh_tree))
+            params_upd = params
+            if offload_params:
+                params_upd = jax.tree.map(jax.device_put, params, _device_kinds(param_sh))
 
-        lr = schedule(state["step"]).astype(jnp.float32) * state["lr_scale"]
-        updates, new_opt_state = tx.update(grads, opt_in, params_upd)
-        updates = jax.tree.map(lambda u: (-lr * u).astype(u.dtype), updates)
-        new_params = optax.apply_updates(params_upd, updates)
+            lr = schedule(state["step"]).astype(jnp.float32) * state["lr_scale"]
+            updates, new_opt_state = tx.update(grads, opt_in, params_upd)
+            updates = jax.tree.map(lambda u: (-lr * u).astype(u.dtype), updates)
+            new_params = optax.apply_updates(params_upd, updates)
         new_state = {
             "params": new_params,
             "opt_state": new_opt_state,
