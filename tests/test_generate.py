@@ -50,7 +50,8 @@ def test_prefill_then_decode_matches_forward():
 
 
 def test_decode_gqa_model():
-    # A GQA variant (KV heads < heads) exercises the cache repeat path.
+    # A GQA variant (KV heads < heads): decode contracts the grouped query
+    # heads (KV-major, head h on KV head h // G) against the cache as stored.
     cfg, params, tokens = _setup()
     cfg = cfg.with_(n_kv_heads=cfg.n_heads // 2)
     params = tfm.init_params(jax.random.PRNGKey(3), cfg)

@@ -931,3 +931,132 @@ def test_prefix_cache_rejects_oversized_entry():
     # budget keeps evicting correctly afterwards.
     c.insert(tuple(range(300, 396)), _E(96))
     assert c.tokens == 96 and len(c._entries) == 1
+
+
+# --- decode attention: the grouped contraction against the cache as stored ---
+
+def _repeat_reference_block(x, lp, k_cache, v_cache, write, slot_pos, positions,
+                            cfg, k_scale_c, v_scale_c):
+    """``generate._decode_block`` for a dense llama-family layer with GQA done
+    the plain way: keys and values repeated ``H // KV`` times along the head
+    axis, then one contraction per query head. Everything around the
+    attention calls the same helpers as the block, so a difference between
+    the two is the attention's."""
+    from tpu_engine.generate import _NEG_INF, _quantize_rows
+
+    B, T, _ = x.shape
+    H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    h = tfm._norm(x, lp["attn_norm"], cfg)
+    q = tfm._rope(tfm._proj(h, lp["q"]["kernel"]).reshape(B, T, H, HD),
+                  positions, cfg.rope_theta)
+    k = tfm._rope(tfm._proj(h, lp["k"]["kernel"]).reshape(B, T, KV, HD),
+                  positions, cfg.rope_theta)
+    v = tfm._proj(h, lp["v"]["kernel"]).reshape(B, T, KV, HD)
+    if k_scale_c is not None:
+        (k, k_s), (v, v_s) = _quantize_rows(k), _quantize_rows(v)
+        k_scale_c, v_scale_c = write(k_scale_c, k_s), write(v_scale_c, v_s)
+    k_cache, v_cache = write(k_cache, k), write(v_cache, v)
+    kc, vc = k_cache, v_cache
+    if k_scale_c is not None:
+        kc = kc.astype(x.dtype) * k_scale_c.astype(x.dtype)
+        vc = vc.astype(x.dtype) * v_scale_c.astype(x.dtype)
+    kc = jnp.repeat(kc, H // KV, axis=2)            # [B, M, H, HD]
+    vc = jnp.repeat(vc, H // KV, axis=2)
+    scores = jnp.einsum("bthd,bmhd->bhtm", q, kc,
+                        preferred_element_type=jnp.float32) / (HD ** 0.5)
+    kp = (slot_pos if slot_pos.ndim == 2 else slot_pos[None, :])[:, None, :]
+    mask = (kp >= 0) & (kp <= positions[:, :, None])
+    if cfg.sliding_window:
+        mask &= kp > positions[:, :, None] - cfg.sliding_window
+    scores = jnp.where(mask[:, None], scores, _NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+    attn = jnp.einsum("bhtm,bmhd->bthd", probs, vc).reshape(B, T, H * HD)
+    x = x + tfm._proj(attn, lp["o"]["kernel"])
+    x = x + tfm._dense_mlp(tfm._norm(x, lp["mlp_norm"], cfg), lp, cfg=cfg)
+    return x, k_cache, v_cache, k_scale_c, v_scale_c
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["kv16", "kv8"])
+@pytest.mark.parametrize("window", [0, 4], ids=["full", "win4"])
+@pytest.mark.parametrize("rank", [1, 2], ids=["lockstep", "per_row"])
+@pytest.mark.parametrize("T", [1, 5])
+@pytest.mark.parametrize("G", [1, 2, 4])
+def test_decode_block_grouped_attention_matches_repeat(G, T, rank, window,
+                                                       kv_quant, dtype):
+    """The block contracts the grouped queries against the cache as stored
+    (KV-major: query head h reads KV head h // G). Against the explicit
+    G-fold repeat it must agree for MHA (G = 1) and GQA alike, for a decode
+    token and a chunk, with lockstep ([M]) and per-row ([B, M]) slot
+    positions, under a sliding window, and through an int8 cache."""
+    from tpu_engine.generate import _decode_block, _quantize_rows
+
+    B, M, H = 2, 16, 4
+    cfg = tfm.MODEL_CONFIGS["gpt-tiny"].with_(
+        n_kv_heads=H // G, sliding_window=window, n_layers=1)
+    KV, HD = cfg.n_kv_heads, cfg.head_dim
+    params = tfm.init_params(jax.random.PRNGKey(G), cfg, dtype=jnp.float32)
+    lp = jax.tree.map(lambda a: a[0].astype(dtype), params["layers"])
+    ks = jax.random.split(jax.random.PRNGKey(7 * G + T), 3)
+    x = jax.random.normal(ks[0], (B, T, cfg.d_model), dtype)
+    k_cache = jax.random.normal(ks[1], (B, M, KV, HD), jnp.float32)
+    v_cache = jax.random.normal(ks[2], (B, M, KV, HD), jnp.float32)
+    if kv_quant:
+        (k_cache, k_s), (v_cache, v_s) = _quantize_rows(k_cache), _quantize_rows(v_cache)
+        k_cache, v_cache = k_cache.astype(jnp.int8), v_cache.astype(jnp.int8)
+    else:
+        k_cache, v_cache, k_s, v_s = k_cache.astype(dtype), v_cache.astype(dtype), None, None
+    steps = jnp.arange(T, dtype=jnp.int32)
+    if rank == 1:  # generate(): all rows at one length, slot_pos [M]
+        length = 6
+        positions = jnp.broadcast_to(length + steps[None, :], (B, T))
+        slot_pos = jnp.where(jnp.arange(M) < length + T, jnp.arange(M), -1)
+
+        def write(arr, rows):
+            return jax.lax.dynamic_update_slice(arr, rows.astype(arr.dtype),
+                                                (0, length, 0, 0))
+    else:          # the slot pool: each row at its own length, slot_pos [B, M]
+        positions = jnp.asarray([7, 3], jnp.int32)[:, None] + steps[None, :]
+        slot_pos = jnp.broadcast_to(jnp.arange(M, dtype=jnp.int32)[None, :], (B, M))
+
+        def write(arr, rows):
+            return arr.at[jnp.arange(B)[:, None], positions].set(rows.astype(arr.dtype))
+
+    args = (x, lp, k_cache, v_cache, write, slot_pos, positions, cfg)
+    got = _decode_block(*args, k_scale_c=k_s, v_scale_c=v_s)
+    want = _repeat_reference_block(*args, k_s, v_s)
+    for g, w in zip(got[1:], want[1:]):          # the caches: the same writes
+        assert (g is None and w is None) or np.array_equal(np.asarray(g), np.asarray(w))
+    g, w = np.asarray(got[0], np.float32), np.asarray(want[0], np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(g, w, atol=2e-4, rtol=2e-4)
+    else:  # the int8-cache tests' tolerance: 2 % of the largest value
+        assert np.max(np.abs(g - w)) < 0.02 * np.max(np.abs(w))
+
+
+def test_decode_step_never_expands_the_pool():
+    """A G = 4 decode step holds no intermediate with a lane axis and as many
+    elements as a pool layer expanded to the query heads (B·M·H·HD): the
+    G-fold copy of the keys or values cannot come back unnoticed on CPU."""
+    from tpu_engine.serving import decode_step
+
+    B, M = 3, 48
+    cfg = tfm.MODEL_CONFIGS["gpt-tiny"].with_(n_kv_heads=1)
+    H, HD = cfg.n_heads, cfg.head_dim
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
+    cache = init_slot_cache(cfg, B, M, jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda p, t, c, a: decode_step(p, t, c, a, cfg, jnp.float32)
+    )(params, jnp.zeros((B,), jnp.int32), cache, jnp.ones((B,), bool))
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            yield from (v.aval for v in eqn.outvars)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    avals = [a for a in walk(jaxpr.jaxpr) if hasattr(a, "shape")]
+    scores = [a for a in avals if a.shape[-1:] == (M,) and a.size == B * H * M]
+    assert scores, "the walk did not reach the attention inside the layer scan"
+    expanded = [a for a in avals if M in a.shape and a.size >= B * M * H * HD]
+    assert not expanded, expanded

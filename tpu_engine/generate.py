@@ -188,7 +188,13 @@ def _quantize_rows(rows: jax.Array) -> tuple[jax.Array, jax.Array]:
 
 def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
                   cfg: ModelConfig, k_scale_c=None, v_scale_c=None):
-    """One transformer block attending against the cache.
+    """One transformer block attending against the cache as stored.
+
+    Attention contracts the query heads, grouped by the KV head they share,
+    straight against ``k_cache`` / ``v_cache``: ``H = KV × G`` KV-major —
+    query head ``h`` reads KV head ``h // G``, the order training's repeat
+    implies — so the G-fold keys and values are never built, and MHA is the
+    case ``G = 1`` of the same two contractions.
 
     x: [B, T, D] new activations; k_cache/v_cache: [B, M, KV, HD];
     ``write(cache_arr, rows)`` stores the chunk's rows at its slots (built
@@ -242,13 +248,10 @@ def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
                 vc = v_cache.astype(x.dtype) * v_scale_c.astype(x.dtype)
             else:
                 kc, vc = k_cache, v_cache
-            if KV != H:  # GQA
-                kc = jnp.repeat(kc, H // KV, axis=2)
-                vc = jnp.repeat(vc, H // KV, axis=2)
-
+            qg = q.reshape(B, T, KV, H // KV, HD)  # KV-major groups
             scale = 1.0 / (HD ** 0.5)
             scores = jnp.einsum(
-                "bthd,bmhd->bhtm", q, kc, preferred_element_type=jnp.float32
+                "btkgd,bmkd->bkgtm", qg, kc, preferred_element_type=jnp.float32
             ) * scale
             # Slot m is visible to query t iff it holds a real position (≥ 0)
             # that is ≤ the query's global position (causal). Sliding-window
@@ -261,9 +264,9 @@ def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
             mask = (kp >= 0) & (kp <= positions[:, :, None])
             if cfg.sliding_window:
                 mask &= kp > positions[:, :, None] - cfg.sliding_window
-            scores = jnp.where(mask[:, None, :, :], scores, _NEG_INF)
+            scores = jnp.where(mask[:, None, None, :, :], scores, _NEG_INF)
             probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(x.dtype)
-            attn = jnp.einsum("bhtm,bmhd->bthd", probs, vc).reshape(B, T, H * HD)
+            attn = jnp.einsum("bkgtm,bmkd->btkgd", probs, vc).reshape(B, T, H * HD)
         x = x + proj(attn, "o")
 
     h = _norm(x, layer_params["mlp_norm"], cfg)
