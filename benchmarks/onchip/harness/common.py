@@ -7,6 +7,7 @@ import gc
 import glob
 import json
 import os
+import sys
 import time
 
 from .manifest import BENCH_DIR, load_reader
@@ -164,7 +165,8 @@ def assemble(run: dict, args, trace, correct: bool, attempted: int, failed: int,
              end_to_end: dict, rehearsal_counts: dict) -> dict:
     """The result line: the cell's end-to-end metrics (``--trace 0``) or its
     per-layer metrics, read by their own readers from ``run`` and the reduced
-    trace (``--trace 1``); the rehearsal reports counts and no device metric."""
+    trace (``--trace 1``); the rehearsal reports counts and no device metric.
+    ``rehearsal_counts["compared"]`` are the rows of ``check``'s comparison."""
     cell, chips = run["cell"], run["chips"]
     result = {"correct": bool(correct), "attempted": attempted, "failed": failed}
     device = dict(run["device"], memory_peak_bytes=run["peak_bytes"])
@@ -182,12 +184,17 @@ def assemble(run: dict, args, trace, correct: bool, attempted: int, failed: int,
         result["metrics"] = {m["name"]: {"value": end_to_end[m["name"]], "unit": m["unit"]}
                              for m in cell["end_to_end"]}
     result["device"] = device
+    # Last in the line: every number compared, beside its limit (inf: nothing was there to compare).
+    result["compared"] = {r["number"]: {"value": min(r["value"], sys.float_info.max), "limit": r["limit"]}
+                          for r in rehearsal_counts["compared"]}
     return result
 
 
 def print_result(result: dict) -> None:
-    """The contract's one line, last on standard output."""
-    import sys
-
+    """The numbers compared as the last lines of standard error, then the
+    contract's one line, last on standard output."""
     sys.stdout.flush()
+    for name, c in result["compared"].items():
+        print(f"compared {name} {c['value']!r} limit {c['limit']!r}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)

@@ -1,6 +1,8 @@
 """BENCHMARK.json and the files it names. Nothing here knows a cell's name:
 a cell is found by the ``--workload`` argument, its configuration and traffic
-by the names the cell gives, a per-layer metric's reader by the metric's name.
+by the names the cell gives, a per-layer metric's reader by the metric's name,
+a configuration's family by the ``model_type`` its file publishes and its
+reference by the file's ``reference``.
 """
 
 from __future__ import annotations
@@ -15,6 +17,12 @@ ROOT = os.path.dirname(os.path.dirname(BENCH_DIR))
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 TRAFFIC_SUFFIXES = (".json", ".jsonl", ".toml", ".txt", ".csv")
+WIDTH_RE = re.compile(r"(_dim|_rank|hidden_size|intermediate_size|per_tok)$")
+
+
+def names_a_width(key: str) -> bool:
+    """A key ``reduced`` may never name: depth and scale are cut, widths are not."""
+    return bool(WIDTH_RE.search(key))
 
 
 def load_manifest(root: str = ROOT) -> dict:
@@ -81,10 +89,10 @@ def load_reader(name: str):
 
 def load_by_name(package_dir: str, name: str):
     """A module found by name in a directory of the benchmark (a generator, a
-    reference family)."""
+    reference, a family)."""
     path = os.path.join(BENCH_DIR, package_dir, name + ".py")
     if not os.path.isfile(path):
-        raise FileNotFoundError(f"{package_dir}/{name}.py does not exist")
+        raise FileNotFoundError(f"{path} does not exist")
     pkg = package_dir.replace("/", ".")
     return importlib.import_module(f"{pkg}.{name}")
 
@@ -119,8 +127,12 @@ def lint(manifest: dict, root: str = ROOT) -> list[str]:
             bad.append(f"config {c['name']}: keys {sorted(c)}")
         if not under(c["file"]) or not os.path.isfile(os.path.join(root, c["file"])):
             bad.append(f"config {c['name']}: file {c['file']}")
+        else:
+            family = str(_read_json(os.path.join(root, c["file"])).get("model_type"))
+            if not os.path.isfile(os.path.join(BENCH_DIR, "families", family + ".py")):
+                bad.append(f"config {c['name']}: model_type {family!r} has no file under families/")
         for k in c["reduced"]:
-            if not NAME_RE.match(k) or re.search(r"(_dim|_rank|hidden_size|intermediate_size|per_tok)$", k):
+            if not NAME_RE.match(k) or names_a_width(k):
                 bad.append(f"config {c['name']}: reduced names a width: {k}")
     if len({c["file"] for c in manifest["configs"]}) != len(cfgs):
         bad.append("two configurations share a file")

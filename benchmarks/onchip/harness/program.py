@@ -3,7 +3,8 @@ TO ``tpu_engine`` is here, so a reader can see that it is only the system
 under test, its spans and its counters.
 
 - a configuration file becomes a ``ModelConfig`` registered under the
-  configuration's name (published widths, the depth the file states);
+  configuration's name, through the family file its ``model_type`` names
+  (``families/<model_type>.py``: published widths, the depth the file states);
 - the worker's start-up sequence, as ``chip_smoke.start_up`` runs it, with the
   compile cache pointed inside the checkout;
 - engine-side token and dispatch timestamps, after
@@ -19,7 +20,7 @@ import contextlib
 import os
 import time
 
-from .manifest import BENCH_DIR
+from .manifest import BENCH_DIR, load_by_name
 
 CACHE_DIR = os.path.join(BENCH_DIR, ".cache")
 MAX_SEED = 2**32 - 5
@@ -40,30 +41,16 @@ def prepare_environment() -> None:
 
 
 def model_config(config: dict, name: str):
-    """Register the configuration file's sizes as a program ``ModelConfig``."""
+    """The configuration file as a program ``ModelConfig``, registered under
+    the configuration's name. Which published keys become which fields is the
+    family's business: ``families/<model_type>.py``, found by the file's own
+    published ``model_type``. Nothing here knows a family."""
     from tpu_engine.models import transformer as tfm
 
-    heads = config["num_attention_heads"]
-    head_dim = config.get("head_dim") or config["hidden_size"] // heads
-    mc = tfm.ModelConfig(
-        name=name,
-        arch="llama",
-        vocab_size=config["vocab_size"],
-        d_model=config["hidden_size"],
-        n_layers=config["num_hidden_layers"],
-        n_heads=heads,
-        n_kv_heads=config["num_key_value_heads"],
-        d_ff=config["intermediate_size"],
-        max_seq_len=config["max_position_embeddings"],
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        sliding_window=int(config.get("sliding_window") or 0),
-        n_experts=int(config.get("num_local_experts") or 0),
-        top_k=int(config.get("num_experts_per_tok") or 2),
-        head_dim_override=0 if head_dim * heads == config["hidden_size"] else head_dim,
-    )
-    if config.get("tie_word_embeddings"):
-        raise ValueError("a tied head is not this family's recipe")
+    family = load_by_name("families", config["model_type"])
+    mc = family.model_config(config, name)
+    if mc.name != name:
+        raise ValueError(f"families/{config['model_type']}.py named its ModelConfig {mc.name!r}, not {name!r}")
     tfm.MODEL_CONFIGS[name] = mc
     return mc
 

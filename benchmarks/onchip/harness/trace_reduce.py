@@ -4,14 +4,18 @@ needs the image's xprof converter; this does not).
 
 A TPU chip's plane is ``/device:TPU:<n>``; its line ``XLA Ops`` holds one
 event per executed HLO operation, serial on the core, and ``XLA Modules`` one
-per program run. Host threads are lines of ``/host:CPU``; the harness's own
-spans there are named ``onchip.<what>``.
+per program run. Host threads are lines of ``/host:CPU``; the program's phase
+clock holds a ``tpu_engine.<loop>.<phase>`` annotation there around every
+phase of its loops, and the harness's own spans are named ``onchip.<what>``.
 
 - busy: the union of the op intervals of a chip; idle share is 1 - busy over
   the traced window (first to last event on any chip). Averaged over chips.
 - a kernel's time: the sum of its events' durations (``tpu_custom_call``).
-- idle gaps: the longest complements of busy, each named by the harness span
-  that covers its middle on the host (else ``host``).
+- idle gaps: the longest complements of busy, each named by the innermost
+  program phase that covers its middle on the host (``supervisor.health``,
+  ``batcher.admit``); where no phase does, by the innermost harness span
+  (``client.wait``), else ``host``. The phases are the loop's that feeds the
+  chip; a harness span on another thread is merely concurrent.
 """
 
 from __future__ import annotations
@@ -21,7 +25,7 @@ from collections import defaultdict
 
 KERNEL = re.compile(r"custom-call|custom_call|pallas|mosaic", re.I)
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
-SPAN_PREFIX = "onchip."
+SPAN_PREFIXES = ("tpu_engine.", "onchip.")  # the program's phases first, then the harness's spans
 
 
 def _union(intervals):
@@ -141,14 +145,13 @@ def _host_spans(data):
             continue
         for ln in plane.lines:
             for ev in ln.events:
-                if ev.name.startswith(SPAN_PREFIX):
-                    spans.append((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name[len(SPAN_PREFIX):]))
+                rank = next((i for i, p in enumerate(SPAN_PREFIXES) if ev.name.startswith(p)), None)
+                if rank is not None:
+                    spans.append((ev.start_ns, ev.start_ns + ev.duration_ns,
+                                  ev.name[len(SPAN_PREFIXES[rank]):], rank))
     return spans
 
 
 def _covering(spans, t) -> str:
-    best = None
-    for a, b, name in spans:
-        if a <= t <= b and (best is None or b - a < best[0]):
-            best = (b - a, name)
-    return best[1] if best else "host"
+    covering = [(rank, b - a, name) for a, b, name, rank in spans if a <= t <= b]
+    return min(covering)[2] if covering else "host"

@@ -218,7 +218,9 @@ def run(cell: dict, args, t_process_start: float) -> dict:
         correct = False
     rows.append({"number": "programs_lowered_in_window", "value": float(window_compiles["lowered"]),
                  "limit": 0.0, "ok": window_compiles["lowered"] == 0})
-    print(json.dumps({"compared": rows, "reference_s": ref_s, "reference_split_s": ref_out.get("split_s")}),
+    print(json.dumps({"reference_s": ref_s, "reference_split_s": ref_out.get("split_s"),
+                      "worst_leaf": {r["number"]: r["leaf"] for r in rows if "leaf" in r},
+                      "loss_program_reference": [[r["program"], r["reference"]] for r in rows if "program" in r]}),
           flush=True)
 
     run = {

@@ -94,6 +94,10 @@ def test_serving_cell_sound_run_is_correct(monkeypatch):
     assert res["correct"] is True, res
     assert res["metrics"] == {} and res["failed"] == 0 and res["attempted"] >= 8
     assert _numbers(res)["served_logit_gap_max"]["tokens_compared"] >= 20
+    # every number compared sits beside its limit under the line's last key
+    assert list(res)[-1] == "compared" and set(res["compared"]) == set(_numbers(res))
+    assert res["compared"]["served_logit_gap_mean"] == {"value": _numbers(res)["served_logit_gap_mean"]["value"],
+                                                        "limit": 0.001}
 
 
 def test_moe_closed_loop_cell_runs_and_every_slot_is_used(monkeypatch):

@@ -44,17 +44,20 @@ def test_lint_refuses(man, breakage):
 def test_every_cell_loads_and_every_reader_exists(man):
     for w in man["workloads"]:
         cell = manifest.load_cell(man, w["name"])
-        assert cell["config"]["hidden_size"] == 4096 and cell["config"]["intermediate_size"] == 14336
+        # A cell's widths are whatever its file publishes; what is cut is never a width.
+        assert cell["config"]["reduced"] == cell["config_entry"]["reduced"]
+        assert not [k for k in cell["config"]["reduced"] if manifest.names_a_width(k)]
         assert {m["name"] for m in cell["end_to_end"]} >= {"setup_s"}
         for m in cell["per_layer"]:
             assert callable(manifest.load_reader(m["name"]))
         assert manifest.load_by_name("harness/generators", cell["traffic"]["generator"]).KIND in ("train", "serve")
         assert hasattr(manifest.load_by_name("reference", cell["config"]["reference"]), "init_params")
+        assert callable(manifest.load_by_name("families", cell["config"]["model_type"]).model_config)
 
 
 def test_a_dummy_cell_is_files_and_entries_only(man, tmp_path, monkeypatch):
     bench = tmp_path / "benchmarks" / "onchip"
-    for d in ("configs", "traffic", "layer_metrics"):
+    for d in ("configs", "traffic", "layer_metrics", "families"):
         shutil.copytree(os.path.join(manifest.BENCH_DIR, d), bench / d)
     cfg = json.load(open(bench / "configs" / "mistral-7b-1chip-train.json"))
     cfg["num_hidden_layers"] = 1

@@ -61,6 +61,25 @@ def test_known_idle_kernel_and_collective_time():
     assert len(red["breakdown"]["device_ops"]) <= 10 and len(gaps) <= 10
 
 
+def test_an_idle_gap_takes_the_programs_innermost_phase_before_a_harness_span():
+    """``tpu_engine.<loop>.<phase>`` annotations nest inside the harness's
+    ``onchip.<loop>.step`` and inside the loop's own ``other``, which spans the
+    whole iteration; the main thread's ``onchip.client.wait`` is shorter than
+    either and merely concurrent."""
+    ops = [("fusion.1", 0, 40), ("fusion.1", 70, 30),      # 30 ms idle, middle at 55
+           ("fusion.1", 120, 20),                          # 20 ms idle, middle at 110
+           ("fusion.1", 150, 10)]                          # 10 ms idle, middle at 145
+    red = _reduce({
+        "/device:TPU:0": {"XLA Ops": ops},
+        "/host:CPU": {"engine": [("onchip.batcher.step", 35, 100), ("tpu_engine.batcher.other", 36, 98),
+                                 ("tpu_engine.batcher.admit", 45, 25)],
+                      "main": [("onchip.client.wait", 50, 10), ("onchip.client.wait", 141, 8)]},
+    })
+    assert red["breakdown"]["idle_gaps"] == [["batcher.admit", pytest.approx(0.030)],
+                                             ["batcher.other", pytest.approx(0.020)],
+                                             ["client.wait", pytest.approx(0.010)]]
+
+
 def test_busy_is_averaged_over_the_chips_used():
     mk = lambda busy: {"XLA Ops": [("fusion.1", 0, busy), ("fusion.1", 90, 10)]}  # noqa: E731
     red = _reduce({"/device:TPU:0": mk(50), "/device:TPU:1": mk(30), "/device:TPU:2": mk(90),
