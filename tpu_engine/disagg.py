@@ -137,10 +137,14 @@ def extract_slot_kv(
 
     An already-quantized pool always ships codes + scales (dequantizing
     on extraction would add error AND bytes); a fp pool quantizes on the
-    wire only when asked.
+    wire only when asked. The wire carries keys and values and nothing
+    else: a stack with recurrent layers is refused by name.
     """
     import jax.numpy as jnp  # local: keep module import engine-free
 
+    from tpu_engine.models.transformer import refuse_recurrent
+
+    refuse_recurrent(cfg, "the KV handoff wire (extract_slot_kv)")
     if getattr(cache, "ring", False):
         raise ValueError("extract_slot_kv does not support ring pools")
     k = cache.k[:, slot, :length]          # [L, T, KV, HD] device
@@ -335,6 +339,11 @@ class DisaggServingFleet:
         prefill_fault_injector: Optional[Any] = None,
         decode_fault_injector: Optional[Any] = None,
     ):
+        from tpu_engine.models.transformer import refuse_recurrent_model
+
+        for spec in (prefill_spec, decode_spec):
+            refuse_recurrent_model(
+                spec.model_name, "disaggregated serving (the KV handoff wire)")
         inflight = prefill_spec.inflight_handoffs or prefill_spec.max_slots
         prefill_spec = prefill_spec.model_copy(update={
             "pool_role": "prefill",

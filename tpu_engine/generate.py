@@ -40,10 +40,14 @@ from tpu_engine.models.transformer import (
     _dense_mlp,
     _norm,
     _proj,
+    _residual,
     _rms_norm,
     _rope,
+    attention_scale,
     cast_layer_stack,
+    check_hybrid,
     embed_tokens,
+    refuse_recurrent,
     unembed,
 )
 from tpu_engine.quant import QuantWeight, dequantize_weight
@@ -76,6 +80,13 @@ class KVCache:
     # scales); dequantisation fuses into the attention reads.
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
+    # Hybrid stacks: k/v cover the ATTENTION layers only ([L_attn, ...]) and
+    # the Mamba-2 layers carry a recurrent state instead — ``ssm``
+    # [L_ssm, B, heads, head_dim, state] float32, the state after the last
+    # REAL token fed, and ``conv`` [L_ssm, B, taps-1, conv_dim], the last
+    # taps-1 convolution inputs before it. Neither has a position to mask.
+    ssm: Optional[jax.Array] = None
+    conv: Optional[jax.Array] = None
 
     @property
     def max_len(self) -> int:
@@ -114,10 +125,14 @@ def init_cache(
     ``kv_quant=True`` stores k/v as int8 with per-(slot, kv-head) scales —
     half the cache HBM of bf16, at ~1% quantisation error (symmetric
     absmax over head_dim)."""
+    check_hybrid(cfg)
+    if kv_quant:
+        refuse_recurrent(cfg, "an int8 KV cache (kv_quant)")
     slots = ring_lanes(cfg, max_len, max_chunk)
-    shape = (cfg.n_layers, batch, slots, cfg.n_kv_heads, cfg.head_dim)
+    shape = (cfg.n_attn_layers, batch, slots, cfg.n_kv_heads, cfg.head_dim)
     store_dtype = jnp.int8 if kv_quant else dtype
     scale_shape = shape[:-1] + (1,)
+    ssm, conv = init_recurrent_state(cfg, batch, dtype)
     return KVCache(
         k=jnp.zeros(shape, store_dtype),
         v=jnp.zeros(shape, store_dtype),
@@ -126,6 +141,24 @@ def init_cache(
         ring=slots < max_len,
         k_scale=jnp.zeros(scale_shape, jnp.float32) if kv_quant else None,
         v_scale=jnp.zeros(scale_shape, jnp.float32) if kv_quant else None,
+        ssm=ssm, conv=conv,
+    )
+
+
+def init_recurrent_state(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16):
+    """``(ssm, conv)`` zeros for ``batch`` rows of a hybrid stack's Mamba-2
+    layers — the SSM state float32 (it integrates hundreds of small updates),
+    the convolution inputs in the compute dtype — or ``(None, None)``. THE
+    one place their shapes are written: the single-row ingestion cache and
+    the serving pool both allocate here, and the slot insert copies one into
+    the other."""
+    if not cfg.is_hybrid:
+        return None, None
+    Ls = cfg.n_ssm_layers
+    return (
+        jnp.zeros((Ls, batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+                  jnp.float32),
+        jnp.zeros((Ls, batch, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype),
     )
 
 
@@ -187,7 +220,7 @@ def _quantize_rows(rows: jax.Array) -> tuple[jax.Array, jax.Array]:
 
 
 def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
-                  cfg: ModelConfig, k_scale_c=None, v_scale_c=None):
+                  cfg: ModelConfig, k_scale_c=None, v_scale_c=None, read=None):
     """One transformer block attending against the cache as stored.
 
     Attention contracts the query heads, grouped by the KV head they share,
@@ -206,6 +239,9 @@ def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
     ``k_scale_c``/``v_scale_c`` [B, M, KV, 1] are present for int8 caches:
     new rows are quantised before the write and the cache reads dequantise
     (the convert+mul fuses into the attention dots).
+    ``read`` (hybrid stacks): ``k_cache`` / ``v_cache`` are then whatever
+    ``write`` takes and returns — the whole per-kind pool, written at this
+    layer's rows only — and ``read(cache)`` gives this layer's [B, M, KV, HD].
     """
     B, T, D = x.shape
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -226,7 +262,7 @@ def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
         if cfg.arch == "qwen":  # per-head qk-norm, before RoPE (as in training)
             q = _rms_norm(q, layer_params["q_norm"]["scale"], cfg.norm_eps)
             k = _rms_norm(k, layer_params["k_norm"]["scale"], cfg.norm_eps)
-        if not gpt2:  # gpt2 adds learned positions at embed time instead
+        if cfg.rope and not gpt2:  # gpt2 adds learned positions at embed time instead
             q = _rope(q, positions, cfg.rope_theta)
             k = _rope(k, positions, cfg.rope_theta)
 
@@ -246,10 +282,12 @@ def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
             if k_scale_c is not None:
                 kc = k_cache.astype(x.dtype) * k_scale_c.astype(x.dtype)
                 vc = v_cache.astype(x.dtype) * v_scale_c.astype(x.dtype)
+            elif read is not None:
+                kc, vc = read(k_cache), read(v_cache)
             else:
                 kc, vc = k_cache, v_cache
             qg = q.reshape(B, T, KV, H // KV, HD)  # KV-major groups
-            scale = 1.0 / (HD ** 0.5)
+            scale = attention_scale(cfg)
             scores = jnp.einsum(
                 "btkgd,bmkd->bkgtm", qg, kc, preferred_element_type=jnp.float32
             ) * scale
@@ -267,15 +305,187 @@ def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
             scores = jnp.where(mask[:, None, None, :, :], scores, _NEG_INF)
             probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(x.dtype)
             attn = jnp.einsum("bkgtm,bmkd->btkgd", probs, vc).reshape(B, T, H * HD)
-        x = x + proj(attn, "o")
+        x = _residual(x, proj(attn, "o"), cfg)
 
     h = _norm(x, layer_params["mlp_norm"], cfg)
     if cfg.is_moe:
-        x = x + _moe_mlp_decode(h, layer_params, cfg)
+        x = _residual(x, _moe_mlp_decode(h, layer_params, cfg), cfg)
     else:
         with jax.named_scope("mlp"):
-            x = x + _dense_mlp(h, layer_params, cfg=cfg)
+            x = _residual(x, _dense_mlp(h, layer_params, cfg=cfg), cfg)
     return x, k_cache, v_cache, k_scale_c, v_scale_c
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2 layers (hybrid stacks)
+# ---------------------------------------------------------------------------
+
+
+def layer_slice(a: jax.Array, at) -> jax.Array:
+    """Layer ``at`` of an array stacked over a kind's layers."""
+    return lax.dynamic_index_in_dim(a, at, 0, keepdims=False)
+
+
+def _ssd_chunk(x, dt, A, Bm, Cm, h):
+    """One chunk of the selective scan in its chunked (SSD) form.
+
+    x [B,Q,H,P]; dt [B,Q,H] float32, 0 where a position must leave the state
+    as it was; A [H] (negative); Bm, Cm [B,Q,N] (one group, shared by the
+    heads); h [B,H,P,N] float32, the state entering. Returns (y [B,Q,H,P]
+    float32 without the skip term, the state leaving).
+
+    Inside the chunk ``Y = (L o C B^T)(dt x) + diag(exp(cumsum dt A)) C h``
+    with ``L[t,s] = exp(sum_{s<r<=t} dt_r A)`` for s <= t: two matmuls and
+    a masked decay instead of Q sequential updates. Decays, their cumulative
+    sums and the state stay float32; the matmul operands are the compute
+    dtype's, accumulated in float32."""
+    cd = x.dtype
+    Q = x.shape[1]
+    cum = jnp.cumsum(dt * A, axis=1)                              # [B,Q,H] <= 0
+    seg = cum[:, :, None, :] - cum[:, None, :, :]                 # [B,t,s,H]
+    causal = jnp.tril(jnp.ones((Q, Q), bool))[None, :, :, None]
+    decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
+    cb = jnp.einsum("btn,bsn->bts", Cm, Bm, preferred_element_type=jnp.float32)
+    xdt = x.astype(jnp.float32) * dt[..., None]                   # [B,Q,H,P]
+    y = jnp.einsum("btsh,bshp->bthp", (decay * cb[..., None]).astype(cd),
+                   xdt.astype(cd), preferred_element_type=jnp.float32)
+    # What the entering state still contributes at t, read in float32.
+    y = y + jnp.exp(cum)[..., None] * jnp.einsum(
+        "btn,bhpn->bthp", Cm.astype(jnp.float32), h,
+        precision=lax.Precision.HIGHEST)
+    to_end = jnp.exp(cum[:, -1:, :] - cum)                        # [B,Q,H]
+    h = h * jnp.exp(cum[:, -1, :])[:, :, None, None] + jnp.einsum(
+        "bshp,bsn->bhpn", (xdt * to_end[..., None]).astype(cd), Bm,
+        preferred_element_type=jnp.float32)
+    return y, h
+
+
+def _ssm_mixer(u, lp, h, conv_state, valid, cfg: ModelConfig):
+    """The Mamba-2 mixer over ``u`` [B,T,D] (already normed), from and into
+    one layer's recurrent state: ``h`` [B,H,P,N] float32 and ``conv_state``
+    [B,taps-1,C]. ``valid`` [B,T] marks the real positions, a PREFIX of each
+    row (pad tokens after a prompt's end; a decode row that is not active):
+    a position that is not valid leaves both states exactly as they were —
+    ``dt = 0`` there, so the decay is 1 and nothing is added, and the
+    convolution state is taken at the row's true length. Outputs at such
+    positions are garbage the caller never reads.
+
+    T = 1 is the decode update (one recurrence step, all float32
+    elementwise: it is bound by reading and writing ``h``); longer T runs
+    the chunked form ``cfg.ssm_chunk`` positions at a time."""
+    B, T, _ = u.shape
+    H, P, N, K = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_conv
+    I, C = cfg.ssm_inner, cfg.ssm_conv_dim
+    f32 = jnp.float32
+    h = h.astype(f32)  # the recurrence runs in float32 whatever the cache stores
+
+    with jax.named_scope("ssm_in_proj"):
+        zxbcdt = _proj(u, lp["in_proj"]["kernel"])               # [B,T,I+C+H]
+        z, xbc, dt = (zxbcdt[..., :I], zxbcdt[..., I:I + C], zxbcdt[..., I + C:])
+
+    with jax.named_scope("ssm_conv"):
+        # Causal depthwise convolution over the last taps-1 inputs and the new.
+        window = jnp.concatenate([conv_state.astype(xbc.dtype), xbc], axis=1)
+        w = lp["conv"]["kernel"].astype(f32)                      # [K, C]
+        acc = lp["conv"]["bias"].astype(f32)
+        for k in range(K):
+            acc = acc + window[:, k:k + T].astype(f32) * w[k]
+        xbc = jax.nn.silu(acc).astype(u.dtype)
+        if T == 1:
+            conv_state = jnp.where(valid[:, :, None], window[:, 1:], window[:, :-1])
+        else:
+            n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
+            conv_state = jax.vmap(
+                lambda win, n: lax.dynamic_slice_in_dim(win, n, K - 1, 0)
+            )(window, n_valid)
+    x = xbc[..., :I].reshape(B, T, H, P)
+    Bm, Cm = xbc[..., I:I + N], xbc[..., I + N:]
+    dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
+    dt = jnp.where(valid[:, :, None], dt, 0.0)                    # [B,T,H]
+    A = -jnp.exp(lp["A_log"].astype(f32))                         # [H]
+
+    if T == 1:
+        with jax.named_scope("ssm_update"):
+            d1 = dt[:, 0]                                         # [B,H]
+            dbx = (d1[:, :, None] * x[:, 0].astype(f32))[..., None] \
+                * Bm[:, 0].astype(f32)[:, None, None, :]
+            h = h * jnp.exp(d1 * A)[:, :, None, None] + dbx
+            y = jnp.sum(h * Cm[:, 0].astype(f32)[:, None, None, :], axis=-1)[:, None]
+    else:
+        with jax.named_scope("ssm_scan"):
+            Q = min(cfg.ssm_chunk, T)
+            if T == Q:
+                y, h = _ssd_chunk(x, dt, A, Bm, Cm, h)
+            else:
+                n = -(-T // Q)
+
+                def chunks(a):  # [B,T,...] -> [n,B,Q,...], padded with dt = 0
+                    a = jnp.pad(a, ((0, 0), (0, n * Q - T)) + ((0, 0),) * (a.ndim - 2))
+                    return jnp.moveaxis(a.reshape(B, n, Q, *a.shape[2:]), 1, 0)
+
+                def step(h, xs):
+                    y, h = _ssd_chunk(xs[0], xs[1], A, xs[2], xs[3], h)
+                    return h, y
+
+                h, y = lax.scan(step, h, (chunks(x), chunks(dt), chunks(Bm), chunks(Cm)))
+                y = jnp.moveaxis(y, 0, 1).reshape(B, n * Q, H, P)[:, :T]
+    y = y + lp["D"].astype(f32)[:, None] * x.astype(f32)
+
+    with jax.named_scope("ssm_gate_norm"):
+        g = y.reshape(B, T, I) * jax.nn.silu(z.astype(f32))
+        g = g * lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True) + cfg.norm_eps)
+        o = (g * lp["gate_norm"]["scale"].astype(f32)).astype(u.dtype)
+    with jax.named_scope("ssm_out_proj"):
+        return _proj(o, lp["out_proj"]["kernel"]), h, conv_state
+
+
+def _ssm_block(x, layer_params, ssm, conv, at, valid, cfg: ModelConfig):
+    """One Mamba-2 layer: the mixer where an attention layer attends, then
+    the MLP both kinds share. ``ssm`` / ``conv`` are the whole per-kind state
+    ([L_ssm, B, ...]); this layer reads and rewrites its own slice, ``at``, in
+    place, under the scope of the step that does it (``ssm_update`` for one
+    token, ``ssm_scan`` for a chunk), so that a profile charges the state's
+    traffic to the mixer. Returns (x, ssm, conv)."""
+    with jax.named_scope("ssm"):
+        u = _norm(x, layer_params["ssm_norm"], cfg)
+        out, h, conv_state = _ssm_mixer(u, layer_params, layer_slice(ssm, at),
+                                        layer_slice(conv, at), valid, cfg)
+        with jax.named_scope("ssm_update" if x.shape[1] == 1 else "ssm_scan"):
+            ssm = lax.dynamic_update_index_in_dim(ssm, h.astype(ssm.dtype), at, 0)
+        with jax.named_scope("ssm_conv"):
+            conv = lax.dynamic_update_index_in_dim(conv, conv_state.astype(conv.dtype), at, 0)
+        x = _residual(x, out, cfg)
+    with jax.named_scope("mlp"):
+        x = _residual(x, _dense_mlp(_norm(x, layer_params["mlp_norm"], cfg),
+                                    layer_params, cfg=cfg), cfg)
+    return x, ssm, conv
+
+
+def scan_hybrid_layers(x, stacks, cfg: ModelConfig, state, attn_layer, ssm_layer):
+    """Walk a hybrid stack by RUNS of like layers: one ``lax.scan`` per run
+    (for 5 ssm, 1 attn, 9 ssm, 1 attn, 4 ssm: five small loops, not twenty
+    unrolled blocks), each indexing its kind's parameter stack and state at
+    ``first + i``.
+
+    ``stacks`` are the cast per-kind parameter stacks; ``state`` maps
+    "attn" / "ssm" to that kind's arrays, each with the kind's layers
+    leading. The state is CARRIED whole: ``attn_layer(x, lp, k, v, at)`` /
+    ``ssm_layer(x, lp, ssm, conv, at)`` get the kind's whole arrays and the
+    layer's index in them, write what the layer changes (one row of keys, its
+    own recurrent slice) in place, and return ``(x, *arrays)`` — a run never
+    rebuilds the rest of the pool."""
+    layer_fns = {"attn": attn_layer, "ssm": ssm_layer}
+    for kind, first, count in cfg.layer_runs():
+
+        def body(carry, i, kind=kind, first=first):
+            x, state = carry
+            at = first + i
+            lp = jax.tree.map(lambda a: layer_slice(a, at), stacks[kind])
+            x, *arrays = layer_fns[kind](x, lp, *state[kind], at)
+            return (x, {**state, kind: tuple(arrays)}), None
+
+        (x, state), _ = lax.scan(body, (x, state), jnp.arange(count, dtype=jnp.int32))
+    return x, state
 
 
 def forward_with_cache(
@@ -285,8 +495,14 @@ def forward_with_cache(
     cfg: ModelConfig,
     compute_dtype=jnp.bfloat16,
     want_logits: bool = True,
+    n_valid: Optional[jax.Array] = None,
 ) -> tuple[Optional[jax.Array], KVCache]:
     """Run ``tokens`` [B, T] through the stack against (and into) ``cache``.
+
+    ``n_valid`` (scalar, default T): how many leading tokens of the chunk are
+    real; the rest is padding. Keys and values of padding are written and
+    masked later, as ever; a hybrid stack's recurrent state has no mask, so
+    its Mamba-2 layers stop at ``n_valid``.
 
     Serves both phases: prefill (T = prompt length) and decode (T = 1).
     Returns (logits [B, T, V] fp32, updated cache with length += T).
@@ -362,6 +578,34 @@ def forward_with_cache(
     # join the scanned arrays when present (pytree structure is static per
     # trace).
     scales = (cache.k_scale, cache.v_scale) if cache.quantized else ()
+
+    if cfg.is_hybrid:
+        valid = jnp.broadcast_to(
+            jnp.arange(T)[None, :] < (T if n_valid is None else n_valid), (B, T))
+
+        def attn_layer(x, lp, k_all, v_all, at):
+            # The chunk's rows go straight into the layer's lanes of the
+            # [L_attn, B, M, KV, HD] cache (hybrids have no ring).
+            def write_at(cache_arr, rows):
+                return lax.dynamic_update_slice(
+                    cache_arr, rows[None].astype(cache_arr.dtype),
+                    (at, 0, cache.length, 0, 0))
+
+            return _decode_block(
+                x, lp, k_all, v_all, write_at, pos_new, positions, cfg,
+                read=lambda a: layer_slice(a, at))[:3]
+
+        def ssm_layer(x, lp, ssm, conv, at):
+            return _ssm_block(x, lp, ssm, conv, at, valid, cfg)
+
+        x, state = scan_hybrid_layers(
+            x, layer_stack, cfg,
+            {"attn": (cache.k, cache.v), "ssm": (cache.ssm, cache.conv)},
+            attn_layer, ssm_layer)
+        logits = unembed(params, x, cfg) if want_logits else None
+        return logits, dataclasses.replace(
+            cache, k=state["attn"][0], v=state["attn"][1], pos=pos_new,
+            length=cache.length + T, ssm=state["ssm"][0], conv=state["ssm"][1])
 
     def body(carry, xs):
         x = carry
@@ -561,6 +805,8 @@ def speculative_generate(
     ``(tokens, rounds)`` where ``rounds`` is the number of target forward
     passes taken (a perfect draft needs ceil(N / (gamma+1))).
     """
+    for c in (cfg, draft_cfg):  # the rewind is a length; a recurrent state has none
+        refuse_recurrent(c, "speculative decoding (speculative_generate)")
     if prompt.shape[0] != 1:
         raise ValueError("speculative_generate supports batch size 1")
     if gamma < 1:

@@ -148,6 +148,10 @@ class HBMEstimate(BaseModel):
     # replica's KV dtype). Zero for training jobs — their KV never outlives
     # a forward pass, so it rides the activations term.
     kv_pool_gib: float = 0.0
+    # Serving a hybrid stack: the slot pool's second kind of state, the
+    # Mamba-2 layers' SSM and convolution state of every slot (part of
+    # ``device_total_gib``, beside ``kv_pool_gib``). Zero elsewhere.
+    recurrent_state_gib: float = 0.0
     notes: list[str] = Field(default_factory=list)
 
 
@@ -460,18 +464,32 @@ def estimate_serving_hbm(
 
     # KV pool: k and v, [L, slots, lanes, KV, HD]; kv-heads shard over the
     # model axis only when divisible (serving.py falls back to replicated).
+    # Only ATTENTION layers keep keys and values (all of them, unless the
+    # model has a layer pattern).
     lanes = ring_lanes(cfg, int(max_len), int(prefill_chunk))
     kv_shard = tp if cfg.n_kv_heads % tp == 0 else 1
     if kv_shard == 1 and tp > 1:
         notes.append(f"kv pool replicated: {cfg.n_kv_heads} kv-heads !% model={tp}")
-    kv_cells = 2 * cfg.n_layers * slots * lanes * cfg.n_kv_heads * cfg.head_dim
+    kv_cells = 2 * cfg.n_attn_layers * slots * lanes * cfg.n_kv_heads * cfg.head_dim
     if kv_quant:
         # int8 codes + fp32 scale per (lane, kv-head) row of each of k/v.
-        kv_pool = kv_cells * 1 + 2 * cfg.n_layers * slots * lanes * cfg.n_kv_heads * 4
+        kv_pool = kv_cells * 1 + 2 * cfg.n_attn_layers * slots * lanes * cfg.n_kv_heads * 4
         notes.append("kv pool: int8 codes + per-(lane, kv-head) fp32 scales")
     else:
         kv_pool = kv_cells * compute_b
     kv_pool /= kv_shard
+    # A hybrid stack's recurrent layers: every slot's SSM state in float32
+    # and convolution inputs at the compute dtype, whatever the occupancy —
+    # the arrays ``generate.init_recurrent_state`` allocates, unsharded.
+    recurrent = 0.0
+    if cfg.is_hybrid:
+        recurrent = float(cfg.n_ssm_layers * slots * (
+            cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state * 4
+            + (cfg.ssm_conv - 1) * cfg.ssm_conv_dim * compute_b))
+        notes.append(
+            f"recurrent state: {cfg.n_ssm_layers} Mamba-2 layers x {slots} "
+            f"slots (float32 SSM state + convolution inputs); keys and "
+            f"values for the {cfg.n_attn_layers} attention layers only")
     if prefix_cache_tokens > 0:
         # Shared-prefix entries are extra KV lanes outside the slot pool,
         # bounded by the token budget (eviction enforces it).
@@ -532,7 +550,7 @@ def estimate_serving_hbm(
                 budget_gib=host_budget_gib,
             )
 
-    total = params_dev + kv_pool + working + logits + draft_bytes
+    total = params_dev + kv_pool + recurrent + working + logits + draft_bytes
     if device_budget_gib is not None and total > device_budget_gib * _GIB:
         raise SpecHBMOversubscribed(
             model_name, draft_model_name or "<none>",
@@ -552,5 +570,6 @@ def estimate_serving_hbm(
         device_total_gib=round(total / _GIB, 4),
         host_gib=round(host_bytes / _GIB, 4),
         kv_pool_gib=round(kv_pool / _GIB, 4),
+        recurrent_state_gib=round(recurrent / _GIB, 4),
         notes=notes,
     )

@@ -95,9 +95,80 @@ class ModelConfig:
     # Per-head dim decoupled from d_model // n_heads (Gemma: 256). 0 = derived.
     head_dim_override: int = 0
 
+    # Layer pattern: one entry per layer, "attention" or "mamba" (a Mamba-2
+    # mixer in the attention's place; the MLP follows either kind). Empty =
+    # every layer attends. A pattern with a "mamba" entry is a HYBRID stack:
+    # parameters are stacked per kind, the stack is scanned by runs of like
+    # layers (:meth:`layer_runs`), and it is served only (llama recipe,
+    # dense MLP, no window; see :func:`check_hybrid`).
+    layer_types: tuple = ()
+    # Mamba-2 widths: heads x head size is the mixer's inner width; B and C
+    # are ``ssm_state`` wide and shared by all heads (one group); the causal
+    # depthwise convolution is ``ssm_conv`` taps over x|B|C; prefill runs the
+    # chunked (SSD) form ``ssm_chunk`` tokens at a time.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    # Scalar multipliers, each absent at its default: embeddings x
+    # ``embed_scale``, every mixer and MLP output x ``residual_scale`` before
+    # it joins the residual stream, logits / ``logits_divisor``; attention
+    # scores x ``attn_scale`` (0 = 1/sqrt(head_dim)).
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logits_divisor: float = 1.0
+    attn_scale: float = 0.0
+    # ``rope=False``: no positional rotation of q and k (position comes from
+    # causality, or from the recurrent layers). ``tie_head``: the LM head is
+    # the token embedding, outside the gpt2/gemma families too.
+    rope: bool = True
+    tie_head: bool = False
+
     @property
     def head_dim(self) -> int:
         return self.head_dim_override or self.d_model // self.n_heads
+
+    @property
+    def tied_head(self) -> bool:
+        return self.tie_head or self.arch in ("gpt2", "gemma")
+
+    @property
+    def is_hybrid(self) -> bool:
+        return "mamba" in self.layer_types
+
+    @property
+    def n_ssm_layers(self) -> int:
+        return sum(t == "mamba" for t in self.layer_types)
+
+    @property
+    def n_attn_layers(self) -> int:
+        return self.n_layers - self.n_ssm_layers
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the convolution runs over: x | B | C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    def layer_runs(self) -> tuple:
+        """The pattern as runs of like layers: ``(kind, first, count)`` with
+        ``kind`` the per-kind stack ("attn" / "ssm") and ``first`` the run's
+        first index WITHIN that stack."""
+        runs: list = []
+        seen = {"attn": 0, "ssm": 0}
+        for t in self.layer_types:
+            kind = "ssm" if t == "mamba" else "attn"
+            if runs and runs[-1][0] == kind:
+                runs[-1][2] += 1
+            else:
+                runs.append([kind, seen[kind], 1])
+            seen[kind] += 1
+        return tuple(tuple(r) for r in runs)
 
     @property
     def is_moe(self) -> bool:
@@ -110,6 +181,62 @@ class ModelConfig:
 
     def with_(self, **kw) -> "ModelConfig":
         return replace(self, **kw)
+
+
+class RecurrentLayersUnsupported(NotImplementedError):
+    """A feature that assumes every layer's per-request state is keys and
+    values was asked of a model with recurrent (Mamba-2) layers. Raised by
+    name, never worked around: a recurrent state has no lanes to slice, mask
+    or rewind."""
+
+    def __init__(self, feature: str, cfg: "ModelConfig"):
+        self.feature = feature
+        super().__init__(
+            f"{feature} does not support model {cfg.name!r}: "
+            f"{cfg.n_ssm_layers} of its {cfg.n_layers} layers are recurrent "
+            "(Mamba-2), and their per-request state is not keys and values"
+        )
+
+
+def refuse_recurrent(cfg: "ModelConfig", feature: str) -> None:
+    """Raise :class:`RecurrentLayersUnsupported` for a hybrid ``cfg`` (any
+    object without the property is a geometry stand-in, never a hybrid)."""
+    if getattr(cfg, "is_hybrid", False):
+        raise RecurrentLayersUnsupported(feature, cfg)
+
+
+def refuse_recurrent_model(model_name: str, feature: str) -> None:
+    """:func:`refuse_recurrent` for a registered model's name (fleet-level
+    planes know a spec's ``model_name``, not its config); an unknown name
+    passes, as it does everywhere a fleet degrades to capacity-only."""
+    cfg = MODEL_CONFIGS.get(model_name)
+    if cfg is not None:
+        refuse_recurrent(cfg, feature)
+
+
+def check_hybrid(cfg: "ModelConfig") -> None:
+    """What a hybrid pattern can be today, checked where parameters or a
+    cache are built (trace time, free)."""
+    if not cfg.layer_types:
+        return
+    if len(cfg.layer_types) != cfg.n_layers or \
+            set(cfg.layer_types) - {"attention", "mamba"}:
+        raise ValueError(
+            f"layer_types must hold n_layers={cfg.n_layers} entries of "
+            f"'attention' or 'mamba', got {cfg.layer_types!r}"
+        )
+    if not cfg.is_hybrid:
+        return
+    if cfg.arch != "llama" or cfg.is_moe or cfg.sliding_window:
+        raise ValueError(
+            "a hybrid layer pattern needs the llama recipe with a dense MLP "
+            f"and no sliding window (arch={cfg.arch!r}, "
+            f"n_experts={cfg.n_experts}, sliding_window={cfg.sliding_window})"
+        )
+    if cfg.ssm_groups != 1:
+        raise ValueError(f"ssm_groups={cfg.ssm_groups}: only one B/C group is supported")
+    if min(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state) < 1 or cfg.ssm_conv < 2:
+        raise ValueError("a 'mamba' layer needs ssm_heads, ssm_head_dim, ssm_state >= 1 and ssm_conv >= 2")
 
 
 # Model scales matching the reference's preset names (7b/13b/70b at
@@ -205,6 +332,9 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
 def init_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> dict[str, Any]:
     """Initialise parameters (normal(0.02); residual-out projections scaled
     by 1/sqrt(2·n_layers), GPT-2 style)."""
+    check_hybrid(cfg)
+    if cfg.is_hybrid:
+        return _init_hybrid_params(rng, cfg, dtype)
     k_embed, k_q, k_k, k_v, k_o, k_gate, k_up, k_down, k_head = jax.random.split(rng, 9)
     L, D, V, F = cfg.n_layers, cfg.d_model, cfg.vocab_size, cfg.d_ff
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -274,13 +404,121 @@ def init_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> dict[str
         "layers": layers,
         "final_norm": {"scale": norm_init((D,), dtype)},
     }
-    if not gemma:
+    if not cfg.tied_head:
         out["lm_head"] = {"kernel": norm(k_head, (D, V), std)}
     return out
 
 
+def _init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype) -> dict[str, Any]:
+    """A hybrid stack's parameters, stacked PER KIND: ``layers["attn"]`` holds
+    the ``n_attn_layers`` attention layers (the llama leaves) and
+    ``layers["ssm"]`` the ``n_ssm_layers`` Mamba-2 layers, each with its own
+    MLP, in the order the pattern meets them.
+
+    Projection kernels as every family (normal(0.02), outputs / sqrt(2 L));
+    the embedding is drawn ``embed_scale`` times smaller, so that the scaled
+    ``x0`` has the 0.02 of the other families' (a tied table of 0.02 x 12
+    would outvote every layer at the head). The recurrence takes Mamba-2's
+    published ranges, so that a state really outlives a chunk: ``A_log =
+    log U(1, 16)``, ``dt_bias = softplus^-1(dt)`` with ``dt`` log-uniform in
+    [1e-3, 1e-1], ``D = 1``, convolution taps ``U(+-1/sqrt(taps))`` and zero
+    bias. The small recurrence leaves stay float32 whatever ``dtype`` is."""
+    ks = jax.random.split(rng, 16)
+    L, D, V, F = cfg.n_layers, cfg.d_model, cfg.vocab_size, cfg.d_ff
+    La, Ls = cfg.n_attn_layers, cfg.n_ssm_layers
+    H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    SH, I, C, K = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_dim, cfg.ssm_conv
+    std = 0.02
+    res_std = std / (2 * L) ** 0.5
+
+    def norm(key, shape, s):
+        return (jax.random.normal(key, shape, jnp.float32) * s).astype(dtype)
+
+    attn = {
+        "attn_norm": {"scale": jnp.ones((La, D), dtype)},
+        "q": {"kernel": norm(ks[1], (La, D, H * HD), std)},
+        "k": {"kernel": norm(ks[2], (La, D, KV * HD), std)},
+        "v": {"kernel": norm(ks[3], (La, D, KV * HD), std)},
+        "o": {"kernel": norm(ks[4], (La, H * HD, D), res_std)},
+        "mlp_norm": {"scale": jnp.ones((La, D), dtype)},
+        "gate": {"kernel": norm(ks[5], (La, D, F), std)},
+        "up": {"kernel": norm(ks[6], (La, D, F), std)},
+        "down": {"kernel": norm(ks[7], (La, F, D), res_std)},
+    }
+    dt = jnp.exp(jax.random.uniform(ks[11], (Ls, SH), jnp.float32,
+                                    jnp.log(1e-3), jnp.log(1e-1)))
+    bound = 1.0 / K ** 0.5
+    ssm = {
+        "ssm_norm": {"scale": jnp.ones((Ls, D), dtype)},
+        "in_proj": {"kernel": norm(ks[8], (Ls, D, I + C + SH), std)},
+        "conv": {"kernel": jax.random.uniform(ks[9], (Ls, K, C), jnp.float32,
+                                              -bound, bound).astype(dtype),
+                 "bias": jnp.zeros((Ls, C), dtype)},
+        "A_log": jnp.log(jax.random.uniform(ks[10], (Ls, SH), jnp.float32, 1.0, 16.0)),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1
+        "D": jnp.ones((Ls, SH), jnp.float32),
+        "gate_norm": {"scale": jnp.ones((Ls, I), dtype)},
+        "out_proj": {"kernel": norm(ks[12], (Ls, I, D), res_std)},
+        "mlp_norm": {"scale": jnp.ones((Ls, D), dtype)},
+        "gate": {"kernel": norm(ks[13], (Ls, D, F), std)},
+        "up": {"kernel": norm(ks[14], (Ls, D, F), std)},
+        "down": {"kernel": norm(ks[15], (Ls, F, D), res_std)},
+    }
+    out = {
+        "embed": {"embedding": norm(ks[0], (V, D), std / cfg.embed_scale)},
+        "layers": {"attn": attn, "ssm": ssm},
+        "final_norm": {"scale": jnp.ones((D,), dtype)},
+    }
+    if not cfg.tied_head:
+        out["lm_head"] = {"kernel": norm(jax.random.fold_in(ks[0], 1), (D, V), std)}
+    return out
+
+
+# Recurrence leaves of a Mamba-2 layer that stay float32 through
+# :func:`cast_layer_stack`: a bf16 ``A_log`` moves every decay.
+SSM_FLOAT32_LEAVES = ("A_log", "dt_bias", "D")
+
+
 def logical_axes(cfg: ModelConfig) -> dict[str, Any]:
     """Logical-axis tree matching :func:`init_params`' structure exactly."""
+    if cfg.is_hybrid:
+        mlp_axes = {
+            "mlp_norm": {"scale": ("layers", "embed")},
+            "gate": {"kernel": ("layers", "embed", "mlp")},
+            "up": {"kernel": ("layers", "embed", "mlp")},
+            "down": {"kernel": ("layers", "mlp", "embed")},
+        }
+        out = {
+            "embed": {"embedding": ("vocab", "embed")},
+            "layers": {
+                "attn": {
+                    "attn_norm": {"scale": ("layers", "embed")},
+                    "q": {"kernel": ("layers", "embed", "heads")},
+                    "k": {"kernel": ("layers", "embed", "kv_heads")},
+                    "v": {"kernel": ("layers", "embed", "kv_heads")},
+                    "o": {"kernel": ("layers", "heads", "embed")},
+                    **mlp_axes,
+                },
+                # The mixer's fused projection (z | x | B | C | dt) has no
+                # head-aligned split to shard: its width stays whole.
+                "ssm": {
+                    "ssm_norm": {"scale": ("layers", "embed")},
+                    "in_proj": {"kernel": ("layers", "embed", None)},
+                    "conv": {"kernel": ("layers", None, None),
+                             "bias": ("layers", None)},
+                    "A_log": ("layers", None),
+                    "dt_bias": ("layers", None),
+                    "D": ("layers", None),
+                    "gate_norm": {"scale": ("layers", None)},
+                    "out_proj": {"kernel": ("layers", None, "embed")},
+                    **mlp_axes,
+                },
+            },
+            "final_norm": {"scale": ("embed",)},
+        }
+        if not cfg.tied_head:
+            out["lm_head"] = {"kernel": ("embed", "vocab")}
+        return out
     if cfg.arch == "gpt2":
         return {
             "embed": {"embedding": ("vocab", "embed")},
@@ -330,7 +568,7 @@ def logical_axes(cfg: ModelConfig) -> dict[str, Any]:
         "layers": layers,
         "final_norm": {"scale": ("embed",)},
     }
-    if cfg.arch != "gemma":  # gemma ties the head to the embedding
+    if not cfg.tied_head:
         out["lm_head"] = {"kernel": ("embed", "vocab")}
     return out
 
@@ -348,7 +586,15 @@ def param_count(cfg: ModelConfig) -> int:
     per_layer = D * H * HD + 2 * D * KV * HD + H * HD * D + mlp + router + 2 * D
     if cfg.arch == "qwen":
         per_layer += 2 * HD  # per-head q/k RMSNorm scales
-    head = 0 if cfg.arch == "gemma" else D * V  # gemma: tied
+    head = 0 if cfg.tied_head else D * V
+    if cfg.is_hybrid:
+        I, C, SH = cfg.ssm_inner, cfg.ssm_conv_dim, cfg.ssm_heads
+        # in_proj (z | xBC | dt), conv taps + bias, A_log / dt_bias / D,
+        # the gate's norm, out_proj; then the layer's two norms and MLP.
+        per_ssm = (D * (I + C + SH) + (cfg.ssm_conv + 1) * C + 3 * SH + I
+                   + I * D + mlp + 2 * D)
+        return (V * D + cfg.n_attn_layers * per_layer
+                + cfg.n_ssm_layers * per_ssm + D + head)
     return V * D + L * per_layer + D + head
 
 
@@ -371,7 +617,7 @@ def train_flops_per_token(cfg: ModelConfig, seq_len: int) -> float:
         # Tied head: the V·D weight is a real matmul at the head; only the
         # positional-embedding lookup is not.
         n = active_param_count(cfg) - cfg.max_seq_len * cfg.d_model
-    elif cfg.arch == "gemma":
+    elif cfg.tied_head:
         # Tied head: the embedding's V·D is counted once and spent on the
         # head matmul; the lookup itself is free.
         n = active_param_count(cfg)
@@ -411,6 +657,22 @@ def _norm(x: jax.Array, p: dict, cfg: "ModelConfig") -> jax.Array:
     if cfg.arch == "gemma":
         return _rms_norm(x, p["scale"].astype(jnp.float32) + 1.0, cfg.norm_eps)
     return _rms_norm(x, p["scale"], cfg.norm_eps)
+
+
+def _residual(x: jax.Array, y: jax.Array, cfg: "ModelConfig") -> jax.Array:
+    """A mixer's or MLP's output ``y`` joining the residual stream ``x``,
+    times ``cfg.residual_scale`` where the model has one — applied in
+    float32, because 0.22 is not a bfloat16 number and every layer would
+    carry its rounding."""
+    if cfg.residual_scale == 1.0:
+        return x + y
+    return x + (y.astype(jnp.float32) * cfg.residual_scale).astype(x.dtype)
+
+
+def attention_scale(cfg: "ModelConfig") -> float:
+    """What attention scores are multiplied by: the model's own multiplier,
+    else 1/sqrt(head_dim)."""
+    return cfg.attn_scale or 1.0 / (cfg.head_dim ** 0.5)
 
 
 def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -755,15 +1017,17 @@ def _block(
         if cfg.arch == "qwen":  # per-head qk-norm, before RoPE
             q = _rms_norm(q, layer_params["q_norm"]["scale"], cfg.norm_eps)
             k = _rms_norm(k, layer_params["k_norm"]["scale"], cfg.norm_eps)
-        if not gpt2:  # gpt2 uses learned absolute positions, added at embed time
+        if cfg.rope and not gpt2:  # gpt2 uses learned absolute positions, added at embed time
             q = _rope(q, positions, cfg.rope_theta)
             k = _rope(k, positions, cfg.rope_theta)
+        if cfg.attn_scale:  # the kernels scale by 1/sqrt(head_dim): fold the rest into q
+            q = q * jnp.asarray(cfg.attn_scale * HD ** 0.5, q.dtype)
         q, k, v = tag(q, "q"), tag(k, "k"), tag(v, "v")
         attn = _attention(q, k, v, cfg.attention_impl, mesh=mesh,
                           window=cfg.sliding_window)
         attn = tag(attn.reshape(B, S, H * HD), "attn_out")
-        x = x + _proj(attn, layer_params["o"]["kernel"], lora.get("o"), lora_scale,
-                      bias("o"), dot=dot)
+        x = _residual(x, _proj(attn, layer_params["o"]["kernel"], lora.get("o"),
+                               lora_scale, bias("o"), dot=dot), cfg)
 
     h = _norm(x, layer_params["mlp_norm"], cfg)
     if cfg.is_moe:
@@ -780,10 +1044,10 @@ def _block(
             )
         moe = _moe_mlp_ragged if cfg.moe_impl == "ragged" else _moe_mlp
         mlp_out, aux = moe(h, layer_params, cfg)
-        x = x + mlp_out
+        x = _residual(x, mlp_out, cfg)
         return x, aux
     with jax.named_scope("mlp"):
-        x = x + _dense_mlp(h, layer_params, lora, lora_scale, cfg=cfg)
+        x = _residual(x, _dense_mlp(h, layer_params, lora, lora_scale, cfg=cfg), cfg)
     return x, jnp.zeros((), jnp.float32)
 
 
@@ -897,6 +1161,8 @@ def embed_tokens(params: dict[str, Any], tokens: jax.Array, compute_dtype=jnp.bf
         x = jnp.take(embed, tokens, axis=0)
         if cfg.arch == "gemma":
             x = x * jnp.asarray(cfg.d_model ** 0.5, compute_dtype)
+        if cfg.embed_scale != 1.0:
+            x = x * jnp.asarray(cfg.embed_scale, compute_dtype)
         if "pos_embed" in params:
             if positions is None:
                 positions = jnp.arange(tokens.shape[-1], dtype=jnp.int32)
@@ -907,21 +1173,25 @@ def embed_tokens(params: dict[str, Any], tokens: jax.Array, compute_dtype=jnp.bf
 
 def unembed(params: dict[str, Any], x: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Final norm + LM head: activations [..., S, D] → logits [..., S, V]
-    fp32. GPT-2-family models tie the head to the token embedding."""
+    fp32. A tied head (``cfg.tied_head``) is the token embedding; a model
+    with a ``logits_divisor`` divides its logits by it."""
     with jax.named_scope("head"):
         x = _norm(x, jax.tree.map(lambda a: a.astype(x.dtype), params["final_norm"]), cfg)
-        head = (params["embed"]["embedding"].T if cfg.arch in ("gpt2", "gemma")
+        head = (params["embed"]["embedding"].T if cfg.tied_head
                 else params["lm_head"]["kernel"])
         if isinstance(head, QuantWeight):
             logits = jnp.einsum(
                 "...sd,dv->...sv", x, head.q.astype(x.dtype),
                 preferred_element_type=jnp.float32,
+            ) * head.scale.astype(jnp.float32)
+        else:
+            logits = jnp.einsum(
+                "...sd,dv->...sv", x, head.astype(x.dtype),
+                preferred_element_type=jnp.float32,
             )
-            return logits * head.scale.astype(jnp.float32)
-        return jnp.einsum(
-            "...sd,dv->...sv", x, head.astype(x.dtype),
-            preferred_element_type=jnp.float32,
-        )
+        if cfg.logits_divisor != 1.0:
+            logits = logits / cfg.logits_divisor
+        return logits
 
 
 def cast_layer_stack(params: dict[str, Any], compute_dtype=jnp.bfloat16) -> dict[str, Any]:
@@ -929,14 +1199,24 @@ def cast_layer_stack(params: dict[str, Any], compute_dtype=jnp.bfloat16) -> dict
     :class:`QuantWeight` kernels pass through untouched — their int8
     codes cast at the matmul and their fp32 scales must NOT round to
     bf16 (that would double the quantization error for free)."""
+    def cast(a):
+        if isinstance(a, QuantWeight) or not jnp.issubdtype(a.dtype, jnp.floating):
+            return a
+        return a.astype(compute_dtype)
+
+    is_quant = lambda a: isinstance(a, QuantWeight)  # noqa: E731
     with jax.named_scope("cast_weights"):
-        return jax.tree.map(
-            lambda a: a if isinstance(a, QuantWeight)
-            else a.astype(compute_dtype) if jnp.issubdtype(a.dtype, jnp.floating)
-            else a,
-            params["layers"],
-            is_leaf=lambda a: isinstance(a, QuantWeight),
-        )
+        layers = params["layers"]
+        if "ssm" not in layers:
+            return jax.tree.map(cast, layers, is_leaf=is_quant)
+        # A hybrid's per-kind stacks; the recurrence's own leaves stay float32.
+        ssm = layers["ssm"]
+        return {
+            "attn": jax.tree.map(cast, layers["attn"], is_leaf=is_quant),
+            "ssm": {k: v if k in SSM_FLOAT32_LEAVES
+                    else jax.tree.map(cast, v, is_leaf=is_quant)
+                    for k, v in ssm.items()},
+        }
 
 
 def forward_hidden_and_aux(
@@ -972,6 +1252,9 @@ def forward_hidden_and_aux(
     would materialise the full device-resident stack the offload exists to
     avoid."""
     B, S = tokens.shape
+    # The cache-less forward (training, evaluation) scans one kind of layer;
+    # a hybrid's prefill is generate.forward_with_cache.
+    refuse_recurrent(cfg, "the cache-less forward pass (training and evaluation)")
     if cfg.arch == "gpt2" and S > cfg.max_seq_len:
         # Learned position table: jnp.take would silently clamp out-of-range
         # rows (RoPE models have no such bound).

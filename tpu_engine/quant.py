@@ -87,23 +87,31 @@ def dequantize_weight(qw: QuantWeight, dtype=jnp.float32) -> jax.Array:
 # Per-layer projection names whose "kernel" quantizes. Covers the llama
 # family (q/k/v/o/gate/up/down), GPT-2 (q/k/v/o/fc/proj), and MoE
 # (stacked expert gate/up/down; the router stays fp32).
-_QUANT_LAYER_KEYS = ("q", "k", "v", "o", "gate", "up", "down", "fc", "proj")
+_QUANT_LAYER_KEYS = ("q", "k", "v", "o", "gate", "up", "down", "fc", "proj",
+                     # a hybrid stack's Mamba-2 mixer projections
+                     "in_proj", "out_proj")
 
 
 def _walk(params: dict[str, Any], kernel_fn) -> dict[str, Any]:
     """Structural walk shared by the param transform and the
     pspec mirror: applies ``kernel_fn`` to every quantization site,
     preserving everything else (biases, norms, router, embeddings)."""
-    out = dict(params)
-    if "layers" in params:
-        layers = dict(params["layers"])
+    def walk_stack(stack):
+        layers = dict(stack)
         for name in _QUANT_LAYER_KEYS:
             sub = layers.get(name)
             if isinstance(sub, dict) and "kernel" in sub:
                 new_sub = dict(sub)
                 new_sub["kernel"] = kernel_fn(sub["kernel"])
                 layers[name] = new_sub
-        out["layers"] = layers
+        return layers
+
+    out = dict(params)
+    if "layers" in params:
+        layers = params["layers"]
+        # A hybrid stack keeps one stack per kind of layer.
+        out["layers"] = ({kind: walk_stack(stack) for kind, stack in layers.items()}
+                         if "ssm" in layers else walk_stack(layers))
     if "lm_head" in params:
         head = dict(params["lm_head"])
         head["kernel"] = kernel_fn(head["kernel"])

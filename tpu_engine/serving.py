@@ -63,14 +63,20 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from tpu_engine.generate import (
     KVCache,
     _decode_block,
+    _ssm_block,
     forward_with_cache,
     init_cache,
+    init_recurrent_state,
+    layer_slice,
     ring_lanes,
+    scan_hybrid_layers,
 )
 from tpu_engine.models.transformer import (
     ModelConfig,
     cast_layer_stack,
+    check_hybrid,
     embed_tokens,
+    refuse_recurrent,
     unembed,
 )
 from tpu_engine.profiler import StepProfiler
@@ -110,10 +116,29 @@ class SlotCache:
     # dequantisation fuses into the attention reads.
     k_scale: Optional[jax.Array] = None
     v_scale: Optional[jax.Array] = None
+    # Hybrid stacks hold TWO kinds of state per slot: k/v above cover the
+    # attention layers only ([L_attn, B, S, KV, HD]); the Mamba-2 layers keep
+    # ``ssm`` [L_ssm, B, heads, head_dim, state] float32 and ``conv``
+    # [L_ssm, B, taps-1, conv_dim]. A recurrent state has no lane to mask, so
+    # what keys and values make harmless is handled where it happens: pad
+    # positions and rows that are not ``active`` leave it exactly as it was
+    # (``generate._ssm_mixer``), and a finished row's overshoot steps do
+    # advance it, which is why ``_reset_slot`` zeroes it and
+    # ``_insert_prefill`` overwrites all of it before the slot decodes again.
+    ssm: Optional[jax.Array] = None
+    conv: Optional[jax.Array] = None
 
     @property
     def n_lanes(self) -> int:
         return self.k.shape[2]
+
+    @property
+    def recurrent(self) -> bool:
+        return self.ssm is not None
+
+    @property
+    def recurrent_state_bytes(self) -> int:
+        return 0 if self.ssm is None else self.ssm.nbytes + self.conv.nbytes
 
     @property
     def quantized(self) -> bool:
@@ -130,11 +155,15 @@ def init_slot_cache(
     slot-pool analogue of :func:`generate.init_cache`'s ring mode.
     ``kv_quant=True`` stores the pool as int8 codes + per-(lane, kv-head)
     scales — half the serving-pool HBM."""
+    check_hybrid(cfg)
+    if kv_quant:
+        refuse_recurrent(cfg, "an int8 KV pool (kv_quant)")
     lanes = ring_lanes(cfg, max_len, prefill_chunk)
     ring = lanes < max_len
-    shape = (cfg.n_layers, slots, lanes, cfg.n_kv_heads, cfg.head_dim)
+    shape = (cfg.n_attn_layers, slots, lanes, cfg.n_kv_heads, cfg.head_dim)
     store_dtype = jnp.int8 if kv_quant else dtype
     scale_shape = shape[:-1] + (1,)
+    ssm, conv = init_recurrent_state(cfg, slots, dtype)
     return SlotCache(
         k=jnp.zeros(shape, store_dtype),
         v=jnp.zeros(shape, store_dtype),
@@ -143,6 +172,7 @@ def init_slot_cache(
         ring=ring,
         k_scale=jnp.zeros(scale_shape, jnp.float32) if kv_quant else None,
         v_scale=jnp.zeros(scale_shape, jnp.float32) if kv_quant else None,
+        ssm=ssm, conv=conv,
     )
 
 
@@ -201,6 +231,36 @@ def decode_step(
         )
 
     scales = (cache.k_scale, cache.v_scale) if cache.quantized else ()
+
+    if cfg.is_hybrid:
+        # Two kinds of layer, two kinds of state: the attention layers run
+        # the stock block against their own [L_attn, ...] pool, the Mamba-2
+        # layers one recurrence step; a row that is not active keeps its
+        # recurrent state exactly (it has no mask to hide a garbage step).
+        def attn_layer(x, lp, k_all, v_all, at):
+            # One row per slot, scattered straight into the layer's lanes of
+            # the [L_attn, B, S, KV, HD] pool: the layer's slice is read for
+            # the attention and never written back whole.
+            def write_at(cache_arr, new_rows):
+                return cache_arr.at[at, rows, lane].set(
+                    new_rows[:, 0].astype(cache_arr.dtype))
+
+            return _decode_block(
+                x, lp, k_all, v_all, write_at, slot_pos, positions, cfg,
+                read=lambda a: layer_slice(a, at))[:3]
+
+        def ssm_layer(x, lp, ssm, conv, at):
+            return _ssm_block(x, lp, ssm, conv, at, active[:, None], cfg)
+
+        x, state = scan_hybrid_layers(
+            x, layer_stack, cfg,
+            {"attn": (cache.k, cache.v), "ssm": (cache.ssm, cache.conv)},
+            attn_layer, ssm_layer)
+        logits = unembed(params, x, cfg)[:, 0]
+        return logits, dataclasses.replace(
+            cache, k=state["attn"][0], v=state["attn"][1],
+            lengths=cache.lengths + active.astype(jnp.int32),
+            ssm=state["ssm"][0], conv=state["ssm"][1])
 
     def body(x, xs):
         lp, k_c, v_c, *scale_cs = xs                        # k_c [B,S,KV,HD]
@@ -304,7 +364,10 @@ def decode_verify(
     for active rows; the CALLER rewinds them to the accepted frontier
     (free under per-row positions: lanes past a row's length are masked
     and the next round's chain overwrites them before exposure).
-    Non-ring pools only (speculative serving rejects window models)."""
+    Non-ring pools only (speculative serving rejects window models), and
+    attention-only stacks: rewinding to the accepted frontier is free for
+    keys and values and impossible for a recurrent state."""
+    refuse_recurrent(cfg, "the speculative verify pass (decode_verify)")
     B, T = tokens.shape
     S = cache.n_lanes
     rows = jnp.arange(B)
@@ -689,6 +752,17 @@ class ContinuousBatcher:
         self.mesh = mesh
         self.kv_quant = bool(kv_quant)
         self._compute_dtype = compute_dtype
+        # What assumes that a slot's state is keys and values is refused for
+        # a stack with recurrent layers, by name, before anything is built
+        # (kv_quant: init_slot_cache refuses it).
+        if mesh is not None:
+            refuse_recurrent(cfg, "mesh-sharded serving")
+        if prefix_cache_tokens:
+            refuse_recurrent(cfg, "the prompt-prefix cache (prefix_cache_tokens)")
+        if draft_params is not None:
+            refuse_recurrent(cfg, "speculative serving (draft_params)")
+            if draft_cfg is not None:
+                refuse_recurrent(draft_cfg, "speculative serving (as the draft)")
         self._cache = init_slot_cache(
             cfg, self.max_slots, self.max_len, compute_dtype,
             prefill_chunk=self.prefill_chunk, kv_quant=self.kv_quant,
@@ -872,6 +946,11 @@ class ContinuousBatcher:
         self._profiler = StepProfiler(loop="batcher", phases=BATCHER_PHASES)
         self._decode_tokens_computed = 0
         self._decode_tokens_emitted = 0
+        # Recurrent state written into a slot by a finished prefill, and
+        # zeroed when a slot is freed (hybrid stacks; else both stay 0).
+        self._recurrent_state_bytes = self._cache.recurrent_state_bytes
+        self._state_inserts = 0
+        self._state_resets = 0
         self._spec_rounds = 0
         self._spec_accepted = 0
         self._started = time.time()
@@ -894,6 +973,8 @@ class ContinuousBatcher:
                 "for argmax streams); start a non-speculative server for "
                 "sampling"
             )
+        if hold_kv:
+            refuse_recurrent(self.cfg, "hold_kv (the KV handoff plane)")
         if hold_kv and self._cache.ring:
             raise ValueError(
                 "hold_kv does not support sliding-window models (ring lanes "
@@ -937,6 +1018,7 @@ class ContinuousBatcher:
         bounds the tokens THIS engine adds."""
         if self.last_error is not None:
             raise RuntimeError(f"serving loop failed: {self.last_error}")
+        refuse_recurrent(self.cfg, "submit_prefilled (the KV handoff wire)")
         if self._cache.ring:
             raise ValueError(
                 "submit_prefilled does not support sliding-window pools"
@@ -1240,6 +1322,12 @@ class ContinuousBatcher:
                 # thrown away.
                 "decode_tokens_computed_total": self._decode_tokens_computed,
                 "decode_tokens_emitted_total": self._decode_tokens_emitted,
+                # The pool's second kind of state (0 for attention-only
+                # stacks): its bytes, every slot's whether in use or not,
+                # and how often a slot's was written whole or zeroed.
+                "recurrent_state_bytes": self._recurrent_state_bytes,
+                "state_inserts_total": self._state_inserts,
+                "state_resets_total": self._state_resets,
             }
             if self._prefix_cache is not None:
                 out["prefix_cache"] = self._prefix_cache.stats()
@@ -1331,8 +1419,12 @@ class ContinuousBatcher:
         # Logits row of the last REAL prompt token (it seeds the first
         # sampled/greedy token) — only meaningful in its chunk.
         row = min(max(P_len - 1 - t0, 0), t1 - t0 - 1)
+        # A hybrid stack's recurrent layers stop at the chunk's last REAL
+        # token (the bucket's padding would otherwise enter the state).
+        n_valid = jnp.asarray(min(max(P_len - t0, 0), t1 - t0), jnp.int32) \
+            if self._cache.recurrent else None
         last_row, st.c1 = self._prefill_fn(
-            self.params, chunk, st.c1, jnp.asarray(row, jnp.int32)
+            self.params, chunk, st.c1, jnp.asarray(row, jnp.int32), n_valid
         )
         if st.dc1 is not None:  # speculative: the draft ingests the prompt too
             st.dc1 = self._draft_prefill_fn(self._draft_params, chunk, st.dc1)
@@ -1368,6 +1460,7 @@ class ContinuousBatcher:
         self._cache = self._insert(self._cache, st.c1, jnp.asarray(st.slot),
                                    jnp.asarray(P_len, jnp.int32),
                                    self._cache.ring)
+        self._state_inserts += self._cache.recurrent
         if st.dc1 is not None:
             self._draft_cache = self._draft_insert(
                 self._draft_cache, st.dc1, jnp.asarray(st.slot),
@@ -1431,7 +1524,9 @@ class ContinuousBatcher:
                 self._prefilling.pop(slot)  # cancelled/failed meanwhile
             else:
                 with prof.phase("prefill", rid=st.req.id, slot=slot,
-                                chunk=st.consumed // self.prefill_chunk):
+                                chunk=st.consumed // self.prefill_chunk,
+                                tokens=min(self.prefill_chunk,
+                                           st.padded - st.consumed)):
                     if st.req.prefill_started_at is None:
                         st.req.prefill_started_at = time.time()
                     ingested = self._advance_prefill(st)
@@ -1639,6 +1734,7 @@ class ContinuousBatcher:
             # reuses it cleanly; overshoot lanes from a mid-chunk finish
             # become invisible the same instant.
             self._cache = self._reset(self._cache, slot)
+            self._state_resets += self._cache.recurrent
             if self._draft_cache is not None:
                 self._draft_cache = self._draft_reset(self._draft_cache, slot)
             self._done.notify_all()
@@ -1693,12 +1789,16 @@ class ContinuousBatcher:
             self._done.notify_all()
 
 
-def _prefill_forward(params, toks, cache, row_idx, *, cfg, compute_dtype):
+def _prefill_forward(params, toks, cache, row_idx, n_valid=None, *, cfg,
+                     compute_dtype):
     """One prefill chunk through the stock cached forward; returns only the
     requested logits row (the [V] vector that seeds the first token) — on a
-    mesh this avoids all-gathering the full [T, V] logits per chunk."""
+    mesh this avoids all-gathering the full [T, V] logits per chunk.
+    ``n_valid``: the chunk's real tokens (the rest pads the prompt to its
+    bucket), which only a hybrid stack's recurrent layers need."""
     logits, cache = forward_with_cache(params, toks, cache, cfg,
-                                       compute_dtype=compute_dtype)
+                                       compute_dtype=compute_dtype,
+                                       n_valid=n_valid)
     return logits[0, row_idx], cache
 
 
@@ -1733,10 +1833,17 @@ def _insert_prefill(cache: SlotCache, c1: KVCache, slot, true_len, ring: bool):
     if ring:
         # Lane-aligned by construction (c1 ring size == pool lane count).
         pos = lax.dynamic_update_slice(pos, c1.pos[None, :], (slot, 0))
+    ssm, conv = cache.ssm, cache.conv
+    if cache.recurrent:
+        # The row's whole recurrent state, as the prefill left it at the
+        # prompt's TRUE length: whatever the slot held is gone.
+        ssm = lax.dynamic_update_slice(ssm, c1.ssm, (0, slot, 0, 0, 0))
+        conv = lax.dynamic_update_slice(conv, c1.conv.astype(conv.dtype),
+                                        (0, slot, 0, 0))
     return SlotCache(
         k=k, v=v,
         lengths=cache.lengths.at[slot].set(true_len.astype(jnp.int32)),
-        pos=pos, ring=cache.ring, k_scale=ks, v_scale=vs,
+        pos=pos, ring=cache.ring, k_scale=ks, v_scale=vs, ssm=ssm, conv=conv,
     )
 
 
@@ -1744,8 +1851,14 @@ def _reset_slot(cache: SlotCache, slot):
     pos = cache.pos
     if cache.ring:
         pos = pos.at[slot].set(-1)
+    ssm, conv = cache.ssm, cache.conv
+    if cache.recurrent:
+        # No length hides a recurrent state: a freed slot's is zeroed (a
+        # finished row's overshoot steps advanced it past its last token).
+        ssm = ssm.at[:, slot].set(0.0)
+        conv = conv.at[:, slot].set(0.0)
     return SlotCache(
         k=cache.k, v=cache.v, lengths=cache.lengths.at[slot].set(0),
         pos=pos, ring=cache.ring, k_scale=cache.k_scale,
-        v_scale=cache.v_scale,
+        v_scale=cache.v_scale, ssm=ssm, conv=conv,
     )

@@ -745,6 +745,11 @@ class ServingFleet:
         # replica-cache overflow to its host tier via export_prefix.
         self.prefix_plane = prefix_plane
         if prefix_plane is not None:
+            from tpu_engine.models.transformer import refuse_recurrent_model
+
+            # The plane's host tier parks KVHandoff payloads: keys and values.
+            refuse_recurrent_model(
+                spec.model_name, "the fleet prefix plane (HostKVTier)")
             if self.router.prefix_plane is None:
                 self.router.prefix_plane = prefix_plane
             if prefix_plane.spill is None:

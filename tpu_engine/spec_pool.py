@@ -384,6 +384,11 @@ class SpecServingFleet:
         accept_ema_beta: float = 0.25,
         clock: Callable[[], float] = time.time,
     ):
+        from tpu_engine.models.transformer import refuse_recurrent_model
+
+        for spec in (verify_spec, draft_spec):
+            refuse_recurrent_model(
+                spec.model_name, "speculative serving (SpecServingFleet)")
         verify_spec = verify_spec.model_copy(update={"pool_role": "decode"})
         draft_spec = draft_spec.model_copy(update={"pool_role": "draft"})
         self.verify = ServingFleet(

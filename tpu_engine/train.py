@@ -376,6 +376,9 @@ def build_train_program(
     """
     if model_cfg is None:
         model_cfg = tfm.MODEL_CONFIGS[cfg.model_name]
+    # A hybrid (Mamba-2 + attention) stack is served only: the backward of
+    # the chunked scan and packed documents are not written.
+    tfm.refuse_recurrent(model_cfg, "training (build_train_program)")
     if runtime is None:
         runtime = MeshRuntime(cfg.mesh)
     mesh = runtime.mesh
