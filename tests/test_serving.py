@@ -1060,3 +1060,192 @@ def test_decode_step_never_expands_the_pool():
     assert scores, "the walk did not reach the attention inside the layer scan"
     expanded = [a for a in avals if M in a.shape and a.size >= B * M * H * HD]
     assert not expanded, expanded
+
+
+# --- the layer walk carries the pool and writes a layer's rows in place ---
+
+_WINDOW = 6  # a sliding window small enough that a 4-token chunk wraps the ring
+
+
+def _walk_case(fn, ring, kv_quant, G=1, seed=0):
+    """A ``gpt-tiny`` stack of 3 layers with a randomly filled cache, and the
+    call of ``fn`` on it: ``(cfg, params, call, args, cache)`` with the cache
+    the LAST of ``args``. Rows sit at different lengths; a ring cache has
+    wrapped (its ``pos`` holds the position each lane stores)."""
+    from tpu_engine import serving
+    from tpu_engine.generate import KVCache, forward_with_cache, init_cache
+
+    B, H = 3, 4
+    cfg = tfm.MODEL_CONFIGS["gpt-tiny"].with_(
+        n_layers=3, n_kv_heads=H // G, sliding_window=_WINDOW if ring else 0)
+    params = tfm.init_params(jax.random.PRNGKey(seed), cfg, dtype=jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(seed + 1), 5)
+
+    def fill(empty):  # random keys and values (int8: codes and scales)
+        if kv_quant:
+            code = lambda k: jax.random.randint(k, empty.k.shape, -127, 128).astype(jnp.int8)  # noqa: E731
+            scale = lambda k: jax.random.uniform(k, empty.k_scale.shape, jnp.float32, 0.002, 0.02)  # noqa: E731
+            return dict(k=code(keys[0]), v=code(keys[1]), k_scale=scale(keys[2]), v_scale=scale(keys[3]))
+        return dict(k=jax.random.normal(keys[0], empty.k.shape), v=jax.random.normal(keys[1], empty.v.shape))
+
+    def held(length, lanes):  # the position each lane of a ring holds, -1 = none
+        m = np.arange(lanes)
+        last = length - 1 - ((length - 1 - m) % lanes)
+        return np.where((length > 0) & (last >= 0), last, -1).astype(np.int32)
+
+    if fn in ("decode_step", "decode_verify"):
+        T = 1 if fn == "decode_step" else 4
+        empty = init_slot_cache(cfg, B, 24, jnp.float32, prefill_chunk=4, kv_quant=kv_quant)
+        assert empty.ring == ring
+        lengths = np.asarray([11, 14, 0] if ring else [7, 3, 0], np.int32)
+        pos = jnp.asarray(np.stack([held(n, empty.n_lanes) for n in lengths])) if ring else None
+        cache = serving.SlotCache(lengths=jnp.asarray(lengths), pos=pos, ring=ring, **fill(empty))
+        toks = jax.random.randint(keys[4], (B,) if T == 1 else (B, T), 1, cfg.vocab_size)
+        active = jnp.asarray([True, True, False])
+        call = lambda p, t, a, c: getattr(serving, fn)(p, t, c, a, cfg, jnp.float32)  # noqa: E731
+        return cfg, params, call, (params, toks, active, cache)
+    T = 4 if fn == "forward_chunk" else 1
+    empty = init_cache(cfg, B, 24, jnp.float32, max_chunk=4, kv_quant=kv_quant)
+    assert empty.ring == ring
+    length = 13 if ring else 7
+    lanes = empty.max_len
+    pos = held(length, lanes) if ring else np.where(np.arange(lanes) < length, np.arange(lanes), -1)
+    cache = KVCache(pos=jnp.asarray(pos, jnp.int32), length=jnp.asarray(length, jnp.int32),
+                    ring=ring, **fill(empty))
+    toks = jax.random.randint(keys[4], (B, T), 1, cfg.vocab_size)
+    call = lambda p, t, c: forward_with_cache(p, t, c, cfg, compute_dtype=jnp.float32)  # noqa: E731
+    return cfg, params, call, (params, toks, cache)
+
+
+_WALK_CASES = [
+    pytest.param(fn, ring, kv_quant, id=f"{fn}-{'ring' if ring else 'flat'}-{'kv8' if kv_quant else 'kv16'}")
+    for fn in ("decode_step", "decode_verify", "forward_chunk", "forward_token")
+    for ring in (False, True) if not (ring and fn == "decode_verify")  # verify: flat pools only
+    for kv_quant in (False, True)
+]
+
+
+@pytest.mark.parametrize("fn, ring, kv_quant", [
+    c for c in _WALK_CASES if c.values[0] != "forward_token"])  # one token: decode_step's write
+def test_the_layer_scan_carries_the_pool(fn, ring, kv_quant):
+    """No walk of the stack hands the pool to its layer scan as ``xs`` and
+    collects it as ``ys`` (a scan output is a new buffer: XLA then takes each
+    layer out, writes into it, stacks it back, and copies the whole pool once
+    per step). The pool's arrays are CARRIED, and nothing of the pool's full
+    size is produced but by the in-place write itself. ``forward_chunk`` is
+    what ``_prefill_forward`` runs."""
+    from tpu_engine import serving
+
+    cfg, params, call, args = _walk_case(fn, ring, kv_quant)
+    cache = args[-1]
+    if fn == "forward_chunk":
+        call = lambda p, t, c: serving._prefill_forward(  # noqa: E731
+            p, t, c, jnp.asarray(0), cfg=cfg, compute_dtype=jnp.float32)
+    jaxpr = jax.make_jaxpr(call)(*args)
+    pool_shapes = {cache.k.shape} | ({cache.k_scale.shape} if kv_quant else set())
+    lanes = cache.k.shape[2]  # no width of the model equals the lane count
+
+    def of_pool(v):
+        shape = getattr(v.aval, "shape", ())
+        return shape in pool_shapes or (lanes in shape and v.aval.size >= cache.k.size)
+
+    scans, made_by = [], []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            subs = list(jax.core.jaxprs_in_params(eqn.params))
+            if eqn.primitive.name == "scan":
+                scans.append(eqn)
+            if not subs:  # a leaf: what it makes of the pool's size, it made
+                made_by.extend(eqn.primitive.name for v in eqn.outvars if of_pool(v))
+            for sub in subs:
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert scans, "the walk did not reach the layer scan"
+    carrying = 0
+    for eqn in scans:
+        n_consts, n_carry = eqn.params["num_consts"], eqn.params["num_carry"]
+        ys = [v.aval for v in eqn.outvars[n_carry:] if of_pool(v)]
+        assert not ys, f"the pool is a stacked scan output: {ys}"
+        xs = [v.aval for v in eqn.invars[n_consts + n_carry:] if of_pool(v)]
+        assert not xs, f"the pool is a scanned input: {xs}"
+        carried = {v.aval.shape for v in eqn.invars[n_consts:n_consts + n_carry] if of_pool(v)}
+        carrying += carried == pool_shapes
+    assert carrying == 1, "exactly the one layer scan carries every array of the pool"
+    assert made_by and set(made_by) <= {"scatter", "dynamic_update_slice"}, made_by
+
+
+@pytest.mark.parametrize("G", [1, 4], ids=["mha", "gqa4"])
+@pytest.mark.parametrize("fn, ring, kv_quant", _WALK_CASES)
+def test_carried_pool_equals_a_layer_by_layer_reference(fn, ring, kv_quant, G):
+    """Logits and every array of the returned cache equal a plain Python loop
+    over layers that slices layer ``l`` out, runs ``_decode_block`` on it with
+    a write into that one layer, and stacks the layers back — what the
+    ``xs``/``ys`` scan bodies computed before the pool was carried."""
+    from tpu_engine.generate import _decode_block
+
+    cfg, params, call, args = _walk_case(fn, ring, kv_quant, G=G, seed=G)
+    cache, toks = args[-1], args[1]
+    got_logits, got = call(*args)
+
+    B = toks.shape[0]
+    rows = jnp.arange(B)
+    if fn in ("decode_step", "decode_verify"):
+        active, S = args[2], cache.n_lanes
+        T = 1 if toks.ndim == 1 else toks.shape[1]
+        toks2 = toks.reshape(B, T)
+        positions = cache.lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+        lane = positions % S if ring else positions
+        new_pos = None
+        slot_pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
+        if ring:  # T = 1: only an active row's lane is marked with its position
+            new_pos = slot_pos = cache.pos.at[rows, lane[:, 0]].set(
+                jnp.where(active, cache.lengths, cache.pos[rows, lane[:, 0]]))
+
+        def write(arr, new):
+            return arr.at[rows[:, None], lane].set(new.astype(arr.dtype))
+
+        want_lengths = cache.lengths + T * active.astype(jnp.int32)
+    else:
+        T, M = toks.shape[1], cache.max_len
+        toks2 = toks
+        steps = cache.length + jnp.arange(T, dtype=jnp.int32)
+        positions = jnp.broadcast_to(steps[None, :], (B, T))
+        slots = steps % M if ring else steps
+        new_pos = slot_pos = cache.pos.at[slots].set(steps)
+
+        def write(arr, new):
+            return arr.at[:, slots].set(new.astype(arr.dtype))
+
+    x = tfm.embed_tokens(params, toks2, jnp.float32, positions=positions, cfg=cfg)
+    stack = tfm.cast_layer_stack(params, jnp.float32)
+    layers = []
+    for l in range(cfg.n_layers):
+        lp = jax.tree.map(lambda a: a[l], stack)
+        x, *written = _decode_block(
+            x, lp, cache.k[l], cache.v[l], write, slot_pos, positions, cfg,
+            k_scale_c=cache.k_scale[l] if kv_quant else None,
+            v_scale_c=cache.v_scale[l] if kv_quant else None)
+        layers.append(written)
+    want_logits = tfm.unembed(params, x, cfg)
+    if fn == "decode_step":
+        want_logits = want_logits[:, 0]
+    k, v, k_scale, v_scale = (jnp.stack(a) if kv_quant or i < 2 else None
+                              for i, a in enumerate(zip(*layers)))
+
+    np.testing.assert_allclose(np.asarray(got_logits), np.asarray(want_logits), atol=2e-5, rtol=2e-5)
+    for name, w in (("k", k), ("v", v), ("k_scale", k_scale), ("v_scale", v_scale), ("pos", new_pos)):
+        g = getattr(got, name)
+        if w is None:
+            assert g is None, name
+        elif g.dtype == jnp.int8:  # a code may fall one step aside on round-off
+            assert np.max(np.abs(np.asarray(g, np.int32) - np.asarray(w, np.int32))) <= 1, name
+        else:
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-6, rtol=2e-6, err_msg=name)
+    if fn in ("decode_step", "decode_verify"):
+        assert np.array_equal(np.asarray(got.lengths), np.asarray(want_lengths))
+    else:
+        assert int(got.length) == int(cache.length) + T and got.ring == ring
+    # The walk wrote something: the cache it returns is not the one it was given.
+    assert not np.array_equal(np.asarray(got.k), np.asarray(cache.k))

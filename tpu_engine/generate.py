@@ -9,8 +9,9 @@ training, and serving smoke tests. TPU-first design:
   decode loop is a ``lax.scan`` over ``max_new_tokens`` — no data-dependent
   Python control flow, one compile per (batch, max_len) shape.
 - **Same layer scan as training.** Layers are stacked ``[L, ...]`` pytrees
-  (``models/transformer.py``), so decode scans the cache alongside the
-  layer stack instead of unrolling Python loops per layer.
+  (``models/transformer.py``), so decode loops over the stack instead of
+  unrolling Python loops per layer — and the loop CARRIES the cache: a layer
+  writes its new rows in place and only reads the rest (:func:`scan_layers`).
 - **Sharding by propagation.** Under ``jit`` on a mesh, XLA propagates the
   param shardings (heads/experts over "model", batch over data axes) into
   the cache and attention ops; no decode-specific partition specs needed.
@@ -229,19 +230,23 @@ def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
     implies — so the G-fold keys and values are never built, and MHA is the
     case ``G = 1`` of the same two contractions.
 
-    x: [B, T, D] new activations; k_cache/v_cache: [B, M, KV, HD];
-    ``write(cache_arr, rows)`` stores the chunk's rows at its slots (built
-    once in :func:`forward_with_cache`); ``slot_pos`` is the global
-    position held by each cache slot after this chunk's writes — [M]
-    (all rows in lockstep, the generate() case) or [B, M] (per-row
-    positions, the continuous-batching slot pool in
+    x: [B, T, D] new activations. ``k_cache`` / ``v_cache`` are whatever
+    ``write`` takes and returns, and ``read(cache_arr)`` gives this layer's
+    [B, M, KV, HD] of it. Every walk of a stack (:func:`scan_layers`) hands
+    the WHOLE ``[L, B, M, KV, HD]`` cache: ``write(cache_arr, rows)`` stores
+    the chunk's rows [B, T, KV, HD] in this layer's lanes only, in place, and
+    ``read`` slices the layer out for the two contractions and nothing else —
+    the layer is never written back whole. ``read=None`` is the identity: the
+    arrays are then one layer's own [B, M, KV, HD] (the tests' layer-by-layer
+    references).
+    ``slot_pos`` is the global position held by each cache slot after this
+    chunk's writes — [M] (all rows in lockstep, the generate() case) or
+    [B, M] (per-row positions, the continuous-batching slot pool in
     ``tpu_engine/serving.py``).
-    ``k_scale_c``/``v_scale_c`` [B, M, KV, 1] are present for int8 caches:
-    new rows are quantised before the write and the cache reads dequantise
-    (the convert+mul fuses into the attention dots).
-    ``read`` (hybrid stacks): ``k_cache`` / ``v_cache`` are then whatever
-    ``write`` takes and returns — the whole per-kind pool, written at this
-    layer's rows only — and ``read(cache)`` gives this layer's [B, M, KV, HD].
+    ``k_scale_c``/``v_scale_c`` (as ``k_cache``, trailing 1 instead of HD)
+    are present for int8 caches: new rows are quantised before the write and
+    the cache reads dequantise (the convert+mul fuses into the attention
+    dots).
     """
     B, T, D = x.shape
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -279,13 +284,11 @@ def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
                 v_cache = write(v_cache, v)
 
         with jax.named_scope("decode_attn"):
+            read = read or (lambda a: a)
+            kc, vc = read(k_cache), read(v_cache)
             if k_scale_c is not None:
-                kc = k_cache.astype(x.dtype) * k_scale_c.astype(x.dtype)
-                vc = v_cache.astype(x.dtype) * v_scale_c.astype(x.dtype)
-            elif read is not None:
-                kc, vc = read(k_cache), read(v_cache)
-            else:
-                kc, vc = k_cache, v_cache
+                kc = kc.astype(x.dtype) * read(k_scale_c).astype(x.dtype)
+                vc = vc.astype(x.dtype) * read(v_scale_c).astype(x.dtype)
             qg = q.reshape(B, T, KV, H // KV, HD)  # KV-major groups
             scale = attention_scale(cfg)
             scores = jnp.einsum(
@@ -461,31 +464,65 @@ def _ssm_block(x, layer_params, ssm, conv, at, valid, cfg: ModelConfig):
     return x, ssm, conv
 
 
-def scan_hybrid_layers(x, stacks, cfg: ModelConfig, state, attn_layer, ssm_layer):
-    """Walk a hybrid stack by RUNS of like layers: one ``lax.scan`` per run
-    (for 5 ssm, 1 attn, 9 ssm, 1 attn, 4 ssm: five small loops, not twenty
-    unrolled blocks), each indexing its kind's parameter stack and state at
-    ``first + i``.
+def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
+                valid=None):
+    """Walk the stack against (and into) ``cache`` — THE one cached walk, for
+    every architecture, a :class:`KVCache` or the serving pool alike (both
+    name their per-layer arrays ``k v k_scale v_scale ssm conv``).
 
-    ``stacks`` are the cast per-kind parameter stacks; ``state`` maps
-    "attn" / "ssm" to that kind's arrays, each with the kind's layers
-    leading. The state is CARRIED whole: ``attn_layer(x, lp, k, v, at)`` /
-    ``ssm_layer(x, lp, ssm, conv, at)`` get the kind's whole arrays and the
-    layer's index in them, write what the layer changes (one row of keys, its
-    own recurrent slice) in place, and return ``(x, *arrays)`` — a run never
-    rebuilds the rest of the pool."""
+    The stack is walked by RUNS of like layers (``cfg.layer_runs()``): one
+    ``lax.scan`` per run — a stack of one kind is one run and one loop; 5 ssm,
+    1 attn, 9 ssm, 1 attn, 4 ssm are five small loops, not twenty unrolled
+    blocks. What differs between callers is data: the run list, whether scale
+    arrays ride along, which lanes ``write`` picks.
+
+    - CARRIED: ``x`` and the cache's per-kind state, whole — keys and values
+      ``[L_attn, B, M, KV, HD]`` (with their scales for an int8 cache), the
+      recurrent ``ssm`` / ``conv`` ``[L_ssm, B, ...]``. Nothing of the cache is
+      a scan input or output, so no run rebuilds it and the loop updates the
+      (donated) buffers where they lie.
+    - WRITTEN IN PLACE: ``write(cache_arr, rows, at)`` stores a layer's new
+      rows [B, T, KV, HD] in layer ``at``'s lanes of ``cache_arr`` (a scatter
+      or ``dynamic_update_slice`` with the layer index folded in); a Mamba-2
+      layer rewrites its own slice of ``ssm`` / ``conv`` (:func:`_ssm_block`).
+    - ONLY READ: the layer's [B, M, KV, HD] for the two attention
+      contractions (``_decode_block(read=)``), and the parameters — ``stacks``
+      are the cast stacks of :func:`cast_layer_stack`, never written, so they
+      stay outside the carry and a run indexes its kind's stack at
+      ``first + i`` (for a run that is a whole stack that is what scanning it
+      as ``xs`` lowers to).
+
+    ``valid`` [B, T] marks the real positions for the recurrent layers (which
+    have no mask); attention-only callers may leave it out. Returns
+    ``(x, cache)`` with the cache's per-layer arrays replaced."""
+    stacks = stacks if "ssm" in stacks else {"attn": stacks}
+
+    def attn_layer(x, lp, at, k, v, k_scale, v_scale):
+        return _decode_block(
+            x, lp, k, v, lambda arr, rows: write(arr, rows, at), slot_pos,
+            positions, cfg, k_scale_c=k_scale, v_scale_c=v_scale,
+            read=lambda arr: layer_slice(arr, at))
+
+    def ssm_layer(x, lp, at, ssm, conv):
+        return _ssm_block(x, lp, ssm, conv, at, valid, cfg)
+
     layer_fns = {"attn": attn_layer, "ssm": ssm_layer}
+    state = {"attn": (cache.k, cache.v, cache.k_scale, cache.v_scale),
+             "ssm": (cache.ssm, cache.conv)}
     for kind, first, count in cfg.layer_runs():
 
         def body(carry, i, kind=kind, first=first):
             x, state = carry
             at = first + i
             lp = jax.tree.map(lambda a: layer_slice(a, at), stacks[kind])
-            x, *arrays = layer_fns[kind](x, lp, *state[kind], at)
+            x, *arrays = layer_fns[kind](x, lp, at, *state[kind])
             return (x, {**state, kind: tuple(arrays)}), None
 
         (x, state), _ = lax.scan(body, (x, state), jnp.arange(count, dtype=jnp.int32))
-    return x, state
+    k, v, k_scale, v_scale = state["attn"]
+    ssm, conv = state["ssm"]
+    return x, dataclasses.replace(cache, k=k, v=v, k_scale=k_scale,
+                                  v_scale=v_scale, ssm=ssm, conv=conv)
 
 
 def forward_with_cache(
@@ -506,6 +543,11 @@ def forward_with_cache(
 
     Serves both phases: prefill (T = prompt length) and decode (T = 1).
     Returns (logits [B, T, V] fp32, updated cache with length += T).
+    The layer walk (:func:`scan_layers`) CARRIES the cache's arrays whole and
+    each layer writes the chunk's T rows straight into its own lanes of them
+    (one ``dynamic_update_slice`` at ``(layer, 0, offset, 0, 0)``); a layer's
+    [B, M, KV, HD] is only read, for attention. Jitted with the cache donated,
+    the cache is updated where it lies.
     ``want_logits=False`` (static) skips the unembed entirely and returns
     ``(None, cache)`` — cache-ingestion-only callers (the speculative
     draft's prompt prefill) should not pay a T×D×V matmul per chunk.
@@ -545,7 +587,8 @@ def forward_with_cache(
         # chunk are distinct (M >= T via the guard above), so the einsum
         # copies exactly one row per written slot. O(T·M) int ops — paid
         # only on this wrapping path, not on contiguous prefill/decode
-        # (round-1 advisor finding).
+        # (round-1 advisor finding). The one write that takes its layer out
+        # and puts it back whole: a select has no in-place form.
         slots = new_pos % M
         onehot = jnp.arange(M)[None, :] == slots[:, None]  # [T, M]
         written = onehot.any(axis=0)
@@ -555,75 +598,35 @@ def forward_with_cache(
             cache.pos,
         )
 
-        def write(cache_arr, rows):
+        def write(cache_arr, rows, at):
             rows_m = jnp.einsum("tm,btkh->bmkh", onehot.astype(cache_arr.dtype),
                                 rows.astype(cache_arr.dtype))
-            return jnp.where(written[None, :, None, None], rows_m, cache_arr)
+            layer = jnp.where(written[None, :, None, None], rows_m,
+                              layer_slice(cache_arr, at))
+            return lax.dynamic_update_index_in_dim(cache_arr, layer, at, 0)
     else:
         # Contiguous, non-wrapping write (T=1 ring decode, or any non-ring
-        # chunk): a cheap O(T) dynamic_update_slice at the slot offset, for
-        # the cache rows and the pos vector alike.
+        # chunk): a cheap O(T) dynamic_update_slice at the layer and the slot
+        # offset, straight into the [L, B, M, KV, HD] cache; likewise the pos
+        # vector.
         offset = cache.length % M if cache.ring else cache.length
         pos_new = lax.dynamic_update_slice(cache.pos, new_pos, (offset,))
 
-        def write(cache_arr, rows):
+        def write(cache_arr, rows, at):
             return lax.dynamic_update_slice(
-                cache_arr, rows.astype(cache_arr.dtype), (0, offset, 0, 0)
-            )
+                cache_arr, rows[None].astype(cache_arr.dtype),
+                (at, 0, offset, 0, 0))
 
     x = embed_tokens(params, tokens, compute_dtype, positions=positions, cfg=cfg)
-    layer_stack = cast_layer_stack(params, compute_dtype)
-
-    # One scan body serves both cache precisions: the scale stacks simply
-    # join the scanned arrays when present (pytree structure is static per
-    # trace).
-    scales = (cache.k_scale, cache.v_scale) if cache.quantized else ()
-
-    if cfg.is_hybrid:
-        valid = jnp.broadcast_to(
-            jnp.arange(T)[None, :] < (T if n_valid is None else n_valid), (B, T))
-
-        def attn_layer(x, lp, k_all, v_all, at):
-            # The chunk's rows go straight into the layer's lanes of the
-            # [L_attn, B, M, KV, HD] cache (hybrids have no ring).
-            def write_at(cache_arr, rows):
-                return lax.dynamic_update_slice(
-                    cache_arr, rows[None].astype(cache_arr.dtype),
-                    (at, 0, cache.length, 0, 0))
-
-            return _decode_block(
-                x, lp, k_all, v_all, write_at, pos_new, positions, cfg,
-                read=lambda a: layer_slice(a, at))[:3]
-
-        def ssm_layer(x, lp, ssm, conv, at):
-            return _ssm_block(x, lp, ssm, conv, at, valid, cfg)
-
-        x, state = scan_hybrid_layers(
-            x, layer_stack, cfg,
-            {"attn": (cache.k, cache.v), "ssm": (cache.ssm, cache.conv)},
-            attn_layer, ssm_layer)
-        logits = unembed(params, x, cfg) if want_logits else None
-        return logits, dataclasses.replace(
-            cache, k=state["attn"][0], v=state["attn"][1], pos=pos_new,
-            length=cache.length + T, ssm=state["ssm"][0], conv=state["ssm"][1])
-
-    def body(carry, xs):
-        x = carry
-        layer_params, k_c, v_c, *scale_cs = xs
-        x, k_c, v_c, ks_c, vs_c = _decode_block(
-            x, layer_params, k_c, v_c, write, pos_new, positions, cfg,
-            k_scale_c=scale_cs[0] if scale_cs else None,
-            v_scale_c=scale_cs[1] if scale_cs else None,
-        )
-        return x, (k_c, v_c) + ((ks_c, vs_c) if scale_cs else ())
-
-    x, out = lax.scan(body, x, (layer_stack, cache.k, cache.v) + scales)
-    k_new, v_new = out[0], out[1]
-    ks_new, vs_new = (out[2], out[3]) if cache.quantized else (None, None)
+    # Padding's keys and values are masked later; a recurrent state has no
+    # mask, so the Mamba-2 layers stop at ``n_valid``.
+    valid = jnp.broadcast_to(
+        jnp.arange(T)[None, :] < (T if n_valid is None else n_valid), (B, T))
+    x, cache = scan_layers(x, cast_layer_stack(params, compute_dtype), cfg,
+                           cache, write, pos_new, positions, valid)
     logits = unembed(params, x, cfg) if want_logits else None
-    return logits, KVCache(k=k_new, v=v_new, pos=pos_new,
-                           length=cache.length + T, ring=cache.ring,
-                           k_scale=ks_new, v_scale=vs_new)
+    return logits, dataclasses.replace(cache, pos=pos_new,
+                                       length=cache.length + T)
 
 
 def _filtered_sample(

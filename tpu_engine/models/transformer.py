@@ -158,10 +158,11 @@ class ModelConfig:
     def layer_runs(self) -> tuple:
         """The pattern as runs of like layers: ``(kind, first, count)`` with
         ``kind`` the per-kind stack ("attn" / "ssm") and ``first`` the run's
-        first index WITHIN that stack."""
+        first index WITHIN that stack. No pattern (every layer attends) is the
+        one run ``("attn", 0, n_layers)``."""
         runs: list = []
         seen = {"attn": 0, "ssm": 0}
-        for t in self.layer_types:
+        for t in self.layer_types or ("attention",) * self.n_layers:
             kind = "ssm" if t == "mamba" else "attn"
             if runs and runs[-1][0] == kind:
                 runs[-1][2] += 1

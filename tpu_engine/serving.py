@@ -62,14 +62,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpu_engine.generate import (
     KVCache,
-    _decode_block,
-    _ssm_block,
     forward_with_cache,
     init_cache,
     init_recurrent_state,
-    layer_slice,
     ring_lanes,
-    scan_hybrid_layers,
+    scan_layers,
 )
 from tpu_engine.models.transformer import (
     ModelConfig,
@@ -186,15 +183,26 @@ def decode_step(
 ) -> tuple[jax.Array, SlotCache]:
     """One token for every slot. Returns (logits [B, V] fp32, cache).
 
-    Reuses the stock per-layer decode block (``generate._decode_block``):
-    the slot pool is just the per-row-positions instantiation of its
-    ``write`` callback (row scatter at each slot's own lane) and its
-    rank-2 ``slot_pos``. Every architecture family the block supports is
-    therefore served here with zero forked model code. Inactive rows still
-    compute (static shapes) but their lengths do not advance and their
-    writes land in lanes the mask never exposes (for ring pools the
-    overwritten lane held a position already outside the window, and its
-    ``pos`` entry is not updated, so the garbage stays invisible).
+    Reuses the stock cached walk (``generate.scan_layers`` over
+    ``generate._decode_block`` / ``_ssm_block``): the slot pool is just the
+    per-row-positions instantiation of its ``write`` callback (row scatter at
+    each slot's own lane) and its rank-2 ``slot_pos``. Every architecture
+    family the walk supports is therefore served here with zero forked model
+    code.
+
+    The walk CARRIES the pool — ``k`` / ``v`` ``[L, B, S, KV, HD]`` (and the
+    scales of an int8 pool, the recurrent state of a hybrid) — and each layer
+    scatters one row per slot straight into its own lanes of it; a layer's
+    ``[B, S, KV, HD]`` is only read, for attention. No layer is taken out and
+    put back and no pool is rebuilt as a scan output, so a jitted caller that
+    donates the pool (``decode_chunk`` in the batcher) updates it where it
+    lies: one row of ``B × KV × HD`` per layer and step is all that is written.
+
+    Inactive rows still compute (static shapes) but their lengths do not
+    advance and their writes land in lanes the mask never exposes (for ring
+    pools the overwritten lane held a position already outside the window,
+    and its ``pos`` entry is not updated, so the garbage stays invisible); a
+    recurrent state has no mask, so a row that is not active keeps it exactly.
     """
     B = tokens.shape[0]
     S = cache.n_lanes
@@ -202,7 +210,6 @@ def decode_step(
     positions = cache.lengths[:, None]                      # [B, 1]
     x = embed_tokens(params, tokens[:, None], compute_dtype,
                      positions=positions, cfg=cfg)          # [B, 1, D]
-    layer_stack = cast_layer_stack(params, compute_dtype)
 
     if cache.ring:
         lane = cache.lengths % S
@@ -221,66 +228,20 @@ def decode_step(
             jnp.arange(S, dtype=jnp.int32)[None, :], (B, S)
         )
 
-    def write(cache_arr, new_rows):
-        # Per-row scatter at each slot's own lane (T = 1). Out-of-bounds
-        # lanes (a finished-mid-chunk row running past capacity) drop.
-        # Serves the scale arrays of a quantized pool too (same leading
-        # [B, S, KV] dims, trailing 1 instead of HD).
-        return cache_arr.at[rows, lane].set(
+    def write(cache_arr, new_rows, at):
+        # Per-row scatter at each slot's own lane of layer ``at`` (T = 1).
+        # Out-of-bounds lanes (a finished-mid-chunk row running past
+        # capacity) drop. Serves the scale arrays of a quantized pool too
+        # (same leading [L, B, S, KV] dims, trailing 1 instead of HD).
+        return cache_arr.at[at, rows, lane].set(
             new_rows[:, 0].astype(cache_arr.dtype)
         )
 
-    scales = (cache.k_scale, cache.v_scale) if cache.quantized else ()
-
-    if cfg.is_hybrid:
-        # Two kinds of layer, two kinds of state: the attention layers run
-        # the stock block against their own [L_attn, ...] pool, the Mamba-2
-        # layers one recurrence step; a row that is not active keeps its
-        # recurrent state exactly (it has no mask to hide a garbage step).
-        def attn_layer(x, lp, k_all, v_all, at):
-            # One row per slot, scattered straight into the layer's lanes of
-            # the [L_attn, B, S, KV, HD] pool: the layer's slice is read for
-            # the attention and never written back whole.
-            def write_at(cache_arr, new_rows):
-                return cache_arr.at[at, rows, lane].set(
-                    new_rows[:, 0].astype(cache_arr.dtype))
-
-            return _decode_block(
-                x, lp, k_all, v_all, write_at, slot_pos, positions, cfg,
-                read=lambda a: layer_slice(a, at))[:3]
-
-        def ssm_layer(x, lp, ssm, conv, at):
-            return _ssm_block(x, lp, ssm, conv, at, active[:, None], cfg)
-
-        x, state = scan_hybrid_layers(
-            x, layer_stack, cfg,
-            {"attn": (cache.k, cache.v), "ssm": (cache.ssm, cache.conv)},
-            attn_layer, ssm_layer)
-        logits = unembed(params, x, cfg)[:, 0]
-        return logits, dataclasses.replace(
-            cache, k=state["attn"][0], v=state["attn"][1],
-            lengths=cache.lengths + active.astype(jnp.int32),
-            ssm=state["ssm"][0], conv=state["ssm"][1])
-
-    def body(x, xs):
-        lp, k_c, v_c, *scale_cs = xs                        # k_c [B,S,KV,HD]
-        x, k_c, v_c, ks_c, vs_c = _decode_block(
-            x, lp, k_c, v_c, write, slot_pos, positions, cfg,
-            k_scale_c=scale_cs[0] if scale_cs else None,
-            v_scale_c=scale_cs[1] if scale_cs else None,
-        )
-        return x, (k_c, v_c) + ((ks_c, vs_c) if scale_cs else ())
-
-    x, out = lax.scan(body, x, (layer_stack, cache.k, cache.v) + scales)
-    k_new, v_new = out[0], out[1]
-    ks_new, vs_new = (out[2], out[3]) if cache.quantized else (None, None)
+    x, cache = scan_layers(x, cast_layer_stack(params, compute_dtype), cfg,
+                           cache, write, slot_pos, positions, active[:, None])
     logits = unembed(params, x, cfg)[:, 0]                  # [B, V] fp32
-    new_cache = SlotCache(
-        k=k_new, v=v_new,
-        lengths=cache.lengths + active.astype(jnp.int32),
-        pos=pos_new, ring=cache.ring, k_scale=ks_new, v_scale=vs_new,
-    )
-    return logits, new_cache
+    return logits, dataclasses.replace(
+        cache, lengths=cache.lengths + active.astype(jnp.int32), pos=pos_new)
 
 
 def _pick_tokens(
@@ -366,7 +327,9 @@ def decode_verify(
     and the next round's chain overwrites them before exposure).
     Non-ring pools only (speculative serving rejects window models), and
     attention-only stacks: rewinding to the accepted frontier is free for
-    keys and values and impossible for a recurrent state."""
+    keys and values and impossible for a recurrent state. The pool is
+    carried through the layer walk as in :func:`decode_step`; a layer's
+    ``[B, T]`` rows are one scatter into its lanes of it."""
     refuse_recurrent(cfg, "the speculative verify pass (decode_verify)")
     B, T = tokens.shape
     S = cache.n_lanes
@@ -374,37 +337,20 @@ def decode_verify(
     positions = cache.lengths[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     x = embed_tokens(params, tokens, compute_dtype,
                      positions=positions, cfg=cfg)  # [B, T, D]
-    layer_stack = cast_layer_stack(params, compute_dtype)
     slot_pos = jnp.broadcast_to(
         jnp.arange(S, dtype=jnp.int32)[None, :], (B, S)
     )
 
-    def write(cache_arr, new_rows):  # new_rows [B, T, KV, HD] (or [.., 1])
-        return cache_arr.at[rows[:, None], positions].set(
+    def write(cache_arr, new_rows, at):  # new_rows [B, T, KV, HD] (or [.., 1])
+        return cache_arr.at[at, rows[:, None], positions].set(
             new_rows.astype(cache_arr.dtype)
         )
 
-    scales = (cache.k_scale, cache.v_scale) if cache.quantized else ()
-
-    def body(x, xs):
-        lp, k_c, v_c, *scale_cs = xs
-        x, k_c, v_c, ks_c, vs_c = _decode_block(
-            x, lp, k_c, v_c, write, slot_pos, positions, cfg,
-            k_scale_c=scale_cs[0] if scale_cs else None,
-            v_scale_c=scale_cs[1] if scale_cs else None,
-        )
-        return x, (k_c, v_c) + ((ks_c, vs_c) if scale_cs else ())
-
-    x, out = lax.scan(body, x, (layer_stack, cache.k, cache.v) + scales)
-    k_new, v_new = out[0], out[1]
-    ks_new, vs_new = (out[2], out[3]) if cache.quantized else (None, None)
+    x, cache = scan_layers(x, cast_layer_stack(params, compute_dtype), cfg,
+                           cache, write, slot_pos, positions)
     logits = unembed(params, x, cfg)  # [B, T, V] fp32
-    new_cache = SlotCache(
-        k=k_new, v=v_new,
-        lengths=cache.lengths + T * active.astype(jnp.int32),
-        pos=None, ring=False, k_scale=ks_new, v_scale=vs_new,
-    )
-    return logits, new_cache
+    return logits, dataclasses.replace(
+        cache, lengths=cache.lengths + T * active.astype(jnp.int32))
 
 
 def speculative_round(
@@ -712,7 +658,9 @@ class ContinuousBatcher:
     ``mesh`` (optional) serves models larger than one chip: pass the
     training job's mesh and its sharded params; the KV pool shards
     kv-heads over the ``model`` axis and all dispatches pin their
-    out-shardings (donated, so the pool never copies).
+    out-shardings. The pool is donated to every dispatch and carried
+    through the layer walk (``generate.scan_layers``), which writes a step's
+    rows in place: the pool is never copied, whole or by the layer.
     """
 
     def __init__(
