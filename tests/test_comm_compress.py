@@ -157,38 +157,73 @@ def test_qwz_only_loss_parity(baseline_run):
         assert abs(b - c) < 0.05
 
 
+def _abstract_step_args(prog, state):
+    batch = jax.ShapeDtypeStruct(prog.global_batch_shape(), jnp.int32)
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), state), batch
+
+
 def test_int8_on_wire_and_cross_slice_reduction(baseline_run, compressed_run):
-    """The compiled step's HLO must show int8 all-gathers (the wire dtype
-    IS the operand dtype — a dequant fused below the gather would move
-    fp32), and ring-model byte accounting must show the ≥3x cross-slice
-    reduction the subsystem exists for."""
+    """The compressed step must gather int8 across slices (the wire dtype IS
+    the operand dtype — a dequant below the gather would move fp32), and
+    ring-model byte accounting must show the ≥3x cross-slice reduction the
+    subsystem exists for.
+
+    Read from the step as LOWERED, before XLA's passes: the shard_map's
+    collectives stand there with their operands and groups, once each and on
+    the layer-stacked arrays, whatever the CPU compiler later combines into
+    tuples or leaves inside a loop body (the text of the optimised module,
+    which this test used to parse, counts a loop's collective once for all
+    its trips). The baseline's gradient all-reduce exists only after GSPMD's
+    partitioner has run, so it is priced by the same ring model from the
+    parameters' shapes: fp32, every device's fsdp shard, over the data axis."""
     base_prog, base_state, _ = baseline_run
     comp_prog, comp_state, _ = compressed_run
-    slice_of = cc.slice_of_partition(
-        dict(comp_prog.mesh.shape), comp_prog.config.mesh.dcn_data
-    )
+    mesh = dict(comp_prog.mesh.shape)
+    slice_of = cc.slice_of_partition(mesh, comp_prog.config.mesh.dcn_data)
     assert slice_of == [0, 0, 0, 0, 1, 1, 1, 1]
 
-    def hlo_of(prog, state):
-        batch = jax.ShapeDtypeStruct(prog.global_batch_shape(), jnp.int32)
-        return prog.step.lower(
-            jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), state
-            ),
-            batch,
-        ).compile().as_text()
+    lowered = comp_prog.step.lower(*_abstract_step_args(comp_prog, comp_state))
+    comp_stats = cc.collective_stats(
+        lowered.compiler_ir(dialect="hlo").as_hlo_text(), slice_of)
+    crossing = [o for o in comp_stats["collectives"]
+                if o["cross_slice"] and o["payload_bytes"] > 4]  # not the loss's scalar psum
+    assert crossing and {o["op"] for o in crossing} == {"all-gather"}
+    # int8 codes and their fp32 per-block scales, nothing else, and the codes
+    # are 64/4 = 16 times the scales' bytes (comm_quant_block_size=64).
+    assert {o["dtype"] for o in crossing} == {"s8", "f32"}
+    by_dtype = {d: sum(o["payload_bytes"] for o in crossing if o["dtype"] == d) for d in ("s8", "f32")}
+    assert by_dtype["s8"] == 16 * by_dtype["f32"]
 
-    comp_hlo = hlo_of(comp_prog, comp_state)
-    assert "s8[" in comp_hlo and "all-gather" in comp_hlo
-    comp_stats = cc.collective_stats(comp_hlo, slice_of)
-    base_stats = cc.collective_stats(hlo_of(base_prog, base_state), slice_of)
-    assert base_stats["cross_slice_bytes"] > 0
-    reduction = base_stats["cross_slice_bytes"] / max(
-        comp_stats["cross_slice_bytes"], 1
-    )
-    assert reduction >= 3.0, (base_stats, comp_stats)
-    # Total wire volume must shrink too, not just move intra-slice.
-    assert comp_stats["total_wire_bytes"] < base_stats["total_wire_bytes"]
+    n_params = sum(a.size for a in jax.tree.leaves(base_state["params"]))
+    g = mesh["data"]
+    base_cross = 2 * (g - 1) / g * 4 * n_params / mesh["fsdp"]
+    reduction = base_cross / comp_stats["cross_slice_bytes"]
+    assert reduction >= 3.0, (base_cross, comp_stats["cross_slice_bytes"])
+    # Total wire volume must shrink too, not just move intra-slice: the
+    # compiled modules, tuple results counted (a loop body once on both sides).
+    compiled = [cc.collective_stats(
+        prog.step.lower(*_abstract_step_args(prog, state)).compile().as_text(), slice_of)
+        for prog, state in ((base_prog, base_state), (comp_prog, comp_state))]
+    assert compiled[0]["cross_slice_bytes"] > compiled[1]["cross_slice_bytes"] > 0
+    assert compiled[1]["total_wire_bytes"] < compiled[0]["total_wire_bytes"]
+
+
+@pytest.mark.parametrize("result, payload", [
+    ("f32[4,32]{1,0}", 512),
+    ("(f32[4,32,64]{2,1,0}, f32[4,32,64]{2,1,0}, /*index=2*/f32[64]{0})", 2 * 32768 + 256),
+    ("s8[2,2,32,128]{3,2,1,0}", 16384),
+], ids=["array", "combined-tuple", "int8"])
+def test_collective_stats_counts_every_member_of_a_tuple_result(result, payload):
+    """XLA combines the gradient all-reduces into one with a tuple result;
+    it moved all of the members' bytes, not none."""
+    line = (f"  %all-reduce.259 = {result} all-reduce(%bitcast.3, %bitcast.6), channel_id=2, "
+            "replica_groups=[2,4]<=[8], use_global_device_ids=true, to_apply=%add.clone")
+    stats = cc.collective_stats(line, [0, 0, 0, 0, 1, 1, 1, 1])
+    (op,) = stats["collectives"]
+    assert op["payload_bytes"] == payload and op["group_size"] == 4 and not op["cross_slice"]
+    assert stats["total_wire_bytes"] == int(payload * 2 * 3 / 4) and stats["cross_slice_bytes"] == 0
+    crossing = cc.collective_stats(line.replace("[2,4]<=[8]", "[4,2]<=[2,4]T(1,0)"), [0, 0, 0, 0, 1, 1, 1, 1])
+    assert crossing["collectives"][0]["group_size"] == 2 and crossing["cross_slice_bytes"] == payload
 
 
 def test_hpz_store_consistency(compressed_run):

@@ -259,11 +259,11 @@ def test_handoff_to_cache_buckets_and_pads():
         h, dtype=jnp.float32, kv_quant=False, chunk=4, max_lanes=16
     )
     # T=5 buckets up to the next chunk multiple (8), not max_lanes.
-    assert cache.k.shape == (2, 1, 8, 2, 4)
+    assert cache.layers["attn"]["k"].shape == (2, 1, 8, 2, 4)
     assert int(cache.length) == 5 and not cache.ring
-    np.testing.assert_allclose(np.asarray(cache.k[:, 0, :5]), k, rtol=1e-6)
-    assert np.all(np.asarray(cache.k[:, 0, 5:]) == 0)  # padding lanes
-    assert cache.k_scale is None
+    np.testing.assert_allclose(np.asarray(cache.layers["attn"]["k"][:, 0, :5]), k, rtol=1e-6)
+    assert np.all(np.asarray(cache.layers["attn"]["k"][:, 0, 5:]) == 0)  # padding lanes
+    assert "k_scale" not in cache.layers["attn"]
 
 
 def test_handoff_to_cache_quantizes_fp_wire_for_int8_pool():
@@ -273,10 +273,10 @@ def test_handoff_to_cache_quantizes_fp_wire_for_int8_pool():
     cache = handoff_to_cache(
         h, dtype=jnp.float32, kv_quant=True, chunk=8, max_lanes=8
     )
-    assert cache.k.dtype == jnp.int8
-    assert cache.k_scale is not None
-    deq = (np.asarray(cache.k[:, 0, :5], dtype=np.float32)
-           * np.asarray(cache.k_scale[:, 0, :5]))
+    assert cache.layers["attn"]["k"].dtype == jnp.int8
+    assert "k_scale" in cache.layers["attn"]
+    deq = (np.asarray(cache.layers["attn"]["k"][:, 0, :5], dtype=np.float32)
+           * np.asarray(cache.layers["attn"]["k_scale"][:, 0, :5]))
     assert np.max(np.abs(deq - k)) <= np.max(np.abs(k)) / 127 + 1e-6
 
 
@@ -287,8 +287,8 @@ def test_handoff_to_cache_dequantizes_int8_wire_for_fp_pool():
     cache = handoff_to_cache(
         h, dtype=jnp.float32, kv_quant=False, chunk=8, max_lanes=8
     )
-    assert cache.k.dtype == jnp.float32
-    got = np.asarray(cache.k[:, 0, :5])
+    assert cache.layers["attn"]["k"].dtype == jnp.float32
+    got = np.asarray(cache.layers["attn"]["k"][:, 0, :5])
     assert np.max(np.abs(got - k)) <= np.max(np.abs(k)) / 127 + 1e-6
 
 

@@ -245,7 +245,7 @@ def test_ring_decode_requires_full_window():
     cfg, params, tokens = _setup(S=8)
     cfg = cfg.with_(sliding_window=8)
     small = init_cache(cfg, 2, 4, dtype=jnp.float32)
-    small = KVCache(k=small.k, v=small.v, pos=small.pos, length=small.length,
+    small = KVCache(layers=small.layers, pos=small.pos, length=small.length,
                     ring=True)  # force ring with M=4 < window=8
     with pytest.raises(ValueError, match="cache slots"):
         forward_with_cache(params, tokens[:, :1], small, cfg,
@@ -365,8 +365,9 @@ def test_int8_kv_cache_close_to_full_precision():
     B, S = tokens.shape
     c_full = init_cache(cfg, B, S, dtype=jnp.float32)
     c_q = init_cache(cfg, B, S, dtype=jnp.float32, kv_quant=True)
-    assert c_q.k.dtype == jnp.int8 and c_q.quantized
-    assert c_q.k_scale.shape == c_q.k.shape[:-1] + (1,)
+    kv = c_q.layers["attn"]
+    assert kv["k"].dtype == jnp.int8 and c_q.quantized
+    assert kv["k_scale"].shape == kv["k"].shape[:-1] + (1,)
     l_full, _ = forward_with_cache(params, tokens, c_full, cfg, jnp.float32)
     l_q, _ = forward_with_cache(params, tokens, c_q, cfg, jnp.float32)
     scale = float(jnp.max(jnp.abs(l_full)))

@@ -124,7 +124,8 @@ def test_forward_with_cache_over_a_whole_prompt_equals_the_reference(tiny, n):
     logits, cache = forward_with_cache(params, jnp.asarray(toks)[None], init_cache(mc, 1, 128, dtype=F32),
                                        mc, compute_dtype=F32)
     assert np.abs(np.asarray(logits[0]) - _reference_rows(tiny, toks, 0, n)).max() < TOL
-    assert cache.k.shape[0] == mc.n_attn_layers == 1 and cache.ssm.shape[0] == mc.n_ssm_layers == 3
+    assert cache.layers["attn"]["k"].shape[0] == mc.n_attn_layers == 1
+    assert cache.layers["ssm"]["ssm"].shape[0] == mc.n_ssm_layers == 3
 
 
 # (b) chunked prefill with pad positions, insert, >= 40 decode steps ---------
@@ -186,8 +187,9 @@ def test_a_slots_recurrent_state_is_its_own_requests(tiny, scenario):
     if scenario == "reused_after_a_longer_request":
         _, pool = _decode_logits(params, mc, pool, 0, a[70:90])
         pool = serving._reset_slot(pool, 0)
-        assert float(jnp.abs(pool.ssm[:, 0]).max()) == 0.0 and float(jnp.abs(pool.conv[:, 0]).max()) == 0.0
-        assert float(jnp.abs(pool.ssm[:, 1]).max()) == 0.0  # nothing leaked to a neighbour either
+        rec = pool.layers["ssm"]
+        assert float(jnp.abs(rec["ssm"][:, 0]).max()) == 0.0 and float(jnp.abs(rec["conv"][:, 0]).max()) == 0.0
+        assert float(jnp.abs(rec["ssm"][:, 1]).max()) == 0.0  # nothing leaked to a neighbour either
     elif scenario == "finished_mid_chunk_then_reused":
         # The request in slot 0 "finishes" after 3 of a chunk's 8 steps: the
         # device runs all 8 (static shapes), so the slot's state overshoots;
@@ -203,12 +205,13 @@ def test_a_slots_recurrent_state_is_its_own_requests(tiny, scenario):
     if scenario == "inactive_neighbour":
         # slot 0 holds request a and is NOT active while b decodes beside it:
         # its state must not move, and when it resumes its logits are right.
-        before = (np.asarray(pool.ssm[:, 0]), np.asarray(pool.conv[:, 0]), np.asarray(pool.k[:, 0, :70]))
+        row0 = lambda pool: (np.asarray(pool.layers["ssm"]["ssm"][:, 0]), np.asarray(pool.layers["ssm"]["conv"][:, 0]),  # noqa: E731
+                             np.asarray(pool.layers["attn"]["k"][:, 0, :70]))
+        before = row0(pool)
     logits, pool = _decode_logits(params, mc, pool, slot, b[30:60])
     assert np.abs(np.asarray(logits) - _reference_rows(tiny, b, 30, 30)).max() < TOL
     if scenario == "inactive_neighbour":
-        assert (np.asarray(pool.ssm[:, 0]) == before[0]).all() and (np.asarray(pool.conv[:, 0]) == before[1]).all()
-        assert (np.asarray(pool.k[:, 0, :70]) == before[2]).all() and int(pool.lengths[0]) == 70
+        assert all((now == was).all() for now, was in zip(row0(pool), before)) and int(pool.lengths[0]) == 70
         logits_a, _ = _decode_logits(params, mc, pool, 0, a[70:90])
         assert np.abs(np.asarray(logits_a) - _reference_rows(tiny, a, 70, 20)).max() < TOL
 
@@ -227,7 +230,8 @@ def test_control_a_state_dropped_at_a_chunk_boundary_fails(tiny, dropped):
         _, c1 = forward_with_cache(params, jnp.asarray(padded), init_cache(mc, 1, 96, dtype=F32), mc,
                                    compute_dtype=F32)
     else:
-        zero = lambda c1: dataclasses.replace(c1, **{dropped: jnp.zeros_like(getattr(c1, dropped))})  # noqa: E731
+        zero = lambda c1: dataclasses.replace(c1, layers={**c1.layers, "ssm": {  # noqa: E731
+            **c1.layers["ssm"], dropped: jnp.zeros_like(c1.layers["ssm"][dropped])}})
         _, c1 = _prefill(params, mc, toks[:70], spoil=zero)
     pool = _insert(_pool(mc), c1, 1, 70)
     logits, _ = _decode_logits(params, mc, pool, 1, toks[70:80])
@@ -267,7 +271,7 @@ def test_the_engine_passes_each_chunks_real_length(tiny):
     assert worst_gap(engine) < 1e-7
     st = engine.stats()
     assert st["state_inserts_total"] == 4 and st["state_resets_total"] == 4
-    assert st["recurrent_state_bytes"] == engine._cache.ssm.nbytes + engine._cache.conv.nbytes > 0
+    assert st["recurrent_state_bytes"] == sum(a.nbytes for a in engine._cache.layers["ssm"].values()) > 0
 
     blind = make()
     fn = blind._prefill_fn
@@ -329,9 +333,10 @@ def test_param_count_and_the_serving_estimate_price_what_is_allocated(tiny):
     finally:
         del tfm.MODEL_CONFIGS[mc.name]
     nbytes = lambda *arrs: sum(a.size * a.dtype.itemsize for a in arrs) / 2**30  # noqa: E731
-    assert est.kv_pool_gib == pytest.approx(nbytes(pool.k, pool.v), abs=1e-4)
-    assert est.recurrent_state_gib == pytest.approx(nbytes(pool.ssm, pool.conv), abs=1e-4)
-    assert est.recurrent_state_gib > 0.09 and pool.ssm.dtype == jnp.float32 and pool.conv.dtype == jnp.bfloat16
+    kv, rec = pool.layers["attn"], pool.layers["ssm"]
+    assert est.kv_pool_gib == pytest.approx(nbytes(kv["k"], kv["v"]), abs=1e-4)
+    assert est.recurrent_state_gib == pytest.approx(nbytes(rec["ssm"], rec["conv"]), abs=1e-4)
+    assert est.recurrent_state_gib > 0.09 and rec["ssm"].dtype == jnp.float32 and rec["conv"].dtype == jnp.bfloat16
     assert est.device_total_gib >= est.params_gib + est.kv_pool_gib + est.recurrent_state_gib
 
 

@@ -409,7 +409,7 @@ def test_mesh_sharded_serving_matches_single_device():
                             compute_dtype=jnp.float32, prefill_pad_to=16,
                             chunk_steps=3, mesh=mesh)
     # The pool really is sharded: kv-heads dim carries the model axis.
-    assert srv._cache.k.sharding.spec == jax.sharding.PartitionSpec(
+    assert srv._cache.layers["attn"]["k"].sharding.spec == jax.sharding.PartitionSpec(
         None, None, None, "model", None
     )
     assert srv.stats()["sharded"] is True
@@ -475,7 +475,7 @@ def test_kv_quant_pool_matches_generate_kv_quant():
     srv = ContinuousBatcher(params, cfg, max_slots=2, max_len=96,
                             compute_dtype=jnp.float32, prefill_pad_to=16,
                             chunk_steps=4, kv_quant=True)
-    assert srv._cache.quantized and srv._cache.k.dtype == jnp.int8
+    assert srv._cache.quantized and srv._cache.layers["attn"]["k"].dtype == jnp.int8
     assert srv.stats()["kv_quant"] is True
     rng = np.random.default_rng(21)
     prompts = [rng.integers(1, cfg.vocab_size, n).tolist() for n in (5, 11, 3)]
@@ -556,7 +556,7 @@ def test_kv_quant_sharded_pool():
     srv = ContinuousBatcher(sharded, cfg, max_slots=2, max_len=96,
                             compute_dtype=jnp.float32, prefill_pad_to=16,
                             chunk_steps=3, mesh=mesh, kv_quant=True)
-    assert srv._cache.k_scale.sharding.spec == jax.sharding.PartitionSpec(
+    assert srv._cache.layers["attn"]["k_scale"].sharding.spec == jax.sharding.PartitionSpec(
         None, None, None, "model", None
     )
     p = [5, 11, 3, 8, 2]
@@ -1082,11 +1082,14 @@ def _walk_case(fn, ring, kv_quant, G=1, seed=0):
     keys = jax.random.split(jax.random.PRNGKey(seed + 1), 5)
 
     def fill(empty):  # random keys and values (int8: codes and scales)
+        kv = empty.layers["attn"]
         if kv_quant:
-            code = lambda k: jax.random.randint(k, empty.k.shape, -127, 128).astype(jnp.int8)  # noqa: E731
-            scale = lambda k: jax.random.uniform(k, empty.k_scale.shape, jnp.float32, 0.002, 0.02)  # noqa: E731
-            return dict(k=code(keys[0]), v=code(keys[1]), k_scale=scale(keys[2]), v_scale=scale(keys[3]))
-        return dict(k=jax.random.normal(keys[0], empty.k.shape), v=jax.random.normal(keys[1], empty.v.shape))
+            code = lambda k: jax.random.randint(k, kv["k"].shape, -127, 128).astype(jnp.int8)  # noqa: E731
+            scale = lambda k: jax.random.uniform(k, kv["k_scale"].shape, jnp.float32, 0.002, 0.02)  # noqa: E731
+            attn = dict(k=code(keys[0]), v=code(keys[1]), k_scale=scale(keys[2]), v_scale=scale(keys[3]))
+        else:
+            attn = dict(k=jax.random.normal(keys[0], kv["k"].shape), v=jax.random.normal(keys[1], kv["v"].shape))
+        return {"attn": attn}
 
     def held(length, lanes):  # the position each lane of a ring holds, -1 = none
         m = np.arange(lanes)
@@ -1099,7 +1102,7 @@ def _walk_case(fn, ring, kv_quant, G=1, seed=0):
         assert empty.ring == ring
         lengths = np.asarray([11, 14, 0] if ring else [7, 3, 0], np.int32)
         pos = jnp.asarray(np.stack([held(n, empty.n_lanes) for n in lengths])) if ring else None
-        cache = serving.SlotCache(lengths=jnp.asarray(lengths), pos=pos, ring=ring, **fill(empty))
+        cache = serving.SlotCache(layers=fill(empty), lengths=jnp.asarray(lengths), pos=pos, ring=ring)
         toks = jax.random.randint(keys[4], (B,) if T == 1 else (B, T), 1, cfg.vocab_size)
         active = jnp.asarray([True, True, False])
         call = lambda p, t, a, c: getattr(serving, fn)(p, t, c, a, cfg, jnp.float32)  # noqa: E731
@@ -1111,7 +1114,7 @@ def _walk_case(fn, ring, kv_quant, G=1, seed=0):
     lanes = empty.max_len
     pos = held(length, lanes) if ring else np.where(np.arange(lanes) < length, np.arange(lanes), -1)
     cache = KVCache(pos=jnp.asarray(pos, jnp.int32), length=jnp.asarray(length, jnp.int32),
-                    ring=ring, **fill(empty))
+                    ring=ring, layers=fill(empty))
     toks = jax.random.randint(keys[4], (B, T), 1, cfg.vocab_size)
     call = lambda p, t, c: forward_with_cache(p, t, c, cfg, compute_dtype=jnp.float32)  # noqa: E731
     return cfg, params, call, (params, toks, cache)
@@ -1142,12 +1145,13 @@ def test_the_layer_scan_carries_the_pool(fn, ring, kv_quant):
         call = lambda p, t, c: serving._prefill_forward(  # noqa: E731
             p, t, c, jnp.asarray(0), cfg=cfg, compute_dtype=jnp.float32)
     jaxpr = jax.make_jaxpr(call)(*args)
-    pool_shapes = {cache.k.shape} | ({cache.k_scale.shape} if kv_quant else set())
-    lanes = cache.k.shape[2]  # no width of the model equals the lane count
+    kv = cache.layers["attn"]
+    pool_shapes = {a.shape for a in kv.values()}
+    lanes = kv["k"].shape[2]  # no width of the model equals the lane count
 
     def of_pool(v):
         shape = getattr(v.aval, "shape", ())
-        return shape in pool_shapes or (lanes in shape and v.aval.size >= cache.k.size)
+        return shape in pool_shapes or (lanes in shape and v.aval.size >= kv["k"].size)
 
     scans, made_by = [], []
 
@@ -1223,10 +1227,11 @@ def test_carried_pool_equals_a_layer_by_layer_reference(fn, ring, kv_quant, G):
     layers = []
     for l in range(cfg.n_layers):
         lp = jax.tree.map(lambda a: a[l], stack)
+        kv = cache.layers["attn"]
         x, *written = _decode_block(
-            x, lp, cache.k[l], cache.v[l], write, slot_pos, positions, cfg,
-            k_scale_c=cache.k_scale[l] if kv_quant else None,
-            v_scale_c=cache.v_scale[l] if kv_quant else None)
+            x, lp, kv["k"][l], kv["v"][l], write, slot_pos, positions, cfg,
+            k_scale_c=kv["k_scale"][l] if kv_quant else None,
+            v_scale_c=kv["v_scale"][l] if kv_quant else None)
         layers.append(written)
     want_logits = tfm.unembed(params, x, cfg)
     if fn == "decode_step":
@@ -1236,7 +1241,7 @@ def test_carried_pool_equals_a_layer_by_layer_reference(fn, ring, kv_quant, G):
 
     np.testing.assert_allclose(np.asarray(got_logits), np.asarray(want_logits), atol=2e-5, rtol=2e-5)
     for name, w in (("k", k), ("v", v), ("k_scale", k_scale), ("v_scale", v_scale), ("pos", new_pos)):
-        g = getattr(got, name)
+        g = got.pos if name == "pos" else got.layers["attn"].get(name)
         if w is None:
             assert g is None, name
         elif g.dtype == jnp.int8:  # a code may fall one step aside on round-off
@@ -1248,4 +1253,4 @@ def test_carried_pool_equals_a_layer_by_layer_reference(fn, ring, kv_quant, G):
     else:
         assert int(got.length) == int(cache.length) + T and got.ring == ring
     # The walk wrote something: the cache it returns is not the one it was given.
-    assert not np.array_equal(np.asarray(got.k), np.asarray(cache.k))
+    assert not np.array_equal(np.asarray(got.layers["attn"]["k"]), np.asarray(cache.layers["attn"]["k"]))

@@ -652,9 +652,11 @@ def _parse_groups(line: str, n_devices: int) -> list[list[int]]:
 
 
 def _payload_bytes(line: str) -> int:
-    """Total element bytes of the instruction's result (tuple-aware)."""
-    head = line.split("=", 1)[1] if "=" in line else line
-    head = head.split("(", 1)[0]
+    """Total element bytes of the instruction's result: everything between
+    ``=`` and the collective's name, so a tuple result (the all-reduces XLA
+    combines into one) counts every member."""
+    m = _COLLECTIVE_RE.search(line)
+    head = line[m.start():m.start("op")] if m else line.split("(", 1)[0]
     total = 0
     for dtype, shape in _TUPLE_SHAPE_RE.findall(head):
         if dtype not in _DTYPE_BYTES:
@@ -726,6 +728,7 @@ def collective_stats(
         ops.append({
             "op": op, "bytes": int(wire), "payload_bytes": payload,
             "group_size": g, "cross_slice": crossing,
+            "dtype": m.group("dtype"),  # None for a tuple result
         })
     return {
         "total_wire_bytes": int(total),

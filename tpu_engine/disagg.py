@@ -147,16 +147,16 @@ def extract_slot_kv(
     refuse_recurrent(cfg, "the KV handoff wire (extract_slot_kv)")
     if getattr(cache, "ring", False):
         raise ValueError("extract_slot_kv does not support ring pools")
-    k = cache.k[:, slot, :length]          # [L, T, KV, HD] device
-    v = cache.v[:, slot, :length]
+    # The attention kind's leaves, one row's resident lanes: [L, T, KV, HD].
+    kv = {name: a[:, slot, :length] for name, a in cache.layers["attn"].items()}
+    k, v = kv["k"], kv["v"]
     if cache.quantized:
         return KVHandoff(
             prompt=list(prompt), emitted=list(emitted), length=int(length),
             n_layers=cfg.n_layers, n_kv_heads=cfg.n_kv_heads,
             head_dim=cfg.head_dim, dtype="int8", quantized=True,
             k=np.asarray(k), v=np.asarray(v),
-            k_scale=np.asarray(cache.k_scale[:, slot, :length]),
-            v_scale=np.asarray(cache.v_scale[:, slot, :length]),
+            k_scale=np.asarray(kv["k_scale"]), v_scale=np.asarray(kv["v_scale"]),
             model_name=model_name,
         )
     if quantize:
@@ -244,20 +244,19 @@ def handoff_to_cache(
         return out
 
     if kv_quant:
-        k = jnp.asarray(lanes(codes_k, HD, np.int8))
-        v = jnp.asarray(lanes(codes_v, HD, np.int8))
-        k_scale = jnp.asarray(lanes(scale_k, 1, np.float32))
-        v_scale = jnp.asarray(lanes(scale_v, 1, np.float32))
+        attn = dict(k=jnp.asarray(lanes(codes_k, HD, np.int8)),
+                    v=jnp.asarray(lanes(codes_v, HD, np.int8)),
+                    k_scale=jnp.asarray(lanes(scale_k, 1, np.float32)),
+                    v_scale=jnp.asarray(lanes(scale_v, 1, np.float32)))
     else:
-        k = jnp.asarray(lanes(fp_k, HD, np.float32), dtype=dtype)
-        v = jnp.asarray(lanes(fp_v, HD, np.float32), dtype=dtype)
-        k_scale = v_scale = None
+        attn = dict(k=jnp.asarray(lanes(fp_k, HD, np.float32), dtype=dtype),
+                    v=jnp.asarray(lanes(fp_v, HD, np.float32), dtype=dtype))
 
     return KVCache(
-        k=k, v=v,
+        layers={"attn": attn},
         pos=jnp.full((M,), -1, jnp.int32),  # unused on the non-ring insert
         length=jnp.asarray(T, jnp.int32),
-        ring=False, k_scale=k_scale, v_scale=v_scale,
+        ring=False,
     )
 
 

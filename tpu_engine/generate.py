@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from tpu_engine import layer_state
 from tpu_engine.models.transformer import (
     ModelConfig,
     _dense_mlp,
@@ -59,43 +60,34 @@ _NEG_INF = -1e30
 @jax.tree_util.register_dataclass
 @dataclass
 class KVCache:
-    """Per-layer key/value cache (a pytree — crosses jit/scan boundaries).
+    """Per-layer state of ``B`` rows in lockstep (a pytree — crosses jit/scan
+    boundaries).
 
-    k/v: [L, B, slots, KV, HD]; ``pos`` [slots] holds the global position
-    stored in each slot (-1 = empty); ``length`` is the number of positions
-    already written (scalar int32). When ``ring`` is set (sliding-window
-    models whose cache is smaller than the sequence) the buffer wraps:
-    writes go to ``position % slots`` and the attention mask reads ``pos``,
-    so memory and per-step attention cost are O(window), not O(sequence).
-    Non-ring caches keep the classic contract: the caller never writes past
-    ``slots`` positions total."""
+    ``layers`` is the tree :mod:`tpu_engine.layer_state` allocates,
+    ``{kind: {leaf: [L_kind, B, ...]}}``: keys and values ``[L, B, slots, KV,
+    HD]`` for the attention layers (int8 codes beside their scales when
+    ``init_cache(kv_quant=True)``), the recurrent state of a hybrid stack's
+    Mamba-2 layers, which has no position to mask. ``pos`` [slots] holds the
+    global position stored in each slot (-1 = empty); ``length`` is the number
+    of positions already written (scalar int32). When ``ring`` is set
+    (sliding-window models whose cache is smaller than the sequence) the
+    buffer wraps: writes go to ``position % slots`` and the attention mask
+    reads ``pos``, so memory and per-step attention cost are O(window), not
+    O(sequence). Non-ring caches keep the classic contract: the caller never
+    writes past ``slots`` positions total."""
 
-    k: jax.Array
-    v: jax.Array
+    layers: dict
     pos: jax.Array
     length: jax.Array
     ring: bool = field(default=False, metadata=dict(static=True))
-    # int8-quantized cache (``init_cache(kv_quant=True)``): k/v hold int8
-    # codes and these hold the per-(slot, kv-head) absmax/127 scales
-    # [L, B, slots, KV, 1] — KV memory halves vs bf16 (+1/head_dim for
-    # scales); dequantisation fuses into the attention reads.
-    k_scale: Optional[jax.Array] = None
-    v_scale: Optional[jax.Array] = None
-    # Hybrid stacks: k/v cover the ATTENTION layers only ([L_attn, ...]) and
-    # the Mamba-2 layers carry a recurrent state instead — ``ssm``
-    # [L_ssm, B, heads, head_dim, state] float32, the state after the last
-    # REAL token fed, and ``conv`` [L_ssm, B, taps-1, conv_dim], the last
-    # taps-1 convolution inputs before it. Neither has a position to mask.
-    ssm: Optional[jax.Array] = None
-    conv: Optional[jax.Array] = None
 
     @property
     def max_len(self) -> int:
-        return self.k.shape[2]
+        return layer_state.n_lanes(self.layers)
 
     @property
     def quantized(self) -> bool:
-        return self.k_scale is not None
+        return layer_state.quantized(self.layers)
 
 
 def ring_lanes(cfg: ModelConfig, max_len: int,
@@ -130,36 +122,11 @@ def init_cache(
     if kv_quant:
         refuse_recurrent(cfg, "an int8 KV cache (kv_quant)")
     slots = ring_lanes(cfg, max_len, max_chunk)
-    shape = (cfg.n_attn_layers, batch, slots, cfg.n_kv_heads, cfg.head_dim)
-    store_dtype = jnp.int8 if kv_quant else dtype
-    scale_shape = shape[:-1] + (1,)
-    ssm, conv = init_recurrent_state(cfg, batch, dtype)
     return KVCache(
-        k=jnp.zeros(shape, store_dtype),
-        v=jnp.zeros(shape, store_dtype),
+        layers=layer_state.init_layers(cfg, batch, slots, dtype, kv_quant),
         pos=jnp.full((slots,), -1, jnp.int32),
         length=jnp.zeros((), jnp.int32),
         ring=slots < max_len,
-        k_scale=jnp.zeros(scale_shape, jnp.float32) if kv_quant else None,
-        v_scale=jnp.zeros(scale_shape, jnp.float32) if kv_quant else None,
-        ssm=ssm, conv=conv,
-    )
-
-
-def init_recurrent_state(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16):
-    """``(ssm, conv)`` zeros for ``batch`` rows of a hybrid stack's Mamba-2
-    layers — the SSM state float32 (it integrates hundreds of small updates),
-    the convolution inputs in the compute dtype — or ``(None, None)``. THE
-    one place their shapes are written: the single-row ingestion cache and
-    the serving pool both allocate here, and the slot insert copies one into
-    the other."""
-    if not cfg.is_hybrid:
-        return None, None
-    Ls = cfg.n_ssm_layers
-    return (
-        jnp.zeros((Ls, batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
-                  jnp.float32),
-        jnp.zeros((Ls, batch, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype),
     )
 
 
@@ -468,7 +435,8 @@ def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
                 valid=None):
     """Walk the stack against (and into) ``cache`` — THE one cached walk, for
     every architecture, a :class:`KVCache` or the serving pool alike (both
-    name their per-layer arrays ``k v k_scale v_scale ssm conv``).
+    hold their per-layer arrays as ``cache.layers``, the tree by kind of
+    :mod:`tpu_engine.layer_state`).
 
     The stack is walked by RUNS of like layers (``cfg.layer_runs()``): one
     ``lax.scan`` per run — a stack of one kind is one run and one loop; 5 ssm,
@@ -476,7 +444,7 @@ def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
     blocks. What differs between callers is data: the run list, whether scale
     arrays ride along, which lanes ``write`` picks.
 
-    - CARRIED: ``x`` and the cache's per-kind state, whole — keys and values
+    - CARRIED: ``x`` and ``cache.layers``, whole — keys and values
       ``[L_attn, B, M, KV, HD]`` (with their scales for an int8 cache), the
       recurrent ``ssm`` / ``conv`` ``[L_ssm, B, ...]``. Nothing of the cache is
       a scan input or output, so no run rebuilds it and the loop updates the
@@ -492,37 +460,38 @@ def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
       ``first + i`` (for a run that is a whole stack that is what scanning it
       as ``xs`` lowers to).
 
-    ``valid`` [B, T] marks the real positions for the recurrent layers (which
-    have no mask); attention-only callers may leave it out. Returns
-    ``(x, cache)`` with the cache's per-layer arrays replaced."""
+    A kind's layer function takes ``(x, lp, at, leaves)``, the kind's leaves
+    whole, and returns ``(x, leaves)``. ``valid`` [B, T] marks the real
+    positions for the recurrent layers (which have no mask); attention-only
+    callers may leave it out. Returns ``(x, cache)`` with ``cache.layers``
+    replaced."""
     stacks = stacks if "ssm" in stacks else {"attn": stacks}
 
-    def attn_layer(x, lp, at, k, v, k_scale, v_scale):
-        return _decode_block(
-            x, lp, k, v, lambda arr, rows: write(arr, rows, at), slot_pos,
-            positions, cfg, k_scale_c=k_scale, v_scale_c=v_scale,
+    def attn_layer(x, lp, at, s):
+        x, k, v, k_scale, v_scale = _decode_block(
+            x, lp, s["k"], s["v"], lambda arr, rows: write(arr, rows, at), slot_pos,
+            positions, cfg, k_scale_c=s.get("k_scale"), v_scale_c=s.get("v_scale"),
             read=lambda arr: layer_slice(arr, at))
+        new = {"k": k, "v": v, "k_scale": k_scale, "v_scale": v_scale}
+        return x, {name: new[name] for name in s}  # scales only where they came in
 
-    def ssm_layer(x, lp, at, ssm, conv):
-        return _ssm_block(x, lp, ssm, conv, at, valid, cfg)
+    def ssm_layer(x, lp, at, s):
+        x, ssm, conv = _ssm_block(x, lp, s["ssm"], s["conv"], at, valid, cfg)
+        return x, {"ssm": ssm, "conv": conv}
 
     layer_fns = {"attn": attn_layer, "ssm": ssm_layer}
-    state = {"attn": (cache.k, cache.v, cache.k_scale, cache.v_scale),
-             "ssm": (cache.ssm, cache.conv)}
+    state = cache.layers
     for kind, first, count in cfg.layer_runs():
 
         def body(carry, i, kind=kind, first=first):
             x, state = carry
             at = first + i
             lp = jax.tree.map(lambda a: layer_slice(a, at), stacks[kind])
-            x, *arrays = layer_fns[kind](x, lp, at, *state[kind])
-            return (x, {**state, kind: tuple(arrays)}), None
+            x, leaves = layer_fns[kind](x, lp, at, state[kind])
+            return (x, {**state, kind: leaves}), None
 
         (x, state), _ = lax.scan(body, (x, state), jnp.arange(count, dtype=jnp.int32))
-    k, v, k_scale, v_scale = state["attn"]
-    ssm, conv = state["ssm"]
-    return x, dataclasses.replace(cache, k=k, v=v, k_scale=k_scale,
-                                  v_scale=v_scale, ssm=ssm, conv=conv)
+    return x, dataclasses.replace(cache, layers=state)
 
 
 def forward_with_cache(

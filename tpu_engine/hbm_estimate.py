@@ -432,6 +432,7 @@ def estimate_serving_hbm(
     Returns None for unknown model names — the scheduler then degrades the
     serving submission to capacity-only admission, same as training.
     """
+    from tpu_engine import layer_state
     from tpu_engine.generate import ring_lanes
     from tpu_engine.models import transformer as tfm
 
@@ -462,41 +463,30 @@ def estimate_serving_hbm(
     else:
         params_dev = n_params * compute_b / tp
 
-    # KV pool: k and v, [L, slots, lanes, KV, HD]; kv-heads shard over the
-    # model axis only when divisible (serving.py falls back to replicated).
-    # Only ATTENTION layers keep keys and values (all of them, unless the
-    # model has a layer pattern).
+    # The slot pool: what ``serving.init_slot_cache`` allocates, priced from
+    # the one table of layer kinds (``layer_state``) — a positional kind's
+    # lanes (keys and values of the ATTENTION layers, int8 codes beside fp32
+    # scales when ``kv_quant``; kv-heads shard over the model axis only when
+    # divisible, else replicated) and a whole kind's state of every slot,
+    # whatever the occupancy (a hybrid stack's Mamba-2 layers, unsharded).
     lanes = ring_lanes(cfg, int(max_len), int(prefill_chunk))
-    kv_shard = tp if cfg.n_kv_heads % tp == 0 else 1
-    if kv_shard == 1 and tp > 1:
+    dtype = dtype_of(compute_dtype)
+    if cfg.n_kv_heads % tp and tp > 1:
         notes.append(f"kv pool replicated: {cfg.n_kv_heads} kv-heads !% model={tp}")
-    kv_cells = 2 * cfg.n_attn_layers * slots * lanes * cfg.n_kv_heads * cfg.head_dim
     if kv_quant:
-        # int8 codes + fp32 scale per (lane, kv-head) row of each of k/v.
-        kv_pool = kv_cells * 1 + 2 * cfg.n_attn_layers * slots * lanes * cfg.n_kv_heads * 4
         notes.append("kv pool: int8 codes + per-(lane, kv-head) fp32 scales")
-    else:
-        kv_pool = kv_cells * compute_b
-    kv_pool /= kv_shard
-    # A hybrid stack's recurrent layers: every slot's SSM state in float32
-    # and convolution inputs at the compute dtype, whatever the occupancy —
-    # the arrays ``generate.init_recurrent_state`` allocates, unsharded.
-    recurrent = 0.0
-    if cfg.is_hybrid:
-        recurrent = float(cfg.n_ssm_layers * slots * (
-            cfg.ssm_heads * cfg.ssm_head_dim * cfg.ssm_state * 4
-            + (cfg.ssm_conv - 1) * cfg.ssm_conv_dim * compute_b))
+    kv_pool, recurrent = layer_state.split_bytes(
+        layer_state.state_bytes(cfg, slots, lanes, dtype, kv_quant, tp))
+    if recurrent:
         notes.append(
             f"recurrent state: {cfg.n_ssm_layers} Mamba-2 layers x {slots} "
             f"slots (float32 SSM state + convolution inputs); keys and "
             f"values for the {cfg.n_attn_layers} attention layers only")
     if prefix_cache_tokens > 0:
-        # Shared-prefix entries are extra KV lanes outside the slot pool,
+        # Shared-prefix entries are extra lanes outside the slot pool,
         # bounded by the token budget (eviction enforces it).
-        per_tok = 2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim
-        per_tok = per_tok * 1 + 2 * cfg.n_layers * cfg.n_kv_heads * 4 if kv_quant \
-            else per_tok * compute_b
-        kv_pool += prefix_cache_tokens * per_tok / kv_shard
+        kv_pool += prefix_cache_tokens * layer_state.split_bytes(
+            layer_state.state_bytes(cfg, 1, 1, dtype, kv_quant, tp))[0]
 
     # Decode/prefill workspace: one prefill chunk's layer activations for
     # the widest dispatch plus every slot's fp32 logits row. A prefill
@@ -523,8 +513,8 @@ def estimate_serving_hbm(
         # pool at the draft's geometry — init_slot_cache(draft_cfg, ...)
         # in ContinuousBatcher, always unquantized.
         draft_lanes = ring_lanes(draft_cfg, int(max_len), int(prefill_chunk))
-        draft_kv = (2 * draft_cfg.n_layers * slots * draft_lanes
-                    * draft_cfg.n_kv_heads * draft_cfg.head_dim * compute_b)
+        draft_kv = sum(layer_state.state_bytes(
+            draft_cfg, slots, draft_lanes, dtype).values())
         draft_bytes = tfm.param_count(draft_cfg) * compute_b + draft_kv
         notes.append(
             f"speculative: draft {draft_model_name} colocated "

@@ -1,0 +1,222 @@
+"""The format of a slot's per-layer state, in one place.
+
+A request keeps state per LAYER while it is served, and what it keeps depends
+on the kind of layer: an attention layer keeps keys and values for every
+position, a Mamba-2 layer keeps one recurrent state whatever the length. Both
+caches (:class:`generate.KVCache`, one row in lockstep;
+:class:`serving.SlotCache`, a pool of rows with their own lengths) hold that
+state as ONE tree, ``{kind: {leaf: array [L_kind, rows, ...]}}``, allocated,
+inserted, reset, sliced, sharded and priced by the functions below from the
+one table :data:`LAYER_KINDS`. ``kind`` is the name ``ModelConfig.layer_runs()``
+yields; a kind the stack does not have is absent from the tree.
+
+A kind is **positional** (axis 2 of every leaf is a lane axis, and a row's
+length or ``pos`` hides what lies beyond it: an insert copies lanes, a reset
+needs nothing, a prefix can be sliced out and pasted, a verify pass rewound)
+or **whole** (no lane: an insert overwrites the row's state, a reset zeroes
+it, nothing can be sliced or rewound — which is all that
+``transformer.refuse_recurrent`` asks).
+
+A new kind of state is one entry here and a layer function in
+``generate.scan_layers``; the cache manager, the wire's two ends and the
+estimator read the table.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Callable, Iterable, NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+
+class Leaf(NamedTuple):
+    """One array a kind keeps: ``shape`` follows ``[L_kind, rows]``;
+    ``model_dim`` is the index (into ``shape``) of the dim that shards over
+    the mesh's ``model`` axis when divisible, None = replicated."""
+
+    shape: tuple
+    dtype: Any
+    model_dim: Optional[int] = None
+
+
+class LayerKind(NamedTuple):
+    positional: bool
+    leaves: Callable[..., dict]  # (cfg, lanes, dtype, kv_quant) -> {name: Leaf}
+
+
+def _attn_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
+    """Keys and values per lane and kv-head — or int8 codes with one float32
+    absmax/127 scale per (lane, kv-head), half the bytes of bf16."""
+    rows = Leaf((lanes, cfg.n_kv_heads, cfg.head_dim),
+                jnp.int8 if kv_quant else dtype, model_dim=1)
+    leaves = {"k": rows, "v": rows}
+    if kv_quant:
+        scales = Leaf((lanes, cfg.n_kv_heads, 1), jnp.float32, model_dim=1)
+        leaves.update(k_scale=scales, v_scale=scales)
+    return leaves
+
+
+def _ssm_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
+    """A Mamba-2 layer's state after the last REAL token fed — float32, it
+    integrates hundreds of small updates — and the last taps-1 convolution
+    inputs before it, in the compute dtype."""
+    return {
+        "ssm": Leaf((cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32),
+        "conv": Leaf((cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype),
+    }
+
+
+LAYER_KINDS: dict[str, LayerKind] = {
+    "attn": LayerKind(positional=True, leaves=_attn_leaves),
+    "ssm": LayerKind(positional=False, leaves=_ssm_leaves),
+}
+
+
+# Questions asked of a stack (its kinds).
+
+def layer_counts(cfg) -> dict[str, int]:
+    """Layers of each kind in ``cfg``'s stack, in order of first appearance."""
+    counts: dict[str, int] = {}
+    for kind, _, count in cfg.layer_runs():
+        counts[kind] = counts.get(kind, 0) + count
+    return counts
+
+
+def keeps_whole_state(kinds: Iterable[str]) -> bool:
+    """Does some kind among ``kinds`` keep a state with no lanes to slice,
+    mask or rewind?"""
+    return any(not LAYER_KINDS[kind].positional for kind in kinds)
+
+
+# The one allocation, and its price.
+
+def leaf_specs(cfg, kinds: Iterable[str], lanes: int, dtype,
+               kv_quant: bool = False) -> dict:
+    """``{kind: {leaf: Leaf}}``: what each of ``kinds`` keeps for one row."""
+    return {kind: LAYER_KINDS[kind].leaves(cfg, lanes, dtype, kv_quant)
+            for kind in kinds}
+
+
+def init_layers(cfg, rows: int, lanes: int, dtype, kv_quant: bool = False,
+                counts: Optional[dict] = None) -> dict:
+    """Zeros for ``rows`` rows: ``{kind: {leaf: [L_kind, rows, *shape]}}``, for
+    ``cfg``'s stack (or for ``counts``, kind -> layers)."""
+    counts = layer_counts(cfg) if counts is None else counts
+    return {kind: {name: jnp.zeros((counts[kind], rows) + leaf.shape, leaf.dtype)
+                   for name, leaf in leaves.items()}
+            for kind, leaves in leaf_specs(cfg, counts, lanes, dtype, kv_quant).items()}
+
+
+def _model_sharded(leaf: Leaf, tp: int) -> bool:
+    return leaf.model_dim is not None and leaf.shape[leaf.model_dim] % tp == 0
+
+
+def state_bytes(cfg, rows: int, lanes: int, dtype, kv_quant: bool = False,
+                tp: int = 1, counts: Optional[dict] = None) -> dict:
+    """Bytes per device of what :func:`init_layers` allocates, by kind, with
+    the ``model`` axis ``tp`` wide."""
+    counts = layer_counts(cfg) if counts is None else counts
+    return {kind: sum(counts[kind] * rows * math.prod(leaf.shape)
+                      * jnp.dtype(leaf.dtype).itemsize
+                      / (tp if _model_sharded(leaf, tp) else 1)
+                      for leaf in leaves.values())
+            for kind, leaves in leaf_specs(cfg, counts, lanes, dtype, kv_quant).items()}
+
+
+def split_bytes(by_kind: dict) -> tuple:
+    """:func:`state_bytes`' result as (what the positional kinds hold, what
+    the whole kinds hold)."""
+    positional = sum(b for kind, b in by_kind.items() if LAYER_KINDS[kind].positional)
+    return positional, sum(by_kind.values()) - positional
+
+
+# Questions asked of a tree.
+
+def _positional(layers: dict) -> dict:
+    return {kind: leaves for kind, leaves in layers.items()
+            if LAYER_KINDS[kind].positional}
+
+
+def n_lanes(layers: dict) -> int:
+    """Lanes of the positional kinds (0 for a stack that has none)."""
+    for leaves in _positional(layers).values():
+        return next(iter(leaves.values())).shape[2]
+    return 0
+
+
+def quantized(layers: dict) -> bool:
+    """Does the tree store int8 codes (``kv_quant``)?"""
+    return any(a.dtype == jnp.int8 for a in jax.tree.leaves(layers))
+
+
+def whole_state_bytes(layers: dict) -> int:
+    """Bytes the whole kinds hold, every row's."""
+    return sum(a.size * a.dtype.itemsize for kind, leaves in layers.items()
+               if not LAYER_KINDS[kind].positional for a in leaves.values())
+
+
+# What a cache manager does to a row.
+
+def insert_row(layers: dict, row: dict, slot) -> dict:
+    """Copy a one-row tree into row ``slot``, cast to the pool's dtypes: a
+    positional kind's lanes from 0 (what lies past the row's length stays
+    hidden), a whole kind's whole state (whatever the slot held is gone)."""
+    return {kind: {name: lax.dynamic_update_slice(
+                       a, row[kind][name].astype(a.dtype), (0, slot) + (0,) * (a.ndim - 2))
+                   for name, a in leaves.items()}
+            for kind, leaves in layers.items()}
+
+
+def reset_row(layers: dict, slot) -> dict:
+    """Free row ``slot``: a whole kind's state is zeroed (no length hides it,
+    and a finished row's overshoot steps advanced it past its last token); a
+    positional kind needs nothing, its length is the caller's to zero."""
+    return {kind: leaves if LAYER_KINDS[kind].positional
+            else {name: a.at[:, slot].set(0) for name, a in leaves.items()}
+            for kind, leaves in layers.items()}
+
+
+def slice_lanes(layers: dict, lanes: int) -> dict:
+    """The first ``lanes`` lanes of every positional kind."""
+    return {kind: {name: a[:, :, :lanes] for name, a in leaves.items()}
+            for kind, leaves in _positional(layers).items()}
+
+
+def paste_lanes(layers: dict, src: dict, lanes: int) -> dict:
+    """Write the first ``lanes`` lanes of ``src``'s positional kinds over the
+    same lanes of ``layers``."""
+    src = _positional(src)
+    return {kind: leaves if kind not in src
+            else {name: lax.dynamic_update_slice(
+                      a, src[kind][name][:, :, :lanes].astype(a.dtype), (0,) * a.ndim)
+                  for name, a in leaves.items()}
+            for kind, leaves in layers.items()}
+
+
+# How a cache shards.
+
+def cache_shardings(mesh, cfg, cache):
+    """``cache`` (either cache class) with a ``NamedSharding`` for every
+    array: each leaf of the tree by the table's ``model_dim`` (kv-heads over
+    ``model`` when divisible, else replicated), the bookkeeping replicated."""
+    rep = NamedSharding(mesh, P())
+    on_mesh = "model" in mesh.axis_names
+    # Only each leaf's ``model_dim`` and that dim's size are read: any dtype does.
+    specs = leaf_specs(cfg, cache.layers, n_lanes(cache.layers), jnp.bfloat16,
+                       quantized(cache.layers))
+
+    def of(leaf: Leaf, a) -> NamedSharding:
+        if leaf.model_dim is None:
+            return rep
+        ax = "model" if on_mesh and _model_sharded(leaf, mesh.shape["model"]) else None
+        return NamedSharding(mesh, P(*(ax if i == leaf.model_dim + 2 else None
+                                       for i in range(a.ndim))))
+
+    layers = {kind: {name: of(specs[kind][name], a) for name, a in leaves.items()}
+              for kind, leaves in cache.layers.items()}
+    return dataclasses.replace(jax.tree.map(lambda _: rep, cache), layers=layers)

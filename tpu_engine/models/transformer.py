@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from tpu_engine import layer_state
 from tpu_engine.quant import QuantWeight, dequantize_weight
 from tpu_engine.quant_train import int8_einsum
 
@@ -200,9 +201,12 @@ class RecurrentLayersUnsupported(NotImplementedError):
 
 
 def refuse_recurrent(cfg: "ModelConfig", feature: str) -> None:
-    """Raise :class:`RecurrentLayersUnsupported` for a hybrid ``cfg`` (any
-    object without the property is a geometry stand-in, never a hybrid)."""
-    if getattr(cfg, "is_hybrid", False):
+    """Raise :class:`RecurrentLayersUnsupported` when some kind of layer in
+    ``cfg``'s stack keeps a WHOLE state (``layer_state.LAYER_KINDS``: no lanes
+    to slice, mask or rewind). An object without a layer pattern is a geometry
+    stand-in, never such a stack."""
+    runs = cfg.layer_runs() if hasattr(cfg, "layer_runs") else ()
+    if layer_state.keeps_whole_state(kind for kind, _, _ in runs):
         raise RecurrentLayersUnsupported(feature, cfg)
 
 

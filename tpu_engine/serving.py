@@ -60,11 +60,11 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tpu_engine import layer_state
 from tpu_engine.generate import (
     KVCache,
     forward_with_cache,
     init_cache,
-    init_recurrent_state,
     ring_lanes,
     scan_layers,
 )
@@ -91,7 +91,16 @@ def _program(name: str, fn, **static):
 @jax.tree_util.register_dataclass
 @dataclass
 class SlotCache:
-    """Per-slot KV pool with INDEPENDENT row positions.
+    """Per-slot pool of per-layer state with INDEPENDENT row positions.
+
+    ``layers`` is the tree :mod:`tpu_engine.layer_state` allocates,
+    ``{kind: {leaf: [L_kind, B, ...]}}``: what the attention layers keep has a
+    lane axis ``S`` that ``lengths`` masks; what a hybrid stack's Mamba-2
+    layers keep has no lane to mask, so what keys and values make harmless is
+    handled where it happens — pad positions and rows that are not ``active``
+    leave it exactly as it was (``generate._ssm_mixer``), and a finished row's
+    overshoot steps do advance it, which is why ``_reset_slot`` zeroes it and
+    ``_insert_prefill`` overwrites all of it before the slot decodes again.
 
     ``lengths[b]`` is slot b's global position count (prompt + generated).
     Non-ring pools identify lane m with position m (``pos`` is None);
@@ -101,45 +110,26 @@ class SlotCache:
     cache of :class:`tpu_engine.generate.KVCache`.
     """
 
-    k: jax.Array        # [L, B, S, KV, HD]
-    v: jax.Array
+    layers: dict
     lengths: jax.Array  # [B] int32 — resident tokens per slot (0 = empty)
     pos: Optional[jax.Array] = None  # [B, S] int32, ring pools only
     ring: bool = field(default=False, metadata=dict(static=True))
-    # int8-quantized pool (``init_slot_cache(kv_quant=True)``): k/v hold
-    # int8 codes and these hold the per-(lane, kv-head) absmax/127
-    # scales [L, B, S, KV, 1] — the slot-pool twin of
-    # :class:`generate.KVCache`'s quantized mode. Halves the pool's HBM;
-    # dequantisation fuses into the attention reads.
-    k_scale: Optional[jax.Array] = None
-    v_scale: Optional[jax.Array] = None
-    # Hybrid stacks hold TWO kinds of state per slot: k/v above cover the
-    # attention layers only ([L_attn, B, S, KV, HD]); the Mamba-2 layers keep
-    # ``ssm`` [L_ssm, B, heads, head_dim, state] float32 and ``conv``
-    # [L_ssm, B, taps-1, conv_dim]. A recurrent state has no lane to mask, so
-    # what keys and values make harmless is handled where it happens: pad
-    # positions and rows that are not ``active`` leave it exactly as it was
-    # (``generate._ssm_mixer``), and a finished row's overshoot steps do
-    # advance it, which is why ``_reset_slot`` zeroes it and
-    # ``_insert_prefill`` overwrites all of it before the slot decodes again.
-    ssm: Optional[jax.Array] = None
-    conv: Optional[jax.Array] = None
 
     @property
     def n_lanes(self) -> int:
-        return self.k.shape[2]
+        return layer_state.n_lanes(self.layers)
 
     @property
     def recurrent(self) -> bool:
-        return self.ssm is not None
+        return layer_state.keeps_whole_state(self.layers)
 
     @property
     def recurrent_state_bytes(self) -> int:
-        return 0 if self.ssm is None else self.ssm.nbytes + self.conv.nbytes
+        return layer_state.whole_state_bytes(self.layers)
 
     @property
     def quantized(self) -> bool:
-        return self.k_scale is not None
+        return layer_state.quantized(self.layers)
 
 
 def init_slot_cache(
@@ -157,19 +147,11 @@ def init_slot_cache(
         refuse_recurrent(cfg, "an int8 KV pool (kv_quant)")
     lanes = ring_lanes(cfg, max_len, prefill_chunk)
     ring = lanes < max_len
-    shape = (cfg.n_attn_layers, slots, lanes, cfg.n_kv_heads, cfg.head_dim)
-    store_dtype = jnp.int8 if kv_quant else dtype
-    scale_shape = shape[:-1] + (1,)
-    ssm, conv = init_recurrent_state(cfg, slots, dtype)
     return SlotCache(
-        k=jnp.zeros(shape, store_dtype),
-        v=jnp.zeros(shape, store_dtype),
+        layers=layer_state.init_layers(cfg, slots, lanes, dtype, kv_quant),
         lengths=jnp.zeros((slots,), jnp.int32),
         pos=jnp.full((slots, lanes), -1, jnp.int32) if ring else None,
         ring=ring,
-        k_scale=jnp.zeros(scale_shape, jnp.float32) if kv_quant else None,
-        v_scale=jnp.zeros(scale_shape, jnp.float32) if kv_quant else None,
-        ssm=ssm, conv=conv,
     )
 
 
@@ -190,7 +172,7 @@ def decode_step(
     family the walk supports is therefore served here with zero forked model
     code.
 
-    The walk CARRIES the pool — ``k`` / ``v`` ``[L, B, S, KV, HD]`` (and the
+    The walk CARRIES the pool — keys and values ``[L, B, S, KV, HD]`` (and the
     scales of an int8 pool, the recurrent state of a hybrid) — and each layer
     scatters one row per slot straight into its own lanes of it; a layer's
     ``[B, S, KV, HD]`` is only read, for attention. No layer is taken out and
@@ -419,12 +401,8 @@ def speculative_round(
 def _slice_prefix(c1: KVCache, L: int) -> KVCache:
     """First ``L`` lanes of a single-row ingestion cache — the stored
     form of a prefix-cache entry (non-ring caches only: lane == position)."""
-    return KVCache(
-        k=c1.k[:, :, :L], v=c1.v[:, :, :L], pos=c1.pos[:L],
-        length=jnp.asarray(L, jnp.int32), ring=False,
-        k_scale=None if c1.k_scale is None else c1.k_scale[:, :, :L],
-        v_scale=None if c1.v_scale is None else c1.v_scale[:, :, :L],
-    )
+    return KVCache(layers=layer_state.slice_lanes(c1.layers, L), pos=c1.pos[:L],
+                   length=jnp.asarray(L, jnp.int32), ring=False)
 
 
 def _paste_prefix(c1: KVCache, entry: KVCache, use_len: jax.Array,
@@ -440,16 +418,10 @@ def _paste_prefix(c1: KVCache, entry: KVCache, use_len: jax.Array,
     masking is what makes TOKEN-granular reuse free — the cache stores
     chunk-aligned entries, yet a prompt sharing any prefix of one reuses
     every full ``grain`` of the shared tokens."""
-    def put(dst, src):
-        return lax.dynamic_update_slice(dst, src[:, :, :lanes].astype(dst.dtype),
-                                        (0, 0, 0, 0, 0))
-
     return KVCache(
-        k=put(c1.k, entry.k), v=put(c1.v, entry.v),
+        layers=layer_state.paste_lanes(c1.layers, entry.layers, lanes),
         pos=lax.dynamic_update_slice(c1.pos, entry.pos[:lanes], (0,)),
         length=use_len.astype(jnp.int32), ring=False,
-        k_scale=None if c1.k_scale is None else put(c1.k_scale, entry.k_scale),
-        v_scale=None if c1.v_scale is None else put(c1.v_scale, entry.v_scale),
     )
 
 
@@ -718,26 +690,12 @@ class ContinuousBatcher:
         self._base_key = jax.random.PRNGKey(seed)
 
         # -- sharding surface (mesh-sharded serving) ------------------------
-        rep = kv_sh = None
+        self._cache_sh = self._rep = None
         if mesh is not None:
-            rep = NamedSharding(mesh, P())
-            model_ax = None
-            if "model" in mesh.axis_names and \
-                    cfg.n_kv_heads % mesh.shape["model"] == 0:
-                model_ax = "model"
-            kv_sh = NamedSharding(mesh, P(None, None, None, model_ax, None))
-            cache_sh = SlotCache(
-                k=kv_sh, v=kv_sh, lengths=rep,
-                pos=rep if self._cache.ring else None, ring=self._cache.ring,
-                # Scales shard with their codes (kv-heads over "model").
-                k_scale=kv_sh if self.kv_quant else None,
-                v_scale=kv_sh if self.kv_quant else None,
-            )
-            self._cache = jax.device_put(self._cache, cache_sh)
-            self._base_key = jax.device_put(self._base_key, rep)
-            self._cache_sh, self._rep, self._kv_sh = cache_sh, rep, kv_sh
-        else:
-            self._cache_sh = self._rep = self._kv_sh = None
+            self._rep = NamedSharding(mesh, P())
+            self._cache_sh = layer_state.cache_shardings(mesh, cfg, self._cache)
+            self._cache = jax.device_put(self._cache, self._cache_sh)
+            self._base_key = jax.device_put(self._base_key, self._rep)
 
         # -- speculative decoding (draft-propose / batched verify) ----------
         self._draft_params = draft_params
@@ -795,12 +753,9 @@ class ContinuousBatcher:
                          cfg=draft_cfg, compute_dtype=compute_dtype),
                 donate_argnums=(2,),
             )
-            self._draft_insert = jax.jit(
-                _program("insert_prefill", _insert_prefill),
-                donate_argnums=(0,), static_argnums=(4,),
-            )
-            self._draft_reset = jax.jit(_program("reset_slot", _reset_slot),
-                                        donate_argnums=(0,))
+            # The draft's pool is inserted into and reset by the target's own
+            # programs (``self._insert`` / ``self._reset``: no mesh here, so no
+            # out-shardings tell them apart).
 
         # -- prompt-prefix KV cache (shared system prompts) -----------------
         self._prefix_cache: Optional[_PrefixCache] = None
@@ -829,12 +784,10 @@ class ContinuousBatcher:
             self._paste_prefix = jax.jit(
                 _program("paste_prefix", _paste_prefix),
                 donate_argnums=(0,), static_argnums=(3,),
-                out_shardings=None if mesh is None else KVCache(
-                    k=self._kv_sh, v=self._kv_sh, pos=self._rep,
-                    length=self._rep, ring=False,
-                    k_scale=self._kv_sh if self.kv_quant else None,
-                    v_scale=self._kv_sh if self.kv_quant else None,
-                ),
+                out_shardings=None if mesh is None else layer_state.cache_shardings(
+                    mesh, cfg, jax.eval_shape(lambda: init_cache(
+                        cfg, 1, self.prefill_chunk, compute_dtype,
+                        kv_quant=self.kv_quant))),
             )
 
         self._decode = jax.jit(
@@ -982,15 +935,7 @@ class ContinuousBatcher:
                 f"handoff length {handoff.length} != resident invariant "
                 f"(history {len(history)} - 1): wire payload is inconsistent"
             )
-        if handoff.n_layers != self.cfg.n_layers or \
-                handoff.n_kv_heads != self.cfg.n_kv_heads or \
-                handoff.head_dim != self.cfg.head_dim:
-            raise ValueError(
-                "handoff KV geometry does not match this engine's model "
-                f"({handoff.n_layers}L/{handoff.n_kv_heads}KV/"
-                f"{handoff.head_dim}HD vs {self.cfg.n_layers}L/"
-                f"{self.cfg.n_kv_heads}KV/{self.cfg.head_dim}HD)"
-            )
+        self._check_handoff_geometry(handoff)
         if len(history) + max_new_tokens > self.max_len:
             raise ValueError(
                 f"handoff history ({len(history)}) + max_new_tokens "
@@ -1010,6 +955,16 @@ class ContinuousBatcher:
             self._requests[req.id] = req
             self._prefilled_queue.append((req, handoff))
         return req.id
+
+    def _check_handoff_geometry(self, handoff: Any) -> None:
+        if (handoff.n_layers, handoff.n_kv_heads, handoff.head_dim) != \
+                (self.cfg.n_layers, self.cfg.n_kv_heads, self.cfg.head_dim):
+            raise ValueError(
+                "handoff KV geometry does not match this engine's model "
+                f"({handoff.n_layers}L/{handoff.n_kv_heads}KV/"
+                f"{handoff.head_dim}HD vs {self.cfg.n_layers}L/"
+                f"{self.cfg.n_kv_heads}KV/{self.cfg.head_dim}HD)"
+            )
 
     def request_handoff(self, req_id: int, quantize: bool = False) -> None:
         """Order the ENGINE thread to extract the held slot's K/V into a
@@ -1084,7 +1039,7 @@ class ContinuousBatcher:
         wire fp dtype (the host tier quantizes on store). Returns None
         when the prefix is not resident. Engine-thread only, like every
         other prefix-cache touch."""
-        from tpu_engine.disagg import KVHandoff
+        from tpu_engine import disagg  # local: disagg imports this module
 
         if self._prefix_cache is None:
             return None
@@ -1092,27 +1047,9 @@ class ContinuousBatcher:
         entry = self._prefix_cache._entries.get(key)
         if entry is None:
             return None
-        T = int(entry.length)
-        k = entry.k[:, 0, :T]  # [L, T, KV, HD]
-        v = entry.v[:, 0, :T]
-        if entry.quantized:
-            return KVHandoff(
-                prompt=list(key), emitted=[], length=T,
-                n_layers=self.cfg.n_layers, n_kv_heads=self.cfg.n_kv_heads,
-                head_dim=self.cfg.head_dim, dtype="int8", quantized=True,
-                k=np.asarray(k), v=np.asarray(v),
-                k_scale=np.asarray(entry.k_scale[:, 0, :T]),
-                v_scale=np.asarray(entry.v_scale[:, 0, :T]),
-            )
-        wire = np.float32 if jnp.dtype(k.dtype) == jnp.dtype(jnp.bfloat16) \
-            else np.dtype(np.asarray(k).dtype)
-        return KVHandoff(
-            prompt=list(key), emitted=[], length=T,
-            n_layers=self.cfg.n_layers, n_kv_heads=self.cfg.n_kv_heads,
-            head_dim=self.cfg.head_dim, dtype=np.dtype(wire).name,
-            quantized=False,
-            k=np.asarray(k, dtype=wire), v=np.asarray(v, dtype=wire),
-        )
+        # An entry is a one-row pool whose resident lanes are its length.
+        return disagg.extract_slot_kv(entry, 0, int(entry.length), cfg=self.cfg,
+                                      prompt=list(key), emitted=[])
 
     def install_prefix(self, prefix: list[int], handoff: Any) -> bool:
         """Rehydrate a host-tier payload into this replica's prefix cache
@@ -1122,8 +1059,6 @@ class ContinuousBatcher:
         dtype conversions ride :func:`tpu_engine.disagg.handoff_to_cache`.
         Returns False when this engine has no prefix cache or the entry
         exceeds its budget. Engine-thread only."""
-        import dataclasses as _dc
-
         from tpu_engine import disagg  # local: disagg imports this module
 
         if self._prefix_cache is None:
@@ -1131,15 +1066,7 @@ class ContinuousBatcher:
         key = tuple(int(t) for t in prefix)
         if not key:
             raise ValueError("empty prefix")
-        if handoff.n_layers != self.cfg.n_layers or \
-                handoff.n_kv_heads != self.cfg.n_kv_heads or \
-                handoff.head_dim != self.cfg.head_dim:
-            raise ValueError(
-                "handoff KV geometry does not match this engine's model "
-                f"({handoff.n_layers}L/{handoff.n_kv_heads}KV/"
-                f"{handoff.head_dim}HD vs {self.cfg.n_layers}L/"
-                f"{self.cfg.n_kv_heads}KV/{self.cfg.head_dim}HD)"
-            )
+        self._check_handoff_geometry(handoff)
         history = list(handoff.prompt) + list(handoff.emitted)
         if handoff.length < len(key) or \
                 [int(t) for t in history[: len(key)]] != list(key):
@@ -1157,17 +1084,11 @@ class ContinuousBatcher:
         # handoff_to_cache leaves ``pos`` at -1 (the slot insert ignores
         # it); a prefix entry is pasted into fresh ingestion caches, so
         # give it the lane == position form _slice_prefix stores.
-        c1 = _dc.replace(
+        c1 = dataclasses.replace(
             c1, pos=jnp.arange(c1.max_len, dtype=jnp.int32),
             length=jnp.asarray(len(key), jnp.int32),
         )
-        if self._kv_sh is not None:
-            c1_sh = KVCache(k=self._kv_sh, v=self._kv_sh, pos=self._rep,
-                            length=self._rep, ring=False,
-                            k_scale=self._kv_sh if self.kv_quant else None,
-                            v_scale=self._kv_sh if self.kv_quant else None)
-            c1 = jax.device_put(c1, c1_sh)
-        self._prefix_cache.insert(key, c1)
+        self._prefix_cache.insert(key, self._on_mesh(c1))
         return key in self._prefix_cache._entries
 
     def _result_locked(self, req: Request) -> dict[str, Any]:
@@ -1303,6 +1224,13 @@ class ContinuousBatcher:
 
     # -- engine side ---------------------------------------------------------
 
+    def _on_mesh(self, c1: KVCache) -> KVCache:
+        """A one-row cache placed as the pool shards (mesh-sharded serving)."""
+        if self.mesh is None:
+            return c1
+        return jax.device_put(
+            c1, layer_state.cache_shardings(self.mesh, self.cfg, c1))
+
     def _begin_prefill(self, req: Request, slot: int) -> _PrefillState:
         """Allocate the single-row ingestion cache. Prompts pad up to
         ``prefill_pad_to`` multiples (bounded compiled final-chunk shapes);
@@ -1328,12 +1256,7 @@ class ContinuousBatcher:
             M = max(M, pad)
             c1 = init_cache(self.cfg, 1, M, dtype=self._compute_dtype,
                             kv_quant=self.kv_quant)
-        if self._kv_sh is not None:
-            c1_sh = KVCache(k=self._kv_sh, v=self._kv_sh, pos=self._rep,
-                            length=self._rep, ring=c1.ring,
-                            k_scale=self._kv_sh if self.kv_quant else None,
-                            v_scale=self._kv_sh if self.kv_quant else None)
-            c1 = jax.device_put(c1, c1_sh)
+        c1 = self._on_mesh(c1)
         dc1 = None
         if self._draft_params is not None:
             dc1 = init_cache(self._draft_cfg, 1, c1.max_len,
@@ -1410,7 +1333,7 @@ class ContinuousBatcher:
                                    self._cache.ring)
         self._state_inserts += self._cache.recurrent
         if st.dc1 is not None:
-            self._draft_cache = self._draft_insert(
+            self._draft_cache = self._insert(
                 self._draft_cache, st.dc1, jnp.asarray(st.slot),
                 jnp.asarray(P_len, jnp.int32), False,
             )
@@ -1618,14 +1541,8 @@ class ContinuousBatcher:
             handoff, dtype=self._compute_dtype, kv_quant=self.kv_quant,
             chunk=self.prefill_chunk, max_lanes=self._cache.n_lanes,
         )
-        if self._kv_sh is not None:
-            c1_sh = KVCache(k=self._kv_sh, v=self._kv_sh, pos=self._rep,
-                            length=self._rep, ring=False,
-                            k_scale=self._kv_sh if self.kv_quant else None,
-                            v_scale=self._kv_sh if self.kv_quant else None)
-            c1 = jax.device_put(c1, c1_sh)
         self._cache = self._insert(
-            self._cache, c1, jnp.asarray(slot),
+            self._cache, self._on_mesh(c1), jnp.asarray(slot),
             jnp.asarray(handoff.length, jnp.int32), self._cache.ring,
         )
         self._last_tokens[slot] = handoff.last_token
@@ -1684,7 +1601,7 @@ class ContinuousBatcher:
             self._cache = self._reset(self._cache, slot)
             self._state_resets += self._cache.recurrent
             if self._draft_cache is not None:
-                self._draft_cache = self._draft_reset(self._draft_cache, slot)
+                self._draft_cache = self._reset(self._draft_cache, slot)
             self._done.notify_all()
 
     def serve_forever(self, stop: threading.Event, idle_sleep: float = 0.01):
@@ -1763,35 +1680,17 @@ def _insert_prefill(cache: SlotCache, c1: KVCache, slot, true_len, ring: bool):
     """Copy a single-row prefill cache into ``slot`` and set its length to
     the TRUE prompt length (padding lanes stay masked — causality for ring
     pools, length for flat pools — and are overwritten as decoding
-    proceeds)."""
-    k = lax.dynamic_update_slice(
-        cache.k, c1.k.astype(cache.k.dtype), (0, slot, 0, 0, 0)
-    )
-    v = lax.dynamic_update_slice(
-        cache.v, c1.v.astype(cache.v.dtype), (0, slot, 0, 0, 0)
-    )
-    ks, vs = cache.k_scale, cache.v_scale
-    if cache.quantized:
-        # A quantized pool requires a quantized ingestion cache (the
-        # batcher allocates both from one flag); codes and scales copy
-        # with the same slice placement.
-        ks = lax.dynamic_update_slice(ks, c1.k_scale, (0, slot, 0, 0, 0))
-        vs = lax.dynamic_update_slice(vs, c1.v_scale, (0, slot, 0, 0, 0))
+    proceeds; a state with no lanes is the prefill's at that length). A
+    quantized pool requires a quantized ingestion cache (the batcher
+    allocates both from one flag)."""
     pos = cache.pos
     if ring:
         # Lane-aligned by construction (c1 ring size == pool lane count).
         pos = lax.dynamic_update_slice(pos, c1.pos[None, :], (slot, 0))
-    ssm, conv = cache.ssm, cache.conv
-    if cache.recurrent:
-        # The row's whole recurrent state, as the prefill left it at the
-        # prompt's TRUE length: whatever the slot held is gone.
-        ssm = lax.dynamic_update_slice(ssm, c1.ssm, (0, slot, 0, 0, 0))
-        conv = lax.dynamic_update_slice(conv, c1.conv.astype(conv.dtype),
-                                        (0, slot, 0, 0))
     return SlotCache(
-        k=k, v=v,
+        layers=layer_state.insert_row(cache.layers, c1.layers, slot),
         lengths=cache.lengths.at[slot].set(true_len.astype(jnp.int32)),
-        pos=pos, ring=cache.ring, k_scale=ks, v_scale=vs, ssm=ssm, conv=conv,
+        pos=pos, ring=cache.ring,
     )
 
 
@@ -1799,14 +1698,7 @@ def _reset_slot(cache: SlotCache, slot):
     pos = cache.pos
     if cache.ring:
         pos = pos.at[slot].set(-1)
-    ssm, conv = cache.ssm, cache.conv
-    if cache.recurrent:
-        # No length hides a recurrent state: a freed slot's is zeroed (a
-        # finished row's overshoot steps advanced it past its last token).
-        ssm = ssm.at[:, slot].set(0.0)
-        conv = conv.at[:, slot].set(0.0)
     return SlotCache(
-        k=cache.k, v=cache.v, lengths=cache.lengths.at[slot].set(0),
-        pos=pos, ring=cache.ring, k_scale=cache.k_scale,
-        v_scale=cache.v_scale, ssm=ssm, conv=conv,
+        layers=layer_state.reset_row(cache.layers, slot),
+        lengths=cache.lengths.at[slot].set(0), pos=pos, ring=cache.ring,
     )
