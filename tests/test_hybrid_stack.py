@@ -289,7 +289,7 @@ def test_a_pattern_of_one_kind_lowers_to_the_single_scan(program):
     assert not patterned.is_hybrid and patterned.n_attn_layers == plain.n_layers
 
     def lowered(mc):
-        params = jax.eval_shape(lambda k: tfm.init_params(k, mc), jax.random.PRNGKey(0))
+        params = jax.eval_shape(lambda k: tfm.init_params(k, mc, dtype=jnp.bfloat16), jax.random.PRNGKey(0))
         if program == "decode_step":
             pool = jax.eval_shape(lambda: serving.init_slot_cache(mc, 4, 64, jnp.bfloat16, prefill_chunk=32))
             vec = lambda dt: jax.ShapeDtypeStruct((4,), dt)  # noqa: E731

@@ -1223,7 +1223,7 @@ def test_carried_pool_equals_a_layer_by_layer_reference(fn, ring, kv_quant, G):
             return arr.at[:, slots].set(new.astype(arr.dtype))
 
     x = tfm.embed_tokens(params, toks2, jnp.float32, positions=positions, cfg=cfg)
-    stack = tfm.cast_layer_stack(params, jnp.float32)
+    stack = params["layers"]  # float32 weights under float32 compute: the served format as it is
     layers = []
     for l in range(cfg.n_layers):
         lp = jax.tree.map(lambda a: a[l], stack)

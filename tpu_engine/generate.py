@@ -46,10 +46,11 @@ from tpu_engine.models.transformer import (
     _rms_norm,
     _rope,
     attention_scale,
-    cast_layer_stack,
     check_hybrid,
     embed_tokens,
     refuse_recurrent,
+    require_served_format,
+    served_format,
     unembed,
 )
 from tpu_engine.quant import QuantWeight, dequantize_weight
@@ -455,16 +456,18 @@ def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
       layer rewrites its own slice of ``ssm`` / ``conv`` (:func:`_ssm_block`).
     - ONLY READ: the layer's [B, M, KV, HD] for the two attention
       contractions (``_decode_block(read=)``), and the parameters — ``stacks``
-      are the cast stacks of :func:`cast_layer_stack`, never written, so they
-      stay outside the carry and a run indexes its kind's stack at
-      ``first + i`` (for a run that is a whole stack that is what scanning it
-      as ``xs`` lowers to).
+      is ``params["layers"]`` of a tree in ``transformer.served_format`` (the
+      walk casts nothing and refuses a stack in another dtype than ``x``'s),
+      never written, so it stays outside the carry and a run indexes its
+      kind's stack at ``first + i`` (for a run that is a whole stack that is
+      what scanning it as ``xs`` lowers to).
 
     A kind's layer function takes ``(x, lp, at, leaves)``, the kind's leaves
     whole, and returns ``(x, leaves)``. ``valid`` [B, T] marks the real
     positions for the recurrent layers (which have no mask); attention-only
     callers may leave it out. Returns ``(x, cache)`` with ``cache.layers``
     replaced."""
+    require_served_format(stacks, x.dtype)
     stacks = stacks if "ssm" in stacks else {"attn": stacks}
 
     def attn_layer(x, lp, at, s):
@@ -504,6 +507,10 @@ def forward_with_cache(
     n_valid: Optional[jax.Array] = None,
 ) -> tuple[Optional[jax.Array], KVCache]:
     """Run ``tokens`` [B, T] through the stack against (and into) ``cache``.
+
+    ``params`` are in ``transformer.served_format`` for ``compute_dtype``: the
+    walk casts no weight (a float32 tree with ``compute_dtype=float32`` is in
+    that format as it is).
 
     ``n_valid`` (scalar, default T): how many leading tokens of the chunk are
     real; the rest is padding. Keys and values of padding are written and
@@ -591,7 +598,7 @@ def forward_with_cache(
     # mask, so the Mamba-2 layers stop at ``n_valid``.
     valid = jnp.broadcast_to(
         jnp.arange(T)[None, :] < (T if n_valid is None else n_valid), (B, T))
-    x, cache = scan_layers(x, cast_layer_stack(params, compute_dtype), cfg,
+    x, cache = scan_layers(x, params["layers"], cfg,
                            cache, write, pos_new, positions, valid)
     logits = unembed(params, x, cfg) if want_logits else None
     return logits, dataclasses.replace(cache, pos=pos_new,
@@ -710,6 +717,9 @@ def _generate_jit(
     kv_quant: bool = False,
 ) -> jax.Array:
     B, P = prompt.shape
+    # A training job's float32 parameters: converted once, outside the token
+    # loop, to what the cached walk reads.
+    params = served_format(params, compute_dtype)
 
     def sample(logits, key):
         with jax.named_scope("sample"):
@@ -803,6 +813,8 @@ def _speculative_jit(
     P = prompt.shape[1]
     total = P + max_new_tokens
     buf_len = total + gamma + 1  # room for one over-full final round
+    params = served_format(params, compute_dtype)
+    draft_params = served_format(draft_params, compute_dtype)
 
     cache = init_cache(cfg, 1, buf_len, dtype=compute_dtype,
                        max_chunk=max(P - 1, gamma + 1))

@@ -387,9 +387,14 @@ def estimate_serving_hbm(
     the reason serving admission needs its own estimate. Mirrors the actual
     allocation in ``tpu_engine/serving.py``:
 
-    - params at the serving dtype, or int8 codes + per-channel fp32 scales
-      when the replica loads a ``quant.py`` snapshot (``weight_quant="int8"``),
-      divided over the ``model`` (tensor-parallel) axis;
+    - params at the serving dtype — what the engine HOLDS: one copy in
+      ``transformer.served_format``, converted when it is built, no float32
+      master beside it and no per-dispatch copy (``stats()["weight_bytes"]``
+      is the allocation's side of this term; a hybrid's few float32
+      recurrence leaves are priced at the serving dtype) — or int8 codes +
+      per-channel fp32 scales when the replica loads a ``quant.py`` snapshot
+      (``weight_quant="int8"``), divided over the ``model`` (tensor-parallel)
+      axis;
     - K and V per layer: ``[slots, lanes, n_kv_heads, head_dim]`` at the
       compute dtype, or int8 codes plus per-(lane, kv-head) fp32 scales when
       ``kv_quant`` — the exact layout ``init_slot_cache`` builds, kv-heads

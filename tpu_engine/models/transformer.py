@@ -334,6 +334,15 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
 # ---------------------------------------------------------------------------
 
 
+@partial(jax.jit, static_argnames="dtype")
+def _scaled(x, s, dtype):
+    """``(x * s).astype(dtype)`` as one program: a leaf drawn narrower than
+    float32 never stands as a second float32 array beside its draw (leaf by
+    leaf, that pair was a serving replica's peak memory). The draw itself
+    stays its own program, so the values are those of the ops written out."""
+    return (x * s).astype(dtype)
+
+
 def init_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> dict[str, Any]:
     """Initialise parameters (normal(0.02); residual-out projections scaled
     by 1/sqrt(2·n_layers), GPT-2 style)."""
@@ -347,7 +356,7 @@ def init_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> dict[str
     res_std = std / (2 * L) ** 0.5
 
     def norm(key, shape, s):
-        return (jax.random.normal(key, shape, jnp.float32) * s).astype(dtype)
+        return _scaled(jax.random.normal(key, shape, jnp.float32), s, dtype)
 
     if cfg.arch == "gpt2":
         return {
@@ -437,7 +446,7 @@ def _init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype) -> dict[str, An
     res_std = std / (2 * L) ** 0.5
 
     def norm(key, shape, s):
-        return (jax.random.normal(key, shape, jnp.float32) * s).astype(dtype)
+        return _scaled(jax.random.normal(key, shape, jnp.float32), s, dtype)
 
     attn = {
         "attn_norm": {"scale": jnp.ones((La, D), dtype)},
@@ -479,8 +488,9 @@ def _init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype) -> dict[str, An
     return out
 
 
-# Recurrence leaves of a Mamba-2 layer that stay float32 through
-# :func:`cast_layer_stack`: a bf16 ``A_log`` moves every decay.
+# Recurrence leaves of a Mamba-2 layer that stay float32 wherever the rest of
+# the tree goes to a compute dtype (:func:`served_format`,
+# :func:`cast_layer_stack`): a bf16 ``A_log`` moves every decay.
 SSM_FLOAT32_LEAVES = ("A_log", "dt_bias", "D")
 
 
@@ -1199,29 +1209,78 @@ def unembed(params: dict[str, Any], x: jax.Array, cfg: ModelConfig) -> jax.Array
         return logits
 
 
-def cast_layer_stack(params: dict[str, Any], compute_dtype=jnp.bfloat16) -> dict[str, Any]:
-    """The stacked per-layer params ([L, ...] leaves) cast to compute dtype.
-    :class:`QuantWeight` kernels pass through untouched — their int8
-    codes cast at the matmul and their fp32 scales must NOT round to
-    bf16 (that would double the quantization error for free)."""
-    def cast(a):
-        if isinstance(a, QuantWeight) or not jnp.issubdtype(a.dtype, jnp.floating):
-            return a
-        return a.astype(compute_dtype)
+def _is_quant(a) -> bool:
+    return isinstance(a, QuantWeight)
 
-    is_quant = lambda a: isinstance(a, QuantWeight)  # noqa: E731
+
+def _served_dtype(path, leaf, compute_dtype):
+    """The dtype ``leaf`` has in the served format, or None where the format
+    leaves it as it is: a :class:`QuantWeight` (int8 codes cast at the matmul,
+    and its fp32 scales must NOT round to bf16 — that would double the
+    quantization error for free), a non-floating leaf, and a Mamba-2 layer's
+    :data:`SSM_FLOAT32_LEAVES`."""
+    if _is_quant(leaf) or not jnp.issubdtype(leaf.dtype, jnp.floating):
+        return None
+    if getattr(path[-1], "key", None) in SSM_FLOAT32_LEAVES:
+        return None
+    return jnp.dtype(compute_dtype)
+
+
+def served_format(params: dict[str, Any], compute_dtype=jnp.bfloat16) -> dict[str, Any]:
+    """The weights as the serving programs read them: every floating leaf in
+    the compute dtype — layer stacks, ``embed``, ``pos_embed``, ``lm_head``,
+    ``final_norm`` — except what :func:`_served_dtype` exempts.
+
+    A replica converts ONCE, when its engine is built
+    (``ContinuousBatcher.__init__``; :func:`generate.generate` at its entry),
+    and the cached walks (``decode_step``, ``decode_verify``,
+    ``forward_with_cache``) take the tree as it is: no float32 master lies
+    beside the pool and no program re-casts the stack. Idempotent (a leaf
+    already in the format is returned itself, so a converted tree costs
+    nothing); an elementwise convert keeps a sharded leaf's sharding; works on
+    tracers."""
+    def convert(path, a):
+        dtype = _served_dtype(path, a, compute_dtype)
+        return a if dtype is None or a.dtype == dtype else a.astype(dtype)
+
+    return jax.tree_util.tree_map_with_path(convert, params, is_leaf=_is_quant)
+
+
+def require_served_format(stacks: dict[str, Any], compute_dtype) -> None:
+    """Raise where a layer stack is not in :func:`served_format` (a trace-time
+    look at dtypes). The cached walks no longer cast, and a float32 stack
+    under bf16 activations would silently compute in mixed precision."""
+    off = []
+    for path, a in jax.tree_util.tree_leaves_with_path(stacks, is_leaf=_is_quant):
+        want = _served_dtype(path, a, compute_dtype)
+        if want is not None and a.dtype != want:
+            off.append(f"{jax.tree_util.keystr(path)}: {a.dtype}")
+    if off:
+        raise TypeError(
+            f"weights are not in the served format for {jnp.dtype(compute_dtype)} "
+            f"({', '.join(off[:3])}{', ...' if len(off) > 3 else ''}): convert "
+            "them once with transformer.served_format(params, compute_dtype)"
+        )
+
+
+def weight_bytes_by_dtype(params: dict[str, Any]) -> dict[str, int]:
+    """Bytes a parameter tree holds, by dtype name (a sharded leaf counts
+    whole, over all its devices)."""
+    out: dict[str, int] = {}
+    for a in jax.tree.leaves(params):
+        name = str(a.dtype)
+        out[name] = out.get(name, 0) + a.size * a.dtype.itemsize
+    return out
+
+
+def cast_layer_stack(params: dict[str, Any], compute_dtype=jnp.bfloat16) -> dict[str, Any]:
+    """TRAINING's cast: the stacked per-layer params ([L, ...] leaves) of a
+    master-dtype tree as a compute-dtype copy, made inside the step program
+    (scope ``cast_weights``) so that the backward keeps bf16 slices and the
+    optimizer its float32 master. Serving has no such cast: an engine holds
+    :func:`served_format` and nothing else. Same leaves, same exemptions."""
     with jax.named_scope("cast_weights"):
-        layers = params["layers"]
-        if "ssm" not in layers:
-            return jax.tree.map(cast, layers, is_leaf=is_quant)
-        # A hybrid's per-kind stacks; the recurrence's own leaves stay float32.
-        ssm = layers["ssm"]
-        return {
-            "attn": jax.tree.map(cast, layers["attn"], is_leaf=is_quant),
-            "ssm": {k: v if k in SSM_FLOAT32_LEAVES
-                    else jax.tree.map(cast, v, is_leaf=is_quant)
-                    for k, v in ssm.items()},
-        }
+        return served_format(params["layers"], compute_dtype)
 
 
 def forward_hidden_and_aux(

@@ -70,11 +70,12 @@ from tpu_engine.generate import (
 )
 from tpu_engine.models.transformer import (
     ModelConfig,
-    cast_layer_stack,
     check_hybrid,
     embed_tokens,
     refuse_recurrent,
+    served_format,
     unembed,
+    weight_bytes_by_dtype,
 )
 from tpu_engine.profiler import StepProfiler
 
@@ -219,7 +220,7 @@ def decode_step(
             new_rows[:, 0].astype(cache_arr.dtype)
         )
 
-    x, cache = scan_layers(x, cast_layer_stack(params, compute_dtype), cfg,
+    x, cache = scan_layers(x, params["layers"], cfg,
                            cache, write, slot_pos, positions, active[:, None])
     logits = unembed(params, x, cfg)[:, 0]                  # [B, V] fp32
     return logits, dataclasses.replace(
@@ -328,7 +329,7 @@ def decode_verify(
             new_rows.astype(cache_arr.dtype)
         )
 
-    x, cache = scan_layers(x, cast_layer_stack(params, compute_dtype), cfg,
+    x, cache = scan_layers(x, params["layers"], cfg,
                            cache, write, slot_pos, positions)
     logits = unembed(params, x, cfg)  # [B, T, V] fp32
     return logits, dataclasses.replace(
@@ -655,7 +656,12 @@ class ContinuousBatcher:
         kv_quant: bool = False,
         prefix_cache_tokens: int = 0,
     ):
-        self.params = params
+        # The engine holds its weights ONCE, as its programs read them
+        # (``served_format``): whatever tree a caller brings — a training
+        # job's float32 master, an int8 snapshot, a mesh-sharded tree — is
+        # converted here and no program casts it again.
+        self.params = served_format(params, compute_dtype)
+        self._weight_bytes = weight_bytes_by_dtype(self.params)
         self.cfg = cfg
         self.max_slots = int(max_slots)
         self.max_len = int(max_len)
@@ -698,7 +704,7 @@ class ContinuousBatcher:
             self._base_key = jax.device_put(self._base_key, self._rep)
 
         # -- speculative decoding (draft-propose / batched verify) ----------
-        self._draft_params = draft_params
+        self._draft_params = None
         self._draft_cfg = draft_cfg
         self.spec_gamma = int(spec_gamma)
         self._draft_cache = None
@@ -735,6 +741,8 @@ class ContinuousBatcher:
                     f"spec_gamma must be >= 1, got {spec_gamma}",
                     spec_gamma=self.spec_gamma,
                 )
+            self._draft_params = served_format(draft_params, compute_dtype)
+            self._draft_weight_bytes = weight_bytes_by_dtype(self._draft_params)
             self._draft_cache = init_slot_cache(
                 draft_cfg, self.max_slots, self.max_len, compute_dtype,
                 prefill_chunk=self.prefill_chunk,
@@ -1197,12 +1205,17 @@ class ContinuousBatcher:
                 "recurrent_state_bytes": self._recurrent_state_bytes,
                 "state_inserts_total": self._state_inserts,
                 "state_resets_total": self._state_resets,
+                # The weights the engine holds, by dtype, counted once at
+                # build (the target's; a speculative engine's draft beside
+                # it): what ``estimate_serving_hbm`` prices as ``params_gib``.
+                "weight_bytes": dict(self._weight_bytes),
             }
             if self._prefix_cache is not None:
                 out["prefix_cache"] = self._prefix_cache.stats()
             if self._draft_params is not None:
                 # Fleet-wide speculative telemetry (backend/routers/
                 # metrics.py renders these as tpu_engine_serving_spec_*).
+                out["draft_weight_bytes"] = dict(self._draft_weight_bytes)
                 out["spec_rounds"] = self._spec_rounds
                 out["spec_tokens_accepted"] = self._spec_accepted
                 out["spec_tokens_proposed"] = (

@@ -61,7 +61,7 @@ from tpu_engine.scheduler import (
     Submission,
     SubmissionState,
 )
-from tpu_engine.sharding import Precision, TPUTrainConfig
+from tpu_engine.sharding import Precision, TPUTrainConfig, dtype_of
 from tpu_engine.supervisor import JobStatus
 
 log = logging.getLogger(__name__)
@@ -92,6 +92,9 @@ class ServingReplicaSpec(BaseModel):
     max_slots: int = Field(default=8, ge=1, le=256)
     max_len: int = Field(default=1024, ge=8)
     tensor_parallel: int = Field(default=1, ge=1)
+    # The dtype the replica computes in AND holds its weights in: the engine
+    # keeps one copy, converted once at build (``transformer.served_format``;
+    # a hybrid's recurrence leaves and int8 scales stay float32).
     compute_dtype: Precision = Precision.BF16
     # "int8" → weight-only quantization (snapshot weights arrive already
     # quantized; a fresh init is quantized at build).
@@ -158,6 +161,7 @@ def build_replica_engine(spec: ServingReplicaSpec) -> Any:
     from tpu_engine.models import transformer as tfm
     from tpu_engine.serving import ContinuousBatcher
 
+    compute_dtype = dtype_of(spec.compute_dtype)
     mesh = None
     if spec.snapshot_dir is not None:
         from tpu_engine.quant import load_quantized, load_quantized_config
@@ -191,11 +195,18 @@ def build_replica_engine(spec: ServingReplicaSpec) -> Any:
         cfg = tfm.MODEL_CONFIGS.get(spec.model_name)
         if cfg is None:
             raise ValueError(f"unknown model '{spec.model_name}'")
-        params = tfm.init_params(jax.random.PRNGKey(spec.seed), cfg)
+        key = jax.random.PRNGKey(spec.seed)
         if spec.weight_quant == "int8":
             from tpu_engine.quant import quantize_params
 
-            params = quantize_params(params)
+            # Quantised from the float32 draw (codes of values already rounded
+            # to the compute dtype would be another model); what stays
+            # unquantised is converted by the engine.
+            params = quantize_params(tfm.init_params(key, cfg))
+        else:
+            # Drawn in the format the engine holds: each leaf is rounded from
+            # its float32 draw as it is made, so no float32 tree ever exists.
+            params = tfm.init_params(key, cfg, dtype=compute_dtype)
         if spec.tensor_parallel > 1:
             from tpu_engine.mesh_runtime import build_mesh
             from tpu_engine.models.transformer import logical_axes
@@ -215,7 +226,7 @@ def build_replica_engine(spec: ServingReplicaSpec) -> Any:
 
     return ContinuousBatcher(
         params, cfg, max_slots=spec.max_slots, max_len=spec.max_len,
-        eos_id=spec.eos_id, seed=spec.seed,
+        compute_dtype=compute_dtype, eos_id=spec.eos_id, seed=spec.seed,
         chunk_steps=spec.decode_chunk_steps,
         prefill_chunk=spec.prefill_chunk, mesh=mesh,
         kv_quant=spec.kv_quant,
