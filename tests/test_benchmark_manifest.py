@@ -88,7 +88,7 @@ def test_a_configuration_builds_at_its_rehearsal_size(name):
         assert tfm.param_count(mc) > 0
         full = program.model_config(config, name)  # the published widths build too (no array is made)
         assert full.d_model == config["hidden_size"] and full.vocab_size == config["vocab_size"]
-        if "layer_types" not in config:
+        if "layer_types" not in config and "mixer_types" not in config:
             # A configuration without a layer pattern is untouched by the fields a
             # pattern brought (PR 27): each stays at its default, so its programs
             # are the ones it had.

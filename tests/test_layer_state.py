@@ -10,7 +10,11 @@ the cache manager, the estimator and the sharding reading the table.
 - (c) insert and reset of a row, per kind: positional or whole;
 - (d) a third kind is ONE ENTRY of the table: a toy whole kind added in a
   fixture is allocated, inserted, reset, sharded, priced and refused with no
-  edit to ``serving.py``, ``hbm_estimate.py`` or ``disagg.py``.
+  edit to ``serving.py``, ``hbm_estimate.py`` or ``disagg.py``;
+- (e) a positional leaf may hold a row per ``stride`` lanes (a toy kind with a
+  leaf of one row per 4 lanes beside a leaf of one per lane): ``n_lanes`` is
+  the longest leaf's, and insert, slice and paste cut each leaf at its own
+  stride.
 """
 
 import json
@@ -279,3 +283,63 @@ def test_a_third_kind_is_one_entry(what, toy_kind):
         with pytest.raises(tfm.RecurrentLayersUnsupported, match="the prompt-prefix cache"):
             tfm.refuse_recurrent(Stack(), "the prompt-prefix cache")
         tfm.refuse_recurrent(cfg, "the prompt-prefix cache")  # keys and values only: admitted
+
+
+# (e) a positional leaf with a lane stride --------------------------------------
+
+STRIDE = 4
+STRIDED_COUNTS = {"strided": 2}
+
+
+@pytest.fixture
+def strided_kind(monkeypatch):
+    """A POSITIONAL kind with a leaf of one row per lane and a leaf of one row
+    per ``STRIDE`` lanes (as a sparse-attention layer's compressed keys)."""
+    monkeypatch.setitem(layer_state.LAYER_KINDS, "strided", layer_state.LayerKind(
+        positional=True,
+        leaves=lambda cfg, lanes, dtype, kv_quant: {
+            "coarse": layer_state.Leaf((lanes // STRIDE, 3), dtype),   # listed first: n_lanes must not take it
+            "fine": layer_state.Leaf((lanes, 3), dtype)}))
+    return tfm.MODEL_CONFIGS["gpt-tiny"]
+
+
+@pytest.mark.parametrize("what", ["n_lanes", "insert", "slice", "paste", "price"])
+def test_a_positional_leaf_with_a_lane_stride(what, strided_kind):
+    cfg = strided_kind
+    layers = _random_like(layer_state.init_layers(cfg, 3, 32, jnp.float32, counts=STRIDED_COUNTS), 5)
+    fine, coarse = layers["strided"]["fine"], layers["strided"]["coarse"]
+    assert fine.shape == (2, 3, 32, 3) and coarse.shape == (2, 3, 8, 3)
+    if what == "n_lanes":
+        assert layer_state.n_lanes(layers) == 32
+        assert layer_state.lane_stride(layers, coarse) == STRIDE and layer_state.lane_stride(layers, fine) == 1
+        assert not layer_state.keeps_whole_state(STRIDED_COUNTS) and layer_state.whole_state_bytes(layers) == 0
+    elif what == "insert":
+        row = _random_like(layer_state.init_layers(cfg, 1, 16, jnp.float32, counts=STRIDED_COUNTS), 6)
+        pool = serving.SlotCache(layers=layers, lengths=jnp.zeros((3,), jnp.int32))
+        c1 = KVCache(layers=row, pos=jnp.arange(16, dtype=jnp.int32), length=jnp.asarray(13, jnp.int32))
+        got = jax.jit(serving._insert_prefill, static_argnums=(4,))(pool, c1, jnp.int32(1), jnp.int32(13), False)
+        new = got.layers["strided"]
+        assert (np.asarray(new["fine"][:, 1, :16]) == np.asarray(row["strided"]["fine"][:, 0])).all()
+        assert (np.asarray(new["coarse"][:, 1, :4]) == np.asarray(row["strided"]["coarse"][:, 0])).all()
+        assert (np.asarray(new["fine"][:, 1, 16:]) == np.asarray(fine[:, 1, 16:])).all()
+        assert (np.asarray(new["coarse"][:, 1, 4:]) == np.asarray(coarse[:, 1, 4:])).all()
+        assert (np.asarray(new["coarse"][:, [0, 2]]) == np.asarray(coarse[:, [0, 2]])).all()
+        freed = serving._reset_slot(got, 1)  # positional: the length hides it, nothing is zeroed
+        assert (np.asarray(freed.layers["strided"]["coarse"]) == np.asarray(new["coarse"])).all()
+    elif what == "slice":
+        cut = layer_state.slice_lanes(layers, 16)["strided"]
+        assert cut["fine"].shape == (2, 3, 16, 3) and cut["coarse"].shape == (2, 3, 4, 3)
+        assert (np.asarray(cut["coarse"]) == np.asarray(coarse[:, :, :4])).all()
+        # lanes that do not fill a row of the coarse leaf give it none
+        assert layer_state.slice_lanes(layers, 18)["strided"]["coarse"].shape[2] == 4
+    elif what == "paste":
+        src = _random_like(layer_state.init_layers(cfg, 3, 24, jnp.float32, counts=STRIDED_COUNTS), 7)
+        got = layer_state.paste_lanes(layers, src, 16)["strided"]
+        assert (np.asarray(got["fine"][:, :, :16]) == np.asarray(src["strided"]["fine"][:, :, :16])).all()
+        assert (np.asarray(got["fine"][:, :, 16:]) == np.asarray(fine[:, :, 16:])).all()
+        assert (np.asarray(got["coarse"][:, :, :4]) == np.asarray(src["strided"]["coarse"][:, :, :4])).all()
+        assert (np.asarray(got["coarse"][:, :, 4:]) == np.asarray(coarse[:, :, 4:])).all()
+    else:
+        priced = layer_state.state_bytes(cfg, 3, 32, jnp.float32, counts=STRIDED_COUNTS)
+        assert priced == {"strided": fine.nbytes + coarse.nbytes}
+        assert layer_state.split_bytes(priced) == (fine.nbytes + coarse.nbytes, 0)

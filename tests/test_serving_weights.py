@@ -365,6 +365,22 @@ def test_the_int8_build_quantises_the_float32_draw(stack):
     _assert_same_tree(engine.params, tfm.served_format(quantize_params(params), BF16))
 
 
+@all_families
+def test_the_int8_build_never_holds_the_float32_tree(stack):
+    """``init_params(deferred=True)`` leaves every drawn kernel and table as a
+    call; drawn, the tree is the one drawn at once, bit for bit; and
+    ``quantize_params`` turns such a call into codes where it meets it."""
+    mc, params = stack
+    lazy = tfm.init_params(jax.random.PRNGKey(SEED), mc, deferred=True)
+    calls = [(p, a) for p, a in _leaves(lazy) if callable(a)]
+    held = sum(a.nbytes for _, a in _leaves(lazy) if not callable(a))
+    assert calls and held < 0.02 * sum(a.nbytes for a in jax.tree.leaves(params))
+    _assert_same_tree(tfm.draw_deferred(lazy), params)
+    q = quantize_params(lazy)
+    sites = [a for a in jax.tree.leaves(q, is_leaf=lambda x: isinstance(x, QuantWeight)) if isinstance(a, QuantWeight)]
+    assert len(sites) + sum(callable(a) for a in jax.tree.leaves(q)) == len(calls)
+
+
 # (d) training keeps its master and its cast -------------------------------------
 
 

@@ -53,6 +53,7 @@ from tpu_engine.models.transformer import (
     served_format,
     unembed,
 )
+from tpu_engine.ops import sparse_block_attention
 from tpu_engine.quant import QuantWeight, dequantize_weight
 
 _NEG_INF = -1e30
@@ -297,38 +298,82 @@ def layer_slice(a: jax.Array, at) -> jax.Array:
     return lax.dynamic_index_in_dim(a, at, 0, keepdims=False)
 
 
+def _time_blocks(a, size: int, mode: str = "constant"):
+    """[B,T,...] -> [n,B,size,...]: the time axis cut into ``n`` blocks of
+    ``size``, the last padded (zeros, or ``mode="edge"``: its last entry)."""
+    B, T = a.shape[:2]
+    n = -(-T // size)
+    a = jnp.pad(a, ((0, 0), (0, n * size - T)) + ((0, 0),) * (a.ndim - 2), mode=mode)
+    return jnp.moveaxis(a.reshape(B, n, size, *a.shape[2:]), 1, 0)
+
+
 def _ssd_chunk(x, dt, A, Bm, Cm, h):
     """One chunk of the selective scan in its chunked (SSD) form.
 
     x [B,Q,H,P]; dt [B,Q,H] float32, 0 where a position must leave the state
     as it was; A [H] (negative); Bm, Cm [B,Q,N] (one group, shared by the
-    heads); h [B,H,P,N] float32, the state entering. Returns (y [B,Q,H,P]
+    heads: Mamba-2) or [B,Q,H,N] (per head: lightning attention, which is
+    this scan with ``dt = 1``, ``A = -rate``, B = k, C = q, x = v);
+    h [B,H,P,N] float32, the state entering. Returns (y [B,Q,H,P]
     float32 without the skip term, the state leaving).
 
     Inside the chunk ``Y = (L o C B^T)(dt x) + diag(exp(cumsum dt A)) C h``
     with ``L[t,s] = exp(sum_{s<r<=t} dt_r A)`` for s <= t: two matmuls and
-    a masked decay instead of Q sequential updates. Decays, their cumulative
-    sums and the state stay float32; the matmul operands are the compute
-    dtype's, accumulated in float32."""
+    a masked decay instead of Q sequential updates. The decay between two
+    positions comes from the DIFFERENCE of their cumulative sums (never
+    ``d^t d^-s``, which leaves float32 within a chunk for a fast head).
+    Decays, their cumulative sums and the state stay float32; the matmul
+    operands are the compute dtype's, accumulated in float32."""
     cd = x.dtype
     Q = x.shape[1]
+    g = "h" if Bm.ndim == 4 else ""  # B and C's head axis, where they have one
     cum = jnp.cumsum(dt * A, axis=1)                              # [B,Q,H] <= 0
     seg = cum[:, :, None, :] - cum[:, None, :, :]                 # [B,t,s,H]
     causal = jnp.tril(jnp.ones((Q, Q), bool))[None, :, :, None]
     decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
-    cb = jnp.einsum("btn,bsn->bts", Cm, Bm, preferred_element_type=jnp.float32)
+    cb = jnp.einsum(f"bt{g}n,bs{g}n->bts{g}", Cm, Bm, preferred_element_type=jnp.float32)
     xdt = x.astype(jnp.float32) * dt[..., None]                   # [B,Q,H,P]
-    y = jnp.einsum("btsh,bshp->bthp", (decay * cb[..., None]).astype(cd),
+    y = jnp.einsum("btsh,bshp->bthp", (decay * (cb if g else cb[..., None])).astype(cd),
                    xdt.astype(cd), preferred_element_type=jnp.float32)
     # What the entering state still contributes at t, read in float32.
     y = y + jnp.exp(cum)[..., None] * jnp.einsum(
-        "btn,bhpn->bthp", Cm.astype(jnp.float32), h,
+        f"bt{g}n,bhpn->bthp", Cm.astype(jnp.float32), h,
         precision=lax.Precision.HIGHEST)
     to_end = jnp.exp(cum[:, -1:, :] - cum)                        # [B,Q,H]
     h = h * jnp.exp(cum[:, -1, :])[:, :, None, None] + jnp.einsum(
-        "bshp,bsn->bhpn", (xdt * to_end[..., None]).astype(cd), Bm,
+        f"bshp,bs{g}n->bhpn", (xdt * to_end[..., None]).astype(cd), Bm,
         preferred_element_type=jnp.float32)
     return y, h
+
+
+def _ssd_scan(x, dt, A, Bm, Cm, h, chunk: int):
+    """:func:`_ssd_chunk` over T positions, ``chunk`` at a time (the last
+    chunk padded with ``dt = 0``: dead positions). Returns (y [B,T,H,P], h)."""
+    B, T, H, P = x.shape
+    Q = min(chunk, T)
+    if T == Q:
+        return _ssd_chunk(x, dt, A, Bm, Cm, h)
+
+    def step(h, xs):
+        y, h = _ssd_chunk(xs[0], xs[1], A, xs[2], xs[3], h)
+        return h, y
+
+    h, y = lax.scan(step, h, tuple(_time_blocks(a, Q) for a in (x, dt, Bm, Cm)))
+    return jnp.moveaxis(y, 0, 1).reshape(B, -1, H, P)[:, :T], h
+
+
+def _ssd_step(x, dt, A, Bm, Cm, h):
+    """One recurrence step, all float32 elementwise (it is bound by reading
+    and writing ``h``): x [B,H,P], dt [B,H], Bm, Cm [B,N] or [B,H,N],
+    h [B,H,P,N]. Returns (y [B,H,P], h)."""
+    f32 = jnp.float32
+
+    def over_p(a):  # -> broadcastable against [B,H,P,N]
+        return a.astype(f32)[:, None, None, :] if a.ndim == 2 else a.astype(f32)[:, :, None, :]
+
+    dbx = (dt[:, :, None] * x.astype(f32))[..., None] * over_p(Bm)
+    h = h * jnp.exp(dt * A)[:, :, None, None] + dbx
+    return jnp.sum(h * over_p(Cm), axis=-1), h
 
 
 def _ssm_mixer(u, lp, h, conv_state, valid, cfg: ModelConfig):
@@ -377,29 +422,11 @@ def _ssm_mixer(u, lp, h, conv_state, valid, cfg: ModelConfig):
 
     if T == 1:
         with jax.named_scope("ssm_update"):
-            d1 = dt[:, 0]                                         # [B,H]
-            dbx = (d1[:, :, None] * x[:, 0].astype(f32))[..., None] \
-                * Bm[:, 0].astype(f32)[:, None, None, :]
-            h = h * jnp.exp(d1 * A)[:, :, None, None] + dbx
-            y = jnp.sum(h * Cm[:, 0].astype(f32)[:, None, None, :], axis=-1)[:, None]
+            y, h = _ssd_step(x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], h)
+            y = y[:, None]
     else:
         with jax.named_scope("ssm_scan"):
-            Q = min(cfg.ssm_chunk, T)
-            if T == Q:
-                y, h = _ssd_chunk(x, dt, A, Bm, Cm, h)
-            else:
-                n = -(-T // Q)
-
-                def chunks(a):  # [B,T,...] -> [n,B,Q,...], padded with dt = 0
-                    a = jnp.pad(a, ((0, 0), (0, n * Q - T)) + ((0, 0),) * (a.ndim - 2))
-                    return jnp.moveaxis(a.reshape(B, n, Q, *a.shape[2:]), 1, 0)
-
-                def step(h, xs):
-                    y, h = _ssd_chunk(xs[0], xs[1], A, xs[2], xs[3], h)
-                    return h, y
-
-                h, y = lax.scan(step, h, (chunks(x), chunks(dt), chunks(Bm), chunks(Cm)))
-                y = jnp.moveaxis(y, 0, 1).reshape(B, n * Q, H, P)[:, :T]
+            y, h = _ssd_scan(x, dt, A, Bm, Cm, h, cfg.ssm_chunk)
     y = y + lp["D"].astype(f32)[:, None] * x.astype(f32)
 
     with jax.named_scope("ssm_gate_norm"):
@@ -430,6 +457,240 @@ def _ssm_block(x, layer_params, ssm, conv, at, valid, cfg: ModelConfig):
         x = _residual(x, _dense_mlp(_norm(x, layer_params["mlp_norm"], cfg),
                                     layer_params, cfg=cfg), cfg)
     return x, ssm, conv
+
+
+# ---------------------------------------------------------------------------
+# Lightning (linear) attention layers
+# ---------------------------------------------------------------------------
+
+
+def _lightning_block(x, lp, state, at, positions, valid, cfg: ModelConfig):
+    """One lightning-attention layer, then the MLP every kind has.
+
+    ``q_t = rope(norm(u_t Wq))``, ``k_t`` alike, ``v_t = u_t Wv`` per head;
+    ``S_t = d S_{t-1} + k_t^T v_t``, ``o_t = q_t S_t / sqrt(E)``; the output
+    is ``(RMSNorm(o_t) * sigmoid(u_t Wgate)) Wo``, the norm over the whole
+    inner width. ``state`` is the kind's whole leaf [L, B, H, E, E] float32,
+    a head's ``S`` transposed (value x key, as a Mamba-2 state is [P, N]);
+    this layer reads and rewrites its own slice, ``at``, under the scope of
+    the step that does it: ``lightning_update`` for one token
+    (:func:`_ssd_step`), ``lightning_scan`` for a chunk (:func:`_ssd_scan`,
+    ``cfg.ssm_chunk`` positions at a time). ``valid`` [B,T] marks the real
+    positions, a PREFIX of each row: a position that is not valid leaves
+    the state exactly as it was. Returns (x, state)."""
+    B, T, _ = x.shape
+    H, E = cfg.lightning_heads, cfg.lightning_head_dim
+    f32 = jnp.float32
+    with jax.named_scope("lightning"):
+        u = _norm(x, lp["attn_norm"], cfg)
+        with jax.named_scope("lightning_qkv"):
+            def heads(name):
+                return _proj(u, lp[name]["kernel"]).reshape(B, T, H, E)
+
+            q = _rope(_rms_norm(heads("q"), lp["q_norm"]["scale"], cfg.norm_eps),
+                      positions, cfg.rope_theta)
+            k = _rope(_rms_norm(heads("k"), lp["k_norm"]["scale"], cfg.norm_eps),
+                      positions, cfg.rope_theta)
+            v = heads("v")
+            gate = jax.nn.sigmoid(_proj(u, lp["o_gate"]["kernel"]).astype(f32))
+        # The selective scan with dt = 1 at a real position (0 at one that is
+        # not), A = -rate, B = k, C = q per head and x = v.
+        A = -lp["decay"].astype(f32)                              # [H]
+        dt = jnp.broadcast_to(valid.astype(f32)[..., None], (B, T, H))
+        with jax.named_scope("lightning_update" if T == 1 else "lightning_scan"):
+            h = layer_slice(state, at)
+            if T == 1:
+                o, h = _ssd_step(v[:, 0], dt[:, 0], A, k[:, 0], q[:, 0], h)
+                o = o[:, None]
+            else:
+                o, h = _ssd_scan(v, dt, A, k, q, h, cfg.ssm_chunk)
+            state = lax.dynamic_update_index_in_dim(state, h, at, 0)
+        with jax.named_scope("lightning_gate_norm"):
+            o = o.reshape(B, T, H * E) * (E ** -0.5)
+            o = o * lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True) + cfg.norm_eps)
+            o = (o * lp["out_norm"]["scale"].astype(f32) * gate).astype(x.dtype)
+        with jax.named_scope("lightning_out_proj"):
+            x = _residual(x, _proj(o, lp["o"]["kernel"]), cfg)
+    with jax.named_scope("mlp"):
+        x = _residual(x, _dense_mlp(_norm(x, lp["mlp_norm"], cfg), lp, cfg=cfg), cfg)
+    return x, state
+
+
+# ---------------------------------------------------------------------------
+# Block-sparse attention layers
+# ---------------------------------------------------------------------------
+
+# Queries a prefill chunk attends at a time (their float32 scores against a
+# whole row are the chunk's largest temporary).
+_SPARSE_QUERY_BLOCK = 128
+_FORCED = 1e30  # a forced block's score: above any sum of probabilities
+
+
+def _write_compressed_keys(k_pool, ck_pool, at, positions, valid, cfg: ModelConfig):
+    """Write layer ``at``'s compressed key of every window whose LAST lane this
+    call wrote: window m is the mean of the ``sparse_kernel_size`` keys from
+    lane ``stride x m`` on, so it completes when position ``stride x m + size
+    - 1`` arrives — in the middle of a prefill chunk, across two chunks (its
+    first lanes are then read back from the pool), or on a decode step. A
+    window that ends at a pad or on a row that is not ``valid`` is not written
+    (decode writes it when it gets there). k_pool [L,B,S,W] with this call's
+    keys already in it; ck_pool [L,B,S/stride,W]; positions [B,T] contiguous."""
+    size, stride = cfg.sparse_kernel_size, cfg.sparse_kernel_stride
+    B, T = positions.shape
+    rows = jnp.arange(B)
+    t0 = positions[:, :1]                                         # [B,1]
+    first = jnp.maximum((t0 - size + stride) // stride, 0)        # first window ending at or after t0
+    m = first + jnp.arange(-(-T // stride))                       # [B,W]
+    end = stride * m + size - 1
+    done = (end < t0 + T) & jnp.take_along_axis(valid, jnp.clip(end - t0, 0, T - 1), axis=1)
+    lanes = stride * m[..., None] + jnp.arange(size)              # [B,W,size]
+    keys = k_pool[at, rows[:, None, None], lanes]                 # [B,W,size,KV*HD]
+    ck = jnp.mean(keys.astype(jnp.float32), axis=2).astype(ck_pool.dtype)
+    m = jnp.where(done, m, ck_pool.shape[2])                      # out of bounds: dropped
+    return ck_pool.at[at, rows[:, None], m].set(ck, mode="drop")
+
+
+def _select_blocks(qg, ck, positions, n_blocks: int, cfg: ModelConfig):
+    """The indexer: which ``sparse_topk`` blocks each query attends.
+
+    qg [B,T,KV,G,HD] (normed queries, grouped by kv-head), ck [B,M,KV,HD]
+    (one layer's compressed keys), positions [B,T]. For every query head a
+    float32 softmax of ``q . ck[m] / sqrt(HD)`` over the windows that end at
+    or before the query, summed over the G heads of a group; a block scores
+    the best of the windows that overlap it; the first ``sparse_init_blocks``
+    and the last ``sparse_local_blocks`` up to the query's own are forced,
+    blocks past the query's own cannot be chosen. Returns block ids
+    [B,KV,T,topk] int32, forced ones among them."""
+    size, stride, block = cfg.sparse_kernel_size, cfg.sparse_kernel_stride, cfg.sparse_block_size
+    per, extra = block // stride, size // stride - 1
+    M = ck.shape[1]
+    s = jnp.einsum("btkgd,bmkd->bkgtm", qg, ck,
+                   preferred_element_type=jnp.float32) * attention_scale(cfg)
+    seen = (stride * jnp.arange(M) + size - 1 <= positions[..., None])[:, None, None]  # [B,1,1,T,M]
+    p = jnp.where(seen, jax.nn.softmax(jnp.where(seen, s, _NEG_INF), axis=-1), 0.0)
+    w = jnp.sum(p, axis=2)                                        # [B,KV,T,M]
+    # Window m overlaps block b iff per*b - extra <= m < per*(b + 1).
+    w = jnp.pad(w, ((0, 0),) * 3 + ((extra, per * n_blocks - M),))
+    score = w[..., 0:per * n_blocks:per]
+    for j in range(1, per + extra):
+        score = jnp.maximum(score, w[..., j:j + per * n_blocks:per])
+    b = jnp.arange(n_blocks)
+    own = (positions // block)[:, None, :, None]                  # [B,1,T,1]
+    forced = (b < cfg.sparse_init_blocks) | (b > own - cfg.sparse_local_blocks)
+    score = jnp.where(b <= own, jnp.where(forced, _FORCED, score), -_FORCED)
+    return lax.top_k(score, min(cfg.sparse_topk, n_blocks))[1]  # a short staging row holds fewer
+
+
+def _sparse_decode(qg, k_pool, v_pool, ck_pool, at, positions, valid, cfg: ModelConfig):
+    """One query per row (T = 1) against layer ``at`` of the pool [L,B,S,W],
+    W = KV x HD. A row past ``sparse_dense_len`` scores its compressed keys
+    and attends the chosen blocks' keys and values (``sparse_topk`` blocks of
+    ``sparse_block_size`` lanes per kv-head), which the kernel
+    (``ops.sparse_block_attention``) copies out of the pool block by block:
+    the rest of the row is never read. Every block of the first
+    ``sparse_dense_len`` lanes is attended only while some row is still short
+    of that (a row that is not ``valid`` — an empty slot, one still ingesting
+    its prompt — stands at position 0 and does not count: what it computes is
+    thrown away). Returns [B,KV,G,HD]."""
+    B, _, KV, G, HD = qg.shape
+    S, block, dense_len = k_pool.shape[2], cfg.sparse_block_size, cfg.sparse_dense_len
+    pos = positions[:, 0]
+    scale = attention_scale(cfg)
+    with jax.named_scope("sparse_index"):
+        ck = layer_slice(ck_pool, at).reshape(B, -1, KV, HD)
+        ids = _select_blocks(qg, ck, positions, S // block, cfg)[:, :, 0]       # [B,KV,topk]
+
+    q = qg[:, 0]
+    interpret = sparse_block_attention.interpret_here()  # refused off the TPU unless asked for
+
+    def attend(ids):  # block ids [B,KV,n] -> [B,KV,G,HD]
+        with jax.named_scope("sparse_attend"):
+            return sparse_block_attention.sparse_block_attend(
+                q, k_pool, v_pool, ids, at, pos, block=block, scale=scale, interpret=interpret)
+
+    out = attend(ids)
+    # A row still short of dense_len attends every block up to there: the same
+    # kernel over all of them, and only while such a row is in the pool.
+    short = pos < dense_len
+    n_dense = -(-min(dense_len, S) // block)
+    every = jnp.broadcast_to(jnp.arange(n_dense), (B, KV, n_dense))
+    full = lax.cond(jnp.any(short & valid[:, 0]), lambda: attend(every), lambda: jnp.zeros_like(out))
+    return jnp.where(short[:, None, None, None], full, out)
+
+
+def _sparse_prefill(qg, k_pool, v_pool, ck_pool, at, positions, cfg: ModelConfig):
+    """A chunk of queries (T > 1) against layer ``at``'s row(s): DENSE AND
+    MASKED — every lane's score is computed, ``_SPARSE_QUERY_BLOCK`` queries
+    at a time, and a query past ``sparse_dense_len`` keeps the lanes of its
+    chosen blocks only (one below it keeps every lane up to its own). What a
+    position attends is what :func:`_sparse_decode` would attend there; the
+    blocks not chosen are computed and thrown away. Returns [B,T,KV,G,HD]."""
+    B, T, KV, G, HD = qg.shape
+    S, block = k_pool.shape[2], cfg.sparse_block_size
+    n_blocks, Tq = S // block, min(_SPARSE_QUERY_BLOCK, T)
+    k = layer_slice(k_pool, at).reshape(B, S, KV, HD)
+    v = layer_slice(v_pool, at).reshape(B, S, KV, HD)
+    ck = layer_slice(ck_pool, at).reshape(B, -1, KV, HD)
+    scale = attention_scale(cfg)
+
+    def attend(xs):
+        q, pos = xs                                               # [B,Tq,KV,G,HD], [B,Tq]
+        with jax.named_scope("sparse_index"):
+            ids = _select_blocks(q, ck, pos, n_blocks, cfg)       # [B,KV,Tq,topk]
+            chosen = jnp.any(ids[..., None] == jnp.arange(n_blocks), axis=-2)   # [B,KV,Tq,n_blocks]
+            chosen |= (pos < cfg.sparse_dense_len)[:, None, :, None]
+        with jax.named_scope("sparse_attend"):
+            s = jnp.einsum("btkgd,bmkd->bkgtm", q, k, preferred_element_type=jnp.float32) * scale
+            keep = jnp.repeat(chosen, block, axis=-1) & (jnp.arange(S) <= pos[:, None, :, None])
+            p = jax.nn.softmax(jnp.where(keep[:, :, None], s, _NEG_INF), axis=-1).astype(q.dtype)
+            return jnp.einsum("bkgtm,bmkd->btkgd", p, v)
+
+    # the last query block's padding repeats its last query
+    out = lax.map(attend, (_time_blocks(qg, Tq, "edge"), _time_blocks(positions, Tq, "edge")))
+    return jnp.moveaxis(out, 0, 1).reshape(B, -1, KV, G, HD)[:, :T]       # [n,B,Tq,...] -> [B,T,...]
+
+
+def _sparse_attn_block(x, lp, k_pool, v_pool, ck_pool, at, write, positions, valid,
+                       cfg: ModelConfig):
+    """One block-sparse attention layer, then the MLP every kind has.
+
+    q and k are normed per head and NOT rotated; the attention's output is
+    gated by ``sigmoid(u Wgate)`` before ``Wo``. The kind's three leaves are
+    the pool's, whole (``[L,B,S,KV x HD]``, compressed keys ``[L,B,S/stride,
+    KV x HD]``): ``write`` stores the chunk's keys and values in this layer's
+    lanes, the compressed keys of the windows that completed follow
+    (:func:`_write_compressed_keys`), and the layer's lanes are only read —
+    by one query per row through the chosen blocks (:func:`_sparse_decode`),
+    by a chunk dense and masked (:func:`_sparse_prefill`).
+    Returns (x, k_pool, v_pool, ck_pool)."""
+    B, T, _ = x.shape
+    H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    if k_pool.shape[2] % cfg.sparse_block_size:
+        raise ValueError(f"a sparse_attention layer's cache must hold whole blocks: {k_pool.shape[2]} "
+                         f"lanes are no multiple of sparse_block_size={cfg.sparse_block_size}")
+    with jax.named_scope("sparse_attn"):
+        u = _norm(x, lp["attn_norm"], cfg)
+        q = _rms_norm(_proj(u, lp["q"]["kernel"]).reshape(B, T, H, HD),
+                      lp["q_norm"]["scale"], cfg.norm_eps)
+        k = _rms_norm(_proj(u, lp["k"]["kernel"]).reshape(B, T, KV, HD),
+                      lp["k_norm"]["scale"], cfg.norm_eps)
+        v = _proj(u, lp["v"]["kernel"])
+        gate = jax.nn.sigmoid(_proj(u, lp["o_gate"]["kernel"]).astype(jnp.float32))
+        with jax.named_scope("kv_write"):
+            k_pool = write(k_pool, k.reshape(B, T, KV * HD), at)
+            v_pool = write(v_pool, v, at)
+        with jax.named_scope("sparse_index"):
+            ck_pool = _write_compressed_keys(k_pool, ck_pool, at, positions, valid, cfg)
+        qg = q.reshape(B, T, KV, H // KV, HD)  # KV-major groups, as _decode_block's
+        if T == 1:
+            attn = _sparse_decode(qg, k_pool, v_pool, ck_pool, at, positions, valid, cfg)
+        else:
+            attn = _sparse_prefill(qg, k_pool, v_pool, ck_pool, at, positions, cfg)
+        attn = (attn.reshape(B, T, H * HD).astype(jnp.float32) * gate).astype(x.dtype)
+        x = _residual(x, _proj(attn, lp["o"]["kernel"]), cfg)
+    with jax.named_scope("mlp"):
+        x = _residual(x, _dense_mlp(_norm(x, lp["mlp_norm"], cfg), lp, cfg=cfg), cfg)
+    return x, k_pool, v_pool, ck_pool
 
 
 def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
@@ -468,7 +729,7 @@ def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
     callers may leave it out. Returns ``(x, cache)`` with ``cache.layers``
     replaced."""
     require_served_format(stacks, x.dtype)
-    stacks = stacks if "ssm" in stacks else {"attn": stacks}
+    stacks = stacks if cfg.is_hybrid else {"attn": stacks}
 
     def attn_layer(x, lp, at, s):
         x, k, v, k_scale, v_scale = _decode_block(
@@ -482,7 +743,17 @@ def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
         x, ssm, conv = _ssm_block(x, lp, s["ssm"], s["conv"], at, valid, cfg)
         return x, {"ssm": ssm, "conv": conv}
 
-    layer_fns = {"attn": attn_layer, "ssm": ssm_layer}
+    def sparse_attn_layer(x, lp, at, s):
+        x, k, v, ck = _sparse_attn_block(x, lp, s["k"], s["v"], s["ck"], at, write,
+                                         positions, valid, cfg)
+        return x, {"k": k, "v": v, "ck": ck}
+
+    def lightning_layer(x, lp, at, s):
+        x, state = _lightning_block(x, lp, s["state"], at, positions, valid, cfg)
+        return x, {"state": state}
+
+    layer_fns = {"attn": attn_layer, "ssm": ssm_layer,
+                 "sparse_attn": sparse_attn_layer, "lightning": lightning_layer}
     state = cache.layers
     for kind, first, count in cfg.layer_runs():
 
@@ -591,7 +862,7 @@ def forward_with_cache(
         def write(cache_arr, rows, at):
             return lax.dynamic_update_slice(
                 cache_arr, rows[None].astype(cache_arr.dtype),
-                (at, 0, offset, 0, 0))
+                (at, 0, offset) + (0,) * (cache_arr.ndim - 3))
 
     x = embed_tokens(params, tokens, compute_dtype, positions=positions, cfg=cfg)
     # Padding's keys and values are masked later; a recurrent state has no

@@ -483,10 +483,13 @@ def estimate_serving_hbm(
     kv_pool, recurrent = layer_state.split_bytes(
         layer_state.state_bytes(cfg, slots, lanes, dtype, kv_quant, tp))
     if recurrent:
+        counts = layer_state.layer_counts(cfg)
+        whole = {k: n for k, n in counts.items() if layer_state.keeps_whole_state([k])}
+        named = ", ".join(f"{n} {layer_state.LAYER_KINDS[k].label or k} layers" for k, n in whole.items())
         notes.append(
-            f"recurrent state: {cfg.n_ssm_layers} Mamba-2 layers x {slots} "
-            f"slots (float32 SSM state + convolution inputs); keys and "
-            f"values for the {cfg.n_attn_layers} attention layers only")
+            f"recurrent state: {named} x {slots} slots (float32, every slot's "
+            f"whatever the occupancy); keys and values for the "
+            f"{sum(counts.values()) - sum(whole.values())} attention layers only")
     if prefix_cache_tokens > 0:
         # Shared-prefix entries are extra lanes outside the slot pool,
         # bounded by the token budget (eviction enforces it).

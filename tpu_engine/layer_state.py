@@ -15,7 +15,10 @@ length or ``pos`` hides what lies beyond it: an insert copies lanes, a reset
 needs nothing, a prefix can be sliced out and pasted, a verify pass rewound)
 or **whole** (no lane: an insert overwrites the row's state, a reset zeroes
 it, nothing can be sliced or rewound — which is all that
-``transformer.refuse_recurrent`` asks).
+``transformer.refuse_recurrent`` asks). A positional leaf need not hold a
+row per lane: one whose axis 2 is shorter holds a row per ``stride`` lanes
+(a sparse-attention layer's compressed keys, one per 16), and whoever cuts
+lanes cuts that leaf at ``lanes // stride`` (:func:`lane_stride`).
 
 A new kind of state is one entry here and a layer function in
 ``generate.scan_layers``; the cache manager, the wire's two ends and the
@@ -47,6 +50,7 @@ class Leaf(NamedTuple):
 class LayerKind(NamedTuple):
     positional: bool
     leaves: Callable[..., dict]  # (cfg, lanes, dtype, kv_quant) -> {name: Leaf}
+    label: str = ""              # what an operator's note calls such a layer
 
 
 def _attn_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
@@ -71,9 +75,34 @@ def _ssm_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
     }
 
 
+def _sparse_attn_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
+    """Keys and values per lane, the kv-heads side by side in the last dim
+    (``[lanes, KV x HD]``: with two kv-heads a ``[lanes, 2, HD]`` leaf would
+    leave a tile's second-minor dimension at 2 and pad it eightfold), and the
+    indexer's compressed keys ``ck``, one row per ``sparse_kernel_stride``
+    lanes: row m is the mean of the ``sparse_kernel_size`` keys from lane
+    ``stride x m`` on, written once the last of them is."""
+    if kv_quant:
+        raise NotImplementedError("sparse_attention layers keep no int8 keys and values (kv_quant)")
+    rows = Leaf((lanes, cfg.n_kv_heads * cfg.head_dim), dtype)
+    return {"k": rows, "v": rows,
+            "ck": rows._replace(shape=(lanes // cfg.sparse_kernel_stride,) + rows.shape[1:])}
+
+
+def _lightning_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
+    """A lightning-attention layer's state after the last REAL token fed:
+    per head the decayed sum of ``v^T k`` (value x key, as a Mamba-2 state is
+    ``[head_dim, state]``: both run ``generate._ssd_chunk``), float32 (every
+    token adds to it)."""
+    return {"state": Leaf((cfg.lightning_heads, cfg.lightning_head_dim,
+                           cfg.lightning_head_dim), jnp.float32)}
+
+
 LAYER_KINDS: dict[str, LayerKind] = {
-    "attn": LayerKind(positional=True, leaves=_attn_leaves),
-    "ssm": LayerKind(positional=False, leaves=_ssm_leaves),
+    "attn": LayerKind(positional=True, leaves=_attn_leaves, label="attention"),
+    "ssm": LayerKind(positional=False, leaves=_ssm_leaves, label="Mamba-2"),
+    "sparse_attn": LayerKind(positional=True, leaves=_sparse_attn_leaves, label="sparse-attention"),
+    "lightning": LayerKind(positional=False, leaves=_lightning_leaves, label="lightning"),
 }
 
 
@@ -143,10 +172,15 @@ def _positional(layers: dict) -> dict:
 
 
 def n_lanes(layers: dict) -> int:
-    """Lanes of the positional kinds (0 for a stack that has none)."""
-    for leaves in _positional(layers).values():
-        return next(iter(leaves.values())).shape[2]
-    return 0
+    """Lanes of the positional kinds (0 for a stack that has none): the
+    length of their longest leaf, which holds a row per lane."""
+    return max((a.shape[2] for leaves in _positional(layers).values()
+                for a in leaves.values()), default=0)
+
+
+def lane_stride(layers: dict, a) -> int:
+    """Lanes a row of positional leaf ``a`` (of ``layers``) stands for."""
+    return n_lanes(layers) // a.shape[2]
 
 
 def quantized(layers: dict) -> bool:
@@ -164,8 +198,9 @@ def whole_state_bytes(layers: dict) -> int:
 
 def insert_row(layers: dict, row: dict, slot) -> dict:
     """Copy a one-row tree into row ``slot``, cast to the pool's dtypes: a
-    positional kind's lanes from 0 (what lies past the row's length stays
-    hidden), a whole kind's whole state (whatever the slot held is gone)."""
+    positional kind's lanes from 0, each leaf at its own stride (what lies
+    past the row's length stays hidden), a whole kind's whole state (whatever
+    the slot held is gone)."""
     return {kind: {name: lax.dynamic_update_slice(
                        a, row[kind][name].astype(a.dtype), (0, slot) + (0,) * (a.ndim - 2))
                    for name, a in leaves.items()}
@@ -182,8 +217,10 @@ def reset_row(layers: dict, slot) -> dict:
 
 
 def slice_lanes(layers: dict, lanes: int) -> dict:
-    """The first ``lanes`` lanes of every positional kind."""
-    return {kind: {name: a[:, :, :lanes] for name, a in leaves.items()}
+    """The first ``lanes`` lanes of every positional kind (of a strided leaf,
+    the rows that stand for them)."""
+    return {kind: {name: a[:, :, :lanes // lane_stride(layers, a)]
+                   for name, a in leaves.items()}
             for kind, leaves in _positional(layers).items()}
 
 
@@ -193,7 +230,8 @@ def paste_lanes(layers: dict, src: dict, lanes: int) -> dict:
     src = _positional(src)
     return {kind: leaves if kind not in src
             else {name: lax.dynamic_update_slice(
-                      a, src[kind][name][:, :, :lanes].astype(a.dtype), (0,) * a.ndim)
+                      a, src[kind][name][:, :, :lanes // lane_stride(layers, a)].astype(a.dtype),
+                      (0,) * a.ndim)
                   for name, a in leaves.items()}
             for kind, leaves in layers.items()}
 

@@ -37,6 +37,12 @@ from tpu_engine.quant import QuantWeight, dequantize_weight
 from tpu_engine.quant_train import int8_einsum
 
 
+# A pattern's names for its layers -> the kind each is stacked, scanned and
+# cached under (``params["layers"][kind]``, ``layer_state.LAYER_KINDS[kind]``).
+LAYER_TYPE_KINDS = {"attention": "attn", "mamba": "ssm", "lightning": "lightning",
+                    "sparse_attention": "sparse_attn"}
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     name: str = "gpt-125m"
@@ -96,23 +102,54 @@ class ModelConfig:
     # Per-head dim decoupled from d_model // n_heads (Gemma: 256). 0 = derived.
     head_dim_override: int = 0
 
-    # Layer pattern: one entry per layer, "attention" or "mamba" (a Mamba-2
-    # mixer in the attention's place; the MLP follows either kind). Empty =
-    # every layer attends. A pattern with a "mamba" entry is a HYBRID stack:
+    # Layer pattern: one entry per layer, a key of :data:`LAYER_TYPE_KINDS`:
+    # "attention", "mamba" (a Mamba-2 mixer in the attention's place),
+    # "lightning" (linear attention with a fixed per-head decay) or
+    # "sparse_attention" (block-sparse attention that chooses the blocks it
+    # reads); the MLP follows every kind. Empty = every layer attends. A
+    # pattern with any other entry than "attention" is a HYBRID stack:
     # parameters are stacked per kind, the stack is scanned by runs of like
     # layers (:meth:`layer_runs`), and it is served only (llama recipe,
     # dense MLP, no window; see :func:`check_hybrid`).
     layer_types: tuple = ()
+    # The PUBLISHED index of each kept layer and the published depth: a
+    # lightning layer's decay depends on where the model has it, whatever
+    # depth a configuration keeps. Empty = the layers are 0 .. n_layers - 1.
+    layer_indices: tuple = ()
+    published_layers: int = 0
     # Mamba-2 widths: heads x head size is the mixer's inner width; B and C
     # are ``ssm_state`` wide and shared by all heads (one group); the causal
     # depthwise convolution is ``ssm_conv`` taps over x|B|C; prefill runs the
-    # chunked (SSD) form ``ssm_chunk`` tokens at a time.
+    # chunked (SSD) form ``ssm_chunk`` tokens at a time (a lightning layer's
+    # prefill too: it is the same scan with B and C per head).
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state: int = 0
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    # Lightning attention: ``lightning_heads`` heads of ``lightning_head_dim``
+    # (keys and values have as many), rotation on q and k, per-head q/k norm,
+    # a norm over the inner width and a sigmoid gate on the output; head h
+    # (1-based) of the layer published at index l decays its state by
+    # ``exp(-2^(-8h/H) (1 - l/(L-1) + 1e-5))`` a token.
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
+    # Block-sparse attention (``n_heads`` query heads over ``n_kv_heads``, no
+    # rotation, per-head q/k norm, a sigmoid output gate). A query past
+    # ``sparse_dense_len`` scores the compressed keys (the mean of
+    # ``sparse_kernel_size`` keys every ``sparse_kernel_stride``), pools them
+    # to blocks of ``sparse_block_size`` lanes and attends the ``sparse_topk``
+    # best, the first ``sparse_init_blocks`` and the last
+    # ``sparse_local_blocks`` (its own among them) always counted in; up to
+    # ``sparse_dense_len`` it attends everything.
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_block_size: int = 64
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_local_blocks: int = 32
+    sparse_dense_len: int = 8192
     # Scalar multipliers, each absent at its default: embeddings x
     # ``embed_scale``, every mixer and MLP output x ``residual_scale`` before
     # it joins the residual stream, logits / ``logits_divisor``; attention
@@ -137,15 +174,29 @@ class ModelConfig:
 
     @property
     def is_hybrid(self) -> bool:
-        return "mamba" in self.layer_types
+        return bool(set(self.layer_types) - {"attention"})
+
+    def n_layers_of(self, layer_type: str) -> int:
+        if not self.layer_types:
+            return self.n_layers if layer_type == "attention" else 0
+        return sum(t == layer_type for t in self.layer_types)
 
     @property
     def n_ssm_layers(self) -> int:
-        return sum(t == "mamba" for t in self.layer_types)
+        return self.n_layers_of("mamba")
 
     @property
     def n_attn_layers(self) -> int:
-        return self.n_layers - self.n_ssm_layers
+        return self.n_layers_of("attention")
+
+    @property
+    def lightning_inner(self) -> int:
+        return self.lightning_heads * self.lightning_head_dim
+
+    def published_indices(self, layer_type: str) -> tuple:
+        """The published index of each layer of ``layer_type``, in order."""
+        idx = self.layer_indices or tuple(range(self.n_layers))
+        return tuple(i for i, t in zip(idx, self.layer_types) if t == layer_type)
 
     @property
     def ssm_inner(self) -> int:
@@ -158,18 +209,18 @@ class ModelConfig:
 
     def layer_runs(self) -> tuple:
         """The pattern as runs of like layers: ``(kind, first, count)`` with
-        ``kind`` the per-kind stack ("attn" / "ssm") and ``first`` the run's
+        ``kind`` the per-kind stack (:data:`LAYER_TYPE_KINDS`) and ``first`` the run's
         first index WITHIN that stack. No pattern (every layer attends) is the
         one run ``("attn", 0, n_layers)``."""
         runs: list = []
-        seen = {"attn": 0, "ssm": 0}
+        seen: dict = {}
         for t in self.layer_types or ("attention",) * self.n_layers:
-            kind = "ssm" if t == "mamba" else "attn"
+            kind = LAYER_TYPE_KINDS[t]
             if runs and runs[-1][0] == kind:
                 runs[-1][2] += 1
             else:
-                runs.append([kind, seen[kind], 1])
-            seen[kind] += 1
+                runs.append([kind, seen.get(kind, 0), 1])
+            seen[kind] = seen.get(kind, 0) + 1
         return tuple(tuple(r) for r in runs)
 
     @property
@@ -187,16 +238,21 @@ class ModelConfig:
 
 class RecurrentLayersUnsupported(NotImplementedError):
     """A feature that assumes every layer's per-request state is keys and
-    values was asked of a model with recurrent (Mamba-2) layers. Raised by
-    name, never worked around: a recurrent state has no lanes to slice, mask
-    or rewind."""
+    values was asked of a model with recurrent (Mamba-2 or lightning) layers.
+    Raised by name, never worked around: a recurrent state has no lanes to
+    slice, mask or rewind."""
 
     def __init__(self, feature: str, cfg: "ModelConfig"):
         self.feature = feature
+        whole: dict = {}
+        for kind, _, count in cfg.layer_runs():
+            if layer_state.keeps_whole_state([kind]):
+                whole[kind] = whole.get(kind, 0) + count
         super().__init__(
             f"{feature} does not support model {cfg.name!r}: "
-            f"{cfg.n_ssm_layers} of its {cfg.n_layers} layers are recurrent "
-            "(Mamba-2), and their per-request state is not keys and values"
+            f"{sum(whole.values())} of its {cfg.n_layers} layers are recurrent "
+            f"({', '.join(whole)}), and their per-request state is not keys "
+            "and values"
         )
 
 
@@ -225,10 +281,10 @@ def check_hybrid(cfg: "ModelConfig") -> None:
     if not cfg.layer_types:
         return
     if len(cfg.layer_types) != cfg.n_layers or \
-            set(cfg.layer_types) - {"attention", "mamba"}:
+            set(cfg.layer_types) - set(LAYER_TYPE_KINDS):
         raise ValueError(
             f"layer_types must hold n_layers={cfg.n_layers} entries of "
-            f"'attention' or 'mamba', got {cfg.layer_types!r}"
+            f"{sorted(LAYER_TYPE_KINDS)}, got {cfg.layer_types!r}"
         )
     if not cfg.is_hybrid:
         return
@@ -238,10 +294,34 @@ def check_hybrid(cfg: "ModelConfig") -> None:
             f"and no sliding window (arch={cfg.arch!r}, "
             f"n_experts={cfg.n_experts}, sliding_window={cfg.sliding_window})"
         )
-    if cfg.ssm_groups != 1:
-        raise ValueError(f"ssm_groups={cfg.ssm_groups}: only one B/C group is supported")
-    if min(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state) < 1 or cfg.ssm_conv < 2:
-        raise ValueError("a 'mamba' layer needs ssm_heads, ssm_head_dim, ssm_state >= 1 and ssm_conv >= 2")
+    depth = cfg.published_layers or cfg.n_layers
+    if cfg.layer_indices and (len(cfg.layer_indices) != cfg.n_layers
+                              or not all(0 <= i < depth for i in cfg.layer_indices)):
+        raise ValueError(f"layer_indices must hold the published index (under {depth}) of each "
+                         f"of the n_layers={cfg.n_layers} layers, got {cfg.layer_indices!r}")
+    if "mamba" in cfg.layer_types:
+        if cfg.ssm_groups != 1:
+            raise ValueError(f"ssm_groups={cfg.ssm_groups}: only one B/C group is supported")
+        if min(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state) < 1 or cfg.ssm_conv < 2:
+            raise ValueError("a 'mamba' layer needs ssm_heads, ssm_head_dim, ssm_state >= 1 and ssm_conv >= 2")
+    if "lightning" in cfg.layer_types:
+        if cfg.lightning_heads < 1 or cfg.lightning_head_dim < 2 or cfg.lightning_head_dim % 2:
+            raise ValueError("a 'lightning' layer needs lightning_heads >= 1 "
+                             "and an even lightning_head_dim >= 2 (q and k are rotated)")
+        if depth < 2:
+            raise ValueError(f"a 'lightning' layer's decay needs a published depth >= 2, got {depth}")
+    if "sparse_attention" in cfg.layer_types:
+        size, stride, block = cfg.sparse_kernel_size, cfg.sparse_kernel_stride, cfg.sparse_block_size
+        if stride < 1 or size % stride or block % stride or size > block:
+            raise ValueError(
+                f"sparse_kernel_size={size} and sparse_block_size={block} must be multiples of "
+                f"sparse_kernel_stride={stride}, and a window no longer than a block")
+        if cfg.sparse_init_blocks + cfg.sparse_local_blocks > cfg.sparse_topk \
+                or cfg.sparse_dense_len < cfg.sparse_topk * block:
+            raise ValueError(
+                f"sparse_topk={cfg.sparse_topk} must hold the {cfg.sparse_init_blocks} first and "
+                f"{cfg.sparse_local_blocks} last blocks, and sparse_dense_len={cfg.sparse_dense_len} "
+                f"at least sparse_topk blocks of {block} (a position that selects sees that many)")
 
 
 # Model scales matching the reference's preset names (7b/13b/70b at
@@ -343,12 +423,31 @@ def _scaled(x, s, dtype):
     return (x * s).astype(dtype)
 
 
-def init_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> dict[str, Any]:
+def _drawn(deferred: bool, draw, *args, **kw):
+    """A drawn kernel, or with ``deferred`` the call that draws it."""
+    return partial(draw, *args, **kw) if deferred else draw(*args, **kw)
+
+
+def draw_deferred(params: dict[str, Any]) -> dict[str, Any]:
+    """A tree of :func:`init_params` (``deferred=True``) with every kernel
+    still to be drawn now drawn."""
+    return jax.tree.map(lambda a: a() if callable(a) else a, params)
+
+
+def init_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32,
+                deferred: bool = False) -> dict[str, Any]:
     """Initialise parameters (normal(0.02); residual-out projections scaled
-    by 1/sqrt(2·n_layers), GPT-2 style)."""
+    by 1/sqrt(2·n_layers), GPT-2 style).
+
+    ``deferred``: every drawn kernel (and table) is left as a call that draws
+    it, the programs and values those of the tree drawn at once; a caller that
+    turns each leaf into something smaller as it is made (the int8 build:
+    ``quant.quantize_params``, then :func:`draw_deferred` for what is left)
+    never holds the float32 tree, which for a model that fills a chip in bf16
+    does not fit."""
     check_hybrid(cfg)
     if cfg.is_hybrid:
-        return _init_hybrid_params(rng, cfg, dtype)
+        return _init_hybrid_params(rng, cfg, dtype, deferred)
     k_embed, k_q, k_k, k_v, k_o, k_gate, k_up, k_down, k_head = jax.random.split(rng, 9)
     L, D, V, F = cfg.n_layers, cfg.d_model, cfg.vocab_size, cfg.d_ff
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -356,7 +455,7 @@ def init_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> dict[str
     res_std = std / (2 * L) ** 0.5
 
     def norm(key, shape, s):
-        return _scaled(jax.random.normal(key, shape, jnp.float32), s, dtype)
+        return _drawn(deferred, lambda: _scaled(jax.random.normal(key, shape, jnp.float32), s, dtype))
 
     if cfg.arch == "gpt2":
         return {
@@ -423,11 +522,14 @@ def init_params(rng: jax.Array, cfg: ModelConfig, dtype=jnp.float32) -> dict[str
     return out
 
 
-def _init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype) -> dict[str, Any]:
-    """A hybrid stack's parameters, stacked PER KIND: ``layers["attn"]`` holds
-    the ``n_attn_layers`` attention layers (the llama leaves) and
-    ``layers["ssm"]`` the ``n_ssm_layers`` Mamba-2 layers, each with its own
-    MLP, in the order the pattern meets them.
+def _init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype, deferred: bool = False) -> dict[str, Any]:
+    """A hybrid stack's parameters, stacked PER KIND, for the kinds the
+    pattern has: ``layers["attn"]`` holds the ``n_attn_layers`` attention
+    layers (the llama leaves), ``layers["ssm"]`` the ``n_ssm_layers`` Mamba-2
+    layers, ``layers["sparse_attn"]`` and ``layers["lightning"]`` the
+    block-sparse and the lightning attention layers
+    (:func:`_init_sparse_attn_stack`, :func:`_init_lightning_stack`), each
+    with its own MLP, in the order the pattern meets them.
 
     Projection kernels as every family (normal(0.02), outputs / sqrt(2 L));
     the embedding is drawn ``embed_scale`` times smaller, so that the scaled
@@ -446,9 +548,9 @@ def _init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype) -> dict[str, An
     res_std = std / (2 * L) ** 0.5
 
     def norm(key, shape, s):
-        return _scaled(jax.random.normal(key, shape, jnp.float32), s, dtype)
+        return _drawn(deferred, lambda: _scaled(jax.random.normal(key, shape, jnp.float32), s, dtype))
 
-    attn = {
+    attn = lambda: {  # noqa: E731
         "attn_norm": {"scale": jnp.ones((La, D), dtype)},
         "q": {"kernel": norm(ks[1], (La, D, H * HD), std)},
         "k": {"kernel": norm(ks[2], (La, D, KV * HD), std)},
@@ -462,7 +564,7 @@ def _init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype) -> dict[str, An
     dt = jnp.exp(jax.random.uniform(ks[11], (Ls, SH), jnp.float32,
                                     jnp.log(1e-3), jnp.log(1e-1)))
     bound = 1.0 / K ** 0.5
-    ssm = {
+    ssm = lambda: {  # noqa: E731
         "ssm_norm": {"scale": jnp.ones((Ls, D), dtype)},
         "in_proj": {"kernel": norm(ks[8], (Ls, D, I + C + SH), std)},
         "conv": {"kernel": jax.random.uniform(ks[9], (Ls, K, C), jnp.float32,
@@ -478,9 +580,13 @@ def _init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype) -> dict[str, An
         "up": {"kernel": norm(ks[14], (Ls, D, F), std)},
         "down": {"kernel": norm(ks[15], (Ls, F, D), res_std)},
     }
+    stacks = {"attn": attn, "ssm": ssm,
+              "sparse_attn": partial(_init_sparse_attn_stack, rng, cfg, dtype, deferred),
+              "lightning": partial(_init_lightning_stack, rng, cfg, dtype, deferred)}
+    kinds = dict.fromkeys(kind for kind, _, _ in cfg.layer_runs())
     out = {
         "embed": {"embedding": norm(ks[0], (V, D), std / cfg.embed_scale)},
-        "layers": {"attn": attn, "ssm": ssm},
+        "layers": {kind: stacks[kind]() for kind in kinds},
         "final_norm": {"scale": jnp.ones((D,), dtype)},
     }
     if not cfg.tied_head:
@@ -488,10 +594,96 @@ def _init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype) -> dict[str, An
     return out
 
 
-# Recurrence leaves of a Mamba-2 layer that stay float32 wherever the rest of
-# the tree goes to a compute dtype (:func:`served_format`,
-# :func:`cast_layer_stack`): a bf16 ``A_log`` moves every decay.
+@partial(jax.jit, static_argnames=("n", "shape", "dtype"))
+def _draw_layers(key, s, *, n: int, shape: tuple, dtype):
+    """``n`` layers' worth of normal(0, s) kernels, stacked: layer i is drawn
+    from ``split(key, n)[i]`` alone, so that a reader who wants one layer
+    (the benchmark's reference, which holds one at a time) draws that layer
+    and no other."""
+    draw = lambda k: jax.random.normal(k, shape, jnp.float32)  # noqa: E731
+    return (jax.vmap(draw)(jax.random.split(key, n)) * s).astype(dtype)
+
+
+def _mixer_mlp_stack(draw, keys, n: int, cfg: ModelConfig, dtype) -> dict:
+    """What every kind of layer has after its mixer: the norm and the SwiGLU
+    MLP, ``n`` layers stacked (keys: gate, up, down; ``draw`` as its caller's)."""
+    D, F = cfg.d_model, cfg.d_ff
+    res_std = 0.02 / (2 * cfg.n_layers) ** 0.5
+    return {
+        "mlp_norm": {"scale": jnp.ones((n, D), dtype)},
+        "gate": {"kernel": draw(keys[0], 0.02, shape=(D, F))},
+        "up": {"kernel": draw(keys[1], 0.02, shape=(D, F))},
+        "down": {"kernel": draw(keys[2], res_std, shape=(F, D))},
+    }
+
+
+def _init_sparse_attn_stack(rng, cfg: ModelConfig, dtype, deferred: bool = False) -> dict:
+    """The block-sparse attention layers: the llama projections, per-head q/k
+    norm scales and the output gate's projection. Keys: ``split(fold_in(rng,
+    101), 8)`` in the order q, k, v, o_gate, o, gate, up, down; each leaf's
+    layer i from its own ``split(key, n)[i]`` (:func:`_draw_layers`)."""
+    n = cfg.n_layers_of("sparse_attention")
+    D, H, KV, HD = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    ks = jax.random.split(jax.random.fold_in(rng, 101), 8)
+    res_std = 0.02 / (2 * cfg.n_layers) ** 0.5
+    draw = partial(_drawn, deferred, _draw_layers, n=n, dtype=dtype)
+    return {
+        "attn_norm": {"scale": jnp.ones((n, D), dtype)},
+        "q": {"kernel": draw(ks[0], 0.02, shape=(D, H * HD))},
+        "k": {"kernel": draw(ks[1], 0.02, shape=(D, KV * HD))},
+        "v": {"kernel": draw(ks[2], 0.02, shape=(D, KV * HD))},
+        "o_gate": {"kernel": draw(ks[3], 0.02, shape=(D, H * HD))},
+        "o": {"kernel": draw(ks[4], res_std, shape=(H * HD, D))},
+        "q_norm": {"scale": jnp.ones((n, HD), dtype)},
+        "k_norm": {"scale": jnp.ones((n, HD), dtype)},
+        **_mixer_mlp_stack(draw, ks[5:], n, cfg, dtype),
+    }
+
+
+def lightning_decay_rates(cfg: ModelConfig) -> jax.Array:
+    """[L_lightning, H] float32: what head h (1-based) of each lightning layer
+    multiplies its state's exponent by, ``2^(-8h/H) (1 - l/(L-1) + 1e-5)``
+    with l the layer's PUBLISHED index and L the published depth; the state
+    decays by ``exp(-rate)`` a token."""
+    H = cfg.lightning_heads
+    depth = cfg.published_layers or cfg.n_layers
+    slopes = 2.0 ** (-8.0 * jnp.arange(1, H + 1, dtype=jnp.float32) / H)
+    at = jnp.asarray(cfg.published_indices("lightning"), jnp.float32)
+    return slopes[None, :] * (1.0 - at / (depth - 1) + 1e-5)[:, None]
+
+
+def _init_lightning_stack(rng, cfg: ModelConfig, dtype, deferred: bool = False) -> dict:
+    """The lightning-attention layers: q, k, v, the output gate and o, per-head
+    q/k norm scales, the output norm over the inner width, and ``decay``, the
+    per-head rates (:func:`lightning_decay_rates`: fixed, not learned, and
+    float32 wherever the tree goes). Keys: ``split(fold_in(rng, 102), 8)`` in
+    the order q, k, v, o_gate, o, gate, up, down, as the sparse kind's."""
+    n = cfg.n_layers_of("lightning")
+    D, I, HD = cfg.d_model, cfg.lightning_inner, cfg.lightning_head_dim
+    ks = jax.random.split(jax.random.fold_in(rng, 102), 8)
+    res_std = 0.02 / (2 * cfg.n_layers) ** 0.5
+    draw = partial(_drawn, deferred, _draw_layers, n=n, dtype=dtype)
+    return {
+        "attn_norm": {"scale": jnp.ones((n, D), dtype)},
+        "q": {"kernel": draw(ks[0], 0.02, shape=(D, I))},
+        "k": {"kernel": draw(ks[1], 0.02, shape=(D, I))},
+        "v": {"kernel": draw(ks[2], 0.02, shape=(D, I))},
+        "o_gate": {"kernel": draw(ks[3], 0.02, shape=(D, I))},
+        "o": {"kernel": draw(ks[4], res_std, shape=(I, D))},
+        "q_norm": {"scale": jnp.ones((n, HD), dtype)},
+        "k_norm": {"scale": jnp.ones((n, HD), dtype)},
+        "out_norm": {"scale": jnp.ones((n, I), dtype)},
+        "decay": lightning_decay_rates(cfg),
+        **_mixer_mlp_stack(draw, ks[5:], n, cfg, dtype),
+    }
+
+
+# Recurrence leaves of a Mamba-2 layer, and with a lightning layer's per-head
+# rates all that stays float32 wherever the rest of the tree goes to a compute
+# dtype (:func:`served_format`, :func:`cast_layer_stack`): a bf16 ``A_log``
+# moves every decay.
 SSM_FLOAT32_LEAVES = ("A_log", "dt_bias", "D")
+FLOAT32_LEAVES = SSM_FLOAT32_LEAVES + ("decay",)
 
 
 def logical_axes(cfg: ModelConfig) -> dict[str, Any]:
@@ -503,32 +695,55 @@ def logical_axes(cfg: ModelConfig) -> dict[str, Any]:
             "up": {"kernel": ("layers", "embed", "mlp")},
             "down": {"kernel": ("layers", "mlp", "embed")},
         }
+        mixer_axes = {
+            "attn_norm": {"scale": ("layers", "embed")},
+            "q": {"kernel": ("layers", "embed", "heads")},
+            "o_gate": {"kernel": ("layers", "embed", "heads")},
+            "o": {"kernel": ("layers", "heads", "embed")},
+            "q_norm": {"scale": ("layers", None)},
+            "k_norm": {"scale": ("layers", None)},
+            **mlp_axes,
+        }
+        stacks = {
+            "sparse_attn": {
+                "k": {"kernel": ("layers", "embed", "kv_heads")},
+                "v": {"kernel": ("layers", "embed", "kv_heads")},
+                **mixer_axes,
+            },
+            "lightning": {
+                "k": {"kernel": ("layers", "embed", "heads")},
+                "v": {"kernel": ("layers", "embed", "heads")},
+                "out_norm": {"scale": ("layers", None)},
+                "decay": ("layers", None),
+                **mixer_axes,
+            },
+            "attn": {
+                "attn_norm": {"scale": ("layers", "embed")},
+                "q": {"kernel": ("layers", "embed", "heads")},
+                "k": {"kernel": ("layers", "embed", "kv_heads")},
+                "v": {"kernel": ("layers", "embed", "kv_heads")},
+                "o": {"kernel": ("layers", "heads", "embed")},
+                **mlp_axes,
+            },
+            # The mixer's fused projection (z | x | B | C | dt) has no
+            # head-aligned split to shard: its width stays whole.
+            "ssm": {
+                "ssm_norm": {"scale": ("layers", "embed")},
+                "in_proj": {"kernel": ("layers", "embed", None)},
+                "conv": {"kernel": ("layers", None, None),
+                         "bias": ("layers", None)},
+                "A_log": ("layers", None),
+                "dt_bias": ("layers", None),
+                "D": ("layers", None),
+                "gate_norm": {"scale": ("layers", None)},
+                "out_proj": {"kernel": ("layers", None, "embed")},
+                **mlp_axes,
+            },
+        }
         out = {
             "embed": {"embedding": ("vocab", "embed")},
-            "layers": {
-                "attn": {
-                    "attn_norm": {"scale": ("layers", "embed")},
-                    "q": {"kernel": ("layers", "embed", "heads")},
-                    "k": {"kernel": ("layers", "embed", "kv_heads")},
-                    "v": {"kernel": ("layers", "embed", "kv_heads")},
-                    "o": {"kernel": ("layers", "heads", "embed")},
-                    **mlp_axes,
-                },
-                # The mixer's fused projection (z | x | B | C | dt) has no
-                # head-aligned split to shard: its width stays whole.
-                "ssm": {
-                    "ssm_norm": {"scale": ("layers", "embed")},
-                    "in_proj": {"kernel": ("layers", "embed", None)},
-                    "conv": {"kernel": ("layers", None, None),
-                             "bias": ("layers", None)},
-                    "A_log": ("layers", None),
-                    "dt_bias": ("layers", None),
-                    "D": ("layers", None),
-                    "gate_norm": {"scale": ("layers", None)},
-                    "out_proj": {"kernel": ("layers", None, "embed")},
-                    **mlp_axes,
-                },
-            },
+            "layers": {kind: stacks[kind]
+                       for kind in dict.fromkeys(k for k, _, _ in cfg.layer_runs())},
             "final_norm": {"scale": ("embed",)},
         }
         if not cfg.tied_head:
@@ -608,8 +823,14 @@ def param_count(cfg: ModelConfig) -> int:
         # the gate's norm, out_proj; then the layer's two norms and MLP.
         per_ssm = (D * (I + C + SH) + (cfg.ssm_conv + 1) * C + 3 * SH + I
                    + I * D + mlp + 2 * D)
-        return (V * D + cfg.n_attn_layers * per_layer
-                + cfg.n_ssm_layers * per_ssm + D + head)
+        # q, k, v, the output gate and o, the q/k norms (and lightning's output
+        # norm and decay rates); then the layer's two norms and MLP.
+        per_sparse = 2 * D * H * HD + 2 * D * KV * HD + H * HD * D + 2 * HD + mlp + 2 * D
+        LI, LHD = cfg.lightning_inner, cfg.lightning_head_dim
+        per_lightning = 5 * D * LI + 2 * LHD + LI + cfg.lightning_heads + mlp + 2 * D
+        return (V * D + cfg.n_attn_layers * per_layer + cfg.n_ssm_layers * per_ssm
+                + cfg.n_layers_of("sparse_attention") * per_sparse
+                + cfg.n_layers_of("lightning") * per_lightning + D + head)
     return V * D + L * per_layer + D + head
 
 
@@ -1217,11 +1438,11 @@ def _served_dtype(path, leaf, compute_dtype):
     """The dtype ``leaf`` has in the served format, or None where the format
     leaves it as it is: a :class:`QuantWeight` (int8 codes cast at the matmul,
     and its fp32 scales must NOT round to bf16 — that would double the
-    quantization error for free), a non-floating leaf, and a Mamba-2 layer's
-    :data:`SSM_FLOAT32_LEAVES`."""
+    quantization error for free), a non-floating leaf, and the recurrence
+    leaves (:data:`FLOAT32_LEAVES`)."""
     if _is_quant(leaf) or not jnp.issubdtype(leaf.dtype, jnp.floating):
         return None
-    if getattr(path[-1], "key", None) in SSM_FLOAT32_LEAVES:
+    if getattr(path[-1], "key", None) in FLOAT32_LEAVES:
         return None
     return jnp.dtype(compute_dtype)
 

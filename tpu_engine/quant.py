@@ -43,6 +43,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from tpu_engine.layer_state import LAYER_KINDS
+
 
 @jax.tree_util.register_dataclass
 @dataclass
@@ -89,7 +91,9 @@ def dequantize_weight(qw: QuantWeight, dtype=jnp.float32) -> jax.Array:
 # (stacked expert gate/up/down; the router stays fp32).
 _QUANT_LAYER_KEYS = ("q", "k", "v", "o", "gate", "up", "down", "fc", "proj",
                      # a hybrid stack's Mamba-2 mixer projections
-                     "in_proj", "out_proj")
+                     "in_proj", "out_proj",
+                     # the output gate of its lightning and sparse-attention layers
+                     "o_gate")
 
 
 def _walk(params: dict[str, Any], kernel_fn) -> dict[str, Any]:
@@ -111,7 +115,7 @@ def _walk(params: dict[str, Any], kernel_fn) -> dict[str, Any]:
         layers = params["layers"]
         # A hybrid stack keeps one stack per kind of layer.
         out["layers"] = ({kind: walk_stack(stack) for kind, stack in layers.items()}
-                         if "ssm" in layers else walk_stack(layers))
+                         if set(layers) <= set(LAYER_KINDS) else walk_stack(layers))
     if "lm_head" in params:
         head = dict(params["lm_head"])
         head["kernel"] = kernel_fn(head["kernel"])
@@ -123,12 +127,14 @@ def quantize_params(params: dict[str, Any]) -> dict[str, Any]:
     """Param tree → serving tree with projection kernels as
     :class:`QuantWeight`. Idempotent-hostile by design: quantizing an
     already-quantized tree raises (re-quantization would silently
-    compound the error)."""
+    compound the error). A kernel still to be drawn
+    (``init_params(deferred=True)``) is drawn here and quantized at once,
+    so its float32 lives no longer than that."""
 
     def quant(kernel):
         if isinstance(kernel, QuantWeight):
             raise ValueError("params are already int8-quantized")
-        return quantize_weight(kernel)
+        return quantize_weight(kernel() if callable(kernel) else kernel)
 
     return _walk(params, quant)
 

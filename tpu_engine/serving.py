@@ -855,6 +855,12 @@ class ContinuousBatcher:
         self._profiler = StepProfiler(loop="batcher", phases=BATCHER_PHASES)
         self._decode_tokens_computed = 0
         self._decode_tokens_emitted = 0
+        # Of the computed token-steps, those whose context was past a
+        # sparse-attention stack's ``sparse_dense_len`` (they chose their
+        # blocks); stays 0 for a stack with no such layer.
+        self._sparse_from = cfg.sparse_dense_len \
+            if "sparse_attention" in cfg.layer_types else None
+        self._decode_tokens_sparse = 0
         # Recurrent state written into a slot by a finished prefill, and
         # zeroed when a slot is freed (hybrid stacks; else both stay 0).
         self._recurrent_state_bytes = self._cache.recurrent_state_bytes
@@ -1199,9 +1205,11 @@ class ContinuousBatcher:
                 # thrown away.
                 "decode_tokens_computed_total": self._decode_tokens_computed,
                 "decode_tokens_emitted_total": self._decode_tokens_emitted,
-                # The pool's second kind of state (0 for attention-only
-                # stacks): its bytes, every slot's whether in use or not,
-                # and how often a slot's was written whole or zeroed.
+                "decode_tokens_sparse_total": self._decode_tokens_sparse,
+                # The pool's whole kinds of state (Mamba-2, lightning; 0
+                # for attention-only stacks): their bytes, every slot's
+                # whether in use or not, and how often a slot's was
+                # written whole or zeroed.
                 "recurrent_state_bytes": self._recurrent_state_bytes,
                 "state_inserts_total": self._state_inserts,
                 "state_resets_total": self._state_resets,
@@ -1489,7 +1497,13 @@ class ContinuousBatcher:
             else:
                 toks_host = np.asarray(toks_bn)     # [B, n] — one transfer
                 n_take = None
-        self._decode_tokens_computed += len(active_reqs) * toks_host.shape[1]
+        n_steps = toks_host.shape[1]
+        self._decode_tokens_computed += len(active_reqs) * n_steps
+        if self._sparse_from is not None:
+            # A row's steps run at positions context - 1 .. context + n - 2.
+            self._decode_tokens_sparse += sum(
+                min(max(len(r.prompt) + len(r.tokens) - 1 + n_steps - self._sparse_from, 0), n_steps)
+                for _, r in active_reqs)
         with prof.phase("emit"), self._lock:
             emitted = 0
             for slot, req in active_reqs:

@@ -200,9 +200,12 @@ def build_replica_engine(spec: ServingReplicaSpec) -> Any:
             from tpu_engine.quant import quantize_params
 
             # Quantised from the float32 draw (codes of values already rounded
-            # to the compute dtype would be another model); what stays
-            # unquantised is converted by the engine.
-            params = quantize_params(tfm.init_params(key, cfg))
+            # to the compute dtype would be another model), leaf by leaf: a
+            # kernel's float32 lives from its draw to its codes, never the
+            # whole float32 tree (a model that fills a chip in bf16 is twice
+            # the chip in float32). What stays unquantised is converted by the
+            # engine. Values are those of quantize_params(init_params(key, cfg)).
+            params = tfm.draw_deferred(quantize_params(tfm.init_params(key, cfg, deferred=True)))
         else:
             # Drawn in the format the engine holds: each leaf is rounded from
             # its float32 draw as it is made, so no float32 tree ever exists.
