@@ -317,6 +317,30 @@ def test_control_an_omission_moves_the_logits(tiny, omitted, at_least):
     assert gap > at_least > 100 * TOL, gap
 
 
+def test_control_every_query_attending_its_tiles_union_moves_the_logits(tiny, monkeypatch):
+    """The chunk kernel skips by tile and masks by query. A kernel that is fast
+    because every query attends whatever SOME query of its tile chose is the
+    same kernel handed the tile's union as each query's choice: the logits of
+    the positions that select must move by far more than ``TOL`` (measured
+    2.2e-3; a position below ``dense_len`` attends everything either way)."""
+    cfg, mc, params, _ = tiny
+    toks = _tokens(150)
+    attend = sparse_block_attention.sparse_chunk_attend
+
+    def union_for_all(qg, k_pool, v_pool, chosen, layer, positions, *, block, **kw):
+        B, KV, T, n_blocks = chosen.shape
+        tq, _ = sparse_block_attention.chunk_geometry(T, n_blocks, block)
+        tiles = jnp.pad(chosen, ((0, 0), (0, 0), (0, -T % tq), (0, 0))).reshape(B, KV, -1, tq, n_blocks)
+        union = jnp.repeat(tiles.any(3), tq, axis=2)[:, :, :T]
+        return attend(qg, k_pool, v_pool, union, layer, positions, block=block, **kw)
+
+    monkeypatch.setattr(sparse_block_attention, "sparse_chunk_attend", union_for_all)
+    logits, _ = forward_with_cache(params, jnp.asarray(toks)[None], init_cache(mc, 1, LANES, dtype=F32), mc,
+                                   compute_dtype=F32)
+    gap = np.abs(np.asarray(logits[0]) - _reference(tiny, toks)[0][:150]).max(-1)
+    assert gap[:64].max() < TOL and gap[64:].max() > 6e-4 > 100 * TOL, (gap[:64].max(), gap[64:].max())
+
+
 # (e) the engine, end to end -----------------------------------------------------
 
 
@@ -347,6 +371,31 @@ def test_the_engine_serves_what_the_reference_would(tiny):
     # request 2 (45 + 30) crosses dense_len = 64 while it decodes; 100, 70 and 90 are past it throughout
     computed, sparse = st["decode_tokens_computed_total"], st["decode_tokens_sparse_total"]
     assert 0 < computed - sparse <= 64 - 45 + 4 and sparse >= 11 + 6 + 14 + (45 + 29 - 64)
+
+
+@pytest.mark.parametrize("stack", ["sparse_and_lightning", "attention_only"])
+def test_stats_count_the_prompt_positions_ingested_and_those_that_select(tiny, stack):
+    """``prefill_tokens_computed_total`` counts every position a prefill chunk
+    computed (the bucket's padding among them), ``prefill_tokens_sparse_total``
+    those at or past ``sparse_dense_len`` of a stack that has the kind."""
+    cfg, mc, params, _ = tiny
+    if stack == "attention_only":
+        mc = tfm.ModelConfig(name="plain-tiny", arch="llama", vocab_size=512, d_model=32, n_layers=2, n_heads=4,
+                             n_kv_heads=2, d_ff=64, max_seq_len=256, norm_eps=1e-6)
+        params = tfm.init_params(jax.random.PRNGKey(SEED), mc)
+    engine = serving.ContinuousBatcher(params, mc, max_slots=2, max_len=LANES, compute_dtype=F32,
+                                       prefill_chunk=2 * CHUNK, prefill_pad_to=PAD, chunk_steps=4)
+    st = engine.stats()
+    assert st["prefill_tokens_computed_total"] == st["prefill_tokens_sparse_total"] == 0
+    # 45 pads to 48, one chunk, all under dense_len = 64; 100 pads to 104: chunks at 0, 48 and 96
+    ids = [engine.submit(_tokens(n, 20 + n).tolist(), max_new_tokens=2) for n in (45, 100)]
+    for _ in range(50):
+        engine.step()
+        if all(engine.result(i)["status"] == "done" for i in ids):
+            break
+    st = engine.stats()
+    assert st["prefill_tokens_computed_total"] == 48 + 104
+    assert st["prefill_tokens_sparse_total"] == (104 - 64 if stack == "sparse_and_lightning" else 0)
 
 
 # (f) all four kinds in one stack --------------------------------------------------

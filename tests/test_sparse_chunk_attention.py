@@ -1,0 +1,199 @@
+"""The prefill chunk kernel of a block-sparse attention layer
+(``ops.sparse_block_attention.sparse_chunk_attend``, reached through
+``generate._sparse_prefill``) against a plain ``jnp`` oracle: every lane's
+score for every query, masked to what the query attends — the lanes up to its
+position of its chosen blocks, of every block below ``sparse_dense_len`` —
+and one softmax over the row. That oracle was the program's own prefill until
+the kernel replaced it.
+
+Tiny widths (2 kv-heads x 2 heads of 16, blocks of 16 lanes, the 4 best of
+them, 2 local, windows of 8 keys every 4), float32 on the CPU, the kernel
+interpreted. Both sides are float32 and differ in the order of their sums (a
+running softmax over key tiles against one over the row): the cases measured
+2.1e-7 to 7.2e-7 where outputs spread 0.22, and ``TOL`` = 3e-6 leaves four
+times that. The control attends, for every query, the whole union of its tile,
+and must miss ``TOL`` by far.
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from tpu_engine.models import transformer as tfm  # noqa: E402
+from tpu_engine.ops import sparse_block_attention as sba  # noqa: E402
+
+sba.INTERPRET_OFF_TPU = True  # these are the CPU's tests: both kernels are interpreted
+
+generate = sys.modules["tpu_engine.generate"]  # the package's ``generate`` is the function
+TOL = 3e-6
+KV, G, HD, BLOCK = 2, 2, 16, 16
+F32 = jnp.float32
+
+
+def _config(dense_len=64, topk=4):
+    return tfm.ModelConfig(
+        name="chunk-kernel", arch="llama", vocab_size=64, d_model=KV * G * HD, n_layers=1, n_heads=KV * G,
+        n_kv_heads=KV, d_ff=32, max_seq_len=1024, norm_eps=1e-6, layer_types=("sparse_attention",),
+        sparse_block_size=BLOCK, sparse_topk=topk, sparse_init_blocks=1, sparse_local_blocks=2,
+        sparse_kernel_size=8, sparse_kernel_stride=4, sparse_dense_len=dense_len)
+
+
+def _draw(seed, B, T, lanes, layers, starts):
+    """Random queries, a pool of ``layers`` layers and the compressed keys its
+    windows imply; row b's chunk stands at positions starts[b] .. + T - 1."""
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    qg = jax.random.normal(kq, (B, T, KV, G, HD), F32)
+    k_pool = jax.random.normal(kk, (layers, B, lanes, KV * HD), F32)
+    v_pool = jax.random.normal(kv, (layers, B, lanes, KV * HD), F32)
+    windows = jnp.stack([k_pool[:, :, 4 * m:4 * m + 8].mean(2) for m in range((lanes - 8) // 4 + 1)], 2)
+    ck_pool = jnp.pad(windows, ((0, 0), (0, 0), (0, lanes // 4 - windows.shape[2]), (0, 0)))
+    positions = jnp.asarray(starts, jnp.int32)[:, None] + jnp.arange(T, dtype=jnp.int32)
+    return qg, k_pool, v_pool, ck_pool, positions
+
+
+def _chosen(qg, ck_pool, at, positions, n_blocks, cfg):
+    """[B,KV,T,n_blocks]: the blocks each query attends, by the program's own indexer."""
+    B = qg.shape[0]
+    ids = generate._select_blocks(qg, ck_pool[at].reshape(B, -1, KV, HD), positions, n_blocks, cfg)
+    chosen = jnp.any(ids[..., None] == jnp.arange(n_blocks), axis=-2)
+    chosen |= (positions < cfg.sparse_dense_len)[:, None, :, None]
+    return chosen & (jnp.arange(n_blocks) <= (positions // BLOCK)[:, None, :, None])
+
+
+def _oracle(qg, k_pool, v_pool, at, positions, chosen, cfg):
+    """Dense and masked: every lane's score, one softmax over the row."""
+    B, S = qg.shape[0], k_pool.shape[2]
+    k = k_pool[at].reshape(B, S, KV, HD)
+    v = v_pool[at].reshape(B, S, KV, HD)
+    s = jnp.einsum("btkgd,bmkd->bkgtm", qg, k, preferred_element_type=F32) * tfm.attention_scale(cfg)
+    keep = jnp.repeat(chosen, BLOCK, axis=-1) & (jnp.arange(S) <= positions[:, None, :, None])
+    p = jax.nn.softmax(jnp.where(keep[:, :, None], s, -1e30), axis=-1).astype(qg.dtype)
+    return jnp.einsum("bkgtm,bmkd->btkgd", p, v)
+
+
+# name: (rows' first positions, T, lanes, dense_len, topk, layers, at, lanes a key tile)
+CASES = {
+    "one_row_two_whole_tiles_past_dense_len": ([256], 256, 512, 64, 4, 1, 0, 256),
+    "two_rows_at_different_positions": ([128, 40], 256, 512, 64, 4, 1, 0, 64),
+    "T_is_no_multiple_of_the_tile": ([200], 200, 512, 64, 4, 1, 0, 32),
+    "T_smaller_than_a_tile_and_no_multiple_of_8": ([100], 21, 256, 64, 4, 1, 0, 32),
+    "straddles_dense_len": ([0], 256, 256, 100, 4, 1, 0, 32),
+    "wholly_below_dense_len": ([16], 160, 512, 8192, 4, 1, 0, 64),
+    "wholly_past_dense_len_key_tiles_of_one_block": ([300], 136, 512, 64, 4, 1, 0, 16),
+    "fewer_blocks_than_topk": ([24], 24, 48, 16, 8, 1, 0, 256),
+    "two_rows_layer_2_of_a_pool_of_3": ([64, 150], 144, 320, 64, 4, 3, 2, 32),
+}
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_a_chunk_through_the_kernel_equals_the_dense_masked_oracle(case, monkeypatch):
+    starts, T, lanes, dense_len, topk, layers, at, key_lanes = CASES[case]
+    monkeypatch.setattr(sba, "_KEY_LANES", key_lanes)
+    cfg = _config(dense_len, topk)
+    qg, k_pool, v_pool, ck_pool, positions = _draw(len(case), len(starts), T, lanes, layers, starts)
+    got = jax.jit(generate._sparse_prefill, static_argnums=6)(
+        qg, k_pool, v_pool, ck_pool, jnp.int32(at), positions, cfg)
+    chosen = _chosen(qg, ck_pool, at, positions, lanes // BLOCK, cfg)
+    want = _oracle(qg, k_pool, v_pool, at, positions, chosen, cfg)
+    assert got.shape == want.shape == qg.shape
+    assert float(jnp.abs(got - want).max()) < TOL
+    # the case is what its name says
+    past = np.asarray(positions) >= dense_len
+    assert {"straddles_dense_len": past.any() and not past.all(), "wholly_below_dense_len": not past.any()}.get(
+        case, past.any())
+
+
+def test_control_a_query_that_attends_its_tiles_whole_union_moves_the_output(monkeypatch):
+    """The mask is per query. A kernel that let every query attend what ANY
+    query of its tile chose (fast, and wrong) is this: the same kernel given
+    the tile's union as every query's choice. It must miss by far (measured
+    1.6 where outputs spread 0.22)."""
+    monkeypatch.setattr(sba, "_KEY_LANES", 32)
+    cfg = _config()
+    qg, k_pool, v_pool, ck_pool, positions = _draw(3, 1, 256, 512, 1, [256])
+    chosen = _chosen(qg, ck_pool, 0, positions, 32, cfg)
+    want = _oracle(qg, k_pool, v_pool, 0, positions, chosen, cfg)
+    attend = lambda c: sba.sparse_chunk_attend(  # noqa: E731
+        qg, k_pool, v_pool, c, 0, positions, block=BLOCK, scale=tfm.attention_scale(cfg), interpret=True)
+    assert float(jnp.abs(attend(chosen) - want).max()) < TOL
+    tq, _ = sba.chunk_geometry(256, 32, BLOCK)
+    union = jnp.repeat(chosen.reshape(1, KV, 256 // tq, tq, 32).any(3), tq, axis=2)
+    assert int(union.sum()) > 2 * int(chosen.sum())  # random weights: a tile's queries choose apart
+    assert float(jnp.abs(attend(union) - want).max()) > 0.1 > 1e4 * TOL
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_a_tiles_visits_hold_every_chosen_block_and_nothing_past_the_tile(seed):
+    """Property, over random chosen maps with the causal bound: the list a
+    program walks (i) holds every key tile in which some query of the tile
+    chose some block, once, ascending; (ii) holds no other: none in which no
+    query chose a block, so none that begins past the tile's last position."""
+    rng = np.random.default_rng(seed)
+    B, T, n_blocks = 2, 96, 48
+    tq, nb = (8, 16, 32)[seed % 3], (1, 2, 4)[seed % 3]
+    positions = rng.integers(0, n_blocks * BLOCK - T, (B, 1)) + np.arange(T)
+    own = positions // BLOCK
+    chosen = rng.random((B, KV, T, n_blocks)) < (0.02, 0.2)[seed % 2]
+    chosen |= np.arange(n_blocks) == own[:, None, :, None]          # a query's own block
+    chosen &= np.arange(n_blocks) <= own[:, None, :, None]          # none past it
+    tiles, count = (np.asarray(a) for a in sba.tile_visits(jnp.asarray(chosen), tq, nb))
+    assert tiles.shape == (B, KV, T // tq, n_blocks // nb) and count.shape == tiles.shape[:3]
+    for b, g, i in np.ndindex(*count.shape):
+        walked = tiles[b, g, i, :count[b, g, i]].tolist()
+        mine = chosen[b, g, i * tq:(i + 1) * tq]                    # [tq, n_blocks]
+        wanted = sorted({int(blk) // nb for blk in np.nonzero(mine.any(0))[0]})
+        assert walked == wanted
+        last = positions[b, (i + 1) * tq - 1]
+        assert all(tile * nb * BLOCK <= last for tile in walked)
+        assert sorted(tiles[b, g, i].tolist()) == list(range(n_blocks // nb))  # the rest: a permutation's tail
+
+
+def test_the_chunk_kernel_has_its_own_name_in_a_profile():
+    """``layer_metrics/sparse_block_attn_roofline`` sums every kernel whose
+    name holds the decode kernel's; the chunk kernel's must not."""
+    cfg = _config()
+    qg, k_pool, v_pool, ck_pool, positions = _draw(0, 1, 32, 64, 1, [32])
+    text = jax.jit(generate._sparse_prefill, static_argnums=6).lower(
+        qg, k_pool, v_pool, ck_pool, jnp.int32(0), positions, cfg).as_text(debug_info=True)
+    assert "sparse_chunk_attn" in text and "sparse_block_attn" not in text
+    assert "sparse_attend" in text and "sparse_index" in text
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e chip (the TPU's compiler, no chip attached); made inside
+    the fixture so that only the worker given this file loads the library."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_both_kernels_compile_for_the_chip_at_the_long_document_cells_widths(one_chip):
+    """What interpret mode cannot show: Mosaic takes the kernels at the real
+    widths (one staging row of 10 240 lanes, a 2 048-query chunk, 2 kv-heads x
+    16 heads of 128, blocks of 64; decode: 16 rows of 34 816 lanes, 64 blocks a
+    row). A compile, not a run."""
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    chunk = jax.jit(lambda q, k, v, c, at, pos: sba.sparse_chunk_attend(
+        q, k, v, c, at, pos, block=64, scale=128 ** -0.5))
+    text = chunk.lower(sds((1, 2048, 2, 16, 128), bf16), sds((3, 1, 10240, 256), bf16), sds((3, 1, 10240, 256), bf16),
+                       sds((1, 2, 2048, 160), jnp.bool_), sds((), i32), sds((1, 2048), i32)).compile().as_text()
+    assert "sparse_chunk_attn" in text and "tpu_custom_call" in text
+    step = jax.jit(lambda q, k, v, ids, at, pos: sba.sparse_block_attend(
+        q, k, v, ids, at, pos, block=64, scale=128 ** -0.5))
+    text = step.lower(sds((16, 2, 16, 128), bf16), sds((3, 16, 34816, 256), bf16), sds((3, 16, 34816, 256), bf16),
+                      sds((16, 2, 64), i32), sds((), i32), sds((16,), i32)).compile().as_text()
+    assert "sparse_block_attn" in text and "tpu_custom_call" in text

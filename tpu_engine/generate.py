@@ -520,8 +520,8 @@ def _lightning_block(x, lp, state, at, positions, valid, cfg: ModelConfig):
 # Block-sparse attention layers
 # ---------------------------------------------------------------------------
 
-# Queries a prefill chunk attends at a time (their float32 scores against a
-# whole row are the chunk's largest temporary).
+# Queries a prefill chunk's indexer scores at a time (float32, for every head
+# against every compressed key of the row).
 _SPARSE_QUERY_BLOCK = 128
 _FORCED = 1e30  # a forced block's score: above any sum of probabilities
 
@@ -619,35 +619,35 @@ def _sparse_decode(qg, k_pool, v_pool, ck_pool, at, positions, valid, cfg: Model
 
 
 def _sparse_prefill(qg, k_pool, v_pool, ck_pool, at, positions, cfg: ModelConfig):
-    """A chunk of queries (T > 1) against layer ``at``'s row(s): DENSE AND
-    MASKED — every lane's score is computed, ``_SPARSE_QUERY_BLOCK`` queries
-    at a time, and a query past ``sparse_dense_len`` keeps the lanes of its
-    chosen blocks only (one below it keeps every lane up to its own). What a
-    position attends is what :func:`_sparse_decode` would attend there; the
-    blocks not chosen are computed and thrown away. Returns [B,T,KV,G,HD]."""
+    """A chunk of queries (T > 1) against layer ``at``'s row(s): the indexer,
+    ``_SPARSE_QUERY_BLOCK`` queries at a time, says which blocks each query
+    attends — its chosen ones past ``sparse_dense_len``, every block up to its
+    own below — and one call of the chunk kernel
+    (``ops.sparse_block_attention.sparse_chunk_attend``) attends them where
+    they lie in the pool: what a position attends is what
+    :func:`_sparse_decode` would attend there. Returns [B,T,KV,G,HD]."""
     B, T, KV, G, HD = qg.shape
-    S, block = k_pool.shape[2], cfg.sparse_block_size
-    n_blocks, Tq = S // block, min(_SPARSE_QUERY_BLOCK, T)
-    k = layer_slice(k_pool, at).reshape(B, S, KV, HD)
-    v = layer_slice(v_pool, at).reshape(B, S, KV, HD)
+    block = cfg.sparse_block_size
+    n_blocks, Tq = k_pool.shape[2] // block, min(_SPARSE_QUERY_BLOCK, T)
     ck = layer_slice(ck_pool, at).reshape(B, -1, KV, HD)
-    scale = attention_scale(cfg)
+    blocks = jnp.arange(n_blocks)
 
-    def attend(xs):
+    def choose(xs):
         q, pos = xs                                               # [B,Tq,KV,G,HD], [B,Tq]
-        with jax.named_scope("sparse_index"):
-            ids = _select_blocks(q, ck, pos, n_blocks, cfg)       # [B,KV,Tq,topk]
-            chosen = jnp.any(ids[..., None] == jnp.arange(n_blocks), axis=-2)   # [B,KV,Tq,n_blocks]
-            chosen |= (pos < cfg.sparse_dense_len)[:, None, :, None]
-        with jax.named_scope("sparse_attend"):
-            s = jnp.einsum("btkgd,bmkd->bkgtm", q, k, preferred_element_type=jnp.float32) * scale
-            keep = jnp.repeat(chosen, block, axis=-1) & (jnp.arange(S) <= pos[:, None, :, None])
-            p = jax.nn.softmax(jnp.where(keep[:, :, None], s, _NEG_INF), axis=-1).astype(q.dtype)
-            return jnp.einsum("bkgtm,bmkd->btkgd", p, v)
+        ids = _select_blocks(q, ck, pos, n_blocks, cfg)           # [B,KV,Tq,topk]
+        chosen = jnp.any(ids[..., None] == blocks, axis=-2)       # [B,KV,Tq,n_blocks]
+        chosen |= (pos < cfg.sparse_dense_len)[:, None, :, None]
+        # top_k fills a short context's ids with blocks past the query's own: not chosen
+        return chosen & (blocks <= (pos // block)[:, None, :, None])
 
-    # the last query block's padding repeats its last query
-    out = lax.map(attend, (_time_blocks(qg, Tq, "edge"), _time_blocks(positions, Tq, "edge")))
-    return jnp.moveaxis(out, 0, 1).reshape(B, -1, KV, G, HD)[:, :T]       # [n,B,Tq,...] -> [B,T,...]
+    with jax.named_scope("sparse_index"):
+        # the last query block's padding repeats its last query
+        chosen = lax.map(choose, (_time_blocks(qg, Tq, "edge"), _time_blocks(positions, Tq, "edge")))
+        chosen = jnp.moveaxis(chosen, 0, 2).reshape(B, KV, -1, n_blocks)[:, :, :T]
+    with jax.named_scope("sparse_attend"):
+        return sparse_block_attention.sparse_chunk_attend(
+            qg, k_pool, v_pool, chosen, at, positions, block=block, scale=attention_scale(cfg),
+            interpret=sparse_block_attention.interpret_here())
 
 
 def _sparse_attn_block(x, lp, k_pool, v_pool, ck_pool, at, write, positions, valid,
@@ -661,7 +661,7 @@ def _sparse_attn_block(x, lp, k_pool, v_pool, ck_pool, at, write, positions, val
     lanes, the compressed keys of the windows that completed follow
     (:func:`_write_compressed_keys`), and the layer's lanes are only read —
     by one query per row through the chosen blocks (:func:`_sparse_decode`),
-    by a chunk dense and masked (:func:`_sparse_prefill`).
+    by a chunk through the key tiles its queries chose (:func:`_sparse_prefill`).
     Returns (x, k_pool, v_pool, ck_pool)."""
     B, T, _ = x.shape
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim

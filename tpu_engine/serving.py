@@ -861,6 +861,11 @@ class ContinuousBatcher:
         self._sparse_from = cfg.sparse_dense_len \
             if "sparse_attention" in cfg.layer_types else None
         self._decode_tokens_sparse = 0
+        # Prompt positions a prefill chunk computed (the bucket's padding
+        # among them), and those of them at or past ``sparse_dense_len``:
+        # how often block choice, not only the causal bound, engages.
+        self._prefill_tokens_computed = 0
+        self._prefill_tokens_sparse = 0
         # Recurrent state written into a slot by a finished prefill, and
         # zeroed when a slot is freed (hybrid stacks; else both stay 0).
         self._recurrent_state_bytes = self._cache.recurrent_state_bytes
@@ -1206,6 +1211,8 @@ class ContinuousBatcher:
                 "decode_tokens_computed_total": self._decode_tokens_computed,
                 "decode_tokens_emitted_total": self._decode_tokens_emitted,
                 "decode_tokens_sparse_total": self._decode_tokens_sparse,
+                "prefill_tokens_computed_total": self._prefill_tokens_computed,
+                "prefill_tokens_sparse_total": self._prefill_tokens_sparse,
                 # The pool's whole kinds of state (Mamba-2, lightning; 0
                 # for attention-only stacks): their bytes, every slot's
                 # whether in use or not, and how often a slot's was
@@ -1321,6 +1328,9 @@ class ContinuousBatcher:
         if st.dc1 is not None:  # speculative: the draft ingests the prompt too
             st.dc1 = self._draft_prefill_fn(self._draft_params, chunk, st.dc1)
         st.consumed = t1
+        self._prefill_tokens_computed += t1 - t0
+        if self._sparse_from is not None:
+            self._prefill_tokens_sparse += max(t1 - max(t0, self._sparse_from), 0)
         if self._prefix_cache is not None:
             # Insert ONLY at the walk's last cacheable boundary (largest
             # full chunk of REAL tokens within the budget): intermediate
