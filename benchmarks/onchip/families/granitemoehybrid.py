@@ -6,20 +6,28 @@ The recipe: RMSNorm, a stack whose ``layer_types`` name each layer ``mamba``
 scores scaled by ``attention_multiplier``), a dense SwiGLU MLP after either, a
 tied head, and the multipliers on embeddings, residual branches and logits.
 The program runs it as its ``llama`` architecture with a layer pattern. What
-the recipe cannot represent is refused, not dropped: experts (those are
-granite-4.0-h-small's), an untied head, a positional encoding, more than one
-B/C group, biases, a pattern that does not cover the depth.
+the recipe cannot represent is refused, not dropped: an untied head, a
+positional encoding, more than one B/C group, biases, a pattern that does not
+cover the depth (``fields``), and, of the block after the mixer, experts and a
+shared expert of another width (``model_config``: those are
+granite-4.0-h-small's).
+
+``fields(config, name)`` is everything but the block after the mixer, for a
+second recipe under this ``model_type`` to build on, as ``mixtral.py`` builds
+on ``mistral.fields``: such a family's configuration file states it under
+``family`` (``manifest.family_of``), and what its MLP is, is its own to map
+and to refuse.
 """
 
 from __future__ import annotations
 
 
-def model_config(config: dict, name: str):
-    from tpu_engine.models import transformer as tfm
-
-    if config.get("num_local_experts"):
-        raise ValueError(f"num_local_experts={config['num_local_experts']}: experts are not this recipe "
-                         "(granite-4.0-h-small has them)")
+def fields(config: dict, name: str) -> dict:
+    """The ``ModelConfig`` fields of the recipe's mixers, pattern, embedding,
+    head and multipliers. ``d_ff`` is ``intermediate_size`` whatever the MLP
+    is (an expert's width in a mixture, as Mixtral's ``d_ff`` is);
+    ``shared_intermediate_size``, ``num_local_experts`` and
+    ``num_experts_per_tok`` are not read: they are the caller's."""
     if not config.get("tie_word_embeddings"):
         raise ValueError("an untied head is not this family's recipe")
     if config.get("position_embedding_type") != "nope":
@@ -38,9 +46,7 @@ def model_config(config: dict, name: str):
     hidden, heads = config["hidden_size"], config["mamba_n_heads"]
     if heads * config["mamba_d_head"] != config["mamba_expand"] * hidden:
         raise ValueError("mamba_n_heads x mamba_d_head must be mamba_expand x hidden_size")
-    if config.get("shared_intermediate_size", config["intermediate_size"]) != config["intermediate_size"]:
-        raise ValueError("shared_intermediate_size differs from intermediate_size: one dense MLP is the recipe")
-    return tfm.ModelConfig(
+    return dict(
         name=name,
         arch="llama",
         vocab_size=config["vocab_size"],
@@ -65,3 +71,14 @@ def model_config(config: dict, name: str):
         rope=False,
         tie_head=True,
     )
+
+
+def model_config(config: dict, name: str):
+    from tpu_engine.models import transformer as tfm
+
+    if config.get("num_local_experts"):
+        raise ValueError(f"num_local_experts={config['num_local_experts']}: experts are not this recipe "
+                         "(granite-4.0-h-small has them)")
+    if config.get("shared_intermediate_size", config["intermediate_size"]) != config["intermediate_size"]:
+        raise ValueError("shared_intermediate_size differs from intermediate_size: one dense MLP is the recipe")
+    return tfm.ModelConfig(**fields(config, name))

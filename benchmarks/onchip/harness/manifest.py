@@ -1,8 +1,9 @@
 """BENCHMARK.json and the files it names. Nothing here knows a cell's name:
 a cell is found by the ``--workload`` argument, its configuration and traffic
 by the names the cell gives, a per-layer metric's reader by the metric's name,
-a configuration's family by the ``model_type`` its file publishes and its
-reference by the file's ``reference``.
+a configuration's family by ``family_of`` (the ``family`` its file states,
+else the ``model_type`` it publishes) and its reference by the file's
+``reference``.
 """
 
 from __future__ import annotations
@@ -18,11 +19,25 @@ NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 TRAFFIC_SUFFIXES = (".json", ".jsonl", ".toml", ".txt", ".csv")
 WIDTH_RE = re.compile(r"(_dim|_rank|hidden_size|intermediate_size|per_tok)$")
+FAMILY_RE = re.compile(r"^[A-Za-z0-9_\-]+$")
 
 
 def names_a_width(key: str) -> bool:
     """A key ``reduced`` may never name: depth and scale are cut, widths are not."""
     return bool(WIDTH_RE.search(key))
+
+
+def family_of(config: dict) -> str:
+    """The family of a configuration file, which names ``families/<it>.py``:
+    the file's ``family`` where it has the key, else the ``model_type`` it
+    publishes. ``family`` is a key of the benchmark's, beside ``reference``,
+    ``role``, ``program``, ``check`` and ``rehearsal``: a second recipe under a
+    ``model_type`` the benchmark already has states it, and the published key
+    stays as published."""
+    family = str(config.get("family", config.get("model_type")))
+    if not FAMILY_RE.match(family):
+        raise ValueError(f"family {family!r} is not a plain module name (letters, digits, _ and -)")
+    return family
 
 
 def load_manifest(root: str = ROOT) -> dict:
@@ -87,10 +102,14 @@ def load_reader(name: str):
     return mod.read
 
 
+def module_path(package_dir: str, name: str) -> str:
+    return os.path.join(BENCH_DIR, package_dir, name + ".py")
+
+
 def load_by_name(package_dir: str, name: str):
     """A module found by name in a directory of the benchmark (a generator, a
     reference, a family)."""
-    path = os.path.join(BENCH_DIR, package_dir, name + ".py")
+    path = module_path(package_dir, name)
     if not os.path.isfile(path):
         raise FileNotFoundError(f"{path} does not exist")
     pkg = package_dir.replace("/", ".")
@@ -128,9 +147,14 @@ def lint(manifest: dict, root: str = ROOT) -> list[str]:
         if not under(c["file"]) or not os.path.isfile(os.path.join(root, c["file"])):
             bad.append(f"config {c['name']}: file {c['file']}")
         else:
-            family = str(_read_json(os.path.join(root, c["file"])).get("model_type"))
-            if not os.path.isfile(os.path.join(BENCH_DIR, "families", family + ".py")):
-                bad.append(f"config {c['name']}: model_type {family!r} has no file under families/")
+            try:
+                family = family_of(_read_json(os.path.join(root, c["file"])))
+            except ValueError as e:
+                bad.append(f"config {c['name']}: {e}")
+            else:
+                path = module_path("families", family)
+                if not os.path.isfile(path):
+                    bad.append(f"config {c['name']}: family {family!r}: {path} does not exist")
         for k in c["reduced"]:
             if not NAME_RE.match(k) or names_a_width(k):
                 bad.append(f"config {c['name']}: reduced names a width: {k}")

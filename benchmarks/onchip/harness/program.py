@@ -3,8 +3,10 @@ TO ``tpu_engine`` is here, so a reader can see that it is only the system
 under test, its spans and its counters.
 
 - a configuration file becomes a ``ModelConfig`` registered under the
-  configuration's name, through the family file its ``model_type`` names
-  (``families/<model_type>.py``: published widths, the depth the file states);
+  configuration's name, through its family's file (``families/<family>.py``,
+  where the family is ``manifest.family_of``'s: the ``family`` the file states,
+  else the ``model_type`` it publishes: published widths, the depth the file
+  states);
 - the worker's start-up sequence, as ``chip_smoke.start_up`` runs it, with the
   compile cache pointed inside the checkout;
 - engine-side token and dispatch timestamps, after
@@ -20,7 +22,7 @@ import contextlib
 import os
 import time
 
-from .manifest import BENCH_DIR, load_by_name
+from .manifest import BENCH_DIR, family_of, load_by_name
 
 CACHE_DIR = os.path.join(BENCH_DIR, ".cache")
 MAX_SEED = 2**32 - 5
@@ -43,14 +45,14 @@ def prepare_environment() -> None:
 def model_config(config: dict, name: str):
     """The configuration file as a program ``ModelConfig``, registered under
     the configuration's name. Which published keys become which fields is the
-    family's business: ``families/<model_type>.py``, found by the file's own
-    published ``model_type``. Nothing here knows a family."""
+    family's business: ``families/<family>.py``, found by ``family_of``.
+    Nothing here knows a family."""
     from tpu_engine.models import transformer as tfm
 
-    family = load_by_name("families", config["model_type"])
-    mc = family.model_config(config, name)
+    family = family_of(config)
+    mc = load_by_name("families", family).model_config(config, name)
     if mc.name != name:
-        raise ValueError(f"families/{config['model_type']}.py named its ModelConfig {mc.name!r}, not {name!r}")
+        raise ValueError(f"families/{family}.py named its ModelConfig {mc.name!r}, not {name!r}")
     tfm.MODEL_CONFIGS[name] = mc
     return mc
 

@@ -3,8 +3,11 @@ the indexer's scores against the compressed keys a query sees, and attention
 over the lanes of its CHOSEN blocks only (below ``dense_len``: every lane up to
 itself) — over peak bf16 FLOP/s, against the traced device time of the PREFILL
 program's ops under ``sparse_index`` and ``sparse_attend``. A prefill that
-computes every lane's score and masks (``generate._sparse_prefill`` does) reads
-low here by as much as it computes in vain. A chunk's first position is its
+computes more than the chosen lanes reads low here by as much as it computes
+in vain: ``generate._sparse_prefill`` attends through the kernel
+``sparse_chunk_attn`` (since PR 32), which visits every key tile in which SOME
+query of a 128-query tile chose a block and masks per query, and with random
+weights that is every tile the queries can see. A chunk's first position is its
 index (``chunk=`` of its ``tpu_engine.batcher.prefill`` annotation) x the
 configured prefill chunk."""
 

@@ -147,7 +147,6 @@ def test_the_new_readers_on_a_synthetic_trace(monkeypatch, tmp_path):
     read = lambda name: manifest.load_reader(name)(run, name)  # noqa: E731
     assert read("ssm_time_pct.burst") == pytest.approx(100 * (72 + 45 + 9) / 150)
     assert read("attn_time_pct.burst") == pytest.approx(100 * 4 / 150)
-    assert read("weight_cast_time_pct.burst") == pytest.approx(100 * 20 / 150)
     bw, fl = 819e9, 197e12
     steps = 2 * 8
     assert read("ssm_update_roofline.burst") == pytest.approx(

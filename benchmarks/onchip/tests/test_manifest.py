@@ -52,7 +52,7 @@ def test_every_cell_loads_and_every_reader_exists(man):
             assert callable(manifest.load_reader(m["name"]))
         assert manifest.load_by_name("harness/generators", cell["traffic"]["generator"]).KIND in ("train", "serve")
         assert hasattr(manifest.load_by_name("reference", cell["config"]["reference"]), "init_params")
-        assert callable(manifest.load_by_name("families", cell["config"]["model_type"]).model_config)
+        assert callable(manifest.load_by_name("families", manifest.family_of(cell["config"])).model_config)
 
 
 def test_a_dummy_cell_is_files_and_entries_only(man, tmp_path, monkeypatch):

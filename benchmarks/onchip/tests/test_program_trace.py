@@ -97,7 +97,7 @@ def test_a_trace_without_program_names_reads_as_nothing():
     assert parsed["idle"]["unnamed_s"] == pytest.approx(parsed["idle"]["idle_s"]) == pytest.approx(0.030)
     assert set(parsed["scopes"]["by_scope"]) == {"_unknown"} and parsed["scopes"]["busy_s"] == pytest.approx(0.070)
     run = {"trace": {"busy_s": 0.07}, "cell": {"cell": {"name": "no-such-cell"}}}
-    for name in ("idle_named_pct.train", "attn_time_pct.chat", "weight_cast_time_pct.batch"):
+    for name in ("idle_named_pct.train", "attn_time_pct.chat", "attn_time_pct.batch"):
         assert manifest.load_reader(name)(run, name) is None  # no trace of that cell in this checkout
 
 
@@ -147,7 +147,7 @@ def test_trace_readers_read_the_cells_newest_traced_run(tmp_path, monkeypatch):
     run = {"trace": {"busy_s": 0.08}, "cell": {"cell": {"name": "cellA"}}}
     busy = 30 + 10 + 40
     assert manifest.load_reader("attn_time_pct.chat")(run, "attn_time_pct.chat") == pytest.approx(100 * 30 / busy)
-    assert manifest.load_reader("weight_cast_time_pct.batch")(run, "x") == pytest.approx(100 * 10 / busy)
+    assert manifest.load_reader("attn_time_pct.batch")(run, "x") == pytest.approx(100 * 30 / busy)
     # idle 40-60 ms: 15 in emit (40-55), 1 in idle (58-59), 3 in the loop's ``other``, 1 under no name
     assert manifest.load_reader("idle_named_pct.batch")(run, "x") == pytest.approx(100 * 16 / 20)
     assert manifest.load_reader("idle_named_pct.batch")({**run, "trace": None}, "x") is None  # an untraced run

@@ -62,7 +62,8 @@ def serve(cell, topo):
     p = cell["config"]["program"]
     one = SingleDeviceSharding(topo.devices[0])
     put = lambda t: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), t)  # noqa: E731
-    params = put(jax.eval_shape(lambda k: tfm.init_params(k, mc), jax.random.PRNGKey(0)))
+    # a replica is built at the dtype it computes in and holds its weights once (``build_replica_engine``)
+    params = put(jax.eval_shape(lambda k: tfm.init_params(k, mc, dtype=jnp.bfloat16), jax.random.PRNGKey(0)))
     cache = put(jax.eval_shape(lambda: serving.init_slot_cache(
         mc, p["max_slots"], p["max_len"], jnp.bfloat16, prefill_chunk=p["prefill_chunk"])))
     B = p["max_slots"]
