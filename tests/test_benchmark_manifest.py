@@ -69,7 +69,9 @@ def test_every_cell_loads_its_files_and_every_metric_has_a_reader():
 @pytest.mark.parametrize("name", CONFIGS)
 def test_a_configurations_family_and_reference_load(name):
     config = _config(name)
-    family = manifest.load_by_name("families", config["model_type"])
+    # the family the file states, else the model_type it publishes: with
+    # ``family`` stated, ``model_type`` names another recipe's file
+    family = manifest.load_by_name("families", manifest.family_of(config))
     reference = manifest.load_by_name("reference", config["reference"])
     assert callable(family.model_config)
     assert callable(reference.init_params) and callable(reference.served_logits)
@@ -88,6 +90,13 @@ def test_a_configuration_builds_at_its_rehearsal_size(name):
         assert tfm.param_count(mc) > 0
         full = program.model_config(config, name)  # the published widths build too (no array is made)
         assert full.d_model == config["hidden_size"] and full.vocab_size == config["vocab_size"]
+        if "num_local_experts" in config["reduced"]:
+            # One chip's share of the experts: the router keeps the published
+            # width, the tree holds what the file keeps, at both sizes.
+            for sized, cfg in ((full, config), (mc, small)):
+                assert sized.n_experts == cfg["published"]["num_local_experts"] > cfg["num_local_experts"]
+                assert sized.n_experts_held == cfg["num_local_experts"]
+                assert sized.top_k == cfg["num_experts_per_tok"] <= sized.n_experts
         if "layer_types" not in config and "mixer_types" not in config:
             # A configuration without a layer pattern is untouched by the fields a
             # pattern brought (PR 27): each stays at its default, so its programs

@@ -409,7 +409,7 @@ def test_what_assumes_keys_and_values_refuses_recurrent_layers_by_name(tiny, fea
     (dict(layer_types=("mamba", "attention")), "n_layers"),
     (dict(layer_types=("mamba", "attention", "linear", "mamba")), "n_layers"),
     (dict(sliding_window=16), "sliding window"),
-    (dict(n_experts=4), "dense MLP"),
+    (dict(n_experts=4, top_k=5), "top_k"),  # a mixture is admitted since PR 35; more experts a token than the router has is not
     (dict(ssm_groups=2), "group"),
     (dict(ssm_state=0), "ssm_heads"),
 ])

@@ -258,10 +258,17 @@ PARENT = {
         "prefill_row": [0.20951591432094574, -0.013773605227470398, -0.056673794984817505, -0.2290504276752472],
         "decode_row": [0.07227375358343124, 0.3225421607494354, -0.12000519037246704, -0.05271732062101364],
     },
+    # Re-recorded in PR 35, whose mixture contracts over expert and width
+    # together with the gates folded into the activations, where b04a573 wrote
+    # every expert's [B, T, E, D] and combined it: the same products, rounded
+    # to bfloat16 at another point. The tokens are b04a573's; its logits were
+    # 0.18816834688186646, -0.01070772111415863, -0.07360314577817917,
+    # -0.23313620686531067 (prefill) and 0.05203641951084137, 0.31955811381340027,
+    # -0.11569535732269287, -0.040707044303417206 (decode): within 3e-4 of these.
     "moe": {
         "tokens": [[98, 403, 233, 79, 308, 374, 309, 443], [497, 376, 380, 12, 92, 465, 314, 84]],
-        "prefill_row": [0.18816834688186646, -0.01070772111415863, -0.07360314577817917, -0.23313620686531067],
-        "decode_row": [0.05203641951084137, 0.31955811381340027, -0.11569535732269287, -0.040707044303417206],
+        "prefill_row": [0.1878596395254135, -0.010642990469932556, -0.0733594223856926, -0.23365341126918793],
+        "decode_row": [0.05209147185087204, 0.31985390186309814, -0.11561896651983261, -0.040776386857032776],
     },
     "hybrid": {
         "tokens": [[145] * 8, [503] * 8],

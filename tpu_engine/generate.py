@@ -18,11 +18,17 @@ training, and serving smoke tests. TPU-first design:
 
 MoE decode note: the training forward uses capacity-bounded dispatch
 (tokens over an expert's capacity are dropped — the standard static-shape
-formulation, ``_moe_mlp``). Decode processes a handful of positions, so it
-computes exact capacity-free top-k routing instead (every token reaches
-its chosen experts). Dense models produce bit-identical logits between
-:func:`forward` and prefill+decode; MoE models can differ wherever
-training-time dispatch dropped a token.
+formulation, ``_moe_mlp``). The cached walks compute exact capacity-free
+top-k routing instead (every token reaches its chosen experts): the router
+scores ALL ``n_experts``, and the experts this tree holds (all of them, or
+the share a hybrid's configuration names) are contracted with each token's
+gate folded into its activations, a token that did not choose an expert
+entering it with gate 0 (:func:`_moe_mlp_decode`). Dense models produce
+bit-identical logits between :func:`forward` and prefill+decode; MoE models
+can differ wherever training-time dispatch dropped a token.
+
+One block follows every kind of layer's mixer (:func:`_mlp_block`): a dense
+MLP, or that mixture with its shared expert beside it.
 """
 
 from __future__ import annotations
@@ -76,12 +82,17 @@ class KVCache:
     buffer wraps: writes go to ``position % slots`` and the attention mask
     reads ``pos``, so memory and per-step attention cost are O(window), not
     O(sequence). Non-ring caches keep the classic contract: the caller never
-    writes past ``slots`` positions total."""
+    writes past ``slots`` positions total.
+
+    ``moe_counts`` (a mixture's caches only; ``None`` else, and a leaf less):
+    what :func:`scan_layers` has counted of the router's choices since the
+    cache was made, :data:`MOE_COUNTS` int32."""
 
     layers: dict
     pos: jax.Array
     length: jax.Array
     ring: bool = field(default=False, metadata=dict(static=True))
+    moe_counts: Optional[jax.Array] = None
 
     @property
     def max_len(self) -> int:
@@ -129,24 +140,53 @@ def init_cache(
         pos=jnp.full((slots,), -1, jnp.int32),
         length=jnp.zeros((), jnp.int32),
         ring=slots < max_len,
+        moe_counts=init_moe_counts(cfg),
     )
 
 
-def _moe_mlp_decode(h, layer_params, cfg: ModelConfig):
-    """Exact top-k MoE for decode: every token reaches its chosen experts
-    (no capacity buffer — see module docstring). h: [B, T, D] → [B, T, D].
+# What a walk counts of a mixture's routing, summed over its layers (and a
+# caller's steps): token-expert assignments made at real positions, those of
+# them that fell on experts this tree holds, and held experts that some real
+# token chose (distinct per layer).
+MOE_COUNTS = ("assignments", "assignments_held", "experts_hit")
 
-    Computes all E expert MLPs for the T new positions and combines with
-    the renormalised top-k gates; for decode-sized T this is a handful of
-    [D, F] matmuls and keeps every shape static.
+
+def init_moe_counts(cfg: ModelConfig) -> Optional[jax.Array]:
+    """Zeroed :data:`MOE_COUNTS` for a cache of ``cfg``; None without experts."""
+    return jnp.zeros((len(MOE_COUNTS),), jnp.int32) if cfg.is_moe else None
+
+
+def _moe_mlp_decode(h, layer_params, cfg: ModelConfig, valid):
+    """Exact top-k mixture for the cached walks: every token reaches those of
+    its chosen experts that this tree holds (no capacity buffer — see module
+    docstring). h: [B, T, D] → ([B, T, D], :data:`MOE_COUNTS` of this layer).
+
+    The router scores all ``n_experts`` in float32 and keeps ``top_k`` of
+    them, gates renormalised to sum to 1 over the kept. The held experts
+    (``experts_first`` on, ``n_experts_held`` of them) run for the T new
+    positions, a token's gate (0 for an expert it did not choose) folded into
+    its activations, and one contraction over expert and width together
+    brings them down: nothing of ``[B, T, E, D]`` is written. What an absent
+    expert would have added is left out; a token none of whose experts is held
+    gets the shared expert alone. The shared expert (``shared_d_ff``) is a
+    SwiGLU of its own width for every token. ``valid`` [B, T] marks the real
+    positions, which alone are counted.
     """
-    E, K = cfg.n_experts, cfg.top_k
+    K, first, held = cfg.top_k, cfg.experts_first, cfg.n_experts_held
     with jax.named_scope("moe_router"):
         router_logits = jnp.einsum(
             "btd,de->bte", h, layer_params["router"]["kernel"],
             preferred_element_type=jnp.float32,
         )
         probs = jax.nn.softmax(router_logits, axis=-1)  # [B, T, E] fp32
+        # Top-k gates, renormalised to sum to 1 (matches training's combine).
+        top_vals, top_idx = lax.top_k(probs, K)  # [B, T, K]
+        top_vals = top_vals / jnp.maximum(jnp.sum(top_vals, -1, keepdims=True), 1e-9)
+        chosen = top_idx[..., None] == first + jnp.arange(held)  # [B, T, K, held]
+        weights = jnp.sum(jnp.where(chosen, top_vals[..., None], 0.0), axis=2)  # [B, T, held]
+        live = jnp.any(chosen, axis=2) & valid[..., None]
+        counts = jnp.stack([K * jnp.sum(valid), jnp.sum(live),
+                            jnp.sum(jnp.any(live, axis=(0, 1)))]).astype(jnp.int32)
 
     def kern(name):
         # Expert kernels may be int8 QuantWeights (weight-only quantized
@@ -163,21 +203,33 @@ def _moe_mlp_decode(h, layer_params, cfg: ModelConfig):
     with jax.named_scope("moe_experts"):
         gate = jnp.einsum("btd,edf->btef", h, kern("gate"))
         up = jnp.einsum("btd,edf->btef", h, kern("up"))
-        expert_out = jnp.einsum(
-            "btef,efd->bted", jax.nn.silu(gate) * up, kern("down")
-        )  # [B, T, E, D]
+        act = jax.nn.silu(gate) * up * weights[..., None].astype(h.dtype)
+        out = jnp.einsum("btef,efd->btd", act, kern("down"))
+    if cfg.shared_d_ff:
+        with jax.named_scope("moe_shared"):
+            shared = jax.nn.silu(_proj(h, layer_params["shared_gate"]["kernel"])) \
+                * _proj(h, layer_params["shared_up"]["kernel"])
+            out = out + _proj(shared, layer_params["shared_down"]["kernel"])
+    return out, counts
 
-    with jax.named_scope("moe_router"):
-        # Top-k gates, renormalised to sum to 1 (matches training's combine).
-        top_vals, top_idx = lax.top_k(probs, K)  # [B, T, K]
-        top_vals = top_vals / jnp.maximum(jnp.sum(top_vals, -1, keepdims=True), 1e-9)
-        weights = jnp.zeros_like(probs).at[
-            jnp.arange(probs.shape[0])[:, None, None],
-            jnp.arange(probs.shape[1])[None, :, None],
-            top_idx,
-        ].set(top_vals)  # [B, T, E]
-    with jax.named_scope("moe_experts"):
-        return jnp.einsum("bte,bted->btd", weights.astype(h.dtype), expert_out)
+
+def _mlp_block(x, layer_params, cfg: ModelConfig, valid=None, tally: Optional[list] = None):
+    """THE block after the mixer, for every kind of layer: ``x`` plus the
+    (residual-scaled) dense MLP of its norm, or a mixture's routed experts and
+    shared expert (:func:`_moe_mlp_decode`, under scope ``moe``). ``valid``
+    [B, T] marks the real positions (None: all). A mixture appends its
+    layer's :data:`MOE_COUNTS` to ``tally``, the list the caller that counts
+    hands in (:func:`scan_layers`; traced values, read in the same trace)."""
+    if not cfg.is_moe:
+        with jax.named_scope("mlp"):
+            return _residual(x, _dense_mlp(_norm(x, layer_params["mlp_norm"], cfg),
+                                           layer_params, cfg=cfg), cfg)
+    with jax.named_scope("moe"):
+        valid = jnp.ones(x.shape[:2], bool) if valid is None else valid
+        out, counts = _moe_mlp_decode(_norm(x, layer_params["mlp_norm"], cfg), layer_params, cfg, valid)
+        if tally is not None:
+            tally.append(counts)
+        return _residual(x, out, cfg)
 
 
 def _quantize_rows(rows: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -190,7 +242,8 @@ def _quantize_rows(rows: jax.Array) -> tuple[jax.Array, jax.Array]:
 
 
 def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
-                  cfg: ModelConfig, k_scale_c=None, v_scale_c=None, read=None):
+                  cfg: ModelConfig, k_scale_c=None, v_scale_c=None, read=None,
+                  valid=None, tally=None):
     """One transformer block attending against the cache as stored.
 
     Attention contracts the query heads, grouped by the KV head they share,
@@ -215,7 +268,7 @@ def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
     ``k_scale_c``/``v_scale_c`` (as ``k_cache``, trailing 1 instead of HD)
     are present for int8 caches: new rows are quantised before the write and
     the cache reads dequantise (the convert+mul fuses into the attention
-    dots).
+    dots). ``valid`` / ``tally`` are :func:`_mlp_block`'s.
     """
     B, T, D = x.shape
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -227,7 +280,8 @@ def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
                      bias=layer_params[name]["bias"] if gpt2 else None)
 
     # named_scope is metadata only: the names a profile groups device ops by
-    # (attn > kv_write / decode_attn, then mlp or moe_router / moe_experts).
+    # (attn > kv_write / decode_attn, then mlp or moe > moe_router / moe_experts
+    # / moe_shared).
     with jax.named_scope("attn"):
         h = _norm(x, layer_params["attn_norm"], cfg)
         q = proj(h, "q").reshape(B, T, H, HD)
@@ -279,12 +333,7 @@ def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
             attn = jnp.einsum("bkgtm,bmkd->btkgd", probs, vc).reshape(B, T, H * HD)
         x = _residual(x, proj(attn, "o"), cfg)
 
-    h = _norm(x, layer_params["mlp_norm"], cfg)
-    if cfg.is_moe:
-        x = _residual(x, _moe_mlp_decode(h, layer_params, cfg), cfg)
-    else:
-        with jax.named_scope("mlp"):
-            x = _residual(x, _dense_mlp(h, layer_params, cfg=cfg), cfg)
+    x = _mlp_block(x, layer_params, cfg, valid, tally)
     return x, k_cache, v_cache, k_scale_c, v_scale_c
 
 
@@ -437,13 +486,13 @@ def _ssm_mixer(u, lp, h, conv_state, valid, cfg: ModelConfig):
         return _proj(o, lp["out_proj"]["kernel"]), h, conv_state
 
 
-def _ssm_block(x, layer_params, ssm, conv, at, valid, cfg: ModelConfig):
+def _ssm_block(x, layer_params, ssm, conv, at, valid, cfg: ModelConfig, tally=None):
     """One Mamba-2 layer: the mixer where an attention layer attends, then
-    the MLP both kinds share. ``ssm`` / ``conv`` are the whole per-kind state
-    ([L_ssm, B, ...]); this layer reads and rewrites its own slice, ``at``, in
-    place, under the scope of the step that does it (``ssm_update`` for one
-    token, ``ssm_scan`` for a chunk), so that a profile charges the state's
-    traffic to the mixer. Returns (x, ssm, conv)."""
+    the block every kind shares (:func:`_mlp_block`). ``ssm`` / ``conv`` are
+    the whole per-kind state ([L_ssm, B, ...]); this layer reads and rewrites
+    its own slice, ``at``, in place, under the scope of the step that does it
+    (``ssm_update`` for one token, ``ssm_scan`` for a chunk), so that a profile
+    charges the state's traffic to the mixer. Returns (x, ssm, conv)."""
     with jax.named_scope("ssm"):
         u = _norm(x, layer_params["ssm_norm"], cfg)
         out, h, conv_state = _ssm_mixer(u, layer_params, layer_slice(ssm, at),
@@ -453,10 +502,7 @@ def _ssm_block(x, layer_params, ssm, conv, at, valid, cfg: ModelConfig):
         with jax.named_scope("ssm_conv"):
             conv = lax.dynamic_update_index_in_dim(conv, conv_state.astype(conv.dtype), at, 0)
         x = _residual(x, out, cfg)
-    with jax.named_scope("mlp"):
-        x = _residual(x, _dense_mlp(_norm(x, layer_params["mlp_norm"], cfg),
-                                    layer_params, cfg=cfg), cfg)
-    return x, ssm, conv
+    return _mlp_block(x, layer_params, cfg, valid, tally), ssm, conv
 
 
 # ---------------------------------------------------------------------------
@@ -464,8 +510,9 @@ def _ssm_block(x, layer_params, ssm, conv, at, valid, cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
-def _lightning_block(x, lp, state, at, positions, valid, cfg: ModelConfig):
-    """One lightning-attention layer, then the MLP every kind has.
+def _lightning_block(x, lp, state, at, positions, valid, cfg: ModelConfig, tally=None):
+    """One lightning-attention layer, then the block every kind has
+    (:func:`_mlp_block`).
 
     ``q_t = rope(norm(u_t Wq))``, ``k_t`` alike, ``v_t = u_t Wv`` per head;
     ``S_t = d S_{t-1} + k_t^T v_t``, ``o_t = q_t S_t / sqrt(E)``; the output
@@ -511,9 +558,7 @@ def _lightning_block(x, lp, state, at, positions, valid, cfg: ModelConfig):
             o = (o * lp["out_norm"]["scale"].astype(f32) * gate).astype(x.dtype)
         with jax.named_scope("lightning_out_proj"):
             x = _residual(x, _proj(o, lp["o"]["kernel"]), cfg)
-    with jax.named_scope("mlp"):
-        x = _residual(x, _dense_mlp(_norm(x, lp["mlp_norm"], cfg), lp, cfg=cfg), cfg)
-    return x, state
+    return _mlp_block(x, lp, cfg, valid, tally), state
 
 
 # ---------------------------------------------------------------------------
@@ -651,8 +696,9 @@ def _sparse_prefill(qg, k_pool, v_pool, ck_pool, at, positions, cfg: ModelConfig
 
 
 def _sparse_attn_block(x, lp, k_pool, v_pool, ck_pool, at, write, positions, valid,
-                       cfg: ModelConfig):
-    """One block-sparse attention layer, then the MLP every kind has.
+                       cfg: ModelConfig, tally=None):
+    """One block-sparse attention layer, then the block every kind has
+    (:func:`_mlp_block`).
 
     q and k are normed per head and NOT rotated; the attention's output is
     gated by ``sigmoid(u Wgate)`` before ``Wo``. The kind's three leaves are
@@ -688,9 +734,7 @@ def _sparse_attn_block(x, lp, k_pool, v_pool, ck_pool, at, write, positions, val
             attn = _sparse_prefill(qg, k_pool, v_pool, ck_pool, at, positions, cfg)
         attn = (attn.reshape(B, T, H * HD).astype(jnp.float32) * gate).astype(x.dtype)
         x = _residual(x, _proj(attn, lp["o"]["kernel"]), cfg)
-    with jax.named_scope("mlp"):
-        x = _residual(x, _dense_mlp(_norm(x, lp["mlp_norm"], cfg), lp, cfg=cfg), cfg)
-    return x, k_pool, v_pool, ck_pool
+    return _mlp_block(x, lp, cfg, valid, tally), k_pool, v_pool, ck_pool
 
 
 def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
@@ -723,49 +767,56 @@ def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
       kind's stack at ``first + i`` (for a run that is a whole stack that is
       what scanning it as ``xs`` lowers to).
 
-    A kind's layer function takes ``(x, lp, at, leaves)``, the kind's leaves
-    whole, and returns ``(x, leaves)``. ``valid`` [B, T] marks the real
-    positions for the recurrent layers (which have no mask); attention-only
-    callers may leave it out. Returns ``(x, cache)`` with ``cache.layers``
+    A kind's layer function takes ``(x, lp, at, leaves, tally)``, the kind's
+    leaves whole, and returns ``(x, leaves)``. ``valid`` [B, T] marks the real
+    positions for the recurrent layers (which have no mask) and for a
+    mixture's counts; attention-only callers may leave it out. A cache that
+    carries ``moe_counts`` has each mixture layer's :data:`MOE_COUNTS` added
+    to it (carried beside ``x``; a cache without counts none and nothing
+    more). Returns ``(x, cache)`` with ``cache.layers`` (and the counts)
     replaced."""
     require_served_format(stacks, x.dtype)
     stacks = stacks if cfg.is_hybrid else {"attn": stacks}
 
-    def attn_layer(x, lp, at, s):
+    def attn_layer(x, lp, at, s, tally):
         x, k, v, k_scale, v_scale = _decode_block(
             x, lp, s["k"], s["v"], lambda arr, rows: write(arr, rows, at), slot_pos,
             positions, cfg, k_scale_c=s.get("k_scale"), v_scale_c=s.get("v_scale"),
-            read=lambda arr: layer_slice(arr, at))
+            read=lambda arr: layer_slice(arr, at), valid=valid, tally=tally)
         new = {"k": k, "v": v, "k_scale": k_scale, "v_scale": v_scale}
         return x, {name: new[name] for name in s}  # scales only where they came in
 
-    def ssm_layer(x, lp, at, s):
-        x, ssm, conv = _ssm_block(x, lp, s["ssm"], s["conv"], at, valid, cfg)
+    def ssm_layer(x, lp, at, s, tally):
+        x, ssm, conv = _ssm_block(x, lp, s["ssm"], s["conv"], at, valid, cfg, tally)
         return x, {"ssm": ssm, "conv": conv}
 
-    def sparse_attn_layer(x, lp, at, s):
+    def sparse_attn_layer(x, lp, at, s, tally):
         x, k, v, ck = _sparse_attn_block(x, lp, s["k"], s["v"], s["ck"], at, write,
-                                         positions, valid, cfg)
+                                         positions, valid, cfg, tally)
         return x, {"k": k, "v": v, "ck": ck}
 
-    def lightning_layer(x, lp, at, s):
-        x, state = _lightning_block(x, lp, s["state"], at, positions, valid, cfg)
+    def lightning_layer(x, lp, at, s, tally):
+        x, state = _lightning_block(x, lp, s["state"], at, positions, valid, cfg, tally)
         return x, {"state": state}
 
     layer_fns = {"attn": attn_layer, "ssm": ssm_layer,
                  "sparse_attn": sparse_attn_layer, "lightning": lightning_layer}
-    state = cache.layers
+    state, counts = cache.layers, cache.moe_counts
     for kind, first, count in cfg.layer_runs():
 
         def body(carry, i, kind=kind, first=first):
-            x, state = carry
+            x, state, counts = carry
             at = first + i
             lp = jax.tree.map(lambda a: layer_slice(a, at), stacks[kind])
-            x, leaves = layer_fns[kind](x, lp, at, state[kind])
-            return (x, {**state, kind: leaves}), None
+            tally = None if counts is None else []
+            x, leaves = layer_fns[kind](x, lp, at, state[kind], tally)
+            if tally:
+                counts = counts + sum(tally)
+            return (x, {**state, kind: leaves}, counts), None
 
-        (x, state), _ = lax.scan(body, (x, state), jnp.arange(count, dtype=jnp.int32))
-    return x, dataclasses.replace(cache, layers=state)
+        (x, state, counts), _ = lax.scan(body, (x, state, counts),
+                                         jnp.arange(count, dtype=jnp.int32))
+    return x, dataclasses.replace(cache, layers=state, moe_counts=counts)
 
 
 def forward_with_cache(

@@ -90,6 +90,17 @@ class ModelConfig:
     #   Single-shard experts only (ragged_dot is not GSPMD-partitionable
     #   over the expert dim; validated at build).
     moe_impl: str = "dense"
+    # Which of the ``n_experts`` THIS tree holds, for a chip that is one of
+    # several sharing each layer's experts: ``experts_held`` of them from
+    # index ``experts_first`` on (0 held = all). The router keeps its width
+    # ``n_experts`` and its ``top_k``; only the terms of held experts are
+    # computed, and what the absent ones would have added is left out.
+    # ``shared_d_ff``: the width of a shared expert, a SwiGLU every token
+    # takes beside its routed ones (0 = none). Both are a hybrid stack's,
+    # served only (:func:`check_hybrid`).
+    experts_first: int = 0
+    experts_held: int = 0
+    shared_d_ff: int = 0
     # MXU int8 quantized training (tpu_engine/quant_train.py): "none" or
     # "int8". Routes the listed matmul groups through the channel-scaled
     # int8 einsum primitive — "attn" (Q/K/V/O projections), "mlp" (dense
@@ -106,11 +117,12 @@ class ModelConfig:
     # "attention", "mamba" (a Mamba-2 mixer in the attention's place),
     # "lightning" (linear attention with a fixed per-head decay) or
     # "sparse_attention" (block-sparse attention that chooses the blocks it
-    # reads); the MLP follows every kind. Empty = every layer attends. A
-    # pattern with any other entry than "attention" is a HYBRID stack:
-    # parameters are stacked per kind, the stack is scanned by runs of like
-    # layers (:meth:`layer_runs`), and it is served only (llama recipe,
-    # dense MLP, no window; see :func:`check_hybrid`).
+    # reads); the block after the mixer (a dense MLP, or with ``n_experts`` a
+    # mixture and its shared expert) follows every kind. Empty = every layer
+    # attends. A pattern with any other entry than "attention" is a HYBRID
+    # stack: parameters are stacked per kind, the stack is scanned by runs of
+    # like layers (:meth:`layer_runs`), and it is served only (llama recipe,
+    # no window; see :func:`check_hybrid`).
     layer_types: tuple = ()
     # The PUBLISHED index of each kept layer and the published depth: a
     # lightning layer's decay depends on where the model has it, whatever
@@ -227,6 +239,10 @@ class ModelConfig:
     def is_moe(self) -> bool:
         return self.n_experts > 0
 
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
+
     def expert_capacity(self, seq_len: int) -> int:
         """Tokens each expert accepts per sequence (static)."""
         cap = int(self.capacity_factor * self.top_k * seq_len / self.n_experts)
@@ -266,6 +282,16 @@ def refuse_recurrent(cfg: "ModelConfig", feature: str) -> None:
         raise RecurrentLayersUnsupported(feature, cfg)
 
 
+def refuse_hybrid_mixture(cfg: "ModelConfig", feature: str) -> None:
+    """A mixture of experts after a hybrid stack's mixers is served only,
+    whether or not its mixers keep a whole state (:func:`refuse_recurrent`
+    speaks first where they do)."""
+    if cfg.is_hybrid and cfg.is_moe:
+        raise NotImplementedError(
+            f"{feature} does not support model {cfg.name!r}: a mixture of experts after "
+            "a hybrid stack's mixers is served only")
+
+
 def refuse_recurrent_model(model_name: str, feature: str) -> None:
     """:func:`refuse_recurrent` for a registered model's name (fleet-level
     planes know a spec's ``model_name``, not its config); an unknown name
@@ -278,6 +304,16 @@ def refuse_recurrent_model(model_name: str, feature: str) -> None:
 def check_hybrid(cfg: "ModelConfig") -> None:
     """What a hybrid pattern can be today, checked where parameters or a
     cache are built (trace time, free)."""
+    if cfg.experts_first or cfg.experts_held or cfg.shared_d_ff:
+        if not (cfg.is_hybrid and cfg.is_moe):
+            raise ValueError(
+                "a share of the experts (experts_first, experts_held) and a shared expert "
+                "(shared_d_ff) are a hybrid mixture's: they need layer_types and n_experts "
+                f"(n_experts={cfg.n_experts}, layer_types={cfg.layer_types!r})")
+        if cfg.experts_first < 0 or cfg.experts_first + cfg.n_experts_held > cfg.n_experts:
+            raise ValueError(
+                f"experts {cfg.experts_first}..{cfg.experts_first + cfg.n_experts_held - 1} "
+                f"are not among the router's n_experts={cfg.n_experts}")
     if not cfg.layer_types:
         return
     if len(cfg.layer_types) != cfg.n_layers or \
@@ -288,12 +324,13 @@ def check_hybrid(cfg: "ModelConfig") -> None:
         )
     if not cfg.is_hybrid:
         return
-    if cfg.arch != "llama" or cfg.is_moe or cfg.sliding_window:
+    if cfg.arch != "llama" or cfg.sliding_window:
         raise ValueError(
-            "a hybrid layer pattern needs the llama recipe with a dense MLP "
-            f"and no sliding window (arch={cfg.arch!r}, "
-            f"n_experts={cfg.n_experts}, sliding_window={cfg.sliding_window})"
+            "a hybrid layer pattern needs the llama recipe and no sliding "
+            f"window (arch={cfg.arch!r}, sliding_window={cfg.sliding_window})"
         )
+    if cfg.is_moe and not 1 <= cfg.top_k <= cfg.n_experts:
+        raise ValueError(f"top_k={cfg.top_k} experts a token of n_experts={cfg.n_experts}")
     depth = cfg.published_layers or cfg.n_layers
     if cfg.layer_indices and (len(cfg.layer_indices) != cfg.n_layers
                               or not all(0 <= i < depth for i in cfg.layer_indices)):
@@ -529,7 +566,8 @@ def _init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype, deferred: bool 
     layers, ``layers["sparse_attn"]`` and ``layers["lightning"]`` the
     block-sparse and the lightning attention layers
     (:func:`_init_sparse_attn_stack`, :func:`_init_lightning_stack`), each
-    with its own MLP, in the order the pattern meets them.
+    with its own block after the mixer (:func:`_mixer_mlp_stack`), in the
+    order the pattern meets them.
 
     Projection kernels as every family (normal(0.02), outputs / sqrt(2 L));
     the embedding is drawn ``embed_scale`` times smaller, so that the scaled
@@ -538,9 +576,15 @@ def _init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype, deferred: bool 
     published ranges, so that a state really outlives a chunk: ``A_log =
     log U(1, 16)``, ``dt_bias = softplus^-1(dt)`` with ``dt`` log-uniform in
     [1e-3, 1e-1], ``D = 1``, convolution taps ``U(+-1/sqrt(taps))`` and zero
-    bias. The small recurrence leaves stay float32 whatever ``dtype`` is."""
+    bias. The small recurrence leaves stay float32 whatever ``dtype`` is.
+
+    A dense hybrid's attention and Mamba-2 kernels are each one draw of the
+    whole stack (keys 1-7 and 8-15 of ``split(rng, 16)``). With a mixture
+    after the mixers every kernel's layer i comes from ``split(key, n)[i]``
+    alone (:func:`_draw_layers`; the mixture's own keys are
+    :func:`_mixer_mlp_stack`'s), so that a reader holds one layer at a time."""
     ks = jax.random.split(rng, 16)
-    L, D, V, F = cfg.n_layers, cfg.d_model, cfg.vocab_size, cfg.d_ff
+    L, D, V = cfg.n_layers, cfg.d_model, cfg.vocab_size
     La, Ls = cfg.n_attn_layers, cfg.n_ssm_layers
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     SH, I, C, K = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_dim, cfg.ssm_conv
@@ -550,36 +594,42 @@ def _init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype, deferred: bool 
     def norm(key, shape, s):
         return _drawn(deferred, lambda: _scaled(jax.random.normal(key, shape, jnp.float32), s, dtype))
 
-    attn = lambda: {  # noqa: E731
-        "attn_norm": {"scale": jnp.ones((La, D), dtype)},
-        "q": {"kernel": norm(ks[1], (La, D, H * HD), std)},
-        "k": {"kernel": norm(ks[2], (La, D, KV * HD), std)},
-        "v": {"kernel": norm(ks[3], (La, D, KV * HD), std)},
-        "o": {"kernel": norm(ks[4], (La, H * HD, D), res_std)},
-        "mlp_norm": {"scale": jnp.ones((La, D), dtype)},
-        "gate": {"kernel": norm(ks[5], (La, D, F), std)},
-        "up": {"kernel": norm(ks[6], (La, D, F), std)},
-        "down": {"kernel": norm(ks[7], (La, F, D), res_std)},
-    }
-    dt = jnp.exp(jax.random.uniform(ks[11], (Ls, SH), jnp.float32,
-                                    jnp.log(1e-3), jnp.log(1e-1)))
-    bound = 1.0 / K ** 0.5
-    ssm = lambda: {  # noqa: E731
-        "ssm_norm": {"scale": jnp.ones((Ls, D), dtype)},
-        "in_proj": {"kernel": norm(ks[8], (Ls, D, I + C + SH), std)},
-        "conv": {"kernel": jax.random.uniform(ks[9], (Ls, K, C), jnp.float32,
-                                              -bound, bound).astype(dtype),
-                 "bias": jnp.zeros((Ls, C), dtype)},
-        "A_log": jnp.log(jax.random.uniform(ks[10], (Ls, SH), jnp.float32, 1.0, 16.0)),
-        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1
-        "D": jnp.ones((Ls, SH), jnp.float32),
-        "gate_norm": {"scale": jnp.ones((Ls, I), dtype)},
-        "out_proj": {"kernel": norm(ks[12], (Ls, I, D), res_std)},
-        "mlp_norm": {"scale": jnp.ones((Ls, D), dtype)},
-        "gate": {"kernel": norm(ks[13], (Ls, D, F), std)},
-        "up": {"kernel": norm(ks[14], (Ls, D, F), std)},
-        "down": {"kernel": norm(ks[15], (Ls, F, D), res_std)},
-    }
+    def stack_draw(n):
+        """``draw(key, s, shape=)`` of ``n`` stacked layers of a kernel."""
+        if cfg.is_moe:
+            return partial(_drawn, deferred, _draw_layers, n=n, dtype=dtype)
+        return lambda key, s, shape: norm(key, (n, *shape), s)
+
+    def attn():
+        draw = stack_draw(La)
+        return {
+            "attn_norm": {"scale": jnp.ones((La, D), dtype)},
+            "q": {"kernel": draw(ks[1], std, shape=(D, H * HD))},
+            "k": {"kernel": draw(ks[2], std, shape=(D, KV * HD))},
+            "v": {"kernel": draw(ks[3], std, shape=(D, KV * HD))},
+            "o": {"kernel": draw(ks[4], res_std, shape=(H * HD, D))},
+            **_mixer_mlp_stack(draw, ks[5:8], La, cfg, dtype, deferred),
+        }
+
+    def ssm():
+        draw = stack_draw(Ls)
+        dt = jnp.exp(jax.random.uniform(ks[11], (Ls, SH), jnp.float32,
+                                        jnp.log(1e-3), jnp.log(1e-1)))
+        bound = 1.0 / K ** 0.5
+        return {
+            "ssm_norm": {"scale": jnp.ones((Ls, D), dtype)},
+            "in_proj": {"kernel": draw(ks[8], std, shape=(D, I + C + SH))},
+            "conv": {"kernel": jax.random.uniform(ks[9], (Ls, K, C), jnp.float32,
+                                                  -bound, bound).astype(dtype),
+                     "bias": jnp.zeros((Ls, C), dtype)},
+            "A_log": jnp.log(jax.random.uniform(ks[10], (Ls, SH), jnp.float32, 1.0, 16.0)),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1
+            "D": jnp.ones((Ls, SH), jnp.float32),
+            "gate_norm": {"scale": jnp.ones((Ls, I), dtype)},
+            "out_proj": {"kernel": draw(ks[12], res_std, shape=(I, D))},
+            **_mixer_mlp_stack(draw, ks[13:16], Ls, cfg, dtype, deferred),
+        }
+
     stacks = {"attn": attn, "ssm": ssm,
               "sparse_attn": partial(_init_sparse_attn_stack, rng, cfg, dtype, deferred),
               "lightning": partial(_init_lightning_stack, rng, cfg, dtype, deferred)}
@@ -604,17 +654,56 @@ def _draw_layers(key, s, *, n: int, shape: tuple, dtype):
     return (jax.vmap(draw)(jax.random.split(key, n)) * s).astype(dtype)
 
 
-def _mixer_mlp_stack(draw, keys, n: int, cfg: ModelConfig, dtype) -> dict:
-    """What every kind of layer has after its mixer: the norm and the SwiGLU
-    MLP, ``n`` layers stacked (keys: gate, up, down; ``draw`` as its caller's)."""
+@partial(jax.jit, static_argnames=("n", "n_experts", "first", "held", "shape", "dtype"))
+def _draw_experts(key, s, *, n: int, n_experts: int, first: int, held: int, shape: tuple, dtype):
+    """``[n, held, *shape]``: the kernels of experts ``first .. first + held -
+    1`` of ``n`` layers. Expert e of layer i is drawn from ``split(split(key,
+    n)[i], n_experts)[e]`` alone: a share of the experts holds exactly the
+    uncut model's, and an absent expert is never drawn. One layer at a time
+    (``lax.map``), so that a narrower ``dtype`` never has more than one
+    layer's float32 beside it."""
+    draw = lambda k: jax.random.normal(k, shape, jnp.float32)  # noqa: E731
+
+    def layer(k):
+        return (jax.vmap(draw)(jax.random.split(k, n_experts)[first:first + held]) * s).astype(dtype)
+
+    return lax.map(layer, jax.random.split(key, n))
+
+
+def _mixer_mlp_stack(draw, keys, n: int, cfg: ModelConfig, dtype, deferred: bool = False) -> dict:
+    """What every kind of layer has after its mixer, ``n`` layers stacked
+    (keys: gate, up, down; ``draw`` as its caller's): the norm and the SwiGLU
+    MLP, or with ``cfg.n_experts`` the mixture — ``router`` ``[n, D, E]``
+    over ALL the experts, the HELD experts' ``gate`` / ``up`` ``[n, held, D,
+    F]`` and ``down`` ``[n, held, F, D]`` (:func:`_draw_experts`, from the
+    same three keys), and where ``cfg.shared_d_ff`` the shared expert's
+    ``shared_gate`` / ``shared_up`` / ``shared_down``. The router's key is
+    ``fold_in(keys[0], 1)``, the shared expert's ``fold_in(keys[j], 2)``."""
     D, F = cfg.d_model, cfg.d_ff
     res_std = 0.02 / (2 * cfg.n_layers) ** 0.5
-    return {
-        "mlp_norm": {"scale": jnp.ones((n, D), dtype)},
-        "gate": {"kernel": draw(keys[0], 0.02, shape=(D, F))},
-        "up": {"kernel": draw(keys[1], 0.02, shape=(D, F))},
-        "down": {"kernel": draw(keys[2], res_std, shape=(F, D))},
-    }
+    out = {"mlp_norm": {"scale": jnp.ones((n, D), dtype)}}
+    if not cfg.is_moe:
+        return {**out,
+                "gate": {"kernel": draw(keys[0], 0.02, shape=(D, F))},
+                "up": {"kernel": draw(keys[1], 0.02, shape=(D, F))},
+                "down": {"kernel": draw(keys[2], res_std, shape=(F, D))}}
+    experts = partial(_drawn, deferred, _draw_experts, n=n, n_experts=cfg.n_experts,
+                      first=cfg.experts_first, held=cfg.n_experts_held, dtype=dtype)
+    out.update({
+        "router": {"kernel": draw(jax.random.fold_in(keys[0], 1), 0.02, shape=(D, cfg.n_experts))},
+        "gate": {"kernel": experts(keys[0], 0.02, shape=(D, F))},
+        "up": {"kernel": experts(keys[1], 0.02, shape=(D, F))},
+        "down": {"kernel": experts(keys[2], res_std, shape=(F, D))},
+    })
+    if cfg.shared_d_ff:
+        S = cfg.shared_d_ff
+        shared = [jax.random.fold_in(k, 2) for k in keys[:3]]
+        out.update({
+            "shared_gate": {"kernel": draw(shared[0], 0.02, shape=(D, S))},
+            "shared_up": {"kernel": draw(shared[1], 0.02, shape=(D, S))},
+            "shared_down": {"kernel": draw(shared[2], res_std, shape=(S, D))},
+        })
+    return out
 
 
 def _init_sparse_attn_stack(rng, cfg: ModelConfig, dtype, deferred: bool = False) -> dict:
@@ -636,7 +725,7 @@ def _init_sparse_attn_stack(rng, cfg: ModelConfig, dtype, deferred: bool = False
         "o": {"kernel": draw(ks[4], res_std, shape=(H * HD, D))},
         "q_norm": {"scale": jnp.ones((n, HD), dtype)},
         "k_norm": {"scale": jnp.ones((n, HD), dtype)},
-        **_mixer_mlp_stack(draw, ks[5:], n, cfg, dtype),
+        **_mixer_mlp_stack(draw, ks[5:], n, cfg, dtype, deferred),
     }
 
 
@@ -674,7 +763,7 @@ def _init_lightning_stack(rng, cfg: ModelConfig, dtype, deferred: bool = False) 
         "k_norm": {"scale": jnp.ones((n, HD), dtype)},
         "out_norm": {"scale": jnp.ones((n, I), dtype)},
         "decay": lightning_decay_rates(cfg),
-        **_mixer_mlp_stack(draw, ks[5:], n, cfg, dtype),
+        **_mixer_mlp_stack(draw, ks[5:], n, cfg, dtype, deferred),
     }
 
 
@@ -686,15 +775,31 @@ SSM_FLOAT32_LEAVES = ("A_log", "dt_bias", "D")
 FLOAT32_LEAVES = SSM_FLOAT32_LEAVES + ("decay",)
 
 
+def _mlp_axes(cfg: ModelConfig) -> dict[str, Any]:
+    """Logical axes of the block after the mixer (:func:`_mixer_mlp_stack`'s
+    leaves, and the uniform llama stack's): the norm and a dense MLP, or a
+    mixture's router, experts and shared expert."""
+    dense = {"gate": {"kernel": ("layers", "embed", "mlp")},
+             "up": {"kernel": ("layers", "embed", "mlp")},
+             "down": {"kernel": ("layers", "mlp", "embed")}}
+    out: dict[str, Any] = {"mlp_norm": {"scale": ("layers", "embed")}}
+    if not cfg.is_moe:
+        return {**out, **dense}
+    out.update({
+        "router": {"kernel": ("layers", "embed", None)},
+        "gate": {"kernel": ("layers", "expert", "embed", "mlp")},
+        "up": {"kernel": ("layers", "expert", "embed", "mlp")},
+        "down": {"kernel": ("layers", "expert", "mlp", "embed")},
+    })
+    if cfg.shared_d_ff:
+        out.update({"shared_" + name: axes for name, axes in dense.items()})
+    return out
+
+
 def logical_axes(cfg: ModelConfig) -> dict[str, Any]:
     """Logical-axis tree matching :func:`init_params`' structure exactly."""
     if cfg.is_hybrid:
-        mlp_axes = {
-            "mlp_norm": {"scale": ("layers", "embed")},
-            "gate": {"kernel": ("layers", "embed", "mlp")},
-            "up": {"kernel": ("layers", "embed", "mlp")},
-            "down": {"kernel": ("layers", "mlp", "embed")},
-        }
+        mlp_axes = _mlp_axes(cfg)
         mixer_axes = {
             "attn_norm": {"scale": ("layers", "embed")},
             "q": {"kernel": ("layers", "embed", "heads")},
@@ -779,20 +884,11 @@ def logical_axes(cfg: ModelConfig) -> dict[str, Any]:
         "k": {"kernel": ("layers", "embed", "kv_heads")},
         "v": {"kernel": ("layers", "embed", "kv_heads")},
         "o": {"kernel": ("layers", "heads", "embed")},
-        "mlp_norm": {"scale": ("layers", "embed")},
+        **_mlp_axes(cfg),
     }
     if cfg.arch == "qwen":
         layers["q_norm"] = {"scale": ("layers", None)}
         layers["k_norm"] = {"scale": ("layers", None)}
-    if cfg.is_moe:
-        layers["router"] = {"kernel": ("layers", "embed", None)}
-        layers["gate"] = {"kernel": ("layers", "expert", "embed", "mlp")}
-        layers["up"] = {"kernel": ("layers", "expert", "embed", "mlp")}
-        layers["down"] = {"kernel": ("layers", "expert", "mlp", "embed")}
-    else:
-        layers["gate"] = {"kernel": ("layers", "embed", "mlp")}
-        layers["up"] = {"kernel": ("layers", "embed", "mlp")}
-        layers["down"] = {"kernel": ("layers", "mlp", "embed")}
     out = {
         "embed": {"embedding": ("vocab", "embed")},
         "layers": layers,
@@ -811,7 +907,9 @@ def param_count(cfg: ModelConfig) -> int:
         mlp = 2 * D * F + F + D   # fc/proj kernels + biases
         per_layer = attn + mlp + 4 * D  # two LayerNorms (scale + bias)
         return V * D + cfg.max_seq_len * D + L * per_layer + 2 * D  # tied head
-    mlp = 3 * D * F * (cfg.n_experts if cfg.is_moe else 1)
+    # A mixture counts the experts this tree HOLDS (a share of them in a
+    # hybrid that says so), the router over all of them, the shared expert.
+    mlp = 3 * D * F * (cfg.n_experts_held if cfg.is_moe else 1) + 3 * D * cfg.shared_d_ff
     router = D * cfg.n_experts if cfg.is_moe else 0
     per_layer = D * H * HD + 2 * D * KV * HD + H * HD * D + mlp + router + 2 * D
     if cfg.arch == "qwen":
@@ -819,6 +917,7 @@ def param_count(cfg: ModelConfig) -> int:
     head = 0 if cfg.tied_head else D * V
     if cfg.is_hybrid:
         I, C, SH = cfg.ssm_inner, cfg.ssm_conv_dim, cfg.ssm_heads
+        mlp += router  # the block after every kind of mixer, a mixture's router in it
         # in_proj (z | xBC | dt), conv taps + bias, A_log / dt_bias / D,
         # the gate's norm, out_proj; then the layer's two norms and MLP.
         per_ssm = (D * (I + C + SH) + (cfg.ssm_conv + 1) * C + 3 * SH + I
@@ -840,7 +939,7 @@ def active_param_count(cfg: ModelConfig) -> int:
     if not cfg.is_moe:
         return param_count(cfg)
     L, D, F = cfg.n_layers, cfg.d_model, cfg.d_ff
-    inactive_experts = cfg.n_experts - cfg.top_k
+    inactive_experts = cfg.n_experts_held - cfg.top_k
     return param_count(cfg) - L * 3 * D * F * inactive_experts
 
 
@@ -1540,6 +1639,7 @@ def forward_hidden_and_aux(
     # The cache-less forward (training, evaluation) scans one kind of layer;
     # a hybrid's prefill is generate.forward_with_cache.
     refuse_recurrent(cfg, "the cache-less forward pass (training and evaluation)")
+    refuse_hybrid_mixture(cfg, "the cache-less forward pass (training and evaluation)")
     if cfg.arch == "gpt2" and S > cfg.max_seq_len:
         # Learned position table: jnp.take would silently clamp out-of-range
         # rows (RoPE models have no such bound).

@@ -20,9 +20,9 @@ fusion) and HBM sees only the int8 bytes. int8 magnitudes ≤ 127 are
 exact in bfloat16, so the cast loses nothing.
 
 What quantizes: the per-layer projection kernels (q/k/v/o,
-gate/up/down — incl. stacked MoE expert kernels — or fc/proj for
-GPT-2-family) and the LM head. What stays in the master dtype:
-embeddings (a lookup, and the tied head of gpt2/gemma — tied-head
+gate/up/down — incl. stacked MoE expert kernels and a mixture's shared
+expert — or fc/proj for GPT-2-family) and the LM head. What stays in the
+master dtype: embeddings (a lookup, and the tied head of gpt2/gemma — tied-head
 models keep a full-precision head), norm scales/biases, projection
 biases, the MoE router (fp32-critical and ~0.01% of bytes), and qk-norm
 scales.
@@ -36,6 +36,7 @@ from __future__ import annotations
 import json
 import os
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Optional
 
 import jax
@@ -71,10 +72,13 @@ class QuantWeight:
         return self.q.ndim
 
 
+@partial(jax.jit, static_argnames="axis")
 def quantize_weight(w: jax.Array, axis: int = -2) -> QuantWeight:
     """Symmetric int8 quantization with the absmax taken over ``axis``
     (the contracted dim — every kernel this module touches contracts its
-    second-to-last dim)."""
+    second-to-last dim). One program: beside a float32 leaf stand its codes
+    and no float32 intermediate (op by op, a stacked expert leaf of a few GB
+    stood three times over)."""
     w32 = w.astype(jnp.float32)
     scale = jnp.max(jnp.abs(w32), axis=axis, keepdims=True) / 127.0
     scale = jnp.maximum(scale, 1e-12)
@@ -93,7 +97,10 @@ _QUANT_LAYER_KEYS = ("q", "k", "v", "o", "gate", "up", "down", "fc", "proj",
                      # a hybrid stack's Mamba-2 mixer projections
                      "in_proj", "out_proj",
                      # the output gate of its lightning and sparse-attention layers
-                     "o_gate")
+                     "o_gate",
+                     # the shared expert beside a hybrid's mixture (its routed
+                     # experts are ``gate`` / ``up`` / ``down``, stacked)
+                     "shared_gate", "shared_up", "shared_down")
 
 
 def _walk(params: dict[str, Any], kernel_fn) -> dict[str, Any]:

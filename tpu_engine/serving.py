@@ -62,9 +62,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpu_engine import layer_state
 from tpu_engine.generate import (
+    MOE_COUNTS,
     KVCache,
     forward_with_cache,
     init_cache,
+    init_moe_counts,
     ring_lanes,
     scan_layers,
 )
@@ -109,12 +111,16 @@ class SlotCache:
     write position p into lane ``p % S`` and track the stored position per
     lane in ``pos`` [B, S] (-1 = empty), mirroring the single-row ring
     cache of :class:`tpu_engine.generate.KVCache`.
+
+    ``moe_counts`` (a mixture's pool only): ``generate.MOE_COUNTS`` of the
+    walks since :func:`decode_chunk` last zeroed them, i.e. of one dispatch.
     """
 
     layers: dict
     lengths: jax.Array  # [B] int32 — resident tokens per slot (0 = empty)
     pos: Optional[jax.Array] = None  # [B, S] int32, ring pools only
     ring: bool = field(default=False, metadata=dict(static=True))
+    moe_counts: Optional[jax.Array] = None
 
     @property
     def n_lanes(self) -> int:
@@ -153,6 +159,7 @@ def init_slot_cache(
         lengths=jnp.zeros((slots,), jnp.int32),
         pos=jnp.full((slots, lanes), -1, jnp.int32) if ring else None,
         ring=ring,
+        moe_counts=init_moe_counts(cfg),
     )
 
 
@@ -274,7 +281,12 @@ def decode_chunk(
     its overshoot lanes are masked and later admissions overwrite them.
     A queued request waits at most ``n_steps`` tokens for the next
     admission window — the chunk no longer disengages under load.
+
+    A mixture's pool leaves with ``moe_counts`` those of THIS dispatch (zeroed
+    here, summed over its steps and layers): the host reads them in the fetch
+    that brings the tokens.
     """
+    cache = dataclasses.replace(cache, moe_counts=init_moe_counts(cfg))
 
     def one(carry, _):
         toks, cnts, cache = carry
@@ -419,10 +431,10 @@ def _paste_prefix(c1: KVCache, entry: KVCache, use_len: jax.Array,
     masking is what makes TOKEN-granular reuse free — the cache stores
     chunk-aligned entries, yet a prompt sharing any prefix of one reuses
     every full ``grain`` of the shared tokens."""
-    return KVCache(
-        layers=layer_state.paste_lanes(c1.layers, entry.layers, lanes),
+    return dataclasses.replace(
+        c1, layers=layer_state.paste_lanes(c1.layers, entry.layers, lanes),
         pos=lax.dynamic_update_slice(c1.pos, entry.pos[:lanes], (0,)),
-        length=use_len.astype(jnp.int32), ring=False,
+        length=use_len.astype(jnp.int32),
     )
 
 
@@ -597,6 +609,7 @@ class _PrefillState:
     c1: KVCache
     toks: np.ndarray    # [1, padded] int32 — prompt, zero-padded
     consumed: int = 0
+    chunks: int = 0     # chunks run so far (a prefix hit skips some)
     dc1: Optional[KVCache] = None
     prefix_checked: bool = False
 
@@ -869,6 +882,16 @@ class ContinuousBatcher:
         # Recurrent state written into a slot by a finished prefill, and
         # zeroed when a slot is freed (hybrid stacks; else both stay 0).
         self._recurrent_state_bytes = self._cache.recurrent_state_bytes
+        # A mixture's routing, by program: layer-steps run (counted here) and
+        # ``generate.MOE_COUNTS`` (counted on the device, fetched with each
+        # dispatch's tokens). Empty for a model without experts.
+        self._moe_counts = {
+            program: dict.fromkeys(("layer_steps",) + MOE_COUNTS, 0)
+            for program in (("decode", "prefill") if cfg.is_moe else ())}
+        self._shared_expert_bytes = sum(
+            a.size * a.dtype.itemsize
+            for path, a in jax.tree_util.tree_leaves_with_path(self.params)
+            if any(str(getattr(k, "key", "")).startswith("shared_") for k in path))
         self._state_inserts = 0
         self._state_resets = 0
         self._spec_rounds = 0
@@ -1225,6 +1248,16 @@ class ContinuousBatcher:
                 # it): what ``estimate_serving_hbm`` prices as ``params_gib``.
                 "weight_bytes": dict(self._weight_bytes),
             }
+            if self.cfg.is_moe:
+                # Monotonic, by program: mixture layer-steps run, token-expert
+                # assignments made at real positions, those on experts this
+                # replica holds, and held experts some token chose (distinct
+                # per layer-step, summed).
+                out["held_experts"] = self.cfg.n_experts_held
+                out["shared_expert_bytes"] = self._shared_expert_bytes
+                for program, counts in self._moe_counts.items():
+                    for name, n in counts.items():
+                        out[f"moe_{program}_{name}_total"] = n
             if self._prefix_cache is not None:
                 out["prefix_cache"] = self._prefix_cache.stats()
             if self._draft_params is not None:
@@ -1328,6 +1361,7 @@ class ContinuousBatcher:
         if st.dc1 is not None:  # speculative: the draft ingests the prompt too
             st.dc1 = self._draft_prefill_fn(self._draft_params, chunk, st.dc1)
         st.consumed = t1
+        st.chunks += 1
         self._prefill_tokens_computed += t1 - t0
         if self._sparse_from is not None:
             self._prefill_tokens_sparse += max(t1 - max(t0, self._sparse_from), 0)
@@ -1356,7 +1390,11 @@ class ContinuousBatcher:
                     self._slice_prefix(st.c1, last),
                 )
         if t0 <= P_len - 1 < t1:
-            self._pending_first_logits[st.slot] = np.asarray(last_row)
+            # The prompt's last chunk: with its logits row come the mixture's
+            # counts of all the request's chunks (``c1`` has summed them).
+            last_row, counts = jax.device_get((last_row, st.c1.moe_counts))
+            self._pending_first_logits[st.slot] = last_row
+            self._note_moe("prefill", counts, st.chunks)
         if st.consumed < st.padded:
             return False
         self._cache = self._insert(self._cache, st.c1, jnp.asarray(st.slot),
@@ -1505,7 +1543,9 @@ class ContinuousBatcher:
                 toks_host = np.asarray(tgt)         # [B, gamma+1]
                 n_take = np.asarray(n_acc)          # [B] accepted per slot
             else:
-                toks_host = np.asarray(toks_bn)     # [B, n] — one transfer
+                # [B, n], and a mixture's counts of this dispatch: one fetch
+                toks_host, counts = jax.device_get((toks_bn, self._cache.moe_counts))
+                self._note_moe("decode", counts, toks_host.shape[1])
                 n_take = None
         n_steps = toks_host.shape[1]
         self._decode_tokens_computed += len(active_reqs) * n_steps
@@ -1584,6 +1624,16 @@ class ContinuousBatcher:
         )
         self._last_tokens[slot] = handoff.last_token
         self.handoffs_in += 1
+
+    def _note_moe(self, program: str, counts, steps: int) -> None:
+        """Add a dispatch's (decode) or a prompt's (prefill) mixture counts:
+        ``steps`` walks of the stack and the device's ``MOE_COUNTS``."""
+        if counts is None:
+            return
+        mine = self._moe_counts[program]
+        mine["layer_steps"] += steps * self.cfg.n_layers
+        for name, n in zip(MOE_COUNTS, counts.tolist()):
+            mine[name] += n
 
     def _note_tokens(self, n: int) -> None:
         """Caller holds the lock."""
@@ -1724,10 +1774,9 @@ def _insert_prefill(cache: SlotCache, c1: KVCache, slot, true_len, ring: bool):
     if ring:
         # Lane-aligned by construction (c1 ring size == pool lane count).
         pos = lax.dynamic_update_slice(pos, c1.pos[None, :], (slot, 0))
-    return SlotCache(
-        layers=layer_state.insert_row(cache.layers, c1.layers, slot),
-        lengths=cache.lengths.at[slot].set(true_len.astype(jnp.int32)),
-        pos=pos, ring=cache.ring,
+    return dataclasses.replace(
+        cache, layers=layer_state.insert_row(cache.layers, c1.layers, slot),
+        lengths=cache.lengths.at[slot].set(true_len.astype(jnp.int32)), pos=pos,
     )
 
 
@@ -1735,7 +1784,7 @@ def _reset_slot(cache: SlotCache, slot):
     pos = cache.pos
     if cache.ring:
         pos = pos.at[slot].set(-1)
-    return SlotCache(
-        layers=layer_state.reset_row(cache.layers, slot),
-        lengths=cache.lengths.at[slot].set(0), pos=pos, ring=cache.ring,
+    return dataclasses.replace(
+        cache, layers=layer_state.reset_row(cache.layers, slot),
+        lengths=cache.lengths.at[slot].set(0), pos=pos,
     )

@@ -379,6 +379,7 @@ def build_train_program(
     # A hybrid (Mamba-2 + attention) stack is served only: the backward of
     # the chunked scan and packed documents are not written.
     tfm.refuse_recurrent(model_cfg, "training (build_train_program)")
+    tfm.refuse_hybrid_mixture(model_cfg, "training (build_train_program)")
     if runtime is None:
         runtime = MeshRuntime(cfg.mesh)
     mesh = runtime.mesh
