@@ -28,6 +28,7 @@ import tempfile
 import threading
 import time
 import traceback
+from collections import deque
 from enum import Enum
 from typing import Any, Callable, Iterator, Optional, Sequence
 
@@ -55,8 +56,9 @@ log = logging.getLogger(__name__)
 SUPERVISOR_PHASES = (
     "data",        # data_fn / synthetic batch
     "dispatch",    # trace-cache hit + async enqueue of the jitted step
-    "device",      # the blocking jax.device_get(metrics): device execution lands here
-    "health",      # fault seams + _unhealthy_mesh_devices
+    "device",      # the live fleet sample, then the blocking jax.device_get(metrics):
+                   # device execution lands here, the sample runs under it
+    "health",      # fault seams + the verdict on that sample (_unhealthy_mesh_devices)
     "anomaly",     # step-time detector, attribution, hetero tracker and consult
     "monitor",     # monitor.ingest, _log_metrics, alert handling
     "checkpoint",  # eval, periodic save, _advance_stable
@@ -159,6 +161,10 @@ class TrainingJob:
         self.recovery_state: Optional[str] = None
         self.recovery_events: list[dict[str, Any]] = []
         self.unhealthy_devices: list[int] = []
+        # The fleet samples taken under the step (_sample_fleet): how many,
+        # and each one's host seconds over the phase clock's window.
+        self.health_samples_total = 0
+        self._health_sample_s: deque[float] = deque()
 
         # Flight-recorder identity: the scheduler passes its submission's
         # trace so every attempt chains under one lifecycle root; a
@@ -334,11 +340,45 @@ class TrainingJob:
         if inj is not None:
             inj.record(f"recovery:{kind}", step=step, detail=f"job {self.job_id}: {detail}")
 
-    def _unhealthy_mesh_devices(self) -> list[int]:
+    def _health_poll_due(self, step: int) -> bool:
+        """Whether the iteration that reaches ``step`` judges fleet health."""
+        return (
+            self.self_heal
+            and self.preemption_reason is None
+            and step % self.health_check_interval_steps == 0
+        )
+
+    def _sample_fleet(self, it: int) -> Any:
+        """The live fleet view for iteration ``it``'s health verdict, or None
+        where ``fleet_fn`` raises. The loop calls it between the step's
+        dispatch and the blocking read of its metrics, so its host time
+        (``describe()["health_sample_ms"]``) runs under the chip's step
+        instead of after it; the annotation shows where it ran in a trace."""
+        with jax.profiler.TraceAnnotation("tpu_engine.supervisor.health_sample", step=it):
+            t0 = time.perf_counter()
+            try:
+                return self.fleet_fn()
+            except Exception:
+                return None
+            finally:
+                self._health_sample_s.append(time.perf_counter() - t0)
+                self.health_samples_total += 1
+
+    def _unhealthy_mesh_devices(self, fleet: Any) -> list[int]:
         """Fleet device indices that are CRITICAL *and* inside this job's
-        mesh. This job's own HBM footprint and duty cycle never read as a
-        failure: the fleet view does not classify load on chips that carry
-        this control plane's job claims (``TPUDevice.carries_own_load``)."""
+        mesh: the verdict on ``fleet`` (:meth:`_sample_fleet`'s view, None
+        for no view) and on the injector's chip overlay as it stands now.
+        This job's own HBM footprint and duty cycle never read as a
+        failure, mid-step or between steps: the fleet view does not
+        classify load on chips that carry this control plane's job claims
+        (``TPUDevice.carries_own_load``).
+
+        Latency: the view was taken when the step was dispatched, about one
+        step's device time before it is judged here. A chip that is CRITICAL
+        at dispatch, and every injected fault (the overlay is read here,
+        after ``observe_step``), starts the self-heal at that same step; a
+        chip that turns CRITICAL while the step runs is seen by the next
+        iteration's sample and heals one step later, never more."""
         prog = self.program
         if prog is None:
             return []
@@ -362,17 +402,12 @@ class TrainingJob:
             for idx, kind in inj.chip_overlay().items():
                 if kind is FaultKind.CHIP_UNHEALTHY and in_mesh(idx):
                     bad.add(idx)
-        if self.fleet_fn is not None:
+        if fleet is not None:
             from tpu_engine.tpu_manager import TPUHealthStatus
 
-            try:
-                fleet = self.fleet_fn()
-            except Exception:
-                fleet = None
-            if fleet is not None:
-                for dev in fleet.devices:
-                    if dev.health_status == TPUHealthStatus.CRITICAL and in_mesh(dev.index):
-                        bad.add(dev.index)
+            for dev in fleet.devices:
+                if dev.health_status == TPUHealthStatus.CRITICAL and in_mesh(dev.index):
+                    bad.add(dev.index)
         return sorted(bad)
 
     def _begin_self_heal(self, step: int, bad: list[int]) -> None:
@@ -812,6 +847,7 @@ class TrainingJob:
                     self.config.gradient_accumulation_steps,
                 ),
             )
+            self._health_sample_s = deque(maxlen=prof.window)
             if self._hetero is None and self.hetero_detection:
                 from tpu_engine import hetero as hetero_mod
 
@@ -854,6 +890,13 @@ class TrainingJob:
                 with prof.phase("dispatch", step=it), self._state_lock:
                     self._state, metrics = prog.step(self._state, batch)
                 with prof.phase("device", step=it):
+                    # The chip runs the step from here to the read's return:
+                    # the fleet sample this iteration's health verdict wants
+                    # is taken under it, on the iterations that poll (the
+                    # step the read will report is it + 1).
+                    fleet = None
+                    if self.fleet_fn is not None and self._health_poll_due(it + 1):
+                        fleet = self._sample_fleet(it)
                     host = {k: float(v) for k, v in jax.device_get(metrics).items()}
                 # Step time is the WHOLE iteration, begin to begin — what an
                 # operator's tokens/s must be over — so it is the previous
@@ -914,12 +957,8 @@ class TrainingJob:
                             # Synchronous injection (not via the watcher thread):
                             # the step that triggers is the step that saves.
                             self._on_preemption("fault-injected:preemption-signal")
-                    if (
-                        self.self_heal
-                        and self.preemption_reason is None
-                        and step % self.health_check_interval_steps == 0
-                    ):
-                        bad = self._unhealthy_mesh_devices()
+                    if self._health_poll_due(step):
+                        bad = self._unhealthy_mesh_devices(fleet)
                         if bad:
                             self._begin_self_heal(step, bad)
 
@@ -1595,6 +1634,8 @@ class TrainingJob:
             "tokens_per_sec": self.tokens_per_sec,
             "monitor": self.monitor.get_summary(),
             "profile": self.profiler.summary() if self.profiler is not None else None,
+            "health_samples_total": self.health_samples_total,
+            "health_sample_ms": StepProfiler._stats(list(self._health_sample_s)),
             "eval": self.eval_summary(),
             "disk_spill_bytes": spill,
         }
