@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpu_engine import tracing
+from tpu_engine import profiler, tracing
 from tpu_engine.models import transformer as tfm
 from tpu_engine.serving import BATCHER_PHASES, ContinuousBatcher
 from tpu_engine.serving_fleet import REQUEST_STAGES, build_replica_engine
@@ -159,3 +159,143 @@ def test_fleet_request_trace_holds_its_four_stages(sched_factory):
         assert sp["attrs"]["engine_rid"] is not None
     assert order[0]["t0"] == out["submitted_at"] and order[2]["t1"] == out["first_token_at"]
     fleet.stop()
+
+
+def test_a_phase_that_waits_for_the_engines_lock_books_the_wait_as_blocked(tiny_model, monkeypatch):
+    """``handoff`` is a few microseconds of host code under ``engine._lock``;
+    while another thread holds the lock it is 0.2 s of wall time, and the
+    clock (whose phases read the thread's CPU time under a profiler session)
+    says those were spent off the CPU."""
+    monkeypatch.setattr(profiler, "_tracing", lambda: True)
+    cfg, params = tiny_model
+    srv = ContinuousBatcher(params, cfg, max_slots=1, max_len=32,
+                            compute_dtype=jnp.float32, prefill_pad_to=16)
+    srv.submit([3, 4, 5], max_new_tokens=8)
+    for _ in range(3):
+        srv.step()  # compiled and decoding
+    held = threading.Event()
+
+    def holder():
+        with srv._lock:
+            held.set()
+            time.sleep(0.2)
+
+    t = threading.Thread(target=holder)
+    t.start()
+    assert held.wait(10)
+    srv.step()
+    t.join()
+    srv.step()  # closes the iteration that waited
+    wall, blocked = srv._profiler._phases["handoff"][-1], srv._profiler._blocked["handoff"][-1]
+    assert wall >= 0.1 and blocked == pytest.approx(wall, abs=5e-3)
+    calm_wall = srv._profiler._phases["handoff"][-2]  # the iteration before it
+    assert calm_wall < 0.05 and srv._profiler._blocked["handoff"][-2] <= calm_wall + 1e-4
+    assert srv.profile()["phases"]["handoff"]["blocked_ms"]["mean"] >= 100 / len(srv._profiler._totals)
+
+
+def test_the_replicas_wait_is_phase_idle_and_says_whether_work_was_pending(sched_factory, monkeypatch):
+    """``ServingReplicaJob._run`` waits after every step that produced
+    nothing, through ``ContinuousBatcher.idle_wait``: the wait is phase
+    ``idle`` and not ``other``, and one taken while a prompt is still
+    prefilling counts in ``idle_waits_with_work_total``."""
+    monkeypatch.setattr(profiler, "_tracing", lambda: True)  # the phases read the thread's clock
+    s = sched_factory(max_concurrent_jobs=2, fleet_fn=mock_fleet_fn)
+    spec = small_spec(max_slots=2, max_len=256, prefill_chunk=64)
+    fleet = make_fleet(s, spec=spec, engine_factory=build_replica_engine)
+    fleet.scale_to(1)
+    assert wait_until(lambda: len(fleet.running_replicas()) == 1, timeout=120)
+    (engine,) = fleet.running_replicas().values()
+    assert wait_until(lambda: engine.stats()["idle_waits_total"] >= 5, timeout=30)
+    empty = engine.stats()
+    assert empty["idle_waits_with_work_total"] == 0  # nothing was pending yet
+    # 150 tokens in chunks of 64: the steps that only advance a chunk produce
+    # no token, and the loop waits after each with the prompt still prefilling
+    fid = fleet.submit_request(list(range(1, 151)), max_new_tokens=4)
+    assert wait_until(lambda: fleet.result(fid)["status"] == "done", timeout=120)
+    assert wait_until(lambda: engine.stats()["idle_waits_total"] >= empty["idle_waits_total"] + 5, timeout=30)
+    st, prof = engine.stats(), engine.profile()
+    assert 1 <= st["idle_waits_with_work_total"] <= 4
+    assert st["idle_waits_total"] > st["idle_waits_with_work_total"]
+    idle, other = prof["phases"]["idle"], prof["phases"]["other"]
+    assert idle["p50_ms"] >= 4 and idle["blocked_ms"]["p50"] == pytest.approx(idle["p50_ms"], abs=2)
+    assert other["p50_ms"] < 2  # the 5 ms wait is no longer the iteration's remainder
+    assert fleet.status()["replicas"][next(iter(fleet.status()["replicas"]))]["engine"]["profile"][
+        "phases"]["idle"]["blocked_ms"]["mean"] > 0
+    fleet.stop()
+
+
+def test_a_cpu_trace_shows_the_pump_on_its_own_thread(sched_factory, tmp_path):
+    """In a ``jax.profiler`` trace ``tpu_ctl.scheduler.pass`` lies on the
+    ``fleet-scheduler`` thread's line with ``thread=``, ``queued=`` and
+    ``running=``; a phase of a loop on another thread carries ``blocked_us=``,
+    and no ``tpu_engine.*`` annotation shares the pump's line."""
+    import glob
+
+    import jax.profiler
+    from jax.profiler import ProfileData
+
+    s = sched_factory(max_concurrent_jobs=1, fleet_fn=mock_fleet_fn, poll_interval_s=0.01)
+    before = s.stats()["poll_passes_total"]
+    prof = profiler.StepProfiler(loop="batcher", phases=BATCHER_PHASES)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level, opts.host_tracer_level = 0, 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        s._ensure_thread()
+        for _ in range(3):
+            prof.begin_step()
+            with prof.phase("stage", with_prefill=0):
+                time.sleep(0.02)
+        prof.end_step()
+    finally:
+        jax.profiler.stop_trace()
+    stats = s.stats()
+    assert stats["poll_passes_total"] > before and stats["poll_pass_seconds_total"] > 0
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    lines = [[(ev.name, dict(ev.stats)) for ev in ln.events if ev.name.startswith(("tpu_ctl.", "tpu_engine."))]
+             for plane in ProfileData.from_file(path).planes if plane.name.startswith("/host:")
+             for ln in plane.lines]
+    # (a pump some earlier test of this process left running has a line of its own)
+    pumps = [evs for evs in lines if any(name == "tpu_ctl.scheduler.pass" for name, _ in evs)]
+    assert pumps and sum(len(evs) for evs in pumps) >= 2
+    for pump in pumps:
+        # the mock fleet view goes through get_fleet_status, the samplers' one door, inside the pass
+        assert {name for name, _ in pump} <= {"tpu_ctl.scheduler.pass", "tpu_ctl.manager.fleet_status"}
+        for name, args in pump:
+            assert args["thread"] == "fleet-scheduler"
+            if name == "tpu_ctl.scheduler.pass":
+                assert {"queued", "running"} <= set(args)
+    (loop,) = [evs for evs in lines if any(name == "tpu_engine.batcher.stage" for name, _ in evs)]
+    stage = [args for name, args in loop if name == "tpu_engine.batcher.stage"]
+    assert len(stage) == 3
+    for args in stage:
+        assert args["with_prefill"] == 0 and "blocked_us" in set(args)
+        assert 15000 <= args["blocked_us"] <= 60000  # slept, not computed
+    iteration = [args for name, args in loop if name == "tpu_engine.batcher.other"]
+    assert len(iteration) == 3 and all(a["blocked_us"] >= 15000 for a in iteration)
+
+
+def test_the_schedulers_passes_are_counted_exactly_under_threads(sched_factory):
+    """``poll`` is safe to call from any thread: ``poll_passes_total`` loses
+    no pass and ``poll_pass_seconds_total`` is no more than the callers saw,
+    with no profiler session (``ctl_span`` is then nothing at all)."""
+    s = sched_factory(max_concurrent_jobs=1, fleet_fn=mock_fleet_fn)
+    before = s.stats()
+    n_threads, n_each = 8, 25
+    seen = [0.0] * n_threads
+
+    def worker(k):
+        for _ in range(n_each):
+            t0 = time.perf_counter()
+            s.poll()
+            seen[k] += time.perf_counter() - t0
+
+    threads = [threading.Thread(target=worker, args=(k,)) for k in range(n_threads)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
+    after = s.stats()
+    assert after["poll_passes_total"] == before["poll_passes_total"] + n_threads * n_each
+    assert 0 < after["poll_pass_seconds_total"] - before["poll_pass_seconds_total"] <= sum(seen)

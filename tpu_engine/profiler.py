@@ -9,8 +9,12 @@ The reference delegates all profiling to DeepSpeed config flags
 
 - :class:`StepProfiler` — the phase clock inside the supervisor loop and
   ``ContinuousBatcher.step``: every phase of an iteration by name, on the
-  host clock (rolling mean/p50/p95, bounded window) and as
+  host clock (rolling mean/p50/p95, bounded window; wall seconds and the
+  part of them the loop's thread spent off the CPU) and as
   ``tpu_engine.<loop>.<phase>`` annotations on the profiler's clock;
+- :func:`ctl_span` — what the control plane's *other* threads do beside such
+  a loop (the scheduler's pump, the fleet sample, the serving fleet's
+  request plane), as ``tpu_ctl.<owner>.<what>`` annotations;
 - :func:`mfu` / :func:`peak_flops_per_chip` — tokens/sec/chip → model-FLOPs
   utilisation against the chip's bf16 peak (the BASELINE.json north-star
   metric);
@@ -21,14 +25,16 @@ The reference delegates all profiling to DeepSpeed config flags
 
 from __future__ import annotations
 
-import contextlib
 import statistics
 import threading
 import time
 from collections import deque
+from time import perf_counter, thread_time
 from typing import Any, Optional
 
 import jax
+
+_tracing = jax.profiler.TraceAnnotation.is_enabled  # a profiler session is running
 
 # Peak bf16 FLOP/s per chip by device kind (public spec sheets).
 PEAK_FLOPS_BF16 = {
@@ -124,6 +130,25 @@ class StepProfiler:
     iteration, so in a trace the phases lie inside it and whatever they leave
     uncovered reads ``tpu_engine.<loop>.other``. All in seconds.
 
+    **Blocked seconds.** ``blocked = wall - thread CPU`` (``time.
+    thread_time``): a thread that waits for the interpreter lock, a mutex, a
+    sleep or the device is off the CPU, one that runs Python or dispatches is
+    on it. So host code that took 12 ms because another thread held the
+    interpreter reads 12 ms wall, 11 ms blocked. The thread's clock is a
+    system call (no vDSO), which a sandboxed kernel can make expensive (6-11
+    us a read on the benchmark's machine; 22 reads a dispatch cost
+    serve-batch 1.5 %), so the clock reads it **only in iterations that began
+    while a profiler session ran**, around the iteration and every phase of
+    it: ``summary()["total"]["blocked_ms"]`` and ``["phases"][p]
+    ["blocked_ms"]`` cover the last ``window`` such iterations and are absent
+    before the first, and each phase's annotation carries ``blocked_us=``
+    (the iteration's ``other`` annotation the whole iteration's). Its
+    resolution is the platform's too: where the kernel accounts CPU time in
+    10 ms ticks a single value says little and the mean over many iterations
+    is what to read, so nothing is clamped (a tick credited to a 2 ms phase
+    reads -8 ms). The thread's clock is the calling thread's: one loop drives
+    one clock from one thread.
+
     ``loop`` and ``phases`` are the owning loop's: it names itself and the
     phases of its body (``other`` is appended here).
     """
@@ -148,11 +173,15 @@ class StepProfiler:
         self._span_names = {p: f"tpu_engine.{loop}.{p}" for p in self.phases}
         self._iteration: Optional[Any] = None  # the open iteration's ``other`` annotation
         self._phases: dict[str, deque[float]] = {p: deque(maxlen=window) for p in self.phases}
+        self._blocked: dict[str, deque[float]] = {p: deque(maxlen=window) for p in self.phases}
         self._totals: deque[float] = deque(maxlen=window)
+        self._totals_blocked: deque[float] = deque(maxlen=window)
         self._steps_seen = 0
         self._lock = threading.Lock()
         self._t_step_start: Optional[float] = None
+        self._cpu_step_start = 0.0
         self._current: dict[str, float] = {}
+        self._current_blocked: Optional[dict[str, float]] = None  # None: this iteration's phases read no CPU clock
         self._last: dict[str, float] = {}
         self._last_total: Optional[float] = None
 
@@ -161,10 +190,15 @@ class StepProfiler:
     def begin_step(self) -> Optional[float]:
         """Open an iteration, closing the one before it; returns the closed
         iteration's total seconds (None when none was open)."""
-        now = time.perf_counter()
+        now = perf_counter()
         closed = self._close(now)
         self._t_step_start = now
         self._current = {}
+        # The thread's clock is read only in an iteration that begins under a session.
+        if _tracing():
+            self._current_blocked, self._cpu_step_start = {}, thread_time()
+        else:
+            self._current_blocked = None
         self._iteration = jax.profiler.TraceAnnotation(self._span_names["other"])
         self._iteration.__enter__()
         return closed
@@ -172,35 +206,38 @@ class StepProfiler:
     def end_step(self) -> Optional[float]:
         """Close the open iteration without opening another (the loop has
         left its body for the last time). Returns its total seconds."""
-        return self._close(time.perf_counter())
+        return self._close(perf_counter())
 
     def _close(self, now: float) -> Optional[float]:
         if self._t_step_start is None:
             return None
-        self._iteration.__exit__(None, None, None)
         total = now - self._t_step_start
-        cur = self._current
+        cur, blk = self._current, self._current_blocked
         cur["other"] = max(total - sum(cur.values()), 0.0)
+        blocked = 0.0
+        if blk is not None:
+            blocked = total - (thread_time() - self._cpu_step_start)
+            blk["other"] = blocked - sum(blk.values())
+            if _tracing():
+                self._iteration.set_metadata(blocked_us=round(blocked * 1e6))
+        self._iteration.__exit__(None, None, None)
         with self._lock:
             for p in self.phases:
                 self._phases[p].append(cur.get(p, 0.0))
+                if blk is not None:
+                    self._blocked[p].append(blk.get(p, 0.0))
             self._totals.append(total)
+            if blk is not None:
+                self._totals_blocked.append(blocked)
             self._steps_seen += 1
         self._last, self._last_total = cur, total
         self._t_step_start = None
         return total
 
-    @contextlib.contextmanager
-    def phase(self, name: str, **ids: Any):
+    def phase(self, name: str, **ids: Any) -> "_Phase":
         """Attribute the enclosed interval to ``name``; ``ids`` (step,
         request id, slot …) travel on the profiler annotation only."""
-        with jax.profiler.TraceAnnotation(self._span_names[name], **ids):
-            t0 = time.perf_counter()
-            try:
-                yield
-            finally:
-                cur = self._current
-                cur[name] = cur.get(name, 0.0) + (time.perf_counter() - t0)
+        return _Phase(self, name, jax.profiler.TraceAnnotation(self._span_names[name], **ids))
 
     def open_step(self) -> tuple[dict[str, float], float]:
         """(phase seconds so far, seconds since it began) of the open
@@ -229,10 +266,17 @@ class StepProfiler:
             "p95_ms": p95 * 1e3,
         }
 
+    @classmethod
+    def _blocked_stats(cls, xs: list[float]) -> dict[str, float]:
+        s = cls._stats(xs)
+        return {"mean": s["mean_ms"], "p50": s["p50_ms"], "p95": s["p95_ms"]}
+
     def summary(self) -> dict[str, Any]:
         with self._lock:
             totals = list(self._totals)
+            totals_blocked = list(self._totals_blocked)
             phases = {p: list(v) for p, v in self._phases.items()}
+            blocked = {p: list(v) for p, v in self._blocked.items()}
             steps_seen = self._steps_seen
         out: dict[str, Any] = {
             "steps_seen": steps_seen,
@@ -240,6 +284,10 @@ class StepProfiler:
             "total": self._stats(totals),
             "phases": {p: self._stats(v) for p, v in phases.items()},
         }
+        if totals_blocked:  # iterations that began under a profiler session
+            out["total"]["blocked_ms"] = self._blocked_stats(totals_blocked)
+            for p, v in blocked.items():
+                out["phases"][p]["blocked_ms"] = self._blocked_stats(v)
         mean_total = statistics.fmean(totals) if totals else 0.0
         if totals and mean_total > 0:
             for p, v in phases.items():
@@ -265,6 +313,74 @@ class StepProfiler:
             if out.get("mfu") is not None:
                 out["mfu_bubble_adjusted"] = round(out["mfu"] / busy, 4)
         return out
+
+
+class _Phase:
+    """One ``with prof.phase(name)`` interval: wall seconds always, and in an
+    iteration that began under a profiler session the thread's CPU seconds,
+    read at entry and exit inside the phase's annotation (a class, not a
+    generator: the clock runs ten times a dispatch)."""
+
+    __slots__ = ("_prof", "_name", "_ann", "_t0", "_cpu0")
+
+    def __init__(self, prof: StepProfiler, name: str, ann: Any):
+        self._prof, self._name, self._ann = prof, name, ann
+
+    def __enter__(self) -> None:
+        self._ann.__enter__()
+        self._cpu0 = thread_time() if self._prof._current_blocked is not None else None
+        self._t0 = perf_counter()
+
+    def __exit__(self, *exc: Any) -> None:
+        wall = perf_counter() - self._t0
+        prof, name = self._prof, self._name
+        cur, blk = prof._current, prof._current_blocked
+        cur[name] = cur.get(name, 0.0) + wall
+        if self._cpu0 is not None and blk is not None:  # same iteration as at entry
+            cpu = thread_time() - self._cpu0
+            blk[name] = blk.get(name, 0.0) + wall - cpu
+            if _tracing():
+                self._ann.set_metadata(blocked_us=round((wall - cpu) * 1e6))
+        self._ann.__exit__(*exc)
+
+
+class _NoSpan:
+    """What :func:`ctl_span` hands out while no profiler session runs."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        pass
+
+    def set_metadata(self, **ids: Any) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def ctl_span(owner: str, what: str, **ids: Any) -> Any:
+    """``with ctl_span(owner, what, **ids) as span``: something the control
+    plane does on a thread that is *not* a loop feeding the chip (the
+    scheduler's pump, a fleet sample, the serving fleet's request plane), so
+    that a trace shows what ran beside the loop.
+
+    While a ``jax.profiler`` session runs it is a ``TraceAnnotation(
+    "tpu_ctl.<owner>.<what>", thread=<the calling thread's name>, **ids)``
+    (``span.set_metadata(**more)`` adds arguments known only at the end);
+    with no session a flag test and nothing else. Whoever wants its count or
+    its seconds with no trace keeps them itself (``FleetScheduler.poll``
+    does). The prefix is ``tpu_ctl.`` and not ``tpu_engine.`` on purpose:
+    readers of the phase clock merge all host threads and keep the innermost
+    ``tpu_engine.*`` annotation, so one of those on another thread would
+    rename the loop's idle gaps."""
+    if not _tracing():
+        return _NO_SPAN
+    return jax.profiler.TraceAnnotation(
+        f"tpu_ctl.{owner}.{what}", thread=threading.current_thread().name, **ids)
 
 
 class TraceActiveError(RuntimeError):

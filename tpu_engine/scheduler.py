@@ -55,6 +55,7 @@ from tpu_engine.hbm_estimate import (
     gang_size,
 )
 from tpu_engine.placement import PlacementPlanner
+from tpu_engine.profiler import ctl_span
 from tpu_engine.sharding import TPUTrainConfig
 from tpu_engine.supervisor import JobStatus, TrainingJob
 from tpu_engine.tpu_manager import TPUFleetStatus
@@ -414,6 +415,8 @@ class FleetScheduler:
         self.self_heal_requeues_total = 0
         self.auto_admissions_total = 0
         self.no_estimate_skips_total = 0
+        self.poll_passes_total = 0  # poll() passes and the host seconds they took
+        self.poll_pass_seconds_total = 0.0
         self.precompiles_started_total = 0
         self.grow_back_warm_total = 0
         self.grow_back_cold_total = 0
@@ -722,8 +725,13 @@ class FleetScheduler:
 
     def poll(self) -> None:
         """One pass: reap finished attempts (requeue preempted ones), then
-        admit. Idempotent and safe to call from any thread."""
-        with self._lock:
+        admit. Idempotent and safe to call from any thread. A pass is a
+        ``tpu_ctl.scheduler.pass`` span (``queued=``, ``running=``): the
+        pump runs beside every job it admitted, and this is what shows its
+        period and duty on a trace; ``stats()["poll_passes_total"]`` and
+        ``["poll_pass_seconds_total"]`` say the same with no trace."""
+        t0 = time.perf_counter()
+        with ctl_span("scheduler", "pass") as span, self._lock:
             self._reap()
             if not self._draining:
                 self._admit()
@@ -732,6 +740,9 @@ class FleetScheduler:
             queued = self._queued_count()
             running = self._active_count()
             quarantined = len(self._hetero_quarantined)
+            span.set_metadata(queued=queued, running=running)
+            self.poll_passes_total += 1
+            self.poll_pass_seconds_total += time.perf_counter() - t0
         # Retain queue depth per poll pass in the historian (outside the
         # lock — the historian has its own). Best effort: scheduling must
         # never fail because observability did.
@@ -2342,6 +2353,9 @@ class FleetScheduler:
             "self_heal_requeues_total": self.self_heal_requeues_total,
             "auto_admissions_total": self.auto_admissions_total,
             "no_estimate_skips_total": self.no_estimate_skips_total,
+            # poll() passes and their host seconds.
+            "poll_passes_total": self.poll_passes_total,
+            "poll_pass_seconds_total": round(self.poll_pass_seconds_total, 6),
             "placement": self.planner.stats(),
             "compile_cache": {
                 **self.compile_index.stats(),

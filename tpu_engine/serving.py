@@ -629,7 +629,7 @@ BATCHER_PHASES = (
     "stage",        # host arrays, transfers and the decode/speculative dispatch
     "device",       # the blocking token read
     "emit",         # the locked emission loop
-    "idle",         # serve_forever's sleep when nothing is queued
+    "idle",         # idle_wait: the driving loop's wait between two steps
 )
 
 
@@ -866,6 +866,8 @@ class ContinuousBatcher:
         # The engine loop's phase clock (see StepProfiler) and the decode
         # layer's attempted and useful token counts; engine-thread writes only.
         self._profiler = StepProfiler(loop="batcher", phases=BATCHER_PHASES)
+        self._idle_waits = 0
+        self._idle_waits_with_work = 0
         self._decode_tokens_computed = 0
         self._decode_tokens_emitted = 0
         # Of the computed token-steps, those whose context was past a
@@ -1236,6 +1238,11 @@ class ContinuousBatcher:
                 "decode_tokens_sparse_total": self._decode_tokens_sparse,
                 "prefill_tokens_computed_total": self._prefill_tokens_computed,
                 "prefill_tokens_sparse_total": self._prefill_tokens_sparse,
+                # Monotonic: waits the driving loop took between two steps
+                # (phase ``idle``), and those entered with a prompt still
+                # prefilling, queued or awaiting a handoff.
+                "idle_waits_total": self._idle_waits,
+                "idle_waits_with_work_total": self._idle_waits_with_work,
                 # The pool's whole kinds of state (Mamba-2, lightning; 0
                 # for attention-only stacks): their bytes, every slot's
                 # whether in use or not, and how often a slot's was
@@ -1458,6 +1465,7 @@ class ContinuousBatcher:
                 self._prefilling[slot] = self._begin_prefill(req, slot)
 
         # ---- ONE prefill chunk per step (bounded decode stall) ----
+        with_prefill = 0  # a chunk rode in this iteration
         if self._prefilling:
             slot, st = next(iter(self._prefilling.items()))
             if st.req.status != "running":
@@ -1467,6 +1475,7 @@ class ContinuousBatcher:
                                 chunk=st.consumed // self.prefill_chunk,
                                 tokens=min(self.prefill_chunk,
                                            st.padded - st.consumed)):
+                    with_prefill = 1
                     if st.req.prefill_started_at is None:
                         st.req.prefill_started_at = time.time()
                     ingested = self._advance_prefill(st)
@@ -1515,7 +1524,7 @@ class ContinuousBatcher:
         # emits 1..gamma+1 tokens per slot for two model dispatches.
         # (Greedy-only by the submit guard — no sampling state needed.)
         speculative = self._draft_params is not None
-        with prof.phase("stage"):
+        with prof.phase("stage", with_prefill=with_prefill):
             active = np.zeros((self.max_slots,), bool)
             for i, _ in active_reqs:
                 active[i] = True
@@ -1691,6 +1700,20 @@ class ContinuousBatcher:
                 self._draft_cache = self._reset(self._draft_cache, slot)
             self._done.notify_all()
 
+    def idle_wait(self, stop: threading.Event, seconds: float) -> None:
+        """ENGINE thread, between two ``step`` calls: wait up to ``seconds``
+        on ``stop`` in phase ``idle`` (the annotation says what was pending:
+        ``prefilling=``, ``queued=``), so the loop's own waits are no part of
+        ``other``. Whoever drives ``step`` decides when to wait
+        (:meth:`serve_forever`, ``ServingReplicaJob._run``); a wait entered
+        with work pending counts in ``idle_waits_with_work_total``."""
+        prefilling, queued = len(self._prefilling), len(self._queue)
+        self._idle_waits += 1
+        if prefilling or queued or self._prefilled_queue or self._handoff_requests:
+            self._idle_waits_with_work += 1
+        with self._profiler.phase("idle", prefilling=prefilling, queued=queued):
+            stop.wait(seconds)
+
     def serve_forever(self, stop: threading.Event, idle_sleep: float = 0.01):
         """Drive ``step`` until ``stop``. A step failure (e.g. a prefill
         compile OOM) marks every in-flight and queued request ``failed``
@@ -1715,8 +1738,7 @@ class ContinuousBatcher:
                 if produced == 0 and not self._prefilling and not self._queue \
                         and not self._handoff_requests \
                         and not self._prefilled_queue:
-                    with self._profiler.phase("idle"):
-                        time.sleep(idle_sleep)
+                    self.idle_wait(stop, idle_sleep)
         finally:
             if self.last_error is None:
                 self._drain("server stopped")

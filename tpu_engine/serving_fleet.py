@@ -54,6 +54,7 @@ from tpu_engine import journal as journal_mod
 from tpu_engine import tracing
 from tpu_engine.hbm_estimate import HBMEstimate, estimate_serving_hbm
 from tpu_engine.mesh_runtime import MeshConfig
+from tpu_engine.profiler import ctl_span
 from tpu_engine.scheduler import (
     TERMINAL_STATES,
     FleetScheduler,
@@ -323,6 +324,8 @@ class ServingReplicaJob:
         self.engine = engine
         self.engine_ready.set()
         self.status = JobStatus.RUNNING
+        # ContinuousBatcher's wait is a phase of its clock; a stand-in engine keeps none.
+        idle_wait = getattr(engine, "idle_wait", None) or (lambda stop, seconds: stop.wait(seconds))
         try:
             while True:
                 if self._faults is not None and self._faults.preempt_due(
@@ -338,7 +341,7 @@ class ServingReplicaJob:
                 produced = int(engine.step() or 0)
                 self.current_step += produced
                 if produced == 0:
-                    self._stop.wait(self._idle_sleep_s)
+                    idle_wait(self._stop, self._idle_sleep_s)
         except Exception as e:  # noqa: BLE001 — decode loop boundary
             self.status = JobStatus.FAILED
             self.error = f"{type(e).__name__}: {e}"
@@ -1073,9 +1076,10 @@ class ServingFleet:
     ) -> str:
         """Route a request to a replica (or hold it fleet-side until one is
         admitted). Returns a fleet-scoped request id."""
-        with self._lock:
+        with ctl_span("fleet", "route") as span, self._lock:
             self._req_seq += 1
             fid = f"req_{self._req_seq}"
+            span.set_metadata(fid=fid)
             self.requests_total += 1
             rec = tracing.get_recorder()
             span = rec.start_span(
@@ -1218,7 +1222,7 @@ class ServingFleet:
         """Fleet-side view of one request; re-dispatches it when its
         replica was preempted mid-flight (stateless replicas make retry the
         correct recovery)."""
-        with self._lock:
+        with ctl_span("fleet", "result", fid=fid), self._lock:
             req = self._requests.get(fid)
             if req is None:
                 raise KeyError(fid)
@@ -1318,6 +1322,10 @@ class ServingFleet:
         """One control-loop pass: flush held requests, refresh router
         weights, drive the autoscaler. The HTTP plane calls this on status
         reads; a live deployment would pin it to a timer."""
+        with ctl_span("fleet", "tick"):
+            return self._tick(now)
+
+    def _tick(self, now: Optional[float]) -> dict[str, Any]:
         now = time.time() if now is None else now
         with self._lock:
             self._flush_pending()

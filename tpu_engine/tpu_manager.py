@@ -29,6 +29,8 @@ from typing import Any, Optional, Sequence
 import jax
 from pydantic import BaseModel, Field
 
+from tpu_engine.profiler import ctl_span
+
 # Default HBM per chip when the runtime doesn't report a limit (GiB).
 _DEFAULT_HBM_GIB = {
     "TPU v4": 32.0,
@@ -453,7 +455,16 @@ class TPUManager:
         metrics: Optional[Sequence[dict[str, Any]]] = None,
         metrics_json: Optional[str] = None,
     ) -> TPUFleetStatus:
-        """Aggregate fleet view (reference ``get_fleet_status``, ``gpu_manager.py:275-321``)."""
+        """Aggregate fleet view (reference ``get_fleet_status``, ``gpu_manager.py:275-321``).
+        The one door of every sampler (the scheduler's pump, the supervisor's
+        health sample, admission, the HTTP plane): a ``tpu_ctl.manager.
+        fleet_status`` span on whichever thread came through it."""
+        with ctl_span("manager", "fleet_status"):
+            return self._fleet_status(metrics, metrics_json)
+
+    def _fleet_status(
+        self, metrics: Optional[Sequence[dict[str, Any]]], metrics_json: Optional[str]
+    ) -> TPUFleetStatus:
         telemetry_sources: list[str] = []
         ici_links: list[tuple[str, int]] = []
         if metrics_json is not None:
