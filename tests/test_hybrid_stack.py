@@ -37,6 +37,7 @@ from reference import granitemoehybrid as ref  # noqa: E402
 from tpu_engine import serving  # noqa: E402
 from tpu_engine.generate import forward_with_cache, init_cache  # noqa: E402
 from tpu_engine.models import transformer as tfm  # noqa: E402
+from tpu_engine.ops import ssd_update  # noqa: E402
 
 TOL = 5e-8
 SEED = 5
@@ -277,6 +278,44 @@ def test_the_engine_passes_each_chunks_real_length(tiny):
     fn = blind._prefill_fn
     blind._prefill_fn = lambda p, chunk, c1, row, n_valid: fn(p, chunk, c1, row)
     assert worst_gap(blind) > 1e-5
+
+
+def test_the_engine_decodes_in_place_where_the_kernel_engages(tiny, monkeypatch):
+    """The tiny model with a state of whole register tiles (``[16,128]`` a
+    head), served twice by ``ContinuousBatcher``: with the one-pass kernel
+    (``ops.ssd_update``) interpreted, and with the XLA step. The same tokens;
+    a slot that never decodes keeps the state planted in it bit for bit; and
+    ``recurrent_updates_in_place_total`` counts dispatches x the chunk's steps
+    x the Mamba-2 layers with the kernel, 0 without."""
+    mc = family.model_config({**tiny[0], "mamba_d_state": 128}, "hybrid-tiny-n128")
+    params = tfm.init_params(jax.random.PRNGKey(SEED), mc)
+    params = {**params, "embed": {"embedding": params["embed"]["embedding"] * 0.02}}
+    prompts = [_tokens(n, 20 + i).tolist() for i, n in enumerate((40, 9))]
+    planted = jax.random.normal(jax.random.PRNGKey(1), (mc.n_ssm_layers, mc.ssm_heads, mc.ssm_head_dim, 128))
+
+    def serve(interpret):
+        monkeypatch.setattr(ssd_update, "INTERPRET_OFF_TPU", interpret)
+        engine = serving.ContinuousBatcher(params, mc, max_slots=3, max_len=128, compute_dtype=F32,
+                                           prefill_chunk=CHUNK, prefill_pad_to=PAD, chunk_steps=4)
+        ssm = engine._cache.layers["ssm"]
+        engine._cache = dataclasses.replace(engine._cache, layers={
+            **engine._cache.layers, "ssm": {**ssm, "ssm": ssm["ssm"].at[:, 2].set(planted)}})
+        dispatches, decode = [], engine._decode
+        engine._decode = lambda *a: dispatches.append(1) or decode(*a)
+        ids = [engine.submit(p, max_new_tokens=w) for p, w in zip(prompts, (11, 6))]
+        for _ in range(100):
+            engine.step()
+            if all(engine.result(i)["status"] == "done" for i in ids):
+                break
+        return ([engine.result(i)["tokens"] for i in ids], np.asarray(engine._cache.layers["ssm"]["ssm"][:, 2]),
+                engine.stats()["recurrent_updates_in_place_total"], len(dispatches))
+
+    tokens, kept, in_place, dispatches = serve(True)
+    assert [len(t) for t in tokens] == [11, 6] and dispatches >= 3
+    assert in_place == dispatches * 4 * mc.n_ssm_layers
+    assert np.array_equal(kept, np.asarray(planted))
+    xla_tokens, xla_kept, xla_in_place, _ = serve(False)
+    assert xla_tokens == tokens and xla_in_place == 0 and np.array_equal(xla_kept, np.asarray(planted))
 
 
 # (e) one kind of layer is the parent's single scan ----------------------------

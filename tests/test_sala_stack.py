@@ -41,7 +41,7 @@ from reference import minicpm_sala as ref  # noqa: E402
 from tpu_engine import layer_state, serving  # noqa: E402
 from tpu_engine.generate import forward_with_cache, init_cache  # noqa: E402
 from tpu_engine.models import transformer as tfm  # noqa: E402
-from tpu_engine.ops import sparse_block_attention  # noqa: E402
+from tpu_engine.ops import sparse_block_attention, ssd_update  # noqa: E402
 
 sparse_block_attention.INTERPRET_OFF_TPU = True  # these are the CPU's tests: the decode kernel is interpreted
 
@@ -371,6 +371,45 @@ def test_the_engine_serves_what_the_reference_would(tiny):
     # request 2 (45 + 30) crosses dense_len = 64 while it decodes; 100, 70 and 90 are past it throughout
     computed, sparse = st["decode_tokens_computed_total"], st["decode_tokens_sparse_total"]
     assert 0 < computed - sparse <= 64 - 45 + 4 and sparse >= 11 + 6 + 14 + (45 + 29 - 64)
+
+
+def test_the_engine_updates_the_lightning_state_in_place_where_the_kernel_engages(tiny, monkeypatch):
+    """The tiny model with lightning heads of 128 (a state of whole register
+    tiles), served twice by ``ContinuousBatcher``: with the one-pass kernel
+    (``ops.ssd_update``) interpreted, and with the XLA step. The same tokens;
+    a slot that never decodes keeps the state planted in it bit for bit; and
+    ``recurrent_updates_in_place_total`` counts dispatches x the chunk's steps
+    x the lightning layers with the kernel, 0 without."""
+    mc = family.model_config({**tiny[0], "lightning_head_dim": 128}, "sala-tiny-e128")
+    params = tfm.init_params(jax.random.PRNGKey(SEED), mc)
+    prompts = [_tokens(n, 20 + i).tolist() for i, n in enumerate((70, 9))]
+    n_lightning = layer_state.layer_counts(mc)["lightning"]
+    planted = jax.random.normal(jax.random.PRNGKey(1), (n_lightning, mc.lightning_heads, 128, 128))
+
+    def serve(interpret):
+        monkeypatch.setattr(ssd_update, "INTERPRET_OFF_TPU", interpret)
+        engine = serving.ContinuousBatcher(params, mc, max_slots=3, max_len=LANES, compute_dtype=F32,
+                                           prefill_chunk=2 * CHUNK, prefill_pad_to=PAD, chunk_steps=4)
+        state = engine._cache.layers["lightning"]["state"]
+        engine._cache = dataclasses.replace(engine._cache, layers={
+            **engine._cache.layers, "lightning": {"state": state.at[:, 2].set(planted)}})
+        dispatches, decode = [], engine._decode
+        engine._decode = lambda *a: dispatches.append(1) or decode(*a)
+        ids = [engine.submit(p, max_new_tokens=w) for p, w in zip(prompts, (11, 6))]
+        for _ in range(100):
+            engine.step()
+            if all(engine.result(i)["status"] == "done" for i in ids):
+                break
+        return ([engine.result(i)["tokens"] for i in ids],
+                np.asarray(engine._cache.layers["lightning"]["state"][:, 2]),
+                engine.stats()["recurrent_updates_in_place_total"], len(dispatches))
+
+    tokens, kept, in_place, dispatches = serve(True)
+    assert [len(t) for t in tokens] == [11, 6] and dispatches >= 3
+    assert in_place == dispatches * 4 * n_lightning
+    assert np.array_equal(kept, np.asarray(planted))
+    xla_tokens, xla_kept, xla_in_place, _ = serve(False)
+    assert xla_tokens == tokens and xla_in_place == 0 and np.array_equal(xla_kept, np.asarray(planted))
 
 
 @pytest.mark.parametrize("stack", ["sparse_and_lightning", "attention_only"])

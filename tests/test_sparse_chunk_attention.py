@@ -197,3 +197,41 @@ def test_both_kernels_compile_for_the_chip_at_the_long_document_cells_widths(one
     text = step.lower(sds((16, 2, 16, 128), bf16), sds((3, 16, 34816, 256), bf16), sds((3, 16, 34816, 256), bf16),
                       sds((16, 2, 64), i32), sds((), i32), sds((16,), i32)).compile().as_text()
     assert "sparse_block_attn" in text and "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("cell,L,B,H,P,N,per_head", [
+    ("granite-4.0-h-micro.serve-chat-burst", 18, 32, 64, 64, 128, False),
+    ("granite-4.0-h-small.serve-batch32", 9, 32, 128, 64, 128, False),
+    ("minicpm-sala.serve-longdoc", 9, 16, 32, 128, 128, True),
+])
+def test_the_recurrent_update_compiles_in_place_at_the_cells_shapes(one_chip, monkeypatch, cell, L, B, H, P, N, per_head):
+    """The one-pass decode update (``ops.ssd_update``, kept here with the other
+    compiles for the chip: one file, one worker, one load of the library) at
+    the three recurrent cells' shapes, the stack the donated carry of a scan
+    over the layers as ``scan_layers`` holds it: Mosaic takes the kernel, the
+    whole stack is aliased to the output, and the program's own temporaries
+    stay far under ONE layer's state, so no copy of the stack or of a slice of
+    it is made. A compile, not a run."""
+    from jax import lax
+
+    from tpu_engine.ops import ssd_update
+
+    monkeypatch.setattr(ssd_update, "on_tpu", lambda: True)  # the described chip: this process's devices are the CPU's
+    sds = lambda shape, dt=F32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    bf16, bc = jnp.bfloat16, ((L, B, H, N) if per_head else (L, B, N))
+
+    def walk(x, dt, A, Bm, Cm, state):
+        def layer(state, xs):
+            at, x, dt, Bm, Cm = xs
+            y, state = generate._ssd_step_at(x, dt, A, Bm, Cm, state, at)
+            return state, y
+
+        return lax.scan(layer, state, (jnp.arange(L, dtype=jnp.int32), x, dt, Bm, Cm))
+
+    compiled = jax.jit(walk, donate_argnums=(5,)).lower(
+        sds((L, B, H, P), bf16), sds((L, B, H)), sds((H,)), sds(bc, bf16), sds(bc, bf16),
+        sds((L, B, H, P, N))).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert "ssd_update" in text and "tpu_custom_call" in text
+    assert memory.alias_size_in_bytes == L * B * H * P * N * 4
+    assert memory.temp_size_in_bytes < B * H * P * N * 4 // 8

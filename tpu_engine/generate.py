@@ -59,7 +59,7 @@ from tpu_engine.models.transformer import (
     served_format,
     unembed,
 )
-from tpu_engine.ops import sparse_block_attention
+from tpu_engine.ops import sparse_block_attention, ssd_update
 from tpu_engine.quant import QuantWeight, dequantize_weight
 
 _NEG_INF = -1e30
@@ -425,24 +425,41 @@ def _ssd_step(x, dt, A, Bm, Cm, h):
     return jnp.sum(h * over_p(Cm), axis=-1), h
 
 
-def _ssm_mixer(u, lp, h, conv_state, valid, cfg: ModelConfig):
+def _ssd_step_at(x, dt, A, Bm, Cm, state, at):
+    """:func:`_ssd_step` of layer ``at`` of a WHOLE kind's stacked state
+    [L,B,H,P,N], written back into the stack. Where the one-pass kernel
+    engages (``ops.ssd_update.engages``: a float32 stack of whole ``[P,N]``
+    register tiles, on a TPU) it updates the stack's blocks of that layer where
+    they lie; anywhere else this is the XLA step on the layer's slice, which
+    stays the plain statement of what the kernel computes. Returns (y, state)."""
+    if ssd_update.engages(state):
+        return ssd_update.ssd_update(x, dt, A, Bm, Cm, state, at)
+    # the recurrence runs in float32 whatever the cache stores
+    y, h = _ssd_step(x, dt, A, Bm, Cm, layer_slice(state, at).astype(jnp.float32))
+    return y, lax.dynamic_update_index_in_dim(state, h.astype(state.dtype), at, 0)
+
+
+def _ssm_mixer(u, lp, ssm, conv_state, at, valid, cfg: ModelConfig):
     """The Mamba-2 mixer over ``u`` [B,T,D] (already normed), from and into
-    one layer's recurrent state: ``h`` [B,H,P,N] float32 and ``conv_state``
-    [B,taps-1,C]. ``valid`` [B,T] marks the real positions, a PREFIX of each
-    row (pad tokens after a prompt's end; a decode row that is not active):
-    a position that is not valid leaves both states exactly as they were —
-    ``dt = 0`` there, so the decay is 1 and nothing is added, and the
-    convolution state is taken at the row's true length. Outputs at such
-    positions are garbage the caller never reads.
+    layer ``at``'s recurrent state: its slice [B,H,P,N] of ``ssm``, the kind's
+    whole stack [L,B,H,P,N], rewritten in place under the scope of the step
+    that does it (``ssm_update`` for one token, ``ssm_scan`` for a chunk, so
+    that a profile charges the state's traffic to the mixer), and
+    ``conv_state`` [B,taps-1,C], the layer's own. ``valid`` [B,T] marks the
+    real positions, a PREFIX of each row (pad tokens after a prompt's end; a
+    decode row that is not active): a position that is not valid leaves both
+    states exactly as they were — ``dt = 0`` there, so the decay is 1 and
+    nothing is added, and the convolution state is taken at the row's true
+    length. Outputs at such positions are garbage the caller never reads.
 
     T = 1 is the decode update (one recurrence step, all float32
-    elementwise: it is bound by reading and writing ``h``); longer T runs
-    the chunked form ``cfg.ssm_chunk`` positions at a time."""
+    elementwise: it is bound by reading and writing the state;
+    :func:`_ssd_step_at`); longer T runs the chunked form ``cfg.ssm_chunk``
+    positions at a time. Returns (out, ssm, conv_state)."""
     B, T, _ = u.shape
     H, P, N, K = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_conv
     I, C = cfg.ssm_inner, cfg.ssm_conv_dim
     f32 = jnp.float32
-    h = h.astype(f32)  # the recurrence runs in float32 whatever the cache stores
 
     with jax.named_scope("ssm_in_proj"):
         zxbcdt = _proj(u, lp["in_proj"]["kernel"])               # [B,T,I+C+H]
@@ -471,11 +488,13 @@ def _ssm_mixer(u, lp, h, conv_state, valid, cfg: ModelConfig):
 
     if T == 1:
         with jax.named_scope("ssm_update"):
-            y, h = _ssd_step(x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], h)
+            y, ssm = _ssd_step_at(x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], ssm, at)
             y = y[:, None]
     else:
         with jax.named_scope("ssm_scan"):
-            y, h = _ssd_scan(x, dt, A, Bm, Cm, h, cfg.ssm_chunk)
+            # the recurrence runs in float32 whatever the cache stores
+            y, h = _ssd_scan(x, dt, A, Bm, Cm, layer_slice(ssm, at).astype(f32), cfg.ssm_chunk)
+            ssm = lax.dynamic_update_index_in_dim(ssm, h.astype(ssm.dtype), at, 0)
     y = y + lp["D"].astype(f32)[:, None] * x.astype(f32)
 
     with jax.named_scope("ssm_gate_norm"):
@@ -483,22 +502,19 @@ def _ssm_mixer(u, lp, h, conv_state, valid, cfg: ModelConfig):
         g = g * lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True) + cfg.norm_eps)
         o = (g * lp["gate_norm"]["scale"].astype(f32)).astype(u.dtype)
     with jax.named_scope("ssm_out_proj"):
-        return _proj(o, lp["out_proj"]["kernel"]), h, conv_state
+        return _proj(o, lp["out_proj"]["kernel"]), ssm, conv_state
 
 
 def _ssm_block(x, layer_params, ssm, conv, at, valid, cfg: ModelConfig, tally=None):
     """One Mamba-2 layer: the mixer where an attention layer attends, then
     the block every kind shares (:func:`_mlp_block`). ``ssm`` / ``conv`` are
     the whole per-kind state ([L_ssm, B, ...]); this layer reads and rewrites
-    its own slice, ``at``, in place, under the scope of the step that does it
-    (``ssm_update`` for one token, ``ssm_scan`` for a chunk), so that a profile
-    charges the state's traffic to the mixer. Returns (x, ssm, conv)."""
+    its own slice, ``at``, in place (the mixer that of ``ssm``).
+    Returns (x, ssm, conv)."""
     with jax.named_scope("ssm"):
         u = _norm(x, layer_params["ssm_norm"], cfg)
-        out, h, conv_state = _ssm_mixer(u, layer_params, layer_slice(ssm, at),
-                                        layer_slice(conv, at), valid, cfg)
-        with jax.named_scope("ssm_update" if x.shape[1] == 1 else "ssm_scan"):
-            ssm = lax.dynamic_update_index_in_dim(ssm, h.astype(ssm.dtype), at, 0)
+        out, ssm, conv_state = _ssm_mixer(u, layer_params, ssm, layer_slice(conv, at),
+                                          at, valid, cfg)
         with jax.named_scope("ssm_conv"):
             conv = lax.dynamic_update_index_in_dim(conv, conv_state.astype(conv.dtype), at, 0)
         x = _residual(x, out, cfg)
@@ -521,7 +537,7 @@ def _lightning_block(x, lp, state, at, positions, valid, cfg: ModelConfig, tally
     a head's ``S`` transposed (value x key, as a Mamba-2 state is [P, N]);
     this layer reads and rewrites its own slice, ``at``, under the scope of
     the step that does it: ``lightning_update`` for one token
-    (:func:`_ssd_step`), ``lightning_scan`` for a chunk (:func:`_ssd_scan`,
+    (:func:`_ssd_step_at`), ``lightning_scan`` for a chunk (:func:`_ssd_scan`,
     ``cfg.ssm_chunk`` positions at a time). ``valid`` [B,T] marks the real
     positions, a PREFIX of each row: a position that is not valid leaves
     the state exactly as it was. Returns (x, state)."""
@@ -545,13 +561,12 @@ def _lightning_block(x, lp, state, at, positions, valid, cfg: ModelConfig, tally
         A = -lp["decay"].astype(f32)                              # [H]
         dt = jnp.broadcast_to(valid.astype(f32)[..., None], (B, T, H))
         with jax.named_scope("lightning_update" if T == 1 else "lightning_scan"):
-            h = layer_slice(state, at)
             if T == 1:
-                o, h = _ssd_step(v[:, 0], dt[:, 0], A, k[:, 0], q[:, 0], h)
+                o, state = _ssd_step_at(v[:, 0], dt[:, 0], A, k[:, 0], q[:, 0], state, at)
                 o = o[:, None]
             else:
-                o, h = _ssd_scan(v, dt, A, k, q, h, cfg.ssm_chunk)
-            state = lax.dynamic_update_index_in_dim(state, h, at, 0)
+                o, h = _ssd_scan(v, dt, A, k, q, layer_slice(state, at), cfg.ssm_chunk)
+                state = lax.dynamic_update_index_in_dim(state, h, at, 0)
         with jax.named_scope("lightning_gate_norm"):
             o = o.reshape(B, T, H * E) * (E ** -0.5)
             o = o * lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True) + cfg.norm_eps)

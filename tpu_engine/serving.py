@@ -79,6 +79,7 @@ from tpu_engine.models.transformer import (
     unembed,
     weight_bytes_by_dtype,
 )
+from tpu_engine.ops import ssd_update
 from tpu_engine.profiler import StepProfiler
 
 
@@ -884,6 +885,14 @@ class ContinuousBatcher:
         # Recurrent state written into a slot by a finished prefill, and
         # zeroed when a slot is freed (hybrid stacks; else both stay 0).
         self._recurrent_state_bytes = self._cache.recurrent_state_bytes
+        # Layers of the whole kinds whose decode step the one-pass kernel
+        # takes (``ops.ssd_update``: decided where the program is traced, from
+        # the leaf and the device); 0 where the walk keeps the XLA step.
+        self._in_place_layers = sum(
+            leaf.shape[0] for kind, leaves in self._cache.layers.items()
+            if not layer_state.LAYER_KINDS[kind].positional
+            for leaf in leaves.values() if ssd_update.engages(leaf))
+        self._recurrent_updates_in_place = 0
         # A mixture's routing, by program: layer-steps run (counted here) and
         # ``generate.MOE_COUNTS`` (counted on the device, fetched with each
         # dispatch's tokens). Empty for a model without experts.
@@ -1246,8 +1255,11 @@ class ContinuousBatcher:
                 # The pool's whole kinds of state (Mamba-2, lightning; 0
                 # for attention-only stacks): their bytes, every slot's
                 # whether in use or not, and how often a slot's was
-                # written whole or zeroed.
+                # written whole or zeroed; and the decode layer-steps that
+                # updated it in place, in one pass (the kernel
+                # ``ops.ssd_update``; 0 where the walk keeps the XLA step).
                 "recurrent_state_bytes": self._recurrent_state_bytes,
+                "recurrent_updates_in_place_total": self._recurrent_updates_in_place,
                 "state_inserts_total": self._state_inserts,
                 "state_resets_total": self._state_resets,
                 # The weights the engine holds, by dtype, counted once at
@@ -1558,6 +1570,7 @@ class ContinuousBatcher:
                 n_take = None
         n_steps = toks_host.shape[1]
         self._decode_tokens_computed += len(active_reqs) * n_steps
+        self._recurrent_updates_in_place += n_steps * self._in_place_layers
         if self._sparse_from is not None:
             # A row's steps run at positions context - 1 .. context + n - 2.
             self._decode_tokens_sparse += sum(
