@@ -90,14 +90,21 @@ def test_a_configuration_builds_at_its_rehearsal_size(name):
         assert tfm.param_count(mc) > 0
         full = program.model_config(config, name)  # the published widths build too (no array is made)
         assert full.d_model == config["hidden_size"] and full.vocab_size == config["vocab_size"]
-        if "num_local_experts" in config["reduced"]:
-            # One chip's share of the experts: the router keeps the published
-            # width, the tree holds what the file keeps, at both sizes.
+        for held in {"num_local_experts", "n_routed_experts"} & set(config["reduced"]):
+            # One chip's share of the experts (under the key the family
+            # publishes them by): the router keeps the published width, the
+            # tree holds what the file keeps, at both sizes.
             for sized, cfg in ((full, config), (mc, small)):
-                assert sized.n_experts == cfg["published"]["num_local_experts"] > cfg["num_local_experts"]
-                assert sized.n_experts_held == cfg["num_local_experts"]
+                assert sized.n_experts == cfg["published"][held] > cfg[held]
+                assert sized.n_experts_held == cfg[held]
                 assert sized.top_k == cfg["num_experts_per_tok"] <= sized.n_experts
-        if "layer_types" not in config and "mixer_types" not in config:
+        if "kv_lora_rank" in config:
+            # Latent attention: the family lays the pattern out from
+            # ``first_k_dense_replace`` (the file has no ``layer_types`` key).
+            dense = small["first_k_dense_replace"]
+            assert mc.layer_types == ("mla_dense",) * dense + ("mla",) * (mc.n_layers - dense)
+            assert full.latent_width == config["kv_lora_rank"] + config["qk_rope_head_dim"]
+        elif "layer_types" not in config and "mixer_types" not in config:
             # A configuration without a layer pattern is untouched by the fields a
             # pattern brought (PR 27): each stays at its default, so its programs
             # are the ones it had.

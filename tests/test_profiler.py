@@ -179,8 +179,14 @@ def test_with_no_session_nothing_reads_the_threads_clock(monkeypatch):
     assert len(reads) == 2 + 2 * 2  # the iteration's two and each phase's
     s = prof.summary()
     assert s["phases"]["device"]["blocked_ms"]["mean"] >= 9
-    assert s["total"]["blocked_ms"]["mean"] == pytest.approx(s["total"]["p95_ms"], abs=2)  # one of four iterations
+    # Of the four iterations only the traced one is in ``blocked_ms``, and it
+    # slept nearly all of its own wall time (the thread's CPU time in it is well
+    # under 2 ms however loaded the machine: time descheduled is off the CPU
+    # too). Held against that iteration's own total, not the p95 of all four: a
+    # loaded machine (six workers) stretches another iteration's 10 ms sleep.
     assert len(prof._totals_blocked) == 1 and len(prof._totals) == 4
+    assert s["total"]["blocked_ms"]["mean"] == pytest.approx(prof._totals[-1] * 1e3, abs=2)
+    assert prof._totals_blocked[0] <= prof._totals[-1]
 
 
 def _yardstick(pc=time.perf_counter):

@@ -235,3 +235,56 @@ def test_the_recurrent_update_compiles_in_place_at_the_cells_shapes(one_chip, mo
     assert "ssd_update" in text and "tpu_custom_call" in text
     assert memory.alias_size_in_bytes == L * B * H * P * N * 4
     assert memory.temp_size_in_bytes < B * H * P * N * 4 // 8
+
+
+def test_the_latent_pool_is_written_in_place_at_the_long_context_cells_shapes(one_chip, monkeypatch):
+    """A latent-attention (MLA) decode chunk at ``kimi-vl-a3b.serve-longctx32``'s
+    widths and pool (32 slots x 10 240 lanes; three of its thirteen layers, to
+    keep the compile short), kept here with the other compiles for the chip.
+    The pool's rows are 640 wide (576 padded to the chip's tile columns): the
+    donated pool is aliased to the output and the program's temporaries stay
+    under HALF A LAYER's latent, so no layer of the pool is copied or laid out
+    anew. With rows of 576 the chip lays the lanes out as the minor dimension
+    and the same program transposes the whole pool there and back every
+    dispatch (PERF.md §6 PR 40). Mosaic takes the decode kernel
+    (``ops.mla_decode``) at these widths and the program holds it. A compile,
+    not a run."""
+    import json
+    from functools import partial
+
+    from tpu_engine import layer_state, serving
+    from tpu_engine.ops import mla_decode
+
+    monkeypatch.setattr(mla_decode, "on_tpu", lambda: True)  # the described chip: this process's devices are the CPU's
+
+    bench = os.path.join(ROOT, "benchmarks", "onchip")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from families import deepseek_v3
+
+    with open(os.path.join(bench, "configs", "kimi-vl-a3b-1chip-serve.json")) as f:
+        config = json.load(f)
+    mc = deepseek_v3.model_config({**config, "num_hidden_layers": 3}, "kimi-3-layers")
+    assert layer_state.latent_row_width(mc) == 640 and mc.latent_width == 576
+    put = lambda t: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)  # noqa: E731
+    bf16, B = jnp.bfloat16, 32
+    params = put(jax.eval_shape(lambda k: tfm.init_params(k, mc, dtype=bf16), jax.random.PRNGKey(0)))
+    pool = put(jax.eval_shape(lambda: serving.init_slot_cache(mc, B, 10240, bf16, prefill_chunk=2048)))
+    vec = lambda dt: jax.ShapeDtypeStruct((B,), dt, sharding=one_chip)  # noqa: E731
+    key = put(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    dec = jax.jit(partial(serving.decode_chunk, cfg=mc, n_steps=2, compute_dtype=bf16), donate_argnums=(2,))
+    compiled = dec.lower(params, vec(jnp.int32), pool, vec(jnp.bool_), vec(jnp.float32), vec(jnp.int32),
+                         vec(jnp.int32), key).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert "mla_decode" in text and "tpu_custom_call" in text
+    layer = B * 10240 * 640 * 2
+    assert memory.alias_size_in_bytes >= 3 * layer
+    assert memory.temp_size_in_bytes < layer // 2
+    # and a 2 048-token chunk against a staging row of 8 192 lanes holds the flash-style chunk kernel
+    from tpu_engine.generate import init_cache
+
+    row = put(jax.eval_shape(lambda: init_cache(mc, 1, 8192, dtype=bf16)))
+    pre = jax.jit(partial(serving._prefill_forward, cfg=mc, compute_dtype=bf16), donate_argnums=(2,))
+    text = pre.lower(params, jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=one_chip), row,
+                     jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile().as_text()
+    assert "mla_chunk_attn" in text

@@ -138,13 +138,14 @@ def extract_slot_kv(
     An already-quantized pool always ships codes + scales (dequantizing
     on extraction would add error AND bytes); a fp pool quantizes on the
     wire only when asked. The wire carries keys and values and nothing
-    else: a stack with recurrent layers is refused by name.
+    else: a stack with recurrent layers, or whose attention caches a
+    latent (MLA), is refused by name.
     """
     import jax.numpy as jnp  # local: keep module import engine-free
 
-    from tpu_engine.models.transformer import refuse_recurrent
+    from tpu_engine.models.transformer import refuse_beyond_kv
 
-    refuse_recurrent(cfg, "the KV handoff wire (extract_slot_kv)")
+    refuse_beyond_kv(cfg, "the KV handoff wire (extract_slot_kv)")
     if getattr(cache, "ring", False):
         raise ValueError("extract_slot_kv does not support ring pools")
     # The attention kind's leaves, one row's resident lanes: [L, T, KV, HD].

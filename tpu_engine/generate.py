@@ -28,7 +28,13 @@ bit-identical logits between :func:`forward` and prefill+decode; MoE models
 can differ wherever training-time dispatch dropped a token.
 
 One block follows every kind of layer's mixer (:func:`_mlp_block`): a dense
-MLP, or that mixture with its shared expert beside it.
+MLP, or that mixture with its shared expert beside it (a mixture's leading
+"mla_dense" layers keep a dense one).
+
+A latent-attention (MLA) layer (:func:`_mla_block`) caches neither keys nor
+values but one latent row a token, and attends it by two paths: a chunk
+expands the row's lanes to every head's keys and values, decode contracts
+its queries against the latent rows as they lie (the absorbed form).
 """
 
 from __future__ import annotations
@@ -54,12 +60,13 @@ from tpu_engine.models.transformer import (
     attention_scale,
     check_hybrid,
     embed_tokens,
+    refuse_beyond_kv,
     refuse_recurrent,
     require_served_format,
     served_format,
     unembed,
 )
-from tpu_engine.ops import sparse_block_attention, ssd_update
+from tpu_engine.ops import mla_decode, sparse_block_attention, ssd_update
 from tpu_engine.quant import QuantWeight, dequantize_weight
 
 _NEG_INF = -1e30
@@ -156,32 +163,52 @@ def init_moe_counts(cfg: ModelConfig) -> Optional[jax.Array]:
     return jnp.zeros((len(MOE_COUNTS),), jnp.int32) if cfg.is_moe else None
 
 
+def _route(h, layer_params, cfg: ModelConfig):
+    """The router over ALL ``n_experts`` in float32: (experts [B, T, K], gates
+    [B, T, K] float32). ``softmax``: the ``top_k`` largest probabilities,
+    renormalised to sum to 1. ``sigmoid``: per-expert scores; the choice is
+    ``top_k(score + router_bias)``, the gates are the chosen scores WITHOUT
+    the bias, renormalised to sum to 1 and times ``routed_scale``."""
+    K = cfg.top_k
+    logits = jnp.einsum("btd,de->bte", h, layer_params["router"]["kernel"],
+                        preferred_element_type=jnp.float32)
+    if cfg.router_scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, top_idx = lax.top_k(scores + layer_params["router_bias"], K)
+        top_vals = jnp.take_along_axis(scores, top_idx, axis=-1)
+        top_vals = top_vals / (jnp.sum(top_vals, -1, keepdims=True) + 1e-20) * cfg.routed_scale
+        return top_idx, top_vals
+    probs = jax.nn.softmax(logits, axis=-1)  # [B, T, E] fp32
+    # Top-k gates, renormalised to sum to 1 (matches training's combine).
+    top_vals, top_idx = lax.top_k(probs, K)  # [B, T, K]
+    return top_idx, top_vals / jnp.maximum(jnp.sum(top_vals, -1, keepdims=True), 1e-9)
+
+
 def _moe_mlp_decode(h, layer_params, cfg: ModelConfig, valid):
     """Exact top-k mixture for the cached walks: every token reaches those of
     its chosen experts that this tree holds (no capacity buffer — see module
     docstring). h: [B, T, D] → ([B, T, D], :data:`MOE_COUNTS` of this layer).
 
-    The router scores all ``n_experts`` in float32 and keeps ``top_k`` of
-    them, gates renormalised to sum to 1 over the kept. The held experts
-    (``experts_first`` on, ``n_experts_held`` of them) run for the T new
-    positions, a token's gate (0 for an expert it did not choose) folded into
-    its activations, and one contraction over expert and width together
-    brings them down: nothing of ``[B, T, E, D]`` is written. What an absent
-    expert would have added is left out; a token none of whose experts is held
-    gets the shared expert alone. The shared expert (``shared_d_ff``) is a
-    SwiGLU of its own width for every token. ``valid`` [B, T] marks the real
-    positions, which alone are counted.
+    The router (:func:`_route`) scores all ``n_experts`` in float32 and keeps
+    ``top_k`` of them. The held experts (``experts_first`` on,
+    ``n_experts_held`` of them) run for the T new positions, a token's gate (0
+    for an expert it did not choose) folded into its activations, and one
+    contraction over expert and width together brings them down: nothing of
+    ``[B, T, E, D]`` is written. What an absent expert would have added is
+    left out; a token none of whose experts is held gets the shared expert
+    alone. The shared expert (``shared_d_ff``) is a SwiGLU of its own width for
+    every token. ``valid`` [B, T] marks the real positions, which alone are
+    counted.
+
+    Every held expert is computed for every token (MASKED). A grouped form for
+    long chunks (pairs sorted by expert, ``lax.ragged_dot``) was measured on
+    the chip at 2 048 tokens, 6 of 64 experts a token, 16 held, and lost to
+    this one (ROADMAP M1 (b), PERF.md §6 PR 40: the grouped kernel computes
+    every row it is handed, the absent experts' pairs among them).
     """
     K, first, held = cfg.top_k, cfg.experts_first, cfg.n_experts_held
     with jax.named_scope("moe_router"):
-        router_logits = jnp.einsum(
-            "btd,de->bte", h, layer_params["router"]["kernel"],
-            preferred_element_type=jnp.float32,
-        )
-        probs = jax.nn.softmax(router_logits, axis=-1)  # [B, T, E] fp32
-        # Top-k gates, renormalised to sum to 1 (matches training's combine).
-        top_vals, top_idx = lax.top_k(probs, K)  # [B, T, K]
-        top_vals = top_vals / jnp.maximum(jnp.sum(top_vals, -1, keepdims=True), 1e-9)
+        top_idx, top_vals = _route(h, layer_params, cfg)
         chosen = top_idx[..., None] == first + jnp.arange(held)  # [B, T, K, held]
         weights = jnp.sum(jnp.where(chosen, top_vals[..., None], 0.0), axis=2)  # [B, T, held]
         live = jnp.any(chosen, axis=2) & valid[..., None]
@@ -213,14 +240,17 @@ def _moe_mlp_decode(h, layer_params, cfg: ModelConfig, valid):
     return out, counts
 
 
-def _mlp_block(x, layer_params, cfg: ModelConfig, valid=None, tally: Optional[list] = None):
+def _mlp_block(x, layer_params, cfg: ModelConfig, valid=None, tally: Optional[list] = None,
+               dense: bool = False):
     """THE block after the mixer, for every kind of layer: ``x`` plus the
-    (residual-scaled) dense MLP of its norm, or a mixture's routed experts and
+    (residual-scaled) dense MLP of its norm — a stack without experts, and a
+    mixture's ``dense`` layers (the leading "mla_dense" ones, whose leaves are
+    a dense SwiGLU's) — or a mixture's routed experts and
     shared expert (:func:`_moe_mlp_decode`, under scope ``moe``). ``valid``
     [B, T] marks the real positions (None: all). A mixture appends its
     layer's :data:`MOE_COUNTS` to ``tally``, the list the caller that counts
     hands in (:func:`scan_layers`; traced values, read in the same trace)."""
-    if not cfg.is_moe:
+    if dense or not cfg.is_moe:
         with jax.named_scope("mlp"):
             return _residual(x, _dense_mlp(_norm(x, layer_params["mlp_norm"], cfg),
                                            layer_params, cfg=cfg), cfg)
@@ -752,6 +782,128 @@ def _sparse_attn_block(x, lp, k_pool, v_pool, ck_pool, at, write, positions, val
     return _mlp_block(x, lp, cfg, valid, tally), k_pool, v_pool, ck_pool
 
 
+# ---------------------------------------------------------------------------
+# Latent attention (MLA) layers
+# ---------------------------------------------------------------------------
+
+# The latent's own RMSNorm: the published modelling code builds it with its
+# norm class's default epsilon, not with the configuration's ``rms_norm_eps``.
+_LATENT_NORM_EPS = 1e-6
+
+# Queries a chunk's expanded attention scores at a time (float32, for every
+# head against every lane of the row).
+MLA_QUERY_BLOCK = 512
+
+
+def _mla_block(x, lp, latent, at, write, slot_pos, positions, valid, cfg: ModelConfig,
+               tally=None, dense: bool = False):
+    """One latent-attention layer, then its block (:func:`_mlp_block`; the
+    dense SwiGLU where ``dense``).
+
+    ``q = h W_q`` per head ``(q_n [nope], q_r [rope])``; ``(c_raw, k_raw) = h
+    W_kva``; the cache row of a token is ``RMSNorm(c_raw) | RoPE(k_raw)``
+    (``kv_latent_dim`` + ``qk_rope_dim`` values, one rotated key for all
+    heads), written by ``write`` into layer ``at`` of ``latent``, the kind's
+    whole leaf [L, B, M, latent + rope + zero padding], under scope
+    ``mla_latent``. With ``W_kvb = (W_k, W_v)`` per head the scores are ``(q_n .
+    (c W_k) + q_r . k_r) / sqrt(nope + rope)`` and the output ``softmax(s) (c
+    W_v)``, by one of two paths that give the same numbers:
+
+    - EXPANDED, a chunk (T > 1): the row's lanes go through ``W_kvb`` once
+      (``mla_expand``) and the chunk's queries attend the keys and values so
+      built (``mla_attend``): on a TPU, for whole tiles, through the flash-style
+      kernel ``ops.mla_decode.mla_chunk_attend``; anywhere else in XLA,
+      ``MLA_QUERY_BLOCK`` queries at a time against every lane of the row;
+    - ABSORBED, decode (T = 1): ``q_a = q_n W_k^T`` (``mla_absorb``), one
+      contraction of ``q_a | q_r`` against the latent rows as they lie, the
+      probabilities times the same rows (``mla_attend``: the latent is read for
+      all heads at once and nothing is expanded), and ``W_v`` on the way out.
+      On a TPU the two contractions are the kernel ``ops.mla_decode`` (one pass
+      over the lanes a slot has); anywhere else XLA's, over every lane, which
+      stay the plain statement of what the kernel computes.
+
+    ``slot_pos`` / ``positions`` mask as in :func:`_decode_block`.
+    Returns (x, latent)."""
+    B, T, _ = x.shape
+    H, N, R, V, C = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim, cfg.kv_latent_dim
+    scale = attention_scale(cfg)
+    with jax.named_scope("mla"):
+        h = _norm(x, lp["attn_norm"], cfg)
+        q = _proj(h, lp["q"]["kernel"]).reshape(B, T, H, N + R)
+        q_n, q_r = q[..., :N], _rope(q[..., N:], positions, cfg.rope_theta)
+        with jax.named_scope("mla_latent"):
+            kva = _proj(h, lp["kv_a"]["kernel"])                      # [B, T, C + R]
+            c = _rms_norm(kva[..., :C], lp["kv_norm"]["scale"], _LATENT_NORM_EPS)
+            k_r = _rope(kva[:, :, None, C:], positions, cfg.rope_theta)[:, :, 0]
+            pad = jnp.zeros((B, T, latent.shape[-1] - C - R), c.dtype)   # the row's zero padding
+            latent = write(latent, jnp.concatenate([c, k_r, pad], axis=-1), at)
+        w_kvb = lp["kv_b"]["kernel"]
+        if isinstance(w_kvb, QuantWeight):
+            w_kvb = dequantize_weight(w_kvb, x.dtype)
+        w_kvb = w_kvb.reshape(C, H, N + V)
+        key_pos = slot_pos if slot_pos.ndim == 2 else slot_pos[None, :]  # [B|1, M]
+
+        def masked_probs(s, pos):  # s [B, H, t, M] float32, pos [B, t]
+            kp = key_pos[:, None, :]
+            mask = (kp >= 0) & (kp <= pos[:, :, None])
+            s = jnp.where(mask[:, None], s * scale, _NEG_INF)
+            return jax.nn.softmax(s, axis=-1).astype(x.dtype)
+
+        if T == 1:
+            with jax.named_scope("mla_absorb"):
+                q_a = jnp.einsum("bthn,chn->bthc", q_n, w_kvb[..., :N])
+                q_cat = jnp.concatenate(                             # [B, 1, H, C + R + pad]
+                    [q_a, q_r, jnp.zeros((B, T, H, pad.shape[-1]), q_a.dtype)], axis=-1)
+            with jax.named_scope("mla_attend"):
+                if mla_decode.engages(latent):
+                    # one pass over the lanes a slot has (lane m holds position
+                    # m: no latent stack has a window, so no pool of them wraps)
+                    o_lat = mla_decode.mla_decode(q_cat[:, 0], latent, at, positions[:, 0] + 1,
+                                                  scale=scale)[:, None, :, :C]
+                else:
+                    rows = layer_slice(latent, at)                    # [B, M, C + R + pad]
+                    s = jnp.einsum("bthc,bmc->bhtm", q_cat, rows, preferred_element_type=jnp.float32)
+                    # over the whole row (the rotated key and the padding ride along:
+                    # a slice of ``rows`` would be a copy of the layer's lanes)
+                    o_lat = jnp.einsum("bhtm,bmc->bthc", masked_probs(s, positions), rows)[..., :C]
+            with jax.named_scope("mla_absorb"):
+                o = jnp.einsum("bthc,chv->bthv", o_lat, w_kvb[..., N:])
+        elif mla_decode.chunk_engages(T, latent.shape[2]):
+            with jax.named_scope("mla_expand"):
+                rows = layer_slice(latent, at)                        # [B, M, C + R + pad]
+                kv = jnp.einsum("bmc,chd->bhmd", rows[..., :C], w_kvb)  # head-major: a head's lanes lie together
+                k_rot = jnp.broadcast_to(rows[:, None, :, C:C + R], (B, H, rows.shape[1], R))
+                k = jnp.concatenate([kv[..., :N], k_rot], axis=-1)    # [B, H, M, N + R]
+            with jax.named_scope("mla_attend"):
+                qh = jnp.concatenate([q_n, q_r], axis=-1).transpose(0, 2, 1, 3)   # [B, H, T, N + R]
+                # flash-style over the key blocks a query tile can see (lane m
+                # holds position m, as for the decode kernel)
+                o = mla_decode.mla_chunk_attend(qh, k, kv[..., N:], positions[:, 0],
+                                                scale=scale).transpose(0, 2, 1, 3)
+        else:
+            with jax.named_scope("mla_expand"):
+                rows = layer_slice(latent, at)                        # [B, M, C + R + pad]
+                kv = jnp.einsum("bmc,chd->bmhd", rows[..., :C], w_kvb)  # [B, M, H, N + V]
+                k_n, v, k_rot = kv[..., :N], kv[..., N:], rows[..., C:C + R]
+
+            def attend(xs):
+                qn, qr, pos = xs                                      # [B, t, H, N], [B, t, H, R], [B, t]
+                s = jnp.einsum("bthn,bmhn->bhtm", qn, k_n, preferred_element_type=jnp.float32) \
+                    + jnp.einsum("bthr,bmr->bhtm", qr, k_rot, preferred_element_type=jnp.float32)
+                return jnp.einsum("bhtm,bmhv->bthv", masked_probs(s, pos), v)
+
+            with jax.named_scope("mla_attend"):
+                Tq = min(MLA_QUERY_BLOCK, T)
+                if T == Tq:
+                    o = attend((q_n, q_r, positions))
+                else:
+                    # the last query block's padding repeats its last query
+                    o = lax.map(attend, tuple(_time_blocks(a, Tq, "edge") for a in (q_n, q_r, positions)))
+                    o = jnp.moveaxis(o, 0, 1).reshape(B, -1, H, V)[:, :T]
+        x = _residual(x, _proj(o.reshape(B, T, H * V), lp["o"]["kernel"]), cfg)
+    return _mlp_block(x, lp, cfg, valid, tally, dense=dense), latent
+
+
 def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
                 valid=None):
     """Walk the stack against (and into) ``cache`` — THE one cached walk, for
@@ -814,8 +966,14 @@ def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
         x, state = _lightning_block(x, lp, s["state"], at, positions, valid, cfg, tally)
         return x, {"state": state}
 
+    def mla_layer(x, lp, at, s, tally, dense=False):
+        x, latent = _mla_block(x, lp, s["latent"], at, write, slot_pos, positions, valid,
+                               cfg, tally, dense)
+        return x, {"latent": latent}
+
     layer_fns = {"attn": attn_layer, "ssm": ssm_layer,
-                 "sparse_attn": sparse_attn_layer, "lightning": lightning_layer}
+                 "sparse_attn": sparse_attn_layer, "lightning": lightning_layer,
+                 "mla": mla_layer, "mla_dense": partial(mla_layer, dense=True)}
     state, counts = cache.layers, cache.moe_counts
     for kind, first, count in cfg.layer_runs():
 
@@ -1125,7 +1283,7 @@ def speculative_generate(
     passes taken (a perfect draft needs ceil(N / (gamma+1))).
     """
     for c in (cfg, draft_cfg):  # the rewind is a length; a recurrent state has none
-        refuse_recurrent(c, "speculative decoding (speculative_generate)")
+        refuse_beyond_kv(c, "speculative decoding (speculative_generate)")
     if prompt.shape[0] != 1:
         raise ValueError("speculative_generate supports batch size 1")
     if gamma < 1:

@@ -438,7 +438,7 @@ def estimate_serving_hbm(
     serving submission to capacity-only admission, same as training.
     """
     from tpu_engine import layer_state
-    from tpu_engine.generate import ring_lanes
+    from tpu_engine.generate import MLA_QUERY_BLOCK, ring_lanes
     from tpu_engine.models import transformer as tfm
 
     cfg = tfm.MODEL_CONFIGS.get(model_name)
@@ -490,6 +490,12 @@ def estimate_serving_hbm(
             f"recurrent state: {named} x {slots} slots (float32, every slot's "
             f"whatever the occupancy); keys and values for the "
             f"{sum(counts.values()) - sum(whole.values())} attention layers only")
+    latent_layers = cfg.n_latent_layers
+    if latent_layers:
+        notes.append(
+            f"latent cache: {latent_layers} latent-attention layers x {slots} slots x {lanes} lanes x "
+            f"{layer_state.latent_row_width(cfg)} values (latent {cfg.kv_latent_dim} | rotated key "
+            f"{cfg.qk_rope_dim}, padded to the chip's 128-value tile columns): neither keys nor values")
     if prefix_cache_tokens > 0:
         # Shared-prefix entries are extra lanes outside the slot pool,
         # bounded by the token budget (eviction enforces it).
@@ -503,6 +509,13 @@ def estimate_serving_hbm(
     # the KV pool, is the pool's dominant transient.
     chunk = max(int(prefill_chunk), 1)
     working = chunk * (4 * cfg.d_model + 2 * cfg.d_ff) * compute_b / tp
+    if latent_layers:
+        # A chunk's expanded attention: every lane of the staging row through
+        # kv_b (keys and values of all heads), and one query block's float32
+        # scores and compute-dtype probabilities against them
+        # (``generate.MLA_QUERY_BLOCK``).
+        working += (lanes * cfg.n_heads * (cfg.qk_nope_dim + cfg.v_head_dim) * compute_b
+                    + cfg.n_heads * min(chunk, MLA_QUERY_BLOCK) * lanes * (4 + compute_b)) / tp
     if pool_role == "prefill":
         working *= 2
         notes.append(

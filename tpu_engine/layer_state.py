@@ -98,11 +98,44 @@ def _lightning_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
                            cfg.lightning_head_dim), jnp.float32)}
 
 
+# A latent row is padded with zeros to a whole number of the chip's 128-value
+# tile columns.
+LATENT_PAD_TO = 128
+
+
+def latent_row_width(cfg) -> int:
+    """Values a latent-attention layer's cache row holds: ``cfg.latent_width``
+    (latent | rotated key) rounded up to :data:`LATENT_PAD_TO`."""
+    return -(-cfg.latent_width // LATENT_PAD_TO) * LATENT_PAD_TO
+
+
+def _latent_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
+    """A latent-attention (MLA) layer's one leaf: per lane the normalised
+    latent (``kv_latent_dim``) and, beside it in the same row, the one rotated
+    key all heads share (``qk_rope_dim``) — neither keys nor values; every
+    head's are expanded from it, or never built (the absorbed decode). ONE
+    leaf, not two: a decode step writes one row a layer and its scores are one
+    contraction over the whole row. The row is padded with zeros to a multiple
+    of 128 values (576 -> 640): the chip tiles a bf16 array (8, 128), and left
+    at 576 it lays the LANES out as the minor dimension to save the padding,
+    which turns every dispatch's one-row writes into a transposition of the
+    whole pool there and back (two 4.6 GiB copies and as much scratch, in the
+    compiled decode program of the benchmark's cell). Two leaves (512 | 64 ->
+    128) would pad to the same 640."""
+    if kv_quant:
+        raise NotImplementedError("latent-attention (MLA) layers keep no int8 latent (kv_quant)")
+    return {"latent": Leaf((lanes, latent_row_width(cfg)), dtype)}
+
+
 LAYER_KINDS: dict[str, LayerKind] = {
     "attn": LayerKind(positional=True, leaves=_attn_leaves, label="attention"),
     "ssm": LayerKind(positional=False, leaves=_ssm_leaves, label="Mamba-2"),
     "sparse_attn": LayerKind(positional=True, leaves=_sparse_attn_leaves, label="sparse-attention"),
     "lightning": LayerKind(positional=False, leaves=_lightning_leaves, label="lightning"),
+    # the same leaf under two kinds: a stack's leading layers (dense block)
+    # and the rest (mixture) are stacked, scanned and cached apart
+    "mla": LayerKind(positional=True, leaves=_latent_leaves, label="latent-attention"),
+    "mla_dense": LayerKind(positional=True, leaves=_latent_leaves, label="latent-attention"),
 }
 
 
@@ -192,6 +225,12 @@ def whole_state_bytes(layers: dict) -> int:
     """Bytes the whole kinds hold, every row's."""
     return sum(a.size * a.dtype.itemsize for kind, leaves in layers.items()
                if not LAYER_KINDS[kind].positional for a in leaves.values())
+
+
+def latent_bytes(layers: dict) -> int:
+    """Bytes the latent-attention kinds' leaves hold, every row's and lane's."""
+    return sum(a.size * a.dtype.itemsize for kind, leaves in layers.items()
+               if LAYER_KINDS[kind].leaves is _latent_leaves for a in leaves.values())
 
 
 # What a cache manager does to a row.

@@ -40,7 +40,7 @@ from tpu_engine.quant_train import int8_einsum
 # A pattern's names for its layers -> the kind each is stacked, scanned and
 # cached under (``params["layers"][kind]``, ``layer_state.LAYER_KINDS[kind]``).
 LAYER_TYPE_KINDS = {"attention": "attn", "mamba": "ssm", "lightning": "lightning",
-                    "sparse_attention": "sparse_attn"}
+                    "sparse_attention": "sparse_attn", "mla": "mla", "mla_dense": "mla_dense"}
 
 
 @dataclass(frozen=True)
@@ -170,6 +170,28 @@ class ModelConfig:
     residual_scale: float = 1.0
     logits_divisor: float = 1.0
     attn_scale: float = 0.0
+    # Latent attention (MLA; layer types "mla" and "mla_dense"): ``n_heads``
+    # query heads of ``qk_nope_dim`` unrotated + ``qk_rope_dim`` rotated values
+    # (``head_dim_override`` is their sum, which scales the scores); a token's
+    # keys and values are ONE latent of ``kv_latent_dim`` (RMS-normed) beside
+    # one rotated key of ``qk_rope_dim`` shared by all heads, which is all the
+    # cache holds; ``kv_b`` expands the latent to every head's
+    # ``qk_nope_dim`` key and ``v_head_dim`` value. An "mla" layer's block is
+    # the stack's (the mixture, with ``n_experts``); an "mla_dense" layer's is
+    # one dense SwiGLU of width ``dense_d_ff`` (the published recipe's leading
+    # layers).
+    kv_latent_dim: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    dense_d_ff: int = 0
+    # The router's scores: "softmax" (over all experts, the kept renormalised
+    # to 1) or "sigmoid" (per expert; the ``top_k`` are chosen by score PLUS
+    # the per-expert ``router_bias`` leaf, the gates are the chosen scores
+    # WITHOUT it, renormalised to 1 and times ``routed_scale``).
+    router_scoring: str = "softmax"
+    routed_scale: float = 1.0
+    router_bias_std: float = 0.0  # what init_params draws the bias from (training moves it)
     # ``rope=False``: no positional rotation of q and k (position comes from
     # causality, or from the recurrent layers). ``tie_head``: the LM head is
     # the token embedding, outside the gpt2/gemma families too.
@@ -211,6 +233,16 @@ class ModelConfig:
         return tuple(i for i, t in zip(idx, self.layer_types) if t == layer_type)
 
     @property
+    def n_latent_layers(self) -> int:
+        """Latent-attention (MLA) layers, whichever block follows them."""
+        return self.n_layers_of("mla") + self.n_layers_of("mla_dense")
+
+    @property
+    def latent_width(self) -> int:
+        """What a latent-attention layer caches per token: latent | rotated key."""
+        return self.kv_latent_dim + self.qk_rope_dim
+
+    @property
     def ssm_inner(self) -> int:
         return self.ssm_heads * self.ssm_head_dim
 
@@ -238,6 +270,11 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def n_mixture_layers(self) -> int:
+        """Layers whose block is the mixture: all but the "mla_dense" ones."""
+        return (self.n_layers - self.n_layers_of("mla_dense")) if self.is_moe else 0
 
     @property
     def n_experts_held(self) -> int:
@@ -282,6 +319,32 @@ def refuse_recurrent(cfg: "ModelConfig", feature: str) -> None:
         raise RecurrentLayersUnsupported(feature, cfg)
 
 
+class LatentCacheUnsupported(NotImplementedError):
+    """A feature whose wire or rewind carries keys and values only was asked
+    of a model whose attention layers cache a latent (MLA). Raised by name:
+    the latent has a lane per position, and neither per-head keys nor values."""
+
+    def __init__(self, feature: str, cfg: "ModelConfig"):
+        self.feature = feature
+        super().__init__(
+            f"{feature} does not support model {cfg.name!r}: its latent-attention (MLA) "
+            f"layers cache one latent of {cfg.latent_width} values a token, not keys and values")
+
+
+def refuse_latent(cfg: "ModelConfig", feature: str) -> None:
+    """Raise :class:`LatentCacheUnsupported` when ``cfg``'s stack has
+    latent-attention layers (a geometry stand-in without a pattern has none)."""
+    if getattr(cfg, "n_latent_layers", 0):
+        raise LatentCacheUnsupported(feature, cfg)
+
+
+def refuse_beyond_kv(cfg: "ModelConfig", feature: str) -> None:
+    """For a feature whose wire or rewind carries keys and values and nothing
+    else: :func:`refuse_recurrent`, then :func:`refuse_latent`."""
+    refuse_recurrent(cfg, feature)
+    refuse_latent(cfg, feature)
+
+
 def refuse_hybrid_mixture(cfg: "ModelConfig", feature: str) -> None:
     """A mixture of experts after a hybrid stack's mixers is served only,
     whether or not its mixers keep a whole state (:func:`refuse_recurrent`
@@ -293,22 +356,25 @@ def refuse_hybrid_mixture(cfg: "ModelConfig", feature: str) -> None:
 
 
 def refuse_recurrent_model(model_name: str, feature: str) -> None:
-    """:func:`refuse_recurrent` for a registered model's name (fleet-level
+    """:func:`refuse_beyond_kv` (every caller's wire carries keys and values
+    only) for a registered model's name (fleet-level
     planes know a spec's ``model_name``, not its config); an unknown name
     passes, as it does everywhere a fleet degrades to capacity-only."""
     cfg = MODEL_CONFIGS.get(model_name)
     if cfg is not None:
-        refuse_recurrent(cfg, feature)
+        refuse_beyond_kv(cfg, feature)
 
 
 def check_hybrid(cfg: "ModelConfig") -> None:
     """What a hybrid pattern can be today, checked where parameters or a
     cache are built (trace time, free)."""
-    if cfg.experts_first or cfg.experts_held or cfg.shared_d_ff:
+    if cfg.experts_first or cfg.experts_held or cfg.shared_d_ff \
+            or cfg.router_scoring != "softmax" or cfg.routed_scale != 1.0:
         if not (cfg.is_hybrid and cfg.is_moe):
             raise ValueError(
-                "a share of the experts (experts_first, experts_held) and a shared expert "
-                "(shared_d_ff) are a hybrid mixture's: they need layer_types and n_experts "
+                "a share of the experts (experts_first, experts_held), a shared expert "
+                "(shared_d_ff) and a sigmoid router (router_scoring, routed_scale) are a "
+                "hybrid mixture's: they need layer_types and n_experts "
                 f"(n_experts={cfg.n_experts}, layer_types={cfg.layer_types!r})")
         if cfg.experts_first < 0 or cfg.experts_first + cfg.n_experts_held > cfg.n_experts:
             raise ValueError(
@@ -331,6 +397,16 @@ def check_hybrid(cfg: "ModelConfig") -> None:
         )
     if cfg.is_moe and not 1 <= cfg.top_k <= cfg.n_experts:
         raise ValueError(f"top_k={cfg.top_k} experts a token of n_experts={cfg.n_experts}")
+    if cfg.router_scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"router_scoring={cfg.router_scoring!r}: 'softmax' or 'sigmoid'")
+    if cfg.n_latent_layers:
+        dims = (cfg.kv_latent_dim, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim)
+        if min(dims) < 1 or cfg.qk_rope_dim % 2 or cfg.head_dim != cfg.qk_nope_dim + cfg.qk_rope_dim:
+            raise ValueError(
+                "a latent-attention layer needs kv_latent_dim, qk_nope_dim, v_head_dim >= 1, an even "
+                f"qk_rope_dim and head_dim = qk_nope_dim + qk_rope_dim (got {dims}, head_dim={cfg.head_dim})")
+        if "mla_dense" in cfg.layer_types and cfg.dense_d_ff < 1:
+            raise ValueError("an 'mla_dense' layer needs dense_d_ff, its dense SwiGLU's width")
     depth = cfg.published_layers or cfg.n_layers
     if cfg.layer_indices and (len(cfg.layer_indices) != cfg.n_layers
                               or not all(0 <= i < depth for i in cfg.layer_indices)):
@@ -632,7 +708,9 @@ def _init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype, deferred: bool 
 
     stacks = {"attn": attn, "ssm": ssm,
               "sparse_attn": partial(_init_sparse_attn_stack, rng, cfg, dtype, deferred),
-              "lightning": partial(_init_lightning_stack, rng, cfg, dtype, deferred)}
+              "lightning": partial(_init_lightning_stack, rng, cfg, dtype, deferred),
+              "mla": partial(_init_mla_stack, rng, cfg, dtype, deferred, "mla"),
+              "mla_dense": partial(_init_mla_stack, rng, cfg, dtype, deferred, "mla_dense")}
     kinds = dict.fromkeys(kind for kind, _, _ in cfg.layer_runs())
     out = {
         "embed": {"embedding": norm(ks[0], (V, D), std / cfg.embed_scale)},
@@ -670,7 +748,8 @@ def _draw_experts(key, s, *, n: int, n_experts: int, first: int, held: int, shap
     return lax.map(layer, jax.random.split(key, n))
 
 
-def _mixer_mlp_stack(draw, keys, n: int, cfg: ModelConfig, dtype, deferred: bool = False) -> dict:
+def _mixer_mlp_stack(draw, keys, n: int, cfg: ModelConfig, dtype, deferred: bool = False,
+                     depth: int = 0) -> dict:
     """What every kind of layer has after its mixer, ``n`` layers stacked
     (keys: gate, up, down; ``draw`` as its caller's): the norm and the SwiGLU
     MLP, or with ``cfg.n_experts`` the mixture — ``router`` ``[n, D, E]``
@@ -678,9 +757,12 @@ def _mixer_mlp_stack(draw, keys, n: int, cfg: ModelConfig, dtype, deferred: bool
     F]`` and ``down`` ``[n, held, F, D]`` (:func:`_draw_experts`, from the
     same three keys), and where ``cfg.shared_d_ff`` the shared expert's
     ``shared_gate`` / ``shared_up`` / ``shared_down``. The router's key is
-    ``fold_in(keys[0], 1)``, the shared expert's ``fold_in(keys[j], 2)``."""
+    ``fold_in(keys[0], 1)``, the shared expert's ``fold_in(keys[j], 2)``; a
+    sigmoid router's ``router_bias`` ``[n, E]`` (float32, normal of
+    ``router_bias_std``) comes from ``fold_in(keys[0], 3)``. Output
+    projections are drawn / sqrt(2 ``depth``) (0: the layers kept)."""
     D, F = cfg.d_model, cfg.d_ff
-    res_std = 0.02 / (2 * cfg.n_layers) ** 0.5
+    res_std = 0.02 / (2 * (depth or cfg.n_layers)) ** 0.5
     out = {"mlp_norm": {"scale": jnp.ones((n, D), dtype)}}
     if not cfg.is_moe:
         return {**out,
@@ -695,6 +777,9 @@ def _mixer_mlp_stack(draw, keys, n: int, cfg: ModelConfig, dtype, deferred: bool
         "up": {"kernel": experts(keys[1], 0.02, shape=(D, F))},
         "down": {"kernel": experts(keys[2], res_std, shape=(F, D))},
     })
+    if cfg.router_scoring == "sigmoid":
+        out["router_bias"] = _drawn(deferred, _draw_layers, jax.random.fold_in(keys[0], 3),
+                                    cfg.router_bias_std, n=n, shape=(cfg.n_experts,), dtype=jnp.float32)
     if cfg.shared_d_ff:
         S = cfg.shared_d_ff
         shared = [jax.random.fold_in(k, 2) for k in keys[:3]]
@@ -767,12 +852,41 @@ def _init_lightning_stack(rng, cfg: ModelConfig, dtype, deferred: bool = False) 
     }
 
 
+def _init_mla_stack(rng, cfg: ModelConfig, dtype, deferred: bool, layer_type: str) -> dict:
+    """The latent-attention layers of ``layer_type`` ("mla": the stack's block
+    after the mixer, a mixture where it has experts; "mla_dense": one dense
+    SwiGLU of width ``dense_d_ff``): ``q`` ``[D, H (nope + rope)]``, ``kv_a``
+    ``[D, latent + rope]`` (the latent and the one shared rotary key),
+    ``kv_norm`` over the latent, ``kv_b`` ``[latent, H (nope + v)]`` (per head
+    its key's unrotated part, then its value), ``o`` ``[H v, D]``. Keys:
+    ``split(fold_in(rng, 103 | 104), 7)`` in the order q, kv_a, kv_b, o, gate,
+    up, down; each leaf's layer i from its own ``split(key, n)[i]``
+    (:func:`_draw_layers`); output projections / sqrt(2 x published depth)."""
+    n = cfg.n_layers_of(layer_type)
+    D, H, C, R = cfg.d_model, cfg.n_heads, cfg.kv_latent_dim, cfg.qk_rope_dim
+    ks = jax.random.split(jax.random.fold_in(rng, 103 if layer_type == "mla" else 104), 7)
+    depth = cfg.published_layers or cfg.n_layers
+    res_std = 0.02 / (2 * depth) ** 0.5
+    draw = partial(_drawn, deferred, _draw_layers, n=n, dtype=dtype)
+    block = cfg if layer_type == "mla" else cfg.with_(n_experts=0, d_ff=cfg.dense_d_ff)
+    return {
+        "attn_norm": {"scale": jnp.ones((n, D), dtype)},
+        "q": {"kernel": draw(ks[0], 0.02, shape=(D, H * (cfg.qk_nope_dim + R)))},
+        "kv_a": {"kernel": draw(ks[1], 0.02, shape=(D, C + R))},
+        "kv_norm": {"scale": jnp.ones((n, C), dtype)},
+        "kv_b": {"kernel": draw(ks[2], 0.02, shape=(C, H * (cfg.qk_nope_dim + cfg.v_head_dim)))},
+        "o": {"kernel": draw(ks[3], res_std, shape=(H * cfg.v_head_dim, D))},
+        **_mixer_mlp_stack(draw, ks[4:], n, block, dtype, deferred, depth),
+    }
+
+
 # Recurrence leaves of a Mamba-2 layer, and with a lightning layer's per-head
 # rates all that stays float32 wherever the rest of the tree goes to a compute
 # dtype (:func:`served_format`, :func:`cast_layer_stack`): a bf16 ``A_log``
-# moves every decay.
+# moves every decay, and a sigmoid router's selection bias is added to
+# float32 scores.
 SSM_FLOAT32_LEAVES = ("A_log", "dt_bias", "D")
-FLOAT32_LEAVES = SSM_FLOAT32_LEAVES + ("decay",)
+FLOAT32_LEAVES = SSM_FLOAT32_LEAVES + ("decay", "router_bias")
 
 
 def _mlp_axes(cfg: ModelConfig) -> dict[str, Any]:
@@ -791,6 +905,8 @@ def _mlp_axes(cfg: ModelConfig) -> dict[str, Any]:
         "up": {"kernel": ("layers", "expert", "embed", "mlp")},
         "down": {"kernel": ("layers", "expert", "mlp", "embed")},
     })
+    if cfg.router_scoring == "sigmoid":
+        out["router_bias"] = ("layers", None)
     if cfg.shared_d_ff:
         out.update({"shared_" + name: axes for name, axes in dense.items()})
     return out
@@ -809,7 +925,18 @@ def logical_axes(cfg: ModelConfig) -> dict[str, Any]:
             "k_norm": {"scale": ("layers", None)},
             **mlp_axes,
         }
+        mla_axes = {
+            "attn_norm": {"scale": ("layers", "embed")},
+            "q": {"kernel": ("layers", "embed", "heads")},
+            # the latent is one for all heads: its projection and norm stay whole
+            "kv_a": {"kernel": ("layers", "embed", None)},
+            "kv_norm": {"scale": ("layers", None)},
+            "kv_b": {"kernel": ("layers", None, "heads")},
+            "o": {"kernel": ("layers", "heads", "embed")},
+        }
         stacks = {
+            "mla": {**mla_axes, **mlp_axes},
+            "mla_dense": {**mla_axes, **_mlp_axes(cfg.with_(n_experts=0))},
             "sparse_attn": {
                 "k": {"kernel": ("layers", "embed", "kv_heads")},
                 "v": {"kernel": ("layers", "embed", "kv_heads")},
@@ -927,9 +1054,17 @@ def param_count(cfg: ModelConfig) -> int:
         per_sparse = 2 * D * H * HD + 2 * D * KV * HD + H * HD * D + 2 * HD + mlp + 2 * D
         LI, LHD = cfg.lightning_inner, cfg.lightning_head_dim
         per_lightning = 5 * D * LI + 2 * LHD + LI + cfg.lightning_heads + mlp + 2 * D
+        # q, kv_a, the latent's norm, kv_b, o and the layer's two norms; then
+        # the stack's block (with a sigmoid router's bias) or the dense SwiGLU.
+        C, R = cfg.kv_latent_dim, cfg.qk_rope_dim
+        mla = (D * H * HD + D * (C + R) + C + C * H * (cfg.qk_nope_dim + cfg.v_head_dim)
+               + H * cfg.v_head_dim * D + 2 * D)
+        bias = cfg.n_experts if cfg.router_scoring == "sigmoid" else 0
         return (V * D + cfg.n_attn_layers * per_layer + cfg.n_ssm_layers * per_ssm
                 + cfg.n_layers_of("sparse_attention") * per_sparse
-                + cfg.n_layers_of("lightning") * per_lightning + D + head)
+                + cfg.n_layers_of("lightning") * per_lightning
+                + cfg.n_layers_of("mla") * (mla + mlp + bias)
+                + cfg.n_layers_of("mla_dense") * (mla + 3 * D * cfg.dense_d_ff) + D + head)
     return V * D + L * per_layer + D + head
 
 
@@ -938,7 +1073,7 @@ def active_param_count(cfg: ModelConfig) -> int:
     only for MoE — the honest N for FLOPs accounting)."""
     if not cfg.is_moe:
         return param_count(cfg)
-    L, D, F = cfg.n_layers, cfg.d_model, cfg.d_ff
+    L, D, F = cfg.n_mixture_layers, cfg.d_model, cfg.d_ff
     inactive_experts = cfg.n_experts_held - cfg.top_k
     return param_count(cfg) - L * 3 * D * F * inactive_experts
 
@@ -1638,7 +1773,7 @@ def forward_hidden_and_aux(
     B, S = tokens.shape
     # The cache-less forward (training, evaluation) scans one kind of layer;
     # a hybrid's prefill is generate.forward_with_cache.
-    refuse_recurrent(cfg, "the cache-less forward pass (training and evaluation)")
+    refuse_beyond_kv(cfg, "the cache-less forward pass (training and evaluation)")
     refuse_hybrid_mixture(cfg, "the cache-less forward pass (training and evaluation)")
     if cfg.arch == "gpt2" and S > cfg.max_seq_len:
         # Learned position table: jnp.take would silently clamp out-of-range

@@ -98,6 +98,9 @@ _QUANT_LAYER_KEYS = ("q", "k", "v", "o", "gate", "up", "down", "fc", "proj",
                      "in_proj", "out_proj",
                      # the output gate of its lightning and sparse-attention layers
                      "o_gate",
+                     # a latent-attention (MLA) layer's down- and up-projection
+                     # of keys and values
+                     "kv_a", "kv_b",
                      # the shared expert beside a hybrid's mixture (its routed
                      # experts are ``gate`` / ``up`` / ``down``, stacked)
                      "shared_gate", "shared_up", "shared_down")

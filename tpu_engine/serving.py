@@ -74,6 +74,7 @@ from tpu_engine.models.transformer import (
     ModelConfig,
     check_hybrid,
     embed_tokens,
+    refuse_beyond_kv,
     refuse_recurrent,
     served_format,
     unembed,
@@ -326,7 +327,7 @@ def decode_verify(
     keys and values and impossible for a recurrent state. The pool is
     carried through the layer walk as in :func:`decode_step`; a layer's
     ``[B, T]`` rows are one scatter into its lanes of it."""
-    refuse_recurrent(cfg, "the speculative verify pass (decode_verify)")
+    refuse_beyond_kv(cfg, "the speculative verify pass (decode_verify)")
     B, T = tokens.shape
     S = cache.n_lanes
     rows = jnp.arange(B)
@@ -700,9 +701,9 @@ class ContinuousBatcher:
         if prefix_cache_tokens:
             refuse_recurrent(cfg, "the prompt-prefix cache (prefix_cache_tokens)")
         if draft_params is not None:
-            refuse_recurrent(cfg, "speculative serving (draft_params)")
+            refuse_beyond_kv(cfg, "speculative serving (draft_params)")
             if draft_cfg is not None:
-                refuse_recurrent(draft_cfg, "speculative serving (as the draft)")
+                refuse_beyond_kv(draft_cfg, "speculative serving (as the draft)")
         self._cache = init_slot_cache(
             cfg, self.max_slots, self.max_len, compute_dtype,
             prefill_chunk=self.prefill_chunk, kv_quant=self.kv_quant,
@@ -885,6 +886,8 @@ class ContinuousBatcher:
         # Recurrent state written into a slot by a finished prefill, and
         # zeroed when a slot is freed (hybrid stacks; else both stay 0).
         self._recurrent_state_bytes = self._cache.recurrent_state_bytes
+        # The pool's latent-attention (MLA) leaves: every slot's every lane.
+        self._latent_cache_bytes = layer_state.latent_bytes(self._cache.layers)
         # Layers of the whole kinds whose decode step the one-pass kernel
         # takes (``ops.ssd_update``: decided where the program is traced, from
         # the leaf and the device); 0 where the walk keeps the XLA step.
@@ -928,7 +931,7 @@ class ContinuousBatcher:
                 "sampling"
             )
         if hold_kv:
-            refuse_recurrent(self.cfg, "hold_kv (the KV handoff plane)")
+            refuse_beyond_kv(self.cfg, "hold_kv (the KV handoff plane)")
         if hold_kv and self._cache.ring:
             raise ValueError(
                 "hold_kv does not support sliding-window models (ring lanes "
@@ -972,7 +975,7 @@ class ContinuousBatcher:
         bounds the tokens THIS engine adds."""
         if self.last_error is not None:
             raise RuntimeError(f"serving loop failed: {self.last_error}")
-        refuse_recurrent(self.cfg, "submit_prefilled (the KV handoff wire)")
+        refuse_beyond_kv(self.cfg, "submit_prefilled (the KV handoff wire)")
         if self._cache.ring:
             raise ValueError(
                 "submit_prefilled does not support sliding-window pools"
@@ -1259,6 +1262,9 @@ class ContinuousBatcher:
                 # updated it in place, in one pass (the kernel
                 # ``ops.ssd_update``; 0 where the walk keeps the XLA step).
                 "recurrent_state_bytes": self._recurrent_state_bytes,
+                # What the latent-attention (MLA) layers cache, every slot's
+                # every lane (0 for a stack that has none).
+                "latent_cache_bytes": self._latent_cache_bytes,
                 "recurrent_updates_in_place_total": self._recurrent_updates_in_place,
                 "state_inserts_total": self._state_inserts,
                 "state_resets_total": self._state_resets,
@@ -1653,7 +1659,7 @@ class ContinuousBatcher:
         if counts is None:
             return
         mine = self._moe_counts[program]
-        mine["layer_steps"] += steps * self.cfg.n_layers
+        mine["layer_steps"] += steps * self.cfg.n_mixture_layers
         for name, n in zip(MOE_COUNTS, counts.tolist()):
             mine[name] += n
 

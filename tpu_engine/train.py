@@ -378,7 +378,7 @@ def build_train_program(
         model_cfg = tfm.MODEL_CONFIGS[cfg.model_name]
     # A hybrid (Mamba-2 + attention) stack is served only: the backward of
     # the chunked scan and packed documents are not written.
-    tfm.refuse_recurrent(model_cfg, "training (build_train_program)")
+    tfm.refuse_beyond_kv(model_cfg, "training (build_train_program)")
     tfm.refuse_hybrid_mixture(model_cfg, "training (build_train_program)")
     if runtime is None:
         runtime = MeshRuntime(cfg.mesh)
