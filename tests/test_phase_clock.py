@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from tpu_engine import profiler, tracing
+from tpu_engine.mesh_runtime import MeshConfig
 from tpu_engine.models import transformer as tfm
 from tpu_engine.serving import BATCHER_PHASES, ContinuousBatcher
 from tpu_engine.serving_fleet import REQUEST_STAGES, build_replica_engine
@@ -23,6 +24,7 @@ from tests.test_serving_fleet import (  # noqa: F401 — sched_factory is a fixt
     small_spec,
     wait_until,
 )
+from tests.test_scheduler import cfg as train_cfg
 from tests.test_tracing import tiny_config
 
 
@@ -226,9 +228,12 @@ def test_the_replicas_wait_is_phase_idle_and_says_whether_work_was_pending(sched
 
 def test_a_cpu_trace_shows_the_pump_on_its_own_thread(sched_factory, tmp_path):
     """In a ``jax.profiler`` trace ``tpu_ctl.scheduler.pass`` lies on the
-    ``fleet-scheduler`` thread's line with ``thread=``, ``queued=`` and
-    ``running=``; a phase of a loop on another thread carries ``blocked_us=``,
-    and no ``tpu_engine.*`` annotation shares the pump's line."""
+    ``fleet-scheduler`` thread's line with ``thread=``, ``queued=``,
+    ``running=`` and ``sampled=``: 1 on the pass that admits a job, whose
+    fleet sample lies inside it, 0 on the passes beside the running job,
+    which take none; a phase of a loop on another thread carries
+    ``blocked_us=``, and no ``tpu_engine.*`` annotation shares the pump's
+    line."""
     import glob
 
     import jax.profiler
@@ -241,7 +246,7 @@ def test_a_cpu_trace_shows_the_pump_on_its_own_thread(sched_factory, tmp_path):
     opts.python_tracer_level, opts.host_tracer_level = 0, 2
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
-        s._ensure_thread()
+        sub = s.submit(train_cfg())  # starts the pump; its first pass admits
         for _ in range(3):
             prof.begin_step()
             with prof.phase("stage", with_prefill=0):
@@ -251,6 +256,8 @@ def test_a_cpu_trace_shows_the_pump_on_its_own_thread(sched_factory, tmp_path):
         jax.profiler.stop_trace()
     stats = s.stats()
     assert stats["poll_passes_total"] > before and stats["poll_pass_seconds_total"] > 0
+    assert sub.state.value == "running"
+    assert 1 <= stats["fleet_samples_total"] < stats["poll_passes_total"]
     (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
     lines = [[(ev.name, dict(ev.stats)) for ev in ln.events if ev.name.startswith(("tpu_ctl.", "tpu_engine."))]
              for plane in ProfileData.from_file(path).planes if plane.name.startswith("/host:")
@@ -264,7 +271,15 @@ def test_a_cpu_trace_shows_the_pump_on_its_own_thread(sched_factory, tmp_path):
         for name, args in pump:
             assert args["thread"] == "fleet-scheduler"
             if name == "tpu_ctl.scheduler.pass":
-                assert {"queued", "running"} <= set(args)
+                assert {"queued", "running", "sampled"} <= set(args)
+        passes = [args for name, args in pump if name == "tpu_ctl.scheduler.pass"]
+        assert {a["sampled"] for a in passes} <= {0, 1}
+        # one fleet sample for each pass that says it took one, and for no other
+        assert sum(a["sampled"] for a in passes) == sum(
+            name == "tpu_ctl.manager.fleet_status" for name, _ in pump)
+    ours = [a for pump in pumps for name, a in pump if name == "tpu_ctl.scheduler.pass"]
+    assert any(a["sampled"] == 1 for a in ours) and any(
+        a["sampled"] == 0 and a["running"] == 1 for a in ours)
     (loop,) = [evs for evs in lines if any(name == "tpu_engine.batcher.stage" for name, _ in evs)]
     stage = [args for name, args in loop if name == "tpu_engine.batcher.stage"]
     assert len(stage) == 3
@@ -299,3 +314,53 @@ def test_the_schedulers_passes_are_counted_exactly_under_threads(sched_factory):
     after = s.stats()
     assert after["poll_passes_total"] == before["poll_passes_total"] + n_threads * n_each
     assert 0 < after["poll_pass_seconds_total"] - before["poll_pass_seconds_total"] <= sum(seen)
+
+
+def test_the_schedulers_samples_never_exceed_its_passes_under_threads(sched_factory):
+    """``fleet_samples_total`` counts the passes that called ``fleet_fn``:
+    under concurrent ``poll()`` it equals the calls, one a pass at most, and
+    no lock-free reader of ``stats()`` ever sees it above
+    ``poll_passes_total``, even when every pass samples (a queued head with
+    a free slot that the fleet refuses is retried with a fresh sample)."""
+    calls = []
+
+    def fleet_fn():
+        calls.append(threading.current_thread().name)
+        return mock_fleet_fn()
+
+    s = sched_factory(max_concurrent_jobs=1, fleet_fn=fleet_fn)
+    s._ensure_thread = lambda: None  # the test's threads are the only pumps
+    head = s.submit(train_cfg(mesh=MeshConfig(data=2, fsdp=4)))  # 8 > the mock's 7 healthy
+    n_threads, n_each = 6, 20
+    stop = threading.Event()
+    torn = []
+
+    def reader():
+        while not stop.is_set():
+            st = s.stats()
+            if st["fleet_samples_total"] > st["poll_passes_total"]:
+                torn.append(st)
+
+    start = threading.Barrier(n_threads)
+
+    def worker():
+        start.wait(timeout=30)
+        for _ in range(n_each):
+            s.poll()
+
+    watch = threading.Thread(target=reader)
+    watch.start()
+    threads = [threading.Thread(target=worker) for _ in range(n_threads)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    stop.set()
+    watch.join(timeout=10)
+    assert not any(t.is_alive() for t in threads) and not watch.is_alive()
+    st = s.stats()
+    assert not torn
+    assert head.state.value == "queued" and "healthy chip" in head.last_skip_reason
+    assert st["poll_passes_total"] == n_threads * n_each
+    assert st["fleet_samples_total"] == len(calls) == n_threads * n_each
+    assert len(set(calls)) > 1  # more than one thread ran a sampling pass

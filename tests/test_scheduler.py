@@ -1057,3 +1057,226 @@ def test_metrics_scrape_is_readonly_and_index_backed(sched_factory):
         q.submission_id for q in queued
     ]
     assert len(qs["finished"]) == 12
+
+
+# ---------------------------------------------------------------------------
+# A pass samples the fleet lazily and at most once: only when a decision of
+# that pass depends on it
+# ---------------------------------------------------------------------------
+
+
+class CountingFleet:
+    """A ``fleet_fn`` that counts its calls; ``fleet`` is what it returns
+    (swap it to heal or degrade the fleet) and ``broken`` makes it raise."""
+
+    def __init__(self, fleet=None):
+        self.fleet = fleet if fleet is not None else _healthy_fleet()
+        self.calls = 0
+        self.broken = False
+
+    def __call__(self):
+        self.calls += 1
+        if self.broken:
+            raise RuntimeError("telemetry source down")
+        return self.fleet
+
+
+def _hand_pumped(sched_factory, fleet, **kw):
+    """A scheduler whose only passes are the test's own ``poll()`` calls (no
+    pump thread), so that samples can be counted pass by pass."""
+    s = sched_factory(fleet_fn=fleet, **kw)
+    s._ensure_thread = lambda: None
+    return s
+
+
+def _one_pass(s, fleet):
+    """Run one pass; returns the samples it took, by the caller's count and
+    by the scheduler's own."""
+    calls, counted = fleet.calls, s.stats()["fleet_samples_total"]
+    s.poll()
+    took = fleet.calls - calls
+    assert s.stats()["fleet_samples_total"] - counted == took
+    return took
+
+
+def _steady_one_job_empty_queue(s):
+    s.submit(cfg())
+    return None
+
+
+def _head_behind_equal_priority(s):
+    s.submit(cfg(), priority=JobPriority.NORMAL)
+    s.poll()
+    return s.submit(cfg(), priority=JobPriority.NORMAL)
+
+
+def _head_behind_non_preemptible(s):
+    # No checkpoint_dir → no emergency-save path → nobody to evict.
+    s.submit(cfg(checkpoint_dir=None), priority=JobPriority.LOW)
+    s.poll()
+    return s.submit(cfg(), priority=JobPriority.CRITICAL)
+
+
+def _head_behind_an_eviction_in_flight(s):
+    low = [s.submit(cfg(), priority=JobPriority.LOW) for _ in range(2)]
+    s.poll()
+    s._set_state(low[0], SubmissionState.PREEMPTING)  # its save has not landed
+    return s.submit(cfg(), priority=JobPriority.HIGH)
+
+
+@pytest.mark.parametrize("arrange, slots", [
+    (_steady_one_job_empty_queue, 1),
+    (_head_behind_equal_priority, 1),
+    (_head_behind_non_preemptible, 1),
+    (_head_behind_an_eviction_in_flight, 2),
+], ids=lambda v: v.__name__.lstrip("_") if callable(v) else None)
+def test_a_pass_with_nothing_to_decide_takes_no_sample(sched_factory, arrange, slots):
+    """One job beside an empty queue (every benchmark cell's steady state),
+    and a queued head at ``max_concurrent_jobs`` that no eviction can help:
+    N passes, no call of ``fleet_fn``; the head keeps its skip reason."""
+    fleet = CountingFleet()
+    s = _hand_pumped(sched_factory, fleet, max_concurrent_jobs=slots)
+    head = arrange(s)
+    s.poll()  # admits what fits (that pass samples: admission is the decision)
+    settled = s.stats()
+    assert settled["running"] == slots and settled["fleet_samples_total"] >= 1
+    calls = fleet.calls
+    for _ in range(10):
+        assert _one_pass(s, fleet) == 0
+    after = s.stats()
+    assert fleet.calls == calls
+    assert after["fleet_samples_total"] - settled["fleet_samples_total"] == 0
+    assert after["poll_passes_total"] - settled["poll_passes_total"] == 10
+    assert after["preemptions_total"] == settled["preemptions_total"]
+    assert all(j.is_alive and not j.watcher.fired.is_set() for j in s._stub_jobs)
+    if head is not None:
+        assert head.state == SubmissionState.QUEUED
+        assert head.last_skip_reason == "at max_concurrent_jobs capacity"
+
+
+@pytest.mark.parametrize("broken", [False, True], ids=["sampled", "fleet_fn_raises"])
+def test_a_head_at_capacity_samples_only_once_it_has_a_victim(sched_factory, broken):
+    """A lower-priority preemptible job runs: now whether the head could be
+    placed at all decides the eviction, so the pass samples (once) and the
+    victim goes PREEMPTING. A ``fleet_fn`` that raises on that lazy first
+    use degrades the pass to capacity-only, which evicts too."""
+    fleet = CountingFleet()
+    s = _hand_pumped(sched_factory, fleet, max_concurrent_jobs=1)
+    low = s.submit(cfg(), priority=JobPriority.LOW)
+    s.poll()
+    assert low.state == SubmissionState.RUNNING
+    fleet.broken = broken
+    high = s.submit(cfg(), priority=JobPriority.HIGH)
+    assert _one_pass(s, fleet) == 1
+    assert low.state == SubmissionState.PREEMPTING
+    assert low.job.watcher.fired.is_set()
+    assert high.last_skip_reason == "at max_concurrent_jobs capacity"
+    assert s.preemptions_total == 1
+    # The eviction in flight is fleet-free knowledge: no sample while it lands.
+    low.job.join(timeout=5.0)
+    fleet.broken = False
+    assert _one_pass(s, fleet) == 1  # reap → requeue → a free slot → admission
+    assert high.state == SubmissionState.RUNNING and low.state == SubmissionState.QUEUED
+
+
+def test_a_victim_is_not_evicted_for_a_head_the_fleet_can_never_place(sched_factory):
+    """The sample a victim triggers still protects it: a head whose gang
+    exceeds the healthy fleet evicts nobody, pass after pass."""
+    fleet = CountingFleet()
+    s = _hand_pumped(sched_factory, fleet, max_concurrent_jobs=1)
+    low = s.submit(cfg(), priority=JobPriority.LOW)
+    s.poll()
+    fleet.fleet = _degraded_fleet()  # 7 healthy chips
+    head = s.submit(cfg(mesh=MeshConfig(data=4, fsdp=2)), priority=JobPriority.HIGH)
+    for _ in range(3):
+        assert _one_pass(s, fleet) == 1
+    assert low.state == SubmissionState.RUNNING and s.preemptions_total == 0
+    assert head.last_skip_reason == "at max_concurrent_jobs capacity"
+
+
+def test_a_shrunk_job_is_sampled_for_only_past_its_cooldown(sched_factory):
+    """Grow-back asks who could grow before it asks the fleet: a shrunk job
+    inside ``grow_back_cooldown_s`` costs no sample; past it the pass
+    samples, and the healed fleet grows the job back."""
+    fleet = CountingFleet(_degraded_fleet())
+    s = _hand_pumped(
+        sched_factory, fleet, max_concurrent_jobs=1, grow_back_cooldown_s=3600.0,
+        precompile_before_grow=False,
+    )
+    sub = s.submit(elastic_cfg())
+    assert _one_pass(s, fleet) == 1
+    assert sub.state == SubmissionState.RUNNING and sub.admitted_gang == 6
+    fleet.fleet = _healthy_fleet()
+    for _ in range(5):
+        assert _one_pass(s, fleet) == 0
+    assert s.stats()["grow_backs_total"] == 0
+    s.grow_back_cooldown_s = 0.0
+    assert _one_pass(s, fleet) == 1
+    assert s.stats()["grow_backs_total"] == 1
+    assert sub.state == SubmissionState.PREEMPTING
+    sub.job.join(timeout=5.0)
+    assert _one_pass(s, fleet) == 1  # reap → requeue → admission at the full gang
+    assert sub.state == SubmissionState.RUNNING and sub.admitted_gang == 8
+
+
+def test_a_pass_that_admits_and_grows_takes_exactly_one_sample(sched_factory):
+    """Admission and grow-back of one pass read the same sample."""
+    fleet = CountingFleet(_degraded_fleet())
+    s = _hand_pumped(
+        sched_factory, fleet, max_concurrent_jobs=2, precompile_before_grow=False,
+    )
+    shrunk = s.submit(elastic_cfg())
+    s.poll()
+    assert shrunk.admitted_gang == 6
+    fleet.fleet = _healthy_fleet()
+    s.drain()  # hold every decision until both are due in one pass
+    late = s.submit(cfg())
+    assert _one_pass(s, fleet) == 0  # a draining pass reaps and decides nothing
+    s.resume_admission()
+    admitted, grown = s.admitted_total, s.grow_backs_total
+    assert _one_pass(s, fleet) == 1
+    assert late.state == SubmissionState.RUNNING
+    assert s.admitted_total == admitted + 1 and s.grow_backs_total == grown + 1
+    assert shrunk.state == SubmissionState.PREEMPTING
+
+
+def test_healing_a_quarantine_shares_the_pass_sample(sched_factory):
+    """Two quarantined chips whose running owner has a tracker: the heal
+    asks the fleet's size once for both, not once an entry."""
+    fleet = CountingFleet()
+    s = _hand_pumped(sched_factory, fleet, max_concurrent_jobs=1)
+    sub = s.submit(cfg())
+    s.poll()
+    s._stub_jobs[0]._hetero = _slow_rebalancer()
+    now = time.time()
+    for idx in (0, 7):
+        s._hetero_quarantined[idx] = {"owner": sub.submission_id, "ts": now}
+    assert _one_pass(s, fleet) == 1
+    assert 0 not in s._hetero_quarantined and 7 in s._hetero_quarantined
+
+
+def test_callers_outside_a_pass_sample_when_they_ask(sched_factory):
+    """``fleet_hbm_utilization`` (the telemetry's caller) and ``_fleet`` (the
+    launcher's plan) are no pass: each call is a fresh sample, none is a
+    pass's, and one from another thread while a pass is open is not the
+    pass's either."""
+    fleet = CountingFleet()
+    s = _hand_pumped(sched_factory, fleet, max_concurrent_jobs=1)
+    assert s.fleet_hbm_utilization() is not None and s._fleet() is fleet.fleet
+    assert fleet.calls == 2 and s.stats()["fleet_samples_total"] == 0
+
+    seen = []
+    s.submit(cfg())
+
+    def during_the_pass():
+        fleet.calls += 1
+        if threading.current_thread() is threading.main_thread():
+            t = threading.Thread(target=lambda: seen.append(s._fleet()))
+            t.start()
+            t.join(timeout=5.0)
+        return fleet.fleet
+
+    s.fleet_fn = during_the_pass
+    s.poll()  # admission samples; the other thread's call goes to fleet_fn itself
+    assert fleet.calls == 4 and seen == [fleet.fleet]
+    assert s.stats()["fleet_samples_total"] == 1 and s.stats()["poll_passes_total"] == 1

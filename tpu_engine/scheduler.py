@@ -90,6 +90,10 @@ TERMINAL_STATES = frozenset(
 # implicit in the exposition.
 WAIT_BUCKETS_S = (0.1, 0.5, 1.0, 5.0, 15.0, 60.0, 300.0, 1800.0)
 
+# A pass's fleet view before any decision of the pass has asked for it (None
+# is a sample too: no fleet source, or one that failed).
+_UNSAMPLED = object()
+
 
 def _observe_hist(hist: dict[float, int], value: float) -> None:
     for b in WAIT_BUCKETS_S:
@@ -279,6 +283,17 @@ class FleetScheduler:
     or chips with no HBM telemetry (``hbm_total_gb == 0`` — the CPU backend)
     degrade admission to capacity-only, never to a refusal: missing
     telemetry must not brick the queue.
+
+    A scheduling pass (:meth:`poll`) calls ``fleet_fn`` lazily and at most
+    once: at the first decision of that pass whose outcome depends on the
+    fleet, and every later use in the pass reads the same sample. Each
+    decision asks its fleet-free questions first, so a pass with nothing to
+    place, evict for, shed or grow — one job running beside an empty queue,
+    or a queue behind a full scheduler with nobody to evict — takes no
+    sample at all (``stats()["fleet_samples_total"]`` beside
+    ``["poll_passes_total"]``). Callers outside a pass
+    (:meth:`fleet_hbm_utilization`, the launcher's plan, the recovery
+    audit) sample when they ask.
     """
 
     def __init__(
@@ -417,6 +432,7 @@ class FleetScheduler:
         self.no_estimate_skips_total = 0
         self.poll_passes_total = 0  # poll() passes and the host seconds they took
         self.poll_pass_seconds_total = 0.0
+        self.fleet_samples_total = 0  # passes that called fleet_fn (at most once each)
         self.precompiles_started_total = 0
         self.grow_back_warm_total = 0
         self.grow_back_cold_total = 0
@@ -470,6 +486,12 @@ class FleetScheduler:
         # attached, every state-changing event below is written ahead so a
         # crashed scheduler host can be reconstructed with restore().
         self._journal: Optional[journal_mod.ControlPlaneJournal] = None
+
+        # The open pass's fleet view (set and cleared by poll() under the
+        # lock): the thread that runs the pass, and its one sample once a
+        # decision has asked for it.
+        self._pass_thread: Optional[int] = None
+        self._pass_fleet: Any = _UNSAMPLED
 
         self._shutdown = threading.Event()
         self._wake = threading.Event()
@@ -729,19 +751,35 @@ class FleetScheduler:
         ``tpu_ctl.scheduler.pass`` span (``queued=``, ``running=``): the
         pump runs beside every job it admitted, and this is what shows its
         period and duty on a trace; ``stats()["poll_passes_total"]`` and
-        ``["poll_pass_seconds_total"]`` say the same with no trace."""
+        ``["poll_pass_seconds_total"]`` say the same with no trace.
+
+        The pass owns one lazy fleet view: ``fleet_fn`` is called at the
+        first decision whose outcome depends on the fleet (a head with a
+        free slot, a head at capacity that has a victim to evict, a
+        quarantined chip whose running owner can vouch for it, a hetero
+        shed, a shrunk job past its cooldown) and never again in the same
+        pass; a pass with no such decision does not sample. ``sampled=``
+        on the span and ``stats()["fleet_samples_total"]`` say which."""
         t0 = time.perf_counter()
         with ctl_span("scheduler", "pass") as span, self._lock:
-            self._reap()
-            if not self._draining:
-                self._admit()
-                self._maybe_rebalance()
-                self._maybe_grow()
+            self._pass_thread = threading.get_ident()
+            self._pass_fleet = _UNSAMPLED
+            try:
+                self._reap()
+                if not self._draining:
+                    self._admit()
+                    self._maybe_rebalance()
+                    self._maybe_grow()
+            finally:
+                sampled = self._pass_fleet is not _UNSAMPLED
+                self._pass_thread = None
+                self._pass_fleet = _UNSAMPLED
             queued = self._queued_count()
             running = self._active_count()
             quarantined = len(self._hetero_quarantined)
-            span.set_metadata(queued=queued, running=running)
+            span.set_metadata(queued=queued, running=running, sampled=int(sampled))
             self.poll_passes_total += 1
+            self.fleet_samples_total += sampled
             self.poll_pass_seconds_total += time.perf_counter() - t0
         # Retain queue depth per poll pass in the historian (outside the
         # lock — the historian has its own). Best effort: scheduling must
@@ -1361,6 +1399,16 @@ class FleetScheduler:
         sub.last_skip_reason = reason
 
     def _fleet(self) -> Optional[TPUFleetStatus]:
+        """The fleet view. On the thread of an open pass: that pass's one
+        sample, taken at this first use (a failed sample is the pass's
+        None). Anywhere else: a fresh sample, as asked."""
+        if self._pass_thread != threading.get_ident():
+            return self._sample_fleet()
+        if self._pass_fleet is _UNSAMPLED:
+            self._pass_fleet = self._sample_fleet()
+        return self._pass_fleet
+
+    def _sample_fleet(self) -> Optional[TPUFleetStatus]:
         if self.fleet_fn is None:
             return None
         try:
@@ -1384,18 +1432,27 @@ class FleetScheduler:
         queued = self._queued_heads(max(self.backfill_depth, 1))
         if not queued:
             return
-        fleet = self._fleet()
+        head = queued[0]
         slots = self.max_concurrent_jobs - self._active_count()
-
+        if slots <= 0:
+            # Only an eviction can help the head, so whether one is possible
+            # is asked first: it needs no fleet. Eviction frees a slot and
+            # HBM — but never heals a chip, so a head whose gang exceeds
+            # the healthy fleet must not thrash victims it can never
+            # replace: that question, and the sample it needs, come only
+            # once there is a victim.
+            self._note_skip(head, "at max_concurrent_jobs capacity")
+            victim = self._preempt_victim(head)
+            if victim is not None and self._placeable(head, self._fleet()):
+                self._preempt(victim, head)
+            return
+        # A free slot: admission IS the decision, and it reads the fleet. A
+        # head the fleet refuses is retried with a fresh sample every pass:
+        # only a sample can see a chip heal.
+        fleet = self._fleet()
         preempt_wanted = False
         for rank, sub in enumerate(queued):
             if slots <= 0:
-                if rank == 0:
-                    self._note_skip(sub, "at max_concurrent_jobs capacity")
-                    # Eviction frees a slot and HBM — but never heals a
-                    # chip. A head whose gang exceeds the healthy fleet
-                    # must not thrash victims it can never replace.
-                    preempt_wanted = self._placeable(sub, fleet)
                 break
             if self._try_admit(sub, fleet):
                 slots -= 1
@@ -1406,7 +1463,9 @@ class FleetScheduler:
                 # healthy fleet, which no preemption fixes.
                 preempt_wanted = True
         if preempt_wanted:
-            self._maybe_preempt(queued[0])
+            victim = self._preempt_victim(head)
+            if victim is not None:
+                self._preempt(victim, head)
 
     def _placeable(self, sub: Submission, fleet: Optional[TPUFleetStatus]) -> bool:
         """Could ``sub``'s gang fit the healthy fleet if capacity/HBM were
@@ -2052,6 +2111,28 @@ class FleetScheduler:
             return
         if self._state_idx[SubmissionState.PREEMPTING]:
             return
+        # Who could grow is asked before what the fleet looks like: with no
+        # shrunk job past its cooldown there is nothing a sample could
+        # change (the steady state of a fleet whose jobs run at full size).
+        now = time.time()
+        candidates = [
+            sub for sub in self._running()
+            if sub.shrunk_mesh is not None
+            and sub.admitted_gang is not None
+            and sub.preemptible
+            # Hysteresis: the chip that freed up may be the same one that
+            # flapped this job into its shrink moments ago — hold the grow
+            # until the fleet has stayed healthy a full cooldown, or a flap
+            # cadence under the window turns into a preempt/save/recompile
+            # storm.
+            and not (
+                self.grow_back_cooldown_s > 0
+                and sub.last_resize_at is not None
+                and now - sub.last_resize_at < self.grow_back_cooldown_s
+            )
+        ]
+        if not candidates:
+            return
         fleet = self._fleet()
         if fleet is None or not fleet.devices:
             return
@@ -2066,25 +2147,7 @@ class FleetScheduler:
             and d.index not in self._hetero_quarantined
         ]
         healthy = len(healthy_devs)
-        now = time.time()
-        for sub in self._running():
-            if (
-                sub.shrunk_mesh is None
-                or sub.admitted_gang is None
-                or not sub.preemptible
-            ):
-                continue
-            if (
-                self.grow_back_cooldown_s > 0
-                and sub.last_resize_at is not None
-                and now - sub.last_resize_at < self.grow_back_cooldown_s
-            ):
-                # Hysteresis: the chip that freed up may be the same one
-                # that flapped this job into its shrink moments ago — hold
-                # the grow until the fleet has stayed healthy a full
-                # cooldown, or a flap cadence under the window turns into a
-                # preempt/save/recompile storm.
-                continue
+        for sub in candidates:
             # Planner-driven target: the full configured gang when it fits,
             # else the largest feasible INTERMEDIATE mesh of the elastic
             # family — both HBM-gated against per-device headroom minus
@@ -2211,17 +2274,22 @@ class FleetScheduler:
             )
         return True
 
-    def _maybe_preempt(self, head: Submission) -> None:
-        """Evict the lowest-priority running job strictly below ``head``'s
-        priority (one per pass) via the emergency-save seam."""
+    def _preempt_victim(self, head: Submission) -> Optional[Submission]:
+        """Whom an eviction for ``head`` would take: the lowest-priority,
+        youngest preemptible running job strictly below ``head``'s priority;
+        None when there is none or an eviction is already in flight (one at
+        a time — its save must land). Reads no fleet."""
         if self._state_idx[SubmissionState.PREEMPTING]:
-            return  # one eviction in flight at a time — its save must land
-        running = [s for s in self._running() if s.preemptible]
-        victims = [s for s in running if s.priority < head.priority]
-        if not victims:
-            return
-        victims.sort(key=lambda s: (int(s.priority), -s.seq))  # lowest, youngest
-        victim = victims[0]
+            return None
+        victims = [
+            s for s in self._running()
+            if s.preemptible and s.priority < head.priority
+        ]
+        return min(victims, key=lambda s: (int(s.priority), -s.seq), default=None)
+
+    def _preempt(self, victim: Submission, head: Submission) -> None:
+        """Evict ``victim`` for ``head`` via the emergency-save seam (one
+        per pass)."""
         self._set_state(victim, SubmissionState.PREEMPTING)
         self.preemptions_total += 1
         rec = tracing.get_recorder()
@@ -2353,7 +2421,11 @@ class FleetScheduler:
             "self_heal_requeues_total": self.self_heal_requeues_total,
             "auto_admissions_total": self.auto_admissions_total,
             "no_estimate_skips_total": self.no_estimate_skips_total,
-            # poll() passes and their host seconds.
+            # Passes that sampled the fleet, then poll() passes and their
+            # host seconds. This scrape takes no lock: a pass counts itself
+            # before its sample and the sample is read first here, so a
+            # reader never sees more samples than passes.
+            "fleet_samples_total": self.fleet_samples_total,
             "poll_passes_total": self.poll_passes_total,
             "poll_pass_seconds_total": round(self.poll_pass_seconds_total, 6),
             "placement": self.planner.stats(),
