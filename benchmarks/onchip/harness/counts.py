@@ -10,6 +10,18 @@ is never counted. ``cfg`` is a configuration file's dict (Hugging Face keys).
 
 from __future__ import annotations
 
+import statistics
+
+
+def knows(cfg: dict) -> bool:
+    """The Llama recipe (Mistral's, Mixtral's): every layer the same grouped-query
+    attention before a dense or routed SwiGLU. A file that states a pattern of
+    layers (``layer_types``, ``mixer_types``) or a latent (``kv_lora_rank``) is
+    another module's (``counts_for``: exactly one module knows a configuration)."""
+    return all(k in cfg for k in ("hidden_size", "intermediate_size", "vocab_size", "num_attention_heads",
+                                  "num_key_value_heads", "num_hidden_layers")) \
+        and not any(k in cfg for k in ("layer_types", "mixer_types", "kv_lora_rank"))
+
 
 def visible_keys_total(seq: int, window: int) -> int:
     """Sum over queries 0..seq-1 of the keys each sees (causal, window)."""
@@ -91,3 +103,24 @@ def expected_experts_hit(n_experts: int, top_k: int, tokens: int) -> float:
     if not n_experts:
         return 1.0
     return n_experts * (1.0 - (1.0 - top_k / n_experts) ** tokens)
+
+
+def decode_step(run: dict) -> tuple[float, float] | None:
+    """(bytes one decode step must read, traced seconds of one step) of a
+    traced serving run, for ``decode_step_hbm_roofline``: every weight once in
+    the serving dtype (for experts, those the live slots are expected to route
+    to), keys and values at the slots' real lengths; the step is the median run
+    of the program that takes most device time in the trace, which in a serving
+    cell is the decode chunk (the trace calls it ``jit__unknown``: it is jitted
+    from a partial), over the chunk's steps. Contexts stay under the window in
+    today's cells, so the sum of contexts stands for the slots' lengths."""
+    tr = run["trace"]
+    if not tr["module_runs"] or not run.get("occupancy"):
+        return None
+    decode = max(tr["module_runs"].values(), key=sum)
+    step_s = statistics.median(decode) / run["decode_chunk_steps"]
+    cfg = run["cell"]["config"]
+    live = statistics.fmean(run["occupancy"])
+    hit = expected_experts_hit(cfg.get("num_local_experts") or 0, cfg.get("num_experts_per_tok") or 0, live)
+    ctx = statistics.fmean(run["dispatch_context"])
+    return weight_bytes_per_decode_step(cfg, hit) + kv_bytes_per_decode_step(cfg, [ctx]), step_s

@@ -1,7 +1,8 @@
 """Operations and bytes a hybrid (Mamba-2 + attention) stack needs, from
-shapes alone: what the readers of the ``ssm_*`` and ``hybrid_*`` rooflines
-divide by a peak. ``harness/counts.py`` counts Llama layers; a stack with two
-kinds of layer, a tied head and a recurrent state is counted here.
+shapes alone: what the readers of the ``ssm_*`` rooflines and
+``decode_step_hbm_roofline`` divide by a peak. ``harness/counts.py`` counts
+Llama layers; a stack with two kinds of layer, a tied head and a recurrent
+state is counted here.
 
 ``cfg`` is a configuration file's dict (Hugging Face keys:
 ``mamba_n_heads`` H, ``mamba_d_head`` P, ``mamba_d_state`` N, ``mamba_d_conv``
@@ -11,7 +12,15 @@ dtype (``itemsize``), as the configuration's ``assumed.state_dtypes`` says.
 
 from __future__ import annotations
 
+import statistics
+
 STATE_ITEMSIZE = 4
+
+
+def knows(cfg: dict) -> bool:
+    """Mamba-2 beside attention layers with one dense MLP after every mixer (a
+    mixture after the mixers is ``counts_hybrid_moe``'s)."""
+    return "mamba_n_heads" in cfg and not cfg.get("num_local_experts")
 
 
 def _dims(cfg: dict):
@@ -89,3 +98,26 @@ def decode_chunk_runs(trace: dict) -> list:
     (``jit_decode_chunk``), from ``trace_reduce``'s ``module_runs``; empty
     where the program has no such name."""
     return next((v for k, v in trace["module_runs"].items() if "decode_chunk" in k), [])
+
+
+def decode_chunk_step_s(run: dict) -> float | None:
+    """Traced device seconds of one decode step: the median run of
+    ``jit_decode_chunk`` over the chunk's steps."""
+    runs = decode_chunk_runs(run["trace"])
+    return statistics.median(runs) / run["decode_chunk_steps"] if runs else None
+
+
+def decode_step(run: dict) -> tuple[float, float] | None:
+    """(bytes one decode step must move, traced seconds of one step) of a
+    traced serving run, for ``decode_step_hbm_roofline``: the weights of both
+    kinds of layer once in the serving dtype, the tied head, keys and values of
+    the attention layers at the slots' real lengths, and the recurrent state of
+    every Mamba-2 layer in and out for every slot (the program computes all of
+    the pool)."""
+    cfg, step_s = run["cell"]["config"], decode_chunk_step_s(run)
+    if not step_s or not run.get("dispatch_context"):
+        return None
+    need = (weight_bytes_per_decode_step(cfg)
+            + kv_bytes_per_decode_step(cfg, statistics.fmean(run["dispatch_context"]))
+            + recurrent_bytes_per_decode_step(cfg, run["slots"]))
+    return need, step_s

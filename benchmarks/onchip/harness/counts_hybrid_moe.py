@@ -1,11 +1,11 @@
 """Operations and bytes of a hybrid stack whose block after every mixer is a
 mixture of experts with a shared expert (granite-4.0-h-small's recipe), from
 shapes and from the program's own counters: what the readers of the
-``expert_*`` and ``hybrid_moe_*`` rooflines divide by a peak. Each is the least
-the mathematics needs, whatever implements it: an expert's weights once per
-layer-step in which some token chose it, six FLOPs per weight of an expert per
-token it was chosen by. The mixers, the state and the keys and values are
-``counts_hybrid``'s.
+``expert_*`` rooflines and ``decode_step_hbm_roofline`` divide by a peak. Each
+is the least the mathematics needs, whatever implements it: an expert's weights
+once per layer-step in which some token chose it, six FLOPs per weight of an
+expert per token it was chosen by. The mixers, the state and the keys and
+values are ``counts_hybrid``'s.
 
 ``cfg`` is a configuration file's dict (Hugging Face keys; ``intermediate_size``
 is one expert's width, ``shared_intermediate_size`` the shared expert's,
@@ -17,6 +17,8 @@ their ratios are used, the traced window's layer-steps come from the trace.
 
 from __future__ import annotations
 
+import statistics
+
 from . import counts_hybrid
 
 
@@ -25,8 +27,14 @@ def is_mixture(cfg: dict) -> bool:
         "num_local_experts" in cfg.get("published", {})
 
 
+knows = is_mixture
+
+
 def n_layers(cfg: dict) -> int:
     return len(cfg["layer_types"])
+
+
+n_mixture_layers = n_layers  # every layer of the period has the mixture after its mixer
 
 
 def expert_weights(cfg: dict) -> int:
@@ -93,3 +101,17 @@ def decode_step_bytes(cfg: dict, slots: int, context_tokens: float, experts_hit:
             + n_layers(cfg) * (mixture_fixed_bytes(cfg) + experts_hit * expert_bytes(cfg))
             + counts_hybrid.kv_bytes_per_decode_step(cfg, context_tokens)
             + counts_hybrid.recurrent_bytes_per_decode_step(cfg, slots))
+
+
+def decode_step(run: dict) -> tuple[float, float] | None:
+    """(bytes one decode step must move, traced seconds of one step) of a
+    traced serving run, for ``decode_step_hbm_roofline``: ``decode_step_bytes``
+    at the slots' real lengths with the experts some row chose a layer-step
+    (the engine's counters); ``counts_hybrid``'s count holds one dense MLP a
+    layer and would under-read here."""
+    hit = per_layer_step(run.get("engine_stats") or {}, "decode", "experts_hit")
+    step_s = counts_hybrid.decode_chunk_step_s(run)
+    if not step_s or not hit or not run.get("dispatch_context"):
+        return None
+    context = statistics.fmean(run["dispatch_context"])
+    return decode_step_bytes(run["cell"]["config"], run["slots"], context, hit), step_s

@@ -1,12 +1,13 @@
 """Operations and bytes of a DeepSeek-V3-recipe stack (latent attention, MLA,
 before a mixture of experts with shared experts; leading dense layers), from
 shapes and from the program's own counters: what the readers of the ``mla_*``
-and ``mla_moe_*`` rooflines divide by a peak. Each is the LEAST the mathematics
-needs, whatever implements it: a latent row once per layer-step for the live
-rows at their real lengths, an expert's weights once per layer-step in which
-some token chose it, two FLOPs a multiply-add over the causal triangle and
-nothing past it. A program that reads every lane of every slot, or the latent
-twice, or computes masked lanes, reads under 100 % by that much.
+and ``expert_*`` rooflines and ``decode_step_hbm_roofline`` divide by a peak.
+Each is the LEAST the mathematics needs, whatever implements it: a latent row
+once per layer-step for the live rows at their real lengths, an expert's
+weights once per layer-step in which some token chose it, two FLOPs a
+multiply-add over the causal triangle and nothing past it. A program that reads
+every lane of every slot, or the latent twice, or computes masked lanes, reads
+under 100 % by that much.
 
 ``cfg`` is a configuration file's dict (Hugging Face keys: ``kv_lora_rank`` C,
 ``qk_nope_head_dim`` N, ``qk_rope_head_dim`` R, ``v_head_dim`` V, H heads;
@@ -14,17 +15,22 @@ twice, or computes masked lanes, reads under 100 % by that much.
 HELD, ``published.n_routed_experts`` the router's width, ``n_shared_experts``,
 ``first_k_dense_replace`` leading dense layers of ``intermediate_size``). The
 mixture's counters and ratios are ``counts_hybrid_moe``'s (they know no
-family); only its ``is_mixture`` asks for granite's keys.
+family); only its ``knows`` asks for granite's keys.
 """
 
 from __future__ import annotations
 
+from .counts_hybrid import decode_chunk_step_s
 from .counts_hybrid_moe import (expert_tokens_per_step, held_assignments_per_token,  # noqa: F401
                                 per_layer_step)
+from .counts_sala import decoding_context
 
 
 def is_mla_moe(cfg: dict) -> bool:
     return "kv_lora_rank" in cfg and "n_routed_experts" in cfg.get("published", {})
+
+
+knows = is_mla_moe
 
 
 def _dims(cfg: dict) -> dict:
@@ -105,6 +111,18 @@ def decode_step_bytes(cfg: dict, context_tokens: float, experts_hit: float, item
                + d["mix"] * mixture_fixed_weights(cfg) + d["D"] * cfg["vocab_size"])
     return (itemsize * weights + d["mix"] * experts_hit * expert_bytes(cfg, itemsize)
             + d["L"] * context_tokens * latent_row_bytes(cfg, itemsize))
+
+
+def decode_step(run: dict) -> tuple[float, float] | None:
+    """(bytes one decode step must move, traced seconds of one step) of a
+    traced serving run, for ``decode_step_hbm_roofline``: ``decode_step_bytes``
+    for the rows that decode at their contexts (``counts_sala.decoding_context``)
+    with the held experts some row chose a layer-step (the engine's counters)."""
+    hit = per_layer_step(run.get("engine_stats") or {}, "decode", "experts_hit")
+    context, step_s = decoding_context(run), decode_chunk_step_s(run)
+    if not step_s or not hit or not context:
+        return None
+    return decode_step_bytes(run["cell"]["config"], context, hit), step_s
 
 
 # -- a prefill chunk ----------------------------------------------------------------

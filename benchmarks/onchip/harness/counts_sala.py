@@ -1,10 +1,10 @@
 """Operations and bytes a MiniCPM-SALA stack (block-sparse attention beside
 lightning attention) needs, from shapes alone: what the readers of the
-``lightning_*``, ``sparse_*`` and ``longctx_*`` rooflines divide by a peak.
-Every count is the LEAST work the mathematics needs (a lightning layer in its
-recurrent form, a sparse layer reading its chosen blocks and nothing else), so
-a share of a roofline says how far the program is from that, and cannot pass
-100 %.
+``lightning_*`` and ``sparse_*`` rooflines and ``decode_step_hbm_roofline``
+divide by a peak. Every count is the LEAST work the mathematics needs (a
+lightning layer in its recurrent form, a sparse layer reading its chosen blocks
+and nothing else), so a share of a roofline says how far the program is from
+that, and cannot pass 100 %.
 
 ``cfg`` is a configuration file's dict (Hugging Face keys; the indexer's sizes
 under ``sparse_config``). The lightning state is float32 and every other
@@ -16,13 +16,16 @@ from __future__ import annotations
 
 from functools import lru_cache
 
-from .counts_hybrid import decode_chunk_runs  # noqa: F401  (the readers' one name for it)
+from .counts_hybrid import decode_chunk_runs, decode_chunk_step_s  # noqa: F401  (the readers' one name for it)
 
 STATE_ITEMSIZE = 4
 
 
 def has_both_kinds(cfg: dict) -> bool:
     return "lightning_nh" in cfg and "sparse_config" in cfg
+
+
+knows = has_both_kinds
 
 
 def _dims(cfg: dict):
@@ -167,6 +170,17 @@ def decoding_context(run: dict) -> float | None:
     if not rows or not ctx or not held or not sum(held):
         return None
     return rows * sum(ctx) / sum(held)
+
+
+def decode_step(run: dict) -> tuple[float, float] | None:
+    """(bytes one decode step must move, traced seconds of one step) of a
+    traced serving run, for ``decode_step_hbm_roofline``: ``decode_step_bytes``
+    for the rows that decode (``decoding_rows``: not the slots held, of which
+    some still ingest) at their contexts, the lightning state of every slot."""
+    rows, context, step_s = decoding_rows(run), decoding_context(run), decode_chunk_step_s(run)
+    if not step_s or not rows or not context:
+        return None
+    return decode_step_bytes(run["cell"]["config"], run["slots"], rows, context), step_s
 
 
 # -- the trace ----------------------------------------------------------------------

@@ -83,7 +83,9 @@ def metrics_of(manifest: dict, section: str, workload: str) -> list[dict]:
 
 def reader_path(name: str) -> str | None:
     """``layer_metrics/<name>.py``, or the file of the name without its last
-    dotted suffix (``device_idle_pct.chat`` is read by ``device_idle_pct.py``)."""
+    dotted suffix (``device_idle_pct.tpot`` is read by ``device_idle_pct.py``:
+    a suffix names the end-to-end metric the entry moves, ``.train`` / ``.ttft``
+    / ``.tpot`` / ``.rate``, or the one cell that reads the entry)."""
     d = os.path.join(BENCH_DIR, "layer_metrics")
     for cand in (name, name.rsplit(".", 1)[0]):
         p = os.path.join(d, cand + ".py")

@@ -1,7 +1,7 @@
 """What the kimi-vl-a3b configuration brought to the benchmark, on the CPU: the
 family's mapping and refusals, the configuration against the catalog's row, the
 reference against the program at rehearsal size, the count functions and the
-seven new readers on a synthetic trace whose numbers are known exactly, and the
+seven readers of its mechanisms on a synthetic trace whose numbers are known exactly, and the
 new cell driven end to end through ``run.py``'s runner at its rehearsal size."""
 
 import copy
@@ -16,9 +16,11 @@ from tests.test_program_trace import _bytes, _op
 
 CELL = "kimi-vl-a3b.serve-longctx32"
 CONFIG = "kimi-vl-a3b-1chip-serve"
+# The family's own three, and the four it shares with every family whose counts
+# module states the same names (PR 42: ``harness/counts_for.py``).
 NEW_READERS = ("mla_time_pct.longctx32", "mla_decode_roofline.longctx32", "mla_prefill_roofline.longctx32",
-               "mla_moe_decode_hbm_roofline.longctx32", "mla_moe_expert_decode_roofline.longctx32",
-               "mla_moe_expert_prefill_roofline.longctx32", "mla_moe_expert_tokens_per_step.longctx32")
+               "decode_step_hbm_roofline.rate", "expert_decode_roofline.rate",
+               "expert_prefill_roofline.rate", "expert_tokens_per_step.rate")
 
 
 def _config():
@@ -249,20 +251,20 @@ def test_the_new_readers_on_a_synthetic_trace(monkeypatch, tmp_path):
     read = lambda name: manifest.load_reader(name)(run, name)  # noqa: E731
     bw, fl = 819e9, 197e12
     assert read("mla_time_pct.longctx32") == pytest.approx(100 * (36 + 16 + 100 + 6 + 30) / 250)
-    assert read("moe_time_pct.longctx32") == pytest.approx(100 * (40 + 14 + 8) / 250)
+    assert read("moe_time_pct.rate") == pytest.approx(100 * (40 + 14 + 8) / 250)
     context = 31.0 * 384_000 / 64  # 31 of the 32 held rows decode, at the held rows' average context
     need = 2 * 8 * 13 * counts_mla_moe.absorbed_decode_bytes(cfg, context)
     assert read("mla_decode_roofline.longctx32") == pytest.approx(100 * need / bw / 0.116)
     flops = counts_mla_moe.mla_chunk_flops(cfg, 0, 2048) + counts_mla_moe.mla_chunk_flops(cfg, 4096, 2048)
     assert read("mla_prefill_roofline.longctx32") == pytest.approx(100 * 13 * flops / fl / 0.036)
     step = counts_mla_moe.decode_step_bytes(cfg, context, 15.0)
-    assert read("mla_moe_decode_hbm_roofline.longctx32") == pytest.approx(100 * step / bw / (0.120 / 8))
+    assert read("decode_step_hbm_roofline.rate") == pytest.approx(100 * step / bw / (0.120 / 8))
     expert = 2 * 3 * 2048 * 1408
-    assert read("mla_moe_expert_decode_roofline.longctx32") == pytest.approx(100 * 2 * 8 * 12 * 15.0 * expert / bw / 0.040)
+    assert read("expert_decode_roofline.rate") == pytest.approx(100 * 2 * 8 * 12 * 15.0 * expert / bw / 0.040)
     held = 6 * 368_640 / 1_474_560
     one = max(2048 * held * 6 * 2048 * 1408 / fl, 16 * expert / bw)
-    assert read("mla_moe_expert_prefill_roofline.longctx32") == pytest.approx(100 * 12 * 2 * one / 0.014)
-    assert read("mla_moe_expert_tokens_per_step.longctx32") == pytest.approx(31.0 * 1.5 / 16)
+    assert read("expert_prefill_roofline.rate") == pytest.approx(100 * 12 * 2 * one / 0.014)
+    assert read("expert_tokens_per_step.rate") == pytest.approx(31.0 * 1.5 / 16)
     for name in NEW_READERS:
         assert 0 < read(name)  # a synthetic trace: its times are made up, its arithmetic is not
 
@@ -270,20 +272,30 @@ def test_the_new_readers_on_a_synthetic_trace(monkeypatch, tmp_path):
 def test_on_a_program_without_the_names_or_the_counters_the_new_readers_return_nothing(monkeypatch, tmp_path):
     """The driver lays these files over the parent's checkout for its traced
     runs: no ``mla`` scope, no ``moe_*`` counter there, and another family's
-    configuration in the other cells."""
+    configuration in the other cells. The family's own readers read nothing of
+    another family; the four shared ones read nothing of a configuration that
+    no counts module knows, and the experts' three nothing of a family without
+    a mixture."""
     run = _traced_run(monkeypatch, tmp_path, with_names=False)
     for name in NEW_READERS:
         assert manifest.load_reader(name)(run, name) is None, name
     named = _traced_run(monkeypatch, tmp_path / "b")
     untraced = {**named, "trace": None}
-    other = copy.deepcopy(named)
+    other, unknown, dense = copy.deepcopy(named), copy.deepcopy(named), copy.deepcopy(named)
     other["cell"]["config"] = manifest.load_cell(manifest.load_manifest(),
                                                  "granite-4.0-h-small.serve-batch32")["config"]
+    unknown["cell"]["config"] = {"hidden_size": 2048}
+    dense["cell"]["config"] = manifest.load_cell(manifest.load_manifest(),
+                                                 "granite-4.0-h-micro.serve-chat-burst")["config"]
     for name in NEW_READERS[1:6]:
         assert manifest.load_reader(name)(untraced, name) is None, name
+    for name in NEW_READERS[1:3]:
         assert manifest.load_reader(name)(other, name) is None, name
+    for name in NEW_READERS[3:]:
+        assert manifest.load_reader(name)(unknown, name) is None, name
+    for name in NEW_READERS[4:]:
+        assert manifest.load_reader(name)(dense, name) is None, name
     assert manifest.load_reader(NEW_READERS[0])(untraced, NEW_READERS[0]) is None
-    assert manifest.load_reader(NEW_READERS[6])(other, NEW_READERS[6]) is None
 
 
 # -- the cell, driven -------------------------------------------------------------

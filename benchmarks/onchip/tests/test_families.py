@@ -125,10 +125,22 @@ def test_the_family_a_file_states_is_taken_before_the_model_type_it_publishes():
             program.model_config({**config, "family": name}, "x")
 
 
-def test_none_of_the_committed_configurations_states_a_family():
-    for entry in manifest.load_manifest()["configs"]:
-        config = _config(entry["name"])
-        assert "family" not in config and manifest.family_of(config) == config["model_type"], entry["name"]
+def test_a_committed_configuration_states_a_family_only_where_its_model_type_cannot_name_one():
+    """``family`` is a key a file MAY state (PR 33), and only two kinds of file
+    do: a second recipe under a ``model_type`` that another committed file
+    already takes as its family (granite-4.0-h-small beside -micro, PR 35), and
+    a file that publishes no ``model_type`` (kimi-vl-a3b, PR 40: the language
+    decoder of a checkpoint whose ``model_type`` is the wrapper's). Every other
+    file's family is the ``model_type`` it publishes."""
+    configs = {e["name"]: _config(e["name"]) for e in manifest.load_manifest()["configs"]}
+    taken = {c["model_type"] for c in configs.values() if "family" not in c}
+    for name, config in configs.items():
+        if "family" not in config:
+            assert manifest.family_of(config) == config["model_type"], name
+        else:
+            assert config.get("model_type") != config["family"], name
+            assert "model_type" not in config or config["model_type"] in taken, name
+            assert manifest.family_of(config) == config["family"], name
 
 
 def _lint_with(tmp_path, **keys):

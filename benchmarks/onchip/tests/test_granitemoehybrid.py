@@ -146,7 +146,7 @@ def test_the_new_readers_on_a_synthetic_trace(monkeypatch, tmp_path):
     cfg = run["cell"]["config"]
     read = lambda name: manifest.load_reader(name)(run, name)  # noqa: E731
     assert read("ssm_time_pct.burst") == pytest.approx(100 * (72 + 45 + 9) / 150)
-    assert read("attn_time_pct.burst") == pytest.approx(100 * 4 / 150)
+    assert read("attn_time_pct.tpot") == pytest.approx(100 * 4 / 150)
     bw, fl = 819e9, 197e12
     steps = 2 * 8
     assert read("ssm_update_roofline.burst") == pytest.approx(
@@ -156,7 +156,7 @@ def test_the_new_readers_on_a_synthetic_trace(monkeypatch, tmp_path):
     assert read("ssm_scan_roofline.burst") == pytest.approx(100 * 18 * need / 0.009)
     step_bytes = (counts_hybrid.weight_bytes_per_decode_step(cfg) + counts_hybrid.kv_bytes_per_decode_step(cfg, 10000)
                   + counts_hybrid.recurrent_bytes_per_decode_step(cfg, 32))
-    assert read("hybrid_decode_hbm_roofline.burst") == pytest.approx(100 * step_bytes / bw / (0.040 / 8))
+    assert read("decode_step_hbm_roofline.tpot") == pytest.approx(100 * step_bytes / bw / (0.040 / 8))
     for name in ("ssm_update_roofline.burst", "ssm_scan_roofline.burst"):
         assert 0 < read(name)  # a synthetic trace: its times are made up, its arithmetic is not
 
@@ -164,15 +164,17 @@ def test_the_new_readers_on_a_synthetic_trace(monkeypatch, tmp_path):
 def test_on_a_program_without_the_names_the_new_readers_return_nothing(monkeypatch, tmp_path):
     """The driver lays these files over the parent's checkout for its traced
     runs: no ``ssm`` scope, no ``tokens=``, no ``jit_decode_chunk`` there, and
-    a Llama configuration in the other cells."""
+    a Llama configuration in the other cells (here the file without its
+    ``mamba_*`` keys, which no counts module knows: ``decode_step_hbm_roofline``
+    reads nothing of it, and reads a Llama file by ``counts.decode_step``)."""
     run = _traced_run(monkeypatch, tmp_path, with_names=False)
     for name in ("ssm_time_pct.burst", "ssm_update_roofline.burst", "ssm_scan_roofline.burst",
-                 "hybrid_decode_hbm_roofline.burst"):
+                 "decode_step_hbm_roofline.tpot"):
         assert manifest.load_reader(name)(run, name) is None
     untraced = {**run, "trace": None}
     llama = copy.deepcopy(_traced_run(monkeypatch, tmp_path / "b"))
     llama["cell"]["config"] = {k: v for k, v in llama["cell"]["config"].items() if not k.startswith("mamba_")}
-    for name in ("ssm_update_roofline.burst", "ssm_scan_roofline.burst", "hybrid_decode_hbm_roofline.burst"):
+    for name in ("ssm_update_roofline.burst", "ssm_scan_roofline.burst", "decode_step_hbm_roofline.tpot"):
         assert manifest.load_reader(name)(untraced, name) is None
         assert manifest.load_reader(name)(llama, name) is None
 

@@ -17,8 +17,8 @@ from tests.test_program_trace import _bytes, _op
 
 CELL = "granite-4.0-h-small.serve-batch32"
 CONFIG = "granite-4.0-h-small-1chip-serve"
-NEW_READERS = ("moe_time_pct.batch32", "expert_decode_roofline.batch32", "expert_prefill_roofline.batch32",
-               "hybrid_moe_decode_hbm_roofline.batch32", "expert_tokens_per_step.batch32")
+NEW_READERS = ("moe_time_pct.rate", "expert_decode_roofline.rate", "expert_prefill_roofline.rate",
+               "decode_step_hbm_roofline.rate", "expert_tokens_per_step.rate")
 
 
 def _config():
@@ -233,16 +233,16 @@ def test_the_new_readers_on_a_synthetic_trace(monkeypatch, tmp_path):
     cfg = run["cell"]["config"]
     read = lambda name: manifest.load_reader(name)(run, name)  # noqa: E731
     bw, fl = 819e9, 197e12
-    assert read("moe_time_pct.batch32") == pytest.approx(100 * (20 + 60 + 12 + 6) / 190)
+    assert read("moe_time_pct.rate") == pytest.approx(100 * (20 + 60 + 12 + 6) / 190)
     assert read("ssm_time_pct.batch32") == pytest.approx(100 * 92 / 190)
     expert = 2 * 3 * 4096 * 768
-    assert read("expert_decode_roofline.batch32") == pytest.approx(100 * 2 * 8 * 10 * 35.5 * expert / bw / 0.060)
+    assert read("expert_decode_roofline.rate") == pytest.approx(100 * 2 * 8 * 10 * 35.5 * expert / bw / 0.060)
     held = 10 * 98_000 / 200_000
     need = sum(max(t * held * 6 * 4096 * 768 / fl, 36 * expert / bw) for t in (256, 256, 64))
-    assert read("expert_prefill_roofline.batch32") == pytest.approx(100 * 10 * need / 0.012)
+    assert read("expert_prefill_roofline.rate") == pytest.approx(100 * 10 * need / 0.012)
     step = counts_hybrid_moe.decode_step_bytes(cfg, 32, 20000, 35.5)
-    assert read("hybrid_moe_decode_hbm_roofline.batch32") == pytest.approx(100 * step / bw / (0.080 / 8))
-    assert read("expert_tokens_per_step.batch32") == pytest.approx(31.0 * 5.0 / 36)  # 248 tokens a dispatch of 8
+    assert read("decode_step_hbm_roofline.rate") == pytest.approx(100 * step / bw / (0.080 / 8))
+    assert read("expert_tokens_per_step.rate") == pytest.approx(31.0 * 5.0 / 36)  # 248 tokens a dispatch of 8
     assert read("ssm_update_roofline.batch32") == pytest.approx(
         100 * 2 * 8 * 9 * counts_hybrid.ssm_update_bytes(cfg, 32) / bw / 0.092)
     for name in NEW_READERS:
@@ -252,7 +252,9 @@ def test_the_new_readers_on_a_synthetic_trace(monkeypatch, tmp_path):
 def test_on_a_program_without_the_names_or_the_counters_the_new_readers_return_nothing(monkeypatch, tmp_path):
     """The driver lays these files over the parent's checkout for its traced
     runs: no ``moe`` scope, no ``moe_*`` counter there, and another family's
-    configuration in the other cells."""
+    configuration in the other cells: the experts' readers read nothing of a
+    family without a mixture, and the whole step's share is then that family's
+    own (``counts_hybrid.decode_step``), not this one's."""
     run = _traced_run(monkeypatch, tmp_path, with_names=False)
     for name in NEW_READERS:
         assert manifest.load_reader(name)(run, name) is None, name
@@ -263,7 +265,13 @@ def test_on_a_program_without_the_names_or_the_counters_the_new_readers_return_n
                                                  "granite-4.0-h-micro.serve-chat-burst")["config"]
     for name in NEW_READERS[1:4]:
         assert manifest.load_reader(name)(untraced, name) is None, name
+    for name in (NEW_READERS[1], NEW_READERS[2], NEW_READERS[4]):
         assert manifest.load_reader(name)(micro, name) is None, name
+    bytes_of_micro = (counts_hybrid.weight_bytes_per_decode_step(micro["cell"]["config"])
+                      + counts_hybrid.kv_bytes_per_decode_step(micro["cell"]["config"], 20000)
+                      + counts_hybrid.recurrent_bytes_per_decode_step(micro["cell"]["config"], 32))
+    assert manifest.load_reader(NEW_READERS[3])(micro, NEW_READERS[3]) == pytest.approx(
+        100 * bytes_of_micro / 819e9 / (0.080 / 8))
     assert manifest.load_reader(NEW_READERS[0])(untraced, NEW_READERS[0]) is None
 
 

@@ -114,12 +114,12 @@ def test_unknown_device_kind_is_an_error():
 
 
 def test_decode_roofline_reader_takes_the_program_with_most_device_time(man):
-    read = manifest.load_reader("decode_hbm_roofline.chat")
+    read = manifest.load_reader("decode_step_hbm_roofline.tpot")
     cell = manifest.load_cell(man, "mistral-7b.serve-chat")
     run = {"trace": {"module_runs": {"jit__unknown(1)": [0.30] * 40, "jit__unknown(2)": [0.05] * 10,
                                       "jit_convert_element_type(3)": [1e-4] * 90}},
            "device": {"platform": "tpu", "kind": "TPU v5 lite"}, "occupancy": [3, 4], "decode_chunk_steps": 8,
            "cell": cell, "dispatch_context": [2400]}
-    share = read(run, "decode_hbm_roofline.chat")
+    share = read(run, "decode_step_hbm_roofline.tpot")
     assert 5 < share < 30          # ~3.8 GB of bf16 weights and KV over 819 GB/s against 37.5 ms a step
     assert read({**run, "trace": None}, "x") is None

@@ -37,9 +37,11 @@ def _drive(monkeypatch, **kw):
     return _run(monkeypatch, CELL, **kw)
 
 
-NEW = ["lightning_time_pct", "sparse_attn_time_pct", "lightning_update_roofline", "lightning_scan_roofline",
-       "sparse_decode_roofline", "sparse_prefill_roofline", "longctx_decode_hbm_roofline",
-       "sparse_block_attn_roofline"]
+# The family's own readers keep the cell's suffix; the whole step's share is the
+# one reader of every serving family (PR 42), named by the metric it moves.
+NEW = ["lightning_time_pct.longdoc", "sparse_attn_time_pct.longdoc", "lightning_update_roofline.longdoc",
+       "lightning_scan_roofline.longdoc", "sparse_decode_roofline.longdoc", "sparse_prefill_roofline.longdoc",
+       "decode_step_hbm_roofline.rate", "sparse_block_attn_roofline.longdoc"]
 
 
 # -- the family and the file ------------------------------------------------------
@@ -122,7 +124,7 @@ def test_the_cell_is_the_issues_traffic_and_the_lane_leaves_the_slots_full():
     assert t["prompt_tokens"] == {"dist": "uniform", "min": 10240, "max": 32768, "round_to": 2048}
     assert t["output_tokens"] == {"dist": "uniform", "min": 256, "max": 1024, "round_to": 1}
     assert [m["name"] for m in cell["end_to_end"]] == ["serve_tokens_per_s", "setup_s"]
-    assert {m["name"] for m in cell["per_layer"]} >= {n + ".longdoc" for n in NEW + ["sparse_decode_share_pct"]}
+    assert {m["name"] for m in cell["per_layer"]} >= set(NEW + ["sparse_decode_share_pct.longdoc"])
     from harness.generators import closed
 
     plan = closed.Plan(t, 73448, 1, 50.0)
@@ -218,7 +220,9 @@ def _traced_run(monkeypatch, tmp_path, with_names=True):
 def test_the_new_readers_on_a_synthetic_trace(monkeypatch, tmp_path):
     run = _traced_run(monkeypatch, tmp_path)
     cfg = run["cell"]["config"]
-    read = lambda name: manifest.load_reader(name + ".longdoc")(run, name + ".longdoc")  # noqa: E731
+    def read(name, suffix=".longdoc"):
+        return manifest.load_reader(name + suffix)(run, name + suffix)
+
     busy = 170
     assert read("lightning_time_pct") == pytest.approx(100 * (2 * 30 + 10) / busy)
     assert read("sparse_attn_time_pct") == pytest.approx(100 * (2 * 10 + 40) / busy)
@@ -235,13 +239,13 @@ def test_the_new_readers_on_a_synthetic_trace(monkeypatch, tmp_path):
         100 * steps * 3 * counts_sala.sparse_decode_bytes(cfg, 7, 140000) / bw / 0.020)
     flops = counts_sala.sparse_prefill_flops(cfg, 4 * 2048, 2048) + counts_sala.sparse_prefill_flops(cfg, 5 * 2048, 2048)
     assert read("sparse_prefill_roofline") == pytest.approx(100 * 3 * flops / fl / 0.040)
-    assert read("longctx_decode_hbm_roofline") == pytest.approx(
+    assert read("decode_step_hbm_roofline", ".rate") == pytest.approx(
         100 * counts_sala.decode_step_bytes(cfg, 16, 7, 140000) / bw / (0.050 / 8))
     assert read("sparse_block_attn_roofline") == pytest.approx(
         100 * steps * 3 * counts_sala.chosen_block_bytes(cfg, 7) / bw / 0.008)
     assert read("sparse_decode_share_pct") == 100.0
-    assert read("decode_overshoot_pct") == pytest.approx(0.5)
-    assert read("slot_occupancy_pct") == pytest.approx(100 * 15.5 / 16)
+    assert read("decode_overshoot_pct", ".rate") == pytest.approx(0.5)
+    assert read("slot_occupancy_pct", ".rate") == pytest.approx(100 * 15.5 / 16)
 
 
 def test_on_a_program_without_the_names_the_new_readers_return_nothing(monkeypatch, tmp_path):
@@ -251,17 +255,17 @@ def test_on_a_program_without_the_names_the_new_readers_return_nothing(monkeypat
     in the other cells."""
     run = _traced_run(monkeypatch, tmp_path, with_names=False)
     run["engine_stats"].pop("decode_tokens_sparse_total")
-    for name in NEW + ["sparse_decode_share_pct"]:
-        assert manifest.load_reader(name + ".longdoc")(run, name + ".longdoc") is None, name
+    for name in NEW + ["sparse_decode_share_pct.longdoc"]:
+        assert manifest.load_reader(name)(run, name) is None, name
     named = _traced_run(monkeypatch, tmp_path / "b")
     untraced = {**named, "trace": None}
     granite = copy.deepcopy(named)
     granite["cell"]["config"] = {k: v for k, v in granite["cell"]["config"].items()
                                  if k not in ("lightning_nh", "sparse_config")}
     for name in NEW:
-        assert manifest.load_reader(name + ".longdoc")(untraced, name + ".longdoc") is None, name
+        assert manifest.load_reader(name)(untraced, name) is None, name
     for name in NEW[2:]:
-        assert manifest.load_reader(name + ".longdoc")(granite, name + ".longdoc") is None, name
+        assert manifest.load_reader(name)(granite, name) is None, name
 
 
 # -- the cell, driven -------------------------------------------------------------

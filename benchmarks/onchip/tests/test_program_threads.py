@@ -66,8 +66,8 @@ def test_the_loops_line_its_iterations_and_what_ran_beside_it():
     assert program_threads.span_seconds(tr, "tpu_ctl.no.such") is None
 
 
-NEW = ("dispatch_host_ms.batch", "loop_blocked_ms.batch", "read_lag_ms_p90.batch", "idle_beside_ctl_pct.batch",
-       "scheduler_pass_busy_pct.burst", "batcher_idle_ms.batch", "health_sample_ms.train")
+NEW = ("dispatch_host_ms.rate", "loop_blocked_ms.rate", "read_lag_ms_p90.rate", "idle_beside_ctl_pct.rate",
+       "scheduler_pass_busy_pct.burst", "batcher_idle_ms.rate", "health_sample_ms.train")
 
 
 def _traced(tmp_path, monkeypatch, cell, planes):
@@ -84,17 +84,17 @@ def _traced(tmp_path, monkeypatch, cell, planes):
 def test_the_readers_on_the_hand_built_trace(tmp_path, monkeypatch, capsys):
     run = _traced(tmp_path, monkeypatch, "cellT", PLANES)
     read = lambda name: manifest.load_reader(name)(run, name)  # noqa: E731
-    assert read("dispatch_host_ms.batch") == pytest.approx((50 + 60 + 30) / 3)
+    assert read("dispatch_host_ms.rate") == pytest.approx((50 + 60 + 30) / 3)
     said = capsys.readouterr().out
     assert '"mean_with_prefill": 60.0' in said  # the split by what the dispatch carried
     assert '"cycle_ms_p50": 100.0' in said  # iterations of 100, 100 and 50 ms
-    assert read("loop_blocked_ms.batch") == pytest.approx((9 + 8 + 8) / 3)
+    assert read("loop_blocked_ms.rate") == pytest.approx((9 + 8 + 8) / 3)
     assert '"sem": ' in capsys.readouterr().out  # the mean's standard error over the window's dispatches
-    assert read("read_lag_ms_p90.batch") == pytest.approx(12, abs=2.3)  # of 0, 1 and 12
-    assert read("idle_beside_ctl_pct.batch") == pytest.approx(100 * 54 / 110)
+    assert read("read_lag_ms_p90.rate") == pytest.approx(12, abs=2.3)  # of 0, 1 and 12
+    assert read("idle_beside_ctl_pct.rate") == pytest.approx(100 * 54 / 110)
     assert read("scheduler_pass_busy_pct.burst") == pytest.approx(100 * 60 / 220)
     assert '"pass_period_ms_p50": 130.0' in capsys.readouterr().out
-    assert read("batcher_idle_ms.batch") == pytest.approx((10 + 9) / 4)  # over every iteration of the window
+    assert read("batcher_idle_ms.rate") == pytest.approx((10 + 9) / 4)  # over every iteration of the window
     assert '"with_work": 1' in capsys.readouterr().out
     assert read("health_sample_ms.train") is None  # a batcher's trace holds no such annotation
     for name in NEW:
@@ -115,7 +115,7 @@ def test_a_supervisors_trace_and_a_program_that_took_no_wait(tmp_path, monkeypat
     assert read("health_sample_ms.train") == pytest.approx(25)
     assert read("loop_blocked_ms.train") == pytest.approx(90 - 60)  # every phase but device, ``other`` among them
     assert read("read_lag_ms_p90.train") == pytest.approx(10)  # the program ended at 80, the read returned at 90
-    assert read("dispatch_host_ms.chat") is None and read("batcher_idle_ms.chat") is None  # no batcher here
+    assert read("dispatch_host_ms.tpot") is None and read("batcher_idle_ms.tpot") is None  # no batcher here
     assert read("scheduler_pass_busy_pct.train") == pytest.approx(100 * 10 / 75)  # 70-80 of the window 5-80
 
 
@@ -130,6 +130,6 @@ def test_a_parents_trace_without_the_new_names_reads_none(tmp_path, monkeypatch)
     run = _traced(tmp_path, monkeypatch, "cellP", planes)
     for name in NEW:
         assert manifest.load_reader(name)(run, name) is None, name
-    assert manifest.load_reader("idle_named_pct.batch")(run, "x") is not None  # the readers it had still read
+    assert manifest.load_reader("idle_named_pct.rate")(run, "x") is not None  # the readers it had still read
     program_trace.load.cache_clear()
     program_threads.load.cache_clear()

@@ -97,7 +97,7 @@ def test_a_trace_without_program_names_reads_as_nothing():
     assert parsed["idle"]["unnamed_s"] == pytest.approx(parsed["idle"]["idle_s"]) == pytest.approx(0.030)
     assert set(parsed["scopes"]["by_scope"]) == {"_unknown"} and parsed["scopes"]["busy_s"] == pytest.approx(0.070)
     run = {"trace": {"busy_s": 0.07}, "cell": {"cell": {"name": "no-such-cell"}}}
-    for name in ("idle_named_pct.train", "attn_time_pct.chat", "attn_time_pct.batch"):
+    for name in ("idle_named_pct.train", "attn_time_pct.tpot", "attn_time_pct.rate"):
         assert manifest.load_reader(name)(run, name) is None  # no trace of that cell in this checkout
 
 
@@ -146,15 +146,15 @@ def test_trace_readers_read_the_cells_newest_traced_run(tmp_path, monkeypatch):
         time.sleep(0.02)
     run = {"trace": {"busy_s": 0.08}, "cell": {"cell": {"name": "cellA"}}}
     busy = 30 + 10 + 40
-    assert manifest.load_reader("attn_time_pct.chat")(run, "attn_time_pct.chat") == pytest.approx(100 * 30 / busy)
-    assert manifest.load_reader("attn_time_pct.batch")(run, "x") == pytest.approx(100 * 30 / busy)
+    assert manifest.load_reader("attn_time_pct.tpot")(run, "attn_time_pct.tpot") == pytest.approx(100 * 30 / busy)
+    assert manifest.load_reader("attn_time_pct.rate")(run, "x") == pytest.approx(100 * 30 / busy)
     # idle 40-60 ms: 15 in emit (40-55), 1 in idle (58-59), 3 in the loop's ``other``, 1 under no name
-    assert manifest.load_reader("idle_named_pct.batch")(run, "x") == pytest.approx(100 * 16 / 20)
-    assert manifest.load_reader("idle_named_pct.batch")({**run, "trace": None}, "x") is None  # an untraced run
+    assert manifest.load_reader("idle_named_pct.rate")(run, "x") == pytest.approx(100 * 16 / 20)
+    assert manifest.load_reader("idle_named_pct.rate")({**run, "trace": None}, "x") is None  # an untraced run
     # a phase's mean over the window's steps: 15 ms in one step and 5 in the other
     assert manifest.load_reader("batcher_emit_ms.batch")(run, "x") == pytest.approx((15 + 5) / 2)
     assert manifest.load_reader("batcher_emit_ms.batch")({**run, "trace": None}, "x") is None
-    for name in ("batcher_admit_ms.batch", "batcher_stage_ms.batch", "batcher_prefill_ms.chat"):
+    for name in ("batcher_admit_ms.batch", "batcher_stage_ms.batch", "batcher_prefill_ms.tpot"):
         assert manifest.load_reader(name)(run, name) is None  # no such annotation in this trace
     program_trace.load.cache_clear()
 
@@ -181,7 +181,7 @@ def test_supervisor_readers_on_a_hand_made_run():
 
 def test_overshoot_reader_on_a_hand_made_run():
     stats = {"slots": 16, "decode_tokens_computed_total": 4000, "decode_tokens_emitted_total": 3700}
-    for name in ("decode_overshoot_pct.chat", "decode_overshoot_pct.batch"):
+    for name in ("decode_overshoot_pct.tpot", "decode_overshoot_pct.rate"):
         assert manifest.load_reader(name)({"engine_stats": stats}, name) == pytest.approx(7.5)
         assert manifest.load_reader(name)({"engine_stats": {"slots": 16}}, name) is None  # a parent's stats
         assert manifest.load_reader(name)({"profile": {}}, name) is None                  # a training run
@@ -204,12 +204,12 @@ def test_request_stage_readers_take_the_requests_the_harness_counted():
                 t += dur
             root.end(t1=t)
         run = {"loop": "open", "ttft_ms": [1750.0, 5250.0, 3500.0]}  # one entry a measured request
-        for name, want in (("engine_queue_ms_p50.chat", 1000.0), ("prefill_wait_ms_p50.chat", 2000.0),
-                           ("prefill_ms_p50.chat", 500.0)):
+        for name, want in (("engine_queue_ms_p50.chat", 1000.0), ("prefill_wait_ms_p50.ttft", 2000.0),
+                           ("prefill_ms_p50.ttft", 500.0)):
             assert manifest.load_reader(name)(run, name) == pytest.approx(want, abs=1.0)
         assert program_trace.request_stage_ms(run, "decode") == pytest.approx([2000.0] * 3, abs=1.0)
-        assert manifest.load_reader("prefill_ms_p50.chat")({"loop": "open", "ttft_ms": []}, "x") is None
-        assert manifest.load_reader("prefill_ms_p50.chat")({**run, "loop": "closed"}, "x") is None
+        assert manifest.load_reader("prefill_ms_p50.ttft")({"loop": "open", "ttft_ms": []}, "x") is None
+        assert manifest.load_reader("prefill_ms_p50.ttft")({**run, "loop": "closed"}, "x") is None
         tracing.set_recorder(tracing.FlightRecorder())  # a parent's program records no such span
         assert manifest.load_reader("engine_queue_ms_p50.chat")(run, "x") is None
     finally:
