@@ -343,3 +343,64 @@ def test_a_positional_leaf_with_a_lane_stride(what, strided_kind):
         priced = layer_state.state_bytes(cfg, 3, 32, jnp.float32, counts=STRIDED_COUNTS)
         assert priced == {"strided": fine.nbytes + coarse.nbytes}
         assert layer_state.split_bytes(priced) == (fine.nbytes + coarse.nbytes, 0)
+
+
+# (f) a ring leaf beside a strided leaf ------------------------------------------
+
+RING = 8
+RING_COUNTS = {"strided": 2, "ringed": 3}
+
+
+@pytest.fixture
+def ring_beside_strided(strided_kind, monkeypatch):
+    """The strided kind and a RING kind in one tree: two leaves shorter than
+    the lanes, one because a row of it stands for ``STRIDE`` lanes, the other
+    because it holds the newest ``RING`` positions and wraps."""
+    monkeypatch.setitem(layer_state.LAYER_KINDS, "ringed", layer_state.LayerKind(
+        positional=True, ring=True,
+        leaves=lambda cfg, lanes, dtype, kv_quant: {"k": layer_state.Leaf((lanes, 3), dtype)}))
+    return strided_kind
+
+
+@pytest.mark.parametrize("what", ["n_lanes", "insert", "insert_short", "slice", "price"])
+def test_a_ring_leaf_beside_a_strided_leaf(what, ring_beside_strided):
+    cfg = ring_beside_strided
+    layers = _random_like(layer_state.init_layers(cfg, 3, 32, jnp.float32, counts=RING_COUNTS, ring_lanes=RING), 8)
+    coarse, ring = layers["strided"]["coarse"], layers["ringed"]["k"]
+    assert coarse.shape == (2, 3, 8, 3) and ring.shape == (3, 3, RING, 3)   # as short as each other
+    if what == "n_lanes":
+        assert layer_state.n_lanes(layers) == 32
+        assert layer_state.lane_stride(layers, coarse) == STRIDE                 # a row per 4 lanes
+        # the ring's leaf is as short, and no stride: the table says so, not the shape
+        assert layer_state.LAYER_KINDS["ringed"].ring and not layer_state.LAYER_KINDS["strided"].ring
+        assert layer_state.ring_bytes(layers) == ring.nbytes and layer_state.lane_bytes(layers, "strided") \
+            == coarse.nbytes + layers["strided"]["fine"].nbytes
+        assert not layer_state.keeps_whole_state(RING_COUNTS)
+        # without ring_lanes a ring kind is as long as the others: the ring that never wraps
+        assert layer_state.init_layers(cfg, 1, 32, jnp.float32, counts=RING_COUNTS)["ringed"]["k"].shape[2] == 32
+    elif what in ("insert", "insert_short"):
+        # a staged row of 24 lanes (flat: lane = position) of which 21 (or 5) are real
+        n = 21 if what == "insert" else 5
+        row = _random_like(layer_state.init_layers(cfg, 1, 24, jnp.float32, counts=RING_COUNTS), 9)
+        pool = serving.SlotCache(layers=layers, lengths=jnp.zeros((3,), jnp.int32))
+        c1 = KVCache(layers=row, pos=jnp.arange(24, dtype=jnp.int32), length=jnp.asarray(n, jnp.int32))
+        got = jax.jit(serving._insert_prefill, static_argnums=(4,))(pool, c1, jnp.int32(2), jnp.int32(n), False)
+        held = np.asarray(layer_state.ring_positions(RING, jnp.asarray(n)))
+        assert sorted(p for p in held.tolist() if p >= 0) == list(range(max(n - RING, 0), n))
+        for m, p in enumerate(held.tolist()):   # lane m holds position p: the newest with p % RING == m
+            if p >= 0:
+                assert p % RING == m
+                assert (np.asarray(got.layers["ringed"]["k"][:, 2, m]) == np.asarray(row["ringed"]["k"][:, 0, p])).all()
+        assert (np.asarray(got.layers["ringed"]["k"][:, :2]) == np.asarray(ring[:, :2])).all()
+        # the strided leaf beside it is inserted at its stride, as ever
+        assert (np.asarray(got.layers["strided"]["coarse"][:, 2, :6]) == np.asarray(row["strided"]["coarse"][:, 0])).all()
+        assert (np.asarray(got.layers["strided"]["fine"][:, 2, :24]) == np.asarray(row["strided"]["fine"][:, 0])).all()
+    elif what == "slice":
+        with pytest.raises(NotImplementedError, match="ringed layers' lanes are a ring"):
+            layer_state.slice_lanes(layers, 16)
+        with pytest.raises(NotImplementedError, match="ringed layers' lanes are a ring"):
+            layer_state.paste_lanes(layers, layers, 16)
+    else:
+        priced = layer_state.state_bytes(cfg, 3, 32, jnp.float32, counts=RING_COUNTS, ring_lanes=RING)
+        assert priced["ringed"] == ring.nbytes == 3 * 3 * RING * 3 * 4
+        assert layer_state.split_bytes(priced) == (sum(a.nbytes for a in jax.tree.leaves(layers)), 0)

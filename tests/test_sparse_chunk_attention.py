@@ -288,3 +288,40 @@ def test_the_latent_pool_is_written_in_place_at_the_long_context_cells_shapes(on
     text = pre.lower(params, jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=one_chip), row,
                      jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile().as_text()
     assert "mla_chunk_attn" in text
+
+
+def test_the_differential_decode_kernel_compiles_and_the_shared_cache_is_never_copied(one_chip, monkeypatch):
+    """``ops.diff_decode`` at Phi-4-mini-flash-reasoning's widths (20 kv-heads of
+    64 = ten pairs of 128 values a lane; 32 slots of 12 288 lanes for the ONE
+    full layer, a ring of 512 for the window layers), inside the decode program
+    of a stack cut to one period of each decoder (6 layers: the pattern's four
+    loops): Mosaic takes the kernel for the full layer, the cross layers and the
+    ring alike; the pool is aliased to the output; and the program's temporaries
+    stay far under ONE copy of the shared cache's keys (XLA's own contractions
+    want the lanes minor and transpose both leaves, 1 GB each, on every step
+    and for every reader: PERF.md, PR 43). A compile, not a run."""
+    from functools import partial
+
+    from tpu_engine import serving
+    from tpu_engine.ops import diff_decode
+
+    monkeypatch.setattr(diff_decode, "on_tpu", lambda: True)  # the described chip: this process's devices are the CPU's
+    types = ("mamba1", "diff_window_attention", "mamba1", "diff_attention", "gmu", "diff_cross_attention")
+    mc = tfm.ModelConfig(name="phi-6-layers", vocab_size=8192, d_model=2560, n_layers=6, n_heads=40, n_kv_heads=20,
+                         d_ff=10240, layer_types=types, sliding_window=512, mamba1_inner=5120, mamba1_state=16,
+                         layer_norm=True, attn_bias=True, rope=False, tie_head=True)
+    put = lambda t: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)  # noqa: E731
+    bf16, B = jnp.bfloat16, 32
+    params = put(jax.eval_shape(lambda k: tfm.init_params(k, mc, dtype=bf16), jax.random.PRNGKey(0)))
+    pool = put(jax.eval_shape(lambda: serving.init_slot_cache(mc, B, 12288, bf16, prefill_chunk=2048)))
+    assert pool.layers["full_attn"]["k"].shape == (1, B, 12288, 1280) and pool.layers["window_attn"]["k"].shape == (1, B, 512, 1280)
+    vec = lambda dt: jax.ShapeDtypeStruct((B,), dt, sharding=one_chip)  # noqa: E731
+    key = put(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    dec = jax.jit(partial(serving.decode_chunk, cfg=mc, n_steps=2, compute_dtype=bf16), donate_argnums=(2,))
+    compiled = dec.lower(params, vec(jnp.int32), pool, vec(jnp.bool_), vec(jnp.float32), vec(jnp.int32),
+                         vec(jnp.int32), key).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert text.count("diff_decode") >= 3 and "tpu_custom_call" in text
+    keys = B * 12288 * 1280 * 2
+    assert memory.alias_size_in_bytes >= 2 * keys
+    assert memory.temp_size_in_bytes < keys // 4

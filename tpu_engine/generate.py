@@ -66,7 +66,7 @@ from tpu_engine.models.transformer import (
     served_format,
     unembed,
 )
-from tpu_engine.ops import mla_decode, sparse_block_attention, ssd_update
+from tpu_engine.ops import diff_decode, mla_decode, sparse_block_attention, ssd_update
 from tpu_engine.quant import QuantWeight, dequantize_weight
 
 _NEG_INF = -1e30
@@ -118,7 +118,7 @@ def ring_lanes(cfg: ModelConfig, max_len: int,
     resident). THE single source of this formula — the serving slot pool
     copies a single-row ring cache into its own lanes and is only correct
     because both sides size lanes identically."""
-    if not cfg.sliding_window:
+    if not cfg.sliding_window or cfg.is_hybrid:  # a hybrid's window is its window KIND's (layer_state)
         return max_len
     chunk = max_len if chunk is None else chunk
     return min(max_len, cfg.sliding_window + chunk - 1)
@@ -904,8 +904,274 @@ def _mla_block(x, lp, latent, at, write, slot_pos, positions, valid, cfg: ModelC
     return _mlp_block(x, lp, cfg, valid, tally, dense=dense), latent
 
 
+# ---------------------------------------------------------------------------
+# A decoder-hybrid-decoder stack: Mamba-1, differential attention (window,
+# full, cross) and gated memory units
+# ---------------------------------------------------------------------------
+
+# Queries a chunk's differential attention scores at a time (float32, for
+# every head against the lanes that block of queries can see).
+DIFF_QUERY_BLOCK = 512
+# The sub-norm of differential attention: the published code's default.
+_SUB_NORM_EPS = 1e-5
+# Positions one iteration of the Mamba-1 chunk scan's loop walks.
+_MAMBA1_UNROLL = 16
+
+
+def _mamba1_scan(x, dt, A, Bm, Cm, h):
+    """The selective scan over a chunk, position by position: x, dt [B,T,I]
+    (dt float32, 0 where a position must leave the state as it was), A [N,I]
+    (negative), Bm, Cm [B,T,N], h [B,N,I] float32 the state entering. Returns
+    (y [B,T,I] float32 without the skip term, the state leaving). The decay is
+    per (state, channel) pair, so there is no chunked (SSD) form: a step is
+    :func:`_mamba1_step`, and the loop carries ``h`` alone."""
+    def step(h, xs):
+        y, h = _mamba1_step(*xs, A, h)
+        return h, y
+
+    h, y = lax.scan(step, h, tuple(jnp.moveaxis(a, 1, 0) for a in (x, dt, Bm, Cm)),
+                    unroll=min(_MAMBA1_UNROLL, x.shape[1]))
+    return jnp.moveaxis(y, 0, 1), h
+
+
+def _mamba1_step(x, dt, Bm, Cm, A, h):
+    """One recurrence step, all float32 elementwise: x, dt [B,I], Bm, Cm [B,N],
+    A [N,I], h [B,N,I]: ``h' = exp(dt A) h + (dt x) B``, ``y = sum_n C h'``."""
+    f32 = jnp.float32
+    h = jnp.exp(dt[:, None, :] * A) * h \
+        + (dt * x.astype(f32))[:, None, :] * Bm.astype(f32)[:, :, None]
+    return jnp.sum(h * Cm.astype(f32)[:, :, None], axis=1), h
+
+
+def _mamba1_block(x, lp, state, conv, at, valid, cfg: ModelConfig, tally=None):
+    """One Mamba-1 layer, then the block every kind has (:func:`_mlp_block`).
+
+    ``(u, z) = h W_in``; ``u = silu(conv(u) + b)`` (causal, depthwise, over
+    the last taps-1 inputs kept in ``conv`` and the new); ``(dt_r, B, C) = u
+    W_x``; ``dt = softplus(dt_r W_dt + dt_bias)``; ``s_t = exp(dt_t A) s_{t-1}
+    + (dt_t u_t) B_t`` per (state, channel), ``A = -exp(A_log)``; ``m_t = sum_n
+    C_t s_t + D u_t``; output ``(m * silu(z)) W_out``. ``state`` [L,B,N,I]
+    float32 and ``conv`` [L,B,taps-1,I] are the kind's whole leaves; this
+    layer reads and rewrites its own slice, ``at``, under ``mamba1_update``
+    (one token) or ``mamba1_scan`` (a chunk). ``valid`` [B,T] marks the real
+    positions, a PREFIX of each row: a position that is not valid leaves both
+    states exactly as they were (``dt = 0``; the convolution state is taken at
+    the row's true length). Returns (x, ``m`` [B,T,I] in x's dtype: the scan
+    output BEFORE the gate, the memory a later gated memory unit reads,
+    state, conv)."""
+    B, T, _ = x.shape
+    I, N, R, K = cfg.mamba1_inner, cfg.mamba1_state, cfg.mamba1_rank, cfg.ssm_conv
+    f32 = jnp.float32
+    with jax.named_scope("mamba1"):
+        hn = _norm(x, lp["mixer_norm"], cfg)
+        with jax.named_scope("mamba1_in_proj"):
+            uz = _proj(hn, lp["in_proj"]["kernel"])
+            u, z = uz[..., :I], uz[..., I:]
+        with jax.named_scope("mamba1_conv"):
+            window = jnp.concatenate([layer_slice(conv, at).astype(u.dtype), u], axis=1)
+            w = lp["conv"]["kernel"].astype(f32)                  # [K, I]
+            acc = lp["conv"]["bias"].astype(f32)
+            for k in range(K):
+                acc = acc + window[:, k:k + T].astype(f32) * w[k]
+            u = jax.nn.silu(acc).astype(x.dtype)
+            if T == 1:
+                conv_state = jnp.where(valid[:, :, None], window[:, 1:], window[:, :-1])
+            else:
+                n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
+                conv_state = jax.vmap(
+                    lambda win, n: lax.dynamic_slice_in_dim(win, n, K - 1, 0))(window, n_valid)
+            conv = lax.dynamic_update_index_in_dim(conv, conv_state.astype(conv.dtype), at, 0)
+        with jax.named_scope("mamba1_x_proj"):
+            dbc = _proj(u, lp["x_proj"]["kernel"])
+            Bm, Cm = dbc[..., R:R + N], dbc[..., R + N:]
+            dt = jax.nn.softplus(_proj(dbc[..., :R], lp["dt_proj"]["kernel"]).astype(f32)
+                                 + lp["dt_bias"].astype(f32))
+            dt = jnp.where(valid[:, :, None], dt, 0.0)            # [B,T,I]
+        A = -jnp.exp(lp["A_log"].astype(f32))                     # [N,I]
+        h = layer_slice(state, at)
+        if T == 1:
+            with jax.named_scope("mamba1_update"):
+                y, h = _mamba1_step(u[:, 0], dt[:, 0], Bm[:, 0], Cm[:, 0], A, h)
+                y = y[:, None]
+                state = lax.dynamic_update_index_in_dim(state, h, at, 0)
+        else:
+            with jax.named_scope("mamba1_scan"):
+                y, h = _mamba1_scan(u, dt, A, Bm, Cm, h)
+                state = lax.dynamic_update_index_in_dim(state, h, at, 0)
+        m = y + lp["D"].astype(f32) * u.astype(f32)
+        with jax.named_scope("mamba1_gate"):
+            g = (m * jax.nn.silu(z.astype(f32))).astype(x.dtype)
+        with jax.named_scope("mamba1_out_proj"):
+            x = _residual(x, _proj(g, lp["out_proj"]["kernel"]), cfg)
+    return _mlp_block(x, lp, cfg, valid, tally), m.astype(x.dtype), state, conv
+
+
+def _diff_attend(q, kc, vc, key_pos, positions, lp, cfg: ModelConfig, window: int, scope: str):
+    """Differential attention of q [B,T,H x HD] over keys and values kc, vc
+    [B,S,KV x HD] that hold the positions ``key_pos`` [B,S] (-1: none).
+
+    The H query heads are H/2 pairs ``(q1, q2)``, the KV kv-heads KV/2 pairs
+    ``(k1, k2)``, ``(v1, v2)`` (interleaved: heads 2j and 2j+1), G query pairs
+    a kv pair; ``V = v1 | v2``; ``a1 = softmax(q1 k1^T / sqrt(HD)) V``, ``a2``
+    alike from ``(q2, k2)``; ``lambda = exp(l_q1 . l_k1) - exp(l_q2 . l_k2) +
+    lambda_init``; the output is ``RMSNorm(a1 - lambda a2) (1 - lambda_init)``
+    per pair, 2 HD wide. A kv pair lies in the cache as 2 HD = 128 values
+    ``k1 | k2``, so the two softmaxes are ONE grouped-query attention over rows
+    of 128: ``q1 | 0`` and ``0 | q2`` against the pair's row as it lies, and
+    no half of a row is sliced out. A query at position p sees the keys at
+    ``p - window < k <= p`` (``window`` 0: every key up to p). Scope ``scope``
+    (``window_attn`` | ``full_attn`` | ``cross_attn``) holds the two
+    contractions, ``diff_combine`` what follows."""
+    B, T, _ = q.shape
+    HD, P = cfg.head_dim, cfg.n_kv_heads // 2
+    S = kc.shape[1]
+    with jax.named_scope(scope):
+        qz = _diff_queries(q, cfg)
+        kc, vc = kc.reshape(B, S, P, 2 * HD), vc.reshape(B, S, P, 2 * HD)
+        scores = jnp.einsum("btkgd,bmkd->bkgtm", qz, kc,
+                            preferred_element_type=jnp.float32) * attention_scale(cfg)
+        kp = key_pos[:, None, :]
+        mask = (kp >= 0) & (kp <= positions[:, :, None])
+        if window:
+            mask &= kp > positions[:, :, None] - window
+        scores = jnp.where(mask[:, None, None, :, :], scores, _NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        a = jnp.einsum("bkgtm,bmkd->btkgd", probs, vc, preferred_element_type=jnp.float32)
+    return _diff_combine(a, lp, cfg, q.dtype)
+
+
+def _diff_queries(q, cfg: ModelConfig):
+    """q [B,T,H x HD] as the rows that attend each kv pair's 2 HD-wide keys as
+    they lie: [B,T,P,2 G,2 HD], a query pair's ``q1 | 0`` then ``0 | q2``."""
+    B, T, _ = q.shape
+    HD, P, G = cfg.head_dim, cfg.n_kv_heads // 2, cfg.n_heads // cfg.n_kv_heads
+    qp = q.reshape(B, T, P, G, 2, HD)
+    zero = jnp.zeros_like(qp[..., 0, :])
+    qz = jnp.stack([jnp.concatenate([qp[..., 0, :], zero], axis=-1),
+                    jnp.concatenate([zero, qp[..., 1, :]], axis=-1)], axis=4)
+    return qz.reshape(B, T, P, 2 * G, 2 * HD)
+
+
+def _diff_combine(a, lp, cfg: ModelConfig, dtype):
+    """What follows the two softmaxes (scope ``diff_combine``): a [B,T,P,2 G,
+    2 HD] float32, a query pair's ``a1`` then ``a2`` -> [B,T,H x HD]."""
+    B, T = a.shape[:2]
+    HD, P, G = cfg.head_dim, cfg.n_kv_heads // 2, cfg.n_heads // cfg.n_kv_heads
+    with jax.named_scope("diff_combine"):
+        a = a.reshape(B, T, P, G, 2, 2 * HD)
+        lam = lp["lambdas"].astype(jnp.float32)                    # [4, HD]: q1, k1, q2, k2
+        lam_init = lp["lambda_init"].astype(jnp.float32)
+        lam_full = jnp.exp(jnp.sum(lam[0] * lam[1])) - jnp.exp(jnp.sum(lam[2] * lam[3])) + lam_init
+        d = a[..., 0, :] - lam_full * a[..., 1, :]
+        d = d * lax.rsqrt(jnp.mean(jnp.square(d), -1, keepdims=True) + _SUB_NORM_EPS)
+        d = d * lp["sub_norm"]["scale"].astype(jnp.float32) * (1.0 - lam_init)
+        return d.reshape(B, T, P * G * 2 * HD).astype(dtype)
+
+
+def _diff_attention(q, k_arr, v_arr, at, positions, lp, cfg: ModelConfig, window: int, scope: str):
+    """:func:`_diff_attend` of q [B,T,H x HD] at ``positions`` [B,T] over layer
+    ``at`` of ``k_arr`` / ``v_arr`` ``[L,B,M,KV x HD]``, whose lanes hold what
+    ``layer_state.ring_positions`` says for rows as long as their last
+    position + 1 (a leaf as long as the row: position m in lane m).
+
+    ``DIFF_QUERY_BLOCK`` queries at a time. Under a window a block of queries
+    reads only the ``block + window - 1`` lanes it can see, sliced out of the
+    layer where the leaf is longer than that: a staged cache's leaf (lockstep
+    rows, never wrapped, which :func:`init_cache` allocates) at a chunk; the
+    pool's ring is no longer than a window and is read whole."""
+    B, T = positions.shape
+    M = k_arr.shape[2]
+    length = positions[:, -1] + 1
+    Tq = min(T, DIFF_QUERY_BLOCK)
+    S = min(M, Tq + window - 1) if window else M
+    if T == 1 and S == M and M <= (window or M) and diff_decode.engages(k_arr):
+        # One query a row against the layer as it lies, the lanes a row has and
+        # no others (of a ring no longer than the window: all it holds is seen).
+        with jax.named_scope(scope):
+            a = diff_decode.diff_decode(_diff_queries(q, cfg)[:, 0], k_arr, v_arr, at,
+                                        jnp.minimum(length, M), scale=attention_scale(cfg))
+        return _diff_combine(a[:, None], lp, cfg, q.dtype)
+
+    def attend(xs):
+        qb, pb = xs
+        if S == M:
+            kc, vc, start = layer_slice(k_arr, at), layer_slice(v_arr, at), 0
+        else:  # the lanes up to the block's last query
+            start = jnp.clip(pb[0, -1] + 1 - S, 0, M - S)
+            cut = lambda a: lax.dynamic_slice(a, (at, 0, start, 0), (1, B, S, a.shape[3]))[0]  # noqa: E731
+            kc, vc = cut(k_arr), cut(v_arr)
+        key_pos = layer_state.ring_positions(M, length, start, S)
+        return _diff_attend(qb, kc, vc, key_pos, pb, lp, cfg, window, scope)
+
+    if T == Tq:
+        return attend((q, positions))
+    # the last query block's padding repeats its last query
+    o = lax.map(attend, (_time_blocks(q, Tq, "edge"), _time_blocks(positions, Tq, "edge")))
+    return jnp.moveaxis(o, 0, 1).reshape(B, -1, q.shape[-1])[:, :T]
+
+
+def _diff_proj(h, lp, name: str):
+    return _proj(h, lp[name]["kernel"], bias=lp[name].get("bias"))
+
+
+def _diff_attn_block(x, lp, k_arr, v_arr, at, write, positions, valid, cfg: ModelConfig,
+                     window: int, scope: str, tally=None, row=None, write_only: bool = False):
+    """One differential-attention layer with keys and values of its own
+    (``window`` > 0: a window layer, whose leaves the pool keeps as a ring;
+    0: a full layer), then the block every kind has. ``write`` stores the
+    chunk's keys and values in layer ``at``'s lanes of the kind's whole leaves
+    ``[L,B,M,KV x HD]`` (scope ``kv_write``), which are then only read
+    (:func:`_diff_attention`, under ``decode_attn``). No rotation, no learned
+    positions.
+
+    ``write_only``: the write and nothing else (a prompt's chunk that is not
+    its last, at the layer whose cache the cross-decoder reads). ``row``
+    (traced index): keys and values of every position are written, and the
+    layer goes on for position ``row`` of the chunk ALONE, x [B,1,D] leaving.
+    Returns (x, k_arr, v_arr)."""
+    with jax.named_scope(scope + "_layer"):
+        h = _norm(x, lp["mixer_norm"], cfg)
+        with jax.named_scope("kv_write"):
+            k_arr = write(k_arr, _diff_proj(h, lp, "k"), at, ring=bool(window))
+            v_arr = write(v_arr, _diff_proj(h, lp, "v"), at, ring=bool(window))
+        if write_only:
+            return None, k_arr, v_arr
+        if row is not None:
+            x, h, positions, valid = (lax.dynamic_slice_in_dim(a, row, 1, 1) for a in (x, h, positions, valid))
+        with jax.named_scope("decode_attn"):
+            o = _diff_attention(_diff_proj(h, lp, "q"), k_arr, v_arr, at, positions, lp, cfg, window, scope)
+        x = _residual(x, _diff_proj(o, lp, "o"), cfg)
+    return _mlp_block(x, lp, cfg, valid, tally), k_arr, v_arr
+
+
+def _cross_attn_block(x, lp, k_arr, v_arr, at, positions, valid, cfg: ModelConfig, tally=None):
+    """One cross-attention layer: queries of its own, keys and values those of
+    layer ``at`` of ANOTHER kind's leaves (the one full-attention cache), which
+    it reads and never writes; differential attention with the layer's own
+    lambdas. Then the block every kind has. Returns x."""
+    with jax.named_scope("cross_attn_layer"):
+        h = _norm(x, lp["mixer_norm"], cfg)
+        with jax.named_scope("decode_attn"):
+            o = _diff_attention(_diff_proj(h, lp, "q"), k_arr, v_arr, at, positions, lp, cfg, 0, "cross_attn")
+        x = _residual(x, _diff_proj(o, lp, "o"), cfg)
+    return _mlp_block(x, lp, cfg, valid, tally)
+
+
+def _gmu_block(x, lp, mem, valid, cfg: ModelConfig, tally=None):
+    """One gated memory unit: ``(m * silu(h W_1)) W_2`` with ``m`` [B,T,I] the
+    memory carried along the walk (the scan output of the last Mamba-1 layer
+    before it, at the SAME positions): an activation, nothing cached. Then the
+    block every kind has. Returns x."""
+    with jax.named_scope("gmu"):
+        h = _norm(x, lp["mixer_norm"], cfg)
+        gate = jax.nn.silu(_proj(h, lp["in_proj"]["kernel"]).astype(jnp.float32))
+        o = (mem.astype(jnp.float32) * gate).astype(x.dtype)
+        x = _residual(x, _proj(o, lp["out_proj"]["kernel"]), cfg)
+    return _mlp_block(x, lp, cfg, valid, tally)
+
+
 def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
-                valid=None):
+                valid=None, ingest_only: bool = False, tail_row=None):
     """Walk the stack against (and into) ``cache`` — THE one cached walk, for
     every architecture, a :class:`KVCache` or the serving pool alike (both
     hold their per-layer arrays as ``cache.layers``, the tree by kind of
@@ -941,9 +1207,29 @@ def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
     carries ``moe_counts`` has each mixture layer's :data:`MOE_COUNTS` added
     to it (carried beside ``x``; a cache without counts none and nothing
     more). Returns ``(x, cache)`` with ``cache.layers`` (and the counts)
-    replaced."""
+    replaced.
+
+    A DECODER-HYBRID-DECODER stack (``cfg.layer_periods()``: a stretch that
+    alternates is one loop over its period, 8 x (mamba1, window_attn), not
+    sixteen runs of one) adds two things to the walk. Its layer functions take
+    ``(x, lp, at, state, mem, tally, kind, view)``, the WHOLE tree, the carried
+    memory ``mem`` [B, T, I] (a Mamba-1 layer's scan output before its gate,
+    which the gated memory units after it read) and ``view`` = (positions,
+    valid) of the positions the layer runs at, and return ``(x, leaves, mem)``: a
+    cross-attention layer owns no leaves and reads the full-attention kind's.
+    And a prompt's chunk need not run it whole: logits are wanted at a
+    prompt's last position only, and that position's cross-decoder (the
+    layers after ``cfg.cross_decoder_start``) needs the cross-decoder at no
+    other position. ``ingest_only``: the walk ends with the keys and values
+    that layer writes (a chunk that is not a prompt's last; ``x`` returned is
+    None). ``tail_row`` (traced index into the chunk): from that layer's
+    attention on the stack runs at position ``tail_row`` alone, and ``x``
+    returned is [B, 1, D]. Neither: every layer at every position (decode)."""
     require_served_format(stacks, x.dtype)
     stacks = stacks if cfg.is_hybrid else {"attn": stacks}
+    shared_at = cfg.cross_decoder_start
+    if (ingest_only or tail_row is not None) and shared_at is None:
+        raise ValueError(f"model {cfg.name!r} has no cross-decoder to leave out of a prompt's chunk")
 
     def attn_layer(x, lp, at, s, tally):
         x, k, v, k_scale, v_scale = _decode_block(
@@ -971,24 +1257,75 @@ def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
                                cfg, tally, dense)
         return x, {"latent": latent}
 
-    layer_fns = {"attn": attn_layer, "ssm": ssm_layer,
-                 "sparse_attn": sparse_attn_layer, "lightning": lightning_layer,
-                 "mla": mla_layer, "mla_dense": partial(mla_layer, dense=True)}
+    def own(fn):  # a kind that reads and rewrites its own leaves and nothing else
+        def layer(x, lp, at, state, mem, tally, kind, view):
+            x, leaves = fn(x, lp, at, state[kind], tally)
+            return x, leaves, mem
+        return layer
+
+    # A decoder-hybrid-decoder kind is handed what it sees of the chunk, ``view``
+    # = (positions, valid): the walk narrows it to one position once it has
+    # passed the shared layer under ``tail_row``.
+    def mamba1_layer(x, lp, at, state, mem, tally, kind, view):
+        s = state[kind]
+        x, mem, h, conv = _mamba1_block(x, lp, s["state"], s["conv"], at, view[1], cfg, tally)
+        return x, {"state": h, "conv": conv}, mem
+
+    def diff_attn_layer(x, lp, at, state, mem, tally, kind, view, **tail):
+        s = state[kind]
+        x, k, v = _diff_attn_block(
+            x, lp, s["k"], s["v"], at, write, view[0], view[1], cfg,
+            cfg.sliding_window if kind == "window_attn" else 0, kind, tally, **tail)
+        return x, {"k": k, "v": v}, mem
+
+    def cross_attn_layer(x, lp, at, state, mem, tally, kind, view):
+        shared = state["full_attn"]  # the one cache: its last layer's keys and values
+        x = _cross_attn_block(x, lp, shared["k"], shared["v"], shared["k"].shape[0] - 1,
+                              view[0], view[1], cfg, tally)
+        return x, state[kind], mem
+
+    def gmu_layer(x, lp, at, state, mem, tally, kind, view):
+        return _gmu_block(x, lp, mem, view[1], cfg, tally), state[kind], mem
+
+    layer_fns = {"attn": own(attn_layer), "ssm": own(ssm_layer),
+                 "sparse_attn": own(sparse_attn_layer), "lightning": own(lightning_layer),
+                 "mla": own(mla_layer), "mla_dense": own(partial(mla_layer, dense=True)),
+                 "mamba1": mamba1_layer, "window_attn": diff_attn_layer, "full_attn": diff_attn_layer,
+                 "cross_attn": cross_attn_layer, "gmu": gmu_layer}
     state, counts = cache.layers, cache.moe_counts
-    for kind, first, count in cfg.layer_runs():
+    # The memory the gated memory units read rides beside ``x`` (a stack
+    # without them carries none, and not a leaf more).
+    mem = jnp.zeros(x.shape[:2] + (cfg.mamba1_inner,), x.dtype) if "gmu" in state else None
+    walked = 0  # layers behind the walk
+    view = (positions, valid)
+    for kinds, firsts, count in cfg.layer_periods():
 
-        def body(carry, i, kind=kind, first=first):
-            x, state, counts = carry
-            at = first + i
-            lp = jax.tree.map(lambda a: layer_slice(a, at), stacks[kind])
-            tally = None if counts is None else []
-            x, leaves = layer_fns[kind](x, lp, at, state[kind], tally)
-            if tally:
-                counts = counts + sum(tally)
-            return (x, {**state, kind: leaves}, counts), None
+        def body(carry, i, kinds=kinds, firsts=firsts, view=view, **tail):
+            x, state, counts, mem = carry
+            for kind, first in zip(kinds, firsts):
+                at = first + i
+                lp = jax.tree.map(lambda a: layer_slice(a, at), stacks[kind])
+                tally = None if counts is None else []
+                x, leaves, mem = layer_fns[kind](x, lp, at, state, mem, tally, kind, view, **tail)
+                if tally:
+                    counts = counts + sum(tally)
+                state = {**state, kind: leaves}
+            return (x, state, counts, mem), None
 
-        (x, state, counts), _ = lax.scan(body, (x, state, counts),
-                                         jnp.arange(count, dtype=jnp.int32))
+        if (ingest_only or tail_row is not None) and walked == shared_at:
+            if count != 1 or kinds != ("full_attn",):
+                raise NotImplementedError("the layer whose cache the cross-decoder reads must stand alone "
+                                          f"in the pattern to split a prompt's chunk at it (got {kinds} x {count})")
+            tail = {"write_only": True} if ingest_only else {"row": tail_row}
+            (x, state, counts, mem), _ = body((x, state, counts, mem), jnp.int32(0), **tail)
+            if ingest_only:
+                break
+            view = tuple(lax.dynamic_slice_in_dim(a, tail_row, 1, 1) for a in view)
+            mem = lax.dynamic_slice_in_dim(mem, tail_row, 1, 1)
+        else:
+            (x, state, counts, mem), _ = lax.scan(body, (x, state, counts, mem),
+                                                  jnp.arange(count, dtype=jnp.int32))
+        walked += len(kinds) * count
     return x, dataclasses.replace(cache, layers=state, moe_counts=counts)
 
 
@@ -1000,6 +1337,8 @@ def forward_with_cache(
     compute_dtype=jnp.bfloat16,
     want_logits: bool = True,
     n_valid: Optional[jax.Array] = None,
+    ingest_only: bool = False,
+    logits_row: Optional[jax.Array] = None,
 ) -> tuple[Optional[jax.Array], KVCache]:
     """Run ``tokens`` [B, T] through the stack against (and into) ``cache``.
 
@@ -1022,6 +1361,11 @@ def forward_with_cache(
     ``want_logits=False`` (static) skips the unembed entirely and returns
     ``(None, cache)`` — cache-ingestion-only callers (the speculative
     draft's prompt prefill) should not pay a T×D×V matmul per chunk.
+    A decoder-hybrid-decoder stack's prompt takes two programs
+    (:func:`scan_layers`): ``ingest_only`` (static) for a chunk that is not
+    its last — the self-decoder and the shared layer's keys and values,
+    ``(None, cache)`` — and ``logits_row`` (traced index) for its last: the
+    cross-decoder and the head at that position alone, logits [B, 1, V].
 
     For non-ring caches the caller must keep ``cache.length + T <=
     cache.max_len`` (size the cache to prompt + max_new_tokens, as
@@ -1083,7 +1427,8 @@ def forward_with_cache(
         offset = cache.length % M if cache.ring else cache.length
         pos_new = lax.dynamic_update_slice(cache.pos, new_pos, (offset,))
 
-        def write(cache_arr, rows, at):
+        def write(cache_arr, rows, at, ring=False):
+            # (a ring kind's leaves are as long as the cache here: they never wrap)
             return lax.dynamic_update_slice(
                 cache_arr, rows[None].astype(cache_arr.dtype),
                 (at, 0, offset) + (0,) * (cache_arr.ndim - 3))
@@ -1094,8 +1439,10 @@ def forward_with_cache(
     valid = jnp.broadcast_to(
         jnp.arange(T)[None, :] < (T if n_valid is None else n_valid), (B, T))
     x, cache = scan_layers(x, params["layers"], cfg,
-                           cache, write, pos_new, positions, valid)
-    logits = unembed(params, x, cfg) if want_logits else None
+                           cache, write, pos_new, positions, valid,
+                           **({"ingest_only": True} if ingest_only else {}),
+                           **({} if logits_row is None else {"tail_row": logits_row}))
+    logits = unembed(params, x, cfg) if want_logits and not ingest_only else None
     return logits, dataclasses.replace(cache, pos=pos_new,
                                        length=cache.length + T)
 

@@ -480,8 +480,16 @@ def estimate_serving_hbm(
         notes.append(f"kv pool replicated: {cfg.n_kv_heads} kv-heads !% model={tp}")
     if kv_quant:
         notes.append("kv pool: int8 codes + per-(lane, kv-head) fp32 scales")
-    kv_pool, recurrent = layer_state.split_bytes(
-        layer_state.state_bytes(cfg, slots, lanes, dtype, kv_quant, tp))
+    # A hybrid's window layers keep a ring of one window beside the full
+    # kinds' lanes (``init_slot_cache``): lanes per kind, from the same table.
+    window = cfg.sliding_window if cfg.is_hybrid else None
+    by_kind = layer_state.state_bytes(cfg, slots, lanes, dtype, kv_quant, tp, ring_lanes=window)
+    kv_pool, recurrent = layer_state.split_bytes(by_kind)
+    if window:
+        rings = {k: n for k, n in layer_state.layer_counts(cfg).items() if layer_state.LAYER_KINDS[k].ring}
+        notes.append(
+            f"window rings: {sum(rings.values())} window layers x {slots} slots x {min(lanes, window)} lanes "
+            f"beside {lanes} lanes of the full kinds ({sum(by_kind[k] for k in rings) / _GIB:.3f} GiB)")
     if recurrent:
         counts = layer_state.layer_counts(cfg)
         whole = {k: n for k, n in counts.items() if layer_state.keeps_whole_state([k])}
@@ -516,6 +524,10 @@ def estimate_serving_hbm(
         # (``generate.MLA_QUERY_BLOCK``).
         working += (lanes * cfg.n_heads * (cfg.qk_nope_dim + cfg.v_head_dim) * compute_b
                     + cfg.n_heads * min(chunk, MLA_QUERY_BLOCK) * lanes * (4 + compute_b)) / tp
+    if window:
+        # One prompt's staging row holds the window layers at full lanes (a
+        # pool row holds a ring of them): far more than a row of the pool.
+        working += sum(layer_state.state_bytes(cfg, 1, lanes, dtype, kv_quant, tp).values())
     if pool_role == "prefill":
         working *= 2
         notes.append(

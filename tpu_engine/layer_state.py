@@ -20,6 +20,19 @@ row per lane: one whose axis 2 is shorter holds a row per ``stride`` lanes
 (a sparse-attention layer's compressed keys, one per 16), and whoever cuts
 lanes cuts that leaf at ``lanes // stride`` (:func:`lane_stride`).
 
+A positional kind may be a **ring**: its leaves hold fewer lanes than the
+others', NOT a row per ``stride`` lanes but the newest ``R`` positions, position
+``p`` in lane ``p % R`` (a windowed attention layer beside full ones: two lane
+counts in one pool). Which position a lane holds follows from the row's length
+alone (:func:`ring_positions`), so a ring keeps no bookkeeping of its own; a
+leaf with as many lanes as the row is long is the ring that never wrapped, and
+an insert lays a row's newest positions out for the pool's ring whatever lane
+count staged them. A ring has no stable lanes to slice or paste.
+
+A kind may keep NOTHING (a cross-attention layer reads another kind's leaves,
+a gated memory unit an activation carried along the walk): it is in the table
+with no leaves, so that every layer of a stack has its kind.
+
 A new kind of state is one entry here and a layer function in
 ``generate.scan_layers``; the cache manager, the wire's two ends and the
 estimator read the table.
@@ -51,6 +64,7 @@ class LayerKind(NamedTuple):
     positional: bool
     leaves: Callable[..., dict]  # (cfg, lanes, dtype, kv_quant) -> {name: Leaf}
     label: str = ""              # what an operator's note calls such a layer
+    ring: bool = False           # positional, and its lanes the newest positions (``p % R``)
 
 
 def _attn_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
@@ -127,6 +141,32 @@ def _latent_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
     return {"latent": Leaf((lanes, latent_row_width(cfg)), dtype)}
 
 
+def _mamba1_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
+    """A Mamba-1 layer's state after the last REAL token fed, per (state,
+    channel) pair — channels MINOR, so that a tile of it is whole, where
+    ``[channels, 16]`` would pad every row of 16 to a tile's 128 columns —
+    float32, and the last taps-1 convolution inputs in the compute dtype."""
+    return {
+        "state": Leaf((cfg.mamba1_state, cfg.mamba1_inner), jnp.float32),
+        "conv": Leaf((cfg.ssm_conv - 1, cfg.mamba1_inner), dtype),
+    }
+
+
+def _diff_attn_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
+    """A differential-attention layer's keys and values per lane, the kv-heads
+    side by side in the last dim (``[lanes, KV x HD]``, as the sparse kind's: a
+    head of 64 would leave half a tile's columns empty): a PAIR of heads is
+    128 values, ``k1 | k2`` and ``v1 | v2``, which is how the layer reads them."""
+    if kv_quant:
+        raise NotImplementedError("differential-attention layers keep no int8 keys and values (kv_quant)")
+    rows = Leaf((lanes, cfg.n_kv_heads * cfg.head_dim), dtype)
+    return {"k": rows, "v": rows}
+
+
+def _no_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
+    return {}
+
+
 LAYER_KINDS: dict[str, LayerKind] = {
     "attn": LayerKind(positional=True, leaves=_attn_leaves, label="attention"),
     "ssm": LayerKind(positional=False, leaves=_ssm_leaves, label="Mamba-2"),
@@ -136,6 +176,13 @@ LAYER_KINDS: dict[str, LayerKind] = {
     # and the rest (mixture) are stacked, scanned and cached apart
     "mla": LayerKind(positional=True, leaves=_latent_leaves, label="latent-attention"),
     "mla_dense": LayerKind(positional=True, leaves=_latent_leaves, label="latent-attention"),
+    # a decoder-hybrid-decoder stack: the window layers' ring beside the ONE
+    # full layer's lanes, which the cross-attention layers read and do not own
+    "mamba1": LayerKind(positional=False, leaves=_mamba1_leaves, label="Mamba-1"),
+    "window_attn": LayerKind(positional=True, leaves=_diff_attn_leaves, label="window-attention", ring=True),
+    "full_attn": LayerKind(positional=True, leaves=_diff_attn_leaves, label="attention"),
+    "cross_attn": LayerKind(positional=True, leaves=_no_leaves, label="cross-attention"),
+    "gmu": LayerKind(positional=True, leaves=_no_leaves, label="gated-memory"),
 }
 
 
@@ -158,20 +205,25 @@ def keeps_whole_state(kinds: Iterable[str]) -> bool:
 # The one allocation, and its price.
 
 def leaf_specs(cfg, kinds: Iterable[str], lanes: int, dtype,
-               kv_quant: bool = False) -> dict:
-    """``{kind: {leaf: Leaf}}``: what each of ``kinds`` keeps for one row."""
-    return {kind: LAYER_KINDS[kind].leaves(cfg, lanes, dtype, kv_quant)
+               kv_quant: bool = False, ring_lanes: Optional[int] = None) -> dict:
+    """``{kind: {leaf: Leaf}}``: what each of ``kinds`` keeps for one row, a
+    ring kind at ``ring_lanes`` lanes (None: at ``lanes``, a ring that never
+    wraps)."""
+    return {kind: LAYER_KINDS[kind].leaves(
+                cfg, min(lanes, ring_lanes) if ring_lanes and LAYER_KINDS[kind].ring else lanes,
+                dtype, kv_quant)
             for kind in kinds}
 
 
 def init_layers(cfg, rows: int, lanes: int, dtype, kv_quant: bool = False,
-                counts: Optional[dict] = None) -> dict:
+                counts: Optional[dict] = None, ring_lanes: Optional[int] = None) -> dict:
     """Zeros for ``rows`` rows: ``{kind: {leaf: [L_kind, rows, *shape]}}``, for
     ``cfg``'s stack (or for ``counts``, kind -> layers)."""
     counts = layer_counts(cfg) if counts is None else counts
+    specs = leaf_specs(cfg, counts, lanes, dtype, kv_quant, ring_lanes)
     return {kind: {name: jnp.zeros((counts[kind], rows) + leaf.shape, leaf.dtype)
                    for name, leaf in leaves.items()}
-            for kind, leaves in leaf_specs(cfg, counts, lanes, dtype, kv_quant).items()}
+            for kind, leaves in specs.items()}
 
 
 def _model_sharded(leaf: Leaf, tp: int) -> bool:
@@ -179,15 +231,17 @@ def _model_sharded(leaf: Leaf, tp: int) -> bool:
 
 
 def state_bytes(cfg, rows: int, lanes: int, dtype, kv_quant: bool = False,
-                tp: int = 1, counts: Optional[dict] = None) -> dict:
+                tp: int = 1, counts: Optional[dict] = None,
+                ring_lanes: Optional[int] = None) -> dict:
     """Bytes per device of what :func:`init_layers` allocates, by kind, with
-    the ``model`` axis ``tp`` wide."""
+    the ``model`` axis ``tp`` wide (a kind that keeps nothing: 0)."""
     counts = layer_counts(cfg) if counts is None else counts
+    specs = leaf_specs(cfg, counts, lanes, dtype, kv_quant, ring_lanes)
     return {kind: sum(counts[kind] * rows * math.prod(leaf.shape)
                       * jnp.dtype(leaf.dtype).itemsize
                       / (tp if _model_sharded(leaf, tp) else 1)
                       for leaf in leaves.values())
-            for kind, leaves in leaf_specs(cfg, counts, lanes, dtype, kv_quant).items()}
+            for kind, leaves in specs.items()}
 
 
 def split_bytes(by_kind: dict) -> tuple:
@@ -212,8 +266,31 @@ def n_lanes(layers: dict) -> int:
 
 
 def lane_stride(layers: dict, a) -> int:
-    """Lanes a row of positional leaf ``a`` (of ``layers``) stands for."""
+    """Lanes a row of positional leaf ``a`` (of ``layers``) stands for. Not for
+    a ring kind's leaf, which is short because it wraps (``LayerKind.ring``:
+    whoever cuts lanes refuses a ring first, :func:`slice_lanes`)."""
     return n_lanes(layers) // a.shape[2]
+
+
+def ring_positions(lanes: int, length, start=0, size: Optional[int] = None):
+    """The position each of lanes ``start .. start + size - 1`` (default: all)
+    of a ring of ``lanes`` lanes holds in a row ``length`` positions long
+    (``length`` [...] int32 -> [..., size]): the newest ``p < length`` with
+    ``p % lanes == m``, -1 for a lane no position has reached. A row no longer
+    than the ring has position m in lane m."""
+    m = start + jnp.arange(lanes if size is None else size, dtype=jnp.int32)
+    last = jnp.asarray(length, jnp.int32)[..., None] - 1
+    return jnp.where(m <= last, m + lanes * ((last - m) // lanes), -1)
+
+
+def lane_bytes(layers: dict, kind: str) -> int:
+    """Bytes ``kind``'s leaves hold, every row's (0 for a kind not in the tree)."""
+    return sum(a.size * a.dtype.itemsize for a in layers.get(kind, {}).values())
+
+
+def ring_bytes(layers: dict) -> int:
+    """Bytes the ring kinds' leaves hold, every row's."""
+    return sum(lane_bytes(layers, kind) for kind in layers if LAYER_KINDS[kind].ring)
 
 
 def quantized(layers: dict) -> bool:
@@ -235,14 +312,22 @@ def latent_bytes(layers: dict) -> int:
 
 # What a cache manager does to a row.
 
-def insert_row(layers: dict, row: dict, slot) -> dict:
+def insert_row(layers: dict, row: dict, slot, length=None) -> dict:
     """Copy a one-row tree into row ``slot``, cast to the pool's dtypes: a
     positional kind's lanes from 0, each leaf at its own stride (what lies
     past the row's length stays hidden), a whole kind's whole state (whatever
-    the slot held is gone)."""
-    return {kind: {name: lax.dynamic_update_slice(
-                       a, row[kind][name].astype(a.dtype), (0, slot) + (0,) * (a.ndim - 2))
-                   for name, a in leaves.items()}
+    the slot held is gone). A ring kind's lanes are laid out anew for the
+    pool's ring: lane m takes the position :func:`ring_positions` says it
+    holds in a row ``length`` long, from the lane of the staged ring (of any
+    size that still holds it) where that position lies."""
+    def staged(kind, a, src):
+        src = src.astype(a.dtype)
+        if LAYER_KINDS[kind].ring:
+            at = jnp.clip(ring_positions(a.shape[2], length), 0) % src.shape[2]
+            src = jnp.take(src, at, axis=2)
+        return lax.dynamic_update_slice(a, src, (0, slot) + (0,) * (a.ndim - 2))
+
+    return {kind: {name: staged(kind, a, row[kind][name]) for name, a in leaves.items()}
             for kind, leaves in layers.items()}
 
 
@@ -255,9 +340,18 @@ def reset_row(layers: dict, slot) -> dict:
             for kind, leaves in layers.items()}
 
 
+def _refuse_ring(layers: dict, what: str) -> None:
+    rings = [kind for kind, leaves in layers.items() if LAYER_KINDS[kind].ring and leaves]
+    if rings:
+        raise NotImplementedError(
+            f"{what} needs lanes that keep their positions: the {', '.join(rings)} layers' lanes are a "
+            "ring (lane = position % lanes)")
+
+
 def slice_lanes(layers: dict, lanes: int) -> dict:
     """The first ``lanes`` lanes of every positional kind (of a strided leaf,
-    the rows that stand for them)."""
+    the rows that stand for them). Refused for a tree with a ring kind."""
+    _refuse_ring(layers, "slicing a prefix out")
     return {kind: {name: a[:, :, :lanes // lane_stride(layers, a)]
                    for name, a in leaves.items()}
             for kind, leaves in _positional(layers).items()}
@@ -266,6 +360,7 @@ def slice_lanes(layers: dict, lanes: int) -> dict:
 def paste_lanes(layers: dict, src: dict, lanes: int) -> dict:
     """Write the first ``lanes`` lanes of ``src``'s positional kinds over the
     same lanes of ``layers``."""
+    _refuse_ring(layers, "pasting a prefix in")
     src = _positional(src)
     return {kind: leaves if kind not in src
             else {name: lax.dynamic_update_slice(

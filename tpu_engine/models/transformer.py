@@ -40,7 +40,15 @@ from tpu_engine.quant_train import int8_einsum
 # A pattern's names for its layers -> the kind each is stacked, scanned and
 # cached under (``params["layers"][kind]``, ``layer_state.LAYER_KINDS[kind]``).
 LAYER_TYPE_KINDS = {"attention": "attn", "mamba": "ssm", "lightning": "lightning",
-                    "sparse_attention": "sparse_attn", "mla": "mla", "mla_dense": "mla_dense"}
+                    "sparse_attention": "sparse_attn", "mla": "mla", "mla_dense": "mla_dense",
+                    # a decoder-hybrid-decoder stack (Phi-4-mini-flash: SambaY with
+                    # differential attention), see ``ModelConfig.layer_types``
+                    "mamba1": "mamba1", "diff_window_attention": "window_attn",
+                    "diff_attention": "full_attn", "diff_cross_attention": "cross_attn",
+                    "gmu": "gmu"}
+
+# Longest period of unlike layers :meth:`ModelConfig.layer_periods` looks for.
+_MAX_PERIOD = 4
 
 
 @dataclass(frozen=True)
@@ -123,6 +131,16 @@ class ModelConfig:
     # stack: parameters are stacked per kind, the stack is scanned by runs of
     # like layers (:meth:`layer_runs`), and it is served only (llama recipe,
     # no window; see :func:`check_hybrid`).
+    #
+    # A DECODER-HYBRID-DECODER stack holds five more: "mamba1" (a Mamba-1
+    # selective scan, decay per channel and state; its scan output BEFORE the
+    # gate is the memory later "gmu" layers read), "diff_window_attention"
+    # (differential attention under ``sliding_window``), "diff_attention" (the
+    # same with no window; the LAST one's keys and values are the one cache
+    # that every later "diff_cross_attention" layer reads, with queries of its
+    # own and no keys or values), and "gmu" (a gated memory unit: the memory
+    # gated by a projection of the layer's input; nothing cached). Such a
+    # stack's ``sliding_window`` is its window layers' alone.
     layer_types: tuple = ()
     # The PUBLISHED index of each kept layer and the published depth: a
     # lightning layer's decay depends on where the model has it, whatever
@@ -140,6 +158,12 @@ class ModelConfig:
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    # Mamba-1 widths ("mamba1" layers): the mixer's inner width, its state per
+    # channel, and the rank of the projection its step size comes through
+    # (0 = ceil(d_model / 16)); the convolution has ``ssm_conv`` taps.
+    mamba1_inner: int = 0
+    mamba1_state: int = 16
+    mamba1_dt_rank: int = 0
     # Lightning attention: ``lightning_heads`` heads of ``lightning_head_dim``
     # (keys and values have as many), rotation on q and k, per-head q/k norm,
     # a norm over the inner width and a sigmoid gate on the output; head h
@@ -197,6 +221,12 @@ class ModelConfig:
     # the token embedding, outside the gpt2/gemma families too.
     rope: bool = True
     tie_head: bool = False
+    # ``layer_norm``: every norm of a llama-recipe stack is a mean-subtracting
+    # LayerNorm with a bias (gpt2's norm without gpt2's positions, GELU or MLP
+    # biases). ``attn_bias``: the differential-attention kinds' projections
+    # (q, k, v, o) carry biases.
+    layer_norm: bool = False
+    attn_bias: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -266,6 +296,55 @@ class ModelConfig:
                 runs.append([kind, seen.get(kind, 0), 1])
             seen[kind] = seen.get(kind, 0) + 1
         return tuple(tuple(r) for r in runs)
+
+    def layer_periods(self) -> tuple:
+        """The pattern as :meth:`layer_runs` has it, with a stretch that
+        alternates (``a b a b ...``: up to :data:`_MAX_PERIOD` unlike layers,
+        repeated at least twice) as ONE entry: ``(kinds, firsts, count)``,
+        ``count`` repeats of a period whose j-th layer is of kind ``kinds[j]``
+        and first stands at ``firsts[j]`` of that kind's stack. A run of like
+        layers is a period of one kind; 8 x (mamba1, window) is one loop of
+        eight, not sixteen runs of one."""
+        kinds = [LAYER_TYPE_KINDS[t] for t in self.layer_types or ("attention",) * self.n_layers]
+        out: list = []
+        seen: dict = {}
+        i = 0
+        while i < len(kinds):
+            run = 1
+            while i + run < len(kinds) and kinds[i + run] == kinds[i]:
+                run += 1
+            p, count = 1, run
+            if run == 1:
+                for q in range(2, _MAX_PERIOD + 1):
+                    unit = kinds[i:i + q]
+                    if len(set(unit)) < q:
+                        continue
+                    r = 1
+                    while kinds[i + r * q:i + (r + 1) * q] == unit:
+                        r += 1
+                    if r >= 2 and q * r > p * count:
+                        p, count = q, r
+            unit = tuple(kinds[i:i + p])
+            out.append((unit, tuple(seen.get(k, 0) for k in unit), count))
+            for k in unit:
+                seen[k] = seen.get(k, 0) + count
+            i += p * count
+        return tuple(out)
+
+    @property
+    def cross_decoder_start(self) -> Optional[int]:
+        """Index of the layer whose keys and values the cross-attention layers
+        read (the last "diff_attention" before the first of them): from its
+        attention on, a prompt needs the stack at its LAST position only.
+        None for a stack without cross-attention layers."""
+        if "diff_cross_attention" not in self.layer_types:
+            return None
+        first = self.layer_types.index("diff_cross_attention")
+        return max(i for i, t in enumerate(self.layer_types[:first]) if t == "diff_attention")
+
+    @property
+    def mamba1_rank(self) -> int:
+        return self.mamba1_dt_rank or -(-self.d_model // 16)
 
     @property
     def is_moe(self) -> bool:
@@ -389,12 +468,18 @@ def check_hybrid(cfg: "ModelConfig") -> None:
             f"{sorted(LAYER_TYPE_KINDS)}, got {cfg.layer_types!r}"
         )
     if not cfg.is_hybrid:
+        if cfg.layer_norm or cfg.attn_bias:
+            raise ValueError("layer_norm and attn_bias are a hybrid stack's (the gpt2 recipe has its own)")
         return
-    if cfg.arch != "llama" or cfg.sliding_window:
+    windowed = "diff_window_attention" in cfg.layer_types
+    if cfg.arch != "llama" or bool(cfg.sliding_window) != windowed \
+            or (windowed and "attention" in cfg.layer_types):
         raise ValueError(
-            "a hybrid layer pattern needs the llama recipe and no sliding "
-            f"window (arch={cfg.arch!r}, sliding_window={cfg.sliding_window})"
+            "a hybrid layer pattern needs the llama recipe, and a sliding window exactly where it "
+            "has 'diff_window_attention' layers (whose window it is) and no plain 'attention' "
+            f"layer (arch={cfg.arch!r}, sliding_window={cfg.sliding_window})"
         )
+    _check_decoder_hybrid_decoder(cfg)
     if cfg.is_moe and not 1 <= cfg.top_k <= cfg.n_experts:
         raise ValueError(f"top_k={cfg.top_k} experts a token of n_experts={cfg.n_experts}")
     if cfg.router_scoring not in ("softmax", "sigmoid"):
@@ -435,6 +520,36 @@ def check_hybrid(cfg: "ModelConfig") -> None:
                 f"sparse_topk={cfg.sparse_topk} must hold the {cfg.sparse_init_blocks} first and "
                 f"{cfg.sparse_local_blocks} last blocks, and sparse_dense_len={cfg.sparse_dense_len} "
                 f"at least sparse_topk blocks of {block} (a position that selects sees that many)")
+
+
+DIFF_ATTENTION_TYPES = ("diff_window_attention", "diff_attention", "diff_cross_attention")
+
+
+def _check_decoder_hybrid_decoder(cfg: "ModelConfig") -> None:
+    """What the five decoder-hybrid-decoder layer types need of a pattern and
+    of the widths (:func:`check_hybrid`'s part for them)."""
+    types = cfg.layer_types
+    mine = set(types) & ({"mamba1", "gmu"} | set(DIFF_ATTENTION_TYPES))
+    if not mine:
+        if cfg.layer_norm or cfg.attn_bias:
+            raise ValueError("layer_norm and attn_bias are a decoder-hybrid-decoder stack's "
+                             "('mamba1', 'diff_*attention', 'gmu' layers)")
+        return
+    if cfg.is_moe:
+        raise ValueError(f"a mixture of experts after {sorted(mine)} layers is not supported")
+    if "mamba1" in types and (cfg.mamba1_inner < 1 or cfg.mamba1_state < 1 or cfg.ssm_conv < 2):
+        raise ValueError("a 'mamba1' layer needs mamba1_inner, mamba1_state >= 1 and ssm_conv >= 2")
+    if "gmu" in types and "mamba1" not in types[:types.index("gmu")]:
+        raise ValueError("a 'gmu' layer reads the scan output of a 'mamba1' layer before it: the pattern has none")
+    if mine & set(DIFF_ATTENTION_TYPES):
+        pairs, kv_pairs = divmod(cfg.n_heads, 2), divmod(cfg.n_kv_heads, 2)
+        if pairs[1] or kv_pairs[1] or kv_pairs[0] < 1 or pairs[0] % kv_pairs[0]:
+            raise ValueError(
+                f"differential attention pairs its heads: n_heads={cfg.n_heads} and n_kv_heads="
+                f"{cfg.n_kv_heads} must be even, the query pairs a multiple of the kv pairs")
+    if "diff_cross_attention" in types and "diff_attention" not in types[:types.index("diff_cross_attention")]:
+        raise ValueError("a 'diff_cross_attention' layer reads the keys and values of a 'diff_attention' "
+                         "layer before it: the pattern has none")
 
 
 # Model scales matching the reference's preset names (7b/13b/70b at
@@ -710,12 +825,13 @@ def _init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype, deferred: bool 
               "sparse_attn": partial(_init_sparse_attn_stack, rng, cfg, dtype, deferred),
               "lightning": partial(_init_lightning_stack, rng, cfg, dtype, deferred),
               "mla": partial(_init_mla_stack, rng, cfg, dtype, deferred, "mla"),
-              "mla_dense": partial(_init_mla_stack, rng, cfg, dtype, deferred, "mla_dense")}
+              "mla_dense": partial(_init_mla_stack, rng, cfg, dtype, deferred, "mla_dense"),
+              **{kind: partial(_init_dhd_stack, rng, cfg, dtype, deferred, kind) for kind in DHD_FOLD}}
     kinds = dict.fromkeys(kind for kind, _, _ in cfg.layer_runs())
     out = {
         "embed": {"embedding": norm(ks[0], (V, D), std / cfg.embed_scale)},
         "layers": {kind: stacks[kind]() for kind in kinds},
-        "final_norm": {"scale": jnp.ones((D,), dtype)},
+        "final_norm": _norm_leaves(cfg, (D,), dtype),
     }
     if not cfg.tied_head:
         out["lm_head"] = {"kernel": norm(jax.random.fold_in(ks[0], 1), (D, V), std)}
@@ -880,13 +996,109 @@ def _init_mla_stack(rng, cfg: ModelConfig, dtype, deferred: bool, layer_type: st
     }
 
 
+# A decoder-hybrid-decoder kind's keys: ``split(fold_in(rng, DHD_FOLD[kind]), 16)``,
+# a leaf's at :data:`DHD_KEYS`; layer i of a leaf from ``split(key, n)[i]`` alone.
+DHD_FOLD = {"mamba1": 105, "window_attn": 106, "full_attn": 107, "cross_attn": 108, "gmu": 109}
+DHD_KEYS = {"q": 0, "in_proj": 0, "k": 1, "conv": 1, "v": 2, "x_proj": 2, "o": 3, "dt_proj": 3,
+            "lambdas": 4, "dt_bias": 4, "out_proj": 5, "gate": 6, "up": 7, "down": 8,
+            "q_bias": 9, "k_bias": 10, "v_bias": 11, "o_bias": 12}
+DHD_LAYER_TYPE = {kind: t for t, kind in LAYER_TYPE_KINDS.items() if kind in DHD_FOLD}
+
+
+def _norm_leaves(cfg: ModelConfig, shape: tuple, dtype) -> dict:
+    """A norm's leaves at init: unit scale, and with ``cfg.layer_norm`` a zero bias."""
+    out = {"scale": jnp.ones(shape, dtype)}
+    if cfg.layer_norm:
+        out["bias"] = jnp.zeros(shape, dtype)
+    return out
+
+
+def diff_lambda_init(cfg: ModelConfig, layer_type: str) -> jax.Array:
+    """[n] float32: ``0.8 - 0.6 exp(-0.3 l)`` for each layer of ``layer_type``,
+    l its PUBLISHED index (differential attention's fixed part of lambda)."""
+    at = jnp.asarray(cfg.published_indices(layer_type), jnp.float32)
+    return 0.8 - 0.6 * jnp.exp(-0.3 * at)
+
+
+def _init_dhd_stack(rng, cfg: ModelConfig, dtype, deferred: bool, kind: str) -> dict:
+    """One kind's stack of a decoder-hybrid-decoder pattern (keys:
+    :data:`DHD_FOLD`, :data:`DHD_KEYS`), then the block every layer has.
+
+    - ``mamba1``: ``in_proj`` ``[D, 2 I]`` (x | z), the depthwise ``conv``
+      (taps ``U(+-1/sqrt(taps))``, zero bias), ``x_proj`` ``[I, R + 2 N]``
+      (step | B | C), ``dt_proj`` ``[R, I]`` (``U(+-R^-1/2)``) with ``dt_bias =
+      softplus^-1(dt)``, ``dt`` log-uniform in [1e-3, 1e-1], ``A_log`` ``[N, I]``
+      ``= log(1..N)`` for every channel (channels minor, as the state lies),
+      ``D = 1``, ``out_proj`` ``[I, D]``: Mamba's published ranges;
+    - ``window_attn`` / ``full_attn``: ``q`` / ``k`` / ``v`` / ``o`` with biases
+      (``cfg.attn_bias``; normal(0.02), so that a dropped bias shows), the four
+      lambda vectors ``lambdas`` ``[4, HD]`` (q1, k1, q2, k2; normal(0.1)),
+      ``lambda_init`` (:func:`diff_lambda_init`) and the sub-norm's scale
+      ``[2 HD]``; ``cross_attn`` the same without k and v;
+    - ``gmu``: ``in_proj`` ``[D, I]`` and ``out_proj`` ``[I, D]``.
+
+    Projections normal(0.02), outputs (``o``, ``out_proj``, ``down``) / sqrt(2 x
+    depth). The recurrence's small leaves, the lambdas and ``lambda_init`` stay
+    float32 wherever the tree goes (:data:`FLOAT32_LEAVES`)."""
+    layer_type = DHD_LAYER_TYPE[kind]
+    n = cfg.n_layers_of(layer_type)
+    D, H, KV, HD = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    I, N, R, K = cfg.mamba1_inner, cfg.mamba1_state, cfg.mamba1_rank, cfg.ssm_conv
+    ks = jax.random.split(jax.random.fold_in(rng, DHD_FOLD[kind]), 16)
+    key = lambda name: ks[DHD_KEYS[name]]  # noqa: E731
+    res_std = 0.02 / (2 * (cfg.published_layers or cfg.n_layers)) ** 0.5
+    draw = partial(_drawn, deferred, _draw_layers, n=n, dtype=dtype)
+    f32 = jnp.float32
+
+    def uniform(name, shape, lo, hi):
+        return jax.vmap(lambda k: jax.random.uniform(k, shape, f32, lo, hi))(jax.random.split(key(name), n))
+
+    def proj(name, shape, s=0.02, bias=False):
+        out = {"kernel": draw(key(name), s, shape=shape)}
+        if bias:
+            out["bias"] = _draw_layers(key(name + "_bias"), 0.02, n=n, shape=shape[-1:], dtype=dtype)
+        return out
+
+    out = {"mixer_norm": _norm_leaves(cfg, (n, D), dtype)}
+    if kind == "mamba1":
+        dt = jnp.exp(uniform("dt_bias", (I,), jnp.log(1e-3), jnp.log(1e-1)))
+        out.update({
+            "in_proj": proj("in_proj", (D, 2 * I)),
+            "conv": {"kernel": uniform("conv", (K, I), -1.0 / K ** 0.5, 1.0 / K ** 0.5).astype(dtype),
+                     "bias": jnp.zeros((n, I), dtype)},
+            "x_proj": proj("x_proj", (I, R + 2 * N)),
+            "dt_proj": {"kernel": uniform("dt_proj", (R, I), -1.0 / R ** 0.5, 1.0 / R ** 0.5).astype(dtype)},
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1
+            "A_log": jnp.broadcast_to(jnp.log(jnp.arange(1, N + 1, dtype=f32))[None, :, None], (n, N, I)),
+            "D": jnp.ones((n, I), f32),
+            "out_proj": proj("out_proj", (I, D), res_std),
+        })
+    elif kind == "gmu":
+        out.update({"in_proj": proj("in_proj", (D, I)), "out_proj": proj("out_proj", (I, D), res_std)})
+    else:
+        out["q"] = proj("q", (D, H * HD), bias=cfg.attn_bias)
+        if kind != "cross_attn":
+            out["k"] = proj("k", (D, KV * HD), bias=cfg.attn_bias)
+            out["v"] = proj("v", (D, KV * HD), bias=cfg.attn_bias)
+        out.update({
+            "o": proj("o", (H * HD, D), res_std, bias=cfg.attn_bias),
+            "lambdas": _draw_layers(key("lambdas"), 0.1, n=n, shape=(4, HD), dtype=f32),
+            "lambda_init": diff_lambda_init(cfg, layer_type),
+            "sub_norm": {"scale": jnp.ones((n, 2 * HD), dtype)},
+        })
+    mlp = _mixer_mlp_stack(draw, [key("gate"), key("up"), key("down")], n, cfg, dtype, deferred,
+                           cfg.published_layers or cfg.n_layers)
+    mlp["mlp_norm"] = _norm_leaves(cfg, (n, D), dtype)
+    return {**out, **mlp}
+
+
 # Recurrence leaves of a Mamba-2 layer, and with a lightning layer's per-head
 # rates all that stays float32 wherever the rest of the tree goes to a compute
 # dtype (:func:`served_format`, :func:`cast_layer_stack`): a bf16 ``A_log``
 # moves every decay, and a sigmoid router's selection bias is added to
 # float32 scores.
 SSM_FLOAT32_LEAVES = ("A_log", "dt_bias", "D")
-FLOAT32_LEAVES = SSM_FLOAT32_LEAVES + ("decay", "router_bias")
+FLOAT32_LEAVES = SSM_FLOAT32_LEAVES + ("decay", "router_bias", "lambdas", "lambda_init")
 
 
 def _mlp_axes(cfg: ModelConfig) -> dict[str, Any]:
@@ -972,11 +1184,43 @@ def logical_axes(cfg: ModelConfig) -> dict[str, Any]:
                 **mlp_axes,
             },
         }
+        norm_axes = {"scale": ("layers", "embed"), **({"bias": ("layers", "embed")} if cfg.layer_norm else {})}
+        bias = lambda axis: {"bias": ("layers", axis)} if cfg.attn_bias else {}  # noqa: E731
+        dhd = {"mixer_norm": norm_axes, **mlp_axes, "mlp_norm": norm_axes}
+        diff_axes = {
+            "q": {"kernel": ("layers", "embed", "heads"), **bias("heads")},
+            "o": {"kernel": ("layers", "heads", "embed"), **bias("embed")},
+            "lambdas": ("layers", None, None),
+            "lambda_init": ("layers",),
+            "sub_norm": {"scale": ("layers", None)},
+            **dhd,
+        }
+        kv_axes = {"k": {"kernel": ("layers", "embed", "kv_heads"), **bias("kv_heads")},
+                   "v": {"kernel": ("layers", "embed", "kv_heads"), **bias("kv_heads")}}
+        stacks.update({
+            "window_attn": {**kv_axes, **diff_axes},
+            "full_attn": {**kv_axes, **diff_axes},
+            "cross_attn": diff_axes,
+            # the mixer's inner width has no head-aligned split: it stays whole
+            "mamba1": {
+                "in_proj": {"kernel": ("layers", "embed", None)},
+                "conv": {"kernel": ("layers", None, None), "bias": ("layers", None)},
+                "x_proj": {"kernel": ("layers", None, None)},
+                "dt_proj": {"kernel": ("layers", None, None)},
+                "dt_bias": ("layers", None),
+                "A_log": ("layers", None, None),
+                "D": ("layers", None),
+                "out_proj": {"kernel": ("layers", None, "embed")},
+                **dhd,
+            },
+            "gmu": {"in_proj": {"kernel": ("layers", "embed", None)},
+                    "out_proj": {"kernel": ("layers", None, "embed")}, **dhd},
+        })
         out = {
             "embed": {"embedding": ("vocab", "embed")},
             "layers": {kind: stacks[kind]
                        for kind in dict.fromkeys(k for k, _, _ in cfg.layer_runs())},
-            "final_norm": {"scale": ("embed",)},
+            "final_norm": {"scale": ("embed",), **({"bias": ("embed",)} if cfg.layer_norm else {})},
         }
         if not cfg.tied_head:
             out["lm_head"] = {"kernel": ("embed", "vocab")}
@@ -1060,7 +1304,25 @@ def param_count(cfg: ModelConfig) -> int:
         mla = (D * H * HD + D * (C + R) + C + C * H * (cfg.qk_nope_dim + cfg.v_head_dim)
                + H * cfg.v_head_dim * D + 2 * D)
         bias = cfg.n_experts if cfg.router_scoring == "sigmoid" else 0
-        return (V * D + cfg.n_attn_layers * per_layer + cfg.n_ssm_layers * per_ssm
+        # The decoder-hybrid-decoder kinds: a layer's two norms (with biases
+        # under ``layer_norm``) and its MLP, and the mixer: Mamba-1's in_proj
+        # (x | z), conv taps + bias, x_proj, dt_proj + dt_bias, A_log, D and
+        # out_proj; differential attention's projections (+ biases), four
+        # lambda vectors, lambda_init and the sub-norm; the GMU's two.
+        MI, MN, MR = cfg.mamba1_inner, cfg.mamba1_state, cfg.mamba1_rank
+        norms = 2 * D * (2 if cfg.layer_norm else 1)
+        qo = 2 * D * H * HD + (H * HD + D if cfg.attn_bias else 0)
+        kv = 2 * D * KV * HD + (2 * KV * HD if cfg.attn_bias else 0)
+        diff = 4 * HD + 1 + 2 * HD
+        mamba1 = (2 * D * MI + (cfg.ssm_conv + 1) * MI + MI * (MR + 2 * MN) + MR * MI + MI
+                  + MN * MI + MI + MI * D)
+        dhd = (cfg.n_layers_of("mamba1") * (mamba1 + mlp + norms)
+               + (cfg.n_layers_of("diff_window_attention") + cfg.n_layers_of("diff_attention"))
+               * (qo + kv + diff + mlp + norms)
+               + cfg.n_layers_of("diff_cross_attention") * (qo + diff + mlp + norms)
+               + cfg.n_layers_of("gmu") * (2 * D * MI + mlp + norms)
+               + (D if cfg.layer_norm else 0))  # the final norm's bias
+        return (dhd + V * D + cfg.n_attn_layers * per_layer + cfg.n_ssm_layers * per_ssm
                 + cfg.n_layers_of("sparse_attention") * per_sparse
                 + cfg.n_layers_of("lightning") * per_lightning
                 + cfg.n_layers_of("mla") * (mla + mlp + bias)
@@ -1122,7 +1384,7 @@ def _norm(x: jax.Array, p: dict, cfg: "ModelConfig") -> jax.Array:
     """Arch-dispatching norm: RMSNorm (llama), LayerNorm+bias (gpt2), or
     zero-centred RMSNorm (gemma: the stored scale is an offset from 1, so a
     zero-initialised checkpoint is the identity scale)."""
-    if cfg.arch == "gpt2":
+    if cfg.arch == "gpt2" or cfg.layer_norm:
         return _layer_norm(x, p["scale"], p["bias"], cfg.norm_eps)
     if cfg.arch == "gemma":
         return _rms_norm(x, p["scale"].astype(jnp.float32) + 1.0, cfg.norm_eps)

@@ -96,6 +96,8 @@ def dequantize_weight(qw: QuantWeight, dtype=jnp.float32) -> jax.Array:
 _QUANT_LAYER_KEYS = ("q", "k", "v", "o", "gate", "up", "down", "fc", "proj",
                      # a hybrid stack's Mamba-2 mixer projections
                      "in_proj", "out_proj",
+                     # a Mamba-1 mixer's projections to its step, B and C
+                     "x_proj", "dt_proj",
                      # the output gate of its lightning and sparse-attention layers
                      "o_gate",
                      # a latent-attention (MLA) layer's down- and up-projection

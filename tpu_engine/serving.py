@@ -157,7 +157,11 @@ def init_slot_cache(
     lanes = ring_lanes(cfg, max_len, prefill_chunk)
     ring = lanes < max_len
     return SlotCache(
-        layers=layer_state.init_layers(cfg, slots, lanes, dtype, kv_quant),
+        # A hybrid's window layers keep a ring of one window beside the full
+        # kinds' ``lanes`` (a decode step needs no more of them resident; the
+        # insert lays a prompt's newest positions out for it).
+        layers=layer_state.init_layers(cfg, slots, lanes, dtype, kv_quant,
+                                       ring_lanes=cfg.sliding_window if cfg.is_hybrid else None),
         lengths=jnp.zeros((slots,), jnp.int32),
         pos=jnp.full((slots, lanes), -1, jnp.int32) if ring else None,
         ring=ring,
@@ -220,12 +224,13 @@ def decode_step(
             jnp.arange(S, dtype=jnp.int32)[None, :], (B, S)
         )
 
-    def write(cache_arr, new_rows, at):
+    def write(cache_arr, new_rows, at, ring=False):
         # Per-row scatter at each slot's own lane of layer ``at`` (T = 1).
         # Out-of-bounds lanes (a finished-mid-chunk row running past
         # capacity) drop. Serves the scale arrays of a quantized pool too
-        # (same leading [L, B, S, KV] dims, trailing 1 instead of HD).
-        return cache_arr.at[at, rows, lane].set(
+        # (same leading [L, B, S, KV] dims, trailing 1 instead of HD). A
+        # ring KIND's leaf (a hybrid's window layers) wraps at its own length.
+        return cache_arr.at[at, rows, lane % cache_arr.shape[2] if ring else lane].set(
             new_rows[:, 0].astype(cache_arr.dtype)
         )
 
@@ -608,8 +613,8 @@ class _PrefillState:
 
     req: Request
     slot: int
-    c1: KVCache
     toks: np.ndarray    # [1, padded] int32 — prompt, zero-padded
+    c1: Optional[KVCache] = None  # allocated when the first chunk runs
     consumed: int = 0
     chunks: int = 0     # chunks run so far (a prefix hit skips some)
     dc1: Optional[KVCache] = None
@@ -836,6 +841,15 @@ class ContinuousBatcher:
             _program("reset_slot", _reset_slot), donate_argnums=(0,),
             out_shardings=None if mesh is None else self._cache_sh,
         )
+        # A stack with a cross-decoder ingests a prompt by two programs: every
+        # chunk but the last stops at the cache the cross-decoder reads.
+        self._ingest_fn = None
+        if cfg.cross_decoder_start is not None:
+            self._ingest_fn = jax.jit(
+                _program("prefill_ingest", _prefill_ingest, cfg=cfg,
+                         compute_dtype=compute_dtype),
+                donate_argnums=(2,),
+            )
 
         self._slots: list[Optional[Request]] = [None] * self.max_slots
         self._last_tokens = np.zeros((self.max_slots,), np.int32)
@@ -883,11 +897,19 @@ class ContinuousBatcher:
         # how often block choice, not only the causal bound, engages.
         self._prefill_tokens_computed = 0
         self._prefill_tokens_sparse = 0
+        # Of those positions, the ones that ran a cross-decoder (a prompt's
+        # last alone, where the stack has one; 0 for a stack that has none).
+        self._prefill_positions_cross = 0
         # Recurrent state written into a slot by a finished prefill, and
         # zeroed when a slot is freed (hybrid stacks; else both stay 0).
         self._recurrent_state_bytes = self._cache.recurrent_state_bytes
         # The pool's latent-attention (MLA) leaves: every slot's every lane.
         self._latent_cache_bytes = layer_state.latent_bytes(self._cache.layers)
+        # The one full-attention cache that cross-attention layers read, and
+        # the window layers' rings beside it (0 for a stack without them).
+        self._shared_kv_bytes = layer_state.lane_bytes(self._cache.layers, "full_attn") \
+            if cfg.cross_decoder_start is not None else 0
+        self._window_kv_bytes = layer_state.ring_bytes(self._cache.layers)
         # Layers of the whole kinds whose decode step the one-pass kernel
         # takes (``ops.ssd_update``: decided where the program is traced, from
         # the leaf and the device); 0 where the walk keeps the XLA step.
@@ -1250,6 +1272,10 @@ class ContinuousBatcher:
                 "decode_tokens_sparse_total": self._decode_tokens_sparse,
                 "prefill_tokens_computed_total": self._prefill_tokens_computed,
                 "prefill_tokens_sparse_total": self._prefill_tokens_sparse,
+                # Of ``prefill_tokens_computed_total``, the positions that ran
+                # a cross-decoder (a decoder-hybrid-decoder stack needs it at
+                # a prompt's last position only).
+                "prefill_positions_cross_decoder_total": self._prefill_positions_cross,
                 # Monotonic: waits the driving loop took between two steps
                 # (phase ``idle``), and those entered with a prompt still
                 # prefilling, queued or awaiting a handoff.
@@ -1265,6 +1291,10 @@ class ContinuousBatcher:
                 # What the latent-attention (MLA) layers cache, every slot's
                 # every lane (0 for a stack that has none).
                 "latent_cache_bytes": self._latent_cache_bytes,
+                # The ONE full-attention cache cross-attention layers share,
+                # and the window layers' rings, every slot's.
+                "shared_kv_bytes": self._shared_kv_bytes,
+                "window_kv_bytes": self._window_kv_bytes,
                 "recurrent_updates_in_place_total": self._recurrent_updates_in_place,
                 "state_inserts_total": self._state_inserts,
                 "state_resets_total": self._state_resets,
@@ -1318,15 +1348,23 @@ class ContinuousBatcher:
             c1, layer_state.cache_shardings(self.mesh, self.cfg, c1))
 
     def _begin_prefill(self, req: Request, slot: int) -> _PrefillState:
-        """Allocate the single-row ingestion cache. Prompts pad up to
-        ``prefill_pad_to`` multiples (bounded compiled final-chunk shapes);
-        padded positions are never exposed (mask is per-row length) and
-        decode overwrites the first pad lane before it can be seen."""
+        """Pad the prompt up to a ``prefill_pad_to`` multiple (bounded
+        compiled final-chunk shapes); padded positions are never exposed
+        (mask is per-row length) and decode overwrites the first pad lane
+        before it can be seen."""
         P_len = len(req.prompt)
         pad = min(-(-P_len // self.prefill_pad_to) * self.prefill_pad_to,
                   self.max_len)
         toks = np.zeros((1, pad), np.int32)
         toks[0, :P_len] = req.prompt
+        return _PrefillState(req=req, slot=slot, toks=toks)
+
+    def _stage(self, st: _PrefillState) -> None:
+        """Allocate ``st``'s single-row ingestion cache(s), when its first
+        chunk is about to run: prompts are ingested one at a time, so one
+        staging cache is live however many requests an admission pass took
+        (a full pool's worth at once held gigabytes of them, all zeros)."""
+        pad = st.padded
         if self._cache.ring:
             # Ring pools need lane-aligned ingestion: the c1 ring must have
             # exactly the pool's lane count so positions map to the same
@@ -1342,16 +1380,16 @@ class ContinuousBatcher:
             M = max(M, pad)
             c1 = init_cache(self.cfg, 1, M, dtype=self._compute_dtype,
                             kv_quant=self.kv_quant)
-        c1 = self._on_mesh(c1)
-        dc1 = None
+        st.c1 = self._on_mesh(c1)
         if self._draft_params is not None:
-            dc1 = init_cache(self._draft_cfg, 1, c1.max_len,
-                             dtype=self._compute_dtype)
-        return _PrefillState(req=req, slot=slot, c1=c1, toks=toks, dc1=dc1)
+            st.dc1 = init_cache(self._draft_cfg, 1, c1.max_len,
+                                dtype=self._compute_dtype)
 
     def _advance_prefill(self, st: _PrefillState) -> bool:
         """Ingest ONE bounded chunk; True when the prompt is fully in and
         its K/V rows have been copied into the slot."""
+        if st.c1 is None:
+            self._stage(st)
         if self._prefix_cache is not None and not st.prefix_checked:
             # Lookup at FIRST advance, not at admission: prefills drain
             # one chunk per engine step in admission order, so a burst of
@@ -1380,9 +1418,16 @@ class ContinuousBatcher:
         # token (the bucket's padding would otherwise enter the state).
         n_valid = jnp.asarray(min(max(P_len - t0, 0), t1 - t0), jnp.int32) \
             if self._cache.recurrent else None
-        last_row, st.c1 = self._prefill_fn(
-            self.params, chunk, st.c1, jnp.asarray(row, jnp.int32), n_valid
-        )
+        is_last = t0 <= P_len - 1 < t1
+        if self._ingest_fn is not None and not is_last:
+            # A stack with a cross-decoder: only a prompt's last position needs
+            # it, so every other chunk stops at the cache it reads.
+            last_row, st.c1 = None, self._ingest_fn(self.params, chunk, st.c1, n_valid)
+        else:
+            last_row, st.c1 = self._prefill_fn(
+                self.params, chunk, st.c1, jnp.asarray(row, jnp.int32), n_valid
+            )
+            self._prefill_positions_cross += self._ingest_fn is not None  # the prompt's last position alone
         if st.dc1 is not None:  # speculative: the draft ingests the prompt too
             st.dc1 = self._draft_prefill_fn(self._draft_params, chunk, st.dc1)
         st.consumed = t1
@@ -1414,7 +1459,7 @@ class ContinuousBatcher:
                     tuple(st.req.prompt[:last]),
                     self._slice_prefix(st.c1, last),
                 )
-        if t0 <= P_len - 1 < t1:
+        if is_last:
             # The prompt's last chunk: with its logits row come the mixture's
             # counts of all the request's chunks (``c1`` has summed them).
             last_row, counts = jax.device_get((last_row, st.c1.moe_counts))
@@ -1565,7 +1610,11 @@ class ContinuousBatcher:
                     jnp.asarray(active), jnp.asarray(temps), jnp.asarray(req_ids),
                     jnp.asarray(counts), self._base_key,
                 )
-        with prof.phase("device"):
+        # What this dispatch decodes rides on the annotation: a trace that ends
+        # before the slots are full can still hold each run of the decode
+        # program against the rows it computed for and the lanes they held.
+        with prof.phase("device", rows=len(active_reqs),
+                        context=sum(len(r.prompt) + len(r.tokens) for _, r in active_reqs)):
             if speculative:
                 toks_host = np.asarray(tgt)         # [B, gamma+1]
                 n_take = np.asarray(n_acc)          # [B] accepted per slot
@@ -1789,10 +1838,24 @@ def _prefill_forward(params, toks, cache, row_idx, n_valid=None, *, cfg,
     mesh this avoids all-gathering the full [T, V] logits per chunk.
     ``n_valid``: the chunk's real tokens (the rest pads the prompt to its
     bucket), which only a hybrid stack's recurrent layers need."""
+    if cfg.cross_decoder_start is not None:
+        # the cross-decoder and the head at the one position whose logits are wanted
+        logits, cache = forward_with_cache(params, toks, cache, cfg, compute_dtype=compute_dtype,
+                                           n_valid=n_valid, logits_row=row_idx)
+        return logits[0, 0], cache
     logits, cache = forward_with_cache(params, toks, cache, cfg,
                                        compute_dtype=compute_dtype,
                                        n_valid=n_valid)
     return logits[0, row_idx], cache
+
+
+def _prefill_ingest(params, toks, cache, n_valid=None, *, cfg, compute_dtype):
+    """A prefill chunk that is not its prompt's last, of a stack with a
+    cross-decoder: the self-decoder and the keys and values the cross-decoder
+    will read, nothing after them and no logits."""
+    _, cache = forward_with_cache(params, toks, cache, cfg, compute_dtype=compute_dtype,
+                                  n_valid=n_valid, ingest_only=True)
+    return cache
 
 
 def _draft_prefill_ingest(params, toks, cache, *, cfg, compute_dtype):
@@ -1816,7 +1879,7 @@ def _insert_prefill(cache: SlotCache, c1: KVCache, slot, true_len, ring: bool):
         # Lane-aligned by construction (c1 ring size == pool lane count).
         pos = lax.dynamic_update_slice(pos, c1.pos[None, :], (slot, 0))
     return dataclasses.replace(
-        cache, layers=layer_state.insert_row(cache.layers, c1.layers, slot),
+        cache, layers=layer_state.insert_row(cache.layers, c1.layers, slot, true_len),
         lengths=cache.lengths.at[slot].set(true_len.astype(jnp.int32)), pos=pos,
     )
 
