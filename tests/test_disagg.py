@@ -258,10 +258,11 @@ def test_handoff_to_cache_buckets_and_pads():
     cache = handoff_to_cache(
         h, dtype=jnp.float32, kv_quant=False, chunk=4, max_lanes=16
     )
-    # T=5 buckets up to the next chunk multiple (8), not max_lanes.
-    assert cache.layers["attn"]["k"].shape == (2, 1, 8, 2, 4)
+    # T=5 buckets up to the next chunk multiple (8), not max_lanes; a lane's
+    # row holds its kv-heads side by side (2 x 4 on the wire -> 8).
+    assert cache.layers["attn"]["k"].shape == (2, 1, 8, 2 * 4)
     assert int(cache.length) == 5 and not cache.ring
-    np.testing.assert_allclose(np.asarray(cache.layers["attn"]["k"][:, 0, :5]), k, rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(cache.layers["attn"]["k"][:, 0, :5]), k.reshape(2, 5, 8), rtol=1e-6)
     assert np.all(np.asarray(cache.layers["attn"]["k"][:, 0, 5:]) == 0)  # padding lanes
     assert "k_scale" not in cache.layers["attn"]
 
@@ -288,7 +289,7 @@ def test_handoff_to_cache_dequantizes_int8_wire_for_fp_pool():
         h, dtype=jnp.float32, kv_quant=False, chunk=8, max_lanes=8
     )
     assert cache.layers["attn"]["k"].dtype == jnp.float32
-    got = np.asarray(cache.layers["attn"]["k"][:, 0, :5])
+    got = np.asarray(cache.layers["attn"]["k"][:, 0, :5]).reshape(k.shape)
     assert np.max(np.abs(got - k)) <= np.max(np.abs(k)) / 127 + 1e-6
 
 

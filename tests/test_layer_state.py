@@ -239,7 +239,7 @@ def test_a_third_kind_is_one_entry(what, toy_kind):
     if what == "allocation":
         assert set(pool.layers) == {"attn", "toy"} and set(pool.layers["toy"]) == {"vec"}
         assert vec.shape == (3, 4, cfg.d_model) and vec.dtype == jnp.float32
-        assert pool.layers["attn"]["k"].shape == (2, 4, 32, cfg.n_kv_heads, cfg.head_dim)
+        assert pool.layers["attn"]["k"].shape == (2, 4, 32, cfg.n_kv_heads * cfg.head_dim)
         assert pool.n_lanes == 32 and pool.recurrent and not pool.quantized
         assert pool.recurrent_state_bytes == vec.nbytes
     elif what == "insert_and_reset":
@@ -260,7 +260,7 @@ def test_a_third_kind_is_one_entry(what, toy_kind):
         mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2), ("fsdp", "model"))
         sh = layer_state.cache_shardings(mesh, cfg, pool)
         P = jax.sharding.PartitionSpec
-        assert sh.layers["attn"]["k"].spec == P(None, None, None, "model", None)
+        assert sh.layers["attn"]["k"].spec == P(None, None, None, "model")
         assert sh.layers["toy"]["vec"].spec == P() and sh.lengths.spec == P() and sh.pos is None
         placed = jax.device_put(pool, sh)
         assert placed.layers["toy"]["vec"].sharding.is_fully_replicated

@@ -540,11 +540,11 @@ def test_the_references_differential_attention_equals_diffllama_attention(tiny, 
 
 @pytest.mark.parametrize("window", [0, 512])
 def test_the_decode_kernel_equals_the_xla_contractions(monkeypatch, window):
-    """``ops.diff_decode`` interpreted, at the smallest sizes it engages at
+    """``ops.lane_decode`` interpreted, at the smallest sizes it engages at
     (blocks of 512 lanes, pairs of 128 values): rows of different lengths (one
     shorter than a block, one inside the second, one full; under the window the
     pool's ring of 512 lanes, wrapped and not), layer 1 of 2."""
-    from tpu_engine.ops import diff_decode
+    from tpu_engine.ops import lane_decode
 
     mc = tfm.ModelConfig(name="k", vocab_size=64, d_model=512, n_layers=4, n_heads=8, n_kv_heads=4, d_ff=64,
                          layer_types=("mamba1", "diff_window_attention", "mamba1", "diff_attention"),
@@ -558,9 +558,9 @@ def test_the_decode_kernel_equals_the_xla_contractions(monkeypatch, window):
     lp = {"lambdas": jnp.asarray(rng.normal(size=(4, 64)) * 0.1, F32), "lambda_init": jnp.float32(0.5),
           "sub_norm": {"scale": jnp.ones((128,), BF16)}}
     positions = jnp.asarray([[99], [700], [M - 1 if not window else 2000]], jnp.int32)
-    assert not diff_decode.engages(k_arr)   # off the TPU the XLA contractions stay
+    assert not lane_decode.engages(k_arr)   # off the TPU the XLA contractions stay
     want = generate._diff_attention(q, k_arr, v_arr, jnp.int32(1), positions, lp, mc, window, "full_attn")
-    monkeypatch.setattr(diff_decode, "INTERPRET_OFF_TPU", True)
-    assert diff_decode.engages(k_arr) and not diff_decode.engages(k_arr[:, :, :500])
+    monkeypatch.setattr(lane_decode, "INTERPRET_OFF_TPU", True)
+    assert lane_decode.engages(k_arr) and not lane_decode.engages(k_arr[:, :, :500])
     got = generate._diff_attention(q, k_arr, v_arr, jnp.int32(1), positions, lp, mc, window, "full_attn")
     assert np.abs(np.asarray(got, np.float32) - np.asarray(want, np.float32)).max() < 0.02

@@ -408,10 +408,12 @@ def test_mesh_sharded_serving_matches_single_device():
     srv = ContinuousBatcher(sharded_params, cfg, max_slots=4, max_len=96,
                             compute_dtype=jnp.float32, prefill_pad_to=16,
                             chunk_steps=3, mesh=mesh)
-    # The pool really is sharded: kv-heads dim carries the model axis.
+    # The pool really is sharded: a lane's row [KV x HD] carries the model
+    # axis, in whole kv-heads.
     assert srv._cache.layers["attn"]["k"].sharding.spec == jax.sharding.PartitionSpec(
-        None, None, None, "model", None
+        None, None, None, "model"
     )
+    assert srv._cache.sharded
     assert srv.stats()["sharded"] is True
 
     rng = np.random.default_rng(17)
@@ -955,8 +957,10 @@ def _repeat_reference_block(x, lp, k_cache, v_cache, write, slot_pos, positions,
     if k_scale_c is not None:
         (k, k_s), (v, v_s) = _quantize_rows(k), _quantize_rows(v)
         k_scale_c, v_scale_c = write(k_scale_c, k_s), write(v_scale_c, v_s)
-    k_cache, v_cache = write(k_cache, k), write(v_cache, v)
-    kc, vc = k_cache, v_cache
+        k_cache, v_cache = write(k_cache, k), write(v_cache, v)
+    else:  # a cache that is not int8 keeps a lane's kv-heads side by side
+        k_cache, v_cache = (write(c, r.reshape(B, T, KV * HD)) for c, r in ((k_cache, k), (v_cache, v)))
+    kc, vc = (c.reshape(*c.shape[:2], KV, HD) for c in (k_cache, v_cache))
     if k_scale_c is not None:
         kc = kc.astype(x.dtype) * k_scale_c.astype(x.dtype)
         vc = vc.astype(x.dtype) * v_scale_c.astype(x.dtype)
@@ -1004,8 +1008,9 @@ def test_decode_block_grouped_attention_matches_repeat(G, T, rank, window,
     if kv_quant:
         (k_cache, k_s), (v_cache, v_s) = _quantize_rows(k_cache), _quantize_rows(v_cache)
         k_cache, v_cache = k_cache.astype(jnp.int8), v_cache.astype(jnp.int8)
-    else:
-        k_cache, v_cache, k_s, v_s = k_cache.astype(dtype), v_cache.astype(dtype), None, None
+    else:  # [B, M, KV x HD], as the cache that is not int8 stores them
+        k_cache, v_cache = (c.astype(dtype).reshape(B, M, KV * HD) for c in (k_cache, v_cache))
+        k_s = v_s = None
     steps = jnp.arange(T, dtype=jnp.int32)
     if rank == 1:  # generate(): all rows at one length, slot_pos [M]
         length = 6
@@ -1014,7 +1019,7 @@ def test_decode_block_grouped_attention_matches_repeat(G, T, rank, window,
 
         def write(arr, rows):
             return jax.lax.dynamic_update_slice(arr, rows.astype(arr.dtype),
-                                                (0, length, 0, 0))
+                                                (0, length) + (0,) * (arr.ndim - 2))
     else:          # the slot pool: each row at its own length, slot_pos [B, M]
         positions = jnp.asarray([7, 3], jnp.int32)[:, None] + steps[None, :]
         slot_pos = jnp.broadcast_to(jnp.arange(M, dtype=jnp.int32)[None, :], (B, M))

@@ -291,7 +291,7 @@ def test_the_latent_pool_is_written_in_place_at_the_long_context_cells_shapes(on
 
 
 def test_the_differential_decode_kernel_compiles_and_the_shared_cache_is_never_copied(one_chip, monkeypatch):
-    """``ops.diff_decode`` at Phi-4-mini-flash-reasoning's widths (20 kv-heads of
+    """``ops.lane_decode`` at Phi-4-mini-flash-reasoning's widths (20 kv-heads of
     64 = ten pairs of 128 values a lane; 32 slots of 12 288 lanes for the ONE
     full layer, a ring of 512 for the window layers), inside the decode program
     of a stack cut to one period of each decoder (6 layers: the pattern's four
@@ -303,9 +303,9 @@ def test_the_differential_decode_kernel_compiles_and_the_shared_cache_is_never_c
     from functools import partial
 
     from tpu_engine import serving
-    from tpu_engine.ops import diff_decode
+    from tpu_engine.ops import lane_decode
 
-    monkeypatch.setattr(diff_decode, "on_tpu", lambda: True)  # the described chip: this process's devices are the CPU's
+    monkeypatch.setattr(lane_decode, "on_tpu", lambda: True)  # the described chip: this process's devices are the CPU's
     types = ("mamba1", "diff_window_attention", "mamba1", "diff_attention", "gmu", "diff_cross_attention")
     mc = tfm.ModelConfig(name="phi-6-layers", vocab_size=8192, d_model=2560, n_layers=6, n_heads=40, n_kv_heads=20,
                          d_ff=10240, layer_types=types, sliding_window=512, mamba1_inner=5120, mamba1_state=16,
@@ -325,3 +325,44 @@ def test_the_differential_decode_kernel_compiles_and_the_shared_cache_is_never_c
     keys = B * 12288 * 1280 * 2
     assert memory.alias_size_in_bytes >= 2 * keys
     assert memory.temp_size_in_bytes < keys // 4
+
+
+@pytest.mark.parametrize("shape", ["mistral-7b", "granite-4.0-h-micro"])
+def test_the_attn_decode_kernel_compiles_and_no_layer_of_the_pool_is_copied(one_chip, monkeypatch, shape):
+    """``ops.lane_decode`` under the ``attn`` kind at the serving cells' widths
+    — Mistral-7B's (8 kv-heads of 128: a lane's row 1 024 values, one head a
+    column group; 16 slots of 2 048 lanes) and granite-4.0-h-micro's attention
+    layers' (8 kv-heads of 64: 512 values, two heads a group; 32 slots) — inside
+    the decode program of a two-layer stack: Mosaic takes the kernel, the pool
+    is aliased to the output, and nothing in the program has the shape of a
+    layer's keys but the step's own in-place writes — no copy, transposition or
+    slice of ``[slots, lanes, KV x HD]`` (XLA's contractions copy both leaves'
+    layer out of the carried pool on every layer-step: PERF.md, PR 44). A
+    compile, not a run."""
+    import re
+    from functools import partial
+
+    from tpu_engine import serving
+    from tpu_engine.ops import lane_decode
+
+    monkeypatch.setattr(lane_decode, "on_tpu", lambda: True)  # the described chip: this process's devices are the CPU's
+    B, HD, d_model, d_ff = (16, 128, 4096, 14336) if shape == "mistral-7b" else (32, 64, 2048, 8192)
+    mc = tfm.ModelConfig(name=shape + "-2-layers", vocab_size=8192, d_model=d_model, n_layers=2, n_heads=32,
+                         n_kv_heads=8, d_ff=d_ff, sliding_window=4096 if shape == "mistral-7b" else 0)
+    put = lambda t: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), t)  # noqa: E731
+    bf16 = jnp.bfloat16
+    params = put(jax.eval_shape(lambda k: tfm.init_params(k, mc, dtype=bf16), jax.random.PRNGKey(0)))
+    pool = put(jax.eval_shape(lambda: serving.init_slot_cache(mc, B, 2048, bf16, prefill_chunk=512)))
+    assert pool.layers["attn"]["k"].shape == (2, B, 2048, 8 * HD) and not pool.ring
+    vec = lambda dt: jax.ShapeDtypeStruct((B,), dt, sharding=one_chip)  # noqa: E731
+    key = put(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    dec = jax.jit(partial(serving.decode_chunk, cfg=mc, n_steps=2, compute_dtype=bf16), donate_argnums=(2,))
+    compiled = dec.lower(params, vec(jnp.int32), pool, vec(jnp.bool_), vec(jnp.float32), vec(jnp.int32),
+                         vec(jnp.int32), key).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert "attn_decode" in text and "tpu_custom_call" in text
+    layer = B * 2048 * 8 * HD * 2
+    assert memory.alias_size_in_bytes >= 4 * layer
+    assert memory.temp_size_in_bytes < layer // 2
+    a_layer = re.compile(rf"= bf16\[(1,)?{B},2048,({8 * HD}|8,{HD})\]\S* (copy|transpose|slice|dynamic-slice|fusion)\(")
+    assert not [line for line in text.splitlines() if a_layer.search(line)]

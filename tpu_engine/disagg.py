@@ -148,9 +148,10 @@ def extract_slot_kv(
     refuse_beyond_kv(cfg, "the KV handoff wire (extract_slot_kv)")
     if getattr(cache, "ring", False):
         raise ValueError("extract_slot_kv does not support ring pools")
-    # The attention kind's leaves, one row's resident lanes: [L, T, KV, HD].
+    # The attention kind's leaves, one row's resident lanes, as the wire has
+    # them: [L, T, KV, HD] (the pool keeps a lane's kv-heads side by side).
     kv = {name: a[:, slot, :length] for name, a in cache.layers["attn"].items()}
-    k, v = kv["k"], kv["v"]
+    k, v = (kv[name].reshape(-1, length, cfg.n_kv_heads, cfg.head_dim) for name in ("k", "v"))
     if cache.quantized:
         return KVHandoff(
             prompt=list(prompt), emitted=list(emitted), length=int(length),
@@ -242,7 +243,8 @@ def handoff_to_cache(
     def lanes(arr: np.ndarray, trailing: int, np_dtype: Any) -> np.ndarray:
         out = np.zeros((L, 1, M, KV, trailing), dtype=np_dtype)
         out[:, 0, :T] = arr
-        return out
+        # a pool that is not int8 keeps a lane's kv-heads side by side
+        return out if kv_quant else out.reshape(L, 1, M, KV * trailing)
 
     if kv_quant:
         attn = dict(k=jnp.asarray(lanes(codes_k, HD, np.int8)),

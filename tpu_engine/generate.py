@@ -5,7 +5,7 @@ a complete framework needs one for held-out evaluation, sampling during
 training, and serving smoke tests. TPU-first design:
 
 - **Static shapes end to end.** The cache is a fixed-``max_len`` set of
-  ``[L, B, M, KV, HD]`` buffers written with ``dynamic_update_slice``; the
+  ``[L, B, M, KV x HD]`` buffers written with ``dynamic_update_slice``; the
   decode loop is a ``lax.scan`` over ``max_new_tokens`` — no data-dependent
   Python control flow, one compile per (batch, max_len) shape.
 - **Same layer scan as training.** Layers are stacked ``[L, ...]`` pytrees
@@ -66,7 +66,7 @@ from tpu_engine.models.transformer import (
     served_format,
     unembed,
 )
-from tpu_engine.ops import diff_decode, mla_decode, sparse_block_attention, ssd_update
+from tpu_engine.ops import lane_decode, mla_decode, sparse_block_attention, ssd_update
 from tpu_engine.quant import QuantWeight, dequantize_weight
 
 _NEG_INF = -1e30
@@ -79,10 +79,10 @@ class KVCache:
     boundaries).
 
     ``layers`` is the tree :mod:`tpu_engine.layer_state` allocates,
-    ``{kind: {leaf: [L_kind, B, ...]}}``: keys and values ``[L, B, slots, KV,
-    HD]`` for the attention layers (int8 codes beside their scales when
-    ``init_cache(kv_quant=True)``), the recurrent state of a hybrid stack's
-    Mamba-2 layers, which has no position to mask. ``pos`` [slots] holds the
+    ``{kind: {leaf: [L_kind, B, ...]}}``: keys and values ``[L, B, slots, KV x
+    HD]`` for the attention layers (``[L, B, slots, KV, HD]`` int8 codes beside
+    their scales when ``init_cache(kv_quant=True)``), the recurrent state of a
+    hybrid stack's Mamba-2 layers, which has no position to mask. ``pos`` [slots] holds the
     global position stored in each slot (-1 = empty); ``length`` is the number
     of positions already written (scalar int32). When ``ring`` is set
     (sliding-window models whose cache is smaller than the sequence) the
@@ -271,9 +271,57 @@ def _quantize_rows(rows: jax.Array) -> tuple[jax.Array, jax.Array]:
     return codes, scale
 
 
+def _heads_a_group(cfg: ModelConfig) -> int:
+    """kv-heads one 128-value column group of a cache row holds (1 for heads of 128 or wider)."""
+    return max(lane_decode.COLUMNS // cfg.head_dim, 1)
+
+
+def _grouped_queries(q, cfg: ModelConfig):
+    """One step's queries q [B, H, HD] as the rows that attend each COLUMN GROUP
+    of a cache row ``[KV x HD]`` as it lies (``ops.lane_decode``): [B, P, R, W].
+    A head of 128 values (or a multiple) is a group of its own, its rows the
+    kv-head's G queries; narrower heads lie ``128 // HD`` to a group, and a row
+    is its query in its own head's columns and zero in the others' (``q_a | 0``
+    for the first head's G queries, ``0 | q_b`` for the second's: the packing
+    of :func:`_diff_queries`), so that the group's keys are contracted whole."""
+    B = q.shape[0]
+    KV, HD, G = cfg.n_kv_heads, cfg.head_dim, cfg.n_heads // cfg.n_kv_heads
+    per = _heads_a_group(cfg)
+    qg = q.reshape(B, KV // per, per, G, HD)
+    if per > 1:
+        qg = jnp.einsum("bpigd,ij->bpigjd", qg, jnp.eye(per, dtype=q.dtype))
+    return qg.reshape(B, KV // per, per * G, per * HD)
+
+
+def _grouped_outputs(a, cfg: ModelConfig):
+    """:func:`_grouped_queries`' rows back: a [B, P, R, W] -> [B, H x HD], of
+    each row the columns of its own head."""
+    B = a.shape[0]
+    KV, HD, G = cfg.n_kv_heads, cfg.head_dim, cfg.n_heads // cfg.n_kv_heads
+    per = _heads_a_group(cfg)
+    if per > 1:
+        a = jnp.einsum("bpigjd,ij->bpigd", a.reshape(B, KV // per, per, G, per, HD),
+                       jnp.eye(per, dtype=a.dtype))
+    return a.reshape(B, KV * G * HD)
+
+
+def lane_walk_engages(k_cache, T: int, cfg: ModelConfig) -> bool:
+    """Whether a step's read of the ``attn`` kind's keys and values goes
+    through ``ops.lane_decode`` (decided from what the trace sees): one query a
+    row, a bfloat16 stack of whole blocks and column groups on a TPU
+    (``lane_decode.engages``), kv-heads that fill whole column groups, and no
+    sliding window shorter than the row's lanes (none binds: a shorter one
+    keeps XLA's masked contractions)."""
+    KV, HD, M = cfg.n_kv_heads, cfg.head_dim, k_cache.shape[2]
+    per = _heads_a_group(cfg)
+    return (T == 1 and k_cache.dtype == jnp.bfloat16 and lane_decode.engages(k_cache)
+            and (HD * per) % lane_decode.COLUMNS == 0 and KV % per == 0
+            and not 0 < cfg.sliding_window < M)
+
+
 def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
                   cfg: ModelConfig, k_scale_c=None, v_scale_c=None, read=None,
-                  valid=None, tally=None):
+                  valid=None, tally=None, at=None, visible=None):
     """One transformer block attending against the cache as stored.
 
     Attention contracts the query heads, grouped by the KV head they share,
@@ -284,21 +332,36 @@ def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
 
     x: [B, T, D] new activations. ``k_cache`` / ``v_cache`` are whatever
     ``write`` takes and returns, and ``read(cache_arr)`` gives this layer's
-    [B, M, KV, HD] of it. Every walk of a stack (:func:`scan_layers`) hands
-    the WHOLE ``[L, B, M, KV, HD]`` cache: ``write(cache_arr, rows)`` stores
-    the chunk's rows [B, T, KV, HD] in this layer's lanes only, in place, and
-    ``read`` slices the layer out for the two contractions and nothing else —
-    the layer is never written back whole. ``read=None`` is the identity: the
-    arrays are then one layer's own [B, M, KV, HD] (the tests' layer-by-layer
-    references).
+    [B, M, KV x HD] of it: a lane's row holds its kv-heads side by side, as
+    the newer positional kinds' do. Every walk of a stack (:func:`scan_layers`)
+    hands the WHOLE ``[L, B, M, KV x HD]`` cache: ``write(cache_arr, rows)``
+    stores the chunk's rows [B, T, KV x HD] in this layer's lanes only, in
+    place, and the layer is never written back whole. It is READ by one of
+    two paths that give the same numbers:
+
+    - a decode step (T = 1) of a walk that says how many leading lanes each
+      row sees (``visible`` [B] int32, lane m holding position m; 0: the row
+      does not decode) and which layer this is (``at``), where
+      :func:`lane_walk_engages`: the kernel ``ops.lane_decode`` (profile name
+      ``attn_decode``) reads the blocks of 512 lanes a row's length covers from
+      the stack where it lies, once; a row that does not decode is neither
+      read nor computed, and its attention is zeros;
+    - anywhere else (a chunk, a verify pass, a ring or int8 or mesh-sharded
+      cache, off the TPU): ``read`` slices the layer out for XLA's two
+      contractions over every lane, masked by ``slot_pos`` — the plain
+      statement of what the kernel computes. ``read=None`` is the identity: the
+      arrays are then one layer's own [B, M, KV x HD] (the tests'
+      layer-by-layer references).
+
     ``slot_pos`` is the global position held by each cache slot after this
     chunk's writes — [M] (all rows in lockstep, the generate() case) or
     [B, M] (per-row positions, the continuous-batching slot pool in
     ``tpu_engine/serving.py``).
-    ``k_scale_c``/``v_scale_c`` (as ``k_cache``, trailing 1 instead of HD)
-    are present for int8 caches: new rows are quantised before the write and
-    the cache reads dequantise (the convert+mul fuses into the attention
-    dots). ``valid`` / ``tally`` are :func:`_mlp_block`'s.
+    ``k_scale_c``/``v_scale_c`` are present for int8 caches, which keep
+    ``[.., M, KV, HD]`` codes beside ``[.., M, KV, 1]`` scales: new rows
+    [B, T, KV, HD] are quantised before the write and the cache reads
+    dequantise (the convert+mul fuses into the attention dots). ``valid`` /
+    ``tally`` are :func:`_mlp_block`'s.
     """
     B, T, D = x.shape
     H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -333,34 +396,41 @@ def _decode_block(x, layer_params, k_cache, v_cache, write, slot_pos, positions,
                 k_scale_c = write(k_scale_c, k_s)
                 v_scale_c = write(v_scale_c, v_s)
             else:
-                k_cache = write(k_cache, k)
-                v_cache = write(v_cache, v)
+                k_cache = write(k_cache, k.reshape(B, T, KV * HD))
+                v_cache = write(v_cache, v.reshape(B, T, KV * HD))
 
+        scale = attention_scale(cfg)
         with jax.named_scope("decode_attn"):
-            read = read or (lambda a: a)
-            kc, vc = read(k_cache), read(v_cache)
-            if k_scale_c is not None:
-                kc = kc.astype(x.dtype) * read(k_scale_c).astype(x.dtype)
-                vc = vc.astype(x.dtype) * read(v_scale_c).astype(x.dtype)
-            qg = q.reshape(B, T, KV, H // KV, HD)  # KV-major groups
-            scale = attention_scale(cfg)
-            scores = jnp.einsum(
-                "btkgd,bmkd->bkgtm", qg, kc, preferred_element_type=jnp.float32
-            ) * scale
-            # Slot m is visible to query t iff it holds a real position (≥ 0)
-            # that is ≤ the query's global position (causal). Sliding-window
-            # models additionally hide keys older than the window, matching
-            # the training-time mask; ring-buffer slots overwritten by
-            # in-chunk later positions are masked for earlier queries by the
-            # same comparison.
-            key_pos = slot_pos if slot_pos.ndim == 2 else slot_pos[None, :]  # [B|1, M]
-            kp = key_pos[:, None, :]                                         # [B|1, 1, M]
-            mask = (kp >= 0) & (kp <= positions[:, :, None])
-            if cfg.sliding_window:
-                mask &= kp > positions[:, :, None] - cfg.sliding_window
-            scores = jnp.where(mask[:, None, None, :, :], scores, _NEG_INF)
-            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(x.dtype)
-            attn = jnp.einsum("bkgtm,bmkd->btkgd", probs, vc).reshape(B, T, H * HD)
+            if visible is not None and k_scale_c is None and lane_walk_engages(k_cache, T, cfg):
+                attn = _grouped_outputs(
+                    lane_decode.lane_decode(_grouped_queries(q[:, 0], cfg), k_cache, v_cache, at, visible,
+                                            scale=scale, name="attn_decode"), cfg)[:, None].astype(x.dtype)
+            else:
+                read = read or (lambda a: a)
+                kc, vc = read(k_cache), read(v_cache)
+                if k_scale_c is not None:
+                    kc = kc.astype(x.dtype) * read(k_scale_c).astype(x.dtype)
+                    vc = vc.astype(x.dtype) * read(v_scale_c).astype(x.dtype)
+                # ONE layer's view (or the staging row's) by kv-head, never the stack's
+                kc, vc = (a.reshape(*a.shape[:2], KV, HD) for a in (kc, vc))
+                qg = q.reshape(B, T, KV, H // KV, HD)  # KV-major groups
+                scores = jnp.einsum(
+                    "btkgd,bmkd->bkgtm", qg, kc, preferred_element_type=jnp.float32
+                ) * scale
+                # Slot m is visible to query t iff it holds a real position (≥ 0)
+                # that is ≤ the query's global position (causal). Sliding-window
+                # models additionally hide keys older than the window, matching
+                # the training-time mask; ring-buffer slots overwritten by
+                # in-chunk later positions are masked for earlier queries by the
+                # same comparison.
+                key_pos = slot_pos if slot_pos.ndim == 2 else slot_pos[None, :]  # [B|1, M]
+                kp = key_pos[:, None, :]                                         # [B|1, 1, M]
+                mask = (kp >= 0) & (kp <= positions[:, :, None])
+                if cfg.sliding_window:
+                    mask &= kp > positions[:, :, None] - cfg.sliding_window
+                scores = jnp.where(mask[:, None, None, :, :], scores, _NEG_INF)
+                probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(x.dtype)
+                attn = jnp.einsum("bkgtm,bmkd->btkgd", probs, vc).reshape(B, T, H * HD)
         x = _residual(x, proj(attn, "o"), cfg)
 
     x = _mlp_block(x, layer_params, cfg, valid, tally)
@@ -1084,12 +1154,12 @@ def _diff_attention(q, k_arr, v_arr, at, positions, lp, cfg: ModelConfig, window
     length = positions[:, -1] + 1
     Tq = min(T, DIFF_QUERY_BLOCK)
     S = min(M, Tq + window - 1) if window else M
-    if T == 1 and S == M and M <= (window or M) and diff_decode.engages(k_arr):
+    if T == 1 and S == M and M <= (window or M) and lane_decode.engages(k_arr):
         # One query a row against the layer as it lies, the lanes a row has and
         # no others (of a ring no longer than the window: all it holds is seen).
         with jax.named_scope(scope):
-            a = diff_decode.diff_decode(_diff_queries(q, cfg)[:, 0], k_arr, v_arr, at,
-                                        jnp.minimum(length, M), scale=attention_scale(cfg))
+            a = lane_decode.lane_decode(_diff_queries(q, cfg)[:, 0], k_arr, v_arr, at, jnp.minimum(length, M),
+                                        scale=attention_scale(cfg), name="diff_decode")
         return _diff_combine(a[:, None], lp, cfg, q.dtype)
 
     def attend(xs):
@@ -1171,7 +1241,7 @@ def _gmu_block(x, lp, mem, valid, cfg: ModelConfig, tally=None):
 
 
 def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
-                valid=None, ingest_only: bool = False, tail_row=None):
+                valid=None, ingest_only: bool = False, tail_row=None, visible=None):
     """Walk the stack against (and into) ``cache`` — THE one cached walk, for
     every architecture, a :class:`KVCache` or the serving pool alike (both
     hold their per-layer arrays as ``cache.layers``, the tree by kind of
@@ -1184,16 +1254,23 @@ def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
     arrays ride along, which lanes ``write`` picks.
 
     - CARRIED: ``x`` and ``cache.layers``, whole — keys and values
-      ``[L_attn, B, M, KV, HD]`` (with their scales for an int8 cache), the
-      recurrent ``ssm`` / ``conv`` ``[L_ssm, B, ...]``. Nothing of the cache is
-      a scan input or output, so no run rebuilds it and the loop updates the
-      (donated) buffers where they lie.
+      ``[L_attn, B, M, KV x HD]`` (an int8 cache: ``[L_attn, B, M, KV, HD]``
+      codes with their scales), the recurrent ``ssm`` / ``conv``
+      ``[L_ssm, B, ...]``. Nothing of the cache is a scan input or output, so
+      no run rebuilds it and the loop updates the (donated) buffers where
+      they lie.
     - WRITTEN IN PLACE: ``write(cache_arr, rows, at)`` stores a layer's new
-      rows [B, T, KV, HD] in layer ``at``'s lanes of ``cache_arr`` (a scatter
+      rows [B, T, KV x HD] in layer ``at``'s lanes of ``cache_arr`` (a scatter
       or ``dynamic_update_slice`` with the layer index folded in); a Mamba-2
       layer rewrites its own slice of ``ssm`` / ``conv`` (:func:`_ssm_block`).
-    - ONLY READ: the layer's [B, M, KV, HD] for the two attention
-      contractions (``_decode_block(read=)``), and the parameters — ``stacks``
+    - ONLY READ: an attention layer's keys and values — by a decode step of a
+      caller that hands ``visible`` ([B] int32: the leading lanes each row
+      sees, lane m holding position m, 0 for a row that does not decode; the
+      serving pool's non-ring step) through the kernel ``ops.lane_decode``,
+      the blocks a row's length covers from the stack where it lies, where
+      that engages (``_decode_block``); else the layer's [B, M, KV x HD]
+      sliced out for XLA's two contractions (``_decode_block(read=)``) — and
+      the parameters — ``stacks``
       is ``params["layers"]`` of a tree in ``transformer.served_format`` (the
       walk casts nothing and refuses a stack in another dtype than ``x``'s),
       never written, so it stays outside the carry and a run indexes its
@@ -1235,7 +1312,7 @@ def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
         x, k, v, k_scale, v_scale = _decode_block(
             x, lp, s["k"], s["v"], lambda arr, rows: write(arr, rows, at), slot_pos,
             positions, cfg, k_scale_c=s.get("k_scale"), v_scale_c=s.get("v_scale"),
-            read=lambda arr: layer_slice(arr, at), valid=valid, tally=tally)
+            read=lambda arr: layer_slice(arr, at), valid=valid, tally=tally, at=at, visible=visible)
         new = {"k": k, "v": v, "k_scale": k_scale, "v_scale": v_scale}
         return x, {name: new[name] for name in s}  # scales only where they came in
 
@@ -1355,8 +1432,8 @@ def forward_with_cache(
     Returns (logits [B, T, V] fp32, updated cache with length += T).
     The layer walk (:func:`scan_layers`) CARRIES the cache's arrays whole and
     each layer writes the chunk's T rows straight into its own lanes of them
-    (one ``dynamic_update_slice`` at ``(layer, 0, offset, 0, 0)``); a layer's
-    [B, M, KV, HD] is only read, for attention. Jitted with the cache donated,
+    (one ``dynamic_update_slice`` at ``(layer, 0, offset, 0)``); a layer's
+    [B, M, KV x HD] is only read, for attention. Jitted with the cache donated,
     the cache is updated where it lies.
     ``want_logits=False`` (static) skips the unembed entirely and returns
     ``(None, cache)`` — cache-ingestion-only callers (the speculative
@@ -1414,15 +1491,15 @@ def forward_with_cache(
         )
 
         def write(cache_arr, rows, at):
-            rows_m = jnp.einsum("tm,btkh->bmkh", onehot.astype(cache_arr.dtype),
+            rows_m = jnp.einsum("tm,bt...->bm...", onehot.astype(cache_arr.dtype),
                                 rows.astype(cache_arr.dtype))
-            layer = jnp.where(written[None, :, None, None], rows_m,
+            layer = jnp.where(written.reshape((1, M) + (1,) * (rows.ndim - 2)), rows_m,
                               layer_slice(cache_arr, at))
             return lax.dynamic_update_index_in_dim(cache_arr, layer, at, 0)
     else:
         # Contiguous, non-wrapping write (T=1 ring decode, or any non-ring
         # chunk): a cheap O(T) dynamic_update_slice at the layer and the slot
-        # offset, straight into the [L, B, M, KV, HD] cache; likewise the pos
+        # offset, straight into the [L, B, M, KV x HD] cache; likewise the pos
         # vector.
         offset = cache.length % M if cache.ring else cache.length
         pos_new = lax.dynamic_update_slice(cache.pos, new_pos, (offset,))

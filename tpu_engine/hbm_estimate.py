@@ -395,7 +395,7 @@ def estimate_serving_hbm(
       per-channel fp32 scales when the replica loads a ``quant.py`` snapshot
       (``weight_quant="int8"``), divided over the ``model`` (tensor-parallel)
       axis;
-    - K and V per layer: ``[slots, lanes, n_kv_heads, head_dim]`` at the
+    - K and V per layer: ``[slots, lanes, n_kv_heads x head_dim]`` at the
       compute dtype, or int8 codes plus per-(lane, kv-head) fp32 scales when
       ``kv_quant`` — the exact layout ``init_slot_cache`` builds, kv-heads
       sharded over the model axis when divisible;

@@ -2,7 +2,11 @@
 
 A request keeps state per LAYER while it is served, and what it keeps depends
 on the kind of layer: an attention layer keeps keys and values for every
-position, a Mamba-2 layer keeps one recurrent state whatever the length. Both
+position (a lane's row ``[KV x HD]``, the kv-heads side by side: the format of
+every positional kind that keeps keys and values, so that a block of lanes is
+whole contiguous rows and a decode step's kernel reads the pool where it lies;
+only an int8 pool keeps ``[KV, HD]`` codes beside their scales), a Mamba-2
+layer keeps one recurrent state whatever the length. Both
 caches (:class:`generate.KVCache`, one row in lockstep;
 :class:`serving.SlotCache`, a pool of rows with their own lengths) hold that
 state as ONE tree, ``{kind: {leaf: array [L_kind, rows, ...]}}``, allocated,
@@ -53,11 +57,14 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 class Leaf(NamedTuple):
     """One array a kind keeps: ``shape`` follows ``[L_kind, rows]``;
     ``model_dim`` is the index (into ``shape``) of the dim that shards over
-    the mesh's ``model`` axis when divisible, None = replicated."""
+    the mesh's ``model`` axis when divisible, None = replicated;
+    ``model_units`` is how many pieces that dim may be cut into (kv-heads lying
+    side by side in it: a shard holds whole ones), None = its length."""
 
     shape: tuple
     dtype: Any
     model_dim: Optional[int] = None
+    model_units: Optional[int] = None
 
 
 class LayerKind(NamedTuple):
@@ -68,15 +75,21 @@ class LayerKind(NamedTuple):
 
 
 def _attn_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
-    """Keys and values per lane and kv-head — or int8 codes with one float32
-    absmax/127 scale per (lane, kv-head), half the bytes of bf16."""
-    rows = Leaf((lanes, cfg.n_kv_heads, cfg.head_dim),
-                jnp.int8 if kv_quant else dtype, model_dim=1)
-    leaves = {"k": rows, "v": rows}
+    """Keys and values per lane, the kv-heads side by side in the last dim
+    (``[lanes, KV x HD]``, as every newer positional kind's): a block of lanes
+    is whole contiguous rows, which is how a decode step reads them where they
+    lie (``ops.lane_decode``), and a head of 64 does not leave half a tile's
+    columns empty. Over a mesh's ``model`` axis the last dim splits in whole
+    kv-heads. ``kv_quant``: int8 codes per lane and kv-head ``[lanes, KV, HD]``
+    with one float32 absmax/127 scale per (lane, kv-head) ``[lanes, KV, 1]``,
+    half the bytes of bf16 (read by XLA's dequantising contractions)."""
     if kv_quant:
+        codes = Leaf((lanes, cfg.n_kv_heads, cfg.head_dim), jnp.int8, model_dim=1)
         scales = Leaf((lanes, cfg.n_kv_heads, 1), jnp.float32, model_dim=1)
-        leaves.update(k_scale=scales, v_scale=scales)
-    return leaves
+        return {"k": codes, "v": codes, "k_scale": scales, "v_scale": scales}
+    rows = Leaf((lanes, cfg.n_kv_heads * cfg.head_dim), dtype, model_dim=1,
+                model_units=cfg.n_kv_heads)
+    return {"k": rows, "v": rows}
 
 
 def _ssm_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
@@ -227,7 +240,8 @@ def init_layers(cfg, rows: int, lanes: int, dtype, kv_quant: bool = False,
 
 
 def _model_sharded(leaf: Leaf, tp: int) -> bool:
-    return leaf.model_dim is not None and leaf.shape[leaf.model_dim] % tp == 0
+    return leaf.model_dim is not None \
+        and (leaf.model_units or leaf.shape[leaf.model_dim]) % tp == 0
 
 
 def state_bytes(cfg, rows: int, lanes: int, dtype, kv_quant: bool = False,

@@ -9,10 +9,11 @@ requests and short prompts are not held hostage by long ones.
 
 TPU-first design:
 
-- **Static shapes everywhere.** The KV pool is ``[L, slots, S, KV, HD]``
+- **Static shapes everywhere.** The KV pool is ``[L, slots, S, KV x HD]``
   for the server's lifetime; one jitted dispatch advances ALL slots
-  ``chunk_steps`` tokens per call (empty/finished lanes compute masked
-  garbage — wasted lanes, never a recompile).
+  ``chunk_steps`` tokens per call (empty/finished slots are wasted rows of
+  the projections, never a recompile; on a TPU a decode step's attention
+  reads only the lanes a live slot has, ``ops.lane_decode``).
 - **Per-row positions.** Unlike :class:`generate.KVCache` (whose scalar
   ``length`` advances every row in lockstep), each slot carries its own
   length; K/V writes are per-row scatters (``.at[arange(B), lane]``) and
@@ -67,6 +68,7 @@ from tpu_engine.generate import (
     forward_with_cache,
     init_cache,
     init_moe_counts,
+    lane_walk_engages,
     ring_lanes,
     scan_layers,
 )
@@ -80,7 +82,7 @@ from tpu_engine.models.transformer import (
     unembed,
     weight_bytes_by_dtype,
 )
-from tpu_engine.ops import ssd_update
+from tpu_engine.ops import lane_decode, ssd_update
 from tpu_engine.profiler import StepProfiler
 
 
@@ -116,6 +118,10 @@ class SlotCache:
 
     ``moe_counts`` (a mixture's pool only): ``generate.MOE_COUNTS`` of the
     walks since :func:`decode_chunk` last zeroed them, i.e. of one dispatch.
+
+    ``sharded``: the pool lies over a mesh (set by whoever places it there;
+    a trace cannot see it otherwise), so its leaves are not one device's to
+    walk and a decode step keeps XLA's contractions.
     """
 
     layers: dict
@@ -123,6 +129,7 @@ class SlotCache:
     pos: Optional[jax.Array] = None  # [B, S] int32, ring pools only
     ring: bool = field(default=False, metadata=dict(static=True))
     moe_counts: Optional[jax.Array] = None
+    sharded: bool = field(default=False, metadata=dict(static=True))
 
     @property
     def n_lanes(self) -> int:
@@ -186,19 +193,30 @@ def decode_step(
     family the walk supports is therefore served here with zero forked model
     code.
 
-    The walk CARRIES the pool — keys and values ``[L, B, S, KV, HD]`` (and the
-    scales of an int8 pool, the recurrent state of a hybrid) — and each layer
-    scatters one row per slot straight into its own lanes of it; a layer's
-    ``[B, S, KV, HD]`` is only read, for attention. No layer is taken out and
-    put back and no pool is rebuilt as a scan output, so a jitted caller that
-    donates the pool (``decode_chunk`` in the batcher) updates it where it
-    lies: one row of ``B × KV × HD`` per layer and step is all that is written.
+    The walk CARRIES the pool — keys and values ``[L, B, S, KV x HD]`` (an
+    int8 pool's codes and scales, the recurrent state of a hybrid) — and each
+    layer scatters one row per slot straight into its own lanes of it; a
+    layer's keys and values are only read, for attention. No layer is taken
+    out and put back and no pool is rebuilt as a scan output, so a jitted
+    caller that donates the pool (``decode_chunk`` in the batcher) updates it
+    where it lies: one row of ``B × KV × HD`` per layer and step is all that is
+    written.
 
-    Inactive rows still compute (static shapes) but their lengths do not
-    advance and their writes land in lanes the mask never exposes (for ring
-    pools the overwritten lane held a position already outside the window,
-    and its ``pos`` entry is not updated, so the garbage stays invisible); a
-    recurrent state has no mask, so a row that is not active keeps it exactly.
+    A pool in which lane m holds position m (not a ring) on one device hands
+    the walk each row's visible lanes (``length + 1``, 0 for a row that is not
+    active): on a TPU an ``attn`` layer then reads, per active row, the blocks
+    of 512 lanes its length covers, from the pool's leaf where it lies, once
+    (``generate._decode_block``, the kernel ``ops.lane_decode``), and a row
+    that is not active is neither read nor computed — its attention is zeros.
+    A ring pool, a pool over a mesh, an int8 pool and every run off the TPU
+    keep XLA's two contractions over every lane of every slot.
+
+    Inactive rows still compute their projections (static shapes) but their
+    lengths do not advance and their writes land in lanes the mask never
+    exposes (for ring pools the overwritten lane held a position already
+    outside the window, and its ``pos`` entry is not updated, so the garbage
+    stays invisible); a recurrent state has no mask, so a row that is not
+    active keeps it exactly.
     """
     B = tokens.shape[0]
     S = cache.n_lanes
@@ -215,6 +233,7 @@ def decode_step(
             jnp.where(active, cache.lengths, cache.pos[rows, lane])
         )
         slot_pos = pos_new                                   # [B, S]
+        visible = None
     else:
         lane = cache.lengths
         pos_new = None
@@ -223,22 +242,38 @@ def decode_step(
         slot_pos = jnp.broadcast_to(
             jnp.arange(S, dtype=jnp.int32)[None, :], (B, S)
         )
+        # ... which is the same mask said by a count: a row sees its leading
+        # ``length + 1`` lanes, a row that does not advance none (one device's
+        # pool: a leaf a mesh shards is not the kernel's to walk).
+        visible = None if cache.sharded else jnp.where(active, cache.lengths + 1, 0)
 
     def write(cache_arr, new_rows, at, ring=False):
         # Per-row scatter at each slot's own lane of layer ``at`` (T = 1).
         # Out-of-bounds lanes (a finished-mid-chunk row running past
-        # capacity) drop. Serves the scale arrays of a quantized pool too
-        # (same leading [L, B, S, KV] dims, trailing 1 instead of HD). A
+        # capacity) drop. Serves the codes and scale arrays of a quantized
+        # pool too ([L, B, S, KV, HD] and trailing 1 instead of HD). A
         # ring KIND's leaf (a hybrid's window layers) wraps at its own length.
         return cache_arr.at[at, rows, lane % cache_arr.shape[2] if ring else lane].set(
             new_rows[:, 0].astype(cache_arr.dtype)
         )
 
     x, cache = scan_layers(x, params["layers"], cfg,
-                           cache, write, slot_pos, positions, active[:, None])
+                           cache, write, slot_pos, positions, active[:, None],
+                           visible=visible)
     logits = unembed(params, x, cfg)[:, 0]                  # [B, V] fp32
     return logits, dataclasses.replace(
         cache, lengths=cache.lengths + active.astype(jnp.int32), pos=pos_new)
+
+
+def lane_walk_layers(cfg: ModelConfig, cache: SlotCache) -> int:
+    """``attn`` layers of ``cache`` whose keys and values a :func:`decode_step`
+    reads through ``ops.lane_decode``, decided as its trace decides (the pool's
+    kind, the leaf's shape and dtype, the device); 0 where XLA's contractions
+    stay."""
+    keys = cache.layers.get("attn", {}).get("k")
+    if keys is None or cache.ring or cache.sharded or not lane_walk_engages(keys, 1, cfg):
+        return 0
+    return keys.shape[0]
 
 
 def _pick_tokens(
@@ -292,6 +327,11 @@ def decode_chunk(
     A mixture's pool leaves with ``moe_counts`` those of THIS dispatch (zeroed
     here, summed over its steps and layers): the host reads them in the fetch
     that brings the tokens.
+
+    The pool ``[L, B, S, KV x HD]`` rides in the scan's carry, donated: every
+    step writes one row a layer and slot into it and reads, on a TPU, only the
+    lanes the active slots have (:func:`decode_step`); no copy, transposition
+    or slice of a whole layer is in the compiled program.
     """
     cache = dataclasses.replace(cache, moe_counts=init_moe_counts(cfg))
 
@@ -343,7 +383,7 @@ def decode_verify(
         jnp.arange(S, dtype=jnp.int32)[None, :], (B, S)
     )
 
-    def write(cache_arr, new_rows, at):  # new_rows [B, T, KV, HD] (or [.., 1])
+    def write(cache_arr, new_rows, at):  # new_rows [B, T, KV x HD] (int8: [B, T, KV, HD | 1])
         return cache_arr.at[at, rows[:, None], positions].set(
             new_rows.astype(cache_arr.dtype)
         )
@@ -719,6 +759,7 @@ class ContinuousBatcher:
         self._cache_sh = self._rep = None
         if mesh is not None:
             self._rep = NamedSharding(mesh, P())
+            self._cache = dataclasses.replace(self._cache, sharded=True)
             self._cache_sh = layer_state.cache_shardings(mesh, cfg, self._cache)
             self._cache = jax.device_put(self._cache, self._cache_sh)
             self._base_key = jax.device_put(self._base_key, self._rep)
@@ -918,6 +959,15 @@ class ContinuousBatcher:
             if not layer_state.LAYER_KINDS[kind].positional
             for leaf in leaves.values() if ssd_update.engages(leaf))
         self._recurrent_updates_in_place = 0
+        # ``attn`` layers whose decode step reads the pool through the
+        # lane-walking kernel (``ops.lane_decode``; a speculative engine's
+        # target never steps one token), the lanes of keys it read there (and
+        # as many of values) and the lanes the pool holds for them.
+        self._pool_lanes = self._cache.n_lanes
+        self._lane_walk_layers = 0 if draft_params is not None \
+            else lane_walk_layers(cfg, self._cache)
+        self._decode_attn_lanes_read = 0
+        self._decode_attn_lanes_pool = 0
         # A mixture's routing, by program: layer-steps run (counted here) and
         # ``generate.MOE_COUNTS`` (counted on the device, fetched with each
         # dispatch's tokens). Empty for a model without experts.
@@ -1296,6 +1346,15 @@ class ContinuousBatcher:
                 "shared_kv_bytes": self._shared_kv_bytes,
                 "window_kv_bytes": self._window_kv_bytes,
                 "recurrent_updates_in_place_total": self._recurrent_updates_in_place,
+                # Monotonic, per decode step and ``attn`` layer that reads the
+                # pool through the lane-walking kernel (``ops.lane_decode``):
+                # the lanes of keys it fetched (whole blocks of 512 up to each
+                # active slot's length; as many of values) and the lanes the
+                # pool holds (slots x lanes, what XLA's contractions read).
+                # Their ratio is the pool's live share as the kernel sees it;
+                # both stay 0 where the kernel does not engage.
+                "decode_attn_lanes_read_total": self._decode_attn_lanes_read,
+                "decode_attn_lanes_pool_total": self._decode_attn_lanes_pool,
                 "state_inserts_total": self._state_inserts,
                 "state_resets_total": self._state_resets,
                 # The weights the engine holds, by dtype, counted once at
@@ -1613,8 +1672,10 @@ class ContinuousBatcher:
         # What this dispatch decodes rides on the annotation: a trace that ends
         # before the slots are full can still hold each run of the decode
         # program against the rows it computed for and the lanes they held.
-        with prof.phase("device", rows=len(active_reqs),
-                        context=sum(len(r.prompt) + len(r.tokens) for _, r in active_reqs)):
+        contexts = [len(r.prompt) + len(r.tokens) for _, r in active_reqs]
+        lanes_read = self._attn_lanes_read(contexts)
+        with prof.phase("device", rows=len(active_reqs), context=sum(contexts),
+                        attn_lanes_read=lanes_read):
             if speculative:
                 toks_host = np.asarray(tgt)         # [B, gamma+1]
                 n_take = np.asarray(n_acc)          # [B] accepted per slot
@@ -1626,6 +1687,9 @@ class ContinuousBatcher:
         n_steps = toks_host.shape[1]
         self._decode_tokens_computed += len(active_reqs) * n_steps
         self._recurrent_updates_in_place += n_steps * self._in_place_layers
+        self._decode_attn_lanes_read += lanes_read
+        self._decode_attn_lanes_pool += (n_steps * self._lane_walk_layers
+                                         * self.max_slots * self._pool_lanes)
         if self._sparse_from is not None:
             # A row's steps run at positions context - 1 .. context + n - 2.
             self._decode_tokens_sparse += sum(
@@ -1701,6 +1765,16 @@ class ContinuousBatcher:
         )
         self._last_tokens[slot] = handoff.last_token
         self.handoffs_in += 1
+
+    def _attn_lanes_read(self, contexts: list[int]) -> int:
+        """Lanes of keys a dispatch's ``attn`` layers fetch through the
+        lane-walking kernel for rows at ``contexts`` (prompt + generated: what a
+        row sees at the dispatch's first step, one more each step, in whole
+        blocks, never past the pool's lanes); 0 where it does not engage."""
+        if not self._lane_walk_layers:
+            return 0
+        seen = np.minimum(np.asarray(contexts)[:, None] + np.arange(self.chunk_steps), self._pool_lanes)
+        return self._lane_walk_layers * int((-(-seen // lane_decode.LANES) * lane_decode.LANES).sum())
 
     def _note_moe(self, program: str, counts, steps: int) -> None:
         """Add a dispatch's (decode) or a prompt's (prefill) mixture counts:
