@@ -268,7 +268,8 @@ def test_the_router_keeps_its_width_and_a_token_with_no_held_expert_gets_the_sha
     assert not np.array_equal(np.asarray(out[0])[~none_held], np.asarray(shared)[~none_held])
     # the counts are the hand count of the router's choices
     held = np.asarray(idx < 4)
-    assert counts.tolist() == [400 * 3, int(held.sum()), len(set(np.asarray(idx)[held].tolist()))]
+    assert counts.tolist() == [400 * 3, int(held.sum()), len(set(np.asarray(idx)[held].tolist())),
+                               400 * 4]  # the masked form's rows: every real position x the 4 held
 
 
 # (d) an expert's weights depend on (seed, layer, expert index) only ----------
@@ -425,7 +426,7 @@ def test_mixtrals_mixture_through_the_new_block_is_the_parents():
     want, weights = _parent_moe_mlp_decode(h, lp, mc)
     got, counts = _moe_mlp_decode(h, lp, mc, jnp.ones((3, 17), bool))
     vals, idx = lax.top_k(weights, 2)
-    assert counts.tolist() == [3 * 17 * 2, 3 * 17 * 2, 4]
+    assert counts.tolist() == [3 * 17 * 2, 3 * 17 * 2, 4, 3 * 17 * 4]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-8, rtol=0)  # outputs are O(1e-3)
     # the gates, through the new code's own expression
     chosen = idx[..., None] == jnp.arange(4)
@@ -434,7 +435,7 @@ def test_mixtrals_mixture_through_the_new_block_is_the_parents():
     # and the uniform stack's cached walk still equals prefill-then-decode of itself
     toks = jnp.asarray(_tokens(24, 30) % mc.vocab_size)[None]
     whole, cache = forward_with_cache(params, toks, init_cache(mc, 1, 32, dtype=F32), mc, compute_dtype=F32)
-    assert cache.moe_counts.tolist() == [24 * 2 * 2, 24 * 2 * 2, cache.moe_counts.tolist()[2]]
+    assert cache.moe_counts.tolist() == [24 * 2 * 2, 24 * 2 * 2, cache.moe_counts.tolist()[2], 24 * 4 * 2]
     part, c = forward_with_cache(params, toks[:, :16], init_cache(mc, 1, 32, dtype=F32), mc, compute_dtype=F32)
     rest, _ = forward_with_cache(params, toks[:, 16:], c, mc, compute_dtype=F32)
     np.testing.assert_allclose(np.asarray(jnp.concatenate([part, rest], 1)), np.asarray(whole), atol=2e-6)

@@ -15,6 +15,7 @@ times that. The control attends, for every query, the whole union of its tile,
 and must miss ``TOL`` by far.
 """
 
+import dataclasses
 import os
 import sys
 
@@ -366,3 +367,49 @@ def test_the_attn_decode_kernel_compiles_and_no_layer_of_the_pool_is_copied(one_
     assert memory.temp_size_in_bytes < layer // 2
     a_layer = re.compile(rf"= bf16\[(1,)?{B},2048,({8 * HD}|8,{HD})\]\S* (copy|transpose|slice|dynamic-slice|fusion)\(")
     assert not [line for line in text.splitlines() if a_layer.search(line)]
+
+
+def test_the_grouped_expert_kernels_compile_and_no_experts_weights_are_copied(one_chip, monkeypatch):
+    """``ops.expert_gmm`` inside a walk of a three-layer stack at the
+    long-context cell's widths (D 2 048, F 1 408, 16 of 64 experts held, 6 a
+    token, a chunk of 2 048): Mosaic takes both kernels with an expert's whole
+    ``[2048, 1408]`` block resident (twice in flight, gate and up: 23 MB of the
+    chip's fast memory), their operands are the stacks AS THEY ARE HANDED IN
+    (``[3, 16, ...]``: nothing in the program has the shape of one layer's
+    experts, so no slice of the stack is copied for a custom call), and each
+    carries its scope. A compile, not a run."""
+    import re
+
+    from tpu_engine.ops import expert_gmm
+
+    generate = sys.modules["tpu_engine.generate"]
+    monkeypatch.setattr(expert_gmm, "on_tpu", lambda: True)  # the described chip: this process's devices are the CPU's
+    L, E, D, F, T = 3, 16, 2048, 1408, 2048
+    mc = dataclasses.replace(tfm.MODEL_CONFIGS["moe-tiny"], d_model=D, d_ff=F, n_experts=64, top_k=6,
+                             experts_held=E, router_scoring="sigmoid", routed_scale=2.446)
+    bf16 = jnp.bfloat16
+    sds = lambda shape, dt=bf16: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    stacks = {"router": {"kernel": sds((L, D, 64))}, "router_bias": sds((L, 64), jnp.float32),
+              "gate": {"kernel": sds((L, E, D, F))}, "up": {"kernel": sds((L, E, D, F))},
+              "down": {"kernel": sds((L, E, F, D))}}
+    assert generate.experts_grouped_engages(T, mc, stacks["gate"]["kernel"])
+
+    def walk(h, stacks):
+        def layer(x, at):
+            lp = jax.tree.map(lambda a: generate.layer_slice(a, at), stacks)
+            lp["experts_in_stack"] = (stacks, at)
+            with jax.named_scope("moe"):
+                y, _ = generate._moe_mlp_decode(x, lp, mc, jnp.ones(x.shape[:2], bool))
+            return x + y, None
+        return jax.lax.scan(layer, h, jnp.arange(L, dtype=jnp.int32))[0]
+
+    compiled = jax.jit(walk).lower(sds((1, T, D)), stacks).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    for line, name in zip(sorted(calls, key=lambda ln: "expert_down" in ln), ("expert_gate_up", "expert_down")):
+        assert f"moe/moe_experts/{name}" in line
+        assert f"bf16[{L},{E}," in line  # the whole stack is the operand
+    one_layer = re.compile(rf"= bf16\[(1,)?{E},({D},{F}|{F},{D})\]")
+    assert not [line for line in text.splitlines() if one_layer.search(line)]
+    assert f"bf16[{expert_gmm.n_tiles(T * 6, E) * expert_gmm.ROWS},{D}]" in text  # the buffer any routing fits

@@ -23,7 +23,9 @@ top-k routing instead (every token reaches its chosen experts): the router
 scores ALL ``n_experts``, and the experts this tree holds (all of them, or
 the share a hybrid's configuration names) are contracted with each token's
 gate folded into its activations, a token that did not choose an expert
-entering it with gate 0 (:func:`_moe_mlp_decode`). Dense models produce
+entering it with gate 0 — or, for a long chunk, only the routed pairs are
+computed, grouped by expert (:func:`_moe_mlp_decode`,
+:func:`experts_grouped_engages`). Dense models produce
 bit-identical logits between :func:`forward` and prefill+decode; MoE models
 can differ wherever training-time dispatch dropped a token.
 
@@ -66,7 +68,7 @@ from tpu_engine.models.transformer import (
     served_format,
     unembed,
 )
-from tpu_engine.ops import lane_decode, mla_decode, sparse_block_attention, ssd_update
+from tpu_engine.ops import expert_gmm, lane_decode, mla_decode, sparse_block_attention, ssd_update
 from tpu_engine.quant import QuantWeight, dequantize_weight
 
 _NEG_INF = -1e30
@@ -93,13 +95,18 @@ class KVCache:
 
     ``moe_counts`` (a mixture's caches only; ``None`` else, and a leaf less):
     what :func:`scan_layers` has counted of the router's choices since the
-    cache was made, :data:`MOE_COUNTS` int32."""
+    cache was made, :data:`MOE_COUNTS` int32.
+
+    ``sharded``: the cache and the stacks it is walked with lie over a mesh
+    (set by whoever places them there; a trace cannot see it otherwise), so a
+    walk hands no kernel a stack that is not one device's to read."""
 
     layers: dict
     pos: jax.Array
     length: jax.Array
     ring: bool = field(default=False, metadata=dict(static=True))
     moe_counts: Optional[jax.Array] = None
+    sharded: bool = field(default=False, metadata=dict(static=True))
 
     @property
     def max_len(self) -> int:
@@ -126,7 +133,7 @@ def ring_lanes(cfg: ModelConfig, max_len: int,
 
 def init_cache(
     cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16,
-    max_chunk: Optional[int] = None, kv_quant: bool = False,
+    max_chunk: Optional[int] = None, kv_quant: bool = False, sharded: bool = False,
 ) -> KVCache:
     """Allocate a cache able to hold ``max_len`` positions — or, for a
     sliding-window model, a ring buffer of ``window + max_chunk - 1`` slots
@@ -137,7 +144,8 @@ def init_cache(
 
     ``kv_quant=True`` stores k/v as int8 with per-(slot, kv-head) scales —
     half the cache HBM of bf16, at ~1% quantisation error (symmetric
-    absmax over head_dim)."""
+    absmax over head_dim). ``sharded``: the caller will walk it with stacks
+    that lie over a mesh (``KVCache.sharded``)."""
     check_hybrid(cfg)
     if kv_quant:
         refuse_recurrent(cfg, "an int8 KV cache (kv_quant)")
@@ -148,14 +156,27 @@ def init_cache(
         length=jnp.zeros((), jnp.int32),
         ring=slots < max_len,
         moe_counts=init_moe_counts(cfg),
+        sharded=sharded,
     )
 
 
 # What a walk counts of a mixture's routing, summed over its layers (and a
 # caller's steps): token-expert assignments made at real positions, those of
-# them that fell on experts this tree holds, and held experts that some real
-# token chose (distinct per layer).
-MOE_COUNTS = ("assignments", "assignments_held", "experts_hit")
+# them that fell on experts this tree holds, held experts that some real
+# token chose (distinct per layer), and the expert-rows the layer's
+# contractions ran for them (the masked form: real positions x held experts;
+# the grouped one: the rows of the tiles it visited, a group's padding among
+# them). ``rows_computed / assignments_held`` is 1 where nothing but routed
+# pairs is computed.
+MOE_COUNTS = ("assignments", "assignments_held", "experts_hit", "rows_computed")
+
+# Rows (B x T) from which a walk's held experts run grouped: under the chip's
+# ≈ 240 FLOP a byte the masked contraction is bound by the experts' READ, which
+# no grouping saves (decode: 16-32 rows); at 256 rows the grouped form is even
+# (Mixtral's widths) or loses (granite-small's, by 0.45 ms a layer), at 512 it
+# reads +25 %, +5 % and -11 % at the three mixture cells' widths, at 2 048 it
+# wins at all three (2.0x, 2.0x, 1.2x): PERF.md §6 PR 45, the probe's table.
+_GROUPED_FROM_ROWS = 1024
 
 
 def init_moe_counts(cfg: ModelConfig) -> Optional[jax.Array]:
@@ -184,27 +205,80 @@ def _route(h, layer_params, cfg: ModelConfig):
     return top_idx, top_vals / jnp.maximum(jnp.sum(top_vals, -1, keepdims=True), 1e-9)
 
 
+def experts_grouped_engages(rows: int, cfg: ModelConfig, gate, sharded: bool = False) -> bool:
+    """Whether a walk of ``rows`` (B x T) positions runs its held experts
+    GROUPED over the routed pairs (:func:`_experts_grouped`) and not masked
+    over every held expert (decided from what the trace sees; no option):
+
+    - enough rows that the masked contraction's FLOPs lead the experts' read,
+      which no grouping saves (:data:`_GROUPED_FROM_ROWS`: a decode step and a
+      256-token chunk decline);
+    - a routing sparse enough that the tiles the pairs are EXPECTED to fill,
+      every group's padding with them, are at most half the masked form's rows;
+    - ``gate``, the kind's stacked leaf ``[L, E, D, F]``, is what the kernels
+      read (``expert_gmm.engages``: plain bfloat16 or float32 in whole tile
+      columns, on a TPU; an int8 ``QuantWeight`` declines);
+    - the stacks are one device's (``sharded``: a replica placed over a mesh
+      declines; the kernels carry no partitioning rule)."""
+    held = cfg.n_experts_held
+    expected = rows * cfg.top_k * held // cfg.n_experts + held * expert_gmm.ROWS
+    return (not sharded and rows >= _GROUPED_FROM_ROWS and 2 * expected <= rows * held
+            and expert_gmm.engages(gate))
+
+
+def _experts_grouped(h, stacks, at, top_idx, top_vals, valid, cfg: ModelConfig):
+    """The held experts over the routed pairs this tree holds, and over
+    nothing else: ([B, T, D], expert-rows computed). Exact for any routing.
+
+    The pairs (token, choice) whose expert lies in ``[experts_first,
+    experts_first + n_experts_held)`` at a ``valid`` position are laid out by
+    expert in row tiles (``expert_gmm.pair_layout``), their rows of ``h``
+    gathered, gate / up / down run per tile against the tile's expert in the
+    kind's ``stacks`` where they lie (layer ``at``; ``expert_gmm.experts``),
+    and every token gathers its ``top_k`` slots back, each pair's gate folded
+    in float32 (a pair that is not ours adds zero)."""
+    B, T, D = h.shape
+    K, first, held = cfg.top_k, cfg.experts_first, cfg.n_experts_held
+    local = top_idx - first
+    ours = (local >= 0) & (local < held) & valid[..., None]                                   # [B, T, K]
+    layout = expert_gmm.pair_layout(jnp.where(ours, local, held).reshape(-1).astype(jnp.int32), held,
+                                    expert_gmm.n_tiles(B * T * min(K, held), held))
+    xs = expert_gmm.take(h.reshape(B * T, D), layout.src // K)
+    ys = expert_gmm.experts(xs, *(stacks[name]["kernel"] for name in ("gate", "up", "down")), at, layout)
+    # the way back CHOICE-major, [K, B x T, D], its K slabs added one to the next: ONE elementwise pass over the
+    # gathered slots (token-major, a token's K = 6 rows are no whole tile of the chip's, and as a reduction the
+    # slots are first written out again in float32: 0.26 + 0.29 s of the 1.84 s under the scope, PERF.md §6 PR 45)
+    mine = ours.reshape(B * T, K).T                                                           # [K, B x T]
+    back = expert_gmm.take(ys, jnp.where(mine, layout.pos.reshape(B * T, K).T, 0))            # [K, B x T, D]
+    gates = top_vals.reshape(B * T, K).T
+    out = sum(jnp.where(mine[k, :, None], gates[k, :, None] * back[k].astype(jnp.float32), 0.0) for k in range(K))
+    return out.reshape(B, T, D).astype(h.dtype), layout.tiles_used[0] * expert_gmm.ROWS
+
+
 def _moe_mlp_decode(h, layer_params, cfg: ModelConfig, valid):
     """Exact top-k mixture for the cached walks: every token reaches those of
     its chosen experts that this tree holds (no capacity buffer — see module
     docstring). h: [B, T, D] → ([B, T, D], :data:`MOE_COUNTS` of this layer).
 
     The router (:func:`_route`) scores all ``n_experts`` in float32 and keeps
-    ``top_k`` of them. The held experts (``experts_first`` on,
-    ``n_experts_held`` of them) run for the T new positions, a token's gate (0
-    for an expert it did not choose) folded into its activations, and one
-    contraction over expert and width together brings them down: nothing of
-    ``[B, T, E, D]`` is written. What an absent expert would have added is
-    left out; a token none of whose experts is held gets the shared expert
-    alone. The shared expert (``shared_d_ff``) is a SwiGLU of its own width for
-    every token. ``valid`` [B, T] marks the real positions, which alone are
-    counted.
+    ``top_k`` of them. What an absent expert would have added is left out; a
+    token none of whose experts is held gets the shared expert alone. The
+    shared expert (``shared_d_ff``) is a SwiGLU of its own width for every
+    token. ``valid`` [B, T] marks the real positions, which alone are counted.
 
-    Every held expert is computed for every token (MASKED). A grouped form for
-    long chunks (pairs sorted by expert, ``lax.ragged_dot``) was measured on
-    the chip at 2 048 tokens, 6 of 64 experts a token, 16 held, and lost to
-    this one (ROADMAP M1 (b), PERF.md §6 PR 40: the grouped kernel computes
-    every row it is handed, the absent experts' pairs among them).
+    The held experts (``experts_first`` on, ``n_experts_held`` of them) run in
+    one of two forms that give the same numbers at every real position:
+
+    - MASKED: every held expert is computed for the T new positions, a token's
+      gate (0 for an expert it did not choose) folded into its activations, and
+      one contraction over expert and width together brings them down: nothing
+      of ``[B, T, E, D]`` is written. Bound by the experts' read while the rows
+      are few: every decode step and short chunk.
+    - GROUPED (:func:`_experts_grouped`), where the walk hands the kind's whole
+      stacks and the layer's index (``layer_params["experts_in_stack"]``:
+      :func:`scan_layers` does where :func:`experts_grouped_engages`): only the
+      routed pairs this tree holds are computed, a long chunk's 1.5 a token of
+      longctx32's 16 and not all 16 (PERF.md §6 PR 45).
     """
     K, first, held = cfg.top_k, cfg.experts_first, cfg.n_experts_held
     with jax.named_scope("moe_router"):
@@ -212,8 +286,9 @@ def _moe_mlp_decode(h, layer_params, cfg: ModelConfig, valid):
         chosen = top_idx[..., None] == first + jnp.arange(held)  # [B, T, K, held]
         weights = jnp.sum(jnp.where(chosen, top_vals[..., None], 0.0), axis=2)  # [B, T, held]
         live = jnp.any(chosen, axis=2) & valid[..., None]
-        counts = jnp.stack([K * jnp.sum(valid), jnp.sum(live),
-                            jnp.sum(jnp.any(live, axis=(0, 1)))]).astype(jnp.int32)
+        real = jnp.sum(valid)
+        counts = jnp.stack([K * real, jnp.sum(live), jnp.sum(jnp.any(live, axis=(0, 1))),
+                            held * real]).astype(jnp.int32)  # the last: the MASKED form's rows
 
     def kern(name):
         # Expert kernels may be int8 QuantWeights (weight-only quantized
@@ -228,10 +303,14 @@ def _moe_mlp_decode(h, layer_params, cfg: ModelConfig, valid):
         return w
 
     with jax.named_scope("moe_experts"):
-        gate = jnp.einsum("btd,edf->btef", h, kern("gate"))
-        up = jnp.einsum("btd,edf->btef", h, kern("up"))
-        act = jax.nn.silu(gate) * up * weights[..., None].astype(h.dtype)
-        out = jnp.einsum("btef,efd->btd", act, kern("down"))
+        if "experts_in_stack" in layer_params:
+            out, rows = _experts_grouped(h, *layer_params["experts_in_stack"], top_idx, top_vals, valid, cfg)
+            counts = counts.at[-1].set(rows)  # ``rows_computed``: the visited tiles' rows
+        else:
+            gate = jnp.einsum("btd,edf->btef", h, kern("gate"))
+            up = jnp.einsum("btd,edf->btef", h, kern("up"))
+            act = jax.nn.silu(gate) * up * weights[..., None].astype(h.dtype)
+            out = jnp.einsum("btef,efd->btd", act, kern("down"))
     if cfg.shared_d_ff:
         with jax.named_scope("moe_shared"):
             shared = jax.nn.silu(_proj(h, layer_params["shared_gate"]["kernel"])) \
@@ -1382,6 +1461,9 @@ def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
             for kind, first in zip(kinds, firsts):
                 at = first + i
                 lp = jax.tree.map(lambda a: layer_slice(a, at), stacks[kind])
+                if cfg.is_moe and "gate" in stacks[kind] and experts_grouped_engages(
+                        x.shape[0] * x.shape[1], cfg, stacks[kind]["gate"]["kernel"], cache.sharded):
+                    lp["experts_in_stack"] = (stacks[kind], at)  # run grouped, read where they lie
                 tally = None if counts is None else []
                 x, leaves, mem = layer_fns[kind](x, lp, at, state, mem, tally, kind, view, **tail)
                 if tally:
@@ -1569,6 +1651,14 @@ def sample_token(
     return _filtered_sample(logits, rng, temperature, top_k, top_p)
 
 
+def _over_a_mesh(*trees) -> bool:
+    """Whether some leaf of these (concrete) trees lies over more than one
+    device: what a host entry tells its caches (``KVCache.sharded``), since
+    the traces inside cannot see where a stack lies."""
+    return any(len(a.sharding.device_set) > 1
+               for a in jax.tree.leaves(trees) if isinstance(a, jax.Array) and hasattr(a, "sharding"))
+
+
 def generate(
     params: dict[str, Any],
     prompt: jax.Array,
@@ -1610,6 +1700,7 @@ def generate(
         greedy=greedy,
         compute_dtype=compute_dtype,
         kv_quant=kv_quant,
+        sharded=_over_a_mesh(params),
     )
 
 
@@ -1617,7 +1708,7 @@ def generate(
     jax.jit,
     static_argnames=(
         "cfg", "max_new_tokens", "top_k", "use_top_p", "greedy", "compute_dtype",
-        "kv_quant",
+        "kv_quant", "sharded",
     ),
 )
 def _generate_jit(
@@ -1634,6 +1725,7 @@ def _generate_jit(
     greedy: bool,
     compute_dtype,
     kv_quant: bool = False,
+    sharded: bool = False,
 ) -> jax.Array:
     B, P = prompt.shape
     # A training job's float32 parameters: converted once, outside the token
@@ -1650,7 +1742,7 @@ def _generate_jit(
 
     keys = jax.random.split(rng, max_new_tokens)  # one fresh key per draw
     cache = init_cache(cfg, B, P + max_new_tokens, dtype=compute_dtype,
-                       max_chunk=P, kv_quant=kv_quant)
+                       max_chunk=P, kv_quant=kv_quant, sharded=sharded)
     logits, cache = forward_with_cache(params, prompt, cache, cfg, compute_dtype)
     first = sample(logits[:, -1, :], keys[0])
 
@@ -1715,19 +1807,19 @@ def speculative_generate(
     out, rounds = _speculative_jit(
         params, draft_params, prompt,
         cfg=cfg, draft_cfg=draft_cfg, max_new_tokens=max_new_tokens,
-        gamma=gamma, compute_dtype=compute_dtype,
+        gamma=gamma, compute_dtype=compute_dtype, sharded=_over_a_mesh(params, draft_params),
     )
     return (out, int(rounds)) if return_stats else out
 
 
 @partial(
     jax.jit,
-    static_argnames=("cfg", "draft_cfg", "max_new_tokens", "gamma", "compute_dtype"),
+    static_argnames=("cfg", "draft_cfg", "max_new_tokens", "gamma", "compute_dtype", "sharded"),
 )
 def _speculative_jit(
     params, draft_params, prompt, *,
     cfg: ModelConfig, draft_cfg: ModelConfig,
-    max_new_tokens: int, gamma: int, compute_dtype,
+    max_new_tokens: int, gamma: int, compute_dtype, sharded: bool = False,
 ) -> jax.Array:
     P = prompt.shape[1]
     total = P + max_new_tokens
@@ -1736,9 +1828,9 @@ def _speculative_jit(
     draft_params = served_format(draft_params, compute_dtype)
 
     cache = init_cache(cfg, 1, buf_len, dtype=compute_dtype,
-                       max_chunk=max(P - 1, gamma + 1))
+                       max_chunk=max(P - 1, gamma + 1), sharded=sharded)
     dcache = init_cache(draft_cfg, 1, buf_len, dtype=compute_dtype,
-                        max_chunk=max(P - 1, 1))
+                        max_chunk=max(P - 1, 1), sharded=sharded)
 
     out = jnp.zeros((1, buf_len), jnp.int32)
     out = lax.dynamic_update_slice(out, prompt.astype(jnp.int32), (0, 0))

@@ -97,6 +97,10 @@ class ModelConfig:
     #   capacity, no drops, dispatch/combine become gathers/scatters.
     #   Single-shard experts only (ragged_dot is not GSPMD-partitionable
     #   over the expert dim; validated at build).
+    # Training's alone: the cached walks that SERVE a mixture
+    # (generate._moe_mlp_decode) read neither value; they run the held
+    # experts masked or grouped by what the trace sees
+    # (generate.experts_grouped_engages).
     moe_impl: str = "dense"
     # Which of the ``n_experts`` THIS tree holds, for a chip that is one of
     # several sharing each layer's experts: ``experts_held`` of them from

@@ -65,6 +65,7 @@ from tpu_engine import layer_state
 from tpu_engine.generate import (
     MOE_COUNTS,
     KVCache,
+    experts_grouped_engages,
     forward_with_cache,
     init_cache,
     init_moe_counts,
@@ -974,6 +975,14 @@ class ContinuousBatcher:
         self._moe_counts = {
             program: dict.fromkeys(("layer_steps",) + MOE_COUNTS, 0)
             for program in (("decode", "prefill") if cfg.is_moe else ())}
+        # Prefill chunks whose program runs its held experts grouped over the
+        # routed pairs (``generate.experts_grouped_engages``: a property of
+        # the chunk's shape and of the experts' stacked leaf, known here
+        # without asking the device).
+        stacks = self.params["layers"]
+        self._expert_gate = next((s["gate"]["kernel"] for s in (stacks.values() if cfg.is_hybrid else [stacks])
+                                  if "gate" in s), None) if cfg.is_moe else None  # (a dense MLP has a gate too)
+        self._moe_grouped_chunks = 0
         self._shared_expert_bytes = sum(
             a.size * a.dtype.itemsize
             for path, a in jax.tree_util.tree_leaves_with_path(self.params)
@@ -1372,6 +1381,7 @@ class ContinuousBatcher:
                 for program, counts in self._moe_counts.items():
                     for name, n in counts.items():
                         out[f"moe_{program}_{name}_total"] = n
+                out["moe_prefill_grouped_chunks_total"] = self._moe_grouped_chunks
             if self._prefix_cache is not None:
                 out["prefix_cache"] = self._prefix_cache.stats()
             if self._draft_params is not None:
@@ -1403,6 +1413,7 @@ class ContinuousBatcher:
         """A one-row cache placed as the pool shards (mesh-sharded serving)."""
         if self.mesh is None:
             return c1
+        c1 = dataclasses.replace(c1, sharded=True)  # its walks hand no kernel a sharded stack
         return jax.device_put(
             c1, layer_state.cache_shardings(self.mesh, self.cfg, c1))
 
@@ -1492,6 +1503,7 @@ class ContinuousBatcher:
         st.consumed = t1
         st.chunks += 1
         self._prefill_tokens_computed += t1 - t0
+        self._moe_grouped_chunks += self._experts_grouped(t1 - t0)
         if self._sparse_from is not None:
             self._prefill_tokens_sparse += max(t1 - max(t0, self._sparse_from), 0)
         if self._prefix_cache is not None:
@@ -1593,10 +1605,14 @@ class ContinuousBatcher:
             if st.req.status != "running":
                 self._prefilling.pop(slot)  # cancelled/failed meanwhile
             else:
+                # ``expert_rows``: a mixture's ``moe_prefill_rows_computed_total``
+                # as the phase opens (a prompt's counts come with its last chunk).
+                moe = {"expert_rows": self._moe_counts["prefill"]["rows_computed"]} \
+                    if self.cfg.is_moe else {}
                 with prof.phase("prefill", rid=st.req.id, slot=slot,
                                 chunk=st.consumed // self.prefill_chunk,
                                 tokens=min(self.prefill_chunk,
-                                           st.padded - st.consumed)):
+                                           st.padded - st.consumed), **moe):
                     with_prefill = 1
                     if st.req.prefill_started_at is None:
                         st.req.prefill_started_at = time.time()
@@ -1775,6 +1791,12 @@ class ContinuousBatcher:
             return 0
         seen = np.minimum(np.asarray(contexts)[:, None] + np.arange(self.chunk_steps), self._pool_lanes)
         return self._lane_walk_layers * int((-(-seen // lane_decode.LANES) * lane_decode.LANES).sum())
+
+    def _experts_grouped(self, rows: int) -> bool:
+        """Whether a walk of ``rows`` positions runs this engine's held experts
+        grouped (False without experts)."""
+        return self._expert_gate is not None and experts_grouped_engages(
+            rows, self.cfg, self._expert_gate, sharded=self.mesh is not None)
 
     def _note_moe(self, program: str, counts, steps: int) -> None:
         """Add a dispatch's (decode) or a prompt's (prefill) mixture counts:
