@@ -17,7 +17,7 @@ and reports, per variant:
 - per-device memory (overlap's cost: in-flight buffers live longer).
 
 Run: ``python benchmarks/comm_overlap.py [--model llama-7b --topo v5e:4x4]``
-Prints one JSON line per variant; paste the summary into RESULTS.md.
+Prints one JSON line per variant.
 
 Wall-clock A/B needs a real multi-chip slice (the flags are TPU-only — the
 CPU dry-run mesh neither accepts ``xla_tpu_*`` options nor shares the TPU
@@ -136,7 +136,7 @@ def main() -> None:
     ap.add_argument("--seq", type=int, default=4096)
     args = ap.parse_args()
 
-    from benchmarks.aot import aot_lowered
+    from tpu_engine.aot import aot_lowered
 
     lowered = aot_lowered(
         args.model, args.topo, dict(data=args.data, fsdp=args.fsdp),

@@ -37,8 +37,9 @@ a one-slot scheduler: watcher fires → synchronous Orbax save → requeue →
 HIGH runs → LOW re-admitted and resumes from exactly the saved step.
 Asserts ``resumed_from_step == step at preemption`` — zero lost steps.
 
-Prints one JSON document; ``bench.py`` reuses :func:`run_trace` for its
-scheduler metric line.
+Prints one JSON document. The mock fleet's jobs are fakes with timed
+"work": what the trace shows is that the queue packs and loses nothing, not
+how fast anything runs.
 """
 
 from __future__ import annotations

@@ -29,10 +29,11 @@ request's prompt opens with one of a few shared system prefixes;
 replica-side prefix caches skip the prefill for resident prefixes, which
 is what router affinity is for.
 
-Reports aggregate tokens/sec (and per chip-second, so extra replicas
-don't get their throughput for free), p50/p99 latency vs the SLO, the
-replica-count trace, router weights and affinity hit rate;
-``bench.py`` reuses :func:`run_trace` for its serving-fleet line.
+Reports the MODEL's aggregate tokens/sec (and per chip-second, so extra
+replicas don't get their throughput for free), p50/p99 latency vs the SLO,
+the replica-count trace, router weights and affinity hit rate, all at the
+assumed rates of ``twin.REPLICA_*``: gates on the router's and the
+autoscaler's logic, not speeds of the chip.
 
 A second experiment (PR 12) A/Bs **symmetric vs disaggregated** serving
 at EQUAL total chips on a long-prefill-heavy bursty trace. The symmetric
@@ -66,7 +67,6 @@ from tpu_engine.serving_fleet import (  # noqa: E402
 )
 from tpu_engine.twin import (  # noqa: E402
     ServingTwinParams,
-    SlotReplica,
     bursty_arrivals,
     replay_serving_fleet,
     run_open_loop,
@@ -110,10 +110,6 @@ AUTOSCALER = AutoscalerConfig(
     scale_up_cooldown_s=3.0,
     scale_down_cooldown_s=90.0,
 )
-
-# Back-compat alias: the capacity replica model now lives in the twin.
-SimReplica = SlotReplica
-
 
 def request_trace(seed: int) -> list[dict]:
     """Seeded bursty open-loop arrivals: [{t, prefix_id, prompt, n_new}]."""

@@ -77,12 +77,24 @@ def test_script_takes_no_arguments_and_starts_no_children():
 
 def test_bench_parents_that_start_children_stay_off_jax():
     """One process per chip: a parent that has touched JAX holds the chip
-    and its children then fail or hang. The two benchmark scripts that
-    start children must import neither jax nor tpu_engine (which imports
-    jax) in the parent."""
+    and its children then fail or hang. A script under ``benchmarks/`` (or
+    ``bench.py``) that starts children must import neither jax nor
+    tpu_engine (which imports jax) in the parent. None of those that are
+    left starts any; one that comes to is held to it here."""
+    import glob
+
+    scripts = [os.path.join(REPO, "bench.py")] + sorted(
+        glob.glob(os.path.join(REPO, "benchmarks", "*.py")))
+    assert scripts[1:], "benchmarks/*.py not found"
+    parents = []
+    for path in scripts:
+        with open(path) as f:
+            src = f.read()
+        if "subprocess" in src or "multiprocessing" in src:
+            parents.append(os.path.splitext(os.path.basename(path))[0])
     code = (
-        "import sys; sys.path.insert(0, 'benchmarks'); "
-        "import mfu_sweep, warm_restart; "
+        "import sys; sys.path[:0] = ['.', 'benchmarks']; "
+        f"[__import__(m) for m in {parents!r}]; "
         "bad = [m for m in ('jax', 'jaxlib', 'tpu_engine') if m in sys.modules]; "
         "sys.exit(f'parent imported {bad}' if bad else 0)"
     )

@@ -485,3 +485,18 @@ def test_active_singleton():
         assert get_active() is None
     finally:
         set_active(prev)
+
+
+def test_hetero_lane_rebalance_beats_uniform_and_shrink():
+    """The slow-host lane (virtual clock, one of 8 hosts 25% slower by the
+    seeded plan): rebalanced rows keep the gang near the heterogeneous
+    ideal, a uniform gang is gated by the slow host, and shrinking throws
+    away the host's remaining three quarters."""
+    from benchmarks.chaos import HET_GLOBAL_MICRO, run_hetero_lane
+
+    het = run_hetero_lane(seed=0)
+    assert het["steady_goodput_on"] >= 0.90
+    assert het["steady_goodput_off"] <= 0.80
+    assert het["steady_goodput_on"] > het["steady_goodput_shrink"]
+    assert sum(het["rebalance_on"]["assignment"]) == HET_GLOBAL_MICRO
+    assert het == run_hetero_lane(seed=0)  # seeded: the same on a repeat

@@ -361,7 +361,7 @@ def test_incident_metric_snippets_come_from_the_historian():
 
 
 # ---------------------------------------------------------------------------
-# Chaos replay fidelity gate (the twin lane the bench sentinel pins)
+# Chaos replay fidelity gate (the twin lane)
 # ---------------------------------------------------------------------------
 
 

@@ -6,8 +6,8 @@ appends), ``FleetScheduler.restore`` (deterministic rebuild, orphan
 re-adoption, vanished-training requeue, the HBM double-grant audit),
 ``ServingFleet.re_adopt`` (roster + held-request recovery) and the
 component export/load hooks behind ``journal.collect_sections``. The
-full kill-mid-storm A/B with exit gates lives in
-``benchmarks/ctl_crash_sim.py`` (``twin.ctl_crash_lane``).
+full kill-mid-storm A/B with exit gates is ``twin.ctl_crash_ab``
+(``test_ctl_crash_ab_gates`` below).
 """
 
 import json
@@ -306,3 +306,27 @@ def test_collect_sections_and_active_journal(tmp_path):
     assert js["attached"] is True and js["appends_total"] == 1
     journal_mod.note_mttr(3.5)
     assert journal_mod.recovery_stats()["last_mttr_seconds"] == 3.5
+
+
+def test_ctl_crash_ab_gates():
+    """The kill-mid-storm lane: the seeded storm through the real scheduler
+    and fleet, journaled, the control plane dropped mid-storm (torn journal
+    line included) and restored, against the same storm with no crash."""
+    from tpu_engine.twin import ctl_crash_ab
+
+    res = ctl_crash_ab(seed=0)
+    assert res["ok"], res["gates"]
+    assert set(res["gates"]) == {
+        "zero_lost_submissions", "zero_duplicated_submissions",
+        "held_requests_complete", "orphans_readopted",
+        "vanished_training_requeued", "vanished_replica_redispatched",
+        "no_phantom_double_grants", "double_recovery_identical",
+        "torn_tail_skipped_not_raised", "mttr_within_budget",
+    }
+    crashed = res["crashed"]
+    assert crashed["recovery"]["readopted"] > 0
+    assert crashed["recovery"]["requeued_vanished"] > 0
+    assert crashed["recovery"]["double_grants"] == 0
+    assert crashed["re_adopt"]["replicas_redispatched"] > 0
+    assert crashed["journal"]["appends_total"] > 0
+    assert crashed["mttr_s"] <= res["mttr_budget_s"]

@@ -156,7 +156,7 @@ def _cfg(**kw) -> TPUTrainConfig:
         precision=Precision.FP32,
         param_dtype=Precision.FP32,
         # Sub-chaotic lr: parity measures per-step quantization error,
-        # not trajectory divergence (see benchmarks/quant_train.py).
+        # not trajectory divergence.
         learning_rate=1e-3,
         warmup_steps=2,
         total_steps=100,

@@ -592,3 +592,26 @@ def test_reshard_roundtrip_report_gates():
     assert rep["ok"], rep
     assert len(rep["targets"]) == 2
     assert all(t["byte_parity_vs_source"] for t in rep["targets"])
+
+
+def test_reshard_ab_gates():
+    """The whole reshard lane: topology-changing resume against the warm
+    same-topology recovery and the topology-locked restart on the seeded
+    chip-fault trace, the checkpoint round trip, and held serving requests
+    migrating to the destination pool."""
+    from tpu_engine.twin import reshard_ab
+
+    res = reshard_ab(seed=0)
+    assert res["ok"], res["gates"]
+    assert set(res["gates"]) == {
+        "zero_lost_steps", "mttr_within_budget", "beats_topology_locked",
+        "roundtrip_byte_parity", "held_requests_complete",
+        "int8_parity_within_bound", "prefix_migrates_both_paths",
+        "deterministic_repeat",
+    }
+    assert res["reshard"]["lost_steps"] == 0
+    assert res["topology_locked"]["lost_steps"] > 0
+    assert res["reshard"]["mttr_mean_s"] <= res["mttr_budget_s"]
+    mig = res["migration"]
+    assert mig["migrated"] > 0 and mig["completed"] == mig["migrated"]
+    assert mig["parity_mismatches"] <= mig["migrated"]

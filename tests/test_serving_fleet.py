@@ -502,21 +502,21 @@ def test_default_engine_factory_builds_real_batcher(sched_factory):
     assert jnp.asarray(out["tokens"]).dtype.kind == "i"
 
 
-def test_bench_emits_serving_fleet_line():
-    from bench import _serving_fleet_metric
+def test_autoscaled_lane_beats_static_replica():
+    """The autoscaled-fleet lane on the seeded bursty trace (real router +
+    autoscaler over the twin's capacity model at its assumed rates)."""
+    from benchmarks.serving_fleet_sim import run_trace
 
-    line = _serving_fleet_metric()
-    assert line is not None
-    assert line["metric"] == "serving_fleet_throughput_vs_static_1"
-    # The acceptance bar: ≥2x aggregate tokens/sec over the static single
-    # replica on the bursty trace, with steady-state p99 inside the SLO.
-    assert line["value"] >= 2.0
-    assert line["p99_within_slo"]
-    assert line["p99_ms"] <= line["p99_slo_ms"]
-    # Replica-count trace and per-replica routing weights ride the line.
-    assert line["replica_trace"][0][1] == 1
-    assert line["max_replicas_used"] > 1
+    trace = run_trace(seed=0)
+    auto = trace["autoscaled"]
+    # The acceptance bar: ≥2x the model's aggregate tokens/sec over the
+    # static single replica, with steady-state p99 inside the SLO.
+    assert trace["throughput_improvement"] >= 2.0
+    assert auto["p99_within_slo"]
+    assert auto["p99_ms"] <= trace["p99_slo_ms"]
+    assert auto["replica_trace"][0][1] == 1
+    assert auto["max_replicas_used"] > 1
     # Weights are the END-of-trace routing plane; scale-downs may have
     # shed replicas since the peak.
-    assert 1 <= len(line["router_weights"]) <= line["max_replicas_used"]
-    assert line["prefix_hit_rate"] > 0.5
+    assert 1 <= len(auto["router"]["weights"]) <= auto["max_replicas_used"]
+    assert auto["prefix_hit_rate"] > 0.5

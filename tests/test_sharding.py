@@ -79,7 +79,8 @@ def test_presets_cover_reference_scales():
     assert {"125m", "7b", "13b", "70b"} <= set(p)
     # Effective batch sizes match the reference's presets
     # (deepspeed_launcher.py:369-407: 128 / 256 / 1024); mesh shapes are
-    # re-tuned for v5e HBM and AOT-verified (benchmarks/RESULTS.md).
+    # re-tuned for v5e HBM and AOT-verified (benchmarks/RESULTS.md,
+    # "7B projection").
     assert p["7b"].effective_batch_size == 128
     assert p["13b"].effective_batch_size == 256
     assert p["70b"].effective_batch_size == 1024

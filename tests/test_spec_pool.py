@@ -384,8 +384,7 @@ def test_plan_serving_pool_draft_role():
 
 
 # ---------------------------------------------------------------------------
-# Twin lane: deterministic A/B machinery (full gates ride the slow tier
-# and benchmarks/spec_pool_sim.py)
+# Twin lane: deterministic A/B machinery (full gates ride the slow tier)
 # ---------------------------------------------------------------------------
 
 _FAST_LANE = dict(duration_s=90.0, warmup_s=30.0, spill_window_s=10.0,
@@ -411,13 +410,11 @@ def test_spec_pool_lane_deterministic_and_spills():
 
 @pytest.mark.slow
 def test_spec_pool_ab_gates():
-    from tpu_engine.twin import spec_pool_ab, spec_pool_bench_line
+    from tpu_engine.twin import spec_pool_ab
 
     res = spec_pool_ab(seed=0)
     assert res["ok"], res["gates"]
     assert res["tokens_per_sec_per_chip_ratio"] >= 1.2
-    line = spec_pool_bench_line(seed=0, ab=res)
-    assert line["metric"] == "spec_pool" and line["ok"]
 
 
 # ---------------------------------------------------------------------------

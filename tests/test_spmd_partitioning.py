@@ -79,7 +79,7 @@ def test_flash_multichip_no_full_remat_in_lowered_program(tiny3):
     *chosen* partitioned program never all-gathers a full stacked-weight
     tensor (the lowering GSPMD falls back to when a reshard really is
     infeasible — "replicate the tensor and then partition it")."""
-    from benchmarks.aot import build_program
+    from tpu_engine.aot import build_program
 
     prog = build_program(
         tiny3, dict(data=2, fsdp=2, model=2), micro=2, accum=2, seq=128,
@@ -119,7 +119,7 @@ def test_7b_flash_v5e16_aot_clean(capfd):
     compile target, and (b) no all-gather in the HLO materialises more than
     one layer's largest weight (i.e. collectives are per-layer ZeRO-3
     gathers + TP reductions, nothing activation- or stack-sized)."""
-    from benchmarks.aot import TopologyUnavailable, aot_lowered
+    from tpu_engine.aot import TopologyUnavailable, aot_lowered
 
     seq = 4096
     try:

@@ -27,7 +27,7 @@ from tpu_engine.twin import (
     read_recorder_jsonl,
     replay_fidelity,
     replay_self_heal,
-    twin_bench_line,
+    twin_replay_gates,
 )
 
 
@@ -357,18 +357,38 @@ def test_policy_scorecard_measures_real_deltas():
     assert again["deltas_vs_baseline"] == card["deltas_vs_baseline"]
 
 
-def test_twin_bench_line_gates_all_pass():
-    line = twin_bench_line(seed=0)
-    assert line["metric"] == "twin_replay_policy_ab"
-    assert line["gates"] == {
+def test_twin_replay_gates_all_pass():
+    assert twin_replay_gates(seed=0) == {
         "replay_within_1pct": True,
         "replay_fast_enough": True,
         "policy_delta_measured": True,
         "warm_beats_fifo": True,
     }
-    assert line["ok"] is True
-    assert line["ab_wait_warm_s"] < line["ab_wait_fifo_s"]
-    assert line["ingest_skipped_lines"] == 0
+
+
+# -- control-plane scale lane ---------------------------------------------------
+
+
+def test_scale_lane_small_is_deterministic_complete_and_bounded():
+    """The 1k-job / 10k-request configuration through the real scheduler,
+    router, historian and correlator: same counts on a repeat, every job
+    completes, every ring at or under its cap. (What the clock says of it
+    is not asserted here: see the slow test below.)"""
+    small = twin.ScaleLaneParams.small()
+    a = twin.scale_lane(seed=0, params=small)
+    b = twin.scale_lane(seed=0, params=small)
+    assert a["deterministic"] == b["deterministic"]
+    assert a["deterministic"]["jobs"]["completed"] == small.n_jobs
+    assert a["deterministic"]["serving"]["routed"] >= 0.98 * small.n_requests
+    assert a["rings_bounded"]
+
+
+@pytest.mark.slow
+def test_ctl_scale_profile_gates():
+    """100x the jobs and requests cost 100x the control work, not more
+    (about a minute; its flatness gate reads this host's CPU clock)."""
+    prof = twin.ctl_scale_profile(seed=0)
+    assert prof["ok"], (prof["gates"], prof["overhead_ratio"])
 
 
 # -- HTTP surface -------------------------------------------------------------

@@ -1,17 +1,13 @@
-"""Shared AOT-compile plumbing for benchmarks and tpu_aot tests.
+"""AOT-compile plumbing: the precompile worker's seam, and the tpu_aot tests'.
 
 One canonical way to build the sharded train program against a described
 TPU topology (libtpu compile-only — no chips needed) so the per-site
 boilerplate (topology → MeshRuntime → build_train_program → eval_shape →
-lower) doesn't drift across benchmarks/ and tests/.
+lower) doesn't drift across ``compile_index._default_precompile``,
+``benchmarks/comm_overlap.py`` and tests/.
 """
 
 from __future__ import annotations
-
-import os
-import sys
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from typing import Any, Optional
 

@@ -18,7 +18,7 @@ view of that cache:
   planner's ranking (equal-step-time layouts tie-break toward warm ones)
   and the scheduler's admission / grow-back decisions.
 - :class:`PrecompileWorker` warms a target layout in the background (AOT
-  lowering through the planner's existing seam, ``benchmarks/aot.py``) so
+  lowering through the planner's existing seam, ``tpu_engine/aot.py``) so
   grow-back preempts only once the destination mesh is warm — or a deadline
   lapses. Fault-injectable via the ``precompile-error`` kind in
   ``tpu_engine/faults.py``.
@@ -482,7 +482,7 @@ class PrecompileTask:
 
 def _default_precompile(task: PrecompileTask) -> None:
     """AOT-lower-and-compile through the planner's existing seam
-    (``benchmarks/aot.py``). Raises on CPU backends / unknown topologies —
+    (``tpu_engine/aot.py``). Raises on CPU backends / unknown topologies —
     the worker degrades that to a failed task, and the grow-back deadline
     then proceeds cold, exactly as if no precompiler existed."""
     cfg = task.config
@@ -495,7 +495,7 @@ def _default_precompile(task: PrecompileTask) -> None:
     # pin grow-backs against the deadline instead of degrading instantly.
     if jax.default_backend() == "cpu":
         raise PrecompileError("AOT precompile needs a TPU runtime (backend=cpu)")
-    from benchmarks.aot import aot_lowered
+    from tpu_engine.aot import aot_lowered
 
     gang = task.gang or 1
     m = cfg.mesh
@@ -516,7 +516,7 @@ class PrecompileWorker:
     """Bounded background thread that warms layouts ahead of a resize.
 
     ``compile_fn(task)`` does the actual work — the default drives AOT
-    lowering via ``benchmarks/aot.py``; tests and simulators inject a stub.
+    lowering via ``tpu_engine/aot.py``; tests and simulators inject a stub.
     Consults the process fault injector's ``precompile-error`` seam before
     every attempt, so chaos plans can break this path deterministically.
 

@@ -1,17 +1,15 @@
 """Per-device HBM footprint estimation for jobs — the admission-control math.
 
-Two measurement planes, promoted out of ``benchmarks/hbm_projection.py`` so
-the fleet scheduler (``tpu_engine/scheduler.py``) can project a *queued*
-job's footprint against live headroom before committing chips to it
-(placement-semantics stance: admission should reason about a job's concrete
-device/memory footprint, arXiv:2601.02311; the AOT compile plane in the
-benchmark remains the strongest evidence and stays there):
+Two measurement planes, so the fleet scheduler (``tpu_engine/scheduler.py``)
+can project a *queued* job's footprint against live headroom before
+committing chips to it (placement-semantics stance: admission should reason
+about a job's concrete device/memory footprint, arXiv:2601.02311; an AOT
+compile through ``tpu_engine/aot.py`` remains the strongest evidence):
 
 1. :func:`per_device_bytes` — **exact** state accounting from a built
    program's shapes + shardings (``shard_shape`` per leaf, device- vs
    host-resident split). Needs ``build_train_program`` → too expensive for
-   an admission decision on every queue pass, but the benchmark and any
-   offline validation use it.
+   an admission decision on every queue pass; offline validation uses it.
 
 2. :func:`estimate_job_hbm` — **analytic** projection straight from a
    :class:`~tpu_engine.sharding.TPUTrainConfig`: params / grads / optimizer
@@ -99,8 +97,7 @@ class SpecHBMOversubscribed(ValueError):
 
 
 # ---------------------------------------------------------------------------
-# Exact plane: state accounting from a built program (ex benchmarks/
-# hbm_projection.run_table — the benchmark now imports this).
+# Exact plane: state accounting from a built program.
 # ---------------------------------------------------------------------------
 
 

@@ -91,7 +91,8 @@ class ModelConfig:
     # - "dense": Switch/MTF-style capacity-factor dense dispatch — all
     #   routing work is einsum on the MXU, tokens over capacity DROP,
     #   [B,S,E,C] dispatch/combine tensors cost ~O(S²) FLOPs at long
-    #   seq (measured 33% tax at seq 2048; ragged WINS at seq 8192 — RESULTS.md). The only
+    #   seq (measured 33% tax at seq 2048; ragged WINS at seq 8192 —
+    #   benchmarks/RESULTS.md §MoE, pre-ledger). The only
     #   choice under expert parallelism (GSPMD partitions einsums).
     # - "ragged": sort-by-expert + lax.ragged_dot grouped matmuls — no
     #   capacity, no drops, dispatch/combine become gathers/scatters.
@@ -1518,7 +1519,7 @@ def _moe_mlp_ragged(h, layer_params, cfg: ModelConfig):
     The dense-dispatch formulation's [B, S, E, C] dispatch/combine
     einsums cost O(B·S²·cf·k/E·D) FLOPs — a 33% routing tax at seq 2048
     that grows with sequence; this ragged path wins +19% at seq 8192
-    (measured crossover, RESULTS.md §MoE). Tokens are SORTED by
+    (measured crossover, benchmarks/RESULTS.md §MoE, pre-ledger). Tokens are SORTED by
     their assigned expert and each expert's contiguous row-group hits one
     grouped matmul: the dispatch/combine become a gather and a
     segment-sum (memory ops, not FLOPs), and there is NO capacity — no

@@ -38,8 +38,8 @@ class FlashUnsupported(ValueError):
 
 
 # Backward tile cap for LONG sequences (see _flash_bwd); module-level so
-# the microbench can sweep it. Swept on chip (round 4, flash_microbench
-# --bwd-block): at seq >= 4096 the 1024 tile beats the old blanket 512
+# a probe can sweep it. Swept on a v5e in round 4 (pre-ledger, through a
+# runtime since replaced; no ledger line bears it): at seq >= 4096 the 1024 tile beats the old blanket 512
 # cap (fwd+bwd 4.87->4.64 ms @ seq4096, 14.57->14.44 @ 8192, 12.39->
 # 12.31 windowed — the 4-tile f32 working set is 16 MiB, inside v5e
 # VMEM), but at seq 2048 the bigger tile LOSES 8.7% (1.80->1.96 ms — a

@@ -11,7 +11,8 @@ microbatch count M. That is the schedule's classic value (Narayanan et al.,
 PipeDream-Flush / Megatron-LM): grow M to amortise the (P-1)/M bubble
 without activation blowup. Bubble TIME is the same as GPipe's — in the
 masked-SPMD formulation warmup/drain lanes still burn compute — so 1F1B
-here is the memory lever, measured as such (RESULTS.md).
+here is the memory lever, measured as such (benchmarks/RESULTS.md
+§Pipeline: AOT memory, no chip run).
 
 Implementation notes:
 

@@ -41,7 +41,7 @@ residency plus the bounded [P, P-1, B, S, D] stash. Raw tick count rises
 to M + 3(P-1) (the tail), but ticks are not equal-cost: the burned
 (masked-lane) compute drops from 8(P-1) to 6(P-1) F-units per stage. The
 analytic account (:func:`schedule_account`) is what the profiler's
-bubble-adjusted MFU and ``bench.py`` report.
+bubble-adjusted MFU reports.
 
 Masking invariants are inherited from 1F1B: bubble lanes carry zero
 activations/cotangents, and a zero cotangent through ``jax.vjp`` yields

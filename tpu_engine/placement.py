@@ -30,8 +30,8 @@ arXiv:2306.10209):
 The prediction is a *ranking* model: absolute seconds assume a nominal
 TPU roofline and are meaningless on the CPU test backend, but every term
 that differs between layouts (bubble fraction, gather/reduce bytes,
-per-shard batch) is modelled, so the order survives — validated by
-``benchmarks/placement_plan.py`` (measured CPU-mesh sweep + llama-7b AOT).
+per-shard batch) is modelled, so the order should survive. PR 7 checked it
+against a CPU-mesh sweep and llama-7b AOT compiles; no run on chips has.
 
 Wiring: ``FleetScheduler.submit(..., mesh="auto")`` admits the
 predicted-fastest feasible plan, ``TPULauncher`` dry runs and
@@ -288,8 +288,9 @@ class PlacementPlanner:
         self.ici_bytes_s = ici_bytes_s
         self.dcn_bytes_s = dcn_bytes_s
         # Quant / comm-compression variants are opt-in: both are measured
-        # wins only on real MXU / real DCN (benchmarks/RESULTS.md — int8
-        # matmul is 0.71x on CPU), so enumerating them by default would
+        # wins only on real MXU / real DCN (round 7, gpt-tiny on the CPU
+        # mesh: an int8 matmul ran at 0.71x a float one there; no ledger
+        # line bears either side), so enumerating them by default would
         # mispredict every CPU-backend ranking.
         self.consider_quant = consider_quant
         self.consider_comm_compress = consider_comm_compress
@@ -297,7 +298,8 @@ class PlacementPlanner:
         self.max_gang_enumeration = max_gang_enumeration
         # estimate_job_hbm is analytic: it cannot see XLA's scheduling
         # temporaries, so a plan near the top of free HBM still OOMs at
-        # compile. Measured on llama-7b via placement_plan.py --aot: flat
+        # compile. Measured on llama-7b (PR 7: the planner's top plans
+        # AOT-compiled for v5e:4x4, the compiler's memory analysis): flat
         # layouts land ~8% over the estimate (15.18 est -> 16.38 real),
         # pipelined ones 30-40% over (13.79 -> 17.82; 13.70 -> 18.99) —
         # the in-flight microbatch stash is the hardest term to project.

@@ -101,7 +101,7 @@ def pipeline_tick_account(
     the pipelined path (``n_stages <= 1``).
 
     Thin re-export of ``tpu_engine.parallel.pipeline_zb.schedule_account``
-    so profiler consumers (supervisor telemetry, bench.py) don't import the
+    so profiler consumers (supervisor telemetry) don't import the
     schedule module directly. ``busy_fraction`` is useful lane F-units over
     total lane F-units — see the schedule module for the cost model.
     """
@@ -164,8 +164,8 @@ class StepProfiler:
         # Analytic schedule account for pipelined runs (from
         # pipeline_tick_account): enables bubble-adjusted MFU — raw MFU
         # divided by the schedule's busy-lane fraction, i.e. utilisation of
-        # the lanes the schedule actually keeps busy. Without it RESULTS.md
-        # under-reports pipelined MFU: the bubble is a schedule property,
+        # the lanes the schedule actually keeps busy. Without it a report
+        # under-states pipelined MFU: the bubble is a schedule property,
         # not a kernel-efficiency loss.
         self.pipeline_account = pipeline_account
         self.loop = loop

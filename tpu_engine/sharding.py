@@ -353,7 +353,7 @@ class TPUTrainConfig(BaseModel):
     # setting (dense). "dense" = capacity-factor dense dispatch (expert-
     # parallel shardable); "ragged" = sort + lax.ragged_dot, no token
     # dropping, wins at long sequence (measured crossover in
-    # benchmarks/RESULTS.md §MoE; single-shard experts only).
+    # benchmarks/RESULTS.md §MoE, pre-ledger; single-shard experts only).
     moe_impl: Optional[Literal["dense", "ragged"]] = None
 
     # LoRA fine-tuning: when lora_rank is set, only rank-sized adapters on
@@ -818,7 +818,7 @@ def presets() -> dict[str, TPUTrainConfig]:
             model_name="moe-8x7b",
             sharding_stage=ShardingStage.FULL_PARTITIONING,
             # v5e-64 (8x8): 12.57 GiB/device AOT-verified (round 5,
-            # benchmarks/preset_fit_sweep.py). The earlier fsdp=4 32-chip
+            # benchmarks/RESULTS.md §MoE). The earlier fsdp=4 32-chip
             # shape compiled 4.7 GiB OVER budget — exactly the
             # never-validated-preset failure this repo criticises the
             # reference for, caught by the same sweep that sizes the

@@ -1,13 +1,17 @@
-"""Trace-replay digital twin: one virtual-clock fleet engine.
+"""Trace-replay digital twin: one virtual-clock fleet engine, and the lanes.
 
-Every fleet policy in this repo used to be evaluated on one of three
-bespoke virtual-clock harnesses (``benchmarks/scheduler_sim.py``,
-``benchmarks/serving_fleet_sim.py``, ``benchmarks/chaos.py``) that could
-not ingest what the flight recorder actually captured. This module is the
-shared engine those sims are now thin scenario definitions over, plus the
-piece none of them had: replaying a *recorded* run.
+The scenario scripts that stay under ``benchmarks/`` as test fixtures
+(``scheduler_sim.py``, ``serving_fleet_sim.py``, ``chaos.py``) are thin
+scenario definitions over this engine, which also replays a *recorded* run.
 
-Three layers:
+A lane drives the REAL control-plane components through their
+explicit-timestamp APIs under one :class:`VirtualClock` and returns its own
+result with ``gates`` and ``ok``: tier-1 tests assert those. A lane gates
+control-plane LOGIC. Its replicas and jobs are capacity models at assumed
+rates (``REPLICA_*`` below), so no number a lane returns is a speed of the
+chip; those are ``benchmarks/onchip/run.py``'s and ``PERF_LEDGER.jsonl``'s.
+
+Layers:
 
 - **Trace ingestion** (:func:`read_recorder_jsonl`,
   :class:`ReplayWorkload`): parse flight-recorder JSONL (spans, events,
@@ -21,26 +25,38 @@ Three layers:
   observed; the bursty generator reproduces the legacy sims' seeded
   traces draw-for-draw.
 
-- **Replay core** (:class:`TwinEngine` + the scenario lanes): drives the
-  real control-plane components through their existing
-  explicit-timestamp APIs under one :class:`VirtualClock` —
-  ``HeteroRebalancer``, ``ReplicaAutoscaler``/``FleetRouter``,
-  ``CompileCacheIndex``, ``GoodputLedger``/``SLOBurnRateAlerter`` — and
-  records the replayed run back onto a fresh :class:`FlightRecorder`
-  with deterministic span ids, so every twin run is itself
-  Perfetto-exportable and byte-for-byte diffable against the source
-  trace (or a previous replay).
+- **Replay core** (:class:`TwinEngine`): records the replayed run back
+  onto a fresh :class:`FlightRecorder` with deterministic span ids, so
+  every twin run is itself Perfetto-exportable and byte-for-byte diffable
+  against the source trace (or a previous replay).
+  :func:`replay_fidelity` and :func:`twin_replay_gates` hold it to the
+  recorded run.
 
 - **A/B scorecard** (:func:`ab_scorecard`,
-  :func:`default_policy_scorecard`): N policy variants over the same
-  ingested trace, one JSON artifact with per-variant goodput
-  decomposition, queue-wait, MTTR and SLO-burn deltas against the first
-  (baseline) variant.
+  :func:`default_policy_scorecard`, :func:`admission_policy_scorecard`):
+  N policy variants over the same ingested trace, one JSON artifact with
+  per-variant goodput decomposition, queue-wait, MTTR and SLO-burn deltas
+  against the first (baseline) variant.
+
+- **The lanes**, each with the component it gates:
+  training self-heal vs die-and-restart (:func:`replay_self_heal`,
+  :func:`replay_die_and_restart`, :func:`goodput_lane`: ``GoodputLedger``,
+  ``CompileCacheIndex``); slow-host rebalancing (:func:`replay_hetero`,
+  :func:`run_hetero_ab`: ``HeteroRebalancer``); the autoscaled serving
+  fleet (:func:`replay_serving_fleet`: ``FleetRouter``,
+  ``ReplicaAutoscaler``); warm admission (:func:`warm_admission_lane`);
+  :func:`historian_lane` (``MetricHistorian``, ``IncidentCorrelator``);
+  :func:`autopilot_lane` (``FleetAutopilot``); :func:`scale_lane` /
+  :func:`ctl_scale_profile` (control overhead as history grows 100x);
+  :func:`prefix_plane_lane` / :func:`prefix_plane_ab` (``PrefixPlane``,
+  ``HostKVTier``); :func:`reshard_ab` (``reshard``);
+  :func:`spec_pool_lane` / :func:`spec_pool_ab` (``SpecSpillController``);
+  :func:`ctl_crash_lane` / :func:`ctl_crash_ab` (``ControlPlaneJournal``).
+  The three serving lanes share ONE replica model, :class:`SlotReplica`.
 
 Health counters for the ``tpu_engine_twin_*`` Prometheus families live
 in module state (:func:`twin_stats`); ``POST /api/v1/twin/replay`` is
-the dry-run HTTP entry (``backend/routers/twin.py``); ``bench.py`` and
-``tools/bench_sentinel.py`` share :func:`twin_bench_line`.
+the dry-run HTTP entry (``backend/routers/twin.py``).
 """
 
 from __future__ import annotations
@@ -95,30 +111,24 @@ __all__ = [
     "default_policy_scorecard",
     "admission_policy_scorecard",
     "replay_fidelity",
-    "twin_bench_line",
+    "twin_replay_gates",
     "historian_lane",
-    "historian_bench_line",
     "replay_autopilot",
     "autopilot_lane",
-    "autopilot_bench_line",
     "ScaleLaneParams",
     "scale_lane",
     "ctl_scale_profile",
-    "ctl_scale_bench_line",
     "PrefixPlaneLaneParams",
     "prefix_plane_lane",
     "prefix_plane_ab",
-    "prefix_plane_bench_line",
     "ReshardLaneParams",
     "replay_reshard_resume",
     "reshard_roundtrip_report",
     "reshard_migration_report",
     "reshard_ab",
-    "reshard_bench_line",
     "CtlCrashLaneParams",
     "ctl_crash_lane",
     "ctl_crash_ab",
-    "ctl_crash_bench_line",
     "twin_stats",
 ]
 
@@ -651,7 +661,7 @@ def heavy_tail_prefill_arrivals(
 @dataclasses.dataclass(frozen=True)
 class TrainTwinParams:
     """The chaos training-gang scenario knobs (defaults = the seeded
-    benchmark the sentinel gates; ``benchmarks/chaos.py`` re-exports
+    benchmark; ``benchmarks/chaos.py`` re-exports
     them as module constants)."""
 
     n_chips: int = 8
@@ -1226,6 +1236,19 @@ def run_open_loop(
     return t
 
 
+# The replica model's rates, one place for the three serving lanes that
+# share them. ASSUMED, not measured: the lanes gate control-plane LOGIC
+# (routing, autoscaling, residency, spill) on a virtual clock, and a ratio
+# one of them reports is that model's output at these rates, never a speed
+# of the chip. (What the chip reads is PERF_LEDGER.jsonl's: its
+# mistral-7b.serve-chat cell decodes ~144 tokens/s a slot and has a p90
+# time to first token of ~0.35 s on PR 45's line.)
+REPLICA_SLOTS = 8
+REPLICA_TOKENS_PER_SLOT_S = 30.0
+REPLICA_PREFILL_S = 1.2
+REPLICA_PREFILL_HIT_S = 0.15
+
+
 @dataclasses.dataclass(frozen=True)
 class ServingTwinParams:
     """Autoscaled serving-fleet scenario knobs (defaults = the seeded
@@ -1234,11 +1257,11 @@ class ServingTwinParams:
     duration_s: float = 600.0
     dt_s: float = 0.05
     control_period_s: float = 1.0
-    slots: int = 8
-    tokens_per_slot_s: float = 30.0
+    slots: int = REPLICA_SLOTS
+    tokens_per_slot_s: float = REPLICA_TOKENS_PER_SLOT_S
     degraded_fraction: float = 0.4
-    prefill_s: float = 1.2
-    prefill_hit_s: float = 0.15
+    prefill_s: float = REPLICA_PREFILL_S
+    prefill_hit_s: float = REPLICA_PREFILL_HIT_S
     startup_delay_s: float = 25.0
     chips_per_replica: int = 1
     prefix_len: int = 32
@@ -1247,22 +1270,21 @@ class ServingTwinParams:
 
 
 class SlotReplica:
-    """Capacity model of one decode replica: a slot pool, a per-slot decode
-    rate, and a prefix cache that skips prefill for resident prefixes."""
+    """Capacity model of one serving replica, the one every serving lane
+    runs: a slot pool, a prefill leg that drains, then a per-slot decode
+    rate. The LANE decides each admission's prefill leg (cold, resident,
+    host-rehydrated, plus a draft's propose leg) and its rate multiple
+    (a speculative speedup) — residency and spill are the lanes' policies
+    under test — so the replica only runs slots and stamps
+    ``first_token_at`` / ``done_at`` on the request."""
 
-    def __init__(
-        self,
-        rid: str,
-        rate_fraction: float,
-        ready_at: float,
-        params: ServingTwinParams = ServingTwinParams(),
-    ):
+    def __init__(self, rid: str, slots: int, rate: float,
+                 ready_at: float = 0.0):
         self.rid = rid
-        self.params = params
-        self.rate = params.tokens_per_slot_s * rate_fraction
+        self.slots = slots
+        self.rate = rate                  # tokens/s of one decoding slot
         self.ready_at = ready_at
-        self.active: List[dict] = []      # {req, prefill_left, tokens_left}
-        self.prefix_cache: set = set()
+        self.active: List[dict] = []      # {req, prefill_left, tokens_left, rate_mult}
         self.tokens_out = 0.0
         self.draining = False
 
@@ -1272,17 +1294,14 @@ class SlotReplica:
     def free_slots(self, now: float) -> int:
         if not self.ready(now) or self.draining:
             return 0
-        return self.params.slots - len(self.active)
+        return self.slots - len(self.active)
 
-    def admit(self, req: dict) -> None:
-        hit = req["prefix_id"] in self.prefix_cache
-        self.prefix_cache.add(req["prefix_id"])
+    def admit(self, req: dict, prefill_s: float, rate_mult: float = 1.0) -> None:
         self.active.append({
             "req": req,
-            "prefill_left": self.params.prefill_hit_s if hit
-            else self.params.prefill_s,
+            "prefill_left": float(prefill_s),
             "tokens_left": float(req["n_new"]),
-            "hit": hit,
+            "rate_mult": float(rate_mult),
         })
 
     def step(self, now: float, dt: float, done: List[dict]) -> None:
@@ -1291,14 +1310,18 @@ class SlotReplica:
         for sl in list(self.active):
             if sl["prefill_left"] > 0:
                 sl["prefill_left"] -= dt
+                if sl["prefill_left"] <= 0:
+                    # First token lands as prefill drains (the prefill
+                    # logits seed it) — the TTFT stamp the A/Bs gate on.
+                    sl["req"]["first_token_at"] = now
                 continue
-            produced = min(self.rate * dt, sl["tokens_left"])
+            produced = min(self.rate * sl["rate_mult"] * dt,
+                           sl["tokens_left"])
             sl["tokens_left"] -= produced
             self.tokens_out += produced
             if sl["tokens_left"] <= 0:
                 sl["req"]["done_at"] = now
                 sl["req"]["replica"] = self.rid
-                sl["req"]["prefix_hit"] = sl["hit"]
                 done.append(sl["req"])
                 self.active.remove(sl)
 
@@ -1309,7 +1332,7 @@ class SlotReplica:
         return {
             "tokens_per_sec": self.rate * max(busy, 0.2),
             "free_slots": self.free_slots(now),
-            "slots": self.params.slots,
+            "slots": self.slots,
         }
 
 
@@ -1325,12 +1348,19 @@ def replay_serving_fleet(
 
     router = FleetRouter(affinity_tokens=params.prefix_len)
     scaler = ReplicaAutoscaler(autoscaler_cfg)
+    def replica(rid: str, rate_fraction: float, ready_at: float) -> SlotReplica:
+        return SlotReplica(rid, params.slots,
+                           params.tokens_per_slot_s * rate_fraction, ready_at)
+
     replicas: Dict[str, SlotReplica] = {
         # Replica 0 is the degraded host — present from t=0 in both modes;
         # in static mode it is the whole fleet.
-        "r0": SlotReplica("r0", params.degraded_fraction, ready_at=0.0,
-                          params=params)
+        "r0": replica("r0", params.degraded_fraction, 0.0)
     }
+    # The lane's residency policy: a replica skips most of the prefill of
+    # a prefix it has seen before (unbounded — the prefix-plane lane is
+    # the one that bounds it).
+    seen: Dict[str, set] = collections.defaultdict(set)
     state = {"next_rid": 1, "chip_seconds": 0.0}
     queue: List[dict] = []
     done: List[dict] = []
@@ -1345,7 +1375,7 @@ def replay_serving_fleet(
         router.update(up)
         ready_n = len(up)
         # Change-point trace: one entry per replica-count transition
-        # keeps the bench JSON line readable.
+        # keeps the result readable.
         if not replica_trace or replica_trace[-1][1] != ready_n:
             replica_trace.append((round(t, 1), ready_n))
         if autoscale and ready_n > 0:
@@ -1359,10 +1389,7 @@ def replay_serving_fleet(
             )
             while desired > ready_n + booting:
                 rid = f"r{state['next_rid']}"
-                replicas[rid] = SlotReplica(
-                    rid, 1.0, ready_at=t + params.startup_delay_s,
-                    params=params,
-                )
+                replicas[rid] = replica(rid, 1.0, t + params.startup_delay_s)
                 state["next_rid"] += 1
                 booting += 1
             if desired < ready_n:
@@ -1386,7 +1413,11 @@ def replay_serving_fleet(
             rid = router.route(req["prompt"])
             rep = replicas.get(rid) if rid else None
             if rep is not None and rep.free_slots(t) > 0:
-                rep.admit(queue.pop(0))
+                queue.pop(0)
+                req["prefix_hit"] = req["prefix_id"] in seen[rid]
+                seen[rid].add(req["prefix_id"])
+                rep.admit(req, params.prefill_hit_s if req["prefix_hit"]
+                          else params.prefill_s)
                 free_total -= 1
                 placed += 1
             else:
@@ -1694,15 +1725,16 @@ def replay_fidelity(seed: int = 0, n_faults: int = 12) -> dict:
     }
 
 
-def twin_bench_line(seed: int = 0) -> dict:
-    """The twin's deterministic bench line, shared by ``bench.py`` and
-    ``tools/bench_sentinel.py``: replay fidelity vs the recorded source
-    run, plus the two policy A/Bs' headline deltas."""
+def twin_replay_gates(seed: int = 0) -> Dict[str, bool]:
+    """The replay engine's exit gates on the seeded chaos trace: the replay
+    reproduces the recorded run's goodput decomposition, runs far faster
+    than the fleet it replays, and both policy A/Bs measure a difference.
+    Gates only: the numbers behind them are :func:`replay_fidelity`'s and
+    the two scorecards'."""
     fid = replay_fidelity(seed=seed)
-    card = default_policy_scorecard(seed=seed)
-    adm = admission_policy_scorecard(seed=seed)
-    variants = card["variants"]
-    gates = {
+    variants = default_policy_scorecard(seed=seed)["variants"]
+    admission = admission_policy_scorecard(seed=seed)["variants"]
+    return {
         "replay_within_1pct": fid["max_error_pct"] < 1.0,
         "replay_fast_enough": fid["fleet_seconds_per_cpu_second"] >= 1000.0,
         "policy_delta_measured": (
@@ -1710,31 +1742,9 @@ def twin_bench_line(seed: int = 0) -> dict:
             != variants["ckpt200_index_on"]["goodput_fraction"]
         ),
         "warm_beats_fifo": (
-            adm["variants"]["warm_preferring"]["mean_wait_s"]
-            < adm["variants"]["fifo"]["mean_wait_s"]
+            admission["warm_preferring"]["mean_wait_s"]
+            < admission["fifo"]["mean_wait_s"]
         ),
-    }
-    return {
-        "metric": "twin_replay_policy_ab",
-        "value": fid["max_error_pct"],
-        "unit": "max per-category replay error, % of wall",
-        "replay_goodput_fraction": fid["replay_goodput_fraction"],
-        "spans_replayed": fid["spans_replayed"],
-        "ingest_skipped_lines": fid["ingest"].get("skipped", 0),
-        "fleet_seconds_per_cpu_second": fid["fleet_seconds_per_cpu_second"],
-        "variant_goodput": {
-            name: v["goodput_fraction"] for name, v in variants.items()
-        },
-        "variant_mttr_s": {
-            name: v["mttr_mean_s"] for name, v in variants.items()
-        },
-        "variant_ckpt_pct": {
-            name: v["checkpoint_pct"] for name, v in variants.items()
-        },
-        "ab_wait_fifo_s": adm["variants"]["fifo"]["mean_wait_s"],
-        "ab_wait_warm_s": adm["variants"]["warm_preferring"]["mean_wait_s"],
-        "gates": gates,
-        "ok": all(gates.values()),
     }
 
 
@@ -1873,27 +1883,6 @@ def historian_lane(seed: int = 0, n_faults: int = 12) -> dict:
         "ok": all(gates.values()),
     }
 
-
-def historian_bench_line(seed: int = 0) -> dict:
-    """The historian's deterministic bench line, shared by ``bench.py``
-    and ``tools/bench_sentinel.py``: series fidelity and incident
-    stitching on the seeded chaos trace, plus (noisy, ungated) ingest
-    and query throughput."""
-    lane = historian_lane(seed=seed)
-    return {
-        "metric": "historian_chaos_incidents",
-        "value": lane["max_series_error_pct"],
-        "unit": "max replayed-series error, % per queried aggregate",
-        "series": lane["series"],
-        "samples": lane["samples"],
-        "fault_incidents": lane["fault_incidents"],
-        "resolved_incidents": lane["resolved_incidents"],
-        "incidents_by_trigger": lane["incidents"],
-        "ingest_samples_per_sec": lane["ingest_samples_per_sec"],
-        "query_avg_us": lane["query_avg_us"],
-        "gates": lane["gates"],
-        "ok": lane["ok"],
-    }
 
 # -- autopilot lane ------------------------------------------------------------
 
@@ -2106,28 +2095,6 @@ def autopilot_lane(
         "incidents_armed": on["incident_stats"]["opened_by_trigger"],
         "gates": gates,
         "ok": all(gates.values()),
-    }
-
-
-def autopilot_bench_line(seed: int = 0) -> dict:
-    """The autopilot's deterministic bench line, shared by ``bench.py``
-    and ``tools/bench_sentinel.py``: chaos goodput A/B (armed vs off vs
-    shadow) plus the decision-stream accounting on the seeded slow-host
-    plan."""
-    lane = autopilot_lane(seed=seed)
-    return {
-        "metric": "autopilot_chaos_ab",
-        "value": lane["steady_goodput_on"],
-        "unit": "steady-state chaos goodput, autopilot armed",
-        "steady_goodput_off": lane["steady_goodput_off"],
-        "steady_goodput_dry": lane["steady_goodput_dry"],
-        "goodput_recovered": lane["goodput_recovered"],
-        "decisions_armed": lane["armed"]["decisions_total"],
-        "actuations_armed": lane["armed"]["actuations_total"],
-        "decisions_dry": lane["dry_run"]["decisions_total"],
-        "actuations_dry": lane["dry_run"]["actuations_total"],
-        "gates": lane["gates"],
-        "ok": lane["ok"],
     }
 
 
@@ -2690,39 +2657,6 @@ def ctl_scale_profile(
     }
 
 
-def ctl_scale_bench_line(seed: int = 0, profile: Optional[dict] = None) -> dict:
-    """Control-plane scale bench line shared by ``bench.py`` and
-    ``tools/bench_sentinel.py``. The gated value and counters are the
-    deterministic job/request totals; the overhead ratio and per-phase
-    wall profile ride along under timing keys the sentinel treats as
-    noisy. The flatness and determinism regressions are caught through
-    the ``gates`` booleans. Pass ``profile`` (a :func:`ctl_scale_profile`
-    result) to reuse an already-computed run."""
-    prof = profile if profile is not None else ctl_scale_profile(seed=seed)
-    big = prof["big"]["deterministic"]
-    return {
-        "metric": "ctl_scale",
-        "value": float(big["jobs"]["completed"]),
-        "unit": "jobs completed through the real scheduler, big config",
-        "requests_routed": big["serving"]["routed"],
-        "historian_samples": big["historian"]["samples_total"],
-        "incidents_opened": big["incidents"]["opened"],
-        "incidents_resolved": big["incidents"]["resolved"],
-        "overhead": {
-            "small_us_per_fleet_s": prof["overhead_small_us_per_fleet_s"],
-            "big_us_per_fleet_s": prof["overhead_big_us_per_fleet_s"],
-            "per_job_us_small": prof["per_job_us"]["small"],
-            "per_job_us_big": prof["per_job_us"]["big"],
-            "per_request_us_small": prof["per_request_us"]["small"],
-            "per_request_us_big": prof["per_request_us"]["big"],
-            "ratio": prof["overhead_ratio"],
-        },
-        "phases": prof["big"]["phases"],
-        "gates": prof["gates"],
-        "ok": prof["ok"],
-    }
-
-
 # -- fleet prefix plane lane ---------------------------------------------------
 
 
@@ -2738,13 +2672,13 @@ class PrefixPlaneLaneParams:
     dt_s: float = 0.05
     control_period_s: float = 1.0
     n_replicas: int = 4
-    slots: int = 8
-    tokens_per_slot_s: float = 30.0
+    slots: int = REPLICA_SLOTS
+    tokens_per_slot_s: float = REPLICA_TOKENS_PER_SLOT_S
     chips_per_replica: int = 1
     # Prefill legs: full prompt (cold), resident-prefix tail, and
     # host-tier rehydration (host->HBM copy + tail) — between the two.
-    prefill_s: float = 1.2
-    prefill_hit_s: float = 0.15
+    prefill_s: float = REPLICA_PREFILL_S
+    prefill_hit_s: float = REPLICA_PREFILL_HIT_S
     prefill_host_s: float = 0.35
     # 32 hot tenants vs 4 replicas x 4 resident prefixes: half the
     # working set cannot be device-resident anywhere.
@@ -2763,76 +2697,6 @@ class PrefixPlaneLaneParams:
     mean_new_tokens: float = 48.0
     min_new_tokens: int = 8
     warmup_s: float = 60.0
-
-
-class _PrefixLaneReplica:
-    """Capacity model of one decode replica for the prefix-plane lane.
-
-    The lane's dispatch loop decides each admission's prefill leg
-    (cold / resident / host-rehydrated) — in baseline mode from this
-    replica's own bounded LRU, in plane mode from
-    ``PrefixPlane.observe_admit`` — so the replica itself only runs
-    slots and stamps ``first_token_at`` when prefill drains."""
-
-    def __init__(self, rid: str, params: PrefixPlaneLaneParams):
-        self.rid = rid
-        self.params = params
-        self.rate = params.tokens_per_slot_s
-        self.active: List[dict] = []
-        # Baseline per-replica residency: LRU over prefix ids, capped at
-        # what the replica's device cache could actually hold.
-        self.cache: "collections.OrderedDict[int, None]" = (
-            collections.OrderedDict()
-        )
-        self.tokens_out = 0.0
-
-    def free_slots(self) -> int:
-        return self.params.slots - len(self.active)
-
-    def touch(self, pid: int) -> bool:
-        """Baseline residency: True on hit; a miss inserts and LRU-evicts
-        past the per-replica budget (the eviction is silent — per-replica
-        LRU has nowhere to put the overflow, which is the point)."""
-        if pid in self.cache:
-            self.cache.move_to_end(pid)
-            return True
-        self.cache[pid] = None
-        while len(self.cache) > self.params.replica_cache_prefixes:
-            self.cache.popitem(last=False)
-        return False
-
-    def admit(self, req: dict, prefill_s: float) -> None:
-        self.active.append({
-            "req": req,
-            "prefill_left": float(prefill_s),
-            "tokens_left": float(req["n_new"]),
-        })
-
-    def step(self, now: float, dt: float, done: List[dict]) -> None:
-        for sl in list(self.active):
-            if sl["prefill_left"] > 0:
-                sl["prefill_left"] -= dt
-                if sl["prefill_left"] <= 0:
-                    # First token lands as prefill drains (the prefill
-                    # logits seed it) — the TTFT stamp the A/B gates on.
-                    sl["req"]["first_token_at"] = now
-                continue
-            produced = min(self.rate * dt, sl["tokens_left"])
-            sl["tokens_left"] -= produced
-            self.tokens_out += produced
-            if sl["tokens_left"] <= 0:
-                sl["req"]["done_at"] = now
-                sl["req"]["replica"] = self.rid
-                done.append(sl["req"])
-                self.active.remove(sl)
-
-    def router_stats(self) -> dict:
-        busy = sum(1 for s in self.active if s["prefill_left"] <= 0)
-        return {
-            "tokens_per_sec": self.rate * max(busy, 0.2),
-            "free_slots": self.free_slots(),
-            "slots": self.params.slots,
-        }
 
 
 def prefix_plane_lane(
@@ -2868,9 +2732,28 @@ def prefix_plane_lane(
     router = FleetRouter(affinity_tokens=params.prefix_len,
                          prefix_plane=pplane)
     replicas = {
-        f"r{i}": _PrefixLaneReplica(f"r{i}", params)
+        f"r{i}": SlotReplica(f"r{i}", params.slots, params.tokens_per_slot_s)
         for i in range(params.n_replicas)
     }
+    # Baseline per-replica residency: LRU over prefix ids, capped at what
+    # the replica's device cache could actually hold.
+    lru: Dict[str, "collections.OrderedDict[int, None]"] = {
+        rid: collections.OrderedDict() for rid in replicas
+    }
+
+    def touch(rid: str, pid: int) -> bool:
+        """True on a hit; a miss inserts and LRU-evicts past the replica's
+        budget (the eviction is silent — per-replica LRU has nowhere to
+        put the overflow, which is the point)."""
+        cache = lru[rid]
+        if pid in cache:
+            cache.move_to_end(pid)
+            return True
+        cache[pid] = None
+        while len(cache) > params.replica_cache_prefixes:
+            cache.popitem(last=False)
+        return False
+
     trace = bursty_arrivals(
         seed,
         duration_s=params.duration_s,
@@ -2888,16 +2771,16 @@ def prefix_plane_lane(
     kinds = {"replica": 0, "host": 0, "cold": 0}
 
     def control(t: float) -> None:
-        router.update({r.rid: r.router_stats() for r in replicas.values()})
+        router.update({r.rid: r.router_stats(t) for r in replicas.values()})
 
     def tick(t: float) -> None:
         clock.set(t)
-        free_total = sum(r.free_slots() for r in replicas.values())
+        free_total = sum(r.free_slots(t) for r in replicas.values())
         while queue and free_total > 0:
             req = queue[0]
             rid = router.route(req["prompt"])
             rep = replicas.get(rid) if rid else None
-            if rep is None or rep.free_slots() <= 0:
+            if rep is None or rep.free_slots(t) <= 0:
                 break  # full pick: weights refresh next control period
             queue.pop(0)
             free_total -= 1
@@ -2910,7 +2793,7 @@ def prefix_plane_lane(
                     "cold": params.prefill_s,
                 }[obs["kind"]]
             else:
-                hit = rep.touch(req["prefix_id"])
+                hit = touch(rid, req["prefix_id"])
                 kinds["replica" if hit else "cold"] += 1
                 prefill = params.prefill_hit_s if hit else params.prefill_s
             rep.admit(req, prefill)
@@ -3003,30 +2886,6 @@ def prefix_plane_ab(
         "host_budget_rejection": rejection,
         "gates": gates,
         "ok": all(gates.values()),
-    }
-
-
-def prefix_plane_bench_line(seed: int = 0, ab: Optional[dict] = None) -> dict:
-    """The prefix plane's deterministic bench line, shared by ``bench.py``
-    and ``tools/bench_sentinel.py``. The gated value is the baseline/plane
-    p99 TTFT ratio on the seeded shared-prefix trace — deterministic under
-    the virtual clock, so the sentinel gates it like the disagg A/B."""
-    res = ab if ab is not None else prefix_plane_ab(seed=seed)
-    plane = res["plane"]
-    return {
-        "metric": "prefix_plane",
-        "value": res["ttft_p99_improvement"],
-        "unit": "baseline/plane p99 TTFT ratio, shared-prefix trace",
-        "baseline_ttft_p99_ms": res["baseline"]["metrics"]["ttft_p99_ms"],
-        "plane_ttft_p99_ms": plane["metrics"]["ttft_p99_ms"],
-        "tokens_per_sec_ratio": res["tokens_per_sec_ratio"],
-        "host_occupancy": plane.get("host_occupancy", 0.0),
-        "host_stores": plane.get("plane", {}).get("host", {}).get("stores", 0),
-        "host_rehydrations": plane.get("plane", {}).get("host_rehydrations", 0),
-        "admission_kinds": plane["admission_kinds"],
-        "host_tier_gib": res["host_tier_gib"],
-        "gates": res["gates"],
-        "ok": res["ok"],
     }
 
 
@@ -3422,35 +3281,6 @@ def reshard_ab(
     }
 
 
-def reshard_bench_line(seed: int = 0, ab: Optional[dict] = None) -> dict:
-    """The reshard plane's deterministic bench line, shared by ``bench.py``
-    and ``tools/bench_sentinel.py``. The gated value is the
-    topology-changing / same-topology-warm MTTR ratio on the seeded
-    chip-fault trace — the exit criterion is that topology freedom costs
-    at most 1.5× the warm same-topology recovery, with zero lost steps
-    and every held serving request completing."""
-    res = ab if ab is not None else reshard_ab(seed=seed)
-    rs = res["reshard"]
-    return {
-        "metric": "reshard",
-        "value": res["mttr_ratio"],
-        "unit": "topology-changing / same-topology warm MTTR ratio",
-        "reshard_mttr_mean_s": rs["mttr_mean_s"],
-        "same_topology_mttr_mean_s": res["same_topology"]["mttr_mean_s"],
-        "mttr_budget_s": res["mttr_budget_s"],
-        "lost_steps": rs["lost_steps"],
-        "locked_lost_steps": res["topology_locked"]["lost_steps"],
-        "topology_changes": rs["topology_changes"],
-        "reshard_s_per_resume": rs["reshard_s_per_resume"],
-        "roundtrip_targets": len(res["roundtrip"].get("targets", [])),
-        "held_migrated": res["migration"]["migrated"],
-        "held_completed": res["migration"]["completed"],
-        "parity_mismatches": res["migration"]["parity_mismatches"],
-        "gates": res["gates"],
-        "ok": res["ok"],
-    }
-
-
 # -- fleet speculative decoding pool lane --------------------------------------
 
 
@@ -3469,8 +3299,8 @@ class SpecPoolLaneParams:
     dt_s: float = 0.05
     control_period_s: float = 1.0
     n_replicas: int = 4
-    slots: int = 8
-    tokens_per_slot_s: float = 30.0
+    slots: int = REPLICA_SLOTS
+    tokens_per_slot_s: float = REPLICA_TOKENS_PER_SLOT_S
     chips_per_replica: int = 1
     prefill_s: float = 0.5
     # Propose leg: gamma sequential draft steps through the draft pool
@@ -3504,56 +3334,6 @@ class SpecPoolLaneParams:
     sustain_consults: int = 3
     cooldown_s: float = 60.0
     canary_every: int = 8
-
-
-class _SpecLaneReplica:
-    """Capacity model of one verify replica for the spec-pool lane: a
-    slot pool where each admission carries its own decode-rate multiple
-    (the speculative speedup of its tenant's draft, or 1.0 for plain /
-    spilled / canary legs)."""
-
-    def __init__(self, rid: str, params: SpecPoolLaneParams):
-        self.rid = rid
-        self.params = params
-        self.rate = params.tokens_per_slot_s
-        self.active: List[dict] = []
-        self.tokens_out = 0.0
-
-    def free_slots(self) -> int:
-        return self.params.slots - len(self.active)
-
-    def admit(self, req: dict, prefill_s: float, rate_mult: float) -> None:
-        self.active.append({
-            "req": req,
-            "prefill_left": float(prefill_s),
-            "tokens_left": float(req["n_new"]),
-            "rate_mult": float(rate_mult),
-        })
-
-    def step(self, now: float, dt: float, done: List[dict]) -> None:
-        for sl in list(self.active):
-            if sl["prefill_left"] > 0:
-                sl["prefill_left"] -= dt
-                if sl["prefill_left"] <= 0:
-                    sl["req"]["first_token_at"] = now
-                continue
-            produced = min(self.rate * sl["rate_mult"] * dt,
-                           sl["tokens_left"])
-            sl["tokens_left"] -= produced
-            self.tokens_out += produced
-            if sl["tokens_left"] <= 0:
-                sl["req"]["done_at"] = now
-                sl["req"]["replica"] = self.rid
-                done.append(sl["req"])
-                self.active.remove(sl)
-
-    def router_stats(self) -> dict:
-        busy = sum(1 for s in self.active if s["prefill_left"] <= 0)
-        return {
-            "tokens_per_sec": self.rate * max(busy, 0.2),
-            "free_slots": self.free_slots(),
-            "slots": self.params.slots,
-        }
 
 
 def spec_pool_lane(
@@ -3592,7 +3372,7 @@ def spec_pool_lane(
         )
     router = FleetRouter()
     replicas = {
-        f"r{i}": _SpecLaneReplica(f"r{i}", params)
+        f"r{i}": SlotReplica(f"r{i}", params.slots, params.tokens_per_slot_s)
         for i in range(params.n_replicas)
     }
     trace = bursty_arrivals(
@@ -3622,19 +3402,19 @@ def spec_pool_lane(
 
     def control(t: float) -> None:
         clock.set(t)
-        router.update({r.rid: r.router_stats() for r in replicas.values()})
+        router.update({r.rid: r.router_stats(t) for r in replicas.values()})
         if spill is not None:
             spill.consult(sorted(emas), now=t)
 
     def tick(t: float) -> None:
         nonlocal scored
         clock.set(t)
-        free_total = sum(r.free_slots() for r in replicas.values())
+        free_total = sum(r.free_slots(t) for r in replicas.values())
         while queue and free_total > 0:
             req = queue[0]
             rid = router.route(req["prompt"])
             rep = replicas.get(rid) if rid else None
-            if rep is None or rep.free_slots() <= 0:
+            if rep is None or rep.free_slots(t) <= 0:
                 break  # full pick: weights refresh next control period
             queue.pop(0)
             free_total -= 1
@@ -3817,35 +3597,6 @@ def spec_pool_ab(
             draft_plans[0].label if draft_plans else None),
         "gates": gates,
         "ok": all(gates.values()),
-    }
-
-
-def spec_pool_bench_line(seed: int = 0, ab: Optional[dict] = None) -> dict:
-    """The spec pool's deterministic bench line, shared by ``bench.py``
-    and ``tools/bench_sentinel.py``. The gated value is the spec/plain
-    tokens-per-sec-per-chip ratio at equal chips on the seeded bursty
-    trace — the headline fleet-level speculative win, with the junk-draft
-    tenant provably spilled by the sustained-α rule."""
-    res = ab if ab is not None else spec_pool_ab(seed=seed)
-    pool = res["spec"]
-    return {
-        "metric": "spec_pool",
-        "value": res["tokens_per_sec_per_chip_ratio"],
-        "unit": "spec/plain tokens-per-sec-per-chip ratio, equal chips",
-        "plain_tokens_per_sec_per_chip": (
-            res["plain"]["metrics"]["tokens_per_sec_per_chip"]),
-        "spec_tokens_per_sec_per_chip": (
-            pool["metrics"]["tokens_per_sec_per_chip"]),
-        "p99_ratio": res["p99_ratio"],
-        "low_alpha_tenant": res["low_alpha_tenant"],
-        "low_alpha_tenant_p99_ratio": res["low_alpha_tenant_p99_ratio"],
-        "tenants_spilled": pool.get("spill", {}).get("spilled", []),
-        "spill_decisions_fired": len(res["spill_decisions_fired"]),
-        "legs": pool["legs"],
-        "draft_plan_label": res["draft_plan_label"],
-        "spec_replica_gib": res["spec_replica_gib"],
-        "gates": res["gates"],
-        "ok": res["ok"],
     }
 
 
@@ -4351,36 +4102,4 @@ def ctl_crash_ab(
         "mttr_budget_s": budget,
         "gates": gates,
         "ok": all(gates.values()),
-    }
-
-
-def ctl_crash_bench_line(seed: int = 0, ab: Optional[dict] = None) -> dict:
-    """The durable control plane's deterministic bench line, shared by
-    ``bench.py`` and ``tools/bench_sentinel.py``. The gated value is the
-    crash-recovery / no-crash MTTR ratio on the seeded storm — the exit
-    criterion is that killing and restoring the control plane mid-storm
-    costs at most 1.5× the no-crash completion time, with zero lost or
-    duplicated submissions and every held request answered."""
-    res = ab if ab is not None else ctl_crash_ab(seed=seed)
-    cr = res["crashed"]
-    return {
-        "metric": "ctl_crash",
-        "value": res["mttr_ratio"],
-        "unit": "crash-recovery / no-crash MTTR ratio",
-        "crash_mttr_s": cr["mttr_s"],
-        "baseline_mttr_s": res["baseline"]["mttr_s"],
-        "mttr_budget_s": res["mttr_budget_s"],
-        "train_completed": cr["train_completed"],
-        "requests_completed": cr["requests_completed"],
-        "held_recovered": cr["held_recovered"],
-        "jobs_readopted": (cr.get("recovery") or {}).get("readopted", 0),
-        "requeued_vanished": (
-            (cr.get("recovery") or {}).get("requeued_vanished", 0)),
-        "replicas_redispatched": (
-            (cr.get("re_adopt") or {}).get("replicas_redispatched", 0)),
-        "double_grants": (cr.get("recovery") or {}).get("double_grants", 0),
-        "journal_appends": cr["journal"]["appends_total"],
-        "journal_snapshots": cr["journal"]["snapshots_total"],
-        "gates": res["gates"],
-        "ok": res["ok"],
     }
