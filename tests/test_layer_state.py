@@ -63,6 +63,9 @@ def _stack(case, registry):
     if case == "hybrid-tiny":
         cfg = _benchmark_config("granite-4.0-h-micro-1chip-serve")
         mc = program.model_config({**cfg, **cfg["rehearsal"]}, "hybrid-tiny")
+    elif case == "power-tiny":  # every mixer a power-retention layer: a stack with NO positional kind
+        cfg = _benchmark_config("brumby-14b-1chip-serve")
+        mc = program.model_config({**cfg, **cfg["rehearsal"]}, "power-tiny")
     elif case == "windowed":
         mc = tiny.with_(name="windowed", sliding_window=64)
     else:
@@ -84,17 +87,18 @@ def _split_nbytes(layers):
 # (a) the allocation is the table's, and the estimator prices it -------------
 
 
-@pytest.mark.parametrize("case", ["gpt-tiny", "windowed", "kv_quant", "hybrid-tiny"])
+@pytest.mark.parametrize("case", ["gpt-tiny", "windowed", "kv_quant", "hybrid-tiny", "power-tiny"])
 def test_the_pool_is_the_tables_leaves_and_the_estimate_prices_its_bytes(case, registry):
     mc, kv_quant = _stack(case, registry)
     slots, max_len, chunk = 4096, 4096, 32  # shapes only: big enough for 1e-4 GiB to mean something
     pool = jax.eval_shape(lambda: serving.init_slot_cache(
         mc, slots, max_len, BF16, prefill_chunk=chunk, kv_quant=kv_quant))
     lanes = ring_lanes(mc, max_len, chunk)
-    assert (lanes < max_len) == pool.ring == (case == "windowed") and pool.n_lanes == lanes
+    held = 0 if case == "power-tiny" else lanes  # a stack with no positional kind holds no lane
+    assert (lanes < max_len) == pool.ring == (case == "windowed") and pool.n_lanes == held
     counts = layer_state.layer_counts(mc)
     assert counts == ({"ssm": mc.n_ssm_layers, "attn": mc.n_attn_layers} if case == "hybrid-tiny"
-                      else {"attn": mc.n_layers})
+                      else {"power": mc.n_layers} if case == "power-tiny" else {"attn": mc.n_layers})
     table = layer_state.leaf_specs(mc, counts, lanes, BF16, kv_quant)
     assert set(pool.layers) == set(table)  # a kind the stack has not is absent
     for kind, leaves in table.items():
@@ -102,17 +106,18 @@ def test_the_pool_is_the_tables_leaves_and_the_estimate_prices_its_bytes(case, r
         for name, leaf in leaves.items():
             a = pool.layers[kind][name]
             assert a.shape == (counts[kind], slots) + leaf.shape and a.dtype == jnp.dtype(leaf.dtype), (kind, name)
-    assert pool.quantized == kv_quant and pool.recurrent == (case == "hybrid-tiny")
+    whole_kinds = case in ("hybrid-tiny", "power-tiny")
+    assert pool.quantized == kv_quant and pool.recurrent == whole_kinds
     # the same table allocates the one-row ingestion cache
     c1 = jax.eval_shape(lambda: init_cache(mc, 1, max_len, BF16, max_chunk=chunk, kv_quant=kv_quant))
-    assert jax.tree.structure(c1.layers) == jax.tree.structure(pool.layers) and c1.max_len == lanes
+    assert jax.tree.structure(c1.layers) == jax.tree.structure(pool.layers) and c1.max_len == held
 
     positional, whole = _split_nbytes(pool.layers)
     priced = layer_state.state_bytes(mc, slots, lanes, BF16, kv_quant)
     assert layer_state.split_bytes(priced) == (positional, whole) and pool.recurrent_state_bytes == whole
     est = estimate_serving_hbm(mc.name, slots, max_len, prefill_chunk=chunk, kv_quant=kv_quant)
     assert est.kv_pool_gib == round(positional / GIB, 4) and est.recurrent_state_gib == round(whole / GIB, 4)
-    assert (whole > 0) == (case == "hybrid-tiny")
+    assert (whole > 0) == whole_kinds and (positional > 0) == (case != "power-tiny")
 
 
 # (b) the estimate's numbers, pinned at the parent commit --------------------
@@ -169,7 +174,7 @@ def _random_like(tree, seed):
 
 
 @pytest.mark.parametrize("case, kind", [("gpt-tiny", "attn"), ("kv_quant", "attn"), ("windowed", "attn"),
-                                        ("hybrid-tiny", "attn"), ("hybrid-tiny", "ssm")])
+                                        ("hybrid-tiny", "attn"), ("hybrid-tiny", "ssm"), ("power-tiny", "power")])
 def test_insert_then_reset_of_a_row(case, kind, registry):
     mc, kv_quant = _stack(case, registry)
     slots, chunk, slot, n = 3, 16, 1, 21
@@ -184,7 +189,7 @@ def test_insert_then_reset_of_a_row(case, kind, registry):
                  length=jnp.asarray(n, jnp.int32), ring=c1.ring)
     M = c1.max_len
     positional = layer_state.LAYER_KINDS[kind].positional
-    assert positional == (kind == "attn")
+    assert positional == (kind == "attn") and (M > 0) == (case != "power-tiny")
 
     got = serving._insert_prefill(pool, c1, jnp.int32(slot), jnp.int32(n), pool.ring)
     assert got.lengths.tolist() == [5, n, 7]

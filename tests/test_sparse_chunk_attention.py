@@ -413,3 +413,28 @@ def test_the_grouped_expert_kernels_compile_and_no_experts_weights_are_copied(on
     one_layer = re.compile(rf"= bf16\[(1,)?{E},({D},{F}|{F},{D})\]")
     assert not [line for line in text.splitlines() if one_layer.search(line)]
     assert f"bf16[{expert_gmm.n_tiles(T * 6, E) * expert_gmm.ROWS},{D}]" in text  # the buffer any routing fits
+
+
+def test_the_power_retention_update_compiles_in_place_at_the_long_document_cells_shapes(one_chip, monkeypatch):
+    """The one-pass decode update of a power-retention state (``ops.power_update``,
+    kept here with the other compiles for the chip) at
+    ``brumby-14b.serve-longdoc12``'s shapes (8 layers, 12 slots, 8 kv-heads of
+    128 values, 5 query heads a state, 9 216 coordinates): Mosaic takes the
+    kernel, both stacks are aliased to the outputs, and nothing but the
+    kernel's small operands is allocated: no copy of the 3.65 GB pool. A
+    compile, not a run."""
+    from functools import partial
+
+    from tpu_engine.ops import power_update
+
+    monkeypatch.setattr(power_update, "on_tpu", lambda: True)  # the described chip: this process's devices are the CPU's
+    L, B, KV, G, HD, W = 8, 12, 8, 5, 128, 9216
+    sds = lambda shape, dt=F32: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+    bf16 = jnp.bfloat16
+    compiled = jax.jit(partial(power_update.power_update, tile=tfm.POWER_TILE), donate_argnums=(5, 6)).lower(
+        sds((B, KV, G, HD), bf16), sds((B, KV, HD), bf16), sds((B, KV, HD), bf16),
+        sds((B, KV)), sds((B, KV)), sds((L, B, KV, HD, W)), sds((L, B, KV, W)), sds((), jnp.int32)).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert "power_update" in text and "tpu_custom_call" in text
+    assert memory.alias_size_in_bytes >= L * B * KV * W * (HD + 1) * 4
+    assert memory.temp_size_in_bytes < 64 << 20

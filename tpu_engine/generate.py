@@ -52,6 +52,8 @@ from jax import lax
 
 from tpu_engine import layer_state
 from tpu_engine.models.transformer import (
+    POWER_NORM_EPS,
+    POWER_TILE,
     ModelConfig,
     _dense_mlp,
     _norm,
@@ -68,7 +70,7 @@ from tpu_engine.models.transformer import (
     served_format,
     unembed,
 )
-from tpu_engine.ops import expert_gmm, lane_decode, mla_decode, sparse_block_attention, ssd_update
+from tpu_engine.ops import expert_gmm, lane_decode, mla_decode, power_update, sparse_block_attention, ssd_update
 from tpu_engine.quant import QuantWeight, dequantize_weight
 
 _NEG_INF = -1e30
@@ -756,6 +758,180 @@ def _lightning_block(x, lp, state, at, positions, valid, cfg: ModelConfig, tally
 
 
 # ---------------------------------------------------------------------------
+# Gated power-retention layers
+# ---------------------------------------------------------------------------
+
+
+def power_expand(y, cfg: ModelConfig, dtype=jnp.float32):
+    """``phi(y)``: the tiled symmetric square of ``y`` [..., HD] — ``HD`` cut
+    in tiles of ``POWER_TILE``; for each pair of tiles a <= b
+    (``cfg.power_tile_pairs``, in that order) the ``tile^2`` products ``y_a (x)
+    y_b``, times sqrt(2) where a < b — so that ``phi(y) . phi(z) = (y . z)^2``
+    exactly. [..., ``cfg.power_state_width``], formed in float32 and handed
+    over in ``dtype`` (a matmul operand is rounded where it is made, not
+    written out in float32 first)."""
+    t = POWER_TILE
+    tiles = y.astype(jnp.float32).reshape(*y.shape[:-1], cfg.head_dim // t, t)
+    a, b = (jnp.asarray(ix, jnp.int32) for ix in zip(*cfg.power_tile_pairs))
+    weight = jnp.where(a == b, 1.0, 2.0 ** 0.5)[:, None]          # folded into the rows: one product a coordinate
+    blocks = jnp.take(tiles, a, axis=-2)[..., :, None] * (jnp.take(tiles, b, axis=-2) * weight)[..., None, :]
+    return blocks.astype(dtype).reshape(*y.shape[:-1], cfg.power_state_width)
+
+
+def _power_step(q, k, v, log_g, w, state, norm, cfg: ModelConfig):
+    """One recurrence step, all float32 (it is bound by reading and writing
+    the state): q [B,KV,G,HD], k, v [B,KV,HD], ``log_g`` [B,KV] the gate's log
+    (0 for a row that keeps its state), ``w`` [B,KV] what the token adds with
+    (1/HD, 0 for such a row), state [B,KV,HD,W], norm [B,KV,W]. Returns the
+    numerator [B,KV,G,HD], the normaliser [B,KV,G] and the two advanced."""
+    fk = power_expand(k, cfg) * w[..., None]                      # [B,KV,W]
+    g = jnp.exp(log_g)
+    state = state * g[..., None, None] + v.astype(jnp.float32)[..., :, None] * fk[..., None, :]
+    norm = norm * g[..., None] + fk
+    fq = power_expand(q, cfg)                                     # [B,KV,G,W]
+    num = jnp.einsum("bkgw,bkpw->bkgp", fq, state, precision=lax.Precision.HIGHEST)
+    den = jnp.einsum("bkgw,bkw->bkg", fq, norm, precision=lax.Precision.HIGHEST)
+    return num, den, state, norm
+
+
+def _power_step_at(q, k, v, log_g, w, state, norm, at, cfg: ModelConfig):
+    """:func:`_power_step` of layer ``at`` of the kind's stacked leaves
+    (``state`` [L,B,KV,HD,W], ``norm`` [L,B,KV,W]), written back into them.
+    Where the one-pass kernel engages (``ops.power_update.engages``) it
+    rewrites that layer's blocks where they lie and expands ``phi`` itself;
+    anywhere else this is the XLA step on the layer's slices, the plain
+    statement of what the kernel computes."""
+    if power_update.engages(state, POWER_TILE):
+        return power_update.power_update(q, k, v, log_g, w, state, norm, at, tile=POWER_TILE)
+    num, den, h, z = _power_step(q, k, v, log_g, w, layer_slice(state, at).astype(jnp.float32),
+                                 layer_slice(norm, at).astype(jnp.float32), cfg)
+    return (num, den, lax.dynamic_update_index_in_dim(state, h.astype(state.dtype), at, 0),
+            lax.dynamic_update_index_in_dim(norm, z.astype(norm.dtype), at, 0))
+
+
+def _power_chunk(q, k, v, log_g, w, state, norm, cfg: ModelConfig):
+    """One chunk of Q positions in the chunked form: scores ``(q . k)^2 / HD``
+    with the gate's decay inside the chunk (never the expansion: 128
+    multiply-adds a pair, not 9 216), the entering state queried through
+    ``phi(q)`` for what came before, the state advanced by the chunk's decayed
+    ``v (x) phi(k)``.
+
+    q [B,Q,KV,G,HD], k, v [B,Q,KV,HD] (compute dtype); ``log_g`` [B,Q,KV]
+    float32 <= 0 and ``w`` [B,Q,KV] (1/HD; both 0 where a position must leave
+    the state as it was); state [B,KV,HD,W], norm [B,KV,W] float32. Returns
+    (numerator [B,Q,KV,G,HD], normaliser [B,Q,KV,G], state, norm), float32.
+
+    Decays come from the DIFFERENCE of cumulative sums (as :func:`_ssd_chunk`);
+    matmul operands are the compute dtype's, accumulated in float32; the
+    float32 state is read as two such terms (its rounding and the rest), so
+    that what it carried is not cut to a bfloat16's eight bits."""
+    cd, f32 = q.dtype, jnp.float32
+    Q, HD = q.shape[1], cfg.head_dim
+    cum = jnp.cumsum(log_g, axis=1)                                # [B,Q,KV] <= 0
+    seg = cum[:, :, None, :] - cum[:, None, :, :]                  # [B,t,s,KV]
+    causal = jnp.tril(jnp.ones((Q, Q), bool))[None, :, :, None]
+    decay = jnp.exp(jnp.where(causal, seg, -jnp.inf)) * w[:, None, :, :]
+    s = jnp.einsum("btkgd,bskd->bkgts", q, k, preferred_element_type=f32)
+    p = jnp.square(s) * jnp.moveaxis(decay, 3, 1)[:, :, None]      # [B,KV,G,t,s]
+    num = jnp.einsum("bkgts,bskd->btkgd", p.astype(cd), v, preferred_element_type=f32)
+    den = jnp.moveaxis(jnp.sum(p, axis=-1), 3, 1)                  # [B,t,KV,G]
+    # What the entering state still contributes at t: one contraction over
+    # the expansion for the values and the normaliser alike.
+    held = jnp.concatenate([state, norm[:, :, None, :]], axis=2)   # [B,KV,HD+1,W]
+    fq = power_expand(q, cfg, cd)                                  # [B,Q,KV,G,W]
+    if cd == f32:
+        past = jnp.einsum("btkgw,bkpw->btkgp", fq, held, precision=lax.Precision.HIGHEST)
+    else:
+        hi = held.astype(cd)
+        both = jnp.concatenate([hi, (held - hi.astype(f32)).astype(cd)], axis=2)
+        past = jnp.einsum("btkgw,bkpw->btkgp", fq, both, preferred_element_type=f32)
+        past = past[..., :HD + 1] + past[..., HD + 1:]
+    past = past * jnp.exp(cum)[..., None, None]
+    num, den = num + past[..., :HD], den + past[..., HD]
+    to_end = jnp.exp(cum[:, -1:, :] - cum) * w                     # [B,Q,KV]
+    fk = power_expand(k, cfg) * to_end[..., None]                  # [B,Q,KV,W]
+    left = jnp.exp(cum[:, -1, :])                                  # [B,KV]
+    state = state * left[..., None, None] + jnp.einsum(
+        "bskp,bskw->bkpw", v, fk.astype(cd), preferred_element_type=f32)
+    norm = norm * left[..., None] + jnp.sum(fk, axis=1)
+    return num, den, state, norm
+
+
+def _power_scan(q, k, v, log_g, w, state, norm, cfg: ModelConfig):
+    """:func:`_power_chunk` over T positions, ``cfg.ssm_chunk`` at a time (the
+    last chunk padded with dead positions: ``log_g = w = 0``)."""
+    B, T = q.shape[:2]
+    Q = min(cfg.ssm_chunk, T)
+    if T == Q:
+        return _power_chunk(q, k, v, log_g, w, state, norm, cfg)
+
+    def step(carry, xs):
+        num, den, h, z = _power_chunk(*xs, *carry, cfg)
+        return (h, z), (num, den)
+
+    (state, norm), (num, den) = lax.scan(
+        step, (state, norm), tuple(_time_blocks(a, Q) for a in (q, k, v, log_g, w)))
+    flat = lambda a: jnp.moveaxis(a, 0, 1).reshape(B, -1, *a.shape[3:])[:, :T]  # noqa: E731
+    return flat(num), flat(den), state, norm
+
+
+def _power_block(x, lp, state, norm, at, positions, valid, cfg: ModelConfig, tally=None):
+    """One gated power-retention layer (degree 2), then the block every kind
+    has (:func:`_mlp_block`).
+
+    ``q_t`` (``n_heads``), ``k_t``, ``v_t`` (``n_kv_heads``) from ``u_t =
+    norm(x_t)``; q and k per-head normed and rotated; ``log gamma_t = log
+    sigmoid(u_t Wg + bg)`` per kv-head, float32. Query head i of kv-head h
+    reads ``o_t = sum_s w_ts v_s / (sum_s w_ts + eps)`` with ``w_ts =
+    (prod_{s<r<=t} gamma_r) (q_t . k_s)^2 / HD`` — held as the state ``S_t =
+    gamma_t S_{t-1} + v_t (x) phi(k_t) / HD`` and its normaliser ``z_t`` alike
+    (``phi``: :func:`power_expand`), which every one of the kv-head's query
+    heads reads through its own ``phi(q_t)``.
+
+    ``state`` [L,B,KV,HD,W] and ``norm`` [L,B,KV,W] are the kind's whole
+    leaves, float32; this layer reads and rewrites its own slice, ``at``,
+    under the scope of the step that does it: ``power_update`` for one token
+    (:func:`_power_step_at`), ``power_scan`` for a chunk (:func:`_power_scan`).
+    ``valid`` [B,T] marks the real positions, a PREFIX of each row: a position
+    that is not valid leaves the state exactly as it was (``gamma = 1``,
+    nothing added). Returns (x, state, norm)."""
+    B, T, _ = x.shape
+    H, KV, HD = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    f32 = jnp.float32
+    with jax.named_scope("power"):
+        u = _norm(x, lp["attn_norm"], cfg)
+        with jax.named_scope("power_qkvg"):
+            def heads(name, n):
+                return _proj(u, lp[name]["kernel"]).reshape(B, T, n, HD)
+
+            q = _rope(_rms_norm(heads("q", H), lp["q_norm"]["scale"], cfg.norm_eps),
+                      positions, cfg.rope_theta).reshape(B, T, KV, H // KV, HD)
+            k = _rope(_rms_norm(heads("k", KV), lp["k_norm"]["scale"], cfg.norm_eps),
+                      positions, cfg.rope_theta)
+            v = heads("v", KV)
+            logit = jnp.einsum("btd,dk->btk", u, lp["g_proj"]["kernel"],
+                               preferred_element_type=f32) + lp["g_bias"].astype(f32)
+            log_g = jnp.where(valid[..., None], jax.nn.log_sigmoid(logit), 0.0)   # [B,T,KV]
+            w = jnp.broadcast_to(valid.astype(f32)[..., None] / HD, (B, T, KV))
+        with jax.named_scope("power_update" if T == 1 else "power_scan"):
+            if T == 1:
+                num, den, state, norm = _power_step_at(
+                    q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], w[:, 0], state, norm, at, cfg)
+                num, den = num[:, None], den[:, None]
+            else:
+                # the recurrence runs in float32 whatever the cache stores
+                num, den, h, z = _power_scan(q, k, v, log_g, w, layer_slice(state, at).astype(f32),
+                                             layer_slice(norm, at).astype(f32), cfg)
+                state = lax.dynamic_update_index_in_dim(state, h.astype(state.dtype), at, 0)
+                norm = lax.dynamic_update_index_in_dim(norm, z.astype(norm.dtype), at, 0)
+        with jax.named_scope("power_norm"):
+            o = (num / (den[..., None] + POWER_NORM_EPS)).reshape(B, T, H * HD).astype(x.dtype)
+        with jax.named_scope("power_out_proj"):
+            x = _residual(x, _proj(o, lp["o"]["kernel"]), cfg)
+    return _mlp_block(x, lp, cfg, valid, tally), state, norm
+
+
+# ---------------------------------------------------------------------------
 # Block-sparse attention layers
 # ---------------------------------------------------------------------------
 
@@ -1408,6 +1584,10 @@ def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
         x, state = _lightning_block(x, lp, s["state"], at, positions, valid, cfg, tally)
         return x, {"state": state}
 
+    def power_layer(x, lp, at, s, tally):
+        x, state, norm = _power_block(x, lp, s["state"], s["norm"], at, positions, valid, cfg, tally)
+        return x, {"state": state, "norm": norm}
+
     def mla_layer(x, lp, at, s, tally, dense=False):
         x, latent = _mla_block(x, lp, s["latent"], at, write, slot_pos, positions, valid,
                                cfg, tally, dense)
@@ -1445,6 +1625,7 @@ def scan_layers(x, stacks, cfg: ModelConfig, cache, write, slot_pos, positions,
 
     layer_fns = {"attn": own(attn_layer), "ssm": own(ssm_layer),
                  "sparse_attn": own(sparse_attn_layer), "lightning": own(lightning_layer),
+                 "power": own(power_layer),
                  "mla": own(mla_layer), "mla_dense": own(partial(mla_layer, dense=True)),
                  "mamba1": mamba1_layer, "window_attn": diff_attn_layer, "full_attn": diff_attn_layer,
                  "cross_attn": cross_attn_layer, "gmu": gmu_layer}

@@ -6,7 +6,10 @@ position (a lane's row ``[KV x HD]``, the kv-heads side by side: the format of
 every positional kind that keeps keys and values, so that a block of lanes is
 whole contiguous rows and a decode step's kernel reads the pool where it lies;
 only an int8 pool keeps ``[KV, HD]`` codes beside their scales), a Mamba-2
-layer keeps one recurrent state whatever the length. Both
+layer keeps one recurrent state whatever the length (as do a Mamba-1, a
+lightning and a power-retention layer: the WHOLE kinds, whose decode step
+rewrites all of it — Mamba-2 and lightning through ``ops.ssd_update``, power
+retention through ``ops.power_update``, Mamba-1 in XLA). Both
 caches (:class:`generate.KVCache`, one row in lockstep;
 :class:`serving.SlotCache`, a pool of rows with their own lengths) hold that
 state as ONE tree, ``{kind: {leaf: array [L_kind, rows, ...]}}``, allocated,
@@ -176,6 +179,28 @@ def _diff_attn_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
     return {"k": rows, "v": rows}
 
 
+def power_tile_pairs(head_dim: int, tile: int) -> tuple:
+    """The pairs of tiles ``(a, b)``, a <= b, of a head cut in tiles of
+    ``tile`` values, in the order a power-retention state lays their blocks of
+    ``tile^2`` products out (the one statement of that order: the expansion,
+    the leaf's width and the decode kernel read it)."""
+    n = head_dim // tile
+    return tuple((a, b) for a in range(n) for b in range(a, n))
+
+
+def _power_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
+    """A power-retention layer's state after the last REAL token fed, per
+    kv-head (its query heads share it): ``state``, the decayed sum of ``v (x)
+    phi(k) / HD`` — value x expanded key, the expansion MINOR, as a Mamba-2
+    state is ``[head_dim, state]`` — and ``norm``, the same sum of ``phi(k) /
+    HD`` alone (the normaliser: the state's 129th row, kept as a leaf of its
+    own because a second-minor dimension of 129 would be stored as 136). Both
+    float32: every token adds to them. ``phi`` is the tiled symmetric square
+    (``cfg.power_state_width``: 9 216 coordinates for a 128-wide key)."""
+    return {"state": Leaf((cfg.n_kv_heads, cfg.head_dim, cfg.power_state_width), jnp.float32),
+            "norm": Leaf((cfg.n_kv_heads, cfg.power_state_width), jnp.float32)}
+
+
 def _no_leaves(cfg, lanes: int, dtype, kv_quant: bool) -> dict:
     return {}
 
@@ -196,6 +221,9 @@ LAYER_KINDS: dict[str, LayerKind] = {
     "full_attn": LayerKind(positional=True, leaves=_diff_attn_leaves, label="attention"),
     "cross_attn": LayerKind(positional=True, leaves=_no_leaves, label="cross-attention"),
     "gmu": LayerKind(positional=True, leaves=_no_leaves, label="gated-memory"),
+    # a stack of these alone keeps NO lane: ``n_lanes`` of its tree is 0, and a
+    # row's length bounds positions (the rotation) and nothing else
+    "power": LayerKind(positional=False, leaves=_power_leaves, label="power-retention"),
 }
 
 

@@ -23,6 +23,7 @@ Design choices for the MXU/HBM (see SURVEY.md §7 and the task's TPU notes):
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, replace
 from functools import partial
 from typing import Any, Optional
@@ -45,10 +46,20 @@ LAYER_TYPE_KINDS = {"attention": "attn", "mamba": "ssm", "lightning": "lightning
                     # differential attention), see ``ModelConfig.layer_types``
                     "mamba1": "mamba1", "diff_window_attention": "window_attn",
                     "diff_attention": "full_attn", "diff_cross_attention": "cross_attn",
-                    "gmu": "gmu"}
+                    "gmu": "gmu",
+                    # gated power retention (linear attention through the symmetric
+                    # power of q and k): a whole state per kv-head and no lane
+                    "power_retention": "power"}
 
 # Longest period of unlike layers :meth:`ModelConfig.layer_periods` looks for.
 _MAX_PERIOD = 4
+
+# A power-retention state lays the key's symmetric square out in tiles: the
+# head cut in tiles of this many values, one block of its square for each pair
+# of tiles a <= b (256 products: two of the chip's 128-lane groups).
+POWER_TILE = 16
+# ... and adds this to the sum of a position's weights before it divides by it.
+POWER_NORM_EPS = 1e-6
 
 
 @dataclass(frozen=True)
@@ -176,6 +187,12 @@ class ModelConfig:
     # ``exp(-2^(-8h/H) (1 - l/(L-1) + 1e-5))`` a token.
     lightning_heads: int = 0
     lightning_head_dim: int = 0
+    # Gated power retention ("power_retention" layers) has no field of its
+    # own: ``n_heads`` query heads over ``n_kv_heads`` states, per-head q/k norm
+    # and rotation as the qwen recipe; the score of a query and a key is ``(q .
+    # k)^2 / head_dim`` (degree 2), decayed by a sigmoid gate per kv-head and
+    # token and divided by the sum of the scores. The state holds the key's
+    # symmetric square in tiles of :data:`POWER_TILE` (:meth:`power_tile_pairs`).
     # Block-sparse attention (``n_heads`` query heads over ``n_kv_heads``, no
     # rotation, per-head q/k norm, a sigmoid output gate). A query past
     # ``sparse_dense_len`` scores the compressed keys (the mean of
@@ -261,6 +278,19 @@ class ModelConfig:
     @property
     def lightning_inner(self) -> int:
         return self.lightning_heads * self.lightning_head_dim
+
+    @property
+    def power_tile_pairs(self) -> tuple:
+        """The pairs of tiles ``(a, b)``, a <= b, in the order a power-retention
+        state lays their blocks of products out."""
+        return layer_state.power_tile_pairs(self.head_dim, POWER_TILE)
+
+    @property
+    def power_state_width(self) -> int:
+        """Coordinates of the key's symmetric square as the state holds it:
+        ``POWER_TILE^2`` for each pair of tiles (128 in tiles of 16: 36 x 256 =
+        9 216; the least layout is 8 256, the full outer product 16 384)."""
+        return len(self.power_tile_pairs) * POWER_TILE ** 2
 
     def published_indices(self, layer_type: str) -> tuple:
         """The published index of each layer of ``layer_type``, in order."""
@@ -375,7 +405,8 @@ class ModelConfig:
 
 class RecurrentLayersUnsupported(NotImplementedError):
     """A feature that assumes every layer's per-request state is keys and
-    values was asked of a model with recurrent (Mamba-2 or lightning) layers.
+    values was asked of a model with recurrent (Mamba-2, Mamba-1, lightning or
+    power-retention) layers.
     Raised by name, never worked around: a recurrent state has no lanes to
     slice, mask or rewind."""
 
@@ -513,6 +544,14 @@ def check_hybrid(cfg: "ModelConfig") -> None:
                              "and an even lightning_head_dim >= 2 (q and k are rotated)")
         if depth < 2:
             raise ValueError(f"a 'lightning' layer's decay needs a published depth >= 2, got {depth}")
+    if "power_retention" in cfg.layer_types:
+        if set(cfg.layer_types) != {"power_retention"} or cfg.is_moe:
+            raise ValueError("'power_retention' layers stand in a stack of their own, with a dense MLP "
+                             f"(layer_types={sorted(set(cfg.layer_types))}, n_experts={cfg.n_experts})")
+        if cfg.head_dim % POWER_TILE or cfg.n_kv_heads < 1 or cfg.n_heads % cfg.n_kv_heads:
+            raise ValueError(
+                f"a 'power_retention' layer needs head_dim={cfg.head_dim} in whole tiles of {POWER_TILE} "
+                f"and n_heads={cfg.n_heads} a multiple of n_kv_heads={cfg.n_kv_heads}")
     if "sparse_attention" in cfg.layer_types:
         size, stride, block = cfg.sparse_kernel_size, cfg.sparse_kernel_stride, cfg.sparse_block_size
         if stride < 1 or size % stride or block % stride or size > block:
@@ -829,6 +868,7 @@ def _init_hybrid_params(rng: jax.Array, cfg: ModelConfig, dtype, deferred: bool 
     stacks = {"attn": attn, "ssm": ssm,
               "sparse_attn": partial(_init_sparse_attn_stack, rng, cfg, dtype, deferred),
               "lightning": partial(_init_lightning_stack, rng, cfg, dtype, deferred),
+              "power": partial(_init_power_stack, rng, cfg, dtype, deferred),
               "mla": partial(_init_mla_stack, rng, cfg, dtype, deferred, "mla"),
               "mla_dense": partial(_init_mla_stack, rng, cfg, dtype, deferred, "mla_dense"),
               **{kind: partial(_init_dhd_stack, rng, cfg, dtype, deferred, kind) for kind in DHD_FOLD}}
@@ -973,6 +1013,43 @@ def _init_lightning_stack(rng, cfg: ModelConfig, dtype, deferred: bool = False) 
     }
 
 
+# Half-lives a power-retention gate's bias is drawn for, in tokens.
+POWER_HALF_LIFE = (64.0, 16384.0)
+
+
+def _init_power_stack(rng, cfg: ModelConfig, dtype, deferred: bool = False) -> dict:
+    """The power-retention layers: q over ``n_heads``, k and v over
+    ``n_kv_heads``, o, per-head q/k norm scales, and the gate: ``g_proj``
+    ``[D, KV]`` (normal(0.02) as the others: a standard deviation of about 1.4
+    in the gate's logit) and ``g_bias`` ``[KV]`` float32 ``= logit(2^(-1/tau))``
+    with the half-life ``tau`` of each head of each layer log-uniform over
+    :data:`POWER_HALF_LIFE` tokens, so that a seeded state remembers as a
+    trained one does. Keys: ``split(fold_in(rng, 110), 9)`` in the order q, k,
+    v, g_proj, o, gate, up, down, g_bias; each leaf's layer i from its own
+    ``split(key, n)[i]`` (:func:`_draw_layers`); outputs / sqrt(2 x layers
+    kept)."""
+    n = cfg.n_layers_of("power_retention")
+    D, H, KV, HD = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    ks = jax.random.split(jax.random.fold_in(rng, 110), 9)
+    res_std = 0.02 / (2 * cfg.n_layers) ** 0.5
+    draw = partial(_drawn, deferred, _draw_layers, n=n, dtype=dtype)
+    lo, hi = (math.log(t) for t in POWER_HALF_LIFE)
+    tau = jnp.exp(jax.vmap(lambda k: jax.random.uniform(k, (KV,), jnp.float32, lo, hi))(
+        jax.random.split(ks[8], n)))
+    return {
+        "attn_norm": {"scale": jnp.ones((n, D), dtype)},
+        "q": {"kernel": draw(ks[0], 0.02, shape=(D, H * HD))},
+        "k": {"kernel": draw(ks[1], 0.02, shape=(D, KV * HD))},
+        "v": {"kernel": draw(ks[2], 0.02, shape=(D, KV * HD))},
+        "g_proj": {"kernel": draw(ks[3], 0.02, shape=(D, KV))},
+        "g_bias": -jnp.log(jnp.expm1(math.log(2.0) / tau)),        # logit(2^(-1/tau)), as 1 / (2^(1/tau) - 1)
+        "o": {"kernel": draw(ks[4], res_std, shape=(H * HD, D))},
+        "q_norm": {"scale": jnp.ones((n, HD), dtype)},
+        "k_norm": {"scale": jnp.ones((n, HD), dtype)},
+        **_mixer_mlp_stack(draw, ks[5:8], n, cfg, dtype, deferred),
+    }
+
+
 def _init_mla_stack(rng, cfg: ModelConfig, dtype, deferred: bool, layer_type: str) -> dict:
     """The latent-attention layers of ``layer_type`` ("mla": the stack's block
     after the mixer, a mixture where it has experts; "mla_dense": one dense
@@ -1103,7 +1180,7 @@ def _init_dhd_stack(rng, cfg: ModelConfig, dtype, deferred: bool, kind: str) -> 
 # moves every decay, and a sigmoid router's selection bias is added to
 # float32 scores.
 SSM_FLOAT32_LEAVES = ("A_log", "dt_bias", "D")
-FLOAT32_LEAVES = SSM_FLOAT32_LEAVES + ("decay", "router_bias", "lambdas", "lambda_init")
+FLOAT32_LEAVES = SSM_FLOAT32_LEAVES + ("decay", "router_bias", "lambdas", "lambda_init", "g_bias")
 
 
 def _mlp_axes(cfg: ModelConfig) -> dict[str, Any]:
@@ -1173,6 +1250,13 @@ def logical_axes(cfg: ModelConfig) -> dict[str, Any]:
                 "v": {"kernel": ("layers", "embed", "kv_heads")},
                 "o": {"kernel": ("layers", "heads", "embed")},
                 **mlp_axes,
+            },
+            "power": {
+                "k": {"kernel": ("layers", "embed", "kv_heads")},
+                "v": {"kernel": ("layers", "embed", "kv_heads")},
+                "g_proj": {"kernel": ("layers", "embed", None)},
+                "g_bias": ("layers", None),
+                **{name: axes for name, axes in mixer_axes.items() if name != "o_gate"},
             },
             # The mixer's fused projection (z | x | B | C | dt) has no
             # head-aligned split to shard: its width stays whole.
@@ -1303,6 +1387,8 @@ def param_count(cfg: ModelConfig) -> int:
         per_sparse = 2 * D * H * HD + 2 * D * KV * HD + H * HD * D + 2 * HD + mlp + 2 * D
         LI, LHD = cfg.lightning_inner, cfg.lightning_head_dim
         per_lightning = 5 * D * LI + 2 * LHD + LI + cfg.lightning_heads + mlp + 2 * D
+        # q, k, v, o, the gate's kernel and bias, the q/k norms; the two norms and MLP.
+        per_power = 2 * D * H * HD + 2 * D * KV * HD + D * KV + KV + 2 * HD + mlp + 2 * D
         # q, kv_a, the latent's norm, kv_b, o and the layer's two norms; then
         # the stack's block (with a sigmoid router's bias) or the dense SwiGLU.
         C, R = cfg.kv_latent_dim, cfg.qk_rope_dim
@@ -1330,6 +1416,7 @@ def param_count(cfg: ModelConfig) -> int:
         return (dhd + V * D + cfg.n_attn_layers * per_layer + cfg.n_ssm_layers * per_ssm
                 + cfg.n_layers_of("sparse_attention") * per_sparse
                 + cfg.n_layers_of("lightning") * per_lightning
+                + cfg.n_layers_of("power_retention") * per_power
                 + cfg.n_layers_of("mla") * (mla + mlp + bias)
                 + cfg.n_layers_of("mla_dense") * (mla + 3 * D * cfg.dense_d_ff) + D + head)
     return V * D + L * per_layer + D + head

@@ -17,6 +17,12 @@ scalar memory and the block index maps pick that layer's blocks: nothing is
 sliced out of the stack or pasted back into it (each would be a pass of its
 own), and the other layers' blocks are never visited.
 
+Which whole kind runs which update (``layer_state.LAYER_KINDS``): ``ssm``
+(Mamba-2) and ``lightning`` this kernel, through ``generate._ssd_step_at``;
+``power`` (gated power retention: a state per kv-head that several query heads
+read through an expansion formed in the kernel) ``ops.power_update``;
+``mamba1`` XLA's step (its state is ``[N, I]``, no ``[P, N]`` tile).
+
 One device's state only. Every caller that would shard a recurrent state
 (mesh-sharded serving, ``disagg``, ``spec_pool``) refuses the stack by name
 before it gets here (``transformer.refuse_recurrent``), so the kernel carries no

@@ -74,6 +74,7 @@ from tpu_engine.generate import (
     scan_layers,
 )
 from tpu_engine.models.transformer import (
+    POWER_TILE,
     ModelConfig,
     check_hybrid,
     embed_tokens,
@@ -83,7 +84,7 @@ from tpu_engine.models.transformer import (
     unembed,
     weight_bytes_by_dtype,
 )
-from tpu_engine.ops import lane_decode, ssd_update
+from tpu_engine.ops import lane_decode, power_update, ssd_update
 from tpu_engine.profiler import StepProfiler
 
 
@@ -275,6 +276,22 @@ def lane_walk_layers(cfg: ModelConfig, cache: SlotCache) -> int:
     if keys is None or cache.ring or cache.sharded or not lane_walk_engages(keys, 1, cfg):
         return 0
     return keys.shape[0]
+
+
+def in_place_update_layers(cfg: ModelConfig, cache: SlotCache) -> int:
+    """Layers of ``cache``'s whole kinds whose decode step a one-pass kernel
+    takes, decided as the trace decides (the leaf and the device): a
+    power-retention state through ``ops.power_update``, a Mamba-2 or lightning
+    state through ``ops.ssd_update``; 0 where the walk keeps the XLA step."""
+    n = 0
+    for kind, leaves in cache.layers.items():
+        if layer_state.LAYER_KINDS[kind].positional:
+            continue
+        if kind == "power":
+            n += leaves["state"].shape[0] * power_update.engages(leaves["state"], POWER_TILE)
+        else:
+            n += sum(leaf.shape[0] for leaf in leaves.values() if ssd_update.engages(leaf))
+    return n
 
 
 def _pick_tokens(
@@ -952,14 +969,14 @@ class ContinuousBatcher:
         self._shared_kv_bytes = layer_state.lane_bytes(self._cache.layers, "full_attn") \
             if cfg.cross_decoder_start is not None else 0
         self._window_kv_bytes = layer_state.ring_bytes(self._cache.layers)
-        # Layers of the whole kinds whose decode step the one-pass kernel
-        # takes (``ops.ssd_update``: decided where the program is traced, from
-        # the leaf and the device); 0 where the walk keeps the XLA step.
-        self._in_place_layers = sum(
-            leaf.shape[0] for kind, leaves in self._cache.layers.items()
-            if not layer_state.LAYER_KINDS[kind].positional
-            for leaf in leaves.values() if ssd_update.engages(leaf))
+        # Layers of the whole kinds whose decode step a one-pass kernel takes
+        # (``ops.ssd_update``, ``ops.power_update``: decided where the program
+        # is traced, from the leaf and the device); 0 where the walk keeps the
+        # XLA step. And the power-retention layers' decode layer-steps run.
+        self._in_place_layers = in_place_update_layers(cfg, self._cache)
         self._recurrent_updates_in_place = 0
+        self._power_layers = cfg.n_layers_of("power_retention")
+        self._power_layer_steps = 0
         # ``attn`` layers whose decode step reads the pool through the
         # lane-walking kernel (``ops.lane_decode``; a speculative engine's
         # target never steps one token), the lanes of keys it read there (and
@@ -1340,12 +1357,13 @@ class ContinuousBatcher:
                 # prefilling, queued or awaiting a handoff.
                 "idle_waits_total": self._idle_waits,
                 "idle_waits_with_work_total": self._idle_waits_with_work,
-                # The pool's whole kinds of state (Mamba-2, lightning; 0
-                # for attention-only stacks): their bytes, every slot's
-                # whether in use or not, and how often a slot's was
-                # written whole or zeroed; and the decode layer-steps that
-                # updated it in place, in one pass (the kernel
-                # ``ops.ssd_update``; 0 where the walk keeps the XLA step).
+                # The pool's whole kinds of state (Mamba-2, Mamba-1, lightning,
+                # power retention; 0 for attention-only stacks): their bytes,
+                # every slot's whether in use or not, and how often a slot's
+                # was written whole or zeroed; and the decode layer-steps that
+                # updated it in place, in one pass (the kernels
+                # ``ops.ssd_update`` and ``ops.power_update``; 0 where the walk
+                # keeps the XLA step).
                 "recurrent_state_bytes": self._recurrent_state_bytes,
                 # What the latent-attention (MLA) layers cache, every slot's
                 # every lane (0 for a stack that has none).
@@ -1355,6 +1373,9 @@ class ContinuousBatcher:
                 "shared_kv_bytes": self._shared_kv_bytes,
                 "window_kv_bytes": self._window_kv_bytes,
                 "recurrent_updates_in_place_total": self._recurrent_updates_in_place,
+                # Decode layer-steps of power-retention layers run, by kernel
+                # or not (0 for a stack that has none).
+                "power_layer_steps_total": self._power_layer_steps,
                 # Monotonic, per decode step and ``attn`` layer that reads the
                 # pool through the lane-walking kernel (``ops.lane_decode``):
                 # the lanes of keys it fetched (whole blocks of 512 up to each
@@ -1703,6 +1724,7 @@ class ContinuousBatcher:
         n_steps = toks_host.shape[1]
         self._decode_tokens_computed += len(active_reqs) * n_steps
         self._recurrent_updates_in_place += n_steps * self._in_place_layers
+        self._power_layer_steps += n_steps * self._power_layers
         self._decode_attn_lanes_read += lanes_read
         self._decode_attn_lanes_pool += (n_steps * self._lane_walk_layers
                                          * self.max_slots * self._pool_lanes)
