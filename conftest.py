@@ -11,6 +11,8 @@ environment names another (a TPU host sets ``JAX_PLATFORMS=tpu,cpu``).
 """
 
 import os
+import sys
+import threading
 
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
@@ -37,3 +39,24 @@ def _clear_jax_caches_per_module():
     only cost is losing cross-module cache hits that barely exist."""
     yield
     jax.clear_caches()
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _stop_scheduler_pumps_per_module():
+    """Stop the scheduler pumps a module leaves running.
+
+    A test that builds a ``TPULauncher`` and does not shut its scheduler
+    down leaves the ``fleet-scheduler`` thread polling for the rest of the
+    worker's life (five modules do), and every pass writes the queue's
+    depths into whatever historian is installed process-wide. The twin's
+    scale lane installs its own and counts its samples, so
+    ``tests/test_twin.py`` failed whenever xdist dealt it to a worker after
+    one of those modules (PR 48: three whole runs of four). The backend's
+    singleton is left alone: later modules submit through it."""
+    yield
+    state = sys.modules.get("backend.state")
+    keep = getattr(getattr(state, "launcher", None), "scheduler", None)
+    for t in threading.enumerate():
+        sched = getattr(getattr(t, "_target", None), "__self__", None)
+        if t.name == "fleet-scheduler" and sched is not None and sched is not keep:
+            sched.shutdown()

@@ -253,9 +253,16 @@ def test_generate_converts_a_float32_tree_at_its_entry():
 # ``_served_values``: greedy tokens of two requests, and four logits
 # [0, 1, 255, 511] of a prefill chunk's row and of a decode step's row.
 PARENT = {
+    # ``prefill_row`` of dense and moe re-recorded in PR 48, whose prefill chunk
+    # contracts the head with its one row alone ([1, D] x [D, V]), which the CPU
+    # sums in another order than that row of the [T, V] product: the same
+    # products, float32 rounding. b04a573's were 0.20951591432094574,
+    # -0.013773605227470398, -0.056673794984817505, -0.2290504276752472 (dense) and
+    # PR 35's -0.0733594223856926 at [255] (moe): within 1.1e-7 relative of these.
+    # Tokens, ``decode_row`` and all of hybrid are as they were.
     "dense": {
         "tokens": [[98, 403, 233, 79, 308, 374, 309, 443], [497, 376, 469, 472, 88, 409, 350, 224]],
-        "prefill_row": [0.20951591432094574, -0.013773605227470398, -0.056673794984817505, -0.2290504276752472],
+        "prefill_row": [0.20951589941978455, -0.013773605227470398, -0.056673791259527206, -0.229050412774086],
         "decode_row": [0.07227375358343124, 0.3225421607494354, -0.12000519037246704, -0.05271732062101364],
     },
     # Re-recorded in PR 35, whose mixture contracts over expert and width
@@ -267,7 +274,7 @@ PARENT = {
     # -0.11569535732269287, -0.040707044303417206 (decode): within 3e-4 of these.
     "moe": {
         "tokens": [[98, 403, 233, 79, 308, 374, 309, 443], [497, 376, 380, 12, 92, 465, 314, 84]],
-        "prefill_row": [0.1878596395254135, -0.010642990469932556, -0.0733594223856926, -0.23365341126918793],
+        "prefill_row": [0.1878596395254135, -0.010642990469932556, -0.0733594298362732, -0.23365341126918793],
         "decode_row": [0.05209147185087204, 0.31985390186309814, -0.11561896651983261, -0.040776386857032776],
     },
     "hybrid": {

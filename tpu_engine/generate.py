@@ -1701,11 +1701,16 @@ def forward_with_cache(
     ``want_logits=False`` (static) skips the unembed entirely and returns
     ``(None, cache)`` — cache-ingestion-only callers (the speculative
     draft's prompt prefill) should not pay a T×D×V matmul per chunk.
-    A decoder-hybrid-decoder stack's prompt takes two programs
+    ``logits_row`` (traced index into the chunk), for ANY stack: the final
+    norm and the head run at that position alone, logits [B, 1, V] — a
+    prefill chunk's caller reads one row, and a dynamic row slice of
+    [B, T, V] does not move in front of the contraction by itself. Every
+    layer still runs at every position (the cache is the full walk's), except
+    in a decoder-hybrid-decoder stack, whose prompt takes two programs
     (:func:`scan_layers`): ``ingest_only`` (static) for a chunk that is not
     its last — the self-decoder and the shared layer's keys and values,
-    ``(None, cache)`` — and ``logits_row`` (traced index) for its last: the
-    cross-decoder and the head at that position alone, logits [B, 1, V].
+    ``(None, cache)`` — and ``logits_row`` for its last, where the
+    cross-decoder too runs at that position alone.
 
     For non-ring caches the caller must keep ``cache.length + T <=
     cache.max_len`` (size the cache to prompt + max_new_tokens, as
@@ -1778,10 +1783,14 @@ def forward_with_cache(
     # mask, so the Mamba-2 layers stop at ``n_valid``.
     valid = jnp.broadcast_to(
         jnp.arange(T)[None, :] < (T if n_valid is None else n_valid), (B, T))
+    # Only a stack with a cross-decoder walks part of its layers at one row.
+    tail = logits_row is not None and cfg.cross_decoder_start is not None
     x, cache = scan_layers(x, params["layers"], cfg,
                            cache, write, pos_new, positions, valid,
                            **({"ingest_only": True} if ingest_only else {}),
-                           **({} if logits_row is None else {"tail_row": logits_row}))
+                           **({"tail_row": logits_row} if tail else {}))
+    if logits_row is not None and not tail:
+        x = lax.dynamic_slice_in_dim(x, logits_row, 1, 1)
     logits = unembed(params, x, cfg) if want_logits and not ingest_only else None
     return logits, dataclasses.replace(cache, pos=pos_new,
                                        length=cache.length + T)

@@ -956,9 +956,10 @@ class ContinuousBatcher:
         # how often block choice, not only the causal bound, engages.
         self._prefill_tokens_computed = 0
         self._prefill_tokens_sparse = 0
-        # Of those positions, the ones that ran a cross-decoder (a prompt's
-        # last alone, where the stack has one; 0 for a stack that has none).
-        self._prefill_positions_cross = 0
+        # Of those positions, the ones a prefill program ran the output head
+        # for: one a chunk that forms logits (a stack with a cross-decoder
+        # runs that at the same position alone).
+        self._prefill_head_rows = 0
         # Recurrent state written into a slot by a finished prefill, and
         # zeroed when a slot is freed (hybrid stacks; else both stay 0).
         self._recurrent_state_bytes = self._cache.recurrent_state_bytes
@@ -1347,11 +1348,16 @@ class ContinuousBatcher:
                 "decode_tokens_emitted_total": self._decode_tokens_emitted,
                 "decode_tokens_sparse_total": self._decode_tokens_sparse,
                 "prefill_tokens_computed_total": self._prefill_tokens_computed,
+                # The rows (positions) the prefill programs ran the output
+                # head for: one a chunk that forms logits, of its
+                # ``prefill_chunk`` positions.
+                "prefill_head_rows_total": self._prefill_head_rows,
                 "prefill_tokens_sparse_total": self._prefill_tokens_sparse,
                 # Of ``prefill_tokens_computed_total``, the positions that ran
                 # a cross-decoder (a decoder-hybrid-decoder stack needs it at
                 # a prompt's last position only).
-                "prefill_positions_cross_decoder_total": self._prefill_positions_cross,
+                "prefill_positions_cross_decoder_total":
+                    self._prefill_head_rows if self._ingest_fn is not None else 0,
                 # Monotonic: waits the driving loop took between two steps
                 # (phase ``idle``), and those entered with a prompt still
                 # prefilling, queued or awaiting a handoff.
@@ -1518,7 +1524,7 @@ class ContinuousBatcher:
             last_row, st.c1 = self._prefill_fn(
                 self.params, chunk, st.c1, jnp.asarray(row, jnp.int32), n_valid
             )
-            self._prefill_positions_cross += self._ingest_fn is not None  # the prompt's last position alone
+            self._prefill_head_rows += 1  # the head (and a cross-decoder) runs at ``row`` alone
         if st.dc1 is not None:  # speculative: the draft ingests the prompt too
             st.dc1 = self._draft_prefill_fn(self._draft_params, chunk, st.dc1)
         st.consumed = t1
@@ -1952,19 +1958,15 @@ class ContinuousBatcher:
 def _prefill_forward(params, toks, cache, row_idx, n_valid=None, *, cfg,
                      compute_dtype):
     """One prefill chunk through the stock cached forward; returns only the
-    requested logits row (the [V] vector that seeds the first token) — on a
-    mesh this avoids all-gathering the full [T, V] logits per chunk.
+    requested logits row (the [V] vector that seeds the first token). The
+    final norm and the head run at that row alone (``logits_row``): no
+    [T, V] logits are formed, nor all-gathered on a mesh. A stack with a
+    cross-decoder runs that too at the one position.
     ``n_valid``: the chunk's real tokens (the rest pads the prompt to its
     bucket), which only a hybrid stack's recurrent layers need."""
-    if cfg.cross_decoder_start is not None:
-        # the cross-decoder and the head at the one position whose logits are wanted
-        logits, cache = forward_with_cache(params, toks, cache, cfg, compute_dtype=compute_dtype,
-                                           n_valid=n_valid, logits_row=row_idx)
-        return logits[0, 0], cache
-    logits, cache = forward_with_cache(params, toks, cache, cfg,
-                                       compute_dtype=compute_dtype,
-                                       n_valid=n_valid)
-    return logits[0, row_idx], cache
+    logits, cache = forward_with_cache(params, toks, cache, cfg, compute_dtype=compute_dtype,
+                                       n_valid=n_valid, logits_row=row_idx)
+    return logits[0, 0], cache
 
 
 def _prefill_ingest(params, toks, cache, n_valid=None, *, cfg, compute_dtype):
