@@ -21,8 +21,22 @@ needs; the XLA form is held to the same count, which is why it reads so low).
 pipeline with the arithmetic taken out (the blocks still copied into fast
 memory, the grid still walked).
 
-Run on the chip: ``python benchmarks/decode_attn_probe.py --ceiling``.
-Refuses to time anything off the TPU.
+``--split`` times the kernel's call alone instead (no write, no XLA form; one
+layer's pool, 32 calls a dispatch as a scan, each with queries of its own), at
+the shapes above AND the differential caller's (``SPLIT_SHAPES``:
+phi-4-mini-flash.serve-reason32's ONE shared cache, 32 slots x 12 288 lanes x
+ten column groups of 128, and a window layer's ring of 512 lanes), with every
+slot idle, at one block a slot, at the contexts the cell's window holds and
+with every slot full; and an idle call over half the lanes, which under a
+grid of a step for every block the leaf holds had half the grid steps and
+nothing else less (under the grid bounded by the blocks in use, PR 49's, it
+reads what the whole leaf's reads). From these it solves what a call is made
+of: microseconds a block the leaf holds and no slot uses, a block, and a live
+slot over an idle one (its reset, division, write-back, query fetch and
+whatever of its first block's copy nothing hides).
+
+Run on the chip: ``python benchmarks/decode_attn_probe.py --ceiling`` or
+``--split``. Refuses to time anything off the TPU.
 """
 
 from __future__ import annotations
@@ -44,6 +58,13 @@ SHAPES = {
     "granite-4.0-h-small.serve-batch32": (32, 2048, 8, 128, 4),
     "granite-4.0-h-micro.serve-chat-burst": (32, 2048, 8, 64, 4),
 }
+# ``--split`` only (eight layers of such a pool do not fit the chip): (slots,
+# lanes, column groups of 128, query rows a group, the contexts of the cell's window)
+SPLIT_SHAPES = {
+    "phi-4-mini-flash.serve-reason32, the shared cache": (32, 12288, 10, 4, (5700, 7000)),
+    "phi-4-mini-flash.serve-reason32, a window layer's ring": (32, 512, 10, 4, (512, 512)),
+}
+CALLS = 32  # calls a dispatch of ``--split``
 
 
 def main():
@@ -52,6 +73,8 @@ def main():
     ap.add_argument("--live", default="1,2,4,all")
     ap.add_argument("--lengths", default="256,1024,2048")
     ap.add_argument("--ceiling", action="store_true")
+    ap.add_argument("--split", action="store_true", help="the kernel's call alone: idle, one block a slot, the "
+                    "window's contexts, full; and what a block, a block no slot uses and a slot cost")
     ap.add_argument("--seed", type=int, default=2147485003)
     ap.add_argument("--seconds", type=float, default=0.4, help="timed window a reading")
     args = ap.parse_args()
@@ -119,7 +142,8 @@ def main():
             calls += 3
         return 1e6 * took / (calls * LAYERS * STEPS), k, v
 
-    def pipeline_only(at_ref, n_ref, src_ref, lo_ref, hi_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, **_):
+    def pipeline_only(*refs, **_):
+        o_ref, acc_ref = refs[-4], refs[-1]          # ... q, k, v, o, m, l, acc
         o_ref[0] = jnp.zeros_like(acc_ref)
 
     def traced_with(body, fn):
@@ -132,8 +156,78 @@ def main():
                 lane_decode._kernel = kernel
         return call
 
+    def chosen(name):
+        return not args.cells or any(c in name for c in args.cells.split(","))
+
+    def split(name, B, M, P, R, window):
+        """The call alone at four fills of one layer's pool, and the three costs they imply."""
+        ks = jax.random.split(jax.random.PRNGKey(args.seed % (2 ** 31)), 3)
+        q = jax.random.normal(ks[0], (CALLS, B, P, R, lane_decode.COLUMNS), bf16)
+        k = jax.random.normal(ks[1], (1, B, M, P * lane_decode.COLUMNS), bf16)
+        v = jax.random.normal(ks[2], (1, B, M, P * lane_decode.COLUMNS), bf16)
+
+        @jax.jit
+        def calls(q, k, v, visible):
+            # every call derives its tables from lengths of its own, as a layer of the model does
+            return lax.map(lambda xs: lane_decode.lane_decode(xs[0], k, v, 0, xs[1], scale=0.09, name="diff_decode"),
+                           (q, visible))
+
+        def us_a_call(k, v, visible):
+            visible = jnp.asarray(np.broadcast_to(visible, (CALLS, B)))   # an operand: nothing hoists the tables
+            for _ in range(2):
+                out = calls(q, k, v, visible)
+            jax.block_until_ready(out)
+            n, t0 = 0, time.perf_counter()
+            while (took := time.perf_counter() - t0) < args.seconds:
+                for _ in range(3):
+                    out = calls(q, k, v, visible)
+                jax.block_until_ready(out)
+                n += 3
+            return 1e6 * took / (n * CALLS)
+
+        typical = np.linspace(*window, B).astype(np.int32)
+        fills = {"idle": np.zeros(B, np.int32), "one block a slot": np.full(B, lane_decode.LANES, np.int32),
+                 "the window's contexts": typical, "full": np.full(B, M, np.int32)}
+        G, us_of = B * (M // lane_decode.LANES), {}
+        walked = lambda visible: int((-(-visible // lane_decode.LANES)).sum())  # noqa: E731
+        for what, visible in fills.items():
+            us_of[what] = us = us_a_call(k, v, visible)
+            live_bytes = int(visible.sum()) * P * lane_decode.COLUMNS * 2 * 2
+            print(json.dumps({"cells": name, "what": "split", "fill": what, "blocks_walked": walked(visible),
+                              "blocks_held": G, "us_a_call": round(us, 2),
+                              "pct_of_819_gb_s_live_bytes": round(100 * live_bytes / HBM_BYTES_PER_S / (us * 1e-6), 1),
+                              "device": dev.device_kind}), flush=True)
+        if G < 2 * B:
+            return      # one block a slot: the three live fills are one, and nothing can be told apart
+        # An idle call over half the lanes holds half the blocks and nothing else less: what a block that no slot
+        # uses costs (a grid step, where the grid has one for it). A block over that: full against one block a
+        # slot. A live slot over an idle one: the rest of one block a slot. What is left of an idle call is its
+        # launch, its tables and whatever an idle slot costs.
+        half = us_a_call(k[:, :, :M // 2], v[:, :, :M // 2], fills["idle"])
+        step = (us_of["idle"] - half) / (G / 2)
+        block = step + (us_of["full"] - us_of["one block a slot"]) / (G - B)
+        slot = (us_of["one block a slot"] - us_of["idle"]) / B - (block - step)
+        parts = {"blocks": walked(typical) * block, "blocks_not_in_use": (G - walked(typical)) * step,
+                 "slots": B * slot, "rest_of_an_idle_call": us_of["idle"] - G * step}
+        print(json.dumps({"cells": name, "what": "split, solved", "us_a_block": round(block, 3),
+                          "us_a_held_block_no_slot_uses": round(step, 3),
+                          "us_a_live_slot_over_an_idle_one": round(slot, 3),
+                          "idle_call_of_half_the_lanes_us": round(half, 2),
+                          "at_the_windows_contexts_us": {k_: round(x, 1) for k_, x in parts.items()},
+                          "sum_less_measured_us": round(sum(parts.values()) - us_of["the window's contexts"], 1),
+                          "device": dev.device_kind}), flush=True)
+
+    if args.split:
+        per_group = lambda KV, HD, G: (KV // max(128 // HD, 1), max(128 // HD, 1) * G)  # noqa: E731
+        shapes = {**{n: (B, M, *per_group(KV, HD, G), (M // 2, M // 2)) for n, (B, M, KV, HD, G) in SHAPES.items()},
+                  **SPLIT_SHAPES}
+        for name, shape in shapes.items():
+            if chosen(name):
+                split(name, *shape)
+        return
+
     for name, (B, M, KV, HD, G) in SHAPES.items():
-        if args.cells and not any(c in name for c in args.cells.split(",")):
+        if not chosen(name):
             continue
         cfg = ModelConfig(name="probe", vocab_size=8, d_model=KV * G * HD, n_layers=LAYERS, n_heads=KV * G,
                           n_kv_heads=KV, d_ff=8)

@@ -111,17 +111,131 @@ def test_the_kernel_equals_the_xla_contractions(monkeypatch, HD, G, at):
     assert a.shape == (B, cfg.n_heads * HD) and (a[~live] == 0).all() and (np.abs(a[live]).max(axis=1) > 0).all()
 
 
-def test_an_idle_slot_names_a_block_in_flight():
-    """The table an idle slot's index map reads: the last block of the live
-    slot before it, or block 0 of the first live slot (which the walk needs
-    next); a live slot walks its own blocks up to its last; an empty pool names
-    one block throughout."""
+def test_the_walk_is_the_live_blocks_back_to_back_and_the_tail_names_the_last():
+    """The tables the index maps read: slot 2's two blocks, slot 4's two, slot
+    6's one, back to back; an idle slot owns no step; every entry past the five
+    in use (the grid stops there) names the last of them again; an empty pool
+    names slot 0's block 0 throughout and counts no block."""
     visible = jnp.asarray([0, 0, 513, 0, 1024, 0, 1, 0], jnp.int32)
-    src, lo, hi = (np.asarray(a).tolist() for a in lane_decode.blocks_named(visible))
-    assert src == [2, 2, 2, 2, 4, 4, 6, 6]
-    assert lo == [0, 0, 0, 1, 0, 1, 0, 0] and hi == [0, 0, 1, 1, 1, 1, 0, 0]
-    src, lo, hi = (np.asarray(a).tolist() for a in lane_decode.blocks_named(jnp.zeros((4,), jnp.int32)))
-    assert src == [0] * 4 and lo == [0] * 4 and hi == [0] * 4
+    slot_of, block_of, blocks = (np.asarray(a).tolist() for a in lane_decode.walk_tables(visible, 16))
+    assert blocks == 5
+    assert slot_of == [2, 2, 4, 4, 6] + [6] * 11 and block_of == [0, 1, 0, 1, 0] + [0] * 11
+    slot_of, block_of, blocks = (np.asarray(a).tolist() for a in lane_decode.walk_tables(jnp.asarray([1024, 1024]), 4))
+    assert blocks == 4 and slot_of == [0, 0, 1, 1] and block_of == [0, 1, 0, 1]       # no tail at all
+    slot_of, block_of, blocks = (np.asarray(a).tolist() for a in lane_decode.walk_tables(jnp.zeros((4,), jnp.int32), 8))
+    assert blocks == 0 and slot_of == [0] * 8 and block_of == [0] * 8
+
+
+# what each slot sees (0: idle) of a pool of ``lanes``; the walk is one sequence over all of them
+WALKS = {
+    "every-slot-live-unequal": (1536, (1, 1536, 700, 512, 1025, 90)),
+    "idle-slots-first": (1024, (0, 0, 0, 600, 1024, 3)),
+    "idle-slots-last": (1024, (1024, 5, 513, 0, 0, 0)),
+    "idle-slots-interleaved": (1024, (0, 1000, 0, 0, 17, 0, 1024, 0)),
+    "all-idle": (1024, (0, 0, 0, 0)),
+    "one-live-slot-last": (1024, (0, 0, 0, 777)),
+    "a-ring-one-block-a-slot": (512, (512, 1, 512, 300, 0, 512)),
+    "slots-at-a-blocks-edge": (1536, (512, 1024, 1536, 511, 513, 1023)),
+}
+
+
+def _two_dimensional_walk(q, keys, values, layer, visible, *, scale):
+    """The kernel as it was before the one walk (PR 44's): a program a slot,
+    ``lanes // 512`` grid steps each, a step past the slot's length naming its
+    last block again. The same arithmetic a block in the same order within a
+    slot, so the one walk must give its bits; interpreted."""
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from tpu_engine.ops.mla_decode import _fold, _reset
+
+    LANES = lane_decode.LANES
+    _, B, S, _ = keys.shape
+    P, R, W = q.shape[1:]
+    rows = -(-R // 16) * 16
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, rows - R), (0, 0)))
+    visible = jnp.clip(visible.astype(jnp.int32), 0, S)
+
+    def kernel(at_ref, n_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
+        b, j = pl.program_id(0), pl.program_id(1)
+        n = n_ref[b]
+
+        @pl.when(j == 0)
+        def _():
+            _reset(m_ref, l_ref, acc_ref)
+
+        @pl.when(j * LANES < n)
+        def _():
+            for i in range(P):
+                cols = slice(i * W, (i + 1) * W)
+                s = lax.dot_general(q_ref[0, i], k_ref[0, 0, :, cols], (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+                lane = j * LANES + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                _fold(jnp.where(lane < n, s, -1e30), v_ref[0, 0, :, cols], m_ref.at[i], l_ref.at[i], acc_ref.at[i])
+
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _():
+            o_ref[0] = jnp.where(n > 0, acc_ref[...] / l_ref[...], 0.0)
+
+    def rows_map(b, j, at, n):
+        return (at[0], b, jnp.minimum(j, jnp.maximum(n[b] - 1, 0) // LANES), 0)
+
+    def own(b, j, *_):
+        return (b, 0, 0, 0)
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B, S // LANES),
+            in_specs=[pl.BlockSpec((1, P, rows, W), own), pl.BlockSpec((1, 1, LANES, P * W), rows_map),
+                      pl.BlockSpec((1, 1, LANES, P * W), rows_map)],
+            out_specs=pl.BlockSpec((1, P, rows, W), own),
+            scratch_shapes=[pltpu.VMEM((P, rows, 1), F32), pltpu.VMEM((P, rows, 1), F32),
+                            pltpu.VMEM((P, rows, W), F32)]),
+        out_shape=jax.ShapeDtypeStruct((B, P, rows, W), F32), interpret=True,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), visible, q, keys, values)[:, :, :R]
+
+
+def _contractions(q, keys, values, layer, visible, *, scale):
+    """The plain statement: scores over every lane, masked past ``visible``, one softmax, per column group."""
+    B, P, R, W = q.shape
+    k, v = (a[layer].reshape(B, -1, P, W) for a in (keys, values))
+    s = jnp.einsum("bprw,bmpw->bprm", q, k, preferred_element_type=F32) * scale
+    mask = jnp.arange(k.shape[1])[None, :] < visible[:, None]
+    p = jax.nn.softmax(jnp.where(mask[:, None, None, :], s, -1e30), axis=-1).astype(BF16)
+    return jnp.einsum("bprm,bmpw->bprw", p, v, preferred_element_type=F32)
+
+
+@pytest.mark.parametrize("heads", ["one-head-of-128", "two-heads-of-64"])
+@pytest.mark.parametrize("walk", list(WALKS))
+def test_the_one_walk_over_all_slots(monkeypatch, walk, heads):
+    """The kernel alone over pools whose slots differ in every way the walk
+    can: against XLA's contractions (to ``TOL``), an idle slot's rows exactly
+    zeros, and BIT FOR BIT what the two-dimensional walk before it gave (the
+    order of a slot's blocks is the same, and a slot never sees another's).
+    Two heads of 64 lie side by side in a column group, each row zero outside
+    its own head's columns (``_grouped_queries``)."""
+    monkeypatch.setattr(lane_decode, "INTERPRET_OFF_TPU", True)
+    lanes, visible = WALKS[walk]
+    cfg = _cfg(128, 2) if heads == "one-head-of-128" else _cfg(64, 2, KV=4)
+    B, width = len(visible), cfg.n_kv_heads * cfg.head_dim
+    rng = np.random.default_rng([len(walk), lanes, width])
+    keys, values = (jnp.asarray(rng.normal(size=(2, B, lanes, width)), BF16) for _ in range(2))
+    q = _grouped_queries(jnp.asarray(rng.normal(size=(B, cfg.n_heads, cfg.head_dim)), BF16), cfg)
+    visible = jnp.asarray(visible, jnp.int32)
+    assert lane_decode.engages(keys)
+    scale = cfg.head_dim ** -0.5
+    got = np.asarray(lane_decode.lane_decode(q, keys, values, 1, visible, scale=scale, name="attn_decode"))
+    live = np.asarray(visible) > 0
+    assert got.shape == q.shape and np.isfinite(got).all() and (got[~live] == 0).all()
+    before = np.asarray(_two_dimensional_walk(q, keys, values, 1, visible, scale=scale))
+    assert np.array_equal(got, before)
+    if live.any():
+        want = np.asarray(_contractions(q, keys, values, 1, visible, scale=scale))
+        own_head = np.asarray(q != 0)[live]                 # a row's own head's columns; the rest is not read
+        assert np.max(np.abs(got[live] - want[live])[own_head]) < TOL * np.max(np.abs(want[live][own_head]))
+        assert (np.abs(got[live]).max(axis=(1, 2, 3)) > 0).all()
 
 
 # (b) the batcher end to end ---------------------------------------------------------------------
@@ -201,7 +315,39 @@ def test_the_counters_add_up_for_one_request(monkeypatch):
     stats = srv.stats()
     assert stats["decode_attn_lanes_read_total"] == 3 * 2 * 2 * 512
     assert stats["decode_attn_lanes_pool_total"] == 3 * 2 * 2 * 3 * M
+    # the one walk of a call: one block of the one live slot, and a grid of that one step (the leaf holds 3 x 2)
+    assert stats["decode_attn_blocks_walked_total"] == 3 * 2 * 2 * 1
+    assert stats["decode_attn_grid_steps_total"] == 3 * 2 * 2 * 1
+    assert srv._attn_blocks_walked([]) == (0, 2 * 2)      # a call with no block still takes a step, which does nothing
+    assert srv._attn_blocks_walked([512, 513]) == (2 * (1 + 2 + 2 + 2), 2 * (1 + 2 + 2 + 2))
     assert srv._attn_lanes_read([512]) == 2 * (512 + 1024) and srv._attn_lanes_read([M, 2 * M]) == 2 * 4 * M
+
+
+def test_the_walks_counters_for_three_requests_are_a_hand_count(monkeypatch):
+    """Three requests at once, 2 steps a dispatch, 2 layers, 3 slots x 1 024
+    lanes. A request's first token comes from its prefill and the rest from
+    whole dispatches, whoever decodes beside it, and a slot that does not decode
+    owns no block, so the blocks walked are each request's own: prompt 5, 5
+    tokens: 4 steps seeing 6..9 lanes, a block each; prompt 510, 5 tokens: 4
+    steps seeing 511, 512, 513, 514 lanes: 1 + 1 + 2 + 2; prompt 600, 4 tokens:
+    3 steps and the one that overshoots, two blocks each. A call's grid is its
+    blocks (no call here is empty), and the blocks are PR 44's lanes read."""
+    monkeypatch.setattr(lane_decode, "INTERPRET_OFF_TPU", True)
+    mc = _mistral_tiny()
+    srv = ContinuousBatcher(tfm.init_params(jax.random.PRNGKey(0), mc, dtype=F32), mc, max_slots=3, max_len=M,
+                            compute_dtype=BF16, prefill_pad_to=16, chunk_steps=2, prefill_chunk=256)
+    rng = np.random.default_rng(5)
+    rids = [srv.submit(rng.integers(1, mc.vocab_size, n).tolist(), max_new_tokens=m)
+            for n, m in ((5, 5), (510, 5), (600, 4))]
+    for _ in range(50):
+        srv.step()
+        if all(srv.result(r)["status"] == "done" for r in rids):
+            break
+    stats = srv.stats()
+    assert stats["decode_attn_blocks_walked_total"] == 2 * ((1 + 1 + 1 + 1) + (1 + 1 + 2 + 2) + (2 + 2 + 2 + 2))
+    assert stats["decode_attn_grid_steps_total"] == stats["decode_attn_blocks_walked_total"]
+    assert stats["decode_attn_blocks_walked_total"] * 512 == stats["decode_attn_lanes_read_total"]
+    assert stats["decode_attn_lanes_read_total"] < stats["decode_attn_lanes_pool_total"]
 
 
 # (c) what `engages` declines, and that each declined case is still the reference ----------------

@@ -564,3 +564,43 @@ def test_the_decode_kernel_equals_the_xla_contractions(monkeypatch, window):
     assert lane_decode.engages(k_arr) and not lane_decode.engages(k_arr[:, :, :500])
     got = generate._diff_attention(q, k_arr, v_arr, jnp.int32(1), positions, lp, mc, window, "full_attn")
     assert np.abs(np.asarray(got, np.float32) - np.asarray(want, np.float32)).max() < 0.02
+
+
+def test_the_walks_counters_count_the_shared_caches_readers_and_the_rings(monkeypatch):
+    """Three requests through a stack whose reads engage the kernel (interpreted):
+    one window layer's ring of 512 lanes, ONE full-attention cache of 1 024 that
+    its own layer and the cross-attention layer after it read. A decode step is
+    three calls: the ring's (every slot shows it a block, a freed one its lane
+    0) and the shared cache's two (a row past 512 lanes walks two blocks, a freed
+    slot one). By hand, from the rows each dispatch decoded: a request's own
+    blocks whoever decodes beside it, and a block a call for every slot that does
+    not decode; a call's grid is bounded by its blocks, so it took as many steps."""
+    from tpu_engine.ops import lane_decode
+
+    monkeypatch.setattr(lane_decode, "INTERPRET_OFF_TPU", True)
+    mc = tfm.ModelConfig(name="k", vocab_size=64, d_model=512, n_layers=6, n_heads=8, n_kv_heads=4, d_ff=64,
+                         layer_types=("mamba1", "diff_window_attention", "mamba1", "diff_attention", "gmu",
+                                      "diff_cross_attention"),
+                         sliding_window=512, mamba1_inner=64, mamba1_state=4, layer_norm=True, attn_bias=True,
+                         rope=False, tie_head=True)
+    engine = serving.ContinuousBatcher(tfm.init_params(jax.random.PRNGKey(2), mc), mc, max_slots=3, max_len=1024,
+                                       compute_dtype=BF16, prefill_pad_to=8, prefill_chunk=512, chunk_steps=2)
+    assert sorted(serving.lane_walks(mc, engine._cache)) == [(1, 512, 1), (2, 1024, 1)]
+    shapes = [(20, 5), (510, 5), (600, 4)]          # (prompt, new tokens): the first comes from the prefill
+    rids = [engine.submit(_tokens(n, 40 + i).tolist(), max_new_tokens=m) for i, (n, m) in enumerate(shapes)]
+    steps, idle_slot_steps = 0, 0
+    while any(engine.result(r)["status"] != "done" for r in rids):
+        before = engine.stats()["decode_tokens_computed_total"]
+        engine.step()
+        computed = engine.stats()["decode_tokens_computed_total"] - before      # 2 steps x the rows that decoded
+        steps += 2 * (computed > 0)
+        idle_slot_steps += (2 * 3 - computed) * (computed > 0)
+        assert steps < 100
+    st = engine.stats()
+    assert st["decode_attn_lanes_read_total"] == 0          # the ``attn`` kind's counters: the stack has none
+    assert 0 < st["decode_attn_grid_steps_total"] == st["decode_attn_blocks_walked_total"]
+    assert st["decode_attn_grid_steps_total"] < steps * (1 * 3 * 1 + 2 * 3 * 2)     # the blocks the leaves hold
+    own = sum(1 + 2 * -(-(n + 1 + i) // 512)                # the ring's block and the shared cache's, twice
+              for n, m in shapes for i in range(-(-(m - 1) // 2) * 2))
+    assert own == 4 * 3 + (3 + 3 + 5 + 5) + 4 * 5
+    assert st["decode_attn_blocks_walked_total"] == own + 3 * idle_slot_steps

@@ -1393,6 +1393,16 @@ def _diff_combine(a, lp, cfg: ModelConfig, dtype):
         return d.reshape(B, T, P * G * 2 * HD).astype(dtype)
 
 
+def diff_walk_engages(k_arr, T: int, window: int) -> bool:
+    """Whether a differential kind's read of ``k_arr`` ``[L,B,M,KV x HD]``
+    goes through ``ops.lane_decode`` (decided from what the trace sees): one
+    query a row against a leaf of which a row sees every lane it has (a ring no
+    longer than the window, or no window), in whole blocks and column groups on
+    a TPU (``lane_decode.engages``)."""
+    M = k_arr.shape[2]
+    return T == 1 and M <= (window or M) and lane_decode.engages(k_arr)
+
+
 def _diff_attention(q, k_arr, v_arr, at, positions, lp, cfg: ModelConfig, window: int, scope: str):
     """:func:`_diff_attend` of q [B,T,H x HD] at ``positions`` [B,T] over layer
     ``at`` of ``k_arr`` / ``v_arr`` ``[L,B,M,KV x HD]``, whose lanes hold what
@@ -1409,7 +1419,7 @@ def _diff_attention(q, k_arr, v_arr, at, positions, lp, cfg: ModelConfig, window
     length = positions[:, -1] + 1
     Tq = min(T, DIFF_QUERY_BLOCK)
     S = min(M, Tq + window - 1) if window else M
-    if T == 1 and S == M and M <= (window or M) and lane_decode.engages(k_arr):
+    if diff_walk_engages(k_arr, T, window):
         # One query a row against the layer as it lies, the lanes a row has and
         # no others (of a ring no longer than the window: all it holds is seen).
         with jax.named_scope(scope):

@@ -1,6 +1,7 @@
 """A decode step's attention on the chip: one Pallas TPU kernel that reads a
 slot's keys and values where the pool holds them, once, and only the lanes the
-slot has. A slot that does not decode is neither read nor computed.
+slot has. A slot that does not decode is neither read nor computed, and a call
+pays for the blocks it walks, not for the slots it walks them for.
 
 A decode step (one query a slot) contracts each slot's query rows against the
 slot's keys as the pool stores them (``[lanes, KV x HD]``, whole rows; a
@@ -9,21 +10,30 @@ side), softmaxes over the lanes the slot has, and contracts the probabilities
 with the values. XLA's form of so few rows first copies the layer out of the
 carried pool (it does not stream a slice of the pool into the contraction's
 fusion), or transposes it so that the LANES lie minor, and then reads EVERY
-lane of EVERY slot. Here a program (one slot) walks the slot's lanes a block at
-a time, flash-style: a block of keys and one of values, whole rows as they lie,
-are copied into the chip's fast memory once and serve all the column groups;
-the running maximum, sum and accumulator stay there; a block past the slot's
-length is neither copied (its index map names the last block in use again,
-which is not fetched twice) nor computed; and a slot with no lane to see names
-a block that is already in flight (the last one of the live slot before it),
-skips its arithmetic and leaves zeros.
+lane of EVERY slot. Here ONE sequence of grid steps walks the blocks in use of
+all slots back to back, flash-style (slot 0's blocks, then the next live
+slot's, ...: :func:`walk_tables`): a step copies a block of keys and one of
+values, whole rows as they lie, into the chip's fast memory once, and they
+serve all the column groups; the running maximum, sum and accumulator stay
+there, reset at a slot's first block, divided and stored at its last. A step's
+operands are named by the tables and not by the grid, so while a slot's last
+block is folded the next slot's first is already on its way, its queries with
+it, and the result of the slot before goes out (a program a slot, which this
+replaced, left a block's copy uncovered at every slot: 3.1 us a slot, 0.10 of
+a call's 1.59 ms at 32 slots x 13 blocks; PERF.md, PR 49). A slot with no lane
+to see owns no step: it is neither copied nor computed, and its rows come back
+zeros. The grid's extent is traced: as many steps as there are blocks in use
+(one, which does nothing, where there are none), so a call over a pool that is
+mostly empty costs what its few blocks cost (a static grid of a step for every
+block the leaf holds walked a tail of empty steps, 0.1 us each: 33 us of 1 487
+at 13 of 24 blocks a slot, 69 of 85 for an idle call; PERF.md, PR 49).
 
 The operands are the serving pool's leaves as stored, the WHOLE stacks
 ``[L, slots, lanes, KV x HD]``; the layer index, the slots' lane counts and the
-table of blocks an idle slot names are prefetched to scalar memory and the
-index maps pick the blocks. A window layer's ring (one window of lanes, every
-one of them inside the window of the position just written) is read the same
-way: its lanes up to the row's length, all of them once it has wrapped. One
+walk's tables are prefetched to scalar memory and the index maps pick the
+blocks. A window layer's ring (one window of lanes, every one of them inside
+the window of the position just written) is read the same way: its lanes up to
+the row's length, all of them once it has wrapped: one block a slot. One
 device's pool only (no caller hands it a leaf that a mesh shards).
 
 The callers (``generate``): the ``attn`` kind's ``_decode_block`` (a column
@@ -75,36 +85,39 @@ def engages(keys) -> bool:
             and (on_tpu() or INTERPRET_OFF_TPU))
 
 
-def blocks_named(visible):
-    """What each slot's index map names, from ``visible`` [slots] int32 (lanes
-    a slot sees; 0: the slot is idle): (src, lo, hi) [slots] int32 — at grid
-    step j slot b reads block ``clip(j, lo[b], hi[b])`` of slot ``src[b]``. A
-    live slot walks its own blocks 0 .. its last; an idle one names ONE block
-    at every step, the one in flight when the walk reaches it (the last block
-    of the live slot before it; before the first live slot that slot's block 0,
-    which the walk needs next), so it fetches nothing."""
-    n = visible.shape[0]
-    slot = jnp.arange(n, dtype=jnp.int32)
-    live = visible > 0
-    before = lax.cummax(jnp.where(live, slot, -1))                    # the live slot at or before b
-    after = lax.cummin(jnp.where(live, slot, n), reverse=True)        # ... at or after b
-    src = jnp.where(before >= 0, before, jnp.where(after < n, after, 0))
-    last = jnp.maximum(visible - 1, 0) // LANES
-    hi = jnp.where(before >= 0, last[src], 0)
-    return src, jnp.where(live, 0, hi), hi
+def walk_tables(visible, steps: int):
+    """The one walk over all slots' blocks, from ``visible`` [slots] int32
+    (lanes a slot sees; 0: the slot is idle): (slot_of, block_of) [steps] int32
+    and the number of blocks in use, int32. Step t < blocks reads block
+    ``block_of[t]`` of slot ``slot_of[t]``: slot 0's blocks 0 .. its last, then
+    the next live slot's, back to back; an idle slot owns no step. The steps
+    from ``blocks`` on (``steps`` is the most a walk can take; the grid stops
+    at ``blocks``) name the last block in use again, block 0 of slot 0 where no
+    slot is live: what the one step of an empty walk names."""
+    owned = -(-visible // LANES)                                   # blocks a slot owns
+    slot = jnp.arange(visible.shape[0], dtype=jnp.int32)
+    # the step after a slot's last (a cumulative sum, said as one compare-and-sum: the chip runs it as one small op)
+    end = jnp.sum(jnp.where(slot[None, :] <= slot[:, None], owned[None, :], 0), axis=1)
+    blocks = jnp.sum(owned)
+    t = jnp.minimum(jnp.arange(steps, dtype=jnp.int32), jnp.maximum(blocks - 1, 0))
+    behind = end[None, :] <= t[:, None]                            # [steps, slots]: the slots a step has passed
+    slot_of = jnp.minimum(jnp.sum(behind, axis=1, dtype=jnp.int32), visible.shape[0] - 1)
+    return slot_of * (blocks > 0), t - jnp.sum(behind * owned[None, :], axis=1, dtype=jnp.int32), blocks
 
 
-def _kernel(at_ref, n_ref, src_ref, lo_ref, hi_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+def _kernel(at_ref, n_ref, blocks_ref, slot_ref, block_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             scale: float, groups: int, width: int):
-    del at_ref, src_ref, lo_ref, hi_ref  # the index maps read them
-    b, j = pl.program_id(0), pl.program_id(1)
-    n = n_ref[b]                                                 # lanes this slot has
+    del at_ref  # the index maps read it
+    t = pl.program_id(0)
+    j = block_ref[t]
+    n = n_ref[slot_ref[t]]                                       # lanes this step's slot has
+    live = t < blocks_ref[0]                                     # else the one step of an empty walk
 
-    @pl.when(j == 0)
+    @pl.when(live & (j == 0))
     def _():
         _reset(m_ref, l_ref, acc_ref)
 
-    @pl.when(j * LANES < n)
+    @pl.when(live)
     def _():
         for i in range(groups):                                  # a column group: `width` columns of the rows
             cols = slice(i * width, (i + 1) * width)
@@ -114,10 +127,9 @@ def _kernel(at_ref, n_ref, src_ref, lo_ref, hi_ref, q_ref, k_ref, v_ref, o_ref, 
             _fold(jnp.where(lane < n, s, _NEG_INF), v_ref[0, 0, :, cols],
                   m_ref.at[i], l_ref.at[i], acc_ref.at[i])
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(live & ((j + 1) * LANES >= n))                      # the slot's last block
     def _():
-        # an idle slot's rows are defined: zeros, not 0 / 0
-        o_ref[0] = jnp.where(n > 0, acc_ref[...] / l_ref[...], 0.0)
+        o_ref[0] = acc_ref[...] / l_ref[...]
 
 
 def lane_decode(q, keys, values, layer, visible, *, scale: float, name: str):
@@ -139,21 +151,20 @@ def lane_decode(q, keys, values, layer, visible, *, scale: float, name: str):
     rows = -(-R // _ROWS) * _ROWS
     q = jnp.pad(q, ((0, 0), (0, 0), (0, rows - R), (0, 0)))
     visible = jnp.clip(visible.astype(jnp.int32), 0, S)
+    slot_of, block_of, blocks = walk_tables(visible, B * (S // LANES))
 
-    def rows_map(b, j, at, n, src, lo, hi):
-        # a block past the slot's last visible one names that one again, an idle
-        # slot the block in flight: neither is fetched anew
-        return (at[0], src[b], jnp.clip(j, lo[b], hi[b]), 0)
+    def rows_map(t, at, n, blocks, slot_of, block_of):
+        return (at[0], slot_of[t], block_of[t], 0)
 
-    def own(b, j, *_):
-        return (b, 0, 0, 0)
+    def own(t, at, n, blocks, slot_of, block_of):
+        return (slot_of[t], 0, 0, 0)
 
     out = pl.pallas_call(
         partial(_kernel, scale=scale, groups=P, width=W),
         name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
-            grid=(B, S // LANES),
+            grid=(jnp.maximum(blocks, 1),),                      # traced: as many steps as blocks in use
             in_specs=[pl.BlockSpec((1, P, rows, W), own),
                       pl.BlockSpec((1, 1, LANES, P * W), rows_map),
                       pl.BlockSpec((1, 1, LANES, P * W), rows_map)],
@@ -162,7 +173,8 @@ def lane_decode(q, keys, values, layer, visible, *, scale: float, name: str):
                             pltpu.VMEM((P, rows, W), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((B, P, rows, W), jnp.float32),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=not on_tpu(),
-    )(jnp.asarray(layer, jnp.int32).reshape(1), visible, *blocks_named(visible), q, keys, values)
-    return out[:, :, :R]
+    )(jnp.asarray(layer, jnp.int32).reshape(1), visible, blocks.reshape(1), slot_of, block_of, q, keys, values)
+    # an idle slot owns no step, so nothing wrote its rows: they are defined, zeros
+    return jnp.where((visible > 0)[:, None, None, None], out[:, :, :R], 0.0)

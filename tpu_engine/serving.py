@@ -65,6 +65,7 @@ from tpu_engine import layer_state
 from tpu_engine.generate import (
     MOE_COUNTS,
     KVCache,
+    diff_walk_engages,
     experts_grouped_engages,
     forward_with_cache,
     init_cache,
@@ -276,6 +277,23 @@ def lane_walk_layers(cfg: ModelConfig, cache: SlotCache) -> int:
     if keys is None or cache.ring or cache.sharded or not lane_walk_engages(keys, 1, cfg):
         return 0
     return keys.shape[0]
+
+
+def lane_walks(cfg: ModelConfig, cache: SlotCache) -> list[tuple[int, int, int]]:
+    """Every read of a :func:`decode_step` that goes through ``ops.lane_decode``,
+    decided as its trace decides: (calls a step, lanes of the leaf they walk,
+    lanes a slot that does not decode shows them). The ``attn`` kind's layers
+    hand such a slot 0 lanes (it owns no block); a differential kind's read
+    sees ``length + 1`` lanes of every row, 1 of a freed slot: the full-attention
+    layer and every cross-attention layer after it walk the ONE shared cache,
+    a window layer its ring."""
+    walks = [(lane_walk_layers(cfg, cache), cache.n_lanes, 0)]
+    for kind, window, readers in (("full_attn", 0, cfg.n_layers_of("diff_cross_attention")),
+                                  ("window_attn", cfg.sliding_window, 0)):
+        keys = cache.layers.get(kind, {}).get("k")
+        if keys is not None and diff_walk_engages(keys, 1, window):
+            walks.append((keys.shape[0] + readers, keys.shape[2], 1))
+    return [w for w in walks if w[0]]
 
 
 def in_place_update_layers(cfg: ModelConfig, cache: SlotCache) -> int:
@@ -987,6 +1005,12 @@ class ContinuousBatcher:
             else lane_walk_layers(cfg, self._cache)
         self._decode_attn_lanes_read = 0
         self._decode_attn_lanes_pool = 0
+        # Every read a decode step makes through that kernel, the differential
+        # kinds' too: the blocks its one walk over all slots carried and the
+        # grid steps it took for them.
+        self._lane_walks = [] if draft_params is not None else lane_walks(cfg, self._cache)
+        self._decode_attn_blocks_walked = 0
+        self._decode_attn_grid_steps = 0
         # A mixture's routing, by program: layer-steps run (counted here) and
         # ``generate.MOE_COUNTS`` (counted on the device, fetched with each
         # dispatch's tokens). Empty for a model without experts.
@@ -1391,6 +1415,15 @@ class ContinuousBatcher:
                 # both stay 0 where the kernel does not engage.
                 "decode_attn_lanes_read_total": self._decode_attn_lanes_read,
                 "decode_attn_lanes_pool_total": self._decode_attn_lanes_pool,
+                # Monotonic, per call of that kernel (an ``attn`` layer's, and
+                # a differential stack's reads of its shared cache and its
+                # rings): the blocks of 512 lanes its one walk over all slots
+                # carried, and the grid steps the call took (its grid is
+                # bounded by the blocks in use: a step a block, and one for a
+                # call that has none). Their ratio is the share of grid steps
+                # that carry a block: 1.0 but for empty calls.
+                "decode_attn_blocks_walked_total": self._decode_attn_blocks_walked,
+                "decode_attn_grid_steps_total": self._decode_attn_grid_steps,
                 "state_inserts_total": self._state_inserts,
                 "state_resets_total": self._state_resets,
                 # The weights the engine holds, by dtype, counted once at
@@ -1734,6 +1767,9 @@ class ContinuousBatcher:
         self._decode_attn_lanes_read += lanes_read
         self._decode_attn_lanes_pool += (n_steps * self._lane_walk_layers
                                          * self.max_slots * self._pool_lanes)
+        blocks, grid_steps = self._attn_blocks_walked(contexts)
+        self._decode_attn_blocks_walked += blocks
+        self._decode_attn_grid_steps += grid_steps
         if self._sparse_from is not None:
             # A row's steps run at positions context - 1 .. context + n - 2.
             self._decode_tokens_sparse += sum(
@@ -1819,6 +1855,21 @@ class ContinuousBatcher:
             return 0
         seen = np.minimum(np.asarray(contexts)[:, None] + np.arange(self.chunk_steps), self._pool_lanes)
         return self._lane_walk_layers * int((-(-seen // lane_decode.LANES) * lane_decode.LANES).sum())
+
+    def _attn_blocks_walked(self, contexts: list[int]) -> tuple[int, int]:
+        """(blocks, grid steps) of a dispatch's calls of the lane-walking
+        kernel (every entry of ``lane_walks``) for rows at ``contexts``, the
+        other slots showing each call what a slot that does not decode shows
+        it. A call's grid is bounded by its blocks, so it takes a step a block,
+        and one where it has none."""
+        idle = self.max_slots - len(contexts)
+        blocks = grid_steps = 0
+        for calls, lanes, idle_lanes in self._lane_walks:
+            seen = np.minimum(np.asarray(contexts, np.int64).reshape(-1, 1) + np.arange(self.chunk_steps), lanes)
+            a_call = (-(-seen // lane_decode.LANES)).sum(axis=0) + idle * -(-idle_lanes // lane_decode.LANES)
+            blocks += calls * int(a_call.sum())
+            grid_steps += calls * int(np.maximum(a_call, 1).sum())
+        return blocks, grid_steps
 
     def _experts_grouped(self, rows: int) -> bool:
         """Whether a walk of ``rows`` positions runs this engine's held experts
